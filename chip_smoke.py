@@ -1,0 +1,398 @@
+"""Quickest proof that the main path still starts on the chip.
+
+    python chip_smoke.py            # one chip: phases A and B
+    python chip_smoke.py --chips 4  # one host, four chips: that phase only
+
+Drives GPT-2 117M at published widths (12 x 768 x 12 heads, vocab 50257,
+n_ctx 1024), flash attention, bf16, AdamW, random weights from a fixed seed,
+through the entry points a user calls:
+
+  phase A  the RPC server binary (``--platform tpu``) as a child process and
+           a CPU-pinned ``TepdistSession`` client: compile_train_step + 5
+           ``run`` steps at batch 8 x seq 1024.
+  phase B  a child process that calls ``plan_training`` on the same model,
+           seed and batch and takes 5 steps; asserts the pallas kernel is in
+           the compiled step and that the planner's chip table describes the
+           attached device.
+  --chips 4  one child owning all four chips: ``plan_training(explore=True)``
+           over ``jax.devices()`` at batch 16, 5 steps, then the same 5 steps
+           on ``devices[:1]``; every device must hold a shard and the
+           compiled step must contain a collective.
+
+A chip belongs to one process at a time, so this script itself never
+initialises a backend other than the CPU and runs each phase's chip owner to
+completion before the next starts. There is no CPU fallback: any failed
+check, any phase that exits non-zero, any platform other than ``tpu`` ends
+the script with a non-zero exit code and no result line.
+
+Earlier stdout lines are one JSON object per phase (losses, planner and
+first-step seconds as SET-UP times, compile-cache traffic, peak device
+memory, whether the native helpers were built). On success the last line is
+exactly ``{"ok": true, "device": {"platform": ..., "kind": ..., "count":
+...}}`` as reported by the process that held the chip.
+
+(``python chip_smoke.py phase_b`` / ``phase_four`` is how the script starts
+its own children; a scratch script can also import the phase functions and
+call them with ``platform="cpu"`` at the tiny ``test`` config to rehearse.)
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import json
+import math
+import os
+import subprocess
+import sys
+import time
+
+import jax
+import optax
+
+from tepdist_tpu.core.compile_cache import (
+    compile_cache_dir,
+    configure_compile_cache,
+)
+from tepdist_tpu.models import gpt2
+from tepdist_tpu.rpc.local_server import pin_client_to_cpu, spawn_local_server
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+SEED = 0
+STEPS = 5
+CHILD_TIMEOUT_S = 900
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
+               "collective-permute", "all-to-all")
+
+
+def _check(ok: bool, why: str) -> None:
+    if not ok:
+        raise SystemExit(f"chip_smoke: FAILED: {why}")
+
+
+def _model(cfg_name: str, batch: int, seq: int):
+    """Same weights and tokens in every phase: everything from SEED."""
+    cfg = dataclasses.replace(gpt2.CONFIGS[cfg_name], attn="flash")
+    params = gpt2.init_params(cfg, jax.random.PRNGKey(SEED))
+    tokens = gpt2.fake_batch(cfg, batch, seq, seed=SEED)
+    return cfg, params, tokens, optax.adamw(1e-3)
+
+
+def _native_helpers() -> dict:
+    """Whether the C helpers were built from source here or fell back to
+    their Python twins (nothing prebuilt is committed)."""
+    from tepdist_tpu.native import native_available
+    from tepdist_tpu.telemetry import _fastobs
+    return {"native_scheduler": native_available(),
+            "fastobs": _fastobs.available()}
+
+
+def _cache_entries(cache_dir: str) -> int:
+    return len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
+
+
+class _CacheTraffic:
+    """This process's persistent-compile-cache lookups, hits and writes
+    (jax writes an entry only for a compile of a second or more)."""
+
+    EVENTS = {
+        "/jax/compilation_cache/compile_requests_use_cache": "cache_requests",
+        "/jax/compilation_cache/cache_hits": "cache_hits",
+        "/jax/compilation_cache/cache_misses": "cache_writes"}
+
+    def __init__(self):
+        self.counts = dict.fromkeys(self.EVENTS.values(), 0)
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_event(self, event: str, **_):
+        if event in self.EVENTS:
+            self.counts[self.EVENTS[event]] += 1
+
+    def since(self, before: dict, prefix: str) -> dict:
+        return {prefix + k: v - before[k] for k, v in self.counts.items()}
+
+
+def _own_devices(platform: str) -> list:
+    """Make this process the chip's owner; a missing chip is an error."""
+    jax.config.update("jax_platforms", platform)
+    devices = jax.devices()
+    _check(devices[0].platform == platform,
+           f"wanted platform {platform!r}, jax gave {devices[0].platform!r}")
+    return devices
+
+
+def _device_record(devices) -> dict:
+    stats = devices[0].memory_stats() or {}
+    return {"platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "n_devices": len(devices),
+            "peak_bytes_in_use": stats.get("peak_bytes_in_use")}
+
+
+def _take_steps(step_once, phase: str) -> tuple:
+    """(losses, seconds of step 0 incl. its compile). ``step_once`` returns
+    the loss as a host float, so a step has finished when it returns."""
+    t0 = time.perf_counter()
+    losses = [step_once()]
+    first = time.perf_counter() - t0
+    losses += [step_once() for _ in range(STEPS - 1)]
+    _check(all(math.isfinite(l) for l in losses),
+           f"{phase}: losses not finite: {losses}")
+    _check(losses[-1] < losses[0],
+           f"{phase}: loss did not fall over {STEPS} steps: {losses}")
+    return losses, round(first, 3)
+
+
+def _plan(cfg_name: str, batch: int, seq: int, devices, traffic,
+          **plan_kwargs):
+    """``plan_training`` on the smoke's model: (plan, tokens, record).
+    Weights are made anew for each plan — a plan's step donates them.
+    ``cache_hit``: the step program's first compile in this process (the
+    winner's post-check, inside ``plan_training``) was read from the
+    persistent cache and nothing that long had to be written."""
+    from tepdist_tpu.train import plan_training
+
+    cfg, params, tokens, tx = _model(cfg_name, batch, seq)
+    before = dict(traffic.counts)
+    t0 = time.perf_counter()
+    tplan = plan_training(lambda p, t: gpt2.loss_fn(p, t, cfg), tx, params,
+                          tokens, devices=devices, **plan_kwargs)
+    seconds = round(time.perf_counter() - t0, 3)
+    in_plan = traffic.since(before, "plan_")
+    return tplan, tokens, {
+        "setup_planner_seconds": seconds, **in_plan,
+        "cache_hit": in_plan["plan_cache_hits"] > 0
+        and in_plan["plan_cache_writes"] == 0}
+
+
+def _compiled_text_with_kernel(tplan, platform: str, phase: str) -> str:
+    text = tplan.compiled_step_text()
+    if platform == "tpu":
+        _check("tpu_custom_call" in text,
+               f"{phase}: no tpu_custom_call in the compiled step — the "
+               "flash kernel is interpreted or replaced by the einsum")
+    return text
+
+
+# ---------------------------------------------------------------------------
+# Phase A: CPU-pinned client + server child that owns the chip.
+# ---------------------------------------------------------------------------
+
+def phase_a(cfg_name: str = "117M", batch: int = 8, seq: int = 1024,
+            platform: str = "tpu") -> dict:
+    import grpc
+
+    from tepdist_tpu.client.session import TepdistSession
+
+    cache_dir = compile_cache_dir()    # the server's; this client has none
+    entries_before = _cache_entries(cache_dir)
+    # Server chatter goes to stderr: stdout carries the JSON lines only.
+    proc, port = spawn_local_server(platform, stdout=sys.stderr)
+    try:
+        # The client builds the model while the server reaches the chip.
+        cfg, params, tokens, tx = _model(cfg_name, batch, seq)
+
+        def step(params, opt_state, tokens):
+            loss, grads = jax.value_and_grad(
+                lambda p: gpt2.loss_fn(p, tokens, cfg))(params)
+            updates, opt_state = tx.update(grads, opt_state, params)
+            return loss, optax.apply_updates(params, updates), opt_state
+
+        sess = TepdistSession(f"127.0.0.1:{port}")
+        deadline = time.monotonic() + 180
+        while True:
+            _check(proc.poll() is None,
+                   f"phase A: server exited with code {proc.returncode} "
+                   f"before listening (no {platform} device?)")
+            try:
+                sess.client.wait_ready(timeout=2.0)
+                break
+            except grpc.FutureTimeoutError:
+                _check(time.monotonic() < deadline,
+                       "phase A: server not ready after 180 s")
+        info = sess.client.ping()
+        _check(info["platform"] == platform,
+               f"phase A: server reports platform {info['platform']!r}, "
+               f"wanted {platform!r}")
+        summary = sess.compile_train_step(step, params, tx.init(params),
+                                          tokens)
+        losses, first = _take_steps(lambda: sess.run(tokens), "phase A")
+        sess.close()
+    finally:
+        proc.terminate()
+        try:
+            proc.wait(timeout=30)
+        except subprocess.TimeoutExpired:
+            proc.kill()
+            proc.wait()
+    new_entries = _cache_entries(cache_dir) - entries_before
+    return {
+        "phase": "A", "entry": "rpc.server + TepdistSession",
+        "model": f"gpt2-{cfg_name}", "batch": batch, "seq": seq,
+        "platform": info["platform"], "device_kind": info["device_kind"],
+        "n_devices": info["n_devices"], "axes": summary["axes"],
+        "losses": losses,
+        "setup_planner_seconds": summary["planner_seconds"],
+        "setup_first_step_seconds": first,
+        # The server owns the chip: no verb reports its cache traffic or
+        # its memory. New files in its cache directory are all a client
+        # can count; the first-step seconds say more.
+        "cache_dir": cache_dir, "cache_new_entries": new_entries,
+        "cache_hit": None, "peak_bytes_in_use": None,
+        **_native_helpers(),
+    }
+
+
+# ---------------------------------------------------------------------------
+# Phase B: plan_training in the process that owns the chip.
+# ---------------------------------------------------------------------------
+
+def phase_b(cfg_name: str = "117M", batch: int = 8, seq: int = 1024,
+            platform: str = "tpu") -> dict:
+    from tepdist_tpu.parallel.performance_utils import (
+        chip_spec,
+        chip_spec_for_device_kind,
+    )
+
+    devices = _own_devices(platform)[:1]
+    cache_dir = configure_compile_cache()
+    traffic = _CacheTraffic()
+    attached = chip_spec_for_device_kind(devices[0].device_kind).name
+    _check(chip_spec().name == attached,
+           f"phase B: planner prices a {chip_spec().name!r} chip but the "
+           f"attached device is {devices[0].device_kind!r} ({attached!r})")
+
+    tplan, tokens, planned = _plan(cfg_name, batch, seq, devices, traffic)
+    text = _compiled_text_with_kernel(tplan, platform, "phase B")
+    losses, first = _take_steps(lambda: tplan.step(tokens), "phase B")
+    return {
+        "phase": "B", "entry": "plan_training",
+        "model": f"gpt2-{cfg_name}", "batch": batch, "seq": seq,
+        **_device_record(devices),
+        "axes": list(tplan.parallel_plan.topology.device_axes()),
+        "chip_spec": chip_spec().name,
+        "kernel_calls_in_hlo": text.count("tpu_custom_call"),
+        "losses": losses, **planned,
+        "setup_first_step_seconds": first,
+        "cache_dir": cache_dir, **traffic.counts,
+        **_native_helpers(),
+    }
+
+
+# ---------------------------------------------------------------------------
+# Four chips: explored layout over the host's devices vs the same steps on
+# one of them, in one process that owns all four.
+# ---------------------------------------------------------------------------
+
+def phase_four(cfg_name: str = "117M", batch: int = 16, seq: int = 1024,
+               platform: str = "tpu", n_devices: int = 4) -> dict:
+    devices = _own_devices(platform)
+    _check(len(devices) == n_devices,
+           f"four-chip phase: {len(devices)} devices attached, wanted "
+           f"{n_devices}")
+    cache_dir = configure_compile_cache()
+    traffic = _CacheTraffic()
+
+    # No gradient accumulation: the SPMD planner cannot see into the GA
+    # scan, and a plan with micro batches shards nothing (every chip would
+    # run the whole step, which plan_training warns about).
+    tplan, tokens, planned = _plan(cfg_name, batch, seq, devices, traffic,
+                                   explore=True, num_micro_batches=1)
+    winner = tplan.exploration_report["winner"]
+    _check(winner["kind"] == "spmd",
+           f"four-chip phase: winner {winner} is not an SPMD plan; the "
+           "shard and collective checks below read one compiled program")
+    text = _compiled_text_with_kernel(tplan, platform, "four-chip phase")
+    found = [c for c in COLLECTIVES if c in text]
+    _check(bool(found), "four-chip phase: no collective in the compiled "
+           "step — nothing crosses chips")
+    losses, first = _take_steps(lambda: tplan.step(tokens),
+                                "four-chip phase")
+    # Code that has never seen a second chip may put everything on the
+    # first: every device must hold a shard of some state array.
+    holders = {s.device.id for leaf in tplan._device_state()
+               for s in leaf.addressable_shards}
+    _check(holders == {d.id for d in devices},
+           f"four-chip phase: state shards live on devices "
+           f"{sorted(holders)} only, of {[d.id for d in devices]}")
+    record = _device_record(devices)
+    del tplan
+
+    # Same weights, tokens and steps on one chip.
+    one, tokens, _ = _plan(cfg_name, batch, seq, devices[:1], traffic,
+                           num_micro_batches=1)
+    one_losses, _ = _take_steps(lambda: one.step(tokens),
+                                "one-chip comparison")
+    for i, (a, b) in enumerate(zip(losses, one_losses)):
+        _check(abs(a - b) <= 2e-2 * abs(b),
+               f"four-chip phase: step {i} loss {a} vs one chip {b} "
+               "differ by more than 2e-2 relative")
+    return {
+        "phase": "four", "entry": "plan_training(explore=True)",
+        "model": f"gpt2-{cfg_name}", "batch": batch, "seq": seq,
+        **record,
+        "winner": winner, "collectives_in_hlo": found,
+        "devices_holding_shards": sorted(holders),
+        "losses": losses, "one_chip_losses": one_losses, **planned,
+        "setup_first_step_seconds": first,
+        "cache_dir": cache_dir, **traffic.counts,
+        **_native_helpers(),
+    }
+
+
+CHILD_PHASES = {"phase_b": phase_b, "phase_four": phase_four}
+
+
+def _run_child(phase: str) -> dict:
+    """Run one chip-owning phase as a child to completion; its last stdout
+    line is its record. A non-zero exit or a timeout fails the script."""
+    out = subprocess.run(
+        [sys.executable, os.path.abspath(__file__), phase], cwd=HERE,
+        stdout=subprocess.PIPE, text=True, timeout=CHILD_TIMEOUT_S)
+    _check(out.returncode == 0,
+           f"{phase} child exited with code {out.returncode}")
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def _emit(record: dict) -> None:
+    print(json.dumps(record), flush=True)
+
+
+def main() -> None:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    parser.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    parser.add_argument("phase", nargs="?", choices=sorted(CHILD_PHASES),
+                        help=argparse.SUPPRESS)
+    args = parser.parse_args()
+    if args.phase:
+        _emit(CHILD_PHASES[args.phase]())
+        return
+
+    # This process stays off the chip; each phase's child owns it in turn.
+    pin_client_to_cpu()
+    if args.chips == 4:
+        holder = _run_child("phase_four")
+        _emit(holder)
+    else:
+        a = phase_a()
+        _emit(a)
+        holder = _run_child("phase_b")
+        _emit(holder)
+        _check((a["platform"], a["device_kind"])
+               == (holder["platform"], holder["device_kind"]),
+               "phases A and B ran on different devices")
+        _check(abs(a["losses"][0] - holder["losses"][0])
+               <= 1e-2 * abs(holder["losses"][0]),
+               f"step-0 loss of phase A {a['losses'][0]} and phase B "
+               f"{holder['losses'][0]} differ by more than 1e-2 relative "
+               "(same weights, same tokens, no update yet)")
+    _check(holder["platform"] == "tpu" and holder["n_devices"] == args.chips,
+           f"ran on {holder['n_devices']} {holder['platform']} device(s), "
+           f"wanted {args.chips} tpu")
+    print(json.dumps({"ok": True, "device": {
+        "platform": holder["platform"], "kind": holder["device_kind"],
+        "count": holder["n_devices"]}}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

@@ -20,23 +20,9 @@ _sys.path.insert(0, _os.path.abspath(_os.path.join(
 import argparse
 import os
 import signal
-import socket
-import subprocess
-import sys
 
 import jax
 import optax
-
-
-def spawn_local_server() -> tuple:
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    port = s.getsockname()[1]
-    s.close()
-    proc = subprocess.Popen(
-        [sys.executable, "-m", "tepdist_tpu.rpc.server",
-         "--port", str(port)], env=dict(os.environ))
-    return proc, port
 
 
 def main():
@@ -55,10 +41,18 @@ def main():
 
     from tepdist_tpu.client.session import TepdistSession
     from tepdist_tpu.models import gpt2, sampling
+    from tepdist_tpu.rpc.local_server import (
+        pin_client_to_cpu,
+        server_platform,
+        spawn_local_server,
+    )
 
+    # One process per chip: the client stays on the CPU, the server it
+    # spawns is told to own the chip.
+    pin_client_to_cpu()
     proc = None
     if args.local:
-        proc, port = spawn_local_server()
+        proc, port = spawn_local_server(server_platform())
         address = f"127.0.0.1:{port}"
     else:
         address = (f"{os.environ.get('SERVER_IP', '127.0.0.1')}:"

@@ -31,9 +31,12 @@ def main():
     args = parser.parse_args()
 
     from jax.sharding import Mesh
+    from tepdist_tpu.core.compile_cache import configure_compile_cache
     from tepdist_tpu.models import gpt2
     from tepdist_tpu.ops.ring_attention import ring_attention
     from tepdist_tpu.ops.ulysses import ulysses_attention
+
+    configure_compile_cache()
 
     cfg = gpt2.CONFIGS[args.config]
     devices = jax.devices()

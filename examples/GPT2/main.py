@@ -43,9 +43,12 @@ def main():
                              "is fake input (reference FAKE_INPUT mode)")
     args = parser.parse_args()
 
+    from tepdist_tpu.core.compile_cache import configure_compile_cache
     from tepdist_tpu.core.mesh import MeshTopology
     from tepdist_tpu.models import gpt2
     from tepdist_tpu.parallel.auto_parallel import auto_parallel
+
+    configure_compile_cache()
 
     if os.path.exists(args.config):
         with open(args.config) as f:

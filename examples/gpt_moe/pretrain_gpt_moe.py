@@ -27,10 +27,13 @@ def main():
                         help="devices on the expert axis (0 = all)")
     args = parser.parse_args()
 
+    from tepdist_tpu.core.compile_cache import configure_compile_cache
     from tepdist_tpu.core.dist_spec import DimStrategy
     from tepdist_tpu.core.mesh import MeshTopology
     from tepdist_tpu.models import gpt2, gpt_moe
     from tepdist_tpu.parallel.auto_parallel import auto_parallel
+
+    configure_compile_cache()
 
     cfg = gpt_moe.CONFIGS[args.config]
     params = gpt_moe.init_params(cfg, jax.random.PRNGKey(0))

@@ -33,9 +33,12 @@ def main():
                         help="packed token file (default: random tokens)")
     args = parser.parse_args()
 
+    from tepdist_tpu.core.compile_cache import configure_compile_cache
     from tepdist_tpu.core.mesh import MeshTopology
     from tepdist_tpu.models import llama
     from tepdist_tpu.parallel.auto_parallel import auto_parallel
+
+    configure_compile_cache()
 
     cfg = dataclasses.replace(llama.CONFIGS[args.config], attn=args.attn)
     params = llama.init_params(cfg, jax.random.PRNGKey(0))

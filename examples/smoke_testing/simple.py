@@ -13,27 +13,11 @@ _sys.path.insert(0, _os.path.abspath(_os.path.join(
     _os.path.dirname(_os.path.abspath(__file__)), "..", "..")))
 
 import argparse
-import os
 import signal
-import socket
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
 import optax
-
-
-def spawn_local_server() -> tuple:
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    port = s.getsockname()[1]
-    s.close()
-    env = dict(os.environ)
-    proc = subprocess.Popen(
-        [sys.executable, "-m", "tepdist_tpu.rpc.server", "--port", str(port)],
-        env=env)
-    return proc, port
 
 
 def main():
@@ -43,14 +27,22 @@ def main():
     parser.add_argument("--steps", type=int, default=10)
     args = parser.parse_args()
 
+    from tepdist_tpu.client.session import TepdistSession
+    from tepdist_tpu.models import mlp
+    from tepdist_tpu.rpc.local_server import (
+        pin_client_to_cpu,
+        server_platform,
+        spawn_local_server,
+    )
+
+    # One process per chip: the client stays on the CPU, the server it
+    # spawns is told to own the chip.
+    pin_client_to_cpu()
     proc = None
     address = None
     if args.local:
-        proc, port = spawn_local_server()
+        proc, port = spawn_local_server(server_platform())
         address = f"127.0.0.1:{port}"
-
-    from tepdist_tpu.client.session import TepdistSession
-    from tepdist_tpu.models import mlp
 
     k = jax.random.PRNGKey(0)
     params = mlp.init_mlp(k, din=32, dh=64, dout=8)
@@ -75,6 +67,7 @@ def main():
     sess.close()
     if proc is not None:
         proc.send_signal(signal.SIGKILL)
+        proc.wait()
 
 
 if __name__ == "__main__":

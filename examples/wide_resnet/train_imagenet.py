@@ -25,9 +25,12 @@ def main():
     parser.add_argument("--steps", type=int, default=10)
     args = parser.parse_args()
 
+    from tepdist_tpu.core.compile_cache import configure_compile_cache
     from tepdist_tpu.core.mesh import MeshTopology
     from tepdist_tpu.models import wide_resnet as wrn
     from tepdist_tpu.parallel.auto_parallel import auto_parallel
+
+    configure_compile_cache()
 
     cfg = wrn.CONFIGS[args.model_type]
     params = wrn.init_params(cfg, jax.random.PRNGKey(0))

@@ -20,7 +20,6 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 from jax.extend import core as jexcore
 
-from tepdist_tpu.core.jax_compat import fresh_var
 from tepdist_tpu.graph.cost import (
     COMPUTE_INTENSIVE,
     aval_bytes,
@@ -105,7 +104,7 @@ def inline_calls(jaxpr, max_depth: int = 16):
                     if type(ov).__name__ == "DropVar":
                         new_outvars.append(ov)
                     else:
-                        fresh = fresh_var(ov.aval)
+                        fresh = Var(ov.aval)
                         inner_env[ov] = fresh
                         new_outvars.append(fresh)
                 new_eqns.append(sub_eqn.replace(invars=new_invars, outvars=new_outvars))

@@ -23,10 +23,8 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from tepdist_tpu.core.jax_compat import pcast, shard_map
 
 
 def _pipeline_local(stage_params, x_micro, *, stage_fn, axis: str,
@@ -67,8 +65,8 @@ def _pipeline_local(stage_params, x_micro, *, stage_fn, axis: str,
     state0 = jnp.zeros(mb_shape, x_micro.dtype)
     out0 = jnp.zeros((M,) + mb_shape, x_micro.dtype)
     vary = tuple(vary_axes) if vary_axes else (axis,)
-    state0 = pcast(state0, vary, to="varying")
-    out0 = pcast(out0, vary, to="varying")
+    state0 = lax.pcast(state0, vary, to="varying")
+    out0 = lax.pcast(out0, vary, to="varying")
     (_, out_buf), _ = lax.scan(tick, (state0, out0), jnp.arange(T))
     # Only the last stage holds real outputs; psum makes them replicated.
     mask = (idx == S - 1).astype(x_micro.dtype)

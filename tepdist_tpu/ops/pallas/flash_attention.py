@@ -11,17 +11,31 @@ Usable standalone, as the ``inner`` of Ulysses sequence parallelism, or as
 the per-block compute of ring attention. Runs in interpret mode off-TPU
 (tests), compiled on TPU. Reference parity: none — the reference has no
 fused attention at all (SURVEY.md §5.7); this is TPU-native surplus.
+
+Sequence-length limit: T <= 8192 per call. Each grid step holds a whole
+``(1, T, D)`` K and V block (forward, dQ) or Q and dO block (dK/dV) in
+VMEM and only tiles the other operand, so VMEM use grows with T. The
+v5e compiler (16 MiB scoped-VMEM limit) accepts forward+backward at
+(B,H,T,D) = (1,12,8192,64) and refuses the backward at T = 16384 and the
+forward at T = 32768 ("Scoped allocation with size 32.75M and limit
+16.00M") — a compile error, never a wrong answer. Longer sequences go
+through ring attention, which calls this kernel per T/P block.
+tests/test_tpu_compile.py compiles the main-path shapes for a described
+v5e.
 """
 
 from __future__ import annotations
 
 import functools
+import logging
 import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+log = logging.getLogger(__name__)
 
 _NEG_INF = -1e30
 
@@ -321,6 +335,7 @@ def flash_attention_with_lse(q, k, v, causal: bool = True,
                 jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad),
                 causal=True, scale=scale, interpret=interpret)
             return o[:, :, :T, :], lse[:, :, :T]
+        _log_dense_fallback(T)
         s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                        k.astype(jnp.float32)) * scale
         m = s.max(axis=-1, keepdims=True)
@@ -352,6 +367,14 @@ def _default_block(T: int) -> Optional[int]:
             if T % b == 0:
                 return b
     return None
+
+
+@functools.lru_cache(maxsize=None)
+def _log_dense_fallback(T: int) -> None:
+    """Runs at trace time; the cache makes it once per sequence length."""
+    log.warning("flash_attention: no lane-aligned tile divides non-causal "
+                "T=%d; tracing the dense O(T^2) einsum instead of the "
+                "pallas kernel", T)
 
 
 def _dense_attention(q, k, v, causal: bool, scale: float):
@@ -391,6 +414,7 @@ def flash_attention(q, k, v, causal: bool = True,
             return out[:, :, :T, :]
         # Non-causal: padded keys would be attended; dense is the only
         # exact fallback (rare — awkward T with bidirectional attention).
+        _log_dense_fallback(T)
         return _dense_attention(q, k, v, causal, scale)
     block_q, block_k = blocks
     if interpret is None:

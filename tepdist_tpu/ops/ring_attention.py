@@ -21,10 +21,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from tepdist_tpu.core.jax_compat import axis_size, pcast, shard_map
 
 _NEG_INF = -1e30
 
@@ -53,7 +51,7 @@ def _block_attention(q, k, v, m, l, o, q_start, k_start, causal, scale):
 def _ring_attention_local(q, k, v, *, axis_name: str, causal: bool,
                           scale: Optional[float]):
     """Per-device body (runs under shard_map)."""
-    P_ = axis_size(axis_name)
+    P_ = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     B, H, Tl, D = q.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
@@ -63,7 +61,7 @@ def _ring_attention_local(q, k, v, *, axis_name: str, causal: bool,
     o0 = jnp.zeros((B, H, Tl, D), jnp.float32)
     # Mark the accumulators as device-varying over the ring axis so the
     # fori_loop carry types match (shard_map varying-axis typing).
-    m0, l0, o0 = (pcast(x, (axis_name,), to="varying")
+    m0, l0, o0 = (lax.pcast(x, (axis_name,), to="varying")
                   for x in (m0, l0, o0))
 
     perm = [(i, (i + 1) % P_) for i in range(P_)]
@@ -96,7 +94,7 @@ def _ring_flash_local(q, k, v, *, axis_name: str, causal: bool,
         flash_attention_with_lse,
     )
 
-    P_ = axis_size(axis_name)
+    P_ = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     B, H, Tl, D = q.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(D)

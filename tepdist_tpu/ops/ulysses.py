@@ -14,10 +14,8 @@ import functools
 from typing import Callable, Optional
 
 import jax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from tepdist_tpu.core.jax_compat import shard_map
 
 
 def _ulysses_local(q, k, v, *, axis_name: str, causal: bool,

@@ -209,16 +209,11 @@ def detect_motifs(graph: JaxprGraph,
     for node in graph.nodes:
         if node.prim != "pallas_call":
             continue
-        # jax 0.4.x keys the tag as name_and_src_info (a NameAndSrcInfo
-        # whose str() appends " for kernel function ... at file:line");
-        # newer jax keys a plain string under "name". Parse the bare name.
-        name = (node.eqn.params.get("name")
-                or node.eqn.params.get("name_and_src_info") or "")
-        name = getattr(name, "name", name)
-        if not str(name).startswith("tepdist_flash_fwd"):
+        name = node.eqn.params.get("name") or ""
+        if not name.startswith("tepdist_flash_fwd"):
             continue
         try:
-            parts = str(name).split("__")
+            parts = name.split("__")
             causal = bool(int(parts[1][1:]))
             scale = float(parts[2][1:])
             n_head = (int(parts[3][1:]) if len(parts) > 3

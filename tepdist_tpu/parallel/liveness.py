@@ -17,7 +17,6 @@ from typing import Dict, List
 
 from jax.extend import core as jexcore
 
-from tepdist_tpu.core.jax_compat import fresh_var
 from tepdist_tpu.graph.jaxpr_graph import JaxprGraph
 
 Var = jexcore.Var
@@ -63,7 +62,7 @@ def optimize_liveness(graph: JaxprGraph, min_range: int = 32,
             if type(o).__name__ == "DropVar":
                 new_outs.append(o)
             else:
-                fresh = fresh_var(o.aval)
+                fresh = Var(o.aval)
                 out_map[o] = fresh
                 new_outs.append(fresh)
         return eqn.replace(outvars=new_outs)

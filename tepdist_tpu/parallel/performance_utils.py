@@ -43,6 +43,30 @@ TPU_CHIPS: Dict[str, TpuChipSpec] = {
 }
 
 
+# ``jax.Device.device_kind`` of each chip in the table. The planner prices
+# with the generation TPU_GENERATION names; measurement code (chip_smoke.py,
+# bench.py) looks the attached device up here so that a peak is never
+# assumed for a chip the table does not describe.
+DEVICE_KIND_GENERATION: Dict[str, str] = {
+    "TPU v4": "v4",
+    "TPU v5 lite": "v5e",
+    "TPU v5": "v5p",
+    "TPU v6 lite": "v6e",
+    "cpu": "cpu",
+}
+
+
+def chip_spec_for_device_kind(device_kind: str) -> TpuChipSpec:
+    """The table row of an attached device (``jax.Device.device_kind``);
+    an unknown kind is an error, not a default."""
+    gen = DEVICE_KIND_GENERATION.get(device_kind)
+    if gen is None:
+        raise KeyError(
+            f"unknown device_kind {device_kind!r}; known: "
+            f"{list(DEVICE_KIND_GENERATION)}")
+    return chip_spec(gen)
+
+
 def chip_spec(generation: str | None = None) -> TpuChipSpec:
     gen = generation or ServiceEnv.get().tpu_generation
     spec = TPU_CHIPS.get(gen.lower())

@@ -205,6 +205,17 @@ def plan_pipeline(
 
     micro_batch = micro_abstract_batch(batch, num_micro_batches, batch_dim)
     graph, in_tree, _ = trace_graph(loss_fn, params, *micro_batch)
+    if any(n.prim == "pallas_call" for n in graph.nodes):
+        # Stage programs differentiate the staged forward with jax.vjp,
+        # and tracing inlined the kernel's custom_vjp wrapper: what is
+        # left is a raw pallas_call, whose generic JVP rule fails deep
+        # inside jax. Refuse here, where exploration records it as a
+        # pruned proposal and a direct caller reads why.
+        raise NotImplementedError(
+            "pipeline stages cannot differentiate a pallas kernel (e.g. "
+            "attn='flash'): its custom_vjp was inlined away when the loss "
+            "was traced. Use an SPMD plan, or einsum attention, for this "
+            "model")
     sketch = GraphSketch(graph)
     assignment = sketch.stage_plan(num_stages)
     decomp = StageDecomposition(graph, assignment, num_stages)

@@ -55,6 +55,88 @@ def combine_axis_strategies(
     return combined
 
 
+# --------------------------------------------------------------------------
+# Pallas kernels inside a multi-device program. XLA cannot partition a Mosaic
+# kernel ("Mosaic kernels cannot be automatically partitioned. Please wrap the
+# call in a shard_map"), and the planner prices ``pallas_call`` as opaque —
+# operands and results replicated — so each kernel is bound under a shard_map
+# whose specs are all replicated: every device runs the whole kernel on
+# gathered operands. Kernels nested in control flow (the GA scan, scan over
+# layers) are reached by re-tracing those bodies through the same binder.
+# --------------------------------------------------------------------------
+
+def _bodies(param) -> tuple:
+    """The ClosedJaxprs a scan/while/cond param holds, else ()."""
+    if isinstance(param, jexcore.ClosedJaxpr):
+        return (param,)
+    if (isinstance(param, tuple) and param
+            and all(isinstance(b, jexcore.ClosedJaxpr) for b in param)):
+        return param
+    return ()
+
+
+def _has_kernel(jaxpr) -> bool:
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            return True
+        if eqn.primitive.name == "shard_map":
+            continue            # already manual: its kernels lower as they are
+        if any(_has_kernel(b.jaxpr) for param in eqn.params.values()
+               for b in _bodies(param)):
+            return True
+    return False
+
+
+def _retrace_for_mesh(closed, mesh: Mesh):
+    """The same body with its kernels bound for ``mesh``."""
+    if not _has_kernel(closed.jaxpr):
+        return closed
+    avals = [jax.ShapeDtypeStruct(v.aval.shape, v.aval.dtype,
+                                  weak_type=v.aval.weak_type)
+             for v in closed.jaxpr.invars]
+
+    def body(*args):
+        env: Dict[Var, Any] = dict(zip(closed.jaxpr.constvars, closed.consts))
+        env.update(zip(closed.jaxpr.invars, args))
+
+        def read(a):
+            return a.val if isinstance(a, Literal) else env[a]
+
+        for eqn in closed.jaxpr.eqns:
+            outs = bind_for_mesh(eqn, [read(a) for a in eqn.invars], mesh)
+            env.update((ov, o) for ov, o in zip(eqn.outvars, outs)
+                       if type(ov).__name__ != "DropVar")
+        return [read(a) for a in closed.jaxpr.outvars]
+
+    return jax.make_jaxpr(body)(*avals)
+
+
+def bind_for_mesh(eqn, vals, mesh: Mesh) -> list:
+    """``eqn.primitive.bind`` on ``vals``, as a list of outputs, with any
+    pallas kernel in it (or in its bodies) bound for ``mesh``."""
+    # get_bind_params: staged params -> bindable form (how eval_jaxpr
+    # re-binds pjit/shard_map/custom_* eqns).
+    subfuns, params = eqn.primitive.get_bind_params(eqn.params)
+    name = eqn.primitive.name
+    if mesh.size > 1 and name == "pallas_call":
+        rep = PartitionSpec()
+        return list(jax.shard_map(
+            lambda *operands: tuple(eqn.primitive.bind(*operands, **params)),
+            mesh=mesh, in_specs=(rep,) * len(vals),
+            out_specs=(rep,) * len(eqn.outvars),
+            # pallas results carry no vma (same posture as ops/ulysses.py)
+            check_vma=False)(*vals))
+    if mesh.size > 1 and name != "shard_map":
+        retraced = {
+            key: (new if isinstance(param, tuple) else new[0])
+            for key, param in params.items()
+            if (new := tuple(_retrace_for_mesh(b, mesh)
+                             for b in _bodies(param)))}
+        params = {**params, **retraced}
+    outs = eqn.primitive.bind(*subfuns, *vals, **params)
+    return list(outs) if eqn.primitive.multiple_results else [outs]
+
+
 @dataclasses.dataclass
 class ShardingPlan:
     """Lowered plan: PartitionSpecs for I/O + interior constraint points."""
@@ -219,14 +301,8 @@ class SpmdTransform:
                     continue
                 if i in skip_ids:
                     continue
-                vals = [read(a) for a in eqn.invars]
-                # get_bind_params: staged params -> bindable form (how
-                # eval_jaxpr re-binds pjit/shard_map/custom_* eqns).
-                subfuns, bind_params = eqn.primitive.get_bind_params(
-                    eqn.params)
-                outs = eqn.primitive.bind(*subfuns, *vals, **bind_params)
-                if not eqn.primitive.multiple_results:
-                    outs = [outs]
+                outs = bind_for_mesh(
+                    eqn, [read(a) for a in eqn.invars], mesh)
                 for ov, val in zip(eqn.outvars, outs):
                     if type(ov).__name__ != "DropVar":
                         write(ov, val)

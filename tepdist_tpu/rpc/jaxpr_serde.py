@@ -28,15 +28,13 @@ import jax
 from jax.extend import core as jexcore
 from jax._src import core as _core
 
-from tepdist_tpu.core.jax_compat import fresh_var
-
 import logging
 log = logging.getLogger(__name__)
 
 # Prims that ARE effects at the leaf level (Ref read/write inside pallas
 # kernels; state primitives; host interaction). Call-like prims (scan/
-# while/cond/pjit/shard_map/remat/custom_* — under whatever name this jax
-# version uses) are handled STRUCTURALLY instead: their decoded sub-jaxpr
+# while/cond/pjit/shard_map/remat/custom_*) are handled STRUCTURALLY
+# instead: their decoded sub-jaxpr
 # params carry recomputed effects, so an eqn re-runs abstract_eval only
 # when an inner effect actually exists — effect-free bodies (the RPC hot
 # path) decode without paying a recursive abstract_eval.
@@ -73,52 +71,30 @@ def _may_carry_effects(prim, params: dict) -> bool:
 
 def _build_primitive_registry() -> Dict[str, Any]:
     registry: Dict[str, Any] = {}
-    modules = []
     from jax.extend.core import primitives as _prims
-    modules.append(_prims)
-    try:
-        import jax._src.lax.lax as m1
-        import jax._src.lax.control_flow as m2
-        import jax._src.lax.slicing as m3
-        import jax._src.lax.convolution as m4
-        import jax._src.lax.windowed_reductions as m5
-        import jax._src.lax.special as m6
-        import jax._src.lax.linalg as m7
-        import jax._src.lax.ann as m8
-        import jax._src.prng as m9
-        import jax._src.ad_util as m10
-        modules.extend([m1, m2, m3, m4, m5, m6, m7, m8, m9, m10])
-        import jax._src.lax.parallel as m11
-        modules.append(m11)
-        import jax._src.ad_checkpoint as m11b  # name_p / remat_p
-        modules.append(m11b)
-    except ImportError:  # pragma: no cover - internal layout moved
-        pass
-    try:
-        import jax._src.shard_map as m12   # shard_map_p: the SPMD wrapper
-        modules.append(m12)
-    except ImportError:  # jax<=0.4.x kept it under experimental
-        try:
-            import jax.experimental.shard_map as m12
-            modules.append(m12)
-        except ImportError:  # pragma: no cover - internal layout moved
-            pass
-    try:
-        import jax._src.pjit as m13        # sharding_constraint_p etc.
-        modules.append(m13)
-        modules.append(_core)              # pvary_p (vma adjustment)
-    except ImportError:  # pragma: no cover - internal layout moved
-        pass
-    try:
-        # Pallas kernels ship over RPC as first-class jaxprs: the call
-        # primitive itself, the in-kernel Ref state primitives (get/swap/
-        # addupdate), and pallas helper prims (program_id etc.).
-        import jax._src.pallas.pallas_call as m14
-        import jax._src.pallas.primitives as m15
-        import jax._src.state.primitives as m16
-        modules.extend([m14, m15, m16])
-    except ImportError:  # pragma: no cover - internal layout moved
-        pass
+    import jax._src.lax.lax as m1
+    import jax._src.lax.control_flow as m2
+    import jax._src.lax.slicing as m3
+    import jax._src.lax.convolution as m4
+    import jax._src.lax.windowed_reductions as m5
+    import jax._src.lax.special as m6
+    import jax._src.lax.linalg as m7
+    import jax._src.lax.ann as m8
+    import jax._src.prng as m9
+    import jax._src.ad_util as m10
+    import jax._src.lax.parallel as m11
+    import jax._src.ad_checkpoint as m11b  # name_p / remat_p
+    import jax._src.shard_map as m12       # shard_map_p: the SPMD wrapper
+    import jax._src.pjit as m13            # sharding_constraint_p etc.
+    # Pallas kernels ship over RPC as first-class jaxprs: the call
+    # primitive itself, the in-kernel Ref state primitives (get/swap/
+    # addupdate), and pallas helper prims (program_id etc.).
+    import jax._src.pallas.pallas_call as m14
+    import jax._src.pallas.primitives as m15
+    import jax._src.state.primitives as m16
+    # _core carries pvary_p (vma adjustment).
+    modules = [_prims, m1, m2, m3, m4, m5, m6, m7, m8, m9, m10, m11, m11b,
+               m12, m13, _core, m14, m15, m16]
     for mod in modules:
         for name in dir(mod):
             obj = getattr(mod, name, None)
@@ -140,7 +116,11 @@ def primitive_by_name(name: str):
 
 
 # Named tuples / enums that appear in lax params.
+import dataclasses as _dc
+
+import jax._src.pallas.core as _pl_core
 from jax import lax as _lax
+from jax._src.frozen_dict import FrozenDict as _FrozenDict
 
 _NAMEDTUPLES = {
     "ConvDimensionNumbers": _lax.ConvDimensionNumbers,
@@ -150,14 +130,9 @@ _NAMEDTUPLES = {
 _ENUMS = {
     "GatherScatterMode": _lax.GatherScatterMode,
     "Precision": _lax.Precision,
-    "RandomAlgorithm": getattr(_lax, "RandomAlgorithm", None),
+    "RandomAlgorithm": _lax.RandomAlgorithm,
+    "PallasMemorySpace": _pl_core.MemorySpace,
 }
-try:
-    import jax._src.pallas.core as _pl_core
-    _ENUMS["PallasMemorySpace"] = _pl_core.MemorySpace
-except ImportError:  # pragma: no cover - internal layout moved
-    _pl_core = None
-_ENUMS = {k: v for k, v in _ENUMS.items() if v is not None}
 
 
 # --------------------------------------------------------------------------
@@ -172,15 +147,9 @@ _ENUMS = {k: v for k, v in _ENUMS.items() if v is not None}
 # --------------------------------------------------------------------------
 
 def _treedef_node_types() -> Dict[str, Any]:
-    types: Dict[str, Any] = {"tuple": tuple, "list": list, "dict": dict,
-                             "NoneType": type(None)}
-    try:
-        from jax._src.state.indexing import NDIndexer, Slice
-        types["NDIndexer"] = NDIndexer
-        types["Slice"] = Slice
-    except ImportError:  # pragma: no cover - internal layout moved
-        pass
-    return types
+    from jax._src.state.indexing import NDIndexer, Slice
+    return {"tuple": tuple, "list": list, "dict": dict,
+            "NoneType": type(None), "NDIndexer": NDIndexer, "Slice": Slice}
 
 
 _TREEDEF_NODES = _treedef_node_types()
@@ -365,40 +334,20 @@ def encode_value(v: Any) -> Any:
         # Avals appear as params of pallas_call (out_avals, GridMapping's
         # index_map/scratch avals, BlockMapping array/block avals).
         return {"t": "aval", "v": _aval_dict(v)}
-    if isinstance(v, jax.ShapeDtypeStruct):
-        # pallas_call's out_shapes on jax 0.4.x carry these directly.
-        return {"t": "sds", "shape": [int(s) for s in v.shape],
-                "dtype": np.dtype(v.dtype).name}
-    if _pl_core is not None:
-        import dataclasses as _dc
-        for cls_name in ("Blocked", "Element", "Squeezed", "Unblocked"):
-            cls = getattr(_pl_core, cls_name, None)
-            if cls is not None and isinstance(v, cls):
-                # On jax 0.4.x Blocked/Unblocked are plain sentinel
-                # classes, not dataclasses — encode with no fields.
-                fields = _dc.fields(cls) if _dc.is_dataclass(cls) else ()
-                return {"t": "pl_dim", "cls": cls_name,
-                        "v": [encode_value(getattr(v, f.name))
-                              for f in fields]}
-        for cls_name in ("BlockMapping", "GridMapping"):
-            cls = getattr(_pl_core, cls_name, None)
-            if cls is not None and isinstance(v, cls):
-                return {"t": "pl_" + cls_name.lower(),
-                        "v": {f.name: encode_value(getattr(v, f.name))
-                              for f in _dc.fields(cls)}}
-        cls = getattr(_pl_core, "NameAndSrcInfo", None)
-        if cls is not None and isinstance(v, cls):
-            # pallas_call's `name` param on jax 0.4.3x is this two-field
-            # frozen dataclass rather than a plain string.
-            return {"t": "pl_namesrc", "name": v.name, "src": v.src_info}
-        try:  # not present on jax 0.4.x (params use plain dicts there)
-            from jax._src.frozen_dict import FrozenDict as _FrozenDict
-        except ImportError:
-            _FrozenDict = None
-        if _FrozenDict is not None and isinstance(v, _FrozenDict):
-            return {"t": "pl_frozendict",
-                    "v": [[encode_value(k), encode_value(x)]
-                          for k, x in dict(v).items()]}
+    for cls in (_pl_core.Blocked, _pl_core.Element, _pl_core.Squeezed):
+        if isinstance(v, cls):
+            return {"t": "pl_dim", "cls": cls.__name__,
+                    "v": [encode_value(getattr(v, f.name))
+                          for f in _dc.fields(cls)]}
+    for cls in (_pl_core.BlockMapping, _pl_core.GridMapping):
+        if isinstance(v, cls):
+            return {"t": "pl_" + cls.__name__.lower(),
+                    "v": {f.name: encode_value(getattr(v, f.name))
+                          for f in _dc.fields(cls)}}
+    if isinstance(v, _FrozenDict):
+        return {"t": "pl_frozendict",
+                "v": [[encode_value(k), encode_value(x)]
+                      for k, x in dict(v).items()]}
     raise TypeError(
         f"cannot serialize param value of type {type(v).__name__}: {v!r}")
 
@@ -443,10 +392,7 @@ def decode_value(v: Any) -> Any:
         from jax.sharding import Mesh
         type_names = v.get("axis_types") or []
         if type_names:
-            try:
-                from jax._src.mesh import AxisType
-            except ImportError:  # jax 0.4.x spells it AxisTypes
-                from jax._src.mesh import AxisTypes as AxisType
+            from jax.sharding import AxisType
             types = tuple(AxisType[n] for n in type_names)
         else:
             types = None
@@ -484,21 +430,13 @@ def decode_value(v: Any) -> Any:
     if t == "pl_dim":
         cls = getattr(_pl_core, v["cls"])
         return cls(*[decode_value(x) for x in v["v"]])
-    if t == "sds":
-        return jax.ShapeDtypeStruct(tuple(v["shape"]), np.dtype(v["dtype"]))
-    if t == "pl_namesrc":
-        return _pl_core.NameAndSrcInfo(v["name"], v["src"])
     if t in ("pl_blockmapping", "pl_gridmapping"):
         cls = (_pl_core.BlockMapping if t == "pl_blockmapping"
                else _pl_core.GridMapping)
         return cls(**{k: decode_value(x) for k, x in v["v"].items()})
     if t == "pl_frozendict":
-        items = {decode_value(k): decode_value(x) for k, x in v["v"]}
-        try:
-            from jax._src.frozen_dict import FrozenDict as _FrozenDict
-        except ImportError:  # jax 0.4.x: plain dict is what params held
-            return items
-        return _FrozenDict(items)
+        return _FrozenDict(
+            {decode_value(k): decode_value(x) for k, x in v["v"]})
     raise TypeError(f"unknown tag {t}")
 
 
@@ -507,12 +445,11 @@ def decode_value(v: Any) -> Any:
 # --------------------------------------------------------------------------
 
 def _aval_dict(aval) -> dict:
-    if type(aval).__name__ in ("AbstractRef", "AbstractMemoryRef"):
+    if type(aval).__name__ == "AbstractRef":
         # Pallas/state Ref avals (kernel operands, scratch): inner aval +
         # memory space. The memory space is a pallas MemorySpace enum (or
-        # None = default), encoded by name. jax 0.4.x keeps memory_space
-        # on the pallas subclass AbstractMemoryRef rather than the base.
-        ms = getattr(aval, "memory_space", None)
+        # None = default), encoded by name.
+        ms = aval.memory_space
         return {"ref": _aval_dict(aval.inner_aval),
                 "memory_space": None if ms is None else encode_value(ms)}
     if jax.dtypes.issubdtype(aval.dtype, jax.dtypes.extended):
@@ -547,13 +484,7 @@ def _make_aval(d: dict):
         from jax._src.state.types import AbstractRef
         ms = d.get("memory_space")
         ms = None if ms is None else decode_value(ms)
-        try:
-            return AbstractRef(_make_aval(d["ref"]), ms)
-        except TypeError:
-            # jax 0.4.x: base AbstractRef takes only inner_aval; the
-            # memory_space slot lives on the pallas subclass.
-            from jax._src.pallas.core import AbstractMemoryRef
-            return AbstractMemoryRef(_make_aval(d["ref"]), ms)
+        return AbstractRef(_make_aval(d["ref"]), ms)
     if d["dtype"] == "float0":
         return _core.ShapedArray(tuple(d["shape"]), jax.dtypes.float0)
     kw = {}
@@ -617,7 +548,7 @@ def _decode_jaxpr_struct(d: dict):
     def dec_var(a):
         i = a["id"]
         if i not in env:
-            env[i] = fresh_var(_make_aval(a["aval"]))
+            env[i] = jexcore.Var(_make_aval(a["aval"]))
         return env[i]
 
     def dec_atom(a):

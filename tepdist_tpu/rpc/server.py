@@ -30,6 +30,7 @@ import numpy as np
 
 import jax
 
+from tepdist_tpu.core.compile_cache import configure_compile_cache
 from tepdist_tpu.core.mesh import MeshTopology
 from tepdist_tpu.core.service_env import ServiceEnv
 from tepdist_tpu.rpc import protocol
@@ -1525,6 +1526,7 @@ class TepdistServicer:
             "task_index": self.task_index,
             "n_devices": len(self.devices),
             "platform": self.devices[0].platform,
+            "device_kind": self.devices[0].device_kind,
             "global_step": self.global_step,
             # Master re-adoption probe (ISSUE 20): a restarted master
             # reconciles its WAL state against the plan generation the
@@ -2018,6 +2020,7 @@ def main() -> None:
         log.info("jax.distributed: process %d/%d, %d global / %d local devices",
                  args.task_index, args.num_processes,
                  len(jax.devices()), len(jax.local_devices()))
+    log.info("compile cache: %s", configure_compile_cache())
     server, _, bound = create_server(args.port, task_index=args.task_index)
     server.start()
     print(f"tepdist server listening on {bound}", flush=True)

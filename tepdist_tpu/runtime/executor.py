@@ -336,7 +336,8 @@ class PipelineExecutable:
                    for a, sh in zip(in_avals, in_shs)]
             return jfn.lower(*sds).compile()
         except Exception as e:  # noqa: BLE001 — keep the jit fallback path
-            log.info("AOT compile fell back to jit for stage %d: %s", s, e)
+            log.warning("AOT compile fell back to jit for stage %d: %r",
+                        s, e)
             return jax.jit(fn)
 
     def _compile_payloads(self) -> None:

@@ -121,6 +121,14 @@ class _SpmdTrainingPlan(TrainingPlan):
                      (time.perf_counter() - t0) * 1e3)
         return loss
 
+    def compiled_step_text(self) -> str:
+        """Optimized HLO of the step ``step()`` runs (same jitted fn, same
+        donation) — what a caller greps for a kernel (``tpu_custom_call``)
+        or a collective."""
+        args = [jax.ShapeDtypeStruct(v.aval.shape, v.aval.dtype)
+                for v in self._plan.graph.invars]
+        return self._step_fn.lower(*args).compile().as_text()
+
     def variables(self):
         return jax.tree_util.tree_unflatten(
             self._state_tree, [jax.device_get(v) for v in self._state])
@@ -381,11 +389,22 @@ def plan_training(
         step_fn, topology, params, opt_state, *example_batch,
         annotations=annotations, mode=mode, state_alias=state_alias,
         var_mem_limit=var_mem_limit, zero_invars=zero_invars)
-    # Winner-only lowering post-check (NOTES_NEXT gap #2): the search loop
-    # cannot afford a compile per candidate, but the CHOSEN plan compiles
-    # anyway — lowering_diagnostics uses the same state-donating jit
-    # _SpmdTrainingPlan steps with, so the diagnostic compile is cached
-    # and the first real step pays nothing extra.
+    if (len(devices) > 1 and not plan.sharding_plan.constraints
+            and not any(ax for spec in plan.sharding_plan.in_specs
+                        for ax in spec)
+            and not any(n.prim == "shard_map" for n in plan.graph.nodes)):
+        # The planner treats lax.scan as opaque, so a gradient-accumulation
+        # step (num_micro_batches > 1) comes out with nothing sharded.
+        log.warning(
+            "the SPMD plan over %d devices shards nothing: every device "
+            "runs the whole step and nothing crosses chips "
+            "(num_micro_batches=%d)", len(devices), num_micro_batches)
+    # Winner-only lowering post-check: the search loop cannot afford a
+    # compile per candidate, but the CHOSEN plan compiles anyway —
+    # lowering_diagnostics uses the same state-donating jit
+    # _SpmdTrainingPlan steps with. The first real step compiles that jit
+    # again through the call path; where the persistent compile cache is
+    # on (core/compile_cache.py) it reads this compile back.
     if explored_winner is not None and env.lowering_postcheck:
         from tepdist_tpu.telemetry import metrics
         try:

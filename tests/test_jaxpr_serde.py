@@ -350,10 +350,6 @@ def test_pallas_flash_gpt2_train_step_round_trip():
                                    rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.xfail(
-    reason="jax 0.4.x shard_map eager bind hashes pallas_call params "
-    "(dict-valued on this version → TypeError: unhashable type)",
-    strict=False, raises=Exception)
 def test_ulysses_flash_inner_round_trip(devices):
     """Sequence parallelism COMPOSED with the pallas kernel crosses the
     wire: a shard_map body containing custom_vjp'd pallas_call eqns.

@@ -136,3 +136,20 @@ def test_stage_flops_reporting():
     flops = prog.stage_flops()
     assert len(flops) == 2 and all(f > 0 for f in flops)
     assert prog.decomp.cross_stage_bytes() > 0
+
+
+def test_plan_pipeline_refuses_pallas_kernel():
+    """Stage programs take jax.vjp of the staged forward; a flash model's
+    custom_vjp is inlined away by tracing, so the proposal must be refused
+    up front (exploration prunes it) instead of failing inside jax at the
+    first step."""
+    import dataclasses
+
+    from tepdist_tpu.models import gpt2
+
+    cfg = dataclasses.replace(gpt2.CONFIGS["test"], attn="flash")
+    params = gpt2.init_params(cfg, jax.random.PRNGKey(0))
+    tokens = gpt2.fake_batch(cfg, 8, 32)
+    with pytest.raises(NotImplementedError, match="pallas kernel"):
+        plan_pipeline(lambda p, t: gpt2.loss_fn(p, t, cfg), 2, 2, params,
+                      tokens)

@@ -25,10 +25,9 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tepdist_tpu.core.jax_compat import shard_map
 from tepdist_tpu.core.mesh import MeshTopology
 from tepdist_tpu.parallel.performance_utils import (
     OPT_STATE_FACTOR,
@@ -263,7 +262,10 @@ def _run_zero_shard_map(comm_dtype="", steps=8, micro=4, dp=2):
     step = jax.jit(shard_map(
         inner, mesh=mesh,
         in_specs=(P(), opt_specs, P("data"), P("data")),
-        out_specs=(P(), P(), opt_specs)))
+        out_specs=(P(), P(), opt_specs),
+        # The all-gathered params ARE replicated, but lax.all_gather's
+        # result is typed varying, so the static check cannot see it.
+        check_vma=False))
     losses = []
     for _ in range(steps):
         loss, params, opt_state = step(params, opt_state, x, y)
