@@ -21,7 +21,8 @@ import time
 
 import numpy as np
 
-from benchmark.lib import openloop
+from benchmark.lib import openloop, recorder
+from benchmark.lib.cells import BenchError
 
 
 def _pow2_at_least(n: int) -> int:
@@ -269,8 +270,11 @@ class _TracedPart:
         self.state = "closed"
 
 
-def run(cell, builder, devices, seed: int, seconds: float, trace: bool,
+def run(cell, builder, devices, seed: int, seconds: float, trace: int,
         host, compiles) -> dict:
+    if trace == 2:
+        raise BenchError("the serving driver has no --trace 2 yet: it "
+                         "returns with its cell")
     t = cell.traffic
     drain_s = float(t["drain_seconds"])
     params, system = build(cell, builder, seed, host)
@@ -284,6 +288,9 @@ def run(cell, builder, devices, seed: int, seconds: float, trace: bool,
     del params
     system.decode_calls = system.decode_rows = 0
 
+    # The window runs with the program's spans and the profiler off; the
+    # traced part switches both on through the program's control.
+    program_compiles = recorder.off_for_window()
     mark = compiles.n
     if trace:
         ramp_s, span_s = (float(t["trace_ramp_seconds"]),
@@ -313,7 +320,8 @@ def run(cell, builder, devices, seed: int, seconds: float, trace: bool,
         "end_to_end": {k: s[k] for k in
                        ("serve_tokens_per_s", "ttft_p95_ms", "itl_p95_ms")},
         "host": {"decode_calls": calls, "decode_rows": rows,
-                 "compiles_in_window": compiled_inside, "summary": s},
+                 "compiles_in_window": compiled_inside, "summary": s,
+                 "program_compiles": program_compiles},
     }
 
 

@@ -17,6 +17,15 @@ the two states are compared. So gradient accumulation, the kernels, remat,
 the loss, the optimizer, the lowering and, on several chips, the sharding and
 the collectives are all on the path, and none of the reference's time is in
 ``setup_s``.
+
+Trace modes. 0: no profiler. 1: the traffic's ``trace_steps`` steps run
+traced in the measured window's place. 2: mode 0 to the end of the measured
+window, whose numbers are taken; then, before the plan's state is released,
+``trace_steps`` more steps of the same traffic run traced (seeds continuing
+from the window's) and count in neither ``attempted`` nor the rate. In every
+mode the program's span recorder, on since process start, is switched off as
+the window opens (``lib/recorder.py``); a traced window switches it on
+through the program's control (``lib/tracing.py``).
 """
 
 from __future__ import annotations
@@ -27,7 +36,7 @@ import time
 import jax
 import numpy as np
 
-from benchmark.lib import device
+from benchmark.lib import device, recorder
 from benchmark.lib.cells import BenchError
 
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
@@ -149,8 +158,9 @@ def _plan(cell, builder, devices, params, example):
         num_micro_batches=t.get("num_micro_batches"))
 
 
-def run(cell, builder, devices, seed: int, seconds: float, trace: bool,
+def run(cell, builder, devices, seed: int, seconds: float, trace: int,
         host, compiles) -> dict:
+    from tepdist_tpu import telemetry
     t = cell.traffic
     batch, seq = int(t["batch"]), int(t["seq"])
 
@@ -196,17 +206,24 @@ def run(cell, builder, devices, seed: int, seconds: float, trace: bool,
         with host.span("step"):
             return plan.step(tokens)
 
-    window_losses = []
-    mark = compiles.n
-    if trace:
+    def traced_steps(first: int) -> tuple:
         from benchmark.lib import tracing
         with tracing.traced_window(cell.root, cell.name, host) as path:
-            t_open = time.perf_counter()
-            for i in range(int(t["trace_steps"])):
-                window_losses.append(one_step(i))
-            t_last = time.perf_counter()
+            t0 = time.perf_counter()
+            losses = [one_step(first + i)
+                      for i in range(int(t["trace_steps"]))]
+            t1 = time.perf_counter()
         cell.facts["trace_path"] = path
+        return losses, t0, t1
+
+    # What the program compiled during set-up, by its own counter; then its
+    # recorder goes off: the window runs with spans and profiler off.
+    program_compiles = recorder.off_for_window()
+    mark = compiles.n
+    if trace == 1:
+        window_losses, t_open, t_last = traced_steps(0)
     else:
+        window_losses = []
         t_open = time.perf_counter()
         host.counters["setup_s"] = t_open - host.t0
         i = 0
@@ -227,6 +244,18 @@ def run(cell, builder, devices, seed: int, seconds: float, trace: bool,
           f"compiles inside the window: {compiled_inside} (limit 0)",
           flush=True)
 
+    if trace == 2:
+        traced_losses, t0, t1 = traced_steps(steps)
+        traced_rate = len(traced_losses) * batch * seq / (t1 - t0) \
+            / len(devices)
+        print(f"traced after the window: {len(traced_losses)} steps in "
+              f"{t1 - t0:.4f} s, {traced_rate:.1f} tokens/s/chip, "
+              f"{100 * (1 - traced_rate / rate):.4f}% under the window's "
+              f"rate (what tracing costs when on), losses "
+              f"{traced_losses[0]:.4f} .. {traced_losses[-1]:.4f}",
+              flush=True)
+    program_spans = telemetry.tracer().snapshot()
+
     release(plan)
     del plan
     check = check_step(cell, builder, seed, losses[0], got, host)
@@ -239,7 +268,9 @@ def run(cell, builder, devices, seed: int, seconds: float, trace: bool,
         "end_to_end": {"train_tokens_per_s_chip": rate},
         "program_peak_bytes": program_peak,
         "host": {"steps": steps, "elapsed_s": elapsed,
-                 "compiles_in_window": compiled_inside},
+                 "compiles_in_window": compiled_inside,
+                 "program_spans": program_spans,
+                 "program_compiles": program_compiles},
     }
 
 

@@ -1,5 +1,9 @@
 """A ``jax.profiler`` trace of a short window, reduced with
-``trace_reduce.py``. Only a ``--trace 1`` run ever imports this."""
+``trace_reduce.py``. Only a traced run ever imports this, and a
+``--trace 2`` run only once its measured window has closed. The trace is
+started and stopped through the program's own control
+(``tepdist_tpu.telemetry.start_device_trace``), which also switches the
+program's span recorder on and lays its spans on the profiler's clock."""
 
 from __future__ import annotations
 
@@ -18,22 +22,25 @@ class WindowTrace:
         self._span = None
 
     def start(self) -> None:
-        import jax
-        shutil.rmtree(self.path, ignore_errors=True)
+        from tepdist_tpu import telemetry
+        discard(self.path)
         os.makedirs(self.path, exist_ok=True)
-        jax.profiler.start_trace(self.path)
+        # The profiler's start, the process's first included, is over before
+        # ``bench:window`` opens and before a driver takes its clock, so what
+        # it costs falls into no number (PERF.md section 6, PR 24).
+        telemetry.start_device_trace(self.path)
         self.host.annotate = True
         self._span = self.host.span("window")
         self._span.__enter__()
 
     def stop(self) -> None:
-        import jax
+        from tepdist_tpu import telemetry
         if self._span is None:
             return
         self._span.__exit__(None, None, None)
         self._span = None
         self.host.annotate = False
-        jax.profiler.stop_trace()
+        telemetry.stop_device_trace()
 
 
 @contextlib.contextmanager
@@ -44,6 +51,10 @@ def traced_window(root: str, cell_name: str, host):
         yield trace.path
     finally:
         trace.stop()
+
+
+def discard(path: str) -> None:
+    shutil.rmtree(path, ignore_errors=True)
 
 
 def reduce_trace(path: str):

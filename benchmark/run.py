@@ -1,16 +1,26 @@
 """One command, one cell, one run.
 
-    python benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
+    python benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1|2>
 
 A new process that owns the cell's chips. It fails (non-zero exit, no result
 line) when JAX offers no TPU or fewer chips than the cell asks for. It makes
 weights and traffic from ``--seed``, warms up the cell's own shapes, measures
 for ``--seconds`` and prints, as the last line of its standard output, one
 JSON object with ``correct``, ``attempted``, ``failed``, ``metrics`` and
-``device`` (and, with ``--trace 1``, ``breakdown``). With ``--trace 0`` the
-metrics are the cell's end-to-end metrics and the profiler is never started;
-with ``--trace 1`` a short window runs under ``jax.profiler`` and the metrics
-are the cell's per-layer metrics.
+``device`` (and, traced, ``breakdown``). With ``--trace 0`` the metrics are
+the cell's end-to-end metrics and the profiler is never started; with
+``--trace 1`` a short window runs under ``jax.profiler`` in the measured
+window's place and the metrics are the cell's per-layer metrics. ``--trace 2``
+is a ``--trace 0`` run, the same process up to the moment the measured window
+closes, followed by a short traced window of the same traffic: its line
+holds the end-to-end metrics of the measured window and the per-layer
+metrics of the traced one side by side.
+
+In every mode the program's span recorder (``tepdist_tpu.telemetry``) is on
+from process start, so set-up leaves its spans, and every driver switches it
+off as its measured window opens (``lib/recorder.py`` holds both switches);
+a traced window switches it on again through the program's own control,
+which also starts the profiler.
 
 Which configuration, traffic, driver loop and readers a cell uses is data:
 ``BENCHMARK.json`` and the files it names under ``benchmark/``.
@@ -37,15 +47,15 @@ def main(argv=None) -> int:
     parser.add_argument("--workload", required=True)
     parser.add_argument("--seed", type=int, required=True)
     parser.add_argument("--seconds", type=float, required=True)
-    parser.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    parser.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     args = parser.parse_args(argv)
 
-    from benchmark.lib import cells, device
+    from benchmark.lib import cells, device, recorder
     from benchmark.lib.host import CompileWatch, HostLog
 
     try:
         cell = cells.load_cell(args.workload, ROOT)
-        import tepdist_tpu  # noqa: F401 — the system under test
+        recorder.on()               # imports the system under test
     except (cells.BenchError, ImportError) as e:
         device.fail(str(e))
     devices = device.own_chips(cell.chips)
@@ -64,7 +74,7 @@ def main(argv=None) -> int:
     builder = cells.builder_for(cell)
     driver = cells.driver_for(cell)
     out = driver.run(cell, builder, devices, args.seed, args.seconds,
-                     bool(args.trace), host, compiles)
+                     args.trace, host, compiles)
 
     record = device.device_record(devices, out.get("program_peak_bytes", 0))
     end_to_end = dict(out["end_to_end"])
@@ -86,6 +96,8 @@ def main(argv=None) -> int:
     result = {"correct": bool(out["correct"]),
               "attempted": int(out["attempted"]),
               "failed": int(out["failed"])}
+    measured = {name: {"value": float(end_to_end[name]), "unit": m["unit"]}
+                for name, m in wanted.items()}
     if args.trace:
         from benchmark import trace_reduce
         from benchmark.lib import tracing
@@ -101,10 +113,11 @@ def main(argv=None) -> int:
         record["window_s"] = summary.window_s
         result["metrics"] = metrics
         result["breakdown"] = trace_reduce.breakdown(summary)
+        if args.trace == 2:
+            result["metrics"] = {**measured, **metrics}
+            tracing.discard(cell.facts["trace_path"])
     else:
-        result["metrics"] = {
-            name: {"value": float(end_to_end[name]), "unit": m["unit"]}
-            for name, m in wanted.items()}
+        result["metrics"] = measured
     result["device"] = record
     print(json.dumps(result), flush=True)
     return 0

@@ -91,25 +91,16 @@ def _cache_entries(cache_dir: str) -> int:
     return len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
 
 
-class _CacheTraffic:
-    """This process's persistent-compile-cache lookups, hits and writes
-    (jax writes an entry only for a compile of a second or more)."""
+def _cache_traffic() -> dict:
+    """This process's persistent-compile-cache lookups, hits and writes, by
+    the program's own compile counter (``telemetry.compile_stats``; jax
+    writes an entry only for a compile of a second or more)."""
+    from tepdist_tpu.telemetry import compile_stats
 
-    EVENTS = {
-        "/jax/compilation_cache/compile_requests_use_cache": "cache_requests",
-        "/jax/compilation_cache/cache_hits": "cache_hits",
-        "/jax/compilation_cache/cache_misses": "cache_writes"}
-
-    def __init__(self):
-        self.counts = dict.fromkeys(self.EVENTS.values(), 0)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_event(self, event: str, **_):
-        if event in self.EVENTS:
-            self.counts[self.EVENTS[event]] += 1
-
-    def since(self, before: dict, prefix: str) -> dict:
-        return {prefix + k: v - before[k] for k, v in self.counts.items()}
+    stats = compile_stats()
+    return {"cache_requests": stats["cache_requests"],
+            "cache_hits": stats["cache_hits"],
+            "cache_writes": stats["cache_misses"]}
 
 
 def _own_devices(platform: str) -> list:
@@ -143,8 +134,7 @@ def _take_steps(step_once, phase: str) -> tuple:
     return losses, round(first, 3)
 
 
-def _plan(cfg_name: str, batch: int, seq: int, devices, traffic,
-          **plan_kwargs):
+def _plan(cfg_name: str, batch: int, seq: int, devices, **plan_kwargs):
     """``plan_training`` on the smoke's model: (plan, tokens, record).
     Weights are made anew for each plan — a plan's step donates them.
     ``cache_hit``: the step program's first compile in this process (the
@@ -153,12 +143,13 @@ def _plan(cfg_name: str, batch: int, seq: int, devices, traffic,
     from tepdist_tpu.train import plan_training
 
     cfg, params, tokens, tx = _model(cfg_name, batch, seq)
-    before = dict(traffic.counts)
+    before = _cache_traffic()
     t0 = time.perf_counter()
     tplan = plan_training(lambda p, t: gpt2.loss_fn(p, t, cfg), tx, params,
                           tokens, devices=devices, **plan_kwargs)
     seconds = round(time.perf_counter() - t0, 3)
-    in_plan = traffic.since(before, "plan_")
+    in_plan = {"plan_" + k: v - before[k]
+               for k, v in _cache_traffic().items()}
     return tplan, tokens, {
         "setup_planner_seconds": seconds, **in_plan,
         "cache_hit": in_plan["plan_cache_hits"] > 0
@@ -256,13 +247,12 @@ def phase_b(cfg_name: str = "117M", batch: int = 8, seq: int = 1024,
 
     devices = _own_devices(platform)[:1]
     cache_dir = configure_compile_cache()
-    traffic = _CacheTraffic()
     attached = chip_spec_for_device_kind(devices[0].device_kind).name
     _check(chip_spec().name == attached,
            f"phase B: planner prices a {chip_spec().name!r} chip but the "
            f"attached device is {devices[0].device_kind!r} ({attached!r})")
 
-    tplan, tokens, planned = _plan(cfg_name, batch, seq, devices, traffic)
+    tplan, tokens, planned = _plan(cfg_name, batch, seq, devices)
     text = _compiled_text_with_kernel(tplan, platform, "phase B")
     losses, first = _take_steps(lambda: tplan.step(tokens), "phase B")
     return {
@@ -274,7 +264,7 @@ def phase_b(cfg_name: str = "117M", batch: int = 8, seq: int = 1024,
         "kernel_calls_in_hlo": text.count("tpu_custom_call"),
         "losses": losses, **planned,
         "setup_first_step_seconds": first,
-        "cache_dir": cache_dir, **traffic.counts,
+        "cache_dir": cache_dir, **_cache_traffic(),
         **_native_helpers(),
     }
 
@@ -291,12 +281,11 @@ def phase_four(cfg_name: str = "117M", batch: int = 16, seq: int = 1024,
            f"four-chip phase: {len(devices)} devices attached, wanted "
            f"{n_devices}")
     cache_dir = configure_compile_cache()
-    traffic = _CacheTraffic()
 
     # No gradient accumulation: the SPMD planner cannot see into the GA
     # scan, and a plan with micro batches shards nothing (every chip would
     # run the whole step, which plan_training warns about).
-    tplan, tokens, planned = _plan(cfg_name, batch, seq, devices, traffic,
+    tplan, tokens, planned = _plan(cfg_name, batch, seq, devices,
                                    explore=True, num_micro_batches=1)
     winner = tplan.exploration_report["winner"]
     _check(winner["kind"] == "spmd",
@@ -319,7 +308,7 @@ def phase_four(cfg_name: str = "117M", batch: int = 16, seq: int = 1024,
     del tplan
 
     # Same weights, tokens and steps on one chip.
-    one, tokens, _ = _plan(cfg_name, batch, seq, devices[:1], traffic,
+    one, tokens, _ = _plan(cfg_name, batch, seq, devices[:1],
                            num_micro_batches=1)
     one_losses, _ = _take_steps(lambda: one.step(tokens),
                                 "one-chip comparison")
@@ -335,7 +324,7 @@ def phase_four(cfg_name: str = "117M", batch: int = 16, seq: int = 1024,
         "devices_holding_shards": sorted(holders),
         "losses": losses, "one_chip_losses": one_losses, **planned,
         "setup_first_step_seconds": first,
-        "cache_dir": cache_dir, **traffic.counts,
+        "cache_dir": cache_dir, **_cache_traffic(),
         **_native_helpers(),
     }
 

@@ -165,6 +165,12 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
+def _kernel_name(which: str, causal, scale, heads: int) -> str:
+    """A stable name for each kernel: a device trace and the compiled HLO
+    show it, so forward, dQ and dK/dV are told apart by name."""
+    return f"tepdist_flash_{which}__c{int(causal)}__s{scale!r}__h{heads}"
+
+
 def _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret):
     B, H, T, D = q.shape
     qf = q.reshape(B * H, T, D)
@@ -179,7 +185,7 @@ def _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret):
         # flash call sites in traced graphs (parallel/attention_motif.py)
         # — causal flag, softmax scale and head count ride along for the
         # rewrite (H lets the ulysses lowering un-flatten [B*H, T, D]).
-        name=f"tepdist_flash_fwd__c{int(causal)}__s{scale!r}__h{H}",
+        name=_kernel_name("fwd", causal, scale, H),
         grid=(B * H, T // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
@@ -221,6 +227,7 @@ def _bwd_call(causal, scale, block_q, block_k, interpret, res, do,
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, block_k=block_k, causal=causal,
                           scale=scale, q_block=block_q, seq_len=T),
+        name=_kernel_name("dq", causal, scale, H),
         grid=(BH, T // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
@@ -237,6 +244,7 @@ def _bwd_call(causal, scale, block_q, block_k, interpret, res, do,
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, block_q=block_q, causal=causal,
                           scale=scale, k_block=block_k, seq_len=T),
+        name=_kernel_name("dkv", causal, scale, H),
         grid=(BH, T // block_k),
         in_specs=[
             full_spec,

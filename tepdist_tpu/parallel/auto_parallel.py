@@ -29,6 +29,7 @@ from tepdist_tpu.graph.jaxpr_graph import JaxprGraph, trace_graph
 from tepdist_tpu.parallel.cost_spmd_strategy import CostSpmdStrategy, GraphStrategy
 from tepdist_tpu.parallel.fast_spmd_strategy import FastSpmdStrategy
 from tepdist_tpu.parallel.spmd_transform import ShardingPlan, SpmdTransform
+from tepdist_tpu.telemetry import span
 
 Var = jexcore.Var
 log = logging.getLogger(__name__)
@@ -452,9 +453,34 @@ def auto_parallel(
         mode = "rule" if env.rule_mode else "cost"
     if env.ignore_annotation:
         annotations = None
-    graph, in_tree, out_tree = trace_graph(fn, *example_args, **example_kwargs)
+    with span("plan:trace", cat="planner"):
+        graph, in_tree, out_tree = trace_graph(fn, *example_args,
+                                               **example_kwargs)
     if var_mem_limit is None and env.var_mem_limit > 0:
         var_mem_limit = env.var_mem_limit
+    with span("plan:search", cat="planner"):
+        strategies, zero_split = _search(
+            graph, topology, annotations, mode, state_alias, var_mem_limit,
+            zero_invars, env)
+    with span("plan:lower", cat="planner"):
+        sharding_plan = SpmdTransform(graph, topology).lower(
+            strategies, state_alias=state_alias)
+    return ParallelPlan(
+        graph=graph,
+        topology=topology,
+        strategies=strategies,
+        sharding_plan=sharding_plan,
+        in_tree=in_tree,
+        out_tree=out_tree,
+        mode=mode,
+        zero=bool(zero_split),
+    )
+
+
+def _search(graph, topology, annotations, mode, state_alias, var_mem_limit,
+            zero_invars, env) -> Tuple[List[GraphStrategy], List[int]]:
+    """``auto_parallel``'s strategy search: ``plan_axes`` and the post-passes
+    on its result. Returns (strategies, the ZeRO-split invars)."""
     strategies = plan_axes(graph, topology, annotations, mode,
                            mem_limit_bytes=var_mem_limit)
     if state_alias:
@@ -491,18 +517,7 @@ def auto_parallel(
                                          zero_invars)
         log.info("ZeRO: sharded %d/%d optimizer-state invars over the "
                  "data axis", len(zero_split), len(zero_invars))
-    xform = SpmdTransform(graph, topology)
-    sharding_plan = xform.lower(strategies, state_alias=state_alias)
-    return ParallelPlan(
-        graph=graph,
-        topology=topology,
-        strategies=strategies,
-        sharding_plan=sharding_plan,
-        in_tree=in_tree,
-        out_tree=out_tree,
-        mode=mode,
-        zero=bool(zero_split),
-    )
+    return strategies, zero_split
 
 
 def auto_parallel_explore(

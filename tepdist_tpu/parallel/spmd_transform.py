@@ -270,7 +270,9 @@ class SpmdTransform:
                 skip_ids |= m.member_ids
                 at_pv[m.pv_id] = (axis_name, m)
 
-        def run(*flat_args):
+        # The function's name is the program's in a device trace
+        # (``jit_tepdist_train_step`` on the ``XLA Modules`` line).
+        def tepdist_train_step(*flat_args):
             env: Dict[Var, Any] = {}
 
             def read(a):
@@ -314,7 +316,7 @@ class SpmdTransform:
             for s in plan.out_specs
         )
         return jax.jit(
-            run,
+            tepdist_train_step,
             in_shardings=in_shardings,
             out_shardings=out_shardings,
             donate_argnums=tuple(donate_invars),

@@ -10,7 +10,11 @@ Usage::
     metrics().counter("steps").inc()
 
 Spans are gated by ``TEPDIST_TRACE`` (or ``DEBUG``) and cost one branch
-when disabled; metrics are always on. ``GetTelemetry`` (rpc/protocol.py)
+when disabled; metrics are always on, the compile counter
+(``compile_stats()``, telemetry/compiles.py) among them.
+``start_device_trace(log_dir)`` / ``stop_device_trace()`` run
+``jax.profiler`` with every span also on its clock as ``tepdist:<name>``.
+``GetTelemetry`` (rpc/protocol.py)
 pulls both from every worker; ``session.dump_trace()`` merges them into
 one Perfetto-loadable timeline.
 """
@@ -26,6 +30,8 @@ from tepdist_tpu.telemetry.trace import (  # noqa: F401
     configure,
     enabled,
     span,
+    start_device_trace,
+    stop_device_trace,
     tracer,
 )
 from tepdist_tpu.telemetry.export import (  # noqa: F401
@@ -36,6 +42,8 @@ from tepdist_tpu.telemetry.export import (  # noqa: F401
     to_prometheus,
     write_trace,
 )
+from tepdist_tpu.telemetry import compiles
+from tepdist_tpu.telemetry.compiles import compile_stats  # noqa: F401
 from tepdist_tpu.telemetry import calibrate  # noqa: F401
 from tepdist_tpu.telemetry import fidelity  # noqa: F401
 from tepdist_tpu.telemetry import flight  # noqa: F401
@@ -48,3 +56,5 @@ from tepdist_tpu.telemetry.watchtower import (  # noqa: F401
     Watchtower,
     active_alerts,
 )
+
+compiles.install()

@@ -27,6 +27,13 @@ Design contract:
   thread): old spans fall off the front and are counted in ``dropped`` —
   a lossy merged trace is misleading (missing tasks look like idle
   time), so exporters surface this count and warn.
+* DEVICE TRACE (``start_device_trace`` / ``stop_device_trace``): the
+  recorder stamps ``time.monotonic_ns``, the device trace is
+  ``jax.profiler``'s. Between start and stop every span is therefore
+  also a ``jax.profiler.TraceAnnotation`` named ``tepdist:<span name>``,
+  so it lies on the device trace's clock and an idle gap of the device
+  can be laid under the span that covers it. The annotation is made only
+  while such a trace runs; off, the paths above are untouched.
 * Gating: ``TEPDIST_TRACE`` in core/service_env.py. ``DEBUG`` mode
   implies tracing — the debug log lines in executor.py / worker_plan.py /
   rpc/server.py read their durations from spans, so spans are THE timing
@@ -139,6 +146,30 @@ class Span:
         return (time.monotonic_ns() - self._t0) / 1e6
 
 
+DEVICE_TRACE_PREFIX = "tepdist:"
+
+
+class _AnnotatedSpan(Span):
+    """A span that is also a ``jax.profiler.TraceAnnotation``. Created
+    only while a device trace runs (``start_device_trace``)."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, tracer: "Tracer", name: str, cat: str,
+                 attrs: Dict[str, Any], annotation):
+        super().__init__(tracer, name, cat, attrs)
+        self._ann = annotation(DEVICE_TRACE_PREFIX + name, **attrs)
+
+    def __enter__(self) -> "Span":
+        self._ann.__enter__()
+        return super().__enter__()
+
+    def __exit__(self, *exc) -> bool:
+        super().__exit__(*exc)
+        self._ann.__exit__(*exc)
+        return False
+
+
 class _Ring:
     """One recording thread's span ring (``cap + 1`` physical slots, see
     the ledger's _Ring for the torn-read argument). The thread name is
@@ -219,6 +250,15 @@ class Tracer:
     def _park(self, ring: _Ring) -> None:
         with self._reg_lock:
             self._free.append(ring)
+
+    def record_finished(self, name: str, cat: str, dur_ns: int,
+                        **attrs) -> None:
+        """Record a span that ends now and lasted ``dur_ns``: for work
+        that reports its duration only when it is over (JAX's compile
+        events, telemetry/compiles.py)."""
+        sp = Span(self, name, cat, attrs)
+        sp._t0 = time.monotonic_ns() - int(dur_ns)
+        sp.__exit__()
 
     def snapshot(self, clear: bool = False) -> List[Dict[str, Any]]:
         """Build the export-ready span dicts (optionally draining the
@@ -382,6 +422,12 @@ def enabled() -> bool:
     return tracer().enabled
 
 
+# ``jax.profiler.TraceAnnotation`` while a device trace started here runs,
+# else None; with it, whether the recorder was on before the start.
+_ANNOTATION = None
+_ENABLED_BEFORE = False
+
+
 def span(name: str, cat: str = "misc", **attrs):
     """Start a span. Returns the shared no-op singleton when disabled."""
     t = _TRACER
@@ -389,7 +435,42 @@ def span(name: str, cat: str = "misc", **attrs):
         t = _init_from_env()
     if not t.enabled:
         return _NULL_SPAN
+    ann = _ANNOTATION
+    if ann is not None:
+        return _AnnotatedSpan(t, name, cat, attrs, ann)
     core = t._core
     if core is not None:
         return core.span(name, cat, attrs)
     return Span(t, name, cat, attrs)
+
+
+def start_device_trace(log_dir: str) -> None:
+    """Start ``jax.profiler`` into ``log_dir`` and switch the span recorder
+    on. Until ``stop_device_trace`` every span is also a profiler
+    annotation named ``tepdist:<span name>`` (attributes as its stats), on
+    the device trace's clock. For the process that holds the chip; may be
+    called any number of times, one trace at a time."""
+    global _ANNOTATION, _ENABLED_BEFORE
+    import jax
+
+    if _ANNOTATION is not None:
+        raise RuntimeError("a device trace is already running")
+    t = tracer()
+    jax.profiler.start_trace(log_dir)
+    _ENABLED_BEFORE = t.enabled
+    _ANNOTATION = jax.profiler.TraceAnnotation
+    t.enabled = True
+
+
+def stop_device_trace() -> None:
+    """End the trace ``start_device_trace`` began (the profiler writes its
+    ``.xplane.pb`` under ``log_dir``) and put the recorder back as it was."""
+    global _ANNOTATION
+    import jax
+
+    if _ANNOTATION is None:
+        raise RuntimeError("no device trace is running")
+    _ANNOTATION = None
+    tracer().enabled = _ENABLED_BEFORE
+    jax.profiler.stop_trace()
+

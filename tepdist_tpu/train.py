@@ -17,14 +17,15 @@ across steps (the server-held-variables model, in-process).
 
 from __future__ import annotations
 
+import functools
 import logging
-import time
 from typing import Any, Callable, Dict, Optional, Sequence
 
 import jax
 
 from tepdist_tpu.core.mesh import MeshTopology
 from tepdist_tpu.core.service_env import ServiceEnv
+from tepdist_tpu.telemetry import metrics, span
 
 log = logging.getLogger(__name__)
 
@@ -85,11 +86,13 @@ class TrainingPlan:
 class _SpmdTrainingPlan(TrainingPlan):
     def __init__(self, plan, params, opt_state, n_batch_leaves, devices):
         self._plan = plan
+        self._steps = 0     # the ``step=<n>`` every span of one step carries
         # The plan owns its state arrays and threads outputs back as the
         # next step's inputs, so the aliased state buffers are donated.
-        self._step_fn = plan.executable(devices=devices,
-                                        donate_invars=plan.state_donation())
-        self._shardings = plan.input_shardings(devices)
+        with span("plan:lower", cat="planner"):
+            self._step_fn = plan.executable(
+                devices=devices, donate_invars=plan.state_donation())
+            self._shardings = plan.input_shardings(devices)
         self._state_tree = jax.tree_util.tree_structure((params, opt_state))
         flat_state = jax.tree_util.tree_leaves((params, opt_state))
         self._n_state = len(flat_state)
@@ -99,8 +102,9 @@ class _SpmdTrainingPlan(TrainingPlan):
         # with compatible inputs. The caller's params/opt_state arrays are
         # therefore moved-from after the first step; read state back via
         # ``variables()``. DISABLE_BUFFER_ALIAS=1 opts out.
-        self._state = [jax.device_put(v, s) for v, s in
-                       zip(flat_state, self._shardings[:self._n_state])]
+        with span("plan:place", cat="planner"):
+            self._state = [jax.device_put(v, s) for v, s in
+                           zip(flat_state, self._shardings[:self._n_state])]
         self._batch_shardings = self._shardings[self._n_state:]
         self.parallel_plan = plan
         # ZeRO winners keep optimizer-state arrays device-sharded; save
@@ -108,17 +112,22 @@ class _SpmdTrainingPlan(TrainingPlan):
         self._ckpt_shard_addressable = bool(getattr(plan, "zero", False))
 
     def step(self, *batch) -> float:
-        env = ServiceEnv.get()
-        t0 = time.perf_counter()
-        flat_batch = jax.tree_util.tree_leaves(batch)
-        flat_batch = [jax.device_put(v, s) for v, s in
-                      zip(flat_batch, self._batch_shardings)]
-        outs = self._step_fn(*self._state, *flat_batch)
-        self._state = list(outs[1:1 + self._n_state])
-        loss = float(jax.device_get(outs[0]))
-        if env.debug:
-            log.info("[ExecutePlan Duration] %.3f ms",
-                     (time.perf_counter() - t0) * 1e3)
+        n = self._steps
+        with span("step", cat="runtime", step=n) as sp:
+            with span("step:h2d", cat="runtime", step=n):
+                flat_batch = jax.tree_util.tree_leaves(batch)
+                flat_batch = [jax.device_put(v, s) for v, s in
+                              zip(flat_batch, self._batch_shardings)]
+            # Enqueue only (on the first call also the compile or its
+            # cache read); the device works on while this returns.
+            with span("step:dispatch", cat="runtime", step=n):
+                outs = self._step_fn(*self._state, *flat_batch)
+            self._state = list(outs[1:1 + self._n_state])
+            with span("step:wait", cat="runtime", step=n):
+                loss = float(jax.device_get(outs[0]))
+            if ServiceEnv.get().debug:
+                log.info("[ExecutePlan Duration] %.3f ms", sp.elapsed_ms)
+        self._steps = n + 1
         return loss
 
     def compiled_step_text(self) -> str:
@@ -190,6 +199,18 @@ def explore_parallelism(
                    entry_point=entry_point)
 
 
+def _in_span(name: str, cat: str):
+    """Run the whole of a function under one span."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def under_span(*args, **kwargs):
+            with span(name, cat=cat):
+                return fn(*args, **kwargs)
+        return under_span
+    return wrap
+
+
+@_in_span("plan", cat="planner")
 def plan_training(
     loss_fn: Callable,
     optimizer,
@@ -343,9 +364,11 @@ def plan_training(
         build_ga_step,
     )
 
-    opt_state = optimizer.init(params)
+    with span("plan:place", cat="planner"):
+        opt_state = optimizer.init(params)
     if num_micro_batches is None:
-        graph, _, _ = trace_graph(grad_fn, params, *example_batch)
+        with span("plan:trace", cat="planner"):
+            graph, _, _ = trace_graph(grad_fn, params, *example_batch)
         n_param_leaves = len(jax.tree_util.tree_leaves(params))
         batch0 = jax.tree_util.tree_leaves(example_batch)[0]
         res = analyze_sync_free(
@@ -406,9 +429,9 @@ def plan_training(
     # again through the call path; where the persistent compile cache is
     # on (core/compile_cache.py) it reads this compile back.
     if explored_winner is not None and env.lowering_postcheck:
-        from tepdist_tpu.telemetry import metrics
         try:
-            remats = plan.lowering_diagnostics(devices=devices)
+            with span("plan:postcheck", cat="planner"):
+                remats = plan.lowering_diagnostics(devices=devices)
         except Exception as e:  # noqa: BLE001 — diagnostics only
             log.warning("lowering post-check failed: %r", e)
         else:

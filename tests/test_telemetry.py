@@ -53,17 +53,20 @@ def test_disabled_span_is_the_shared_singleton(private_tracer):
 
 def test_disabled_span_overhead_is_noop_sized(private_tracer):
     """Micro-benchmark (tier-1-fast): the disabled path must cost no more
-    than a function call + branch. The robust assertion is relative —
-    disabled must be far cheaper than the recording path — plus a very
-    generous absolute ceiling so a real regression (e.g. allocating a Span
-    before checking `enabled`) fails even on a loaded 1-core host."""
+    than a function call + branch. What is asserted does not depend on the
+    host's load: off, every call hands back the shared singleton and
+    nothing is recorded; plus a very generous absolute ceiling so a real
+    regression (e.g. allocating a Span before checking `enabled`) fails
+    even on a loaded 1-core host. (A race against the C recording path,
+    ``disabled_ns < enabled_ns``, lost under six xdist workers.)"""
     n = 10000
 
     def timed_ns():
         t0 = time.perf_counter_ns()
         for _ in range(n):
-            with trace_mod.span("bench", cat="bench"):
+            with trace_mod.span("bench", cat="bench") as sp:
                 pass
+        assert (sp is _NULL_SPAN) is (not private_tracer.enabled)
         return (time.perf_counter_ns() - t0) / n
 
     private_tracer.enabled = False
@@ -71,10 +74,9 @@ def test_disabled_span_overhead_is_noop_sized(private_tracer):
     assert len(private_tracer) == 0
 
     private_tracer.enabled = True
-    enabled_ns = min(timed_ns() for _ in range(3))
+    timed_ns()
     assert len(private_tracer) > 0
 
-    assert disabled_ns < enabled_ns, (disabled_ns, enabled_ns)
     assert disabled_ns < 50_000, f"disabled span costs {disabled_ns:.0f} ns"
 
 
