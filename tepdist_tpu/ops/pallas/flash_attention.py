@@ -1,11 +1,29 @@
 """Pallas TPU flash attention kernel (forward + backward).
 
 The intra-device hot op: online-softmax blockwise attention computed in VMEM
-(one pass over K/V blocks per Q block), MXU-shaped [block, head_dim] matmuls,
-fp32 accumulators. Training-ready via ``jax.custom_vjp``: the forward saves
-(O, LSE) residuals and the backward recomputes P blockwise — two kernels,
-one accumulating dQ over K blocks, one accumulating dK/dV over Q blocks —
-so no [T, T] matrix is ever materialised in HBM in either direction.
+(one pass over K/V blocks per Q block). Training-ready via
+``jax.custom_vjp``: the forward saves (O, LSE) residuals and the backward
+recomputes P blockwise — two kernels, one accumulating dQ over K blocks, one
+accumulating dK/dV over Q blocks — so no [T, T] matrix is ever materialised
+in HBM in either direction.
+
+Precision follows the inputs' dtype and nothing else. The MXU takes q, k, v
+and dO as they arrive and P and dS rounded to that dtype (as the model's
+own einsum attention rounds its probabilities), and accumulates in float32;
+scores, running max and sum, ``exp``, the output and gradient accumulators,
+the log-sum-exp and ``delta`` are float32 whatever the inputs. float32
+inputs get float32 matmuls. (On the v5e the compiler already fed float32
+operands to the MXU in one bf16 pass: bf16 results were identical to the
+last digit before and after; PERF.md, PR 25.)
+
+All three kernels compute the scores transposed, ``S^T = K Q^T`` [bk, bq],
+keys on the rows and queries on the lanes. The softmax statistics are then
+reductions over rows (elementwise maxima and adds of vector registers
+instead of a cross-lane reduction per eight rows, which took 40% of the
+forward), they are [1, bq] rows, and ``P^T`` and ``dS^T`` are what the
+dK/dV matmuls take. The log-sum-exp and ``delta`` cross HBM in that layout,
+``[B*H, T/bq, 1, bq]`` with a Q block's rows on the lanes: one float32 a
+row. (As ``[B*H, T, 1]`` they were tiled (8, 128), 128 times their size.)
 
 Usable standalone, as the ``inner`` of Ulysses sequence parallelism, or as
 the per-block compute of ring attention. Runs in interpret mode off-TPU
@@ -16,12 +34,13 @@ Sequence-length limit: T <= 8192 per call. Each grid step holds a whole
 ``(1, T, D)`` K and V block (forward, dQ) or Q and dO block (dK/dV) in
 VMEM and only tiles the other operand, so VMEM use grows with T. The
 v5e compiler (16 MiB scoped-VMEM limit) accepts forward+backward at
-(B,H,T,D) = (1,12,8192,64) and refuses the backward at T = 16384 and the
-forward at T = 32768 ("Scoped allocation with size 32.75M and limit
-16.00M") — a compile error, never a wrong answer. Longer sequences go
-through ring attention, which calls this kernel per T/P block.
-tests/test_tpu_compile.py compiles the main-path shapes for a described
-v5e.
+(B,H,T,D) = (1,12,8192,64) in bf16 and float32 and (1,4,8192,128) in bf16;
+since the row statistics are lane-dense also (1,12,16384,64) in bf16, and
+it refuses the dK/dV kernel at T = 32768 and float32 at T = 16384 — a
+compile error, never a wrong answer. Longer sequences go through ring
+attention, which calls this kernel per T/P block. tests/test_tpu_compile.py
+compiles the main-path shapes for a described v5e; tools/flash_bench.py
+times the three kernels alone on the chip.
 """
 
 from __future__ import annotations
@@ -40,127 +59,177 @@ log = logging.getLogger(__name__)
 _NEG_INF = -1e30
 
 
+_NT = (((1,), (1,)), ((), ()))    # a @ b.T: contract the last dim of both
+_TN = (((0,), (0,)), ((), ()))    # a.T @ b: contract the first dim of both
+
+
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    """MXU matmul on the operands as they are, float32 accumulation.
+    float32 operands take their passes from ``jax_default_matmul_precision``
+    as before (one bf16 pass by default; ``highest`` ran the parent's
+    kernels four times slower); narrower operands have no more bits to
+    give and say so, or Mosaic refuses them under that setting."""
+    precision = None if a.dtype == jnp.float32 else jax.lax.Precision.DEFAULT
+    return jax.lax.dot_general(a, b, dims, precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+def _fold_scale(dtype, scale: float) -> bool:
+    """Whether q may carry the softmax scale into the matmul: always in
+    float32 (as before), in a narrower dtype only where the product is
+    exact (a power of two, 0.125 at D = 64). Otherwise the float32 scores
+    are scaled."""
+    return dtype == jnp.float32 or math.frexp(scale)[0] == 0.5
+
+
+def _scores_t(k, q, scale, fold, causal_from=None):
+    """Transposed scores [keys, queries] in float32. Keys on the rows and
+    queries on the lanes: the softmax statistics are then reductions over
+    rows (vector maxima and adds, no cross-lane work) and [1, queries]
+    rows, the layout they are stored in, and P^T and dS^T are what the
+    backward matmuls take. ``causal_from`` is the (key, query) position of
+    ``st[0, 0]`` where the diagonal may cross the tile: keys after the
+    query's own position go to -inf."""
+    st = _dot(k, q, _NT)
+    if not fold:
+        st = st * scale
+    if causal_from is not None:
+        k0, q0 = causal_from
+        ahead = (jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
+                 - jax.lax.broadcasted_iota(jnp.int32, st.shape, 0))
+        st = jnp.where(ahead >= k0 - q0, st, _NEG_INF)
+    return st
+
+
+def _over_key_blocks(step, carry, causal, qi, block_q, block_k, n_blocks):
+    """``step(j, carry, causal_from)`` over the K/V blocks Q block ``qi``
+    sees; ``causal_from`` is ``_scores_t``'s. With equal tiles the diagonal
+    crosses only the block of the Q block's own positions: the blocks below
+    it run unmasked in the loop and that one after it, outside, its mask at
+    a fixed place (straight-line code with a constant mask took 15% off the
+    forward; a mask at a computed place costs as much as the loop did;
+    PERF.md, PR 25). Blocks above the diagonal are skipped."""
+    def plain(j, carry):
+        return step(j, carry, None)
+
+    if not causal:
+        return jax.lax.fori_loop(0, n_blocks, plain, carry)
+    if block_q == block_k:
+        return step(qi, jax.lax.fori_loop(0, qi, plain, carry), (0, 0))
+    hi = ((qi + 1) * block_q + block_k - 1) // block_k
+    return jax.lax.fori_loop(
+        0, hi, lambda j, carry: step(j, carry, (j * block_k, qi * block_q)),
+        carry)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
                 causal: bool, scale: float, q_block: int, seq_len: int):
+    """One Q block against the K/V blocks up to its diagonal; the output
+    accumulates as O^T [D, bq] and is transposed once at the end."""
     qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale          # [bq, D]
+    q = q_ref[0]                                      # [bq, D]
     bq, D = q.shape
+    fold = _fold_scale(q.dtype, scale)
+    if fold:
+        q = q * scale
 
-    m0 = jnp.full((bq, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
-    o0 = jnp.zeros((bq, D), jnp.float32)
-
-    n_blocks = seq_len // block_k
-
-    def body(j, carry):
-        m, l, o = carry
-        k = k_ref[0, pl.dslice(j * block_k, block_k)].astype(jnp.float32)
-        v = v_ref[0, pl.dslice(j * block_k, block_k)].astype(jnp.float32)
-        s = q @ k.T                                   # [bq, bk]
-        if causal:
-            qpos = qi * q_block + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 0)
-            kpos = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 1)
-            s = jnp.where(qpos >= kpos, s, _NEG_INF)
-        m_blk = s.max(axis=-1, keepdims=True)
-        m_new = jnp.maximum(m, m_blk)
-        p = jnp.exp(s - m_new)
-        p = jnp.where(m_new <= _NEG_INF / 2, 0.0, p)
+    def step(j, carry, causal_from):
+        m, l, ot = carry
+        k = k_ref[0, pl.dslice(j * block_k, block_k)]
+        v = v_ref[0, pl.dslice(j * block_k, block_k)]
+        st = _scores_t(k, q, scale, fold, causal_from)
+        m_new = jnp.maximum(m, st.max(axis=0, keepdims=True))
+        pt = jnp.exp(st - m_new)                      # [bk, bq]
         corr = jnp.exp(m - m_new)
-        corr = jnp.where(m <= _NEG_INF / 2, 0.0, corr)
-        l_new = l * corr + p.sum(axis=-1, keepdims=True)
-        o_new = o * corr + p @ v
-        return m_new, l_new, o_new
+        l_new = l * corr + pt.sum(axis=0, keepdims=True)
+        return m_new, l_new, ot * corr + _dot(v, pt.astype(v.dtype), _TN)
 
-    if causal:
-        # Only blocks up to (and including) the diagonal contribute.
-        hi = jnp.minimum(((qi + 1) * q_block + block_k - 1) // block_k,
-                         n_blocks)
-    else:
-        hi = n_blocks
-    m, l, o = jax.lax.fori_loop(0, hi, body, (m0, l0, o0))
-    o_ref[0] = (o / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-    lse_ref[0] = m + jnp.log(jnp.maximum(l, 1e-30))
+    # m starts finite, so exp(m - m_new) is 0 (not NaN) on the first block;
+    # every query meets a live key in the first block it sees (key 0 under
+    # the causal mask), so no running max stays at its start and l > 0.
+    carry = (jnp.full((1, bq), _NEG_INF, jnp.float32),
+             jnp.zeros((1, bq), jnp.float32),
+             jnp.zeros((D, bq), jnp.float32))
+    m, l, ot = _over_key_blocks(step, carry, causal, qi, q_block, block_k,
+                                seq_len // block_k)
+    o_ref[0] = (ot * (1.0 / l)).T.astype(o_ref.dtype)
+    lse_ref[0, 0] = m + jnp.log(l)                    # [1, bq]
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
                block_k: int, causal: bool, scale: float, q_block: int,
                seq_len: int):
     """One Q block: dQ = scale * sum_j dS_j @ K_j, with P recomputed from
-    the saved LSE (no renormalisation pass needed)."""
+    the saved LSE (no renormalisation pass needed). Tiled as the forward:
+    dQ accumulates as dQ^T [D, bq]."""
     qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale          # [bq, D]
-    do = do_ref[0].astype(jnp.float32)                # [bq, D]
-    lse = lse_ref[0]                                  # [bq, 1]
-    delta = delta_ref[0]                              # [bq, 1]
+    q = q_ref[0]                                      # [bq, D]
     bq, D = q.shape
-    n_blocks = seq_len // block_k
+    fold = _fold_scale(q.dtype, scale)
+    if fold:
+        q = q * scale
+    do = do_ref[0]                                    # [bq, D]
+    lse = lse_ref[0, 0]                               # [1, bq]
+    delta = delta_ref[0, 0]
 
-    def body(j, dq):
-        k = k_ref[0, pl.dslice(j * block_k, block_k)].astype(jnp.float32)
-        v = v_ref[0, pl.dslice(j * block_k, block_k)].astype(jnp.float32)
-        s = q @ k.T                                   # [bq, bk] (pre-scaled)
-        if causal:
-            qpos = qi * q_block + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 0)
-            kpos = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 1)
-            s = jnp.where(qpos >= kpos, s, _NEG_INF)
-        p = jnp.exp(s - lse)                          # exact softmax probs
-        dp = do @ v.T                                 # [bq, bk]
-        ds = p * (dp - delta)
-        return dq + ds @ k
+    def step(j, dqt, causal_from):
+        k = k_ref[0, pl.dslice(j * block_k, block_k)]
+        v = v_ref[0, pl.dslice(j * block_k, block_k)]
+        st = _scores_t(k, q, scale, fold, causal_from)
+        pt = jnp.exp(st - lse)                        # exact softmax probs
+        dst = pt * (_dot(v, do, _NT) - delta)         # dS^T [bk, bq]
+        return dqt + _dot(k, dst.astype(k.dtype), _TN)
 
-    if causal:
-        hi = jnp.minimum(((qi + 1) * q_block + block_k - 1) // block_k,
-                         n_blocks)
-    else:
-        hi = n_blocks
-    dq = jax.lax.fori_loop(0, hi, body, jnp.zeros((bq, D), jnp.float32))
-    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
+    dqt = _over_key_blocks(step, jnp.zeros((D, bq), jnp.float32), causal,
+                           qi, q_block, block_k, seq_len // block_k)
+    dq_ref[0] = (dqt * scale).T.astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, *, block_q: int, causal: bool, scale: float,
                 k_block: int, seq_len: int):
-    """One K/V block: dV = sum_i P_i^T @ dO_i, dK = scale * sum_i dS_i^T @ Q_i."""
+    """One K/V block: dV = sum_i P_i^T @ dO_i, dK = scale * sum_i dS_i^T @
+    Q_i, over the Q blocks from its diagonal on."""
     ki = pl.program_id(1)
-    k = k_ref[0].astype(jnp.float32)                  # [bk, D]
-    v = v_ref[0].astype(jnp.float32)
+    k = k_ref[0]                                      # [bk, D]
+    v = v_ref[0]
     bk, D = k.shape
+    fold = _fold_scale(k.dtype, scale)
     n_blocks = seq_len // block_q
 
-    def body(i, carry):
+    def step(i, carry, causal_from):
         dk, dv = carry
-        q = q_ref[0, pl.dslice(i * block_q, block_q)].astype(
-            jnp.float32) * scale                      # [bq, D]
-        do = do_ref[0, pl.dslice(i * block_q, block_q)].astype(jnp.float32)
-        lse = lse_ref[0, pl.dslice(i * block_q, block_q)]   # [bq, 1]
-        delta = delta_ref[0, pl.dslice(i * block_q, block_q)]
-        s = q @ k.T                                   # [bq, bk]
-        if causal:
-            qpos = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, bk), 0)
-            kpos = ki * k_block + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, bk), 1)
-            s = jnp.where(qpos >= kpos, s, _NEG_INF)
-        p = jnp.exp(s - lse)
-        dv_new = dv + p.T @ do
-        dp = do @ v.T
-        ds = p * (dp - delta)
-        dk_new = dk + ds.T @ q
-        return dk_new, dv_new
+        q = q_ref[0, pl.dslice(i * block_q, block_q)]  # [bq, D]
+        if fold:
+            q = q * scale
+        do = do_ref[0, pl.dslice(i * block_q, block_q)]
+        st = _scores_t(k, q, scale, fold, causal_from)
+        pt = jnp.exp(st - lse_ref[0, i])              # P^T [bk, bq]
+        dv = dv + _dot(pt.astype(do.dtype), do)
+        dst = pt * (_dot(v, do, _NT) - delta_ref[0, i])   # dS^T
+        return dk + _dot(dst.astype(q.dtype), q), dv
 
-    if causal:
-        # Q blocks strictly before this K block contribute nothing.
-        lo = (ki * k_block) // block_q
+    def plain(i, carry):
+        return step(i, carry, None)
+
+    carry = (jnp.zeros((bk, D), jnp.float32), jnp.zeros((bk, D), jnp.float32))
+    if not causal:
+        carry = jax.lax.fori_loop(0, n_blocks, plain, carry)
+    elif block_q == k_block:
+        # As in _over_key_blocks: Q blocks before this K block see none of
+        # it, its own sees it across the diagonal, those after see it all.
+        carry = jax.lax.fori_loop(ki + 1, n_blocks, plain,
+                                  step(ki, carry, (0, 0)))
     else:
-        lo = 0
-    dk, dv = jax.lax.fori_loop(
-        lo, n_blocks, body,
-        (jnp.zeros((bk, D), jnp.float32), jnp.zeros((bk, D), jnp.float32)))
-    # q was pre-scaled, so dk already carries one factor of scale.
+        lo = (ki * k_block) // block_q
+        carry = jax.lax.fori_loop(
+            lo, n_blocks,
+            lambda i, carry: step(i, carry, (ki * k_block, i * block_q)),
+            carry)
+    dk, dv = carry
+    # With the scale folded into q, dk already carries it.
+    if not fold:
+        dk = dk * scale
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -194,11 +263,14 @@ def _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret):
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, 1, 1, block_q), lambda b, i: (b, i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, T, 1), jnp.float32),
+            # One float32 per row, the rows of a Q block on the lanes: a
+            # [.., T, 1] array is tiled (8, 128) in HBM, 128 times its size.
+            jax.ShapeDtypeStruct((B * H, T // block_q, 1, block_q),
+                                 jnp.float32),
         ],
         interpret=interpret,
     )(qf, kf, vf)
@@ -212,17 +284,19 @@ def _bwd_call(causal, scale, block_q, block_k, interpret, res, do,
     BH = B * H
     qf, kf, vf = (x.reshape(BH, T, D) for x in (q, k, v))
     dof = do.reshape(BH, T, D)
-    lsef = lse.reshape(BH, T, 1)
+    rows = (BH, T // block_q, 1, block_q)
+    lsef = lse.reshape(rows)
     # delta = rowsum(dO * O): cheap elementwise reduce, XLA fuses it.
     # An LSE cotangent folds in exactly here: dS = P * (dP - delta + dLSE)
     # (d lse / d s = P), so delta -= dlse reuses the unmodified kernels.
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1).reshape(BH, T, 1)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     if dlse is not None:
-        delta = delta - dlse.astype(jnp.float32).reshape(BH, T, 1)
+        delta = delta - dlse.astype(jnp.float32)
+    delta = delta.reshape(rows)
 
     full_spec = pl.BlockSpec((1, T, D), lambda b, i: (b, 0, 0))
-    row_full = pl.BlockSpec((1, T, 1), lambda b, i: (b, 0, 0))
+    row_block = pl.BlockSpec((1, 1, 1, block_q), lambda b, i: (b, i, 0, 0))
+    row_full = pl.BlockSpec((1,) + rows[1:], lambda b, i: (b, 0, 0, 0))
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, block_k=block_k, causal=causal,
@@ -233,8 +307,7 @@ def _bwd_call(causal, scale, block_q, block_k, interpret, res, do,
             pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
             full_spec, full_spec,
             pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
+            row_block, row_block,
         ],
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
@@ -359,12 +432,16 @@ def flash_attention_with_lse(q, k, v, causal: bool = True,
 
 
 def _default_block(T: int) -> Optional[int]:
-    """Largest divisor of T up to 512. On-chip sweep (v5e, GPT-2 1.5B
-    training step, T=1024/D=64): 512x512 tiles beat the conventional
-    128x128 by 39% end to end (8,495 vs 6,138 tok/s) — bigger tiles mean
-    fewer grid steps, fewer LSE/accumulator round-trips, and longer MXU
-    bursts; 1024 tiles regress (VMEM pressure). 512 caps the S-block at
-    512*512*4B = 1 MiB of VMEM, safe alongside K/V for any practical D.
+    """Largest divisor of T up to 512. 512x512 tiles beat the conventional
+    128x128 on the GPT-2 1.5B training step (T=1024/D=64) by 39% end to end
+    in a self-reported sweep of an earlier round (8,495 vs 6,138 tok/s; the
+    benchmark read 8,599 at 512 before PR 25 and never ran 128): bigger
+    tiles mean fewer grid steps and longer MXU bursts; 1024 tiles regressed
+    there. On the chip (PR 25, tools/flash_bench.py) a 512x512 score tile
+    takes 0.92 us in the forward, where its two matmuls at D = 64 (half an
+    MXU pass each) cannot take under 0.68. 512 caps the float32 S^T tile at
+    512*512*4B = 1 MiB of VMEM beside the (1, T, D) K and V blocks (2 x T x
+    128 lanes x 2 B, double-buffered: 8 MiB at T = 8192 in bf16).
     Must DIVIDE T (grid constraint). Mosaic wants lane-aligned tiles, so
     only multiples of 128 (ideal) or 8 (acceptable) are returned; an
     awkward T (prime, 3*11*31, ...) gets None and the caller falls back

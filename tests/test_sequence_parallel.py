@@ -210,6 +210,108 @@ def test_flash_attention_backward_matches_reference():
                                        atol=3e-4, rtol=1e-3)
 
 
+def _dense_f32_attention(q, k, v, causal):
+    """(o, lse) of plain attention in float32, whatever the inputs' dtype."""
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest")
+    s = s / np.sqrt(q.shape[-1])
+    if causal:
+        T = q.shape[2]
+        s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -1e30)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    p = jnp.exp(s - lse[..., None])
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v, precision="highest"), lse
+
+
+def _gpt2_einsum_attention(q, k, v, causal):
+    """models/gpt2.py's own plain attention (its `attention`, after the
+    head split), which rounds the logits and the probabilities to the
+    activations' dtype."""
+    T = q.shape[2]
+    logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * (q.shape[-1] ** -0.5)
+    logits = logits.astype(jnp.float32)
+    if causal:
+        logits = jnp.where(jnp.tril(jnp.ones((T, T), bool)), logits, -1e9)
+    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+
+
+def _rel_l2(got, want):
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+# (T, block_q, block_k): one block, several blocks, block_q != block_k
+# either way round.
+_FLASH_TILINGS = [(64, 64, 64), (128, 32, 32), (128, 64, 32), (128, 32, 64)]
+
+
+def _bf16_case(T, seed=5):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q, k, v, do = (jax.random.normal(kk, (2, 3, T, 32), jnp.float32)
+                   .astype(jnp.bfloat16) for kk in ks[:4])
+    dlse = jax.random.normal(ks[4], (2, 3, T), jnp.float32)
+    return q, k, v, do, dlse
+
+
+@pytest.mark.parametrize("tiling", _FLASH_TILINGS,
+                         ids=lambda t: "T%d-bq%d-bk%d" % t)
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_attention_bf16_no_worse_than_einsum(causal, tiling):
+    """On bf16 inputs the kernels feed the MXU bf16 operands (P and dS
+    rounded for the second matmul of each pair, float32 everything else):
+    the forward and the three gradients stay as close to float32 dense
+    attention of the same inputs as the model's own bf16 einsum attention
+    and its autodiff do, within a factor of 1.5."""
+    from tepdist_tpu.ops.pallas.flash_attention import flash_attention
+
+    T, bq, bk = tiling
+    q, k, v, do, _ = _bf16_case(T)
+
+    def outputs(attend, *args):
+        o, vjp = jax.vjp(attend, *args)
+        return (o,) + vjp(do.astype(o.dtype))
+
+    want = outputs(lambda q, k, v: _dense_f32_attention(q, k, v, causal)[0],
+                   *(x.astype(jnp.float32) for x in (q, k, v)))
+    einsum = outputs(lambda q, k, v: _gpt2_einsum_attention(q, k, v, causal),
+                     q, k, v)
+    flash = outputs(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, block_q=bq, block_k=bk, interpret=True),
+        q, k, v)
+    for name, f, e, w in zip(("o", "dq", "dk", "dv"), flash, einsum, want):
+        assert f.dtype == jnp.bfloat16, name
+        assert _rel_l2(f, w) <= 1.5 * _rel_l2(e, w), name
+
+
+@pytest.mark.parametrize("tiling", _FLASH_TILINGS,
+                         ids=lambda t: "T%d-bq%d-bk%d" % t)
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_attention_with_lse_bf16(causal, tiling):
+    """The log-sum-exp stays float32 on bf16 inputs, keeps its [B, H, T]
+    shape, and its cotangent folds into the backward kernels' delta."""
+    from tepdist_tpu.ops.pallas.flash_attention import (
+        flash_attention_with_lse)
+
+    T, bq, bk = tiling
+    q, k, v, do, dlse = _bf16_case(T)
+    (o, lse), vjp = jax.vjp(lambda q, k, v: flash_attention_with_lse(
+        q, k, v, causal=causal, block_q=bq, block_k=bk, interpret=True),
+        q, k, v)
+    got = vjp((do, dlse))
+    (ro, rlse), rvjp = jax.vjp(
+        lambda q, k, v: _dense_f32_attention(q, k, v, causal),
+        *(x.astype(jnp.float32) for x in (q, k, v)))
+    want = rvjp((do.astype(jnp.float32), dlse))
+    assert lse.dtype == jnp.float32 and lse.shape == q.shape[:3]
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(rlse),
+                               rtol=1e-5, atol=1e-5)
+    assert _rel_l2(o, ro) < 5e-3
+    for g, w in zip(got, want):
+        assert g.dtype == jnp.bfloat16
+        assert _rel_l2(g, w) < 1e-2
+
+
 def test_gpt2_flash_config_trains_like_einsum():
     """GPT2Config(attn='flash', remat=True) end-to-end loss/grad parity
     with the einsum model (the benched big-model path)."""

@@ -8,6 +8,7 @@ passes is NOT a chip run: nothing executes here.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
@@ -40,23 +41,77 @@ def v5e_devices():
     compilation_cache.reset_cache()
 
 
-# (B, H, T, D) of the main path: GPT-2 117M at batch 8, GPT-2 1.5B (25
-# heads) at micro batch 4, and a 32-head x 128 long-sequence shape.
-@pytest.mark.parametrize("shape", [(8, 12, 1024, 64), (4, 25, 1024, 64),
-                                   (2, 32, 2048, 128)],
-                         ids=lambda s: "x".join(map(str, s)))
-def test_flash_kernels_compile_for_v5e(v5e_devices, shape):
-    """Forward, dQ and dK/dV kernels (via jax.grad), bf16, not interpreted."""
+# A row statistic stored one to a row, [.., T, 1], is tiled (8, 128) in HBM:
+# 128 times its size, as much as q, k, v and o together (PERF.md, PR 25).
+_PADDED_ROWS = re.compile(r"f32\[(?:\d+,)*1\]\{[^}]*T\(8,128\)")
+
+
+def _flash_call_shapes(text):
+    """For each flash custom call of a compiled module, the shapes (with
+    layouts) of its results and of its operands, as one string."""
+    defined = {}
+    calls = []
+    for line in text.splitlines():
+        name, eq, rhs = line.strip().removeprefix("ROOT ").partition(" = ")
+        if not eq or not name.startswith("%"):
+            continue
+        defined[name] = rhs.split("(%", 1)[0]
+        if "tpu_custom_call" in rhs and " custom-call(" in rhs \
+                and "tepdist_flash_" in name:
+            operands = rhs.split(" custom-call(", 1)[1].split(")", 1)[0]
+            calls.append((name, re.findall(r"%[\w.\-]+", operands)))
+    return {name: defined[name] + " <- " + " ".join(
+        defined.get(o, "?") for o in operands) for name, operands in calls}
+
+
+# (B, H, T, D), dtype, tile, of the main path: GPT-2 117M at batch 8, GPT-2
+# 1.5B (25 heads) at micro batch 4 and, with the 512 tiles its configuration
+# sets, at the benchmark cell's micro batch 3; a 32-head x 128 long-sequence
+# shape; one float32 shape (float32 callers keep float32 matmuls); and the
+# module docstring's limit, T = 8192.
+@pytest.mark.parametrize("shape,dtype,block", [
+    ((8, 12, 1024, 64), jnp.bfloat16, None),
+    ((4, 25, 1024, 64), jnp.bfloat16, None),
+    ((2, 32, 2048, 128), jnp.bfloat16, None),
+    ((3, 25, 1024, 64), jnp.bfloat16, 512),
+    ((2, 4, 1024, 64), jnp.float32, None),
+    ((1, 12, 8192, 64), jnp.bfloat16, None),
+], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple)
+    else getattr(v, "__name__", str(v)))
+def test_flash_kernels_compile_for_v5e(v5e_devices, shape, dtype, block):
+    """Forward, dQ and dK/dV kernels (via jax.grad), not interpreted; no
+    operand or result of a kernel is a row statistic padded 128-fold."""
     one_chip = SingleDeviceSharding(v5e_devices[0])
-    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, block_q=block, block_k=block,
+                                       interpret=False).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    calls = _flash_call_shapes(text)
+    assert len(calls) == 3, sorted(calls)
+    for name, shapes in calls.items():
+        assert "?" not in shapes, (name, shapes)
+        assert not _PADDED_ROWS.search(shapes), (name, shapes)
+
+
+def test_flash_bf16_compiles_under_highest_matmul_precision(v5e_devices):
+    """A global ``jax_default_matmul_precision`` must not reach the bf16
+    kernels: Mosaic refuses a float32-precision matmul on bf16 operands."""
+    one_chip = SingleDeviceSharding(v5e_devices[0])
+    x = jax.ShapeDtypeStruct((3, 25, 1024, 64), jnp.bfloat16,
+                             sharding=one_chip)
 
     def loss(q, k, v):
         return jnp.sum(flash_attention(q, k, v, interpret=False)
                        .astype(jnp.float32))
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        x, x, x).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    with jax.default_matmul_precision("highest"):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            x, x, x).compile().as_text()
+    assert len(_flash_call_shapes(text)) == 3
 
 
 def test_planned_step_with_kernel_in_scan_compiles_for_mesh(v5e_devices):
