@@ -83,6 +83,9 @@ def eqn_flops(eqn) -> float:
         return _dot_general_flops(eqn)
     if name == "conv_general_dilated":
         return conv_flops(eqn)
+    if name == "pallas_call" and eqn.params.get("cost_estimate") is not None:
+        # A kernel that states its own cost (the grouped matmuls do).
+        return float(eqn.params["cost_estimate"].flops)
     if name in CALL_PRIMITIVES:
         inner = _sub_jaxpr(eqn)
         return jaxpr_flops(inner) if inner is not None else 0.0

@@ -19,6 +19,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from tepdist_tpu.models.layers import cross_entropy
+
 
 @dataclasses.dataclass(frozen=True)
 class GPT2Config:
@@ -213,60 +215,10 @@ def forward(params, tokens, cfg: GPT2Config, attn_impl=None):
     return (x @ params["wte"].T).astype(jnp.float32)
 
 
-def _ce_from_hidden(x, wte, targets, cfg: GPT2Config):
-    """Cross entropy from final hidden states, optionally chunked.
-
-    Dense path: logits = x @ wte.T in one [B, T, V] fp32 tensor. Chunked
-    path (cfg.loss_chunk > 0): lax.scan over token chunks with the chunk
-    body checkpointed — forward AND backward hold only [chunk, V] logits
-    at a time; the backward recomputes each chunk's logits from the saved
-    [chunk, D] hidden slice. Summation order changes (per-chunk partial
-    sums), so results match the dense path to float tolerance, not
-    bit-exactly."""
-    B, T, D = x.shape
-    chunk = cfg.loss_chunk
-    n_tokens = B * T
-    if chunk <= 0:
-        logits = (x @ wte.T).astype(jnp.float32)
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(
-            logits, targets[..., None], axis=-1)[..., 0]
-        return jnp.mean(logz - gold)
-
-    # Non-dividing counts get a zero-padded, masked tail chunk — the LM
-    # loss always shifts tokens (n_tokens = B*(T-1) at the call site), so
-    # a divisibility fallback would silently disable chunking for every
-    # power-of-two chunk size.
-    n_chunks = -(-n_tokens // chunk)
-    pad = n_chunks * chunk - n_tokens
-    xf = x.reshape(n_tokens, D)
-    tf = targets.reshape(n_tokens)
-    valid = jnp.ones((n_tokens,), jnp.float32)
-    if pad:
-        xf = jnp.concatenate([xf, jnp.zeros((pad, D), x.dtype)])
-        tf = jnp.concatenate([tf, jnp.zeros((pad,), targets.dtype)])
-        valid = jnp.concatenate([valid, jnp.zeros((pad,), jnp.float32)])
-    xf = xf.reshape(n_chunks, chunk, D)
-    tf = tf.reshape(n_chunks, chunk)
-    valid = valid.reshape(n_chunks, chunk)
-
-    @jax.checkpoint
-    def body(acc, inp):
-        xc, tc, mc = inp
-        logits = (xc @ wte.T).astype(jnp.float32)       # [chunk, V]
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
-        return acc + jnp.sum((logz - gold) * mc), None
-
-    total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32),
-                            (xf, tf, valid))
-    return total / n_tokens
-
-
 def loss_fn(params, tokens, cfg: GPT2Config, attn_impl=None):
     """Next-token cross entropy over shifted tokens (reference GPT2 LM loss)."""
     x = hidden_states(params, tokens[:, :-1], cfg, attn_impl)
-    return _ce_from_hidden(x, params["wte"], tokens[:, 1:], cfg)
+    return cross_entropy(x, params["wte"], tokens[:, 1:], cfg.loss_chunk)
 
 
 # --------------------------------------------------------------------------
@@ -309,7 +261,7 @@ def forward_stacked(params, tokens, cfg: GPT2Config, attn_impl=None):
 
 def loss_fn_stacked(params, tokens, cfg: GPT2Config, attn_impl=None):
     x = hidden_states_stacked(params, tokens[:, :-1], cfg, attn_impl)
-    return _ce_from_hidden(x, params["wte"], tokens[:, 1:], cfg)
+    return cross_entropy(x, params["wte"], tokens[:, 1:], cfg.loss_chunk)
 
 
 # --------------------------------------------------------------------------
@@ -437,7 +389,7 @@ def pipelined_loss_fn(params, stacked_blocks, tokens, cfg: GPT2Config,
     y_micro = pipelined(stacked_blocks, x_micro)
     y = y_micro.reshape(B, T, cfg.n_embd)
     y = _layer_norm(y, params["ln_f_g"], params["ln_f_b"])
-    return _ce_from_hidden(y, params["wte"], targets, cfg)
+    return cross_entropy(y, params["wte"], targets, cfg.loss_chunk)
 
 
 def fake_batch(cfg: GPT2Config, batch_size: int, seq_len: Optional[int] = None,

@@ -16,6 +16,8 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from tepdist_tpu.models.layers import rms_norm as _rms_norm, rope as _rope
+
 
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
@@ -80,27 +82,6 @@ def init_params(cfg: LlamaConfig, key) -> Dict[str, Any]:
             "w_down": norm(lk[6], (f, d), std / math.sqrt(2 * cfg.n_layer)),
         }
     return params
-
-
-def _rms_norm(x, g, eps=1e-5):
-    x32 = x.astype(jnp.float32)
-    scale = jax.lax.rsqrt((x32 * x32).mean(-1, keepdims=True) + eps)
-    return (x32 * scale * g).astype(x.dtype)
-
-
-def _rope(x, theta: float):
-    """Rotary embedding over [B, H, T, hd] (rotate-half formulation)."""
-    B, H, T, hd = x.shape
-    half = hd // 2
-    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
-    angles = jnp.arange(T, dtype=jnp.float32)[:, None] * freqs[None, :]
-    cos = jnp.cos(angles)[None, None, :, :]
-    sin = jnp.sin(angles)[None, None, :, :]
-    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(
-        jnp.float32)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
-                          axis=-1)
-    return out.astype(x.dtype)
 
 
 def _attention(blk, x, cfg: LlamaConfig):

@@ -97,6 +97,38 @@ def test_flash_kernels_compile_for_v5e(v5e_devices, shape, dtype, block):
         assert not _PADDED_ROWS.search(shapes), (name, shapes)
 
 
+# The OLMoE cell's grouped matmuls: 8192 tokens x 8 experts a token in the
+# tile-aligned layout (65536 rows + 64 tiles of pads), 64 experts of
+# 2048 x 1024 (gate, up) and 1024 x 2048 (down).
+@pytest.mark.parametrize("K,N", [(2048, 1024), (1024, 2048)])
+def test_grouped_matmul_kernels_compile_for_v5e(v5e_devices, K, N):
+    """Forward, input gradient (weights contracted as stored) and weight
+    gradient, not interpreted, at the model's tile."""
+    from tepdist_tpu.models.olmoe import CONFIGS
+    from tepdist_tpu.ops.pallas import grouped_matmul as gmm
+    tile, E = CONFIGS["1B-7B"].moe_tile_m, 64
+    one_chip = SingleDeviceSharding(v5e_devices[0])
+    tiles = 65536 // tile + E
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def three(x, dy, w, tile_group, n_tiles):
+        kw = dict(tile_m=tile, interpret=False)
+        return (gmm.gmm(x, w, tile_group, n_tiles, **kw),
+                gmm.gmm(dy, w, tile_group, n_tiles, transpose_rhs=True,
+                        name="tepdist_gmm_dx", **kw),
+                gmm.tgmm(x, dy, tile_group, n_tiles, E, **kw))
+
+    text = jax.jit(three).lower(
+        sds((tiles * tile, K)), sds((tiles * tile, N)), sds((E, K, N)),
+        sds((tiles,), jnp.int32), sds((1,), jnp.int32)).compile().as_text()
+    for name in ("tepdist_gmm_fwd", "tepdist_gmm_dx", "tepdist_gmm_dw"):
+        assert f"%{name}" in text, name
+    assert not [line for line in text.splitlines()    # no transposed weights
+                if " copy(" in line and "= bf16[64," in line]
+
+
 def test_flash_bf16_compiles_under_highest_matmul_precision(v5e_devices):
     """A global ``jax_default_matmul_precision`` must not reach the bf16
     kernels: Mosaic refuses a float32-precision matmul on bf16 operands."""

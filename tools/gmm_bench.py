@@ -1,0 +1,225 @@
+"""Time and check the grouped matmul alone, on the chip: the tree's Pallas
+kernels (``ops/pallas/grouped_matmul.py``) in their tile-aligned layout.
+
+Rows, K, N and groups in; device microseconds a call of the forward, the
+input gradient and the weight gradient out, from one ``jax.profiler`` trace
+a form, each beside its roofline time (``benchmark/kernels/gmm_cost.py``,
+``benchmark/peaks.json``); pads are in the time and not in the count.
+
+``--sizes`` says how the rows fall into groups: ``balanced`` (a multinomial
+over equally likely experts, as a trained router gives them), ``skewed``
+(experts likely as 1 / rank^2, the last two empty: what a capacity scheme
+would drop from) or a JSON file whose ``layers`` is a list of size lists, one
+timed after the other (``tools/olmoe_flips.py --sizes-out`` writes the sizes
+the OLMoE cell's router gives a micro batch).
+
+``--check 1`` (the default) holds the compiled kernels to a per-expert
+float32 loop on the host (numpy, the same bf16 operands): relative L2 error
+of the forward, the input gradient and the weight gradient, the pad rows
+read back as zeros, an empty group's weight gradient exactly zero. A result
+rounded once to bf16 is within 2**-8 of the float32 one; a form above that
+ends the run with 1.
+
+No benchmark cell runs this; it is for work on the kernels. No CPU fallback.
+
+Run: chiprun -- python tools/gmm_bench.py [--rows 65536] [--k 2048]
+     [--n 1024] [--groups 64] [--sizes balanced] [--tile-m 128,256,512]
+     [--block-n 1024] [--check 0]
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+CHECK_LIMIT = 2.0 ** -8
+
+
+def traced_us(fn, args, iters, path):
+    """Device microseconds a call of jitted ``fn``, and its four longest
+    operations."""
+    import jax
+    from benchmark.lib import tracing
+    jax.block_until_ready(fn(*args))                    # compiles
+    tracing.discard(path)
+    jax.profiler.start_trace(path)
+    for _ in range(iters):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    jax.profiler.stop_trace()
+    summary = tracing.reduce_trace(path)
+    tracing.discard(path)
+    ops = sorted(summary.ops(lambda t: True), key=lambda op: -op[1])
+    return (1e6 * sum(s for _, s, _ in ops) / iters,
+            [[t[:100], round(1e6 * s / iters, 1)] for t, s, _ in ops[:4]])
+
+
+def size_sets(spec, rows, groups, rng):
+    """[(label, rows of each group)] of ``--sizes``."""
+    import numpy as np
+    if spec == "balanced":
+        p = np.full(groups, 1.0 / groups)
+    elif spec == "skewed":
+        p = rng.permutation(1.0 / np.arange(1, groups + 1) ** 2)
+        p[-2:] = 0.0
+        p /= p.sum()
+    else:
+        with open(spec) as f:
+            return [(f"layer{i}", np.asarray(s, np.int32))
+                    for i, s in enumerate(json.load(f)["layers"])]
+    return [(spec, rng.multinomial(rows, p).astype(np.int32))]
+
+
+def loop_reference(x, dy, w, ids):
+    """(x @ w[g], dy @ w[g].T, x.T @ dy per group) by a loop over the
+    groups in float32; rows in the order of ``ids`` [R]."""
+    import numpy as np
+    x, dy, w = (np.asarray(a, np.float32) for a in (x, dy, w))
+    out, dx, dw = np.zeros_like(dy), np.zeros_like(x), np.zeros_like(w)
+    for g in range(w.shape[0]):
+        rows = np.flatnonzero(ids == g)
+        out[rows] = x[rows] @ w[g]
+        dx[rows] = dy[rows] @ w[g].T
+        dw[g] = x[rows].T @ dy[rows]
+    return out, dx, dw
+
+
+def rel_l2(got, want):
+    import numpy as np
+    got = np.asarray(got, np.float32)
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--rows", type=int, default=65536)
+    ap.add_argument("--k", type=int, default=2048)
+    ap.add_argument("--n", type=int, default=1024)
+    ap.add_argument("--groups", type=int, default=64)
+    ap.add_argument("--sizes", default="balanced")
+    ap.add_argument("--tile-m", default="128,256,512")
+    ap.add_argument("--block-n", default="1024")
+    ap.add_argument("--check", type=int, default=1)
+    ap.add_argument("--iters", type=int, default=10)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--out", default=None)
+    args = ap.parse_args(argv)
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.kernels import gmm_cost
+    from benchmark.lib import device
+    from tepdist_tpu.ops import grouped_matmul as layout
+    from tepdist_tpu.ops.pallas import grouped_matmul as kernels
+
+    devices = device.own_chips(1)
+    peaks = device.peaks_for(devices[0].device_kind,
+                             os.path.join(ROOT, "benchmark"))
+    K, N, G = args.k, args.n, args.groups
+    rng = np.random.default_rng(args.seed)
+    trace_root = os.path.join(ROOT, ".bench_trace", "gmm_bench")
+    bf16 = jnp.bfloat16
+    kx, kw, ky = jax.random.split(jax.random.PRNGKey(args.seed), 3)
+    w = (jax.random.normal(kw, (G, K, N), jnp.float32) * 0.02).astype(bf16)
+    records, sound = [], True
+
+    for label, sizes in size_sets(args.sizes, args.rows, G, rng):
+        R = int(sizes.sum())
+        least = gmm_cost.roofline_seconds(
+            gmm_cost.grouped_matmul(R, K, N, G), peaks)
+        # One assignment a row ("tokens" with k = 1), in a seeded order.
+        ids = rng.permutation(np.repeat(np.arange(G), sizes)).astype(np.int32)
+        x = jax.random.normal(kx, (R, K), jnp.float32).astype(bf16)
+        dy = jax.random.normal(ky, (R, N), jnp.float32).astype(bf16)
+        want = loop_reference(x, dy, w, ids) if args.check else None
+        for tm in (int(t) for t in args.tile_m.split(",")):
+            r = layout.route(jnp.asarray(ids)[:, None], G, tm)
+            xp = layout.dispatch(x, r.row_token, r.dest)
+            dyp = layout.dispatch(dy, r.row_token, r.dest)
+            tg, nt = r.tile_group, r.n_tiles
+            for bn in (int(b) for b in args.block_n.split(",")):
+                forms = {
+                    "forward": jax.jit(lambda x, w: kernels.gmm(
+                        x, w, tg, nt, tile_m=tm, block_n=bn,
+                        interpret=False)),
+                    "input_grad": jax.jit(lambda dy, w: kernels.gmm(
+                        dy, w, tg, nt, tile_m=tm, block_n=bn,
+                        transpose_rhs=True, name="tepdist_gmm_dx",
+                        interpret=False)),
+                    "weight_grad": jax.jit(lambda x, dy: kernels.tgmm(
+                        x, dy, tg, nt, G, tile_m=tm, block_n=bn,
+                        interpret=False)),
+                }
+                operands = {"forward": (xp, w), "input_grad": (dyp, w),
+                            "weight_grad": (xp, dyp)}
+                rec = {"impl": "pallas", "sizes": label, "rows": R, "K": K,
+                       "N": N, "groups": G, "rows_max": int(sizes.max()),
+                       "rows_min": int(sizes.min()), "tile_m": tm,
+                       "block_n": bn, "rows_in_layout": int(xp.shape[0]),
+                       "live_tiles": int(nt[0]),
+                       "roofline_us": 1e6 * least["seconds"],
+                       "bound": least["bound"],
+                       "device": devices[0].device_kind}
+                try:
+                    if args.check:
+                        pad = np.asarray(r.row_token) >= R
+                        dest = np.asarray(r.dest)[:, 0]
+                        got = {f: np.asarray(fn(*operands[f]), np.float32)
+                               for f, fn in forms.items()}
+                        empty = np.flatnonzero(sizes == 0)
+                        rec["check"] = {
+                            "rel_l2": {
+                                "forward": rel_l2(
+                                    got["forward"][dest], want[0]),
+                                "input_grad": rel_l2(
+                                    got["input_grad"][dest], want[1]),
+                                "weight_grad": rel_l2(
+                                    got["weight_grad"], want[2])},
+                            "pad_rows": int(pad.sum()),
+                            "pad_rows_zero": not (
+                                got["forward"][pad].any()
+                                or got["input_grad"][pad].any()),
+                            "empty_groups": len(empty),
+                            "empty_groups_dw_zero":
+                                not got["weight_grad"][empty].any()}
+                        c = rec["check"]
+                        c["sound"] = bool(
+                            max(c["rel_l2"].values()) < CHECK_LIMIT
+                            and c["pad_rows_zero"]
+                            and c["empty_groups_dw_zero"])
+                        sound = sound and c["sound"]
+                        del got
+                    total = 0.0
+                    for f, fn in forms.items():
+                        us, ops = traced_us(
+                            fn, operands[f], args.iters, os.path.join(
+                                trace_root, f"{label}_{tm}_{bn}_{f}"))
+                        rec[f] = {"us_per_call": us, "top_ops": ops,
+                                  "roofline_share_pct":
+                                      100.0 * rec["roofline_us"] / us}
+                        total += us
+                    rec["three_forms_us"] = total
+                except Exception as e:  # noqa: BLE001 — one refused variant
+                    # must not cost the call that times the others
+                    rec["error"] = repr(e)[:2000]
+                    sound = False
+                line = json.dumps(rec)
+                print(line, flush=True)
+                records.append(line)
+            del xp, dyp
+    if args.out:
+        os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+        with open(args.out, "a") as f:
+            f.write("\n".join(records) + "\n")
+    return 0 if sound else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())
