@@ -140,6 +140,7 @@ def _plan(cfg_name: str, batch: int, seq: int, devices, **plan_kwargs):
     ``cache_hit``: the step program's first compile in this process (the
     winner's post-check, inside ``plan_training``) was read from the
     persistent cache and nothing that long had to be written."""
+    from tepdist_tpu.telemetry import metrics
     from tepdist_tpu.train import plan_training
 
     cfg, params, tokens, tx = _model(cfg_name, batch, seq)
@@ -152,6 +153,10 @@ def _plan(cfg_name: str, batch: int, seq: int, devices, **plan_kwargs):
                for k, v in _cache_traffic().items()}
     return tplan, tokens, {
         "setup_planner_seconds": seconds, **in_plan,
+        # Parameter bytes whose gradients a GA step accumulates inside the
+        # loss's layer loop / by the tree-wide add (both 0: one micro batch).
+        **{k: metrics().gauge(k).value
+           for k in ("ga_fused_bytes", "ga_unfused_bytes")},
         "cache_hit": in_plan["plan_cache_hits"] > 0
         and in_plan["plan_cache_writes"] == 0}
 

@@ -177,7 +177,7 @@ class TepdistSession:
         n_batch = len(example_batch)
         step_fn = build_ga_step(
             grad_fn, apply_fn, num_micro_batches,
-            batch_argnums=tuple(range(1, 1 + n_batch)))
+            batch_argnums=tuple(range(1, 1 + n_batch)), loss_fn=loss_fn)
         opt_state = (optimizer.init(params)
                      if not _is_abstract(params)
                      else jax.eval_shape(optimizer.init, params))

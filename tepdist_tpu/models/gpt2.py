@@ -19,7 +19,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from tepdist_tpu.models.layers import cross_entropy
+from tepdist_tpu.models.layers import cross_entropy, scan_blocks
 
 
 @dataclasses.dataclass(frozen=True)
@@ -246,9 +246,14 @@ def hidden_states_stacked(params, tokens, cfg: GPT2Config, attn_impl=None):
     def body(h, layer_params):
         return transformer_block(layer_params, h, cfg, attn_impl), None
 
-    if cfg.remat:
-        body = jax.checkpoint(body, **_remat_kwargs(cfg))
-    x, _ = jax.lax.scan(body, x, params["blocks"])
+    if cfg.remat and cfg.remat_policy == "full":
+        # Full remat is the form a gradient-accumulation step can reach
+        # into (layers.scan_blocks); a policy that saves more stays below.
+        x, _ = scan_blocks(body, x, params["blocks"])
+    else:
+        if cfg.remat:
+            body = jax.checkpoint(body, **_remat_kwargs(cfg))
+        x, _ = jax.lax.scan(body, x, params["blocks"])
     return _layer_norm(x, params["ln_f_g"], params["ln_f_b"])
 
 

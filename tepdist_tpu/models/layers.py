@@ -1,11 +1,124 @@
-"""Pieces more than one model of the zoo uses: RMSNorm, rotary positions and
-the (optionally chunked) next-token cross entropy. One copy, so that a
-change for one model is seen by the others' tests and benchmark cells."""
+"""Pieces more than one model of the zoo uses: RMSNorm, rotary positions,
+the (optionally chunked) next-token cross entropy and the walk over stacked
+blocks. One copy, so that a change for one model is seen by the others'
+tests and benchmark cells."""
 
 from __future__ import annotations
 
+import contextvars
+from typing import Dict, List, Optional, Tuple
+
 import jax
 import jax.numpy as jnp
+
+
+class BlockGradSink:
+    """What a gradient-accumulation step hands to :func:`scan_blocks`
+    while it traces or differentiates a loss (``with BlockGradSink(...)``).
+
+    ``leaves`` maps ``id(parameter leaf)`` to the caller's key for it; a
+    walk over stacked leaves that are all in it is noted in ``walks`` as the
+    tuple of their keys, any other walk is the plain scan. With ``acc=None``
+    the sink only records: a noted walk returns zeros without reading its
+    leaves, so that any other use of one shows in the traced jaxpr. With
+    ``acc`` (key -> accumulator leaf, an input of the differentiation) a
+    noted walk adds each layer's weight gradient into the accumulator inside
+    the backward layer loop and hands the sum back as the accumulator's
+    cotangent."""
+
+    def __init__(self, leaves: Dict[int, int],
+                 acc: Optional[Dict[int, jax.Array]] = None):
+        self.leaves = leaves
+        self.acc = acc
+        self.walks: List[Tuple[int, ...]] = []
+
+    def __enter__(self):
+        self._token = _SINK.set(self)
+        return self
+
+    def __exit__(self, *exc):
+        _SINK.reset(self._token)
+
+
+_SINK: contextvars.ContextVar[Optional[BlockGradSink]] = \
+    contextvars.ContextVar("tepdist_block_grad_sink", default=None)
+
+
+def scan_blocks(body, x, blocks):
+    """``jax.lax.scan(jax.checkpoint(body), x, blocks)``: ``body(h, block)
+    -> (h, y)`` over blocks stacked on a leading layer dim, every block
+    rematerialised in full in the backward pass. Returns ``(x, ys)``.
+
+    Under a :class:`BlockGradSink` that holds accumulators for ``blocks``
+    (``parallel/sync_free.py:build_ga_step`` with several micro batches) the
+    backward pass is written out: a reverse scan that recomputes the block
+    under ``jax.vjp`` from its saved input, carries ``(dx, accumulators)``
+    and adds layer ``l``'s weight gradient into slice ``l`` in place, so the
+    stacked gradient of a micro batch is never built. Same values as the
+    plain scan's gradient added to the accumulator afterwards: the layer's
+    gradient is rounded to its dtype, then the sum to the accumulator's."""
+    sink = _SINK.get()
+    leaves = jax.tree_util.tree_leaves(blocks)
+    keys = () if sink is None else tuple(
+        sink.leaves.get(id(a)) for a in leaves)
+    if keys and None not in keys and sink.acc is not None:
+        sink.walks.append(keys)
+        acc = jax.tree_util.tree_unflatten(
+            jax.tree_util.tree_structure(blocks), [sink.acc[k] for k in keys])
+        return _walk_accumulating(body, x, blocks, acc)
+    if keys and None not in keys:
+        # Recording. A body that closes over a traced value cannot be
+        # differentiated by hand; that walk stays the plain scan.
+        def aval(a, drop=0):
+            return jax.ShapeDtypeStruct(a.shape[drop:], a.dtype)
+
+        closed, (h, ys) = jax.make_jaxpr(body, return_shape=True)(
+            aval(x), jax.tree_util.tree_map(lambda a: aval(a, 1), blocks))
+        if not any(isinstance(c, jax.core.Tracer) for c in closed.consts):
+            sink.walks.append(keys)
+            n = leaves[0].shape[0]
+            return jnp.zeros(h.shape, h.dtype), jax.tree_util.tree_map(
+                lambda y: jnp.zeros((n,) + y.shape, y.dtype), ys)
+    return jax.lax.scan(jax.checkpoint(body), x, blocks)
+
+
+def _walk_accumulating(body, x, blocks, acc):
+    @jax.custom_vjp
+    def walk(x, blocks, acc):
+        del acc
+        return jax.lax.scan(body, x, blocks)
+
+    def fwd(x, blocks, acc):
+        def step(h, block):
+            out, y = body(h, block)
+            return out, (h, y)
+
+        out, (inputs, ys) = jax.lax.scan(step, x, blocks)
+        return (out, ys), (inputs, blocks, acc)
+
+    def bwd(res, cts):
+        inputs, blocks, acc = res
+        d_out, d_ys = cts
+        layers = jnp.arange(inputs.shape[0])
+
+        def step(carry, per_layer):
+            dh, acc = carry
+            layer, h, block, d_y = per_layer
+            _, pull = jax.vjp(body, h, block)
+            dh, d_block = pull((dh, d_y))
+            acc = jax.tree_util.tree_map(
+                lambda a, g: jax.lax.dynamic_update_index_in_dim(
+                    a, jax.lax.dynamic_index_in_dim(a, layer, keepdims=False)
+                    + g.astype(a.dtype), layer, 0),
+                acc, d_block)
+            return (dh, acc), None
+
+        (dx, acc), _ = jax.lax.scan(
+            step, (d_out, acc), (layers, inputs, blocks, d_ys), reverse=True)
+        return dx, None, acc
+
+    walk.defvjp(fwd, bwd)
+    return walk(x, blocks, acc)
 
 
 def rms_norm(x, g, eps: float = 1e-5):

@@ -40,7 +40,12 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from tepdist_tpu.models.layers import cross_entropy, rms_norm, rope
+from tepdist_tpu.models.layers import (
+    cross_entropy,
+    rms_norm,
+    rope,
+    scan_blocks,
+)
 from tepdist_tpu.ops.grouped_matmul import combine, dispatch, route
 from tepdist_tpu.ops.pallas.grouped_matmul import grouped_matmul
 
@@ -239,12 +244,13 @@ def hidden_states(params, tokens, cfg: OlmoeConfig):
         h, lb, zl = block(blk, h, cfg)
         return h, (lb, zl)
 
-    if cfg.remat:
-        body = jax.checkpoint(body)
     if "blocks" in params:
-        x, aux = jax.lax.scan(body, x, params["blocks"])
+        walk = scan_blocks if cfg.remat else jax.lax.scan
+        x, aux = walk(body, x, params["blocks"])
         lb, zl = (a.mean() for a in aux)
     else:
+        if cfg.remat:
+            body = jax.checkpoint(body)
         aux = []
         for i in range(cfg.num_hidden_layers):
             x, layer_aux = body(x, params[f"l{i}"])

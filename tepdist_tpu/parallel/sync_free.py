@@ -24,6 +24,7 @@ graph and sizes the micro-batch count from the activation-memory estimate
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import logging
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -36,9 +37,11 @@ from jax.extend import core as jexcore
 from tepdist_tpu.core.service_env import ServiceEnv
 from tepdist_tpu.graph.cost import aval_bytes
 from tepdist_tpu.graph.jaxpr_graph import JaxprGraph
+from tepdist_tpu.models.layers import BlockGradSink
 from tepdist_tpu.parallel.performance_utils import chip_spec
 from tepdist_tpu.parallel.strategy_utils import StrategyUtil
 from tepdist_tpu.core.dist_spec import DimStrategy
+from tepdist_tpu.telemetry import metrics
 
 Var = jexcore.Var
 log = logging.getLogger(__name__)
@@ -224,6 +227,42 @@ def zero_pad_params(params, zero_dp: int):
         lambda p: _zero_pad_flat(p, zero_dp), params)
 
 
+def walked_leaves(loss_fn: Callable, params, *batch) -> Tuple[int, ...]:
+    """Flat indices of the leaves of ``params`` whose gradient a GA step may
+    accumulate inside the loss's own layer loop: those ``loss_fn(params,
+    *batch)`` hands to ``models/layers.py:scan_blocks`` as they are (not
+    through a ``jit`` or ``checkpoint`` of the whole loss, not cast or
+    sliced), once, and uses nowhere else. Found by tracing the loss once at
+    the shapes given, with every such walk stubbed out."""
+    leaves, treedef = jax.tree_util.tree_flatten(params)
+    walks: List[Tuple[int, ...]] = []
+
+    def probe(leaves, *batch):
+        with BlockGradSink({id(a): i for i, a in enumerate(leaves)}) as sink:
+            loss = loss_fn(treedef.unflatten(leaves), *batch)
+        walks.extend(sink.walks)
+        return loss
+
+    def shapes(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+
+    jaxpr = jax.make_jaxpr(probe)(shapes(leaves), *shapes(batch)).jaxpr
+    used = {v for eqn in jaxpr.eqns for v in eqn.invars if isinstance(v, Var)}
+    used.update(v for v in jaxpr.outvars if isinstance(v, Var))
+    times = collections.Counter(i for walk in walks for i in walk)
+    whole = [walk for walk in walks if all(
+        times[i] == 1 and jaxpr.invars[i] not in used for i in walk)]
+    return tuple(sorted(i for walk in whole for i in walk))
+
+
+def _report_ga_bytes(fused: int, unfused: int) -> None:
+    """How the step being built accumulates its parameters' gradients:
+    bytes added inside the layer loop / by the tree-wide add."""
+    metrics().gauge("ga_fused_bytes").set(fused)
+    metrics().gauge("ga_unfused_bytes").set(unfused)
+
+
 def build_ga_step(
     grad_fn: Callable,
     apply_fn: Callable,
@@ -233,6 +272,7 @@ def build_ga_step(
     comm_dtype: str = "",
     zero_dp: int = 0,
     zero_axis_name: str = "",
+    loss_fn: Optional[Callable] = None,
 ) -> Callable:
     """Construct the sync-free GA training step (reference decomposition
     ENTRY -> {GAINIT, CG, GA, AG} as one scanned program).
@@ -263,6 +303,17 @@ def build_ga_step(
         The single-jit SPMD path does NOT use this: there the planner
         realizes ZeRO by sharding the optimizer-state invars and GSPMD
         emits the equivalent collectives (auto_parallel ``zero_invars``).
+
+      loss_fn: the loss ``grad_fn`` is ``jax.value_and_grad`` of. Given,
+        and with nothing compressing a micro batch's gradient, the step
+        differentiates it itself so that the stacked blocks it walks with
+        ``models/layers.py:scan_blocks`` get their gradients added into the
+        accumulator inside the backward layer loop (:func:`walked_leaves`
+        finds them by tracing the loss once); the other leaves keep
+        ``acc + g``, and a loss that walks nothing keeps ``grad_fn`` and
+        the tree-wide add. Same values either way. The gauges
+        ``ga_fused_bytes`` / ``ga_unfused_bytes`` say how the parameter
+        bytes split.
 
     Returns ``step(params, opt_state, *batch) -> (mean_loss, params, opt)``.
     """
@@ -327,6 +378,8 @@ def build_ga_step(
             if jnp.issubdtype(x.dtype, jnp.floating) else x, g)
 
     if num_micro_batches <= 1:
+        _report_ga_bytes(0, 0)
+
         def step1(params, opt_state, *batch):
             loss, grads = grad_fn(params, *batch)
             if int8 or compress:
@@ -359,6 +412,15 @@ def build_ga_step(
         # GAInit: zero accumulators shaped like the gradients (fp32 even
         # under FP16_COMM: only the per-micro contributions are compressed).
         acc0 = jax.tree_util.tree_map(jnp.zeros_like, params)
+        leaves, treedef = jax.tree_util.tree_flatten(params)
+        walked = ()
+        if loss_fn is not None and not (int8 or compress):
+            walked = walked_leaves(
+                loss_fn, params, *(mb[0] for mb in micro_batches))
+        rest = [i for i in range(len(leaves)) if i not in walked]
+        fused_bytes = sum(aval_bytes(leaves[i]) for i in walked)
+        _report_ga_bytes(fused_bytes, sum(
+            aval_bytes(a) for a in leaves) - fused_bytes)
 
         def body(carry, xs):  # CG + GA
             micro_index, mb = xs
@@ -369,9 +431,42 @@ def build_ga_step(
                 lambda a, g: a + g.astype(a.dtype), acc, grads)
             return (acc, loss_sum + loss), None
 
+        def body_walked(carry, xs):
+            """``body`` with the walked leaves' gradients added to their
+            accumulators inside ``scan_blocks``' backward layer loop: the
+            accumulators go into the differentiation and come out of it as
+            their own cotangents; the walked parameters are closed over."""
+            _, mb = xs
+            acc, loss_sum = carry
+            acc = treedef.flatten_up_to(acc)
+
+            def loss_of(rest_leaves, walked_acc):
+                merged = list(leaves)
+                for i, leaf in zip(rest, rest_leaves):
+                    merged[i] = leaf
+                sink = BlockGradSink({id(leaves[i]): i for i in walked},
+                                     dict(zip(walked, walked_acc)))
+                with sink:
+                    loss = loss_fn(treedef.unflatten(merged), *mb)
+                if sorted(k for w in sink.walks for k in w) != list(walked):
+                    raise RuntimeError(
+                        "the loss walked other stacked blocks than when it "
+                        "was first traced; their gradients would be lost")
+                return loss
+
+            loss, (grads, walked_acc) = jax.value_and_grad(
+                loss_of, argnums=(0, 1))(
+                    [leaves[i] for i in rest], [acc[i] for i in walked])
+            for i, g in zip(rest, grads):
+                acc[i] = acc[i] + g.astype(acc[i].dtype)
+            for i, a in zip(walked, walked_acc):
+                acc[i] = a
+            return (treedef.unflatten(acc), loss_sum + loss), None
+
         micro_index = jnp.arange(num_micro_batches, dtype=jnp.uint32)
         (acc, loss_sum), _ = lax.scan(
-            body, (acc0, jnp.zeros(())), (micro_index, micro_batches))
+            body_walked if walked else body, (acc0, jnp.zeros(())),
+            (micro_index, micro_batches))
         inv = 1.0 / num_micro_batches
         grads = jax.tree_util.tree_map(lambda g: g * inv, acc)
         # AG: apply-gradients slice (or the ZeRO RS->apply->AG update).

@@ -532,7 +532,8 @@ class TepdistServicer:
 
         step_fn = build_ga_step(
             grad_fn, apply_fn, num_micro_batches,
-            batch_argnums=tuple(range(1, 1 + len(batch_sds))))
+            batch_argnums=tuple(range(1, 1 + len(batch_sds))),
+            loss_fn=loss_fn)
         opt_sds = jax.eval_shape(optimizer.init, params_sds)
         n_server_state = len(params_sds) + len(
             jax.tree_util.tree_leaves(opt_sds))

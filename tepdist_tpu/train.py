@@ -386,7 +386,7 @@ def plan_training(
     step_fn = build_ga_step(
         grad_fn, apply_fn, num_micro_batches,
         batch_argnums=tuple(range(1, 1 + n_batch_args)),
-        comm_dtype=comm_dtype)
+        comm_dtype=comm_dtype, loss_fn=loss_fn)
 
     if topology is None:
         n = len(devices)
@@ -412,6 +412,11 @@ def plan_training(
         step_fn, topology, params, opt_state, *example_batch,
         annotations=annotations, mode=mode, state_alias=state_alias,
         var_mem_limit=var_mem_limit, zero_invars=zero_invars)
+    # Set while auto_parallel traced the step (sync_free.build_ga_step).
+    log.info("gradient accumulation: %.0f parameter bytes added inside the "
+             "layer loop, %.0f by the tree-wide add (%d micro batches)",
+             metrics().gauge("ga_fused_bytes").value,
+             metrics().gauge("ga_unfused_bytes").value, num_micro_batches)
     if (len(devices) > 1 and not plan.sharding_plan.constraints
             and not any(ax for spec in plan.sharding_plan.in_specs
                         for ax in spec)
