@@ -13,13 +13,6 @@
 #   2. FENCE + TORN TAIL: the targeted pytest half — a stale-epoch verb
 #      is rejected with zero worker mutation, and a WAL torn mid-append
 #      replays to at most one step early and still resumes bit-exactly.
-#   3. WAL COST: tools/obs_overhead.py measures wal_overhead_pct on the
-#      two-worker fleet step (null-calibrated A/B); the <=1% gate must
-#      be GREEN — crash safety that taxes the step path is a regression.
-#   4. PERF GATE: master_recover_ms and wal_overhead_pct are recorded
-#      three times to build a rolling baseline, then --check must pass
-#      on the real values and MUST fail on a seeded 50% recovery
-#      regression (the gate actually trips on the new key).
 #
 # Override the per-pass bound with CONTROLPLANE_SMOKE_TIMEOUT (seconds).
 set -euo pipefail
@@ -29,50 +22,21 @@ TIMEOUT="${CONTROLPLANE_SMOKE_TIMEOUT:-600}"
 TMPDIR_SMOKE="$(mktemp -d)"
 trap 'rm -rf "$TMPDIR_SMOKE"' EXIT
 
-echo "=== controlplane smoke 1/4: SIGKILL the master, readopt the fleet ==="
+echo "=== controlplane smoke 1/2: SIGKILL the master, readopt the fleet ==="
 OUT="$TMPDIR_SMOKE/chaos.log"
 timeout -k 10 "$TIMEOUT" env JAX_PLATFORMS=cpu python tools/chaos_run.py \
     --steps 8 --kill-master 3 | tee "$OUT"
 
-RECOVER="$(grep -oE 'master_recover_ms=[0-9.]+' "$OUT" | cut -d= -f2)"
-if [ -z "$RECOVER" ]; then
-    echo "controlplane smoke: FAIL (no master_recover_ms line to record)"
+if ! grep -qE 'master_recover_ms=[0-9.]+' "$OUT"; then
+    echo "controlplane smoke: FAIL (no master_recover_ms line)"
     exit 1
 fi
 
-echo "=== controlplane smoke 2/4: epoch fence + torn WAL tail ==="
+echo "=== controlplane smoke 2/2: epoch fence + torn WAL tail ==="
 timeout -k 10 "$TIMEOUT" env JAX_PLATFORMS=cpu python -m pytest -q \
     -p no:cacheprovider \
     tests/test_controlplane_session.py::test_stale_epoch_rejected_without_mutation \
     tests/test_controlplane_session.py::test_readopt_tolerates_torn_wal_tail \
     tests/test_controlplane.py
-
-echo "=== controlplane smoke 3/4: WAL cost on the step path (<=1%) ==="
-timeout -k 10 "$TIMEOUT" env JAX_PLATFORMS=cpu python tools/obs_overhead.py \
-    --skip-trace --skip-ledger --skip-flight --skip-watch --check \
-    --out "$TMPDIR_SMOKE/wal_cost.json"
-WALPCT="$(python -c "import json,sys;
-r=[x for x in json.load(open('$TMPDIR_SMOKE/wal_cost.json'))['extra']
-   if x.get('metric')=='wal_overhead_pct'];
-print(r[0]['value'] if r else '')")"
-
-echo "=== controlplane smoke 4/4: perf gate on master_recover_ms ==="
-HIST="$TMPDIR_SMOKE/bench_history.jsonl"
-for i in 1 2 3; do
-    timeout -k 10 "$TIMEOUT" python tools/perf_gate.py --history "$HIST" \
-        --record-value "master_recover_ms=$RECOVER" \
-        --record-value "wal_overhead_pct=$WALPCT" > /dev/null
-done
-timeout -k 10 "$TIMEOUT" python tools/perf_gate.py --history "$HIST" \
-    --check --keys master_recover_ms,wal_overhead_pct \
-    --record-value "master_recover_ms=$RECOVER" \
-    --record-value "wal_overhead_pct=$WALPCT"
-if timeout -k 10 "$TIMEOUT" python tools/perf_gate.py --history "$HIST" \
-    --check --keys master_recover_ms \
-    --record-value "master_recover_ms=$RECOVER" \
-    --seed-regression master_recover_ms:50; then
-    echo "controlplane smoke: FAIL (seeded 50% recovery regression did not trip)"
-    exit 1
-fi
 
 echo "controlplane smoke: PASS"

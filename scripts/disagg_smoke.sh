@@ -9,10 +9,6 @@
 #      BOTH pools zero pages remain allocated.
 #   2. LOAD + METRICS: tools/serve_load.py --disagg 1:1 completes a
 #      request mix and emits disagg_ttft_ms / kv_handoff_ms in --out.
-#   3. PERF GATE: both keys are recorded three times to build a rolling
-#      baseline, then --check must pass on the real values and MUST
-#      fail on a seeded 30% kv_handoff_ms regression (the gate actually
-#      trips on the new keys).
 #
 # Override the per-pass bound with DISAGG_SMOKE_TIMEOUT (seconds).
 set -euo pipefail
@@ -22,7 +18,7 @@ TIMEOUT="${DISAGG_SMOKE_TIMEOUT:-600}"
 TMPDIR_SMOKE="$(mktemp -d)"
 trap 'rm -rf "$TMPDIR_SMOKE"' EXIT
 
-echo "=== disagg smoke 1/3: 1P/1D handoff bit-identity + zero leak ==="
+echo "=== disagg smoke 1/2: 1P/1D handoff bit-identity + zero leak ==="
 timeout -k 10 "$TIMEOUT" env JAX_PLATFORMS=cpu python - <<'EOF'
 import jax
 import numpy as np
@@ -66,7 +62,7 @@ print(f"disagg smoke: bit-identical x{len(prompts)}, "
       f"{moved} live pages moved, 0 leaked")
 EOF
 
-echo "=== disagg smoke 2/3: serve_load --disagg 1:1 ==="
+echo "=== disagg smoke 2/2: serve_load --disagg 1:1 ==="
 SERVE="$TMPDIR_SMOKE/serve.json"
 timeout -k 10 "$TIMEOUT" env JAX_PLATFORMS=cpu python tools/serve_load.py \
     --disagg 1:1 --workers 2 --requests 8 --out "$SERVE"
@@ -80,20 +76,5 @@ for k in ("disagg_ttft_ms", "kv_handoff_ms"):
 print(f"serve_load: disagg_ttft_ms={s['disagg_ttft_ms']} "
       f"kv_handoff_ms={s['kv_handoff_ms']} leaked=0")
 EOF
-
-echo "=== disagg smoke 3/3: perf gate on disagg_ttft_ms/kv_handoff_ms ==="
-HIST="$TMPDIR_SMOKE/bench_history.jsonl"
-for i in 1 2 3; do
-    timeout -k 10 "$TIMEOUT" python tools/perf_gate.py --history "$HIST" \
-        --serve-json "$SERVE" > /dev/null
-done
-timeout -k 10 "$TIMEOUT" python tools/perf_gate.py --history "$HIST" \
-    --check --keys disagg_ttft_ms,kv_handoff_ms --serve-json "$SERVE"
-if timeout -k 10 "$TIMEOUT" python tools/perf_gate.py --history "$HIST" \
-    --check --keys disagg_ttft_ms,kv_handoff_ms --serve-json "$SERVE" \
-    --seed-regression kv_handoff_ms:30; then
-    echo "disagg smoke: FAIL (seeded 30% handoff regression did not trip)"
-    exit 1
-fi
 
 echo "disagg smoke: PASS"

@@ -2,17 +2,13 @@
 # Elastic smoke: prove the ISSUE-18 live-migration contract end to end
 # on real worker subprocesses — run it locally or as a CI step.
 #
-#   1. KILL MID-RUN: tools/chaos_run.py --kill-worker SIGKILLs a gRPC
-#      worker subprocess mid-run; the session must complete on the
-#      reshaped mesh via exactly ONE live migration (no checkpoint
-#      rollback) with the loss trajectory matching the undisturbed
-#      reference, the watchtower migration alert lifecycle must fire
-#      (migrations_started counter), and the run prints the
-#      machine-readable migration_stall_ms= line.
-#   2. PERF GATE: migration_stall_ms is recorded three times to build a
-#      rolling baseline, then --check must pass on the real value and
-#      MUST fail on a seeded 50% stall regression (the gate actually
-#      trips on the new key).
+#   KILL MID-RUN: tools/chaos_run.py --kill-worker SIGKILLs a gRPC
+#   worker subprocess mid-run; the session must complete on the
+#   reshaped mesh via exactly ONE live migration (no checkpoint
+#   rollback) with the loss trajectory matching the undisturbed
+#   reference, the watchtower migration alert lifecycle must fire
+#   (migrations_started counter), and the run prints the
+#   machine-readable migration_stall_ms= line.
 #
 # Override the per-pass bound with ELASTIC_SMOKE_TIMEOUT (seconds).
 set -euo pipefail
@@ -22,7 +18,7 @@ TIMEOUT="${ELASTIC_SMOKE_TIMEOUT:-600}"
 TMPDIR_SMOKE="$(mktemp -d)"
 trap 'rm -rf "$TMPDIR_SMOKE"' EXIT
 
-echo "=== elastic smoke 1/2: SIGKILL a worker mid-run, live-migrate ==="
+echo "=== elastic smoke: SIGKILL a worker mid-run, live-migrate ==="
 OUT="$TMPDIR_SMOKE/chaos.log"
 timeout -k 10 "$TIMEOUT" env JAX_PLATFORMS=cpu python tools/chaos_run.py \
     --steps 6 --kill-worker 3 | tee "$OUT"
@@ -31,26 +27,8 @@ if ! grep -qE 'migrations_started\s+1' "$OUT"; then
     echo "elastic smoke: FAIL (watchtower migration alert never fired)"
     exit 1
 fi
-STALL="$(grep -oE 'migration_stall_ms=[0-9.]+' "$OUT" | cut -d= -f2)"
-if [ -z "$STALL" ]; then
-    echo "elastic smoke: FAIL (no migration_stall_ms line to record)"
-    exit 1
-fi
-
-echo "=== elastic smoke 2/2: perf gate on migration_stall_ms ==="
-HIST="$TMPDIR_SMOKE/bench_history.jsonl"
-for i in 1 2 3; do
-    timeout -k 10 "$TIMEOUT" python tools/perf_gate.py --history "$HIST" \
-        --record-value "migration_stall_ms=$STALL" > /dev/null
-done
-timeout -k 10 "$TIMEOUT" python tools/perf_gate.py --history "$HIST" \
-    --check --keys migration_stall_ms \
-    --record-value "migration_stall_ms=$STALL"
-if timeout -k 10 "$TIMEOUT" python tools/perf_gate.py --history "$HIST" \
-    --check --keys migration_stall_ms \
-    --record-value "migration_stall_ms=$STALL" \
-    --seed-regression migration_stall_ms:50; then
-    echo "elastic smoke: FAIL (seeded 50% stall regression did not trip)"
+if ! grep -qE 'migration_stall_ms=[0-9.]+' "$OUT"; then
+    echo "elastic smoke: FAIL (no migration_stall_ms line)"
     exit 1
 fi
 

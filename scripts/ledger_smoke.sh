@@ -1,5 +1,5 @@
 #!/usr/bin/env bash
-# Ledger + perf-gate smoke: prove the PR-9 observability pipeline end to
+# Ledger smoke: prove the PR-9 observability pipeline end to
 # end on the two-worker in-proc fleet fixture.
 #
 #   1. GAP TABLE + RECONCILE: tools/ledger_report.py runs the fixture
@@ -10,15 +10,6 @@
 #      wall reconcile with the independent fidelity attribution.
 #   2. TRACE SECTIONS: the dumped trace renders ledger + flight sections
 #      through tools/trace_summary.py (self-contained trace file).
-#   3. PERF GATE: three recordings of the report's fleet step time build
-#      a rolling baseline; --check passes on the real value and MUST
-#      fail on a seeded 20% slowdown (the gate actually trips).
-#   4. OVERHEAD WATCHLIST (ISSUE 16): tools/obs_overhead.py measures the
-#      enabled-path cost of all four instruments; --check fails unless
-#      every gate is GREEN (ledger <= 2% of the fleet step, trace
-#      <= 600 ns/span, flight <= 2% of a serving burst). Its records
-#      build a second rolling baseline and a seeded 20% ledger-overhead
-#      regression MUST trip the gate.
 #
 # Override the per-pass bound with LEDGER_SMOKE_TIMEOUT (seconds).
 set -euo pipefail
@@ -28,60 +19,18 @@ TIMEOUT="${LEDGER_SMOKE_TIMEOUT:-600}"
 TMPDIR_SMOKE="$(mktemp -d)"
 trap 'rm -rf "$TMPDIR_SMOKE"' EXIT
 
-echo "=== ledger smoke 1/4: gap table + fidelity reconcile ==="
+echo "=== ledger smoke 1/2: gap table + fidelity reconcile ==="
 # Coverage floor 0.93 here (acceptance asks 0.95; a loaded 1-core CI
 # host occasionally lands 93-95% on the tail of the unattributed
 # scheduler noise — the bucket-sum identity and reconcile stay exact).
 timeout -k 10 "$TIMEOUT" env JAX_PLATFORMS=cpu python tools/ledger_report.py \
     --steps 6 --check --min-coverage 0.93 \
     --dump-trace "$TMPDIR_SMOKE/fleet_trace.json" \
-    --json > "$TMPDIR_SMOKE/ledger_report.json"
+    --json > /dev/null
 
-echo "=== ledger smoke 2/4: trace-file ledger + flight sections ==="
+echo "=== ledger smoke 2/2: trace-file ledger + flight sections ==="
 timeout -k 10 "$TIMEOUT" env JAX_PLATFORMS=cpu python tools/trace_summary.py \
     "$TMPDIR_SMOKE/fleet_trace.json" > "$TMPDIR_SMOKE/summary.txt"
 grep -q "rpc ledger" "$TMPDIR_SMOKE/summary.txt"
-
-echo "=== ledger smoke 3/4: perf gate trips on a seeded regression ==="
-HIST="$TMPDIR_SMOKE/bench_history.jsonl"
-FLEET_MS="$(python - "$TMPDIR_SMOKE/ledger_report.json" <<'PY'
-import json, sys
-print(json.load(open(sys.argv[1]))["fleet_step_ms"])
-PY
-)"
-for i in 1 2 3; do
-    timeout -k 10 "$TIMEOUT" python tools/perf_gate.py --history "$HIST" \
-        --record-value "two_worker_fleet_ms=$FLEET_MS" > /dev/null
-done
-timeout -k 10 "$TIMEOUT" python tools/perf_gate.py --history "$HIST" \
-    --check --keys two_worker_fleet_ms \
-    --record-value "two_worker_fleet_ms=$FLEET_MS"
-if timeout -k 10 "$TIMEOUT" python tools/perf_gate.py --history "$HIST" \
-    --check --keys two_worker_fleet_ms \
-    --record-value "two_worker_fleet_ms=$FLEET_MS" \
-    --seed-regression two_worker_fleet_ms:20; then
-    echo "ledger smoke: FAIL (seeded 20% regression did not trip the gate)"
-    exit 1
-fi
-
-echo "=== ledger smoke 4/4: always-on overhead watchlist ==="
-OBS="$TMPDIR_SMOKE/obs_overhead.json"
-OBS_HIST="$TMPDIR_SMOKE/obs_history.jsonl"
-OBS_KEYS="ledger_overhead_pct,trace_enabled_ns_per_span,flight_overhead_pct"
-timeout -k 10 "$TIMEOUT" env JAX_PLATFORMS=cpu python tools/obs_overhead.py \
-    --check --out "$OBS"
-for i in 1 2 3; do
-    timeout -k 10 "$TIMEOUT" python tools/perf_gate.py --history "$OBS_HIST" \
-        --record "$OBS" > /dev/null
-done
-timeout -k 10 "$TIMEOUT" python tools/perf_gate.py --history "$OBS_HIST" \
-    --check --keys "$OBS_KEYS" --record "$OBS"
-if timeout -k 10 "$TIMEOUT" python tools/perf_gate.py --history "$OBS_HIST" \
-    --check --keys "$OBS_KEYS" --record "$OBS" \
-    --seed-regression ledger_overhead_pct:20; then
-    echo "ledger smoke: FAIL (seeded 20% ledger-overhead regression did" \
-         "not trip the gate)"
-    exit 1
-fi
 
 echo "ledger smoke: PASS"

@@ -40,8 +40,8 @@ Violations raise :class:`PlanVerificationError` (a typed
 ids. The gate is wired into ``PipelineExecutable`` (the explore-winner
 build path), ``DistributedPipelineSession`` (fleet dispatch) and
 ``LoadServable`` (serving), behind the ``TEPDIST_VERIFY_PLAN`` knob — on
-by default under pytest, cheap enough to leave on anywhere
-(``bench.py``'s ``plan_verify_ms`` line proves ≪1% of plan time).
+by default under pytest; off it is a no-op
+(``tests/test_plan_verify.py::test_gate_is_a_noop_when_disabled``).
 """
 
 from __future__ import annotations

@@ -1,7 +1,7 @@
 """Placement of JAX's persistent compilation cache.
 
 Called from process entry points only (the server binary, ``chip_smoke.py``
-children, ``bench.py`` and the example mains) — never on import, so a
+children, ``benchmark/run.py`` and the example mains) — never on import, so a
 library user and the test suite keep whatever they configured.
 
 The directory is part of a cache entry's key, so it must not move between

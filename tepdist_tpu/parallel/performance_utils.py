@@ -44,9 +44,9 @@ TPU_CHIPS: Dict[str, TpuChipSpec] = {
 
 
 # ``jax.Device.device_kind`` of each chip in the table. The planner prices
-# with the generation TPU_GENERATION names; measurement code (chip_smoke.py,
-# bench.py) looks the attached device up here so that a peak is never
-# assumed for a chip the table does not describe.
+# with the generation TPU_GENERATION names; measurement code (chip_smoke.py)
+# looks the attached device up here so that a peak is never assumed for a
+# chip the table does not describe.
 DEVICE_KIND_GENERATION: Dict[str, str] = {
     "TPU v4": "v4",
     "TPU v5 lite": "v5e",

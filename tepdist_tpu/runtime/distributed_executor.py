@@ -1172,8 +1172,7 @@ class DistributedPipelineSession:
                    include_predicted: bool = True):
         """Pull every worker's span buffer + metrics (GetTelemetry),
         clock-align them (NTP-midpoint offset from the round-trip), and
-        write ONE merged Perfetto-loadable timeline — the fleet view the
-        one-off fleet_overhead_probe reconstructed by hand. ``path=None``
+        write ONE merged Perfetto-loadable timeline. ``path=None``
         lands in ``$TEPDIST_DUMP_DIR``; returns the written path or None.
         Dead workers are skipped, not fatal. The simulator's predicted
         timeline rides in the trace metadata (``fidelity.predicted``) so

@@ -2,8 +2,8 @@
 
 Reference parity: NONE — the reference never checks its cost model
 against an execution. This module makes prediction-vs-reality a
-permanent observability surface (the analysis tools/
-fleet_overhead_probe.py once did by hand):
+permanent observability surface (the analysis a one-off probe once
+did by hand):
 
 * ``join_timelines`` — exact per-task join of the simulator's
   ``ScheduleResult.predicted_timeline()`` (runtime/task_scheduler.py)

@@ -40,8 +40,8 @@ sampled away is counted in the explicit ``sampled_out`` counter next to
 ring-overflow ``dropped``, and both ride through GetTelemetry into the
 merged-trace LOSSY warnings.
 
-Gating: ``TEPDIST_FLIGHT`` (default ON — enabled cost is gated by
-tools/obs_overhead.py ``flight_overhead_pct`` <= 2% on a serving burst)
+Gating: ``TEPDIST_FLIGHT`` (default ON; its enabled cost has not been
+measured on a chip, ROADMAP D8)
 with ``TEPDIST_FLIGHT_CAPACITY`` bounding per-thread ring memory. Same
 singleton/disabled-path contract as trace.py.
 """

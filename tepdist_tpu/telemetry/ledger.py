@@ -59,9 +59,8 @@ step wall against PR 6's fidelity attribution.
 
 Gating: ``TEPDIST_LEDGER`` (default off). Disabled cost is one module
 attribute load + one branch per hook (same contract as trace.py's
-``_NULL_SPAN``). Enabled cost is gated by tools/obs_overhead.py
-(``ledger_overhead_pct`` <= 2% of the fleet step, a perf_gate
-DEFAULT_KEYS watchlist entry). Ring capacity: ``TEPDIST_LEDGER_RING``
+``_NULL_SPAN``). Enabled cost has not been measured on a chip (ROADMAP
+D8). Ring capacity: ``TEPDIST_LEDGER_RING``
 records per writer thread; overflow drops oldest records and is exported
 per category in ``intervals_dropped`` (plus a ``records_dropped``
 total).

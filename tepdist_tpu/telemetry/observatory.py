@@ -19,8 +19,7 @@ This module makes every exploration an auditable, versioned artifact:
   MEASURED per-worker attribution from ``telemetry/fidelity.py``, so a
   plan choice is auditable against what actually ran.
 * ``diff_reports`` — compares two reports, flags winner flips, and
-  names the cost term that drove each flip (tools/plan_diff.py;
-  tools/perf_gate.py --plan-diff).
+  names the cost term that drove each flip (tools/plan_diff.py).
 
 The report is JSON on disk (``TEPDIST_PLAN_REPORT``), metadata in the
 merged trace (``metadata.exploration``, next to ``metadata.fidelity``),
@@ -527,7 +526,7 @@ def report_from_trace(trace: Dict[str, Any]) -> Optional[Dict[str, Any]]:
 
 
 # ----------------------------------------------------------------------
-# Report diffing (tools/plan_diff.py, perf_gate --plan-diff)
+# Report diffing (tools/plan_diff.py)
 # ----------------------------------------------------------------------
 
 def diff_reports(old: Dict[str, Any],

@@ -2,8 +2,8 @@
 
 Reference parity: NONE — the reference ships no tracing layer; its timing
 evidence is scattered ``VLOG`` lines. This module is the permanent home for
-the cross-worker step timeline that one-off probes (tools/
-fleet_overhead_probe.py) used to reconstruct by hand.
+the cross-worker step timeline that one-off probes used to reconstruct
+by hand.
 
 Design contract:
 
@@ -21,8 +21,9 @@ Design contract:
   captured once at construction (so cross-process buffers stay
   comparable after clock alignment, yet repeated snapshots of one span
   agree to the microsecond), and the thread name is cached per ring, not
-  looked up per span. Budget: <= 600 ns/span enabled, gated by
-  tools/obs_overhead.py (``trace_enabled_ns_per_span``).
+  looked up per span. The enabled cost has no gate; the disabled path
+  is held by ``tests/test_telemetry.py``
+  (``test_disabled_span_overhead_is_noop_sized``).
 * Rings are bounded (``TEPDIST_TRACE_CAPACITY`` spans per recording
   thread): old spans fall off the front and are counted in ``dropped`` —
   a lossy merged trace is misleading (missing tasks look like idle

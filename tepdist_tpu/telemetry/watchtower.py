@@ -13,11 +13,11 @@ elastic-autoscaling and multi-tenant items consume:
   counters. Polls cost O(new records), not O(ring capacity), and consume
   nothing — snapshots and the final trace dump still see everything.
 * **Straggler / anomaly detection** — per-worker rolling step-time and
-  RTT digests scored with the same robust statistics as tools/perf_gate
-  (median + MAD bands). A worker is a straggler when its rolling median
-  sits above the other workers' median plus ``max(3 * 1.4826 * MAD,
-  floor)`` for ``persist_polls`` consecutive polls — a one-poll GC pause
-  never pages. Fleet-shape changes (a worker stops answering, or
+  RTT digests scored with robust statistics (median + MAD bands,
+  ``tests/test_watchtower.py::test_median_and_mad_band``). A worker is
+  a straggler when its rolling median sits above the other workers'
+  median plus ``max(3 * 1.4826 * MAD, floor)`` for ``persist_polls``
+  consecutive polls — a one-poll GC pause never pages. Fleet-shape changes (a worker stops answering, or
   reappears) raise their own event.
 * **Training-health sentinels** — ``TrainingSentinel.observe(step,
   loss)`` runs inside the existing GA step at negligible cost (the loss
@@ -41,9 +41,8 @@ gauges (``watch_alert:<kind>``, ``slo_burn:<name>`` via the existing
 ``to_prometheus``), and the ``tools/watch.py`` live dashboard.
 
 Overhead posture: the sentinel is a few float compares per step; the
-poller thread does one delta RPC per worker per interval. Both are gated
-by tools/obs_overhead.py ``watch_overhead_pct`` <= 1% on the two-worker
-fleet step (perf_gate DEFAULT_KEYS watchlist, null-calibrated).
+poller thread does one delta RPC per worker per interval. Neither has a
+cost measured on a chip (ROADMAP D8).
 """
 
 from __future__ import annotations
@@ -66,7 +65,7 @@ _K_WINDOW = 7
 EXEC_VERBS = ("ExecuteStepSlice", "ExecuteRemotePlan", "ExecutePlan")
 
 
-# -- robust statistics (perf_gate's machinery, importable) ------------------
+# -- robust statistics ------------------------------------------------------
 
 def median(xs: List[float]) -> float:
     s = sorted(xs)
@@ -76,8 +75,7 @@ def median(xs: List[float]) -> float:
 
 
 def mad_band(xs: List[float], floor: float = 0.0, k: float = 3.0) -> float:
-    """Noise band over a sample: ``max(k * 1.4826 * MAD, floor)`` — the
-    same shape tools/perf_gate.py draws around its rolling baselines."""
+    """Noise band over a sample: ``max(k * 1.4826 * MAD, floor)``."""
     if not xs:
         return floor
     med = median(xs)

@@ -69,9 +69,8 @@ def test_gpt2_8layer_s4_tp2_exact(devices):
             np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-5),
         got, jax.device_get(pref))
 
-    # Steady-state step time, informational only — the pinned protocol's
-    # depth number comes from tools/bench_runtime.py, so one short
-    # post-warmup sample is enough here.
+    # Steady-state step time, informational only: one short post-warmup
+    # sample on the CPU mesh is enough here.
     t0 = time.perf_counter()
     for _ in range(2):
         exe.step(toks)

@@ -10,7 +10,7 @@ mover of the new-vs-old winner gap between the two runs),
 ``memory_feasible`` (a feasibility verdict changed), or
 ``candidate_set_change`` (a winner only exists in one report).
 
-Exit-code contract (scripts/explain_smoke.sh, perf_gate --plan-diff):
+Exit-code contract (scripts/explain_smoke.sh, tests/test_observatory.py):
 
 * ``--check``       exit 1 on ANY winner flip (identical runs diff empty);
 * ``--expect-flip`` exit 1 unless a flip WITH a named driver was found

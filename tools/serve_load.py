@@ -279,8 +279,7 @@ def main(argv=None) -> Dict[str, Any]:
                     help="dump the merged trace JSON here")
     ap.add_argument("--json", action="store_true")
     ap.add_argument("--out", default=None, metavar="FILE",
-                    help="also write the summary JSON here (the file "
-                         "tools/perf_gate.py --serve-json consumes)")
+                    help="also write the summary JSON here")
     args = ap.parse_args(argv)
     if args.shared_prefix + 2 > args.max_len:
         ap.error(f"--shared-prefix {args.shared_prefix} leaves no room "

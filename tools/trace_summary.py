@@ -10,10 +10,6 @@ telemetry/export.py) and prints:
   * a pipeline-bubble estimate per worker (1 - compute-busy / window),
     the quantity JaxPP-style pipeline claims are attributed with.
 
-This is the permanent CLI replacement for the one-off
-tools/fleet_overhead_probe.py analysis (the probe measured CPU cycles for
-one verdict; this reads any recorded timeline).
-
 Run: python tools/trace_summary.py TRACE.json [--json]
 """
 
