@@ -46,7 +46,12 @@ from tepdist_tpu.models.layers import (
     rope,
     scan_blocks,
 )
-from tepdist_tpu.ops.grouped_matmul import combine, dispatch, route
+from tepdist_tpu.ops.grouped_matmul import (
+    combine,
+    dispatch,
+    dispatch_values,
+    route,
+)
 from tepdist_tpu.ops.pallas.grouped_matmul import grouped_matmul
 
 
@@ -211,8 +216,7 @@ def moe(blk, x, cfg: OlmoeConfig):
     with jax.named_scope("moe_dispatch"):
         r = route(experts, E, cfg.moe_tile_m)
         rows = dispatch(h, r.row_token, r.dest)
-        row_weight = dispatch(weights.reshape(B * T * k, 1),
-                              r.row_assignment, r.dest.reshape(-1, 1))
+        row_weight = dispatch_values(weights, r)
     with jax.named_scope("moe_experts"):
         def gmm(a, w):
             return grouped_matmul(a, w, r.tile_group, r.n_tiles,
@@ -220,6 +224,7 @@ def moe(blk, x, cfg: OlmoeConfig):
         # The router's weight goes on the row before the down projection
         # (W (w a) = w (W a)): the projected rows then need no keeping for
         # the weight's gradient, 320 MiB a micro batch at the 1B-7B sizes.
+        # On a pad row it is exactly 0, as the row itself is.
         act = gated(gmm(rows, blk["w_gate"]), gmm(rows, blk["w_up"]),
                     row_weight)
         out_rows = gmm(act, blk["w_down"])

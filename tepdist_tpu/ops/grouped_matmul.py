@@ -1,21 +1,44 @@
 """Dropless token-choice dispatch for a routed expert layer.
 
-``route`` sorts the ``S x k`` (token, expert) assignments by expert and lays
-the sorted rows out **tile-aligned**: every expert's rows start at a
-multiple of ``tile_m`` and are padded to whole tiles (an expert nobody chose
-keeps one tile), which is the layout ``ops/pallas/grouped_matmul.py``
-multiplies without masks. No token is dropped whatever the skew; the static
-row count ``(ceil(S*k / tile_m) + E) * tile_m`` is the worst case, and the
-tiles past the live ones are skipped by the kernels. There is no
-``[S, E, C]`` tensor and no capacity.
+``route`` lays the ``S x k`` (token, expert) assignments out **tile-aligned**
+by expert: every expert's rows start at a multiple of ``tile_m`` and are
+padded to whole tiles (an expert nobody chose keeps one tile), which is the
+layout ``ops/pallas/grouped_matmul.py`` multiplies without masks. No token is
+dropped whatever the skew; the static row count
+``(ceil(S*k / tile_m) + E) * tile_m`` is the worst case, and the tiles past
+the live ones are skipped by the kernels. There is no ``[S, E, C]`` tensor
+and no capacity.
 
-``dispatch`` gathers each row's token (or, for the router's weights, each
-row's assignment); ``combine`` gathers each token's k rows and sums them.
-Each is the other's transpose, and each is the other's custom VJP: the
-transpose of a permutation is the inverse permutation, which ``route``
-already holds, while autodiff's scatter-adds of 2048-wide rows are the slow
-way around on a TPU. The index arithmetic uses two small sorts and gathers,
-no scatter.
+``dispatch`` gathers each row's token; ``combine`` gathers each token's k
+rows and sums them. Each is the other's transpose, and each is the other's
+custom VJP: the transpose of a permutation is the inverse permutation, which
+``route`` already holds, while autodiff's scatter-adds of 2048-wide rows are
+the slow way around on a TPU. ``dispatch_values`` carries one value an
+assignment (the router's weights) into the layout.
+
+**What a pad row holds: zeros, and nothing selects them.** ``dispatch`` (and
+``combine``'s backward) gather from the array with one zero row appended,
+and a pad's index names that row. A select over the gathered ``[rows, d]``
+array (``jnp.take(..., mode="fill")``) costs twice the gather it follows
+(1.02 ms against 0.52 at the OLMoE cell's sizes; PERF.md section 5), while
+the appended row costs a copy of the ``[S, d]`` source (0.014 ms). The copy
+also earns its keep: it is produced where it is used, so the compiler holds
+it in fast memory and the gather runs at 6.3 ns a row; the cotangent that
+the backward layer loop carries in HBM, gathered as it stands, took 34 ns a
+row (2.7 ms a call for 0.52). ``dispatch_values`` gives a pad row exactly 0
+too, so with the caller's ``act = silu(gate) * up * row_weight``
+(``models/olmoe.py``) a pad is zero on both sides of every sum over rows:
+the kernels' outputs on pads, each weight gradient's products, and the rows
+``combine`` and ``dispatch``'s backward never read (they go by ``dest``).
+
+The index path is sorts, not gathers: a gather of single 4-byte elements
+runs at 8 ns an element on a v5e (0.67 ms for the layout's 81,920 rows,
+whatever the size of the table) where a stable sort of 81,920 pairs takes
+0.08 ms (0.13 with two more operands riding).
+So the layout is one stable sort of the assignments together with
+``E x tile_m`` pad candidates, of which each expert uses as many as fill its
+last tile; its inverse is a second sort; and values ride into the layout, and
+their gradients out of it, through a sort by the same keys.
 """
 
 from __future__ import annotations
@@ -27,58 +50,81 @@ import jax.numpy as jnp
 
 
 class Routing(NamedTuple):
+    # A pad row names no token and no assignment: one past the last, which
+    # is the zero row ``dispatch`` appends to what it gathers from, and the
+    # zeros ``dispatch_values`` appends to what it sorts. So a pad holds
+    # zeros in the rows and in the router's weight, and no ``[M, d]`` array
+    # is ever masked (the module docstring's invariant).
     row_token: jax.Array        # [M] token of each row; S (out of range) = pad
     row_assignment: jax.Array   # [M] assignment (token * k + slot); S * k = pad
     dest: jax.Array             # [S, k] row of each assignment
     tile_group: jax.Array       # [M / tile_m] expert of each row tile
     n_tiles: jax.Array          # [1] live row tiles
     group_sizes: jax.Array      # [E] assignments per expert
+    sort_key: jax.Array         # [M] what sorts assignments, then pad
+    #                             candidates, into the layout (stable)
+
+
+def _sorted_by(key, value):
+    return jax.lax.sort((key, value), num_keys=1, is_stable=True)[1]
 
 
 def route(expert_ids, num_experts: int, tile_m: int) -> Routing:
     """The tile-aligned layout of ``expert_ids`` [S, k]."""
     S, k = expert_ids.shape
     A, E = S * k, num_experts
-    flat = expert_ids.reshape(A)
-    order = jnp.argsort(flat, stable=True)       # sorted place -> assignment
-    rank = jnp.argsort(order)                    # assignment -> sorted place
+    M = (-(-A // tile_m) + E) * tile_m
+    flat = expert_ids.reshape(A).astype(jnp.int32)
     group_sizes = jnp.sum(
         flat[:, None] == jnp.arange(E, dtype=flat.dtype), axis=0,
         dtype=jnp.int32)
     tiles = jnp.maximum(1, -(-group_sizes // tile_m))
     tile_end = jnp.cumsum(tiles)
-    row_start = (tile_end - tiles) * tile_m      # first row of each group
-    sort_start = jnp.cumsum(group_sizes) - group_sizes
-    dest = (row_start[flat] + rank - sort_start[flat]).reshape(S, k)
-
-    n_row_tiles = -(-A // tile_m) + E
+    # Every tile against every expert's last tile at once: a binary search
+    # is a loop of seven tiny operations on the device.
     tile_group = jnp.minimum(jnp.searchsorted(
-        tile_end, jnp.arange(n_row_tiles, dtype=jnp.int32), side="right"),
-        E - 1).astype(jnp.int32)
-    rows = jnp.arange(n_row_tiles * tile_m, dtype=jnp.int32)
-    g = tile_group[rows // tile_m]
-    offset = rows - row_start[g]
-    live = jnp.logical_and(offset < group_sizes[g],
-                           rows < tile_end[-1] * tile_m)
-    assignment = jnp.where(
-        live, order[jnp.clip(sort_start[g] + offset, 0, A - 1)], A)
+        tile_end, jnp.arange(M // tile_m, dtype=jnp.int32), side="right",
+        method="compare_all"), E - 1).astype(jnp.int32)
+
+    # Expert e's assignments sort under key 2e, the pads that fill its last
+    # tile under 2e + 1, and the candidates nobody needs (with the rows past
+    # the live tiles) under 2E: per-expert values meet the [E, tile_m]
+    # candidates by broadcast, never by a look-up a row.
+    pads = tiles * tile_m - group_sizes                       # 0 .. tile_m
+    experts = jnp.arange(E, dtype=jnp.int32)[:, None]
+    pad_key = jnp.where(
+        jnp.arange(tile_m, dtype=jnp.int32)[None, :] < pads[:, None],
+        2 * experts + 1, 2 * E)
+    sort_key = jnp.concatenate([
+        2 * flat, pad_key.reshape(E * tile_m),
+        jnp.full((M - A - E * tile_m,), 2 * E, jnp.int32)])
+    row_assignment = _sorted_by(sort_key, jnp.minimum(
+        jnp.arange(M, dtype=jnp.int32), A))
+    # The inverse permutation: the pads (all A) sort past the assignments.
+    dest = _sorted_by(row_assignment, jnp.arange(M, dtype=jnp.int32))[:A]
     return Routing(
-        row_token=(assignment // k).astype(jnp.int32),
-        row_assignment=assignment.astype(jnp.int32),
-        dest=dest.astype(jnp.int32), tile_group=tile_group,
-        n_tiles=tile_end[-1:].astype(jnp.int32), group_sizes=group_sizes)
+        row_token=row_assignment // k, row_assignment=row_assignment,
+        dest=dest.reshape(S, k), tile_group=tile_group,
+        n_tiles=tile_end[-1:].astype(jnp.int32), group_sizes=group_sizes,
+        sort_key=sort_key)
 
 
 def _rows(x, source):
     """x [S, d] -> [M, d]: row r is x[source[r]], zeros where the row is a
-    pad (its source out of range)."""
-    return jnp.take(x, source, axis=0, mode="fill", fill_value=0)
+    pad (its source S: the zero row appended here)."""
+    return jnp.pad(x, ((0, 1), (0, 0))).at[source].get(
+        mode="promise_in_bounds")
 
 
 def _sum_of_rows(y, dest):
     """y [M, d] -> [S, d]: the float32 sum of each token's rows, one of the
-    k gathers at a time (all k at once are an [S, k, d] float32 array,
-    512 MiB at the OLMoE cell's micro batch)."""
+    k gathers at a time. Fewer, wider gathers buy nothing: a row out of the
+    layout costs 34 ns however many a gather fetches (its source, 320 MiB,
+    stays in HBM; the rows into the layout come from a 32 MiB source the
+    compiler holds in fast memory, 6.3 ns a row), and the gathered
+    ``[k, S, d]`` array has to be read again to be summed: at the OLMoE
+    cell's sizes 2 gathers of 4 slots made the step 39.7 ms longer and 1 of
+    8 (256 MiB in bf16) 34.0 ms, of 1,940.6 (PERF.md section 6, PR 30)."""
     total = jnp.zeros((dest.shape[0], y.shape[1]), jnp.float32)
     for j in range(dest.shape[1]):
         total = total + y[dest[:, j]].astype(jnp.float32)
@@ -87,10 +133,9 @@ def _sum_of_rows(y, dest):
 
 @jax.custom_vjp
 def dispatch(x, source, dest):
-    """Into the tile-aligned layout: x [S, d] -> [M, d] by ``source`` [M];
-    ``dest`` [S, k] holds the rows that name each x row (``route``'s
-    ``row_token`` and ``dest`` for token rows; ``row_assignment`` and
-    ``dest.reshape(S * k, 1)`` for one value an assignment)."""
+    """Into the tile-aligned layout: x [S, d] -> [M, d] by ``source`` [M]
+    (``route``'s ``row_token``); ``dest`` [S, k] holds the rows that name
+    each x row. Pad rows are zero."""
     return _rows(x, source)
 
 
@@ -121,3 +166,27 @@ def _combine_bwd(source, g):
 
 
 combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+@jax.custom_vjp
+def dispatch_values(v, r: Routing):
+    """One value an assignment into the layout: v [S, k] -> [M, 1] by
+    ``r.sort_key``, **exactly 0 on every pad row**. The gradient of an
+    assignment's value is its row's (``r.row_assignment`` sorts the rows
+    back)."""
+    pads = jnp.zeros((r.sort_key.shape[0] - v.size,), v.dtype)
+    return _sorted_by(r.sort_key, jnp.concatenate([v.reshape(-1), pads]))[
+        :, None]
+
+
+def _dispatch_values_fwd(v, r):
+    return dispatch_values(v, r), (r.row_assignment, r.dest)
+
+
+def _dispatch_values_bwd(res, g):
+    row_assignment, dest = res
+    back = _sorted_by(row_assignment, g[:, 0])[:dest.size]
+    return back.reshape(dest.shape), None
+
+
+dispatch_values.defvjp(_dispatch_values_fwd, _dispatch_values_bwd)

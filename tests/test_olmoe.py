@@ -199,6 +199,108 @@ def test_layout_places_every_assignment_once_and_pads_with_zero_rows():
     assert np.asarray(r.tile_group)[int(r.n_tiles[0]) - 1] == 4
 
 
+@pytest.mark.parametrize("sizes, k", [
+    ((4, 8, 4), 2),          # every live tile full: no pad among them
+    ((7, 8), 3),             # one pad
+    ((5, 0, 9, 0), 2),       # experts nobody chose: whole tiles of pads
+])
+def test_pads_contribute_nothing(sizes, k):
+    """A pad row reads the zero row ``dispatch`` appends (nothing is masked,
+    and an index clamped into range would read the last token's row, large
+    here) and the router's weight of a pad is exactly 0: value and every
+    gradient of dispatch -> gate, up, gated activation, down -> combine are
+    those of a loop over each token's k experts."""
+    E, tile, K, N = len(sizes), 4, 16, 8
+    ids = jnp.asarray(np.random.default_rng(0).permutation(
+        np.repeat(np.arange(E), sizes)).reshape(-1, k), jnp.int32)
+    S = ids.shape[0]
+    r = gm.route(ids, E, tile)
+    pad = np.asarray(r.row_token) == S
+    assert pad[:int(r.n_tiles[0]) * tile].sum() == sum(
+        max(tile, -(-n // tile) * tile) - n for n in sizes)
+    keys = jax.random.split(jax.random.PRNGKey(1), 6)
+    x = jax.random.normal(keys[0], (S, K), jnp.float32).at[-1].mul(100.0)
+    weights = jax.random.uniform(keys[1], (S, k), jnp.float32, 0.1, 1.0)
+    w_gate, w_up = (jax.random.normal(key, (E, K, N), jnp.float32) * 0.3
+                    for key in keys[2:4])
+    w_down = jax.random.normal(keys[4], (E, N, K), jnp.float32) * 0.3
+    cot = jax.random.normal(keys[5], (S, K), jnp.float32)
+    args = (x, weights, w_gate, w_up, w_down)
+
+    def gmm(a, w):
+        return gmk.grouped_matmul(a, w, r.tile_group, r.n_tiles, tile)
+
+    def layer(x, weights, w_gate, w_up, w_down):
+        rows = gm.dispatch(x, r.row_token, r.dest)
+        act = olmoe.gated(gmm(rows, w_gate), gmm(rows, w_up),
+                          gm.dispatch_values(weights, r))
+        return gm.combine(gmm(act, w_down), r.row_token, r.dest)
+
+    def loop(x, weights, w_gate, w_up, w_down):
+        out = []
+        for s in range(S):
+            out.append(sum(
+                weights[s, j] * (jax.nn.silu(x[s] @ w_gate[ids[s, j]])
+                                 * (x[s] @ w_up[ids[s, j]]))
+                @ w_down[ids[s, j]] for j in range(k)))
+        return jnp.stack(out)
+
+    rows = np.asarray(gm.dispatch(x, r.row_token, r.dest))
+    assert not rows[pad].any() and rows[~pad].all()
+    assert not np.asarray(gm.dispatch_values(weights, r))[pad].any()
+    assert rel(layer(*args), loop(*args)) < TOL
+    for got, want in zip(
+            jax.grad(lambda *a: jnp.sum(layer(*a) * cot), range(5))(*args),
+            jax.grad(lambda *a: jnp.sum(loop(*a) * cot), range(5))(*args)):
+        assert rel(got, want) < TOL
+
+
+def _equations(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for held in value if isinstance(value, (list, tuple)) else [
+                    value]:
+                held = getattr(held, "jaxpr", held)
+                if hasattr(held, "eqns"):
+                    yield from _equations(held)
+
+
+@pytest.mark.parametrize("what", ["forward", "gradient"])
+def test_moe_has_no_wide_select_and_no_look_up_a_row(params, what):
+    """The expert layer masks no ``[rows, d]`` array, by a select or by a
+    gather that fills (a pad reads an appended zero row), and looks nothing
+    up row by row in a table of one entry an expert or a row tile."""
+    cfg = ONE_LAYER
+    blk = jax.tree_util.tree_map(lambda a: a[0], params["blocks"])
+    x = jnp.ones((2, 16, cfg.hidden_size), jnp.float32)
+    E, tile, d = cfg.num_experts, cfg.moe_tile_m, cfg.hidden_size
+    rows = (-(-x.shape[0] * x.shape[1] * cfg.num_experts_per_tok // tile)
+            + E) * tile
+
+    def out(blk, x):
+        y, lb, zl = olmoe.moe(blk, x, cfg)
+        return jnp.sum(y * y) + lb + zl
+
+    fn = out if what == "forward" else jax.grad(out, (0, 1))
+    eqns = [e for e in _equations(jax.make_jaxpr(fn)(blk, x).jaxpr)
+            if e.outvars]
+    found = [(e.primitive.name, e.outvars[0].aval.shape) for e in eqns]
+    wide = [e for e in eqns if e.primitive.name == "gather"
+            and e.outvars[0].aval.shape == (rows, d)]
+    assert wide                                # the walk reaches the layer
+    # A gather that fills is a gather and a select once lowered.
+    assert not [e for e in wide if e.params["mode"]
+                == jax.lax.GatherScatterMode.FILL_OR_DROP]
+    assert ("select_n", (rows, d)) not in found
+    look_ups = [
+        e for e in eqns if e.primitive.name == "gather"
+        and e.invars[0].aval.shape in ((E,), (rows // tile,))
+        and e.outvars[0].aval.size >= rows]
+    assert not look_ups, look_ups
+
+
 # --------------------------------------------------------------------------
 # Through plan_training
 # --------------------------------------------------------------------------
