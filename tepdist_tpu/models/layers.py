@@ -10,6 +10,7 @@ from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 class BlockGradSink:
@@ -44,10 +45,15 @@ _SINK: contextvars.ContextVar[Optional[BlockGradSink]] = \
     contextvars.ContextVar("tepdist_block_grad_sink", default=None)
 
 
-def scan_blocks(body, x, blocks):
+def scan_blocks(body, x, blocks, kinds=None):
     """``jax.lax.scan(jax.checkpoint(body), x, blocks)``: ``body(h, block)
     -> (h, y)`` over blocks stacked on a leading layer dim, every block
     rematerialised in full in the backward pass. Returns ``(x, ys)``.
+
+    ``kinds`` (a NumPy array, one entry a layer, no parameter and no
+    gradient) makes it ``body(h, block, kind)``: layers of one shape and
+    unequal kind (a window here, none there) in one stack, the body
+    choosing by ``lax.cond`` on its layer's entry.
 
     Under a :class:`BlockGradSink` that holds accumulators for ``blocks``
     (``parallel/sync_free.py:build_ga_step`` with several micro batches) the
@@ -57,6 +63,11 @@ def scan_blocks(body, x, blocks):
     stacked gradient of a micro batch is never built. Same values as the
     plain scan's gradient added to the accumulator afterwards: the layer's
     gradient is rounded to its dtype, then the sum to the accumulator's."""
+    if kinds is not None:
+        # The entry rides beside the block; every path below sees a body of
+        # (h, (block, kind)) whose parameters are still ``blocks``' leaves.
+        plain, kinds = body, np.asarray(kinds)
+        body = lambda h, layer: plain(h, *layer)          # noqa: E731
     sink = _SINK.get()
     leaves = jax.tree_util.tree_leaves(blocks)
     keys = () if sink is None else tuple(
@@ -65,35 +76,40 @@ def scan_blocks(body, x, blocks):
         sink.walks.append(keys)
         acc = jax.tree_util.tree_unflatten(
             jax.tree_util.tree_structure(blocks), [sink.acc[k] for k in keys])
-        return _walk_accumulating(body, x, blocks, acc)
+        return _walk_accumulating(body, x, blocks, acc, kinds)
     if keys and None not in keys:
         # Recording. A body that closes over a traced value cannot be
         # differentiated by hand; that walk stays the plain scan.
         def aval(a, drop=0):
             return jax.ShapeDtypeStruct(a.shape[drop:], a.dtype)
 
+        one = jax.tree_util.tree_map(lambda a: aval(a, 1), blocks)
         closed, (h, ys) = jax.make_jaxpr(body, return_shape=True)(
-            aval(x), jax.tree_util.tree_map(lambda a: aval(a, 1), blocks))
+            aval(x), one if kinds is None else (one, aval(kinds, 1)))
         if not any(isinstance(c, jax.core.Tracer) for c in closed.consts):
             sink.walks.append(keys)
             n = leaves[0].shape[0]
             return jnp.zeros(h.shape, h.dtype), jax.tree_util.tree_map(
                 lambda y: jnp.zeros((n,) + y.shape, y.dtype), ys)
-    return jax.lax.scan(jax.checkpoint(body), x, blocks)
+    return jax.lax.scan(jax.checkpoint(body), x,
+                        blocks if kinds is None else (blocks, kinds))
 
 
-def _walk_accumulating(body, x, blocks, acc):
+def _walk_accumulating(body, x, blocks, acc, kinds=None):
+    def layers_of(blocks):
+        return blocks if kinds is None else (blocks, kinds)
+
     @jax.custom_vjp
     def walk(x, blocks, acc):
         del acc
-        return jax.lax.scan(body, x, blocks)
+        return jax.lax.scan(body, x, layers_of(blocks))
 
     def fwd(x, blocks, acc):
-        def step(h, block):
-            out, y = body(h, block)
+        def step(h, layer):
+            out, y = body(h, layer)
             return out, (h, y)
 
-        out, (inputs, ys) = jax.lax.scan(step, x, blocks)
+        out, (inputs, ys) = jax.lax.scan(step, x, layers_of(blocks))
         return (out, ys), (inputs, blocks, acc)
 
     def bwd(res, cts):
@@ -104,7 +120,11 @@ def _walk_accumulating(body, x, blocks, acc):
         def step(carry, per_layer):
             dh, acc = carry
             layer, h, block, d_y = per_layer
-            _, pull = jax.vjp(body, h, block)
+            if kinds is None:
+                _, pull = jax.vjp(body, h, block)
+            else:
+                block, kind = block
+                _, pull = jax.vjp(lambda h, b: body(h, (b, kind)), h, block)
             dh, d_block = pull((dh, d_y))
             acc = jax.tree_util.tree_map(
                 lambda a, g: jax.lax.dynamic_update_index_in_dim(
@@ -114,7 +134,8 @@ def _walk_accumulating(body, x, blocks, acc):
             return (dh, acc), None
 
         (dx, acc), _ = jax.lax.scan(
-            step, (d_out, acc), (layers, inputs, blocks, d_ys), reverse=True)
+            step, (d_out, acc), (layers, inputs, layers_of(blocks), d_ys),
+            reverse=True)
         return dx, None, acc
 
     walk.defvjp(fwd, bwd)

@@ -46,13 +46,11 @@ from tepdist_tpu.models.layers import (
     rope,
     scan_blocks,
 )
-from tepdist_tpu.ops.grouped_matmul import (
-    combine,
-    dispatch,
-    dispatch_values,
+from tepdist_tpu.ops.grouped_matmul import (  # noqa: F401 — gated: tests
+    gated,
     route,
+    routed_experts,
 )
-from tepdist_tpu.ops.pallas.grouped_matmul import grouped_matmul
 
 
 @dataclasses.dataclass(frozen=True)
@@ -167,37 +165,6 @@ def router(blk, h, cfg: OlmoeConfig):
     return logits, probs, weights, experts
 
 
-def _gated(gate, up, row_weight):
-    g = gate.astype(jnp.float32)
-    return (jax.nn.silu(g) * up.astype(jnp.float32)
-            * row_weight).astype(gate.dtype)
-
-
-gated = jax.custom_vjp(_gated)
-gated.__doc__ = """``silu(gate) * up * row_weight`` in float32, back in
-gate's dtype. The backward recomputes from the three operands, which is all
-it keeps: autodiff would keep the float32 intermediates, [rows, f] each."""
-
-
-def _gated_fwd(gate, up, row_weight):
-    return _gated(gate, up, row_weight), (gate, up, row_weight)
-
-
-def _gated_bwd(res, ct):
-    gate, up, row_weight = res
-    g, u, ct = (t.astype(jnp.float32) for t in (gate, up, ct))
-    sig = jax.nn.sigmoid(g)
-    silu = g * sig
-    d_gate = ct * u * row_weight * sig * (1.0 + g * (1.0 - sig))
-    d_up = ct * silu * row_weight
-    d_weight = jnp.sum(ct * silu * u, axis=-1, keepdims=True)
-    return (d_gate.astype(gate.dtype), d_up.astype(up.dtype),
-            d_weight.astype(row_weight.dtype))
-
-
-gated.defvjp(_gated_fwd, _gated_bwd)
-
-
 def moe(blk, x, cfg: OlmoeConfig):
     """x [B, T, d] -> (expert layer's output [B, T, d], load-balancing
     loss, router z-loss), the two losses per sequence, averaged."""
@@ -213,23 +180,8 @@ def moe(blk, x, cfg: OlmoeConfig):
             jax.lax.stop_gradient(share)
             * probs.reshape(B, T, E).mean(axis=1), axis=-1))
         zl = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
-    with jax.named_scope("moe_dispatch"):
-        r = route(experts, E, cfg.moe_tile_m)
-        rows = dispatch(h, r.row_token, r.dest)
-        row_weight = dispatch_values(weights, r)
-    with jax.named_scope("moe_experts"):
-        def gmm(a, w):
-            return grouped_matmul(a, w, r.tile_group, r.n_tiles,
-                                  cfg.moe_tile_m)
-        # The router's weight goes on the row before the down projection
-        # (W (w a) = w (W a)): the projected rows then need no keeping for
-        # the weight's gradient, 320 MiB a micro batch at the 1B-7B sizes.
-        # On a pad row it is exactly 0, as the row itself is.
-        act = gated(gmm(rows, blk["w_gate"]), gmm(rows, blk["w_up"]),
-                    row_weight)
-        out_rows = gmm(act, blk["w_down"])
-    with jax.named_scope("moe_combine"):
-        y = combine(out_rows, r.row_token, r.dest)
+    y = routed_experts(h, weights, experts, blk["w_gate"], blk["w_up"],
+                       blk["w_down"], E, cfg.moe_tile_m)
     return y.reshape(B, T, d), lb, zl
 
 

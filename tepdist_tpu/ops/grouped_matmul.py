@@ -39,6 +39,23 @@ So the layout is one stable sort of the assignments together with
 ``E x tile_m`` pad candidates, of which each expert uses as many as fill its
 last tile; its inverse is a second sort; and values ride into the layout, and
 their gradients out of it, through a sort by the same keys.
+
+**A layer that holds a share of the experts** (expert parallelism's rank:
+``held=(first, count)`` of the router's ``num_experts``) lays out only the
+assignments to its own experts, over ``count`` groups. The router still
+chooses among all experts; a choice of an expert elsewhere sorts past the
+live tiles, gets no row, and its ``dest`` names the last row of a spare
+tile the layout keeps for it, which no group ever reaches: the kernels
+write zeros past the live tiles, so ``combine`` adds nothing for it and
+``dispatch``'s backward nothing either. Dropless stays the guarantee: the
+static row count is the worst case, ``min(k, count)`` rows a token plus one
+tile a group, so every assignment to a held expert reaches a row whatever
+the routing. ``(0, num_experts)`` is the whole layer, the same operations as
+without ``held``. Nothing here stands in for the other ranks or for the
+exchange with them.
+
+``routed_experts`` is the layer around the layout: rows in, the three
+grouped matmuls with the gated activation between them, rows out.
 """
 
 from __future__ import annotations
@@ -47,6 +64,8 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+
+from tepdist_tpu.ops.pallas.grouped_matmul import grouped_matmul
 
 
 class Routing(NamedTuple):
@@ -57,7 +76,11 @@ class Routing(NamedTuple):
     # is ever masked (the module docstring's invariant).
     row_token: jax.Array        # [M] token of each row; S (out of range) = pad
     row_assignment: jax.Array   # [M] assignment (token * k + slot); S * k = pad
-    dest: jax.Array             # [S, k] row of each assignment
+    #                             (a share of the experts: the sorted order
+    #                             whole, a choice elsewhere past the live
+    #                             tiles; the values' way back)
+    dest: jax.Array             # [S, k] row of each assignment (a choice of
+    #                             an expert elsewhere: a row no tile reaches)
     tile_group: jax.Array       # [M / tile_m] expert of each row tile
     n_tiles: jax.Array          # [1] live row tiles
     group_sizes: jax.Array      # [E] assignments per expert
@@ -69,12 +92,23 @@ def _sorted_by(key, value):
     return jax.lax.sort((key, value), num_keys=1, is_stable=True)[1]
 
 
-def route(expert_ids, num_experts: int, tile_m: int) -> Routing:
-    """The tile-aligned layout of ``expert_ids`` [S, k]."""
+def route(expert_ids, num_experts: int, tile_m: int, held=None) -> Routing:
+    """The tile-aligned layout of ``expert_ids`` [S, k]; with ``held =
+    (first, count)`` that of the assignments to experts ``first .. first +
+    count - 1`` alone, over ``count`` groups (the module docstring)."""
     S, k = expert_ids.shape
-    A, E = S * k, num_experts
-    M = (-(-A // tile_m) + E) * tile_m
+    first, E = held or (0, num_experts)
+    if first < 0 or E < 1 or first + E > num_experts:
+        raise ValueError(f"route: held={held} of {num_experts} experts")
+    share = E < num_experts
+    A = S * k
+    # Rows: min(k, E) a token at most, a tile's pads a group, and under a
+    # share the spare tile; sorted are all A choices with the candidates.
+    M = (-(-S * min(k, E) // tile_m) + E + share) * tile_m
+    L = max(M, A + E * tile_m)
     flat = expert_ids.reshape(A).astype(jnp.int32)
+    if first:
+        flat = flat - first
     group_sizes = jnp.sum(
         flat[:, None] == jnp.arange(E, dtype=flat.dtype), axis=0,
         dtype=jnp.int32)
@@ -95,15 +129,23 @@ def route(expert_ids, num_experts: int, tile_m: int) -> Routing:
     pad_key = jnp.where(
         jnp.arange(tile_m, dtype=jnp.int32)[None, :] < pads[:, None],
         2 * experts + 1, 2 * E)
+    # A choice of an expert elsewhere sorts with the unused candidates.
+    choice_key = jnp.where((flat >= 0) & (flat < E), 2 * flat, 2 * E) \
+        if share else 2 * flat
     sort_key = jnp.concatenate([
-        2 * flat, pad_key.reshape(E * tile_m),
-        jnp.full((M - A - E * tile_m,), 2 * E, jnp.int32)])
+        choice_key, pad_key.reshape(E * tile_m),
+        jnp.full((L - A - E * tile_m,), 2 * E, jnp.int32)])
     row_assignment = _sorted_by(sort_key, jnp.minimum(
-        jnp.arange(M, dtype=jnp.int32), A))
+        jnp.arange(L, dtype=jnp.int32), A))
     # The inverse permutation: the pads (all A) sort past the assignments.
-    dest = _sorted_by(row_assignment, jnp.arange(M, dtype=jnp.int32))[:A]
+    dest = _sorted_by(row_assignment, jnp.arange(L, dtype=jnp.int32))[:A]
+    row_token = (row_assignment[:M] if L > M else row_assignment) // k
+    if share:
+        live = jnp.arange(M, dtype=jnp.int32) < tile_end[-1] * tile_m
+        row_token = jnp.where(live, row_token, S)
+        dest = jnp.minimum(dest, M - 1)
     return Routing(
-        row_token=row_assignment // k, row_assignment=row_assignment,
+        row_token=row_token, row_assignment=row_assignment,
         dest=dest.reshape(S, k), tile_group=tile_group,
         n_tiles=tile_end[-1:].astype(jnp.int32), group_sizes=group_sizes,
         sort_key=sort_key)
@@ -173,10 +215,13 @@ def dispatch_values(v, r: Routing):
     """One value an assignment into the layout: v [S, k] -> [M, 1] by
     ``r.sort_key``, **exactly 0 on every pad row**. The gradient of an
     assignment's value is its row's (``r.row_assignment`` sorts the rows
-    back)."""
+    back). Under a share of the experts the value of a choice elsewhere
+    lands past the live tiles: the caller hands in 0 for it."""
     pads = jnp.zeros((r.sort_key.shape[0] - v.size,), v.dtype)
-    return _sorted_by(r.sort_key, jnp.concatenate([v.reshape(-1), pads]))[
-        :, None]
+    rows = _sorted_by(r.sort_key, jnp.concatenate([v.reshape(-1), pads]))
+    if rows.shape[0] > r.row_token.shape[0]:
+        rows = rows[:r.row_token.shape[0]]
+    return rows[:, None]
 
 
 def _dispatch_values_fwd(v, r):
@@ -185,8 +230,69 @@ def _dispatch_values_fwd(v, r):
 
 def _dispatch_values_bwd(res, g):
     row_assignment, dest = res
-    back = _sorted_by(row_assignment, g[:, 0])[:dest.size]
+    g = g[:, 0]
+    if row_assignment.shape[0] > g.shape[0]:
+        g = jnp.pad(g, (0, row_assignment.shape[0] - g.shape[0]))
+    back = _sorted_by(row_assignment, g)[:dest.size]
     return back.reshape(dest.shape), None
 
 
 dispatch_values.defvjp(_dispatch_values_fwd, _dispatch_values_bwd)
+
+
+def _gated(gate, up, row_weight):
+    g = gate.astype(jnp.float32)
+    return (jax.nn.silu(g) * up.astype(jnp.float32)
+            * row_weight).astype(gate.dtype)
+
+
+gated = jax.custom_vjp(_gated)
+gated.__doc__ = """``silu(gate) * up * row_weight`` in float32, back in
+gate's dtype. The backward recomputes from the three operands, which is all
+it keeps: autodiff would keep the float32 intermediates, [rows, f] each."""
+
+
+def _gated_fwd(gate, up, row_weight):
+    return _gated(gate, up, row_weight), (gate, up, row_weight)
+
+
+def _gated_bwd(res, ct):
+    gate, up, row_weight = res
+    g, u, ct = (t.astype(jnp.float32) for t in (gate, up, ct))
+    sig = jax.nn.sigmoid(g)
+    silu = g * sig
+    d_gate = ct * u * row_weight * sig * (1.0 + g * (1.0 - sig))
+    d_up = ct * silu * row_weight
+    d_weight = jnp.sum(ct * silu * u, axis=-1, keepdims=True)
+    return (d_gate.astype(gate.dtype), d_up.astype(up.dtype),
+            d_weight.astype(row_weight.dtype))
+
+
+gated.defvjp(_gated_fwd, _gated_bwd)
+
+
+def routed_experts(h, weights, experts, w_gate, w_up, w_down,
+                   num_experts: int, tile_m: int, held=None):
+    """The routed SwiGLU experts' part of a layer: h [S, d], the router's
+    ``weights`` and ``experts`` [S, k] over ``num_experts``, expert weights
+    ``w_gate``, ``w_up`` [G, d, f] and ``w_down`` [G, f, d] -> [S, d], the
+    sum over each token's choices j of ``weights_j . w_down[e_j] (silu(
+    w_gate[e_j] h) * w_up[e_j] h)``. With ``held = (first, count)`` the
+    layer holds experts ``first .. first + count - 1`` (``G = count``) and a
+    choice of any other contributes nothing: the caller hands in weight 0
+    for it. No token is dropped (``route``)."""
+    with jax.named_scope("moe_dispatch"):
+        r = route(experts, num_experts, tile_m, held)
+        rows = dispatch(h, r.row_token, r.dest)
+        row_weight = dispatch_values(weights, r)
+    with jax.named_scope("moe_experts"):
+        def gmm(a, w):
+            return grouped_matmul(a, w, r.tile_group, r.n_tiles, tile_m)
+        # The router's weight goes on the row before the down projection
+        # (W (w a) = w (W a)): the projected rows then need no keeping for
+        # the weight's gradient, 320 MiB a micro batch at the 1B-7B sizes.
+        # On a pad row it is exactly 0, as the row itself is.
+        act = gated(gmm(rows, w_gate), gmm(rows, w_up), row_weight)
+        out_rows = gmm(act, w_down)
+    with jax.named_scope("moe_combine"):
+        return combine(out_rows, r.row_token, r.dest)

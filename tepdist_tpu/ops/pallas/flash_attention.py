@@ -82,36 +82,72 @@ def _fold_scale(dtype, scale: float) -> bool:
     return dtype == jnp.float32 or math.frexp(scale)[0] == 0.5
 
 
-def _scores_t(k, q, scale, fold, causal_from=None):
+def _scores_t(k, q, scale, fold, causal_from=None, window_from=None):
     """Transposed scores [keys, queries] in float32. Keys on the rows and
     queries on the lanes: the softmax statistics are then reductions over
     rows (vector maxima and adds, no cross-lane work) and [1, queries]
     rows, the layout they are stored in, and P^T and dS^T are what the
     backward matmuls take. ``causal_from`` is the (key, query) position of
     ``st[0, 0]`` where the diagonal may cross the tile: keys after the
-    query's own position go to -inf."""
+    query's own position go to -inf. ``window_from`` is (key, query,
+    window) where the window's far edge may cross it: keys ``window`` or
+    more positions before the query go to -inf too."""
     st = _dot(k, q, _NT)
     if not fold:
         st = st * scale
-    if causal_from is not None:
-        k0, q0 = causal_from
+    if causal_from is not None or window_from is not None:
         ahead = (jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
                  - jax.lax.broadcasted_iota(jnp.int32, st.shape, 0))
-        st = jnp.where(ahead >= k0 - q0, st, _NEG_INF)
+        if causal_from is not None:
+            k0, q0 = causal_from
+            st = jnp.where(ahead >= k0 - q0, st, _NEG_INF)
+        if window_from is not None:
+            k0, q0, window = window_from
+            st = jnp.where(ahead < window + k0 - q0, st, _NEG_INF)
     return st
 
 
-def _over_key_blocks(step, carry, causal, qi, block_q, block_k, n_blocks):
+def _once_if(ok, j, body, carry):
+    """``body(j, carry)`` if ``ok`` (traced), else ``carry``: a loop of one
+    trip or none, so that an edge block keeps its mask at a fixed place."""
+    j = jnp.where(ok, j, 0)
+    return jax.lax.fori_loop(j, jnp.where(ok, j + 1, j), body, carry)
+
+
+def _over_key_blocks(step, carry, causal, qi, block_q, block_k, n_blocks,
+                     window=None):
     """``step(j, carry, causal_from)`` over the K/V blocks Q block ``qi``
     sees; ``causal_from`` is ``_scores_t``'s. With equal tiles the diagonal
     crosses only the block of the Q block's own positions: the blocks below
     it run unmasked in the loop and that one after it, outside, its mask at
     a fixed place (straight-line code with a constant mask took 15% off the
     forward; a mask at a computed place costs as much as the loop did;
-    PERF.md, PR 25). Blocks above the diagonal are skipped."""
+    PERF.md, PR 25). Blocks above the diagonal are skipped.
+
+    With a ``window`` (causal; key j visible to query i iff ``0 <= i - j <
+    window``) ``step`` takes ``window_from`` too, and the blocks wholly
+    before the window are skipped as those above the diagonal are. Where
+    equal tiles divide the window its far edge crosses one block only,
+    ``window / block`` blocks before the diagonal's, again at a fixed
+    place; any other tiling masks every block it visits at a computed one."""
     def plain(j, carry):
         return step(j, carry, None)
 
+    if window is not None:
+        if block_q == block_k and window % block_q == 0:
+            far = qi - window // block_q
+            carry = _once_if(
+                far >= 0, far,
+                lambda j, c: step(j, c, None, (0, window, window)), carry)
+            carry = jax.lax.fori_loop(jnp.maximum(far + 1, 0), qi, plain,
+                                      carry)
+            return step(qi, carry, (0, 0))
+        q0 = qi * block_q
+        lo = jnp.maximum(q0 - window + 1, 0) // block_k
+        hi = (q0 + block_q + block_k - 1) // block_k
+        return jax.lax.fori_loop(
+            lo, hi, lambda j, c: step(j, c, (j * block_k, q0),
+                                      (j * block_k, q0, window)), carry)
     if not causal:
         return jax.lax.fori_loop(0, n_blocks, plain, carry)
     if block_q == block_k:
@@ -123,9 +159,11 @@ def _over_key_blocks(step, carry, causal, qi, block_q, block_k, n_blocks):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
-                causal: bool, scale: float, q_block: int, seq_len: int):
-    """One Q block against the K/V blocks up to its diagonal; the output
-    accumulates as O^T [D, bq] and is transposed once at the end."""
+                causal: bool, scale: float, q_block: int, seq_len: int,
+                window: Optional[int] = None):
+    """One Q block against the K/V blocks up to its diagonal (from its
+    window's far edge, with a window); the output accumulates as O^T
+    [D, bq] and is transposed once at the end."""
     qi = pl.program_id(1)
     q = q_ref[0]                                      # [bq, D]
     bq, D = q.shape
@@ -133,11 +171,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
     if fold:
         q = q * scale
 
-    def step(j, carry, causal_from):
+    def step(j, carry, causal_from, window_from=None):
         m, l, ot = carry
         k = k_ref[0, pl.dslice(j * block_k, block_k)]
         v = v_ref[0, pl.dslice(j * block_k, block_k)]
-        st = _scores_t(k, q, scale, fold, causal_from)
+        st = _scores_t(k, q, scale, fold, causal_from, window_from)
         m_new = jnp.maximum(m, st.max(axis=0, keepdims=True))
         pt = jnp.exp(st - m_new)                      # [bk, bq]
         corr = jnp.exp(m - m_new)
@@ -147,18 +185,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
     # m starts finite, so exp(m - m_new) is 0 (not NaN) on the first block;
     # every query meets a live key in the first block it sees (key 0 under
     # the causal mask), so no running max stays at its start and l > 0.
+    # Under a window a query may see nothing of the far edge's block: its
+    # masked scores then equal the start of m and count as ones, until the
+    # first live key (its own position at the latest) raises m and
+    # exp(m - m_new) = 0 wipes them.
     carry = (jnp.full((1, bq), _NEG_INF, jnp.float32),
              jnp.zeros((1, bq), jnp.float32),
              jnp.zeros((D, bq), jnp.float32))
     m, l, ot = _over_key_blocks(step, carry, causal, qi, q_block, block_k,
-                                seq_len // block_k)
+                                seq_len // block_k, window)
     o_ref[0] = (ot * (1.0 / l)).T.astype(o_ref.dtype)
     lse_ref[0, 0] = m + jnp.log(l)                    # [1, bq]
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
                block_k: int, causal: bool, scale: float, q_block: int,
-               seq_len: int):
+               seq_len: int, window: Optional[int] = None):
     """One Q block: dQ = scale * sum_j dS_j @ K_j, with P recomputed from
     the saved LSE (no renormalisation pass needed). Tiled as the forward:
     dQ accumulates as dQ^T [D, bq]."""
@@ -172,24 +214,25 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
     lse = lse_ref[0, 0]                               # [1, bq]
     delta = delta_ref[0, 0]
 
-    def step(j, dqt, causal_from):
+    def step(j, dqt, causal_from, window_from=None):
         k = k_ref[0, pl.dslice(j * block_k, block_k)]
         v = v_ref[0, pl.dslice(j * block_k, block_k)]
-        st = _scores_t(k, q, scale, fold, causal_from)
+        st = _scores_t(k, q, scale, fold, causal_from, window_from)
         pt = jnp.exp(st - lse)                        # exact softmax probs
         dst = pt * (_dot(v, do, _NT) - delta)         # dS^T [bk, bq]
         return dqt + _dot(k, dst.astype(k.dtype), _TN)
 
     dqt = _over_key_blocks(step, jnp.zeros((D, bq), jnp.float32), causal,
-                           qi, q_block, block_k, seq_len // block_k)
+                           qi, q_block, block_k, seq_len // block_k, window)
     dq_ref[0] = (dqt * scale).T.astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, *, block_q: int, causal: bool, scale: float,
-                k_block: int, seq_len: int):
+                k_block: int, seq_len: int, window: Optional[int] = None):
     """One K/V block: dV = sum_i P_i^T @ dO_i, dK = scale * sum_i dS_i^T @
-    Q_i, over the Q blocks from its diagonal on."""
+    Q_i, over the Q blocks from its diagonal on (to its window's far edge,
+    with a window: ``_over_key_blocks``' cases from the key's side)."""
     ki = pl.program_id(1)
     k = k_ref[0]                                      # [bk, D]
     v = v_ref[0]
@@ -197,13 +240,13 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     fold = _fold_scale(k.dtype, scale)
     n_blocks = seq_len // block_q
 
-    def step(i, carry, causal_from):
+    def step(i, carry, causal_from, window_from=None):
         dk, dv = carry
         q = q_ref[0, pl.dslice(i * block_q, block_q)]  # [bq, D]
         if fold:
             q = q * scale
         do = do_ref[0, pl.dslice(i * block_q, block_q)]
-        st = _scores_t(k, q, scale, fold, causal_from)
+        st = _scores_t(k, q, scale, fold, causal_from, window_from)
         pt = jnp.exp(st - lse_ref[0, i])              # P^T [bk, bq]
         dv = dv + _dot(pt.astype(do.dtype), do)
         dst = pt * (_dot(v, do, _NT) - delta_ref[0, i])   # dS^T
@@ -213,7 +256,22 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         return step(i, carry, None)
 
     carry = (jnp.zeros((bk, D), jnp.float32), jnp.zeros((bk, D), jnp.float32))
-    if not causal:
+    if window is not None and block_q == k_block and window % k_block == 0:
+        far = ki + window // k_block
+        carry = jax.lax.fori_loop(ki + 1, jnp.minimum(far, n_blocks), plain,
+                                  step(ki, carry, (0, 0)))
+        carry = _once_if(
+            far < n_blocks, far,
+            lambda i, c: step(i, c, None, (0, window, window)), carry)
+    elif window is not None:
+        k0 = ki * k_block
+        hi = jnp.minimum((k0 + k_block + window + block_q - 2) // block_q,
+                         n_blocks)
+        carry = jax.lax.fori_loop(
+            k0 // block_q, hi,
+            lambda i, c: step(i, c, (k0, i * block_q),
+                              (k0, i * block_q, window)), carry)
+    elif not causal:
         carry = jax.lax.fori_loop(0, n_blocks, plain, carry)
     elif block_q == k_block:
         # As in _over_key_blocks: Q blocks before this K block see none of
@@ -234,32 +292,54 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
-def _kernel_name(which: str, causal, scale, heads: int) -> str:
+def _kernel_name(which: str, causal, scale, heads: int,
+                 window: Optional[int] = None, kv_heads: int = 0) -> str:
     """A stable name for each kernel: a device trace and the compiled HLO
-    show it, so forward, dQ and dK/dV are told apart by name."""
-    return f"tepdist_flash_{which}__c{int(causal)}__s{scale!r}__h{heads}"
+    show it, so forward, dQ and dK/dV are told apart by name. A window and
+    a smaller number of key/value heads follow the fields every call has
+    (``...__h32__w2048__kv4``); a call with neither is named as before."""
+    name = f"tepdist_flash_{which}__c{int(causal)}__s{scale!r}__h{heads}"
+    if window is not None:
+        name += f"__w{window}"
+    if kv_heads and kv_heads != heads:
+        name += f"__kv{kv_heads}"
+    return name
 
 
-def _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret):
+def _kv_spec(block, group: int, tiled: bool):
+    """The BlockSpec of a key/value operand [B*Hkv, T, D] under a grid over
+    the ``B*H`` query heads: query head ``b`` reads key/value head ``b //
+    group``, so a group's heads find the block already in VMEM and no
+    broadcast copy of k or v exists in HBM."""
+    if group == 1:
+        return pl.BlockSpec(block, (lambda b, i: (b, i, 0)) if tiled
+                            else (lambda b, i: (b, 0, 0)))
+    return pl.BlockSpec(block, (lambda b, i: (b // group, i, 0)) if tiled
+                        else (lambda b, i: (b // group, 0, 0)))
+
+
+def _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
+              window=None):
     B, H, T, D = q.shape
+    Hkv = k.shape[1]
     qf = q.reshape(B * H, T, D)
-    kf = k.reshape(B * H, T, D)
-    vf = v.reshape(B * H, T, D)
+    kf = k.reshape(B * Hkv, T, D)
+    vf = v.reshape(B * Hkv, T, D)
+    kv_full = _kv_spec((1, T, D), H // Hkv, tiled=False)
     kernel = functools.partial(
         _fwd_kernel, block_k=block_k, causal=causal, scale=scale,
-        q_block=block_q, seq_len=T)
+        q_block=block_q, seq_len=T, window=window)
     o, lse = pl.pallas_call(
         kernel,
         # The name tags the eqn so the seq-axis planner can motif-match
         # flash call sites in traced graphs (parallel/attention_motif.py)
         # — causal flag, softmax scale and head count ride along for the
         # rewrite (H lets the ulysses lowering un-flatten [B*H, T, D]).
-        name=_kernel_name("fwd", causal, scale, H),
+        name=_kernel_name("fwd", causal, scale, H, window, Hkv),
         grid=(B * H, T // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, T, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, T, D), lambda b, i: (b, 0, 0)),
+            kv_full, kv_full,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
@@ -278,11 +358,14 @@ def _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret):
 
 
 def _bwd_call(causal, scale, block_q, block_k, interpret, res, do,
-              dlse=None):
+              dlse=None, window=None):
     q, k, v, o, lse = res
     B, H, T, D = q.shape
+    Hkv = k.shape[1]
+    group = H // Hkv
     BH = B * H
-    qf, kf, vf = (x.reshape(BH, T, D) for x in (q, k, v))
+    qf = q.reshape(BH, T, D)
+    kf, vf = (x.reshape(B * Hkv, T, D) for x in (k, v))
     dof = do.reshape(BH, T, D)
     rows = (BH, T // block_q, 1, block_q)
     lsef = lse.reshape(rows)
@@ -295,17 +378,20 @@ def _bwd_call(causal, scale, block_q, block_k, interpret, res, do,
     delta = delta.reshape(rows)
 
     full_spec = pl.BlockSpec((1, T, D), lambda b, i: (b, 0, 0))
+    kv_full = _kv_spec((1, T, D), group, tiled=False)
+    kv_block = _kv_spec((1, block_k, D), group, tiled=True)
     row_block = pl.BlockSpec((1, 1, 1, block_q), lambda b, i: (b, i, 0, 0))
     row_full = pl.BlockSpec((1,) + rows[1:], lambda b, i: (b, 0, 0, 0))
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, block_k=block_k, causal=causal,
-                          scale=scale, q_block=block_q, seq_len=T),
-        name=_kernel_name("dq", causal, scale, H),
+                          scale=scale, q_block=block_q, seq_len=T,
+                          window=window),
+        name=_kernel_name("dq", causal, scale, H, window, Hkv),
         grid=(BH, T // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-            full_spec, full_spec,
+            kv_full, kv_full,
             pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
             row_block, row_block,
         ],
@@ -316,13 +402,12 @@ def _bwd_call(causal, scale, block_q, block_k, interpret, res, do,
 
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, block_q=block_q, causal=causal,
-                          scale=scale, k_block=block_k, seq_len=T),
-        name=_kernel_name("dkv", causal, scale, H),
+                          scale=scale, k_block=block_k, seq_len=T,
+                          window=window),
+        name=_kernel_name("dkv", causal, scale, H, window, Hkv),
         grid=(BH, T // block_k),
         in_specs=[
-            full_spec,
-            pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0)),
+            full_spec, kv_block, kv_block,
             full_spec, row_full, row_full,
         ],
         out_specs=[
@@ -336,22 +421,33 @@ def _bwd_call(causal, scale, block_q, block_k, interpret, res, do,
         interpret=interpret,
     )(qf, kf, vf, dof, lsef, delta)
     shape = (B, H, T, D)
+    if group > 1:
+        # The kernel wrote each query head's part of its key/value head's
+        # gradient; the group's sum is the head broadcast's transpose,
+        # without the broadcast.
+        dk, dv = (jnp.sum(x.reshape(B, Hkv, group, T, D), axis=2,
+                          dtype=jnp.float32).astype(x.dtype)
+                  for x in (dk, dv))
+        return dq.reshape(shape), dk, dv
     return dq.reshape(shape), dk.reshape(shape), dv.reshape(shape)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, scale, block_q, block_k, interpret):
-    o, _ = _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, scale, block_q, block_k, interpret, window):
+    o, _ = _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
+                     window)
     return o
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
-    o, lse = _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret)
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret, window):
+    o, lse = _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
+                       window)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, interpret, res, do):
-    return _bwd_call(causal, scale, block_q, block_k, interpret, res, do)
+def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res, do):
+    return _bwd_call(causal, scale, block_q, block_k, interpret, res, do,
+                     window=window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -479,9 +575,35 @@ def flash_attention(q, k, v, causal: bool = True,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None):
-    """q, k, v: [B, H, T, D] -> [B, H, T, D]. Differentiable (custom VJP)."""
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None):
+    """q [B, H, T, D], k and v [B, Hkv, T, D] -> [B, H, T, D].
+    Differentiable (custom VJP).
+
+    ``Hkv`` divides ``H`` (grouped-query attention): query head ``h`` reads
+    key/value head ``h // (H / Hkv)`` through the kernels' index maps, so no
+    broadcast copy of k or v is made; their gradients are the sums over each
+    group. ``window`` (causal only): key j is visible to query i iff ``0 <=
+    i - j < window``; the three kernels skip the blocks wholly outside it. A
+    window that reaches every earlier key (``window >= T``) is no window.
+    Equal tiles that divide the window (the benchmark's: 512 in 2048) mask
+    its far edge at a fixed place; the computed mask serves every other
+    call: a sequence whose tile, the largest divisor of ``T`` up to 512,
+    does not divide the window (``T = 6400`` under 2048: tiles of 400), and
+    ``models/afmoe.py``'s ``test`` preset (window 8 inside one tile).
+    With ``window=None`` and ``Hkv == H`` the kernels, their names and their
+    operands are what they were before either existed."""
     B, H, T, D = q.shape
+    if k.shape != v.shape or k.shape[0] != B or k.shape[2:] != (T, D) \
+            or H % k.shape[1]:
+        raise ValueError(f"flash_attention: q {q.shape}, k {k.shape}, "
+                         f"v {v.shape}")
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError("flash_attention: a window needs causal=True "
+                             f"and window >= 1 (got {window})")
+        if window >= T:
+            window = None
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     blocks = _resolve_blocks(T, block_q, block_k)
     if blocks is None:
@@ -495,13 +617,15 @@ def flash_attention(q, k, v, causal: bool = True,
             pad = ((0, 0), (0, 0), (0, Tp - T), (0, 0))
             out = flash_attention(
                 jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad),
-                causal=True, scale=scale, interpret=interpret)
+                causal=True, scale=scale, interpret=interpret, window=window)
             return out[:, :, :T, :]
         # Non-causal: padded keys would be attended; dense is the only
         # exact fallback (rare — awkward T with bidirectional attention).
         _log_dense_fallback(T)
-        return _dense_attention(q, k, v, causal, scale)
+        group = H // k.shape[1]
+        return _dense_attention(q, jnp.repeat(k, group, axis=1),
+                                jnp.repeat(v, group, axis=1), causal, scale)
     block_q, block_k = blocks
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    return _flash(q, k, v, causal, scale, block_q, block_k, interpret)
+    return _flash(q, k, v, causal, scale, block_q, block_k, interpret, window)

@@ -80,6 +80,49 @@ def adamw_bf16(learning_rate: float, b1: float = 0.9, b2: float = 0.95,
     )
 
 
+def sign_and_centre(rate: float) -> optax.GradientTransformation:
+    """The update of a router's selection bias (auxiliary-loss-free load
+    balancing, torchtitan's form): what arrives as the leaf's "gradient" is
+    ``n``, how often the step's batch chose each expert (the last axis;
+    any scale: only its order about the mean is read), and the update is
+    ``delta - mean(delta)`` with ``delta = rate * sign(mean(n) - n)``: an
+    expert chosen less than the average is raised, and the biases keep
+    their sum. No state: the bias is its own."""
+
+    def update(counts, state, params=None):
+        del params
+
+        def one(n):
+            n = n.astype(jnp.float32)
+            delta = rate * jnp.sign(n.mean(-1, keepdims=True) - n)
+            return delta - delta.mean(-1, keepdims=True)
+
+        return jax.tree_util.tree_map(one, counts), state
+
+    return optax.GradientTransformation(lambda params: optax.EmptyState(),
+                                        update)
+
+
+ROUTER_BIAS = "router_bias"
+
+
+def adamw_bf16_router_bias(learning_rate: float, bias_rate: float = 0.001,
+                           **adamw) -> optax.GradientTransformation:
+    """``adamw_bf16`` for every leaf but those named ``router_bias``, which
+    take :func:`sign_and_centre` at ``bias_rate`` (their "gradient" is the
+    step's per-expert counts: ``models/afmoe.py``). A leaf is labelled by
+    its own path, so a sub-tree's state has the paths the whole tree's has."""
+
+    def labels(params):
+        return jax.tree_util.tree_map_with_path(
+            lambda path, _: "bias" if getattr(
+                path[-1], "key", None) == ROUTER_BIAS else "adamw", params)
+
+    return optax.multi_transform(
+        {"adamw": adamw_bf16(learning_rate, **adamw),
+         "bias": sign_and_centre(bias_rate)}, labels)
+
+
 # ----------------------------------------------------------------------
 # Declarative optimizer specs (the wire form of an optimizer)
 # ----------------------------------------------------------------------
@@ -97,6 +140,7 @@ _OPTIMIZERS = {
     "adam": optax.adam,
     "adamw": optax.adamw,
     "adamw_bf16": adamw_bf16,
+    "adamw_bf16_router_bias": adamw_bf16_router_bias,
 }
 
 

@@ -220,6 +220,10 @@ def detect_motifs(graph: JaxprGraph,
                       and parts[3].startswith("h") else None)
         except (IndexError, ValueError):
             continue
+        if len(parts) > 4:
+            # A window (``__w2048``) or fewer key/value heads (``__kv4``):
+            # not the plain attention a ring or Ulysses rewrite computes.
+            continue
         if len(node.invars) < 3 or not all(
                 isinstance(a, Var) and len(a.aval.shape) == 3
                 for a in node.invars[:3]):

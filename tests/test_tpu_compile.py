@@ -98,6 +98,35 @@ def test_flash_kernels_compile_for_v5e(v5e_devices, shape, dtype, block):
         assert not _PADDED_ROWS.search(shapes), (name, shapes)
 
 
+# The Trinity cell's attention: 32 query heads over 4 key/value heads of 128
+# at 8192 positions, 512 tiles; a window the tiles divide (its layers'), none
+# (its global layer's) and one they do not divide (the masked-everywhere
+# path).
+@pytest.mark.parametrize("window", [2048, None, 1000])
+def test_windowed_grouped_query_flash_compiles_for_v5e(v5e_devices, window):
+    one_chip = SingleDeviceSharding(v5e_devices[0])
+    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, block_q=512, block_k=512, interpret=False,
+            window=window).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    calls = _flash_call_shapes(text)
+    assert len(calls) == 3, sorted(calls)
+    tag = ("" if window is None else f"__w{window}") + "__kv4"
+    for name, shapes in calls.items():
+        assert tag in name and "?" not in shapes, (name, shapes)
+        assert not _PADDED_ROWS.search(shapes), (name, shapes)
+        # k and v reach the kernels at their own head count: no broadcast.
+        assert "bf16[4,8192,128]" in shapes, (name, shapes)
+
+
 # The OLMoE cell's grouped matmuls: 8192 tokens x 8 experts a token in the
 # tile-aligned layout (65536 rows + 64 tiles of pads), 64 experts of
 # 2048 x 1024 (gate, up) and 1024 x 2048 (down).

@@ -6,12 +6,16 @@ resolves, the program can pick the one the reference does not, and from
 that layer on the two compute different functions. This reads how often:
 for every layer and token, the part of the reference's k experts the
 program did not choose. It also fills the program's routing counters
-(``models/olmoe.py:routing_stats``; ``moe_tokens_dropped`` must read 0).
+(the model's ``routing_stats``; ``moe_tokens_dropped`` must read 0). The
+model and its reference are the cell's builder's (``builder.program``,
+``benchmark/reference/<builder>.py``, whose ``hidden`` returns the expert
+ids last): OLMoE's and, with the share of the experts a chip holds
+(``held_share_by_layer``: 1 for a chip that holds them all), AFMoE's.
 ``--sizes-out FILE`` writes the rows each expert got in each layer from the
 first micro batch of the last seed's check batch, for
 ``tools/gmm_bench.py --sizes FILE``: the groups the cell's kernels meet.
 
-Weights and sequences are the cell's own (``benchmark/builders/olmoe.py``,
+Weights and sequences are the cell's own (``benchmark/builders/``,
 ``drivers/train_steps.py:check_batch``), one sequence at a time.
 
 Run: chiprun -- python tools/olmoe_flips.py [--workload olmoe-1b-7b.train.s4096]
@@ -21,6 +25,7 @@ Run: chiprun -- python tools/olmoe_flips.py [--workload olmoe-1b-7b.train.s4096]
 from __future__ import annotations
 
 import argparse
+import importlib
 import json
 import os
 import sys
@@ -40,17 +45,18 @@ def main(argv=None) -> int:
     import numpy as np
 
     from benchmark.lib import cells, device
-    from benchmark.reference import olmoe as ref
-    from tepdist_tpu.models import olmoe
-
     cell = cells.load_cell(args.workload, ROOT)
     devices = device.own_chips(1)
     device.configure_cache(ROOT)
     builder, driver = cells.builder_for(cell), cells.driver_for(cell)
+    program = builder.program
+    ref = importlib.import_module(
+        "benchmark.reference." + cell.config["builder"])
     cfg, hp = builder.program_config(cell.config), \
         builder.reference_hyper(cell.config)
-    want_fn = jax.jit(lambda p, t: ref.hidden(p, t, hp)[3])
+    want_fn = jax.jit(lambda p, t: ref.hidden(p, t, hp)[-1])
     k = cfg.num_experts_per_tok
+    first, count = getattr(cfg, "experts_held", (0, cfg.num_experts))
     micro = int(cell.traffic["batch"]) // int(
         cell.traffic.get("num_micro_batches") or 1)
     for seed in (int(s) for s in args.seeds.split(",")):
@@ -58,10 +64,11 @@ def main(argv=None) -> int:
         unique = driver.check_batch(cell, builder, seed)[0]
         kept, total, stats, busiest, rows = None, 0, {}, [], []
         for tokens in unique:
-            got = olmoe.routing_stats(builder.to_program(params, cell.config),
-                                      tokens[None], cfg)
+            got = program.routing_stats(
+                builder.to_program(params, cell.config), tokens[None], cfg)
             want = np.asarray(want_fn(params, tokens[:-1]))     # [L, T, k]
             have = np.asarray(got.pop("experts"))
+            got.pop("held_rows", None)
             # Share of a layer's assignments its k busiest experts took:
             # k / E under a balanced router, 1 where every token agrees.
             rows.append([np.bincount(layer.ravel(),
@@ -75,15 +82,25 @@ def main(argv=None) -> int:
             for name, v in got.items():
                 stats[name] = max(stats.get(name, 0), v) \
                     if name.endswith("_max") else stats.get(name, 0) + v
-        stats["moe_expert_rows_mean"] /= len(unique)
+        for name in stats:
+            if name.endswith(("_mean", "_share")):
+                stats[name] /= len(unique)
         flipped = 1.0 - kept / total
+        # What the experts held here got of each sequence's choices.
+        held = np.asarray(rows)[:, :, first:first + count]  # [U, L, count]
+        totals = np.asarray(rows).sum(-1)
         print(json.dumps({
             "workload": cell.name, "seed": seed, "sequences": len(unique),
             "choices_a_layer": int(total), "k": k,
             "flipped_share_by_layer": [float(x) for x in flipped],
             "flipped_share": float(flipped.mean()),
             "busiest_k_experts_share_by_layer":
-                [float(x) for x in np.mean(busiest, axis=0)], **stats,
+                [float(x) for x in np.mean(busiest, axis=0)],
+            "held_share_by_layer": [float(x) for x in np.mean(
+                held.sum(-1) / totals, axis=0)],
+            "held_rows_max_by_layer": held.max(axis=(0, 2)).tolist(),
+            "held_empty_by_layer": [float(x) for x in np.mean(
+                (held == 0).sum(-1), axis=0)], **stats,
             "device": devices[0].device_kind}), flush=True)
     if args.sizes_out:
         os.makedirs(os.path.dirname(os.path.abspath(args.sizes_out)),
