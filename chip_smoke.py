@@ -154,9 +154,12 @@ def _plan(cfg_name: str, batch: int, seq: int, devices, **plan_kwargs):
     return tplan, tokens, {
         "setup_planner_seconds": seconds, **in_plan,
         # Parameter bytes whose gradients a GA step accumulates inside the
-        # loss's layer loop / by the tree-wide add (both 0: one micro batch).
+        # loss's layer loop / by the tree-wide add (both 0: one micro batch);
+        # chunks of the loss whose gradients its forward loop makes (0: the
+        # dense loss).
         **{k: metrics().gauge(k).value
-           for k in ("ga_fused_bytes", "ga_unfused_bytes")},
+           for k in ("ga_fused_bytes", "ga_unfused_bytes",
+                     "ce_fused_chunks")},
         "cache_hit": in_plan["plan_cache_hits"] > 0
         and in_plan["plan_cache_writes"] == 0}
 

@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tepdist_tpu.telemetry import metrics
+
 
 class BlockGradSink:
     """What a gradient-accumulation step hands to :func:`scan_blocks`
@@ -170,15 +172,31 @@ def cross_entropy(x, head, targets, chunk: int = 0):
     tied embedding, a model with an untied head that head), optionally
     chunked.
 
-    Dense path: logits = x @ head.T in one [B, T, V] fp32 tensor. Chunked
-    path (chunk > 0): lax.scan over token chunks with the chunk body
-    checkpointed — forward AND backward hold only [chunk, V] logits at a
-    time; the backward recomputes each chunk's logits from the saved
-    [chunk, D] hidden slice. Summation order changes (per-chunk partial
-    sums), so results match the dense path to float tolerance, not
-    bit-exactly."""
+    Dense path (``chunk <= 0``): logits = x @ head.T in one [B, T, V] fp32
+    tensor, differentiated by autodiff.
+
+    Chunked path (``chunk > 0``): ``lax.scan`` over token chunks, one
+    [chunk, V] logits matmul a chunk. Under differentiation the same loop
+    also makes the gradients (a ``jax.custom_vjp`` whose forward rule is
+    the loop): the loss is a scalar and the last thing the forward does,
+    so each chunk's ``softmax - onehot`` is known the moment its logits
+    are, and ``dx_chunk = d @ head`` and ``dhead += d.T @ xc`` run right
+    there. **Kept** for the backward: ``dx`` [n_chunks, chunk, D] and
+    ``dhead`` [V, D], in their operands' dtypes, which the backward would
+    hold anyway; it multiplies them by the upstream scalar. **Never
+    built**: a [chunk, V] array outside its own chunk's iteration, a
+    second logits matmul, any [tokens, V] array. A call that is not
+    differentiated runs the plain loop. The value is the plain loop's bit
+    for bit; against the dense path the summation order changes (per-chunk
+    partial sums), so results match it to float tolerance.
+
+    The gauge ``ce_fused_chunks`` is set while the call is traced: the
+    chunks whose gradients the forward loop makes, 0 for a dense or an
+    undifferentiated call."""
     B, T, D = x.shape
     n_tokens = B * T
+    fused_chunks = metrics().gauge("ce_fused_chunks")
+    fused_chunks.set(0)
     if chunk <= 0:
         logits = (x @ head.T).astype(jnp.float32)
         logz = jax.nn.logsumexp(logits, axis=-1)
@@ -203,14 +221,48 @@ def cross_entropy(x, head, targets, chunk: int = 0):
     tf = tf.reshape(n_chunks, chunk)
     valid = valid.reshape(n_chunks, chunk)
 
-    @jax.checkpoint
-    def body(acc, inp):
-        xc, tc, mc = inp
+    def chunk_loss(xc, head, tc, mc):
         logits = (xc @ head.T).astype(jnp.float32)       # [chunk, V]
         logz = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
-        return acc + jnp.sum((logz - gold) * mc), None
+        return logits, logz, jnp.sum((logz - gold) * mc)
 
-    total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32),
-                            (xf, tf, valid))
-    return total / n_tokens
+    @jax.custom_vjp
+    def mean_loss(xf, head, tf, valid):
+        def body(acc, inp):
+            xc, tc, mc = inp
+            return acc + chunk_loss(xc, head, tc, mc)[2], None
+
+        total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32),
+                                (xf, tf, valid))
+        return total / n_tokens
+
+    def fwd(xf, head, tf, valid):
+        fused_chunks.set(n_chunks)
+        # The dtype autodiff hands the logits' cotangent back in.
+        d_dtype = jnp.result_type(xf.dtype, head.dtype)
+
+        def body(carry, inp):
+            acc, dhead = carry
+            xc, tc, mc = inp
+            logits, logz, term = chunk_loss(xc, head, tc, mc)
+            onehot = jax.nn.one_hot(tc, logits.shape[-1], dtype=logits.dtype)
+            d = ((jnp.exp(logits - logz[:, None]) - onehot)
+                 * (mc / n_tokens)[:, None]).astype(d_dtype)
+            dxc = (d @ head).astype(xc.dtype)
+            dhead = dhead + jax.lax.dot_general(
+                d, xc, (((0,), (0,)), ((), ()))).astype(dhead.dtype)
+            return (acc + term, dhead), dxc
+
+        (total, dhead), dx = jax.lax.scan(
+            body, (jnp.zeros((), jnp.float32), jnp.zeros_like(head)),
+            (xf, tf, valid))
+        return total / n_tokens, (dx, dhead)
+
+    def bwd(residuals, g):
+        dx, dhead = residuals
+        return ((dx * g).astype(dx.dtype), (dhead * g).astype(dhead.dtype),
+                None, None)
+
+    mean_loss.defvjp(fwd, bwd)
+    return mean_loss(xf, head, tf, valid)

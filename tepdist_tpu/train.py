@@ -417,6 +417,10 @@ def plan_training(
              "layer loop, %.0f by the tree-wide add (%d micro batches)",
              metrics().gauge("ga_fused_bytes").value,
              metrics().gauge("ga_unfused_bytes").value, num_micro_batches)
+    # Set while the loss was traced (models/layers.py:cross_entropy).
+    log.info("chunked cross entropy: %.0f chunks a loss call make their "
+             "gradients in the forward chunk loop",
+             metrics().gauge("ce_fused_chunks").value)
     if (len(devices) > 1 and not plan.sharding_plan.constraints
             and not any(ax for spec in plan.sharding_plan.in_specs
                         for ax in spec)

@@ -348,6 +348,146 @@ def test_gpt2_chunked_cross_entropy_matches_dense(devices):
         g_nd, g_dense)
 
 
+def _ce_checkpointed_loop(x, head, targets, chunk):
+    """The chunked loss as it stood before its gradients moved into the
+    forward loop: the chunk body under ``jax.checkpoint``, autodiff's
+    backward scan recomputing each chunk's logits."""
+    n_tokens, D = x.shape[0] * x.shape[1], x.shape[2]
+    n_chunks = -(-n_tokens // chunk)
+    pad = n_chunks * chunk - n_tokens
+    xf = jnp.concatenate([x.reshape(n_tokens, D),
+                          jnp.zeros((pad, D), x.dtype)])
+    tf = jnp.concatenate([targets.reshape(n_tokens),
+                          jnp.zeros((pad,), targets.dtype)])
+    valid = jnp.concatenate([jnp.ones((n_tokens,), jnp.float32),
+                             jnp.zeros((pad,), jnp.float32)])
+
+    @jax.checkpoint
+    def body(acc, inp):
+        xc, tc, mc = inp
+        logits = (xc @ head.T).astype(jnp.float32)
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
+        return acc + jnp.sum((logz - gold) * mc), None
+
+    total, _ = jax.lax.scan(
+        body, jnp.zeros((), jnp.float32),
+        (xf.reshape(n_chunks, chunk, D), tf.reshape(n_chunks, chunk),
+         valid.reshape(n_chunks, chunk)))
+    return total / n_tokens
+
+
+def _ce_case(dtype, tied):
+    """A head [V, D], hidden states [4, 30, D] (120 tokens: 30 divides, 32
+    leaves a masked tail) and targets; tied, the hidden states are rows
+    of the head, so its gradient has both parts."""
+    from tepdist_tpu.models.layers import cross_entropy
+
+    V, D = 96, 16
+    kh, kx, ki, kt = jax.random.split(jax.random.PRNGKey(32), 4)
+    head = (0.5 * jax.random.normal(kh, (V, D))).astype(dtype)
+    ids = jax.random.randint(ki, (4, 30), 0, V)
+    targets = jax.random.randint(kt, (4, 30), 0, V)
+    x = jax.random.normal(kx, (4, 30, D)).astype(dtype)
+
+    def loss(x, head, chunk, ce=cross_entropy, scale=1.0):
+        h = jnp.tanh(head[ids]) + x if tied else x
+        return scale * ce(h, head, targets, chunk)
+
+    return x, head, loss
+
+
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+@pytest.mark.parametrize("chunk", [30, 32], ids=["dividing", "masked_tail"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_chunked_cross_entropy_value_is_the_checkpointed_loops(
+        dtype, chunk, tied):
+    """Differentiated or not, the chunked loss is the value of the loop it
+    replaces, bit for bit."""
+    x, head, loss = _ce_case(dtype, tied)
+    want = loss(x, head, chunk, ce=_ce_checkpointed_loop)
+    assert float(loss(x, head, chunk)) == float(want)
+    got, _ = jax.value_and_grad(loss, argnums=(0, 1))(x, head, chunk)
+    assert float(got) == float(want)
+
+
+@pytest.mark.parametrize("scale", [1.0, 3.0], ids=["plain", "times3"])
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+@pytest.mark.parametrize("chunk", [30, 32], ids=["dividing", "masked_tail"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_chunked_cross_entropy_grads_match_dense_autodiff(
+        dtype, chunk, tied, scale):
+    """The gradients the forward chunk loop makes are the dense path's
+    autodiff's, for the hidden states and the head, whatever the caller
+    multiplies the loss by: to float32 tolerance in float32, to bf16's
+    resolution (against the float32 gradients of the same bf16 values) in
+    bf16."""
+    x, head, loss = _ce_case(dtype, tied)
+    got = jax.grad(loss, argnums=(0, 1))(x, head, chunk, scale=scale)
+    want = jax.grad(loss, argnums=(0, 1))(
+        x.astype(jnp.float32), head.astype(jnp.float32), 0, scale=scale)
+    for g, w, p in zip(got, want, (x, head)):
+        assert g.dtype == p.dtype and g.shape == p.shape
+        w = np.asarray(w)
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(np.asarray(g), w, rtol=1e-5,
+                                       atol=1e-5 * np.abs(w).max())
+        else:
+            err = np.asarray(g.astype(jnp.float32)) - w
+            assert np.linalg.norm(err) < 2.0 ** -7 * np.linalg.norm(w)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("chunk", [30, 32], ids=["dividing", "masked_tail"])
+def test_chunked_cross_entropy_grad_has_one_logits_matmul_a_chunk(chunk):
+    """One ``[chunk, V]`` product a chunk (the loop's body is traced once)
+    where the checkpointed loop's gradient has two, and nothing for a
+    backward pass to recompute."""
+    from tepdist_tpu.models.layers import cross_entropy
+
+    x, head, loss = _ce_case(jnp.float32, tied=False)
+    V = head.shape[0]
+
+    def counts(ce):
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda x, head: loss(x, head, chunk, ce=ce),
+            argnums=(0, 1)))(x, head).jaxpr
+        eqns = list(_eqns(jaxpr))
+        logits = sum(e.primitive.name == "dot_general"
+                     and e.outvars[0].aval.shape == (chunk, V) for e in eqns)
+        remat = sum(e.primitive.name in ("checkpoint", "remat", "remat2")
+                    for e in eqns)
+        return logits, remat
+
+    assert counts(cross_entropy) == (1, 0)
+    assert counts(_ce_checkpointed_loop) == (2, 1)
+
+
+def test_ce_fused_chunks_gauge_follows_the_traced_loss():
+    """``ce_fused_chunks``: the chunks whose gradients the forward loop
+    makes; 0 for the dense path and for a call nobody differentiates."""
+    from tepdist_tpu.telemetry import metrics
+
+    x, head, loss = _ce_case(jnp.float32, tied=False)
+    gauge = metrics().gauge("ce_fused_chunks")
+    for chunk, chunks in ((30, 4), (32, 4), (50, 3), (0, 0)):
+        gauge.set(-1)
+        jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)),
+                       static_argnums=2)(x, head, chunk)
+        assert gauge.value == chunks, chunk
+    jax.make_jaxpr(loss, static_argnums=2)(x, head, 30)
+    assert gauge.value == 0
+
+
 def test_llama_flash_attention_matches_einsum(devices):
     """llama attn='flash' (pallas kernel after RoPE + GQA broadcast) must
     match the einsum path; grads too. Odd T from the LM token shift takes
