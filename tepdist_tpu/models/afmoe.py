@@ -52,7 +52,6 @@ the body chooses by ``lax.cond`` on its layer's kind
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from typing import Any, Dict, Tuple
 
@@ -62,11 +61,12 @@ import numpy as np
 
 from tepdist_tpu.models.layers import (
     cross_entropy,
+    gqa_heads,
+    held_routing_stats,
     rms_norm,
-    rope,
     scan_blocks,
 )
-from tepdist_tpu.ops.grouped_matmul import route, routed_experts
+from tepdist_tpu.ops.grouped_matmul import routed_experts
 
 WINDOW, GLOBAL = "sliding_attention", "full_attention"
 
@@ -199,35 +199,14 @@ def _layers(params, cfg: AfmoeConfig):
 def attention(blk, a, cfg: AfmoeConfig, window):
     """a [B, T, d] (the normed input) -> the gated heads through ``wo``.
     ``window``: this layer's kind, a bool or a traced scalar (a stack of
-    both kinds: the branch is a ``lax.cond``)."""
-    from tepdist_tpu.ops.pallas.flash_attention import flash_attention
-    B, T, _ = a.shape
-    H, Hkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, \
-        cfg.head_dim
-    eps = cfg.rms_norm_eps
-
-    def heads(t, n):
-        return t.reshape(B, T, n, hd).transpose(0, 2, 1, 3)
-
-    def attend(q, k, v, windowed: bool):
-        if windowed:     # positions are rotary on the window layers alone
-            q, k = rope(q, cfg.rope_theta), rope(k, cfg.rope_theta)
-        return flash_attention(
-            q, k, v, causal=True,
-            window=cfg.sliding_window if windowed else None,
-            block_q=cfg.flash_block_q or None,
-            block_k=cfg.flash_block_k or None)
-
-    q = rms_norm(heads(a @ blk["wq"], H), blk["q_norm"], eps)
-    k = rms_norm(heads(a @ blk["wk"], Hkv), blk["k_norm"], eps)
-    v = heads(a @ blk["wv"], Hkv)
-    if isinstance(window, (bool, np.bool_)):
-        o = attend(q, k, v, bool(window))
-    else:
-        o = jax.lax.cond(window != 0,
-                         functools.partial(attend, windowed=True),
-                         functools.partial(attend, windowed=False), q, k, v)
-    o = o.transpose(0, 2, 1, 3).reshape(B, T, H * hd)
+    both kinds: the branch is a ``lax.cond``). Positions are rotary on the
+    window layers alone."""
+    o = gqa_heads(
+        blk, a, n_head=cfg.num_attention_heads,
+        n_kv_head=cfg.num_key_value_heads, head_dim=cfg.head_dim,
+        eps=cfg.rms_norm_eps, window=cfg.sliding_window, windowed=window,
+        rope_window=cfg.rope_theta, rope_global=None,
+        block_q=cfg.flash_block_q, block_k=cfg.flash_block_k)
     with jax.named_scope("attn_gate"):
         gate = jax.nn.sigmoid((a @ blk["wa"]).astype(jnp.float32))
         o = (o.astype(jnp.float32) * gate).astype(o.dtype)
@@ -391,37 +370,11 @@ def expert_choices(params, tokens, cfg: AfmoeConfig):
 def routing_stats(params, tokens, cfg: AfmoeConfig) -> dict:
     """What the routers did with ``tokens`` [B, T+1], outside any step: the
     expert ids of every expert layer (``experts`` [layers, S, k]), the rows
-    each held expert got (``held_rows`` [layers, count]), and the telemetry
-    counters ``moe_assignments_held`` / ``moe_assignments_elsewhere``,
-    ``moe_tokens_dropped`` (assignments to a held expert that reached no row
-    of the layout: 0 by construction, counted from the layout itself) and
-    gauges ``moe_held_rows_max``, ``moe_held_rows_mean`` (rows one held
-    expert got in one layer) and ``moe_layout_live_share`` (rows holding an
-    assignment over the layout's static rows)."""
-    from tepdist_tpu.telemetry import metrics
-
-    ids = expert_choices(params, tokens[:, :-1], cfg)
-    S = ids.shape[1]
-    sizes, placed, rows = [], 0, 0
-    for experts in ids:
-        r = route(experts, cfg.num_experts, cfg.moe_tile_m, cfg.experts_held)
-        placed += int(jnp.sum(r.row_token < S))
-        rows += int(r.row_token.shape[0])
-        sizes.append(r.group_sizes)
-    sizes = jnp.stack(sizes)
-    held = int(sizes.sum())
-    out = {"moe_assignments_held": held,
-           "moe_assignments_elsewhere": int(ids.size) - held,
-           "moe_tokens_dropped": held - placed,
-           "moe_held_rows_max": int(sizes.max()),
-           "moe_held_rows_mean": float(sizes.mean()),
-           "moe_layout_live_share": held / rows}
-    for name, value in out.items():
-        if name.startswith("moe_assignments") or name == "moe_tokens_dropped":
-            metrics().counter(name).inc(value)
-        else:
-            metrics().gauge(name).set(value)
-    return {**out, "experts": ids, "held_rows": sizes}
+    each held expert got (``held_rows`` [layers, count]) and the counters
+    and gauges of ``models/layers.py:held_routing_stats``."""
+    return held_routing_stats(
+        expert_choices(params, tokens[:, :-1], cfg), cfg.num_experts,
+        cfg.moe_tile_m, cfg.experts_held)
 
 
 def fake_batch(cfg: AfmoeConfig, batch_size: int, seq_len: int,

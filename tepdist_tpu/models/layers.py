@@ -1,17 +1,22 @@
-"""Pieces more than one model of the zoo uses: RMSNorm, rotary positions,
-the (optionally chunked) next-token cross entropy and the walk over stacked
-blocks. One copy, so that a change for one model is seen by the others'
+"""Pieces more than one model of the zoo uses: RMSNorm, rotary positions
+(the plain table and YaRN's), grouped-query attention over layers of two
+kinds, the (optionally chunked) next-token cross entropy, the walk over
+stacked blocks and the counters of an expert layer that holds a share of the
+experts. One copy, so that a change for one model is seen by the others'
 tests and benchmark cells."""
 
 from __future__ import annotations
 
 import contextvars
-from typing import Dict, List, Optional, Tuple
+import functools
+import math
+from typing import Dict, List, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tepdist_tpu.ops.grouped_matmul import route
 from tepdist_tpu.telemetry import metrics
 
 
@@ -151,19 +156,147 @@ def rms_norm(x, g, eps: float = 1e-5):
     return (x32 * scale * g).astype(x.dtype)
 
 
-def rope(x, theta: float):
-    """Rotary embedding over [B, H, T, hd] (rotate-half formulation)."""
+class RopeTable(NamedTuple):
+    """A rotary table that is not the plain one: the angle a position
+    advances in each of the ``head_dim / 2`` rotated pairs, and what cos and
+    sin are multiplied by. Plain floats, so that a configuration that holds
+    one stays hashable."""
+    inv_freq: Tuple[float, ...]
+    scale: float = 1.0
+    name: str = "rope_table"
+
+
+def yarn_table(head_dim: int, theta: float, factor: float,
+               original_max_position: int, beta_fast: float = 32.0,
+               beta_slow: float = 1.0,
+               attention_factor: Optional[float] = None) -> RopeTable:
+    """YaRN's table (Peng et al. 2023, arXiv:2309.00071, as
+    ``transformers``' ``_compute_yarn_parameters`` with ``truncate`` at its
+    default): pair ``i`` turns by ``theta ** (-i / half)`` a position where
+    it completes more than ``beta_fast`` turns over the original context
+    (``i <= low``), by ``1 / factor`` of that where it completes fewer than
+    ``beta_slow`` (``i >= high``), and by the blend in between; cos and sin
+    times ``attention_factor`` (``0.1 ln(factor) + 1`` where none is
+    given)."""
+    half = head_dim // 2
+
+    def correction(turns):
+        return head_dim * math.log(original_max_position
+                                   / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction(beta_fast)), 0)
+    high = min(math.ceil(correction(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    freqs = np.float32(theta) ** (np.arange(half, dtype=np.float32) / half)
+    ramp = np.clip((np.arange(half, dtype=np.float32) - low) / (high - low),
+                   0, 1)
+    inv_freq = (1 - ramp) / freqs + ramp / (factor * freqs)
+    if attention_factor is None:
+        attention_factor = 0.1 * math.log(factor) + 1.0 if factor > 1 else 1.0
+    return RopeTable(tuple(float(f) for f in inv_freq.astype(np.float32)),
+                     float(attention_factor), "rope_yarn")
+
+
+def rope(x, table: Union[float, RopeTable]):
+    """Rotary embedding over [B, H, T, hd] (rotate-half formulation).
+    ``table``: the plain table's ``theta`` (pair ``i`` turns by ``theta **
+    (-i / half)`` a position), or a :class:`RopeTable`."""
     B, H, T, hd = x.shape
     half = hd // 2
-    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
-    angles = jnp.arange(T, dtype=jnp.float32)[:, None] * freqs[None, :]
-    cos = jnp.cos(angles)[None, None, :, :]
-    sin = jnp.sin(angles)[None, None, :, :]
-    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(
-        jnp.float32)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
-                          axis=-1)
-    return out.astype(x.dtype)
+    plain = not isinstance(table, RopeTable)
+    with jax.named_scope("rope_plain" if plain else table.name):
+        if plain:
+            freqs = 1.0 / (table ** (jnp.arange(0, half, dtype=jnp.float32)
+                                     / half))
+        else:
+            freqs = jnp.asarray(table.inv_freq, jnp.float32)
+        angles = jnp.arange(T, dtype=jnp.float32)[:, None] * freqs[None, :]
+        cos = jnp.cos(angles)[None, None, :, :]
+        sin = jnp.sin(angles)[None, None, :, :]
+        if not plain and table.scale != 1.0:
+            cos, sin = cos * table.scale, sin * table.scale
+        x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(
+            jnp.float32)
+        out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                              axis=-1)
+        return out.astype(x.dtype)
+
+
+def gqa_heads(blk, a, *, n_head: int, n_kv_head: int, head_dim: int,
+              eps: float, window: int, windowed, rope_window=None,
+              rope_global=None, block_q: int = 0, block_k: int = 0):
+    """a [B, T, d] (the normed input) -> the attention heads' outputs side
+    by side [B, T, n_head * head_dim], before any gate and before ``wo``:
+    ``n_head`` query heads over ``n_kv_head`` key/value heads, RMSNorm over
+    each head of q and k (``blk``'s ``q_norm``, ``k_norm`` [head_dim]), then
+    by the layer's kind the rotary embedding (``rope_window`` /
+    ``rope_global``: a ``theta``, a :class:`RopeTable`, or None for no
+    position encoding) and the flash kernels, with ``window`` on a window
+    layer (key j visible to query i iff ``0 <= i - j < window``) and plain
+    causal on a global one. ``windowed``: this layer's kind, a bool or a
+    traced scalar (a stack of both kinds: the branch is a ``lax.cond``)."""
+    from tepdist_tpu.ops.pallas.flash_attention import flash_attention
+    B, T, _ = a.shape
+
+    def heads(t, n):
+        return t.reshape(B, T, n, head_dim).transpose(0, 2, 1, 3)
+
+    def attend(q, k, v, windowed: bool):
+        table = rope_window if windowed else rope_global
+        if table is not None:
+            q, k = rope(q, table), rope(k, table)
+        return flash_attention(
+            q, k, v, causal=True, window=window if windowed else None,
+            block_q=block_q or None, block_k=block_k or None)
+
+    q = rms_norm(heads(a @ blk["wq"], n_head), blk["q_norm"], eps)
+    k = rms_norm(heads(a @ blk["wk"], n_kv_head), blk["k_norm"], eps)
+    v = heads(a @ blk["wv"], n_kv_head)
+    if isinstance(windowed, (bool, np.bool_)):
+        o = attend(q, k, v, bool(windowed))
+    else:
+        o = jax.lax.cond(windowed != 0,
+                         functools.partial(attend, windowed=True),
+                         functools.partial(attend, windowed=False), q, k, v)
+    return o.transpose(0, 2, 1, 3).reshape(B, T, n_head * head_dim)
+
+
+def held_routing_stats(ids, num_experts: int, tile_m: int,
+                       held: Tuple[int, int]) -> dict:
+    """What a model's ``routing_stats`` reports of an expert layer that
+    holds ``held = (first, count)`` of the router's ``num_experts``, from
+    the expert ids its routers chose (``ids`` [layers, S, k]), outside any
+    step: the rows each held expert got (``held_rows`` [layers, count]) and
+    the telemetry counters ``moe_assignments_held`` /
+    ``moe_assignments_elsewhere``, ``moe_tokens_dropped`` (assignments to a
+    held expert that reached no row of the layout: 0 by construction,
+    counted from the layout itself) and gauges ``moe_held_rows_max``,
+    ``moe_held_rows_mean`` (rows one held expert got in one layer) and
+    ``moe_layout_live_share`` (rows holding an assignment over the layout's
+    static rows)."""
+    S = ids.shape[1]
+    sizes, placed, rows = [], 0, 0
+    for experts in ids:
+        r = route(experts, num_experts, tile_m, held)
+        placed += int(jnp.sum(r.row_token < S))
+        rows += int(r.row_token.shape[0])
+        sizes.append(r.group_sizes)
+    sizes = jnp.stack(sizes)
+    n_held = int(sizes.sum())
+    out = {"moe_assignments_held": n_held,
+           "moe_assignments_elsewhere": int(ids.size) - n_held,
+           "moe_tokens_dropped": n_held - placed,
+           "moe_held_rows_max": int(sizes.max()),
+           "moe_held_rows_mean": float(sizes.mean()),
+           "moe_layout_live_share": n_held / rows}
+    for name, value in out.items():
+        if name.startswith("moe_assignments") or name == "moe_tokens_dropped":
+            metrics().counter(name).inc(value)
+        else:
+            metrics().gauge(name).set(value)
+    return {**out, "experts": ids, "held_rows": sizes}
 
 
 def cross_entropy(x, head, targets, chunk: int = 0):

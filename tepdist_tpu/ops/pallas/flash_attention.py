@@ -30,17 +30,19 @@ the per-block compute of ring attention. Runs in interpret mode off-TPU
 (tests), compiled on TPU. Reference parity: none — the reference has no
 fused attention at all (SURVEY.md §5.7); this is TPU-native surplus.
 
-Sequence-length limit: T <= 8192 per call. Each grid step holds a whole
-``(1, T, D)`` K and V block (forward, dQ) or Q and dO block (dK/dV) in
-VMEM and only tiles the other operand, so VMEM use grows with T. The
-v5e compiler (16 MiB scoped-VMEM limit) accepts forward+backward at
-(B,H,T,D) = (1,12,8192,64) in bf16 and float32 and (1,4,8192,128) in bf16;
-since the row statistics are lane-dense also (1,12,16384,64) in bf16, and
-it refuses the dK/dV kernel at T = 32768 and float32 at T = 16384 — a
-compile error, never a wrong answer. Longer sequences go through ring
-attention, which calls this kernel per T/P block. tests/test_tpu_compile.py
-compiles the main-path shapes for a described v5e; tools/flash_bench.py
-times the three kernels alone on the chip.
+Sequence length: each grid step holds a whole ``(1, T, D)`` K and V block
+(forward, dQ) or Q and dO block (dK/dV) in VMEM and only tiles the other
+operand, so VMEM use grows with T. Under the v5e compiler's default limit
+for one kernel (16 MiB of scoped VMEM) forward+backward compile up to
+(B,H,T,D) = (1,12,8192,64) in bf16 and float32, (1,32,8192,128) and
+(1,12,16384,64) in bf16: those calls are compiled as they always were. Past
+that (the two whole operands, double-buffered, over 8 MiB: T = 16384 at
+D = 128 in bf16, where all three kernels want 16.5 MiB) the call asks for
+the limit it needs (``_compiler_params``), of the chip's 128 MiB. What still
+does not fit is a compile error, never a wrong answer; longer sequences go
+through ring attention, which calls this kernel per T/P block.
+tests/test_tpu_compile.py compiles the main-path shapes for a described
+v5e; tools/flash_bench.py times the three kernels alone on the chip.
 """
 
 from __future__ import annotations
@@ -53,10 +55,23 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 log = logging.getLogger(__name__)
 
 _NEG_INF = -1e30
+_MIB = 2 ** 20
+
+
+def _compiler_params(T: int, D: int, itemsize: int):
+    """None (the compiler's default scoped-VMEM limit, 16 MiB a kernel)
+    where the two whole-sequence operands of a grid step, double-buffered,
+    leave room under it for the tiles, accumulators and score blocks; past
+    that, a limit of their size and 16 MiB more."""
+    whole = 2 * 2 * T * D * itemsize
+    if whole <= 8 * _MIB:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=whole + 16 * _MIB)
 
 
 _NT = (((1,), (1,)), ((), ()))    # a @ b.T: contract the last dim of both
@@ -352,6 +367,7 @@ def _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((B * H, T // block_q, 1, block_q),
                                  jnp.float32),
         ],
+        compiler_params=_compiler_params(T, D, q.dtype.itemsize),
         interpret=interpret,
     )(qf, kf, vf)
     return o.reshape(B, H, T, D), lse.reshape(B, H, T)
@@ -382,6 +398,7 @@ def _bwd_call(causal, scale, block_q, block_k, interpret, res, do,
     kv_block = _kv_spec((1, block_k, D), group, tiled=True)
     row_block = pl.BlockSpec((1, 1, 1, block_q), lambda b, i: (b, i, 0, 0))
     row_full = pl.BlockSpec((1,) + rows[1:], lambda b, i: (b, 0, 0, 0))
+    vmem = _compiler_params(T, D, q.dtype.itemsize)
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, block_k=block_k, causal=causal,
@@ -397,6 +414,7 @@ def _bwd_call(causal, scale, block_q, block_k, interpret, res, do,
         ],
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
+        compiler_params=vmem,
         interpret=interpret,
     )(qf, kf, vf, dof, lsef, delta)
 
@@ -418,6 +436,7 @@ def _bwd_call(causal, scale, block_q, block_k, interpret, res, do,
             jax.ShapeDtypeStruct((BH, T, D), k.dtype),
             jax.ShapeDtypeStruct((BH, T, D), v.dtype),
         ],
+        compiler_params=vmem,
         interpret=interpret,
     )(qf, kf, vf, dof, lsef, delta)
     shape = (B, H, T, D)

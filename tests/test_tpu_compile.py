@@ -102,12 +102,22 @@ def test_flash_kernels_compile_for_v5e(v5e_devices, shape, dtype, block):
 # at 8192 positions, 512 tiles; a window the tiles divide (its layers'), none
 # (its global layer's) and one they do not divide (the masked-everywhere
 # path).
-@pytest.mark.parametrize("window", [2048, None, 1000])
-def test_windowed_grouped_query_flash_compiles_for_v5e(v5e_devices, window):
+# The Mellum2 cell's: the same heads at 16384 positions, window 1024 (two
+# key blocks wide) and none. There the two whole-sequence operands of a grid
+# step, double-buffered, are 16 MiB, the compiler's default limit for one
+# kernel: the calls ask for more (``flash_attention._compiler_params``), and
+# no call of the shapes above does.
+@pytest.mark.parametrize("T,window", [(8192, 2048), (8192, None),
+                                      (8192, 1000), (16384, 1024),
+                                      (16384, None)])
+def test_windowed_grouped_query_flash_compiles_for_v5e(v5e_devices, T,
+                                                       window):
+    from tepdist_tpu.ops.pallas.flash_attention import _compiler_params
+    assert (_compiler_params(T, 128, 2) is None) == (T == 8192)
     one_chip = SingleDeviceSharding(v5e_devices[0])
-    q = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16,
+    q = jax.ShapeDtypeStruct((1, 32, T, 128), jnp.bfloat16,
                              sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16,
+    kv = jax.ShapeDtypeStruct((1, 4, T, 128), jnp.bfloat16,
                               sharding=one_chip)
 
     def loss(q, k, v):
@@ -124,21 +134,27 @@ def test_windowed_grouped_query_flash_compiles_for_v5e(v5e_devices, window):
         assert tag in name and "?" not in shapes, (name, shapes)
         assert not _PADDED_ROWS.search(shapes), (name, shapes)
         # k and v reach the kernels at their own head count: no broadcast.
-        assert "bf16[4,8192,128]" in shapes, (name, shapes)
+        assert f"bf16[4,{T},128]" in shapes, (name, shapes)
 
 
 # The OLMoE cell's grouped matmuls: 8192 tokens x 8 experts a token in the
 # tile-aligned layout (65536 rows + 64 tiles of pads), 64 experts of
 # 2048 x 1024 (gate, up) and 1024 x 2048 (down).
-@pytest.mark.parametrize("K,N", [(2048, 1024), (1024, 2048)])
-def test_grouped_matmul_kernels_compile_for_v5e(v5e_devices, K, N):
+# The Mellum2 cell's: one sequence of 16384 tokens x 8 choices over the 16
+# held experts of 2304 x 896 (gate, up) and 896 x 2304 (down), in the worst-
+# case layout of 131072 rows + 16 tiles of pads + 1 spare: widths that are
+# no power of two (the blocks fall to 768 = 2304 / 3 and 896).
+@pytest.mark.parametrize("K,N,E,rows", [
+    (2048, 1024, 64, 65536), (1024, 2048, 64, 65536),
+    (2304, 896, 16, 131072 + 256), (896, 2304, 16, 131072 + 256)])
+def test_grouped_matmul_kernels_compile_for_v5e(v5e_devices, K, N, E, rows):
     """Forward, input gradient (weights contracted as stored) and weight
     gradient, not interpreted, at the model's tile."""
     from tepdist_tpu.models.olmoe import CONFIGS
     from tepdist_tpu.ops.pallas import grouped_matmul as gmm
-    tile, E = CONFIGS["1B-7B"].moe_tile_m, 64
+    tile = CONFIGS["1B-7B"].moe_tile_m
     one_chip = SingleDeviceSharding(v5e_devices[0])
-    tiles = 65536 // tile + E
+    tiles = rows // tile + E
 
     def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -156,7 +172,7 @@ def test_grouped_matmul_kernels_compile_for_v5e(v5e_devices, K, N):
     for name in ("tepdist_gmm_fwd", "tepdist_gmm_dx", "tepdist_gmm_dw"):
         assert f"%{name}" in text, name
     assert not [line for line in text.splitlines()    # no transposed weights
-                if " copy(" in line and "= bf16[64," in line]
+                if " copy(" in line and f"= bf16[{E}," in line]
 
 
 def test_flash_bf16_compiles_under_highest_matmul_precision(v5e_devices):

@@ -10,7 +10,8 @@ program did not choose. It also fills the program's routing counters
 model and its reference are the cell's builder's (``builder.program``,
 ``benchmark/reference/<builder>.py``, whose ``hidden`` returns the expert
 ids last): OLMoE's and, with the share of the experts a chip holds
-(``held_share_by_layer``: 1 for a chip that holds them all), AFMoE's.
+(``held_share_by_layer``: 1 for a chip that holds them all), AFMoE's and
+Mellum2's.
 ``--sizes-out FILE`` writes the rows each expert got in each layer from the
 first micro batch of the last seed's check batch, for
 ``tools/gmm_bench.py --sizes FILE``: the groups the cell's kernels meet.
@@ -18,7 +19,8 @@ first micro batch of the last seed's check batch, for
 Weights and sequences are the cell's own (``benchmark/builders/``,
 ``drivers/train_steps.py:check_batch``), one sequence at a time.
 
-Run: chiprun -- python tools/olmoe_flips.py [--workload olmoe-1b-7b.train.s4096]
+Run: chiprun -- python tools/olmoe_flips.py [--workload olmoe-1b-7b.train.s4096 |
+     trinity-mini.train.s8192 | mellum2-12b-a2.5b.train.s16384]
      [--seeds 1,2] [--sizes-out chiprun_out/olmoe_group_sizes.json]
 """
 
