@@ -16,7 +16,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tepdist_tpu.ops.grouped_matmul import route
+from tepdist_tpu.ops.grouped_matmul import (
+    at_rows,
+    layout_index,
+    layout_rows,
+    route,
+)
 from tepdist_tpu.telemetry import metrics
 
 
@@ -272,27 +277,37 @@ def held_routing_stats(ids, num_experts: int, tile_m: int,
     the telemetry counters ``moe_assignments_held`` /
     ``moe_assignments_elsewhere``, ``moe_tokens_dropped`` (assignments to a
     held expert that reached no row of the layout: 0 by construction,
-    counted from the layout itself) and gauges ``moe_held_rows_max``,
-    ``moe_held_rows_mean`` (rows one held expert got in one layer) and
-    ``moe_layout_live_share`` (rows holding an assignment over the layout's
-    static rows)."""
-    S = ids.shape[1]
-    sizes, placed, rows = [], 0, 0
+    counted from the layout itself), ``moe_layout_worst_case`` (layers
+    whose routing took the worst-case size) and gauges
+    ``moe_held_rows_max``, ``moe_held_rows_mean`` (rows one held expert got
+    in one layer), ``moe_layout_live_share`` (rows holding an assignment
+    over the rows of the worst case) and ``moe_layout_rows_share`` (rows of
+    the size each layer takes over the worst case's, mean over layers). Each
+    layer is laid out at the size ``routed_experts`` chooses for it on the
+    device (``layout_rows``, ``layout_index``)."""
+    S, k = ids.shape[1:]
+    ladder = layout_rows(S, k, held[1], num_experts, tile_m)
+    sizes, placed, rows = [], 0, []
     for experts in ids:
         r = route(experts, num_experts, tile_m, held)
+        rows.append(ladder[int(layout_index(r.n_tiles, ladder, tile_m))])
+        r = at_rows(r, rows[-1], tile_m)
         placed += int(jnp.sum(r.row_token < S))
-        rows += int(r.row_token.shape[0])
         sizes.append(r.group_sizes)
     sizes = jnp.stack(sizes)
     n_held = int(sizes.sum())
     out = {"moe_assignments_held": n_held,
            "moe_assignments_elsewhere": int(ids.size) - n_held,
            "moe_tokens_dropped": n_held - placed,
+           "moe_layout_worst_case": sum(
+               m == ladder[-1] for m in rows) if len(ladder) > 1 else 0,
            "moe_held_rows_max": int(sizes.max()),
            "moe_held_rows_mean": float(sizes.mean()),
-           "moe_layout_live_share": n_held / rows}
+           "moe_layout_live_share": n_held / (len(rows) * ladder[-1]),
+           "moe_layout_rows_share": sum(rows) / (len(rows) * ladder[-1])}
     for name, value in out.items():
-        if name.startswith("moe_assignments") or name == "moe_tokens_dropped":
+        if name.startswith("moe_assignments") or name in (
+                "moe_tokens_dropped", "moe_layout_worst_case"):
             metrics().counter(name).inc(value)
         else:
             metrics().gauge(name).set(value)

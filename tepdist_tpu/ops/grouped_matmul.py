@@ -4,10 +4,12 @@
 by expert: every expert's rows start at a multiple of ``tile_m`` and are
 padded to whole tiles (an expert nobody chose keeps one tile), which is the
 layout ``ops/pallas/grouped_matmul.py`` multiplies without masks. No token is
-dropped whatever the skew; the static row count
-``(ceil(S*k / tile_m) + E) * tile_m`` is the worst case, and the tiles past
-the live ones are skipped by the kernels. There is no ``[S, E, C]`` tensor
-and no capacity.
+dropped whatever the skew: the row count is static and large enough for the
+routing at hand, ``(ceil(S*k / tile_m) + E) * tile_m`` for a layer that holds
+every expert (at most a tile's pads a group are dead), one of a few sizes
+chosen on the device for a layer that holds a share (below), and the tiles
+past the live ones are skipped by the kernels. There is no ``[S, E, C]``
+tensor and no capacity.
 
 ``dispatch`` gathers each row's token; ``combine`` gathers each token's k
 rows and sums them. Each is the other's transpose, and each is the other's
@@ -47,12 +49,33 @@ chooses among all experts; a choice of an expert elsewhere sorts past the
 live tiles, gets no row, and its ``dest`` names the last row of a spare
 tile the layout keeps for it, which no group ever reaches: the kernels
 write zeros past the live tiles, so ``combine`` adds nothing for it and
-``dispatch``'s backward nothing either. Dropless stays the guarantee: the
-static row count is the worst case, ``min(k, count)`` rows a token plus one
-tile a group, so every assignment to a held expert reaches a row whatever
-the routing. ``(0, num_experts)`` is the whole layer, the same operations as
-without ``held``. Nothing here stands in for the other ranks or for the
-exchange with them.
+``dispatch``'s backward nothing either. ``(0, num_experts)`` is the whole
+layer, the same operations as without ``held``. Nothing here stands in for
+the other ranks or for the exchange with them.
+
+**How many rows a share's layout has is chosen on the device.** Any token
+*may* send all its choices to held experts, so the worst case is ``min(k,
+count)`` rows a token plus one tile a group and the spare tile; a router
+near balance fills ``count / num_experts`` of that, and every operation on a
+``[rows, ...]`` array (the gathers, the gated activation, the input
+gradients' sum, the kernels' zero blocks past the live tiles) pays for all
+of it. ``layout_rows`` derives the static sizes a layout may take from ``(S,
+k, count, num_experts, tile_m)`` alone: 1.5 times the balanced share's
+tiles, then the worst case, each with the groups' pad tiles and the
+spare tile. ``routed_experts`` lays the assignments out once (the sorts
+are as long whatever the rows: they carry all ``S * k`` choices), reads the
+live tile count ``n_tiles`` off the layout, and runs everything wide (the
+gather into the layout, the three grouped matmuls with the gated activation
+between them, ``combine``, and through autodiff their backward) on the
+layout cut to the smallest size that holds the live tiles and the spare
+tile (``layout_index``, ``at_rows``), inside that size's branch of a
+``switch``. Every branch is in the one compiled program: no host sync, no
+callback, no capacity. **Dropless stays the guarantee:** the last size is
+the worst case, so a routing that overfills the smaller ones takes the
+branch with a row for every assignment whatever the routing, and the values
+are the worst case's bit for bit (a live row holds the same sums in the same
+order; the rows that went held zeros). ``route`` takes the size as
+``rows=``; alone it lays out the worst case.
 
 ``routed_experts`` is the layer around the layout: rows in, the three
 grouped matmuls with the gated activation between them, rows out.
@@ -60,10 +83,12 @@ grouped matmuls with the gated activation between them, rows out.
 
 from __future__ import annotations
 
-from typing import NamedTuple
+import functools
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from tepdist_tpu.ops.pallas.grouped_matmul import grouped_matmul
 
@@ -92,19 +117,61 @@ def _sorted_by(key, value):
     return jax.lax.sort((key, value), num_keys=1, is_stable=True)[1]
 
 
-def route(expert_ids, num_experts: int, tile_m: int, held=None) -> Routing:
+def _held(num_experts: int, held):
+    first, count = held or (0, num_experts)
+    if first < 0 or count < 1 or first + count > num_experts:
+        raise ValueError(f"route: held={held} of {num_experts} experts")
+    return first, count
+
+
+def layout_rows(S: int, k: int, count: int, num_experts: int,
+                tile_m: int) -> Tuple[int, ...]:
+    """The static row counts a layout of ``S x k`` choices over ``count``
+    held of ``num_experts`` experts may take, ascending; the last is the
+    worst case (``min(k, count)`` rows a token, a tile's pads a group and,
+    under a share, the spare tile). A layer that holds every expert has that
+    one size. A share has one more below it, where that is less: 1.5 times
+    the tiles a balanced router's ``S * k * count / num_experts``
+    assignments fill, with the same pad tiles and spare tile (the module
+    docstring). One size below the worst case and not two: a second (2.25
+    times the balanced share) was never taken at either cell's routing and
+    cost 4.5 s of every set-up in tracing and 0.9e9 bytes of residual slots
+    (PERF.md section 6, PR 34)."""
+    worst = -(-S * min(k, count) // tile_m)
+    if count == num_experts:
+        return ((worst + count) * tile_m,)
+    balanced = -(-S * k * count // (num_experts * tile_m))
+    tiles = sorted({min(-(-3 * balanced // 2), worst), worst})
+    return tuple((t + count + 1) * tile_m for t in tiles)
+
+
+def layout_index(n_tiles, sizes, tile_m: int):
+    """Which of ``sizes`` (``layout_rows``) a routing with ``n_tiles`` [1]
+    live tiles takes: the smallest that holds them and the spare tile."""
+    need = n_tiles[0] + 1
+    return sum((need > m // tile_m).astype(jnp.int32) for m in sizes[:-1])
+
+
+def route(expert_ids, num_experts: int, tile_m: int, held=None,
+          rows=None) -> Routing:
     """The tile-aligned layout of ``expert_ids`` [S, k]; with ``held =
     (first, count)`` that of the assignments to experts ``first .. first +
-    count - 1`` alone, over ``count`` groups (the module docstring)."""
+    count - 1`` alone, over ``count`` groups (the module docstring).
+    ``rows``: the layout's static row count, the worst case (its default and
+    upper limit) or one of ``layout_rows``' smaller sizes, which the caller
+    has chosen to hold ``n_tiles`` and the spare tile (``layout_index``):
+    the worst case's layout cut to its first ``rows`` rows (``at_rows``)."""
     S, k = expert_ids.shape
-    first, E = held or (0, num_experts)
-    if first < 0 or E < 1 or first + E > num_experts:
-        raise ValueError(f"route: held={held} of {num_experts} experts")
+    first, E = _held(num_experts, held)
     share = E < num_experts
     A = S * k
     # Rows: min(k, E) a token at most, a tile's pads a group, and under a
     # share the spare tile; sorted are all A choices with the candidates.
     M = (-(-S * min(k, E) // tile_m) + E + share) * tile_m
+    if rows is not None and (
+            rows % tile_m or not (E + share) * tile_m <= rows <= M):
+        raise ValueError(f"route: rows={rows} of at most {M}, "
+                         f"tile_m={tile_m}, held={held}")
     L = max(M, A + E * tile_m)
     flat = expert_ids.reshape(A).astype(jnp.int32)
     if first:
@@ -144,11 +211,26 @@ def route(expert_ids, num_experts: int, tile_m: int, held=None) -> Routing:
         live = jnp.arange(M, dtype=jnp.int32) < tile_end[-1] * tile_m
         row_token = jnp.where(live, row_token, S)
         dest = jnp.minimum(dest, M - 1)
-    return Routing(
+    r = Routing(
         row_token=row_token, row_assignment=row_assignment,
         dest=dest.reshape(S, k), tile_group=tile_group,
         n_tiles=tile_end[-1:].astype(jnp.int32), group_sizes=group_sizes,
         sort_key=sort_key)
+    return r if rows is None else at_rows(r, rows, tile_m)
+
+
+def at_rows(r: Routing, rows: int, tile_m: int) -> Routing:
+    """A share's layout cut to its first ``rows`` rows, a whole number of
+    tiles that holds ``r.n_tiles`` live tiles and one more: the rows and
+    tiles past them go, and a choice of an expert elsewhere names the last
+    row that is left, which is in a tile no group reaches. The sorted order
+    (``row_assignment``, ``sort_key``) stays whole: it is how values ride in
+    and out, whatever the rows."""
+    if rows == r.row_token.shape[0]:
+        return r
+    return r._replace(row_token=r.row_token[:rows],
+                      dest=jnp.minimum(r.dest, rows - 1),
+                      tile_group=r.tile_group[:rows // tile_m])
 
 
 def _rows(x, source):
@@ -271,6 +353,27 @@ def _gated_bwd(res, ct):
 gated.defvjp(_gated_fwd, _gated_bwd)
 
 
+def routed_experts_at(h, weights, r: Routing, w_gate, w_up, w_down,
+                      tile_m: int):
+    """``routed_experts`` over the layout ``r`` as it is handed in: the
+    worst case's, or that cut to a size that holds the routing
+    (``at_rows``)."""
+    with jax.named_scope("moe_dispatch"):
+        x = dispatch(h, r.row_token, r.dest)
+        row_weight = dispatch_values(weights, r)
+    with jax.named_scope("moe_experts"):
+        def gmm(a, w):
+            return grouped_matmul(a, w, r.tile_group, r.n_tiles, tile_m)
+        # The router's weight goes on the row before the down projection
+        # (W (w a) = w (W a)): the projected rows then need no keeping for
+        # the weight's gradient, 320 MiB a micro batch at the 1B-7B sizes.
+        # On a pad row it is exactly 0, as the row itself is.
+        act = gated(gmm(x, w_gate), gmm(x, w_up), row_weight)
+        out_rows = gmm(act, w_down)
+    with jax.named_scope("moe_combine"):
+        return combine(out_rows, r.row_token, r.dest)
+
+
 def routed_experts(h, weights, experts, w_gate, w_up, w_down,
                    num_experts: int, tile_m: int, held=None):
     """The routed SwiGLU experts' part of a layer: h [S, d], the router's
@@ -280,19 +383,96 @@ def routed_experts(h, weights, experts, w_gate, w_up, w_down,
     w_gate[e_j] h) * w_up[e_j] h)``. With ``held = (first, count)`` the
     layer holds experts ``first .. first + count - 1`` (``G = count``) and a
     choice of any other contributes nothing: the caller hands in weight 0
-    for it. No token is dropped (``route``)."""
+    for it. No token is dropped (``route``). A share of the experts is laid
+    out once and runs everything wide at the smallest of ``layout_rows``'
+    sizes that its routing fits, chosen by a ``switch`` on the device; the
+    whole layer has one size and no ``switch``."""
     with jax.named_scope("moe_dispatch"):
         r = route(experts, num_experts, tile_m, held)
-        rows = dispatch(h, r.row_token, r.dest)
-        row_weight = dispatch_values(weights, r)
-    with jax.named_scope("moe_experts"):
-        def gmm(a, w):
-            return grouped_matmul(a, w, r.tile_group, r.n_tiles, tile_m)
-        # The router's weight goes on the row before the down projection
-        # (W (w a) = w (W a)): the projected rows then need no keeping for
-        # the weight's gradient, 320 MiB a micro batch at the 1B-7B sizes.
-        # On a pad row it is exactly 0, as the row itself is.
-        act = gated(gmm(rows, w_gate), gmm(rows, w_up), row_weight)
-        out_rows = gmm(act, w_down)
-    with jax.named_scope("moe_combine"):
-        return combine(out_rows, r.row_token, r.dest)
+    sizes = layout_rows(*experts.shape, _held(num_experts, held)[1],
+                        num_experts, tile_m)
+    if len(sizes) == 1:
+        return routed_experts_at(h, weights, r, w_gate, w_up, w_down, tile_m)
+    return _switch(
+        layout_index(r.n_tiles, sizes, tile_m),
+        [lambda r, h, weights, *w, m=m: _branch(
+            h, weights, at_rows(r, m, tile_m), *w, tile_m=tile_m)
+         for m in sizes],
+        r, h, weights, w_gate, w_up, w_down)
+
+
+def _switch(index, branches, r: Routing, *args):
+    """``jax.lax.switch(index, branches, r, *args)`` (each branch
+    ``routed_experts_at`` at one size; differentiable in ``args``) whose
+    backward hands each size's residuals over in slots of their
+    own and **leaves the untaken size's slots unwritten**. Autodiff's own
+    rule for a conditional fills them with zeros: rows ``[m, d]`` and three
+    ``[m, f]`` arrays for every size but the one taken, 1.35 GB a micro
+    batch and layer at the Mellum2 cell's shapes, 2% of its step in writes
+    nobody reads. Here the forward rule is a ``switch`` whose branch ``i``
+    returns the leaves of its ``jax.vjp`` pullback (its residuals) in slot
+    ``i`` and arrays no kernel wrote in the others; the backward rule is a
+    ``switch`` whose branch ``i`` calls slot ``i``'s pullback. The gradients
+    are autodiff's of the size taken."""
+    taken = range(len(branches))
+
+    @jax.custom_vjp
+    def chosen(index, r, *args):
+        return jax.lax.switch(index, branches, r, *args)
+
+    # Slot i holds the leaves of size i's pullback; what puts them together
+    # again is static, made while the forward rule is traced and read by
+    # the backward rule, which is traced after it.
+    trees = {}
+
+    def fwd(index, r, *args):
+        def residuals(i, r, *args):
+            out, pull = jax.vjp(functools.partial(branches[i], r), *args)
+            leaves, trees[i] = jax.tree_util.tree_flatten(pull)
+            return out, leaves
+
+        # Traced once a size: the shapes first, the branch from the cache.
+        sized = [jax.jit(functools.partial(residuals, i), inline=True)
+                 for i in taken]
+        avals = [jax.eval_shape(f, r, *args)[1] for f in sized]
+
+        def branch(i, r, *args):
+            out, leaves = sized[i](r, *args)
+            return out, [leaves if j == i
+                         else [_unwritten(a) for a in avals[j]]
+                         for j in taken]
+
+        out, slots = jax.lax.switch(
+            index, [functools.partial(branch, i) for i in taken], r, *args)
+        return out, (index, slots)
+
+    def bwd(residuals, g):
+        index, slots = residuals
+        return (None, None) + jax.lax.switch(
+            index, [lambda slots, g, i=i: jax.tree_util.tree_unflatten(
+                trees[i], slots[i])(g) for i in taken], slots, g)
+
+    chosen.defvjp(fwd, bwd)
+    return chosen(index, r, *args)
+
+
+@functools.partial(jax.jit, static_argnums=0, inline=True)
+def _unwritten(aval):
+    """An array of ``aval``'s shape and dtype that nothing writes: what
+    stands in a residual slot nobody will read. A kernel with no input and
+    an empty body, whose result stays in HBM as it was allocated; zeros
+    where a fill costs less than a kernel's start."""
+    if aval.size * aval.dtype.itemsize < 1 << 20:
+        return jnp.zeros(aval.shape, aval.dtype)
+    return pl.pallas_call(
+        lambda out: None, name="tepdist_unwritten",
+        out_shape=jax.ShapeDtypeStruct(aval.shape, aval.dtype),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        interpret=jax.default_backend() == "cpu")()
+
+
+# A branch is traced once a shape and a process, however many passes (the
+# forward walk, the backward walk's recompute, the planner's trace and the
+# lowering's) trace the ``switch`` around it: two sizes would else double
+# what tracing the expert layer costs every set-up.
+_branch = jax.jit(routed_experts_at, inline=True, static_argnames="tile_m")

@@ -333,6 +333,11 @@ def _kv_spec(block, group: int, tiled: bool):
                         else (lambda b, i: (b // group, 0, 0)))
 
 
+# The two calls below are traced once a shape and a process (an inlined
+# ``jit``: the same equations in the caller's program, the kernel bodies
+# traced once): a step's trace meets them in every pass over a layer.
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "causal", "scale", "block_q", "block_k", "interpret", "window"))
 def _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
               window=None):
     B, H, T, D = q.shape
@@ -373,6 +378,8 @@ def _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
     return o.reshape(B, H, T, D), lse.reshape(B, H, T)
 
 
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "causal", "scale", "block_q", "block_k", "interpret", "window"))
 def _bwd_call(causal, scale, block_q, block_k, interpret, res, do,
               dlse=None, window=None):
     q, k, v, o, lse = res

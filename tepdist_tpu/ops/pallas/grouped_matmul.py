@@ -9,9 +9,9 @@ one group. The kernels then need no mask and no tile visited twice: a scalar
 prefetched ``tile_group[i]`` picks the weight block of row tile ``i``, and
 consecutive tiles of one group leave the weight block (or, in the weight
 gradient, the accumulator) where it is in VMEM. Tiles past ``n_tiles[0]``
-(the layout's static size is the worst case) are skipped: their operands'
-block indices are clamped to the last live tile, so nothing is fetched for
-them, and their output rows are written as zeros.
+(the layout's static size holds more than the routing fills) are skipped:
+their operands' block indices are clamped to the last live tile, so nothing
+is fetched for them, and their output rows are written as zeros.
 
 * ``gmm``:  ``out[rows of tile i] = x[rows] @ w[tile_group[i]]``; with
   ``transpose_rhs`` the weight is contracted over its last dim, which is the
@@ -88,6 +88,14 @@ def _gmm_kernel(tile_group, n_tiles, x_ref, w_ref, o_ref, *, dims):
         o_ref[...] = jnp.zeros_like(o_ref)
 
 
+# ``gmm`` and ``tgmm`` are traced once a shape and a process (an inlined
+# ``jit``: the same equations in the caller's program): a step's trace meets
+# each of them in the forward walk, in the backward walk's recompute and in
+# its backward, once for the planner and once for the lowering, and a kernel
+# body's trace is the larger part of a call's.
+@functools.partial(
+    jax.jit, inline=True, static_argnames=(
+        "tile_m", "transpose_rhs", "block_n", "name", "interpret"))
 def gmm(x, w, tile_group, n_tiles, *, tile_m: int, transpose_rhs=False,
         block_n: int = 1024, name: str = "tepdist_gmm_fwd", interpret=None):
     """x [M, K] in the tile-aligned layout times its tile's group's weight:
@@ -154,6 +162,9 @@ def _tgmm_kernel(tile_group, n_tiles, x_ref, dy_ref, o_ref, acc_ref):
             o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
 
+@functools.partial(
+    jax.jit, inline=True, static_argnames=(
+        "num_groups", "tile_m", "block_n", "name", "interpret"))
 def tgmm(x, dy, tile_group, n_tiles, num_groups: int, *, tile_m: int,
          block_n: int = 1024,
          name: str = "tepdist_gmm_dw", interpret=None):

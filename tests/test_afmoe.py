@@ -256,6 +256,12 @@ def test_no_assignment_to_a_held_expert_is_dropped(send):
     assert held == stats["moe_assignments_held"]
     assert metrics().gauge("moe_held_rows_max").value \
         == stats["moe_held_rows_max"]
+    # The size each layer's layout takes: the worst case only where the
+    # routing fills it.
+    assert stats["moe_layout_worst_case"] == {"all_held": layers}.get(send, 0)
+    assert (stats["moe_layout_rows_share"] < 1) == (send != "all_held")
+    assert metrics().gauge("moe_layout_rows_share").value \
+        == stats["moe_layout_rows_share"]
     # The gradient runs whatever the routing (no live tile, or all of them).
     loss, grads = loss_and_grads(params, tokens, cfg)
     assert np.isfinite(float(loss))
@@ -312,6 +318,166 @@ def test_route_over_a_share_places_every_held_assignment(held, k):
         g = jnp.asarray(rng.normal(size=out.shape), jnp.float32)
         back = np.asarray(pull(g)[0])
         np.testing.assert_array_equal(back[is_held], np.asarray(g)[rows, 0])
+
+
+# -- the size a share's layout takes, chosen on the device -------------------
+
+@pytest.mark.parametrize("S,k,count,E,tile,want", [
+    (8192, 8, 32, 128, 256, (33024, 73984)),            # the Trinity cell
+    (16384, 8, 16, 64, 256, (53504, 135424)),           # the Mellum2 cell
+    (8192, 8, 64, 64, 256, (81920,)),                   # OLMoE: every expert
+    (64, 2, 4, 16, 8, (88, 168)),                       # the test presets
+    (64, 2, 8, 16, 8, (168, 200)),
+    (64, 8, 1, 16, 8, (64, 80)),        # one expert held: a row a token
+    (40, 4, 2, 16, 8, (64, 104)),
+    (4, 2, 2, 4, 8, (32,)),             # half held, one tile: nothing below
+])
+def test_the_layouts_sizes_follow_from_the_shapes_alone(S, k, count, E, tile,
+                                                        want):
+    """1.5 times the balanced share's tiles where that is under the worst
+    case, then the worst case, which is ``route``'s own size; each with a
+    pad tile a group and the spare tile. No size is another dimension of
+    either cell's program (its operations are found by ``[rows,`` in a
+    trace)."""
+    sizes = gm.layout_rows(S, k, count, E, tile)
+    assert sizes == want and list(sizes) == sorted(set(sizes))
+    ids = jnp.zeros((S, k), jnp.int32)
+    held = (0, count)
+    assert gm.route(ids, E, tile, held=held).row_token.shape == (sizes[-1],)
+    for rows in sizes:
+        assert rows % tile == 0
+        assert gm.route(ids, E, tile, held=held,
+                        rows=rows).row_token.shape == (rows,)
+        assert rows not in (8192, 16384, 65536, 131072, 25024, 12288)
+    with pytest.raises(ValueError):
+        gm.route(ids, E, tile, held=held, rows=sizes[-1] + tile)
+    with pytest.raises(ValueError):
+        gm.route(ids, E, tile, held=held, rows=sizes[0] + 1)
+
+
+def _ids_filling(tiles, S, k, held, E, tile):
+    """Expert ids [S, k] whose held assignments fill exactly ``tiles`` live
+    tiles: whole tiles of first choices for the first held expert, of second
+    choices for the second, one (empty) tile each for the others; every
+    other choice goes to an expert elsewhere."""
+    first, count = held
+    elsewhere = [e for e in range(E) if not first <= e < first + count]
+    ids = np.asarray(elsewhere)[
+        np.arange(S * k).reshape(S, k) % len(elsewhere)]
+    both = tiles - (count - 2)
+    a = min(S // tile, both - 1)
+    assert a >= 1 and 1 <= both - a <= S // tile
+    ids[:a * tile, 0] = first
+    ids[:(both - a) * tile, 1] = first + 1
+    return jnp.asarray(ids, jnp.int32)
+
+
+def _layer_and_gradients(layer, h, weights, experts, w, held, E, tile):
+    def out(h, weights, w_gate, w_up, w_down):
+        y = layer(h, jnp.where((experts >= held[0])
+                               & (experts < sum(held)), weights, 0.0),
+                  experts, w_gate, w_up, w_down, num_experts=E, tile_m=tile,
+                  held=held)
+        return jnp.sum(y * jnp.cos(jnp.arange(y.size).reshape(y.shape))), y
+
+    (_, y), grads = jax.jit(jax.value_and_grad(
+        out, argnums=(0, 1, 2, 3, 4), has_aux=True))(h, weights, *w)
+    return (y,) + grads
+
+
+@pytest.mark.parametrize("fill", [
+    "no_assignment", "first_size_full", "worst_case_by_a_tile",
+    "every_choice_held"])
+def test_every_size_gives_the_worst_case_layouts_values_bit_for_bit(fill):
+    """The routed experts' output and every gradient (rows in, router
+    weights, the three expert weights) under a routing that fills the first
+    size to its last tile and one past it, and at both ends: what the
+    worst-case layout gives."""
+    S, k, E, tile, d, f = 64, 2, 16, 8, 16, 24
+    held = (4, 4)
+    sizes = gm.layout_rows(S, k, held[1], E, tile)          # 88, 168
+    spare = 1
+    tiles, rung = {
+        "no_assignment": (None, 0),
+        "first_size_full": (sizes[0] // tile - spare, 0),
+        "worst_case_by_a_tile": (sizes[0] // tile - spare + 1, 1),
+        "every_choice_held": (None, 1)}[fill]
+    if fill == "no_assignment":
+        experts = jnp.zeros((S, k), jnp.int32)
+    elif fill == "every_choice_held":
+        experts = jnp.asarray(
+            4 + (np.arange(S * k).reshape(S, k) % 2) * 2, jnp.int32)
+    else:
+        experts = _ids_filling(tiles, S, k, held, E, tile)
+    n_tiles = gm.route(experts, E, tile, held).n_tiles
+    if tiles is not None:
+        assert int(n_tiles[0]) == tiles
+    assert int(gm.layout_index(n_tiles, sizes, tile)) == rung
+    keys = jax.random.split(KEY, 5)
+    h = jax.random.normal(keys[0], (S, d))
+    weights = jax.random.uniform(keys[1], (S, k))
+    w = (jax.random.normal(keys[2], (held[1], d, f)) * 0.3,
+         jax.random.normal(keys[3], (held[1], d, f)) * 0.3,
+         jax.random.normal(keys[4], (held[1], f, d)) * 0.3)
+
+    def chosen(*args, num_experts, tile_m, held):
+        return gm.routed_experts(*args, num_experts, tile_m, held=held)
+
+    got = _layer_and_gradients(chosen, h, weights, experts, w, held, E, tile)
+    def worst_case(h, weights, experts, *w, num_experts, tile_m, held):
+        return gm.routed_experts_at(
+            h, weights, gm.route(experts, num_experts, tile_m, held), *w,
+            tile_m)
+
+    want = _layer_and_gradients(worst_case, h, weights, experts, w, held, E,
+                                tile)
+    if fill != "no_assignment":
+        assert float(jnp.abs(want[0]).max()) > 1e-3
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("send", ["all_held", "none_held", "mixed"])
+def test_the_layers_values_do_not_depend_on_the_size_taken(send, monkeypatch):
+    """``afmoe.moe`` under a router forced to each end and the seed's: the
+    output and the gradient of every leaf of the block (router, bias,
+    experts, shared expert) and of the input are those of the program whose
+    ladder is the worst case alone, and the program holds one ``cond`` of
+    two branches each way."""
+    cfg = CFG
+    first, count = cfg.experts_held
+    blk = afmoe.init_params(cfg, KEY)[f"l{LAYERS[0]}"]
+    bias = {"all_held": 10.0, "none_held": -10.0, "mixed": 0.0}[send]
+    blk["router_bias"] = blk["router_bias"].at[first:first + count].set(bias)
+    x = jax.random.normal(jax.random.PRNGKey(9), (2, 32, cfg.hidden_size))
+    experts = afmoe.router(blk, x.reshape(64, -1), cfg)[2]
+    sizes = gm.layout_rows(64, cfg.num_experts_per_tok, count,
+                           cfg.num_experts, cfg.moe_tile_m)
+    taken = int(gm.layout_index(gm.route(
+        experts, cfg.num_experts, cfg.moe_tile_m, cfg.experts_held).n_tiles,
+        sizes, cfg.moe_tile_m))
+    assert len(sizes) == 2
+    assert {"all_held": taken == 1, "none_held": taken == 0,
+            "mixed": True}[send]
+
+    def values():
+        def out(blk, x):
+            y = afmoe.moe(blk, x, cfg)
+            return jnp.sum(y * jnp.sin(jnp.arange(y.size).reshape(y.shape))), y
+        fn = jax.value_and_grad(out, argnums=(0, 1), has_aux=True)
+        conds = [e for e in jax.make_jaxpr(fn)(blk, x).jaxpr.eqns
+                 if e.primitive.name == "cond"]
+        return jax.jit(fn)(blk, x), conds
+
+    got, conds = values()
+    assert [len(e.params["branches"]) for e in conds] == [2, 2]
+    whole = gm.layout_rows
+    monkeypatch.setattr(gm, "layout_rows", lambda *a: whole(*a)[-1:])
+    want, conds = values()
+    assert not conds
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # -- the flash kernels' window and grouped-query heads ----------------------

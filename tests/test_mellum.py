@@ -13,6 +13,7 @@ import pytest
 
 from benchmark.reference import mellum as ref
 from tepdist_tpu.models import layers, mellum
+from tepdist_tpu.ops import grouped_matmul as gm
 from tepdist_tpu.optim import make_optimizer
 from tepdist_tpu.parallel.sync_free import build_ga_step
 from tepdist_tpu.telemetry import metrics
@@ -242,12 +243,63 @@ def test_no_assignment_to_a_held_expert_is_dropped(send):
     assert stats["held_rows"].shape == (L, 4)
     assert metrics().gauge("moe_held_rows_max").value \
         == stats["moe_held_rows_max"]
+    # The size each layer's layout takes: the worst case only where the
+    # routing fills it.
+    assert stats["moe_layout_worst_case"] == {"all_held": L}.get(send, 0)
+    assert (stats["moe_layout_rows_share"] < 1) == (send != "all_held")
+    if send == "none_held":     # the first size of 88 and 168 rows
+        assert stats["moe_layout_rows_share"] == pytest.approx(88 / 168)
+    assert metrics().gauge("moe_layout_rows_share").value \
+        == stats["moe_layout_rows_share"]
     # The gradient runs whatever the routing (no live tile, or all of them).
     loss, grads = loss_and_grads(params, tokens, cfg)
     assert all(np.isfinite(np.asarray(g)).all()
                for g in jax.tree_util.tree_leaves(grads))
     want_loss = ref.loss(to_reference(params, cfg), tokens, hyper(cfg))
     assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
+
+
+@pytest.mark.parametrize("send", ["all_held", "none_held", "mixed"])
+def test_the_layers_values_do_not_depend_on_the_size_taken(send, monkeypatch):
+    """``mellum.moe`` under a router forced to each end and the seed's: the
+    output and the gradient of the router, of the three expert weights and
+    of the input are those of the program whose ladder is the worst case
+    alone. ``all_held`` takes the worst case, ``none_held`` the first
+    size."""
+    cfg = dataclasses.replace(
+        CFG, experts_held={"all_held": (0, 4)}.get(send, (4, 4)))
+    blk = mellum.init_params(cfg, KEY)["l1"]
+    if send != "mixed":
+        # Equal probabilities: ``top_k`` takes experts 0 and 1. A router of
+        # exact zeros would have no gradient to compare.
+        blk["router"] = blk["router"] * 1e-30
+    x = jax.random.normal(jax.random.PRNGKey(9), (2, 32, cfg.hidden_size))
+    experts = mellum.router(blk, x.reshape(64, -1), cfg)[1]
+    sizes = gm.layout_rows(64, cfg.num_experts_per_tok, 4, cfg.num_experts,
+                           cfg.moe_tile_m)
+    taken = int(gm.layout_index(gm.route(
+        experts, cfg.num_experts, cfg.moe_tile_m, cfg.experts_held).n_tiles,
+        sizes, cfg.moe_tile_m))
+    assert sizes == (88, 168)
+    assert taken == {"all_held": 1, "none_held": 0}.get(send, taken)
+
+    def values():
+        def out(blk, x):
+            y = mellum.moe(blk, x, cfg)
+            return jnp.sum(y * jnp.sin(jnp.arange(y.size).reshape(y.shape))), y
+        return jax.jit(jax.value_and_grad(out, argnums=(0, 1),
+                                          has_aux=True))(blk, x)
+
+    got = values()
+    whole = gm.layout_rows
+    monkeypatch.setattr(gm, "layout_rows", lambda *a: whole(*a)[-1:])
+    want = values()
+    if send != "none_held":
+        assert float(jnp.abs(want[0][1]).max()) > 1e-4
+        assert float(jnp.abs(want[1][0]["router"]).max()) > 0
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_both_held_share_models_report_through_one_implementation():

@@ -255,16 +255,19 @@ def test_pads_contribute_nothing(sizes, k):
         assert rel(got, want) < TOL
 
 
-def _equations(jaxpr):
-    """Every equation of ``jaxpr`` and of the jaxprs its equations hold."""
+def _equations(jaxpr, skip=()):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations hold
+    (not those of a primitive named in ``skip``)."""
     for eqn in jaxpr.eqns:
         yield eqn
+        if eqn.primitive.name in skip:
+            continue
         for value in eqn.params.values():
             for held in value if isinstance(value, (list, tuple)) else [
                     value]:
                 held = getattr(held, "jaxpr", held)
                 if hasattr(held, "eqns"):
-                    yield from _equations(held)
+                    yield from _equations(held, skip)
 
 
 @pytest.mark.parametrize("what", ["forward", "gradient"])
@@ -299,6 +302,91 @@ def test_moe_has_no_wide_select_and_no_look_up_a_row(params, what):
         and e.invars[0].aval.shape in ((E,), (rows // tile,))
         and e.outvars[0].aval.size >= rows]
     assert not look_ups, look_ups
+
+
+def _one_by_one(h, weights, experts, w_gate, w_up, w_down, num_experts,
+                tile_m, held=None):
+    """``routed_experts`` as it stood before a share's layout took its size
+    on the device: the layout at its one size and the pieces called one by
+    one (what the whole layer is held to, operation for operation)."""
+    r = gm.route(experts, num_experts, tile_m, held)
+    rows = gm.dispatch(h, r.row_token, r.dest)
+    row_weight = gm.dispatch_values(weights, r)
+
+    def mm(a, w):
+        return gmk.grouped_matmul(a, w, r.tile_group, r.n_tiles, tile_m)
+
+    act = gm.gated(mm(rows, w_gate), mm(rows, w_up), row_weight)
+    return gm.combine(mm(act, w_down), r.row_token, r.dest)
+
+
+@pytest.mark.parametrize("held", [None, "whole"])
+@pytest.mark.parametrize("what", ["forward", "gradient"])
+def test_the_whole_layer_has_one_size_and_no_conditional(params, what, held):
+    """A layer that holds every expert (``held=None`` or ``(0, E)``) has
+    nothing to choose: no ``cond`` is traced, and its operations are those
+    of ``route`` + ``dispatch`` + kernels + ``combine`` called one by one,
+    in their order."""
+    cfg = ONE_LAYER
+    blk = jax.tree_util.tree_map(lambda a: a[0], params["blocks"])
+    h = jnp.ones((32, cfg.hidden_size), jnp.float32)
+    E, tile = cfg.num_experts, cfg.moe_tile_m
+    held = (0, E) if held else None
+    assert len(gm.layout_rows(32, cfg.num_experts_per_tok, E, E, tile)) == 1
+
+    def primitives(layer):
+        def out(blk, h):
+            _, _, weights, experts = olmoe.router(blk, h, cfg)
+            y = layer(h, weights, experts, blk["w_gate"], blk["w_up"],
+                      blk["w_down"], E, tile, held=held)
+            return jnp.sum(y * y)
+
+        fn = out if what == "forward" else jax.grad(out, (0, 1))
+        return [e.primitive.name for e in _equations(
+            jax.make_jaxpr(fn)(blk, h).jaxpr, skip=("pallas_call",))]
+
+    got = primitives(gm.routed_experts)
+    assert "cond" not in got and got.count("pallas_call") == (
+        3 if what == "forward" else 9)
+    assert got == primitives(_one_by_one)
+
+
+def test_two_steps_of_the_whole_layer_are_the_one_by_one_layers(
+        params, monkeypatch):
+    """The state after two accumulated steps at the test preset, with the
+    expert layer as it is and with the pieces called one by one: bit for
+    bit."""
+    from tepdist_tpu.optim import make_optimizer
+    from tepdist_tpu.parallel.sync_free import build_ga_step
+    cfg = dataclasses.replace(CFG, remat=True, loss_chunk=16)
+    batches = [olmoe.fake_batch(cfg, 4, 16, seed=s) for s in (2, 3)]
+
+    def two_steps():
+        tx = make_optimizer({"name": "adamw_bf16", "learning_rate": 1e-3})
+
+        def loss(p, t):
+            return olmoe.loss_fn(p, t, cfg)
+
+        def apply_fn(p, s, g):
+            updates, s = tx.update(g, s, p)
+            return optax.apply_updates(p, updates), s
+
+        step = jax.jit(build_ga_step(
+            lambda p, t: jax.value_and_grad(loss)(p, t), apply_fn, 2,
+            loss_fn=loss))
+        p, state, losses = params, tx.init(params), []
+        for tokens in batches:
+            value, p, state = step(p, state, tokens)
+            losses.append(float(value))
+        return losses, p, state
+
+    got = two_steps()
+    monkeypatch.setattr(olmoe, "routed_experts", _one_by_one)
+    want = two_steps()
+    assert got[0] == want[0]
+    for a, b in zip(jax.tree_util.tree_leaves(got[1:]),
+                    jax.tree_util.tree_leaves(want[1:])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # --------------------------------------------------------------------------

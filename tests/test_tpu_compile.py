@@ -144,9 +144,16 @@ def test_windowed_grouped_query_flash_compiles_for_v5e(v5e_devices, T,
 # held experts of 2304 x 896 (gate, up) and 896 x 2304 (down), in the worst-
 # case layout of 131072 rows + 16 tiles of pads + 1 spare: widths that are
 # no power of two (the blocks fall to 768 = 2304 / 3 and 896).
+# The size below the worst case that a held share's layout may take on the
+# device (``ops/grouped_matmul.py:layout_rows``): Mellum2's 53,504 rows, and
+# the Trinity cell's 33,024 and 73,984 over its 32 held experts of 2048 x
+# 1024 (``rows`` here leaves out a tile a group).
 @pytest.mark.parametrize("K,N,E,rows", [
     (2048, 1024, 64, 65536), (1024, 2048, 64, 65536),
-    (2304, 896, 16, 131072 + 256), (896, 2304, 16, 131072 + 256)])
+    (2304, 896, 16, 131072 + 256), (896, 2304, 16, 131072 + 256),
+    (2304, 896, 16, 53504 - 4096), (896, 2304, 16, 53504 - 4096),
+    (2048, 1024, 32, 33024 - 8192), (1024, 2048, 32, 33024 - 8192),
+    (2048, 1024, 32, 73984 - 8192), (1024, 2048, 32, 73984 - 8192)])
 def test_grouped_matmul_kernels_compile_for_v5e(v5e_devices, K, N, E, rows):
     """Forward, input gradient (weights contracted as stored) and weight
     gradient, not interpreted, at the model's tile."""
@@ -173,6 +180,47 @@ def test_grouped_matmul_kernels_compile_for_v5e(v5e_devices, K, N, E, rows):
         assert f"%{name}" in text, name
     assert not [line for line in text.splitlines()    # no transposed weights
                 if " copy(" in line and f"= bf16[{E}," in line]
+
+
+def test_a_held_share_with_its_switch_compiles_for_v5e(v5e_devices,
+                                                       monkeypatch):
+    """``routed_experts`` at the Mellum2 cell's shapes (16,384 tokens, 16 of
+    64 experts held, 2304 x 896), forward and gradient, kernels not
+    interpreted: one ``conditional`` of two branches each way, the three
+    kernels at both of the ladder's row counts, and the untaken size's
+    residual slots filled by nothing."""
+    from tepdist_tpu.ops import grouped_matmul as gm
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    S, k, E, G, d, f, tile = 16384, 8, 64, 16, 2304, 896, 256
+    sizes = gm.layout_rows(S, k, G, E, tile)
+    assert sizes == (53504, 135424)
+    one_chip = SingleDeviceSharding(v5e_devices[0])
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(h, weights, experts, w_gate, w_up, w_down):
+        y = gm.routed_experts(h, weights, experts, w_gate, w_up, w_down, E,
+                              tile, held=(16, G))
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 3, 4, 5))).lower(
+        sds((S, d)), sds((S, k), jnp.float32), sds((S, k), jnp.int32),
+        sds((G, d, f)), sds((G, d, f)), sds((G, f, d))).compile().as_text()
+    branches = re.findall(r" conditional\(.*branch_computations=\{([^}]*)\}",
+                          text)
+    assert [len(b.split(",")) for b in branches] == [2, 2], branches
+    for name in ("tepdist_gmm_fwd", "tepdist_gmm_dx", "tepdist_gmm_dw"):
+        calls = [line for line in text.splitlines()
+                 if " custom-call(" in line
+                 and name in line.split(" = ", 1)[0]]
+        for rows in sizes:
+            assert any(f"bf16[{rows}," in c for c in calls), (name, rows)
+    # No zeros are written for the size not taken: its wide residuals are
+    # results of the kernel that writes nothing.
+    wide = re.compile(r"= bf16\[(53504|135424),\d+\]\S* broadcast\(")
+    assert not wide.findall(text)
+    assert "tepdist_unwritten" in text
 
 
 def test_flash_bf16_compiles_under_highest_matmul_precision(v5e_devices):

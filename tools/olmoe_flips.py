@@ -6,7 +6,9 @@ resolves, the program can pick the one the reference does not, and from
 that layer on the two compute different functions. This reads how often:
 for every layer and token, the part of the reference's k experts the
 program did not choose. It also fills the program's routing counters
-(the model's ``routing_stats``; ``moe_tokens_dropped`` must read 0). The
+(the model's ``routing_stats``; ``moe_tokens_dropped`` must read 0; for a
+chip that holds a share of the experts ``moe_layout_rows_share`` and
+``moe_layout_worst_case`` say which size each layer's layout took). The
 model and its reference are the cell's builder's (``builder.program``,
 ``benchmark/reference/<builder>.py``, whose ``hidden`` returns the expert
 ids last): OLMoE's and, with the share of the experts a chip holds
