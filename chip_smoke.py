@@ -156,10 +156,13 @@ def _plan(cfg_name: str, batch: int, seq: int, devices, **plan_kwargs):
         # Parameter bytes whose gradients a GA step accumulates inside the
         # loss's layer loop / by the tree-wide add (both 0: one micro batch);
         # chunks of the loss whose gradients its forward loop makes (0: the
-        # dense loss).
+        # dense loss); flash calls a micro batch whose forward pass the
+        # backward does not repeat, and their kept bytes (both 0: GPT-2's
+        # "full" rematerialisation).
         **{k: metrics().gauge(k).value
            for k in ("ga_fused_bytes", "ga_unfused_bytes",
-                     "ce_fused_chunks")},
+                     "ce_fused_chunks", "attn_kept_calls",
+                     "attn_kept_bytes")},
         "cache_hit": in_plan["plan_cache_hits"] > 0
         and in_plan["plan_cache_writes"] == 0}
 

@@ -19,7 +19,11 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from tepdist_tpu.models.layers import cross_entropy, scan_blocks
+from tepdist_tpu.models.layers import (
+    cross_entropy,
+    rematerialised_whole,
+    scan_blocks,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -42,7 +46,10 @@ class GPT2Config:
     # REMAT_POLICY knob): "full" recomputes the whole block in backward
     # (minimum memory); "dots" saves matmul outputs (checkpoint_dots);
     # "dots_no_batch" saves only no-batch-dim matmuls — the backward skips
-    # recomputing MXU-heavy ops at the cost of the saved activations' HBM.
+    # recomputing MXU-heavy ops at the cost of the saved activations' HBM;
+    # "save_attn" keeps the attention's output: in the stacked form, where a
+    # gradient-accumulation step walks the blocks (layers.scan_blocks), the
+    # flash kernel's output and log-sum-exp, so its forward runs once.
     remat_policy: str = "full"
     # Flash attention tile sizes (0 = kernel default). Bigger q tiles mean
     # fewer grid steps/LSE traffic; sweepable per chip generation.
@@ -246,9 +253,14 @@ def hidden_states_stacked(params, tokens, cfg: GPT2Config, attn_impl=None):
     def body(h, layer_params):
         return transformer_block(layer_params, h, cfg, attn_impl), None
 
-    if cfg.remat and cfg.remat_policy == "full":
-        # Full remat is the form a gradient-accumulation step can reach
-        # into (layers.scan_blocks); a policy that saves more stays below.
+    if cfg.remat and cfg.remat_policy in ("full", "save_attn"):
+        # The forms a gradient-accumulation step can reach into
+        # (layers.scan_blocks): every block rematerialised, all of it
+        # ("full") or but for what its flash kernel's forward pass gave
+        # ("save_attn"; what the walk keeps of any block). A policy that
+        # saves more stays below.
+        if cfg.remat_policy == "full":
+            body = rematerialised_whole(body)
         x, _ = scan_blocks(body, x, params["blocks"])
     else:
         if cfg.remat:

@@ -22,6 +22,12 @@ from tepdist_tpu.ops.grouped_matmul import (
     layout_rows,
     route,
 )
+from tepdist_tpu.ops.pallas.flash_attention import (
+    KeptForward,
+    flash_attention_kept,
+    hand_over,
+    nothing_kept,
+)
 from tepdist_tpu.telemetry import metrics
 
 
@@ -37,13 +43,16 @@ class BlockGradSink:
     ``acc`` (key -> accumulator leaf, an input of the differentiation) a
     noted walk adds each layer's weight gradient into the accumulator inside
     the backward layer loop and hands the sum back as the accumulator's
-    cotangent."""
+    cotangent, and counts in ``attn_kept`` the flash calls whose forward
+    pass it keeps and their bytes (the gauges ``attn_kept_calls`` /
+    ``attn_kept_bytes``, summed over the walks of one loss)."""
 
     def __init__(self, leaves: Dict[int, int],
                  acc: Optional[Dict[int, jax.Array]] = None):
         self.leaves = leaves
         self.acc = acc
         self.walks: List[Tuple[int, ...]] = []
+        self.attn_kept = [0, 0]
 
     def __enter__(self):
         self._token = _SINK.set(self)
@@ -60,7 +69,9 @@ _SINK: contextvars.ContextVar[Optional[BlockGradSink]] = \
 def scan_blocks(body, x, blocks, kinds=None):
     """``jax.lax.scan(jax.checkpoint(body), x, blocks)``: ``body(h, block)
     -> (h, y)`` over blocks stacked on a leading layer dim, every block
-    rematerialised in full in the backward pass. Returns ``(x, ys)``.
+    rematerialised in the backward pass but, where the backward is written
+    out (below), for its attention kernels' output and log-sum-exp. Returns
+    ``(x, ys)``.
 
     ``kinds`` (a NumPy array, one entry a layer, no parameter and no
     gradient) makes it ``body(h, block, kind)``: layers of one shape and
@@ -74,7 +85,22 @@ def scan_blocks(body, x, blocks, kinds=None):
     and adds layer ``l``'s weight gradient into slice ``l`` in place, so the
     stacked gradient of a micro batch is never built. Same values as the
     plain scan's gradient added to the accumulator afterwards: the layer's
-    gradient is rounded to its dtype, then the sum to the accumulator's."""
+    gradient is rounded to its dtype, then the sum to the accumulator's.
+
+    That walk saves, beside a block's input, the ``(o, lse)`` of every flash
+    call in it (``ops/pallas/flash_attention.py:KeptForward``), stacked a
+    layer as the inputs are, and the recomputation takes them back: the
+    forward kernel runs once a layer and micro batch, not twice, for ``o``'s
+    and ``lse``'s bytes held from a micro batch's forward to its backward
+    (the gauges ``attn_kept_calls`` / ``attn_kept_bytes``). The same values:
+    they are the arrays the second run would make. A body wrapped in
+    :func:`rematerialised_whole` keeps nothing. The plain scan (no sink: one
+    micro batch, or a body that closes over a traced value) is left as it
+    was, ``jax.checkpoint`` of the whole block, so there the forward kernel
+    still runs twice and the gauges read 0: ``jax.checkpoint`` takes no
+    arrays from outside, only names on what the kernel's own VJP saves and
+    a policy over them, and a body that declines would have to reach into
+    that rule, which JAX traces after the body has returned."""
     if kinds is not None:
         # The entry rides beside the block; every path below sees a body of
         # (h, (block, kind)) whose parameters are still ``blocks``' leaves.
@@ -88,7 +114,7 @@ def scan_blocks(body, x, blocks, kinds=None):
         sink.walks.append(keys)
         acc = jax.tree_util.tree_unflatten(
             jax.tree_util.tree_structure(blocks), [sink.acc[k] for k in keys])
-        return _walk_accumulating(body, x, blocks, acc, kinds)
+        return _walk_accumulating(body, x, blocks, acc, kinds, sink)
     if keys and None not in keys:
         # Recording. A body that closes over a traced value cannot be
         # differentiated by hand; that walk stays the plain scan.
@@ -107,7 +133,18 @@ def scan_blocks(body, x, blocks, kinds=None):
                         blocks if kinds is None else (blocks, kinds))
 
 
-def _walk_accumulating(body, x, blocks, acc, kinds=None):
+def rematerialised_whole(body):
+    """``body`` for :func:`scan_blocks` with its flash calls rematerialised
+    like the rest of the block: the walk keeps nothing of them (a recipe
+    that pins full rematerialisation)."""
+    @functools.wraps(body)
+    def whole(*args):
+        with nothing_kept():
+            return body(*args)
+    return whole
+
+
+def _walk_accumulating(body, x, blocks, acc, kinds, sink):
     def layers_of(blocks):
         return blocks if kinds is None else (blocks, kinds)
 
@@ -118,25 +155,35 @@ def _walk_accumulating(body, x, blocks, acc, kinds=None):
 
     def fwd(x, blocks, acc):
         def step(h, layer):
-            out, y = body(h, layer)
-            return out, (h, y)
+            with KeptForward() as keep:
+                out, y = body(h, layer)
+            return out, (h, y, keep.kept)
 
-        out, (inputs, ys) = jax.lax.scan(step, x, layers_of(blocks))
-        return (out, ys), (inputs, blocks, acc)
+        out, (inputs, ys, kept) = jax.lax.scan(step, x, layers_of(blocks))
+        sink.attn_kept[0] += inputs.shape[0] * len(kept)
+        sink.attn_kept[1] += sum(
+            a.nbytes for a in jax.tree_util.tree_leaves(kept))
+        for gauge, value in zip(("attn_kept_calls", "attn_kept_bytes"),
+                                sink.attn_kept):
+            metrics().gauge(gauge).set(value)
+        return (out, ys), (inputs, kept, blocks, acc)
 
     def bwd(res, cts):
-        inputs, blocks, acc = res
+        inputs, kept, blocks, acc = res
         d_out, d_ys = cts
         layers = jnp.arange(inputs.shape[0])
 
         def step(carry, per_layer):
             dh, acc = carry
-            layer, h, block, d_y = per_layer
-            if kinds is None:
-                _, pull = jax.vjp(body, h, block)
-            else:
+            layer, h, saved, block, d_y = per_layer
+            if kinds is not None:
                 block, kind = block
-                _, pull = jax.vjp(lambda h, b: body(h, (b, kind)), h, block)
+
+            def recompute(h, block):
+                with KeptForward(saved):
+                    return body(h, block if kinds is None else (block, kind))
+
+            _, pull = jax.vjp(recompute, h, block)
             dh, d_block = pull((dh, d_y))
             acc = jax.tree_util.tree_map(
                 lambda a, g: jax.lax.dynamic_update_index_in_dim(
@@ -146,8 +193,8 @@ def _walk_accumulating(body, x, blocks, acc, kinds=None):
             return (dh, acc), None
 
         (dx, acc), _ = jax.lax.scan(
-            step, (d_out, acc), (layers, inputs, layers_of(blocks), d_ys),
-            reverse=True)
+            step, (d_out, acc),
+            (layers, inputs, kept, layers_of(blocks), d_ys), reverse=True)
         return dx, None, acc
 
     walk.defvjp(fwd, bwd)
@@ -241,30 +288,38 @@ def gqa_heads(blk, a, *, n_head: int, n_kv_head: int, head_dim: int,
     position encoding) and the flash kernels, with ``window`` on a window
     layer (key j visible to query i iff ``0 <= i - j < window``) and plain
     causal on a global one. ``windowed``: this layer's kind, a bool or a
-    traced scalar (a stack of both kinds: the branch is a ``lax.cond``)."""
-    from tepdist_tpu.ops.pallas.flash_attention import flash_attention
+    traced scalar (a stack of both kinds: the branch is a ``lax.cond``).
+    Inside a block that :func:`scan_blocks` walks the call hands its
+    forward pass to the walk as ``flash_attention`` does."""
     B, T, _ = a.shape
 
     def heads(t, n):
         return t.reshape(B, T, n, head_dim).transpose(0, 2, 1, 3)
 
-    def attend(q, k, v, windowed: bool):
+    def attend(forward, q, k, v, windowed: bool):
         table = rope_window if windowed else rope_global
         if table is not None:
             q, k = rope(q, table), rope(k, table)
-        return flash_attention(
-            q, k, v, causal=True, window=window if windowed else None,
+        return flash_attention_kept(
+            q, k, v, forward, causal=True,
+            window=window if windowed else None,
             block_q=block_q or None, block_k=block_k or None)
 
     q = rms_norm(heads(a @ blk["wq"], n_head), blk["q_norm"], eps)
     k = rms_norm(heads(a @ blk["wk"], n_kv_head), blk["k_norm"], eps)
     v = heads(a @ blk["wv"], n_kv_head)
-    if isinstance(windowed, (bool, np.bool_)):
-        o = attend(q, k, v, bool(windowed))
-    else:
-        o = jax.lax.cond(windowed != 0,
-                         functools.partial(attend, windowed=True),
-                         functools.partial(attend, windowed=False), q, k, v)
+
+    def either(forward):
+        # What a walk keeps of this call goes in and comes out here, around
+        # the ``cond``: both kinds' ``(o, lse)`` have one shape.
+        if isinstance(windowed, (bool, np.bool_)):
+            return attend(forward, q, k, v, bool(windowed))
+        return jax.lax.cond(windowed != 0,
+                            functools.partial(attend, windowed=True),
+                            functools.partial(attend, windowed=False),
+                            forward, q, k, v)
+
+    o = hand_over(either)
     return o.transpose(0, 2, 1, 3).reshape(B, T, n_head * head_dim)
 
 

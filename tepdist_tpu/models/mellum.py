@@ -83,8 +83,10 @@ class MellumConfig:
     yarn_attention_factor: Optional[float] = 1.2772588722239782
     rms_norm_eps: float = 1e-6
     dtype: Any = jnp.bfloat16
-    # Flash attention tile sizes (0 = kernel default), full remat of every
-    # block and the loss chunk: gpt2.GPT2Config's vocabulary.
+    # Flash attention tile sizes (0 = kernel default), every block
+    # rematerialised in the backward pass but for its attention kernels'
+    # output and log-sum-exp (layers.scan_blocks), and the loss chunk:
+    # gpt2.GPT2Config's vocabulary.
     flash_block_q: int = 0
     flash_block_k: int = 0
     remat: bool = False

@@ -68,8 +68,10 @@ class OlmoeConfig:
     lb_coef: float = 0.01
     z_coef: float = 0.001
     dtype: Any = jnp.bfloat16
-    # Flash attention tile sizes (0 = kernel default), full remat of every
-    # block and the loss chunk: gpt2.GPT2Config's vocabulary.
+    # Flash attention tile sizes (0 = kernel default), every block
+    # rematerialised in the backward pass but for its attention kernels'
+    # output and log-sum-exp (layers.scan_blocks), and the loss chunk:
+    # gpt2.GPT2Config's vocabulary.
     flash_block_q: int = 0
     flash_block_k: int = 0
     remat: bool = False

@@ -47,6 +47,8 @@ v5e; tools/flash_bench.py times the three kernels alone on the chip.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 import logging
 import math
@@ -479,6 +481,29 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res, do):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _flash_from(q, k, v, o, lse, causal, scale, block_q, block_k, interpret,
+                window):
+    """``_flash`` where the forward kernel's two outputs are already in
+    hand: the primal is ``o`` as given (no kernel), the backward is
+    ``_flash``'s on the residuals ``_flash_fwd`` would have saved."""
+    return o
+
+
+def _flash_from_fwd(q, k, v, o, lse, causal, scale, block_q, block_k,
+                    interpret, window):
+    return o, (q, k, v, o, lse)
+
+
+def _flash_from_bwd(causal, scale, block_q, block_k, interpret, window, res,
+                    do):
+    return _flash_bwd(causal, scale, block_q, block_k, interpret, window,
+                      res, do) + (None, None)
+
+
+_flash_from.defvjp(_flash_from_fwd, _flash_from_bwd)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash_o_lse(q, k, v, causal, scale, block_q, block_k, interpret):
     """(o, lse) flash: the LSE is a first-class differentiable output —
@@ -597,6 +622,69 @@ def _dense_attention(q, k, v, causal: bool, scale: float):
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
+class KeptForward:
+    """What a walk over rematerialised blocks
+    (``models/layers.py:scan_blocks``) is to the flash calls of the block it
+    traces (``with KeptForward(...)``): the place their forward kernel's
+    ``(o, lse)`` is handed out to and taken back from, so that the backward
+    pass's recomputation of the block does not run that kernel again.
+
+    Recording (``saved=None``; the walk's forward pass): a call runs the
+    forward kernel alone and leaves its ``(o, lse)`` in ``kept``. Replaying
+    (``saved``: what the recording of the same block kept; the recomputation
+    under ``jax.vjp``): the calls take the pairs back in order and are
+    attention from a saved forward, no forward kernel and today's two
+    backward kernels. Only a call traced where the context was entered
+    takes part (:func:`hand_over`): the branches of a ``lax.cond``, an inner
+    loop or ``jit`` can hand no array out, so a call in one runs as it does
+    outside any walk, in both passes, unless its caller does the hand-over
+    around the ``cond`` (``models/layers.py:gqa_heads``)."""
+
+    def __init__(self, saved=None):
+        self.saved = saved
+        self.kept = []
+
+    def __enter__(self):
+        self._trace = jax.core.get_opaque_trace_state()
+        self._token = _KEPT.set(self)
+        return self
+
+    def __exit__(self, *exc):
+        _KEPT.reset(self._token)
+
+
+_KEPT: contextvars.ContextVar[Optional[KeptForward]] = \
+    contextvars.ContextVar("tepdist_flash_kept_forward", default=None)
+
+
+@contextlib.contextmanager
+def nothing_kept():
+    """Flash calls traced inside run as outside any walk: a block whose
+    recipe pins the rematerialisation of all of it."""
+    token = _KEPT.set(None)
+    try:
+        yield
+    finally:
+        _KEPT.reset(token)
+
+
+def hand_over(attend):
+    """``attend(forward)`` is one flash call, or a ``lax.cond`` over flash
+    calls of one shape, as :func:`flash_attention_kept` takes ``forward``.
+    Outside a :class:`KeptForward` (or under another trace than the one it
+    was entered in) this is ``attend(None)``; recording, ``attend(())``'s
+    ``(o, lse)`` is kept and ``o`` returned; replaying, ``attend((o, lse))``
+    of the next saved pair."""
+    keep = _KEPT.get()
+    if keep is None or keep._trace != jax.core.get_opaque_trace_state():
+        return attend(None)
+    if keep.saved is None:
+        o, lse = attend(())
+        keep.kept.append((o, lse))
+        return o
+    return attend(keep.saved[len(keep.kept)])
+
+
 def flash_attention(q, k, v, causal: bool = True,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
@@ -618,7 +706,38 @@ def flash_attention(q, k, v, causal: bool = True,
     does not divide the window (``T = 6400`` under 2048: tiles of 400), and
     ``models/afmoe.py``'s ``test`` preset (window 8 inside one tile).
     With ``window=None`` and ``Hkv == H`` the kernels, their names and their
-    operands are what they were before either existed."""
+    operands are what they were before either existed.
+
+    Inside a block that ``models/layers.py:scan_blocks`` walks, a call
+    whose kernels run hands its forward pass to the walk
+    (:class:`KeptForward`); the values and the backward kernels are the
+    same."""
+    def attend(forward):
+        return flash_attention_kept(q, k, v, forward, causal, scale, block_q,
+                                    block_k, interpret, window)
+
+    if not causal and _resolve_blocks(q.shape[2], block_q, block_k) is None:
+        return attend(None)   # the dense fallback: no kernel, nothing to keep
+    return hand_over(attend)
+
+
+def flash_attention_kept(q, k, v, forward, causal: bool = True,
+                         scale: Optional[float] = None,
+                         block_q: Optional[int] = None,
+                         block_k: Optional[int] = None,
+                         interpret: Optional[bool] = None,
+                         window: Optional[int] = None):
+    """:func:`flash_attention` in the part a :class:`KeptForward` asks of a
+    call, for a caller that does the :func:`hand_over` itself. ``forward``:
+
+    - ``None``: the whole of it, ``o`` with its custom VJP, the program of a
+      call outside any walk;
+    - ``()``: the forward kernel alone, ``(o [B, H, T, D], lse [B, H, T]
+      float32)``, not differentiable;
+    - ``(o, lse)`` as that gave them for the same ``q, k, v``: attention
+      from its saved forward. The primal is ``o`` (no kernel runs); its VJP
+      runs the dQ and dK/dV kernels on ``(q, k, v, o, lse)`` as
+      ``flash_attention``'s does, under the same names, bit for bit."""
     B, H, T, D = q.shape
     if k.shape != v.shape or k.shape[0] != B or k.shape[2:] != (T, D) \
             or H % k.shape[1]:
@@ -638,13 +757,23 @@ def flash_attention(q, k, v, causal: bool = True,
             # under the causal mask real queries (pos < T) never attend
             # padded keys (pos >= T), and padded query rows are sliced
             # off (their cotangents are zero), so numerics are exact and
-            # memory stays O(T*block) instead of the dense O(T^2).
+            # memory stays O(T*block) instead of the dense O(T^2). (A saved
+            # forward is padded with zeros: a padded row's scores, dO and
+            # delta are 0, so its part of every gradient is too.)
             Tp = -(-T // 128) * 128
-            pad = ((0, 0), (0, 0), (0, Tp - T), (0, 0))
-            out = flash_attention(
-                jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad),
-                causal=True, scale=scale, interpret=interpret, window=window)
-            return out[:, :, :T, :]
+
+            def pad(x):
+                return jnp.pad(x, ((0, 0), (0, 0), (0, Tp - T))
+                               + ((0, 0),) * (x.ndim - 3))
+
+            out = flash_attention_kept(
+                pad(q), pad(k), pad(v),
+                forward and tuple(pad(x) for x in forward), causal=True,
+                scale=scale, interpret=interpret, window=window)
+            return jax.tree_util.tree_map(lambda x: x[:, :, :T], out)
+        if forward is not None:
+            raise ValueError("flash_attention_kept: no kernel runs at "
+                             f"non-causal T={T}, so no forward is kept")
         # Non-causal: padded keys would be attended; dense is the only
         # exact fallback (rare — awkward T with bidirectional attention).
         _log_dense_fallback(T)
@@ -654,4 +783,9 @@ def flash_attention(q, k, v, causal: bool = True,
     block_q, block_k = blocks
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    return _flash(q, k, v, causal, scale, block_q, block_k, interpret, window)
+    static = (causal, scale, block_q, block_k, interpret, window)
+    if forward is None:
+        return _flash(q, k, v, *static)
+    if not forward:
+        return _fwd_call(q, k, v, *static)
+    return _flash_from(q, k, v, *forward, *static)

@@ -258,9 +258,13 @@ def walked_leaves(loss_fn: Callable, params, *batch) -> Tuple[int, ...]:
 
 def _report_ga_bytes(fused: int, unfused: int) -> None:
     """How the step being built accumulates its parameters' gradients:
-    bytes added inside the layer loop / by the tree-wide add."""
+    bytes added inside the layer loop / by the tree-wide add. What the
+    walks of that layer loop keep of their attention
+    (``models/layers.py:scan_blocks``) they add as they are traced."""
     metrics().gauge("ga_fused_bytes").set(fused)
     metrics().gauge("ga_unfused_bytes").set(unfused)
+    metrics().gauge("attn_kept_calls").set(0)
+    metrics().gauge("attn_kept_bytes").set(0)
 
 
 def build_ga_step(

@@ -417,6 +417,13 @@ def plan_training(
              "layer loop, %.0f by the tree-wide add (%d micro batches)",
              metrics().gauge("ga_fused_bytes").value,
              metrics().gauge("ga_unfused_bytes").value, num_micro_batches)
+    # Set while the step's walks were differentiated
+    # (models/layers.py:scan_blocks).
+    log.info("attention kept: %.0f flash calls a micro batch hand their "
+             "forward pass (%.0f bytes of output and log-sum-exp) to the "
+             "backward pass, which does not run it again",
+             metrics().gauge("attn_kept_calls").value,
+             metrics().gauge("attn_kept_bytes").value)
     # Set while the loss was traced (models/layers.py:cross_entropy).
     log.info("chunked cross entropy: %.0f chunks a loss call make their "
              "gradients in the forward chunk loop",

@@ -571,6 +571,75 @@ def test_kernel_names_are_unchanged_without_a_window_and_carry_one_with():
                            jnp.zeros((1, 3, 64, 16)))
 
 
+@pytest.mark.parametrize("T,window,H,Hkv,dtype", [
+    (64, None, 4, 4, jnp.float32),       # plain causal
+    (64, 24, 4, 4, jnp.bfloat16),        # a window
+    (64, None, 8, 2, jnp.bfloat16),      # fewer key/value heads
+    (128, 32, 32, 4, jnp.bfloat16),      # both
+    (50, None, 4, 2, jnp.float32),       # no tile divides T: padded to 128
+])
+def test_attention_from_a_saved_forward_is_flash_attention(T, window, H, Hkv,
+                                                           dtype):
+    """``flash_attention_kept``: the forward kernel alone gives what
+    ``flash_attention`` returns; attention from that ``(o, lse)`` returns
+    ``o`` without any kernel and has ``flash_attention``'s gradients bit for
+    bit, from the same two backward kernels."""
+    ks = jax.random.split(jax.random.PRNGKey(T + H), 4)
+    q = jax.random.normal(ks[0], (2, H, T, 16)).astype(dtype)
+    k = jax.random.normal(ks[1], (2, Hkv, T, 16)).astype(dtype)
+    v = jax.random.normal(ks[2], (2, Hkv, T, 16)).astype(dtype)
+    ct = jax.random.normal(ks[3], (2, H, T, 16)).astype(dtype)
+    tiles = {"block_q": 16, "block_k": 16} if T % 16 == 0 else {}
+
+    def flash(q, k, v):
+        return fa.flash_attention(q, k, v, window=window, **tiles)
+
+    def part(forward):
+        return lambda q, k, v: fa.flash_attention_kept(
+            q, k, v, forward, window=window, **tiles)
+
+    want, want_pull = jax.vjp(flash, q, k, v)
+    o, lse = part(())(q, k, v)
+    assert lse.shape == (2, H, T) and lse.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(o), np.asarray(want))
+    got, pull = jax.vjp(part((o, lse)), q, k, v)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    for a, b in zip(pull(ct), want_pull(ct), strict=True):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    assert "pallas_call" not in str(jax.make_jaxpr(part((o, lse)))(q, k, v))
+    grads = jax.grad(lambda *a: jnp.sum(part((o, lse))(*a)), argnums=(0, 1, 2))
+    assert [n.split("__")[0] for n in _kernel_names(grads, q, k, v)] == [
+        "tepdist_flash_dkv", "tepdist_flash_dq"]
+    assert set(_kernel_names(grads, q, k, v)) < set(_kernel_names(
+        jax.grad(lambda *a: jnp.sum(flash(*a)), argnums=(0, 1, 2)), q, k, v))
+
+
+def test_a_flash_call_in_an_inner_trace_hands_nothing_to_the_walk():
+    """A ``KeptForward`` takes part in the calls traced where it was
+    entered: one inside a ``lax.cond`` or an inner loop can hand no array
+    out and runs whole, and ``nothing_kept`` switches the hand-over off."""
+    q = jnp.ones((1, 2, 64, 16))
+
+    def flash(q):
+        return fa.flash_attention(q, q, q, block_q=16, block_k=16)
+
+    with fa.KeptForward() as keep:
+        here = flash(q)
+        inner = jax.lax.cond(q[0, 0, 0, 0] > 0, flash, lambda q: q, q)
+        with fa.nothing_kept():
+            off = flash(q)
+    assert len(keep.kept) == 1
+    for o in (inner, off, keep.kept[0][0]):
+        np.testing.assert_array_equal(np.asarray(o), np.asarray(here))
+    with fa.KeptForward(keep.kept):
+        np.testing.assert_array_equal(np.asarray(flash(q)), np.asarray(here))
+    with pytest.raises(ValueError):
+        fa.flash_attention_kept(jnp.ones((1, 2, 50, 16)), q[:, :, :50],
+                                q[:, :, :50], (), causal=False)
+
+
 def test_the_seq_planner_leaves_a_windowed_kernel_alone():
     from tepdist_tpu.graph.jaxpr_graph import trace_graph
     from tepdist_tpu.parallel.attention_motif import detect_motifs

@@ -78,7 +78,7 @@ CASES = {
     "comm-bf16": (_gpt2_stacked, {"comm_dtype": "bfloat16"}, False),
     "comm-int8": (_gpt2_stacked, {"comm_dtype": "int8"}, False),
     "remat-dots": (_with(remat_policy="dots"), {}, False),
-    "remat-save-attn": (_with(remat_policy="save_attn"), {}, False),
+    "remat-save-attn": (_with(remat_policy="save_attn"), {}, True),
     "no-remat": (_with(remat=False), {}, False),
     "unstacked-loss": (_gpt2_unstacked, {}, False),
     "loss-without-the-helper": (_own_scan, {}, False),

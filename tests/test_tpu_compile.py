@@ -318,3 +318,53 @@ def test_ga_step_accumulates_in_place_at_the_1p5b_cells_shapes(v5e_devices):
     assert saved >= 2.0e9, saved
     entry = fused.as_text().split("\nENTRY ", 1)
     assert not re.findall(r"= \w+\[48,[\d,]+\]\S* copy\(", entry[0])
+
+
+def test_a_walked_block_keeps_its_flash_forward_in_the_compiled_step(
+        v5e_devices, monkeypatch):
+    """A gradient-accumulation step over a stack of window, global, window
+    layers (Mellum2's kinds at a reduced width, its head size, kernels not
+    interpreted): the compiled step holds each kind's forward kernel once
+    (the layer loop's; the backward loop's recomputation runs none), its dQ
+    and dK/dV kernels once, and the kept ``o`` rides the walk's stack at the
+    kernel's own shape, a layer a slot."""
+    from tepdist_tpu.models import mellum
+    from tepdist_tpu.parallel.sync_free import build_ga_step
+    from tepdist_tpu.telemetry import metrics
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(
+        mellum.CONFIGS["test"], hidden_size=256, head_dim=128,
+        num_attention_heads=4, num_key_value_heads=2, sliding_window=512,
+        moe_intermediate_size=128, moe_tile_m=128, dtype=jnp.bfloat16,
+        flash_block_q=512, flash_block_k=512, remat=True, loss_chunk=512)
+    T, micro = 1024, 2
+    tx = optax.sgd(1e-3)
+
+    def loss(p, t):
+        return mellum.loss_fn(p, t, cfg)
+
+    def apply_fn(p, s, g):
+        updates, s = tx.update(g, s, p)
+        return optax.apply_updates(p, updates), s
+
+    one_chip = SingleDeviceSharding(v5e_devices[0])
+    params = jax.eval_shape(
+        lambda: mellum.stacked_init_params(cfg, jax.random.PRNGKey(0)))
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (params, jax.eval_shape(tx.init, params),
+         jax.ShapeDtypeStruct((micro, T + 1), jnp.int32)))
+    step = build_ga_step(lambda p, t: jax.value_and_grad(loss)(p, t),
+                         apply_fn, micro, loss_fn=loss)
+    text = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile() \
+        .as_text()
+    assert metrics().gauge("attn_kept_calls").value == 3
+    assert metrics().gauge("attn_kept_bytes").value \
+        == 3 * 4 * T * (128 * 2 + 4)
+    calls = [line.split(" = ", 1)[0] for line in text.splitlines()
+             if " custom-call(" in line and "tepdist_flash_" in line]
+    for which in ("fwd", "dq", "dkv"):
+        kinds = sorted(re.sub(r".*(__h4(__w512)?__kv2).*", r"\1", c)
+                       for c in calls if f"tepdist_flash_{which}__" in c)
+        assert kinds == ["__h4__kv2", "__h4__w512__kv2"], (which, calls)
+    assert f"bf16[3,1,4,{T},128]" in text
