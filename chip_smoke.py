@@ -158,11 +158,14 @@ def _plan(cfg_name: str, batch: int, seq: int, devices, **plan_kwargs):
         # chunks of the loss whose gradients its forward loop makes (0: the
         # dense loss); flash calls a micro batch whose forward pass the
         # backward does not repeat, and their kept bytes (both 0: GPT-2's
-        # "full" rematerialisation).
+        # "full" rematerialisation); forward selective-scan kernel calls a
+        # micro batch and the chunk-boundary states a call holds (both 0: no
+        # state-space layer).
         **{k: metrics().gauge(k).value
            for k in ("ga_fused_bytes", "ga_unfused_bytes",
                      "ce_fused_chunks", "attn_kept_calls",
-                     "attn_kept_bytes")},
+                     "attn_kept_bytes", "ssm_scan_calls",
+                     "ssm_boundary_bytes")},
         "cache_hit": in_plan["plan_cache_hits"] > 0
         and in_plan["plan_cache_writes"] == 0}
 

@@ -1,9 +1,16 @@
 """Pieces more than one model of the zoo uses: RMSNorm, rotary positions
 (the plain table and YaRN's), grouped-query attention over layers of two
-kinds, the (optionally chunked) next-token cross entropy, the walk over
-stacked blocks and the counters of an expert layer that holds a share of the
-experts. One copy, so that a change for one model is seen by the others'
-tests and benchmark cells."""
+kinds (QK-norm where the block has the gains for it: ``q_norm`` / ``k_norm``
+among its leaves), the (optionally chunked) next-token cross entropy, the
+walk over stacked blocks and the counters of an expert layer that holds a
+share of the experts. One copy, so that a change for one model is seen by the
+others' tests and benchmark cells.
+
+A model may interleave walks of different shape: layers of one shape and
+unequal kind share a stack (``scan_blocks(..., kinds=)``), layers of unequal
+shape take a stack a run and a :func:`scan_blocks` call each, in the model's
+own order (``models/jamba.py``). A gradient-accumulation step finds every
+such walk whose stack is the parameter tree's own leaves."""
 
 from __future__ import annotations
 
@@ -282,8 +289,9 @@ def gqa_heads(blk, a, *, n_head: int, n_kv_head: int, head_dim: int,
     """a [B, T, d] (the normed input) -> the attention heads' outputs side
     by side [B, T, n_head * head_dim], before any gate and before ``wo``:
     ``n_head`` query heads over ``n_kv_head`` key/value heads, RMSNorm over
-    each head of q and k (``blk``'s ``q_norm``, ``k_norm`` [head_dim]), then
-    by the layer's kind the rotary embedding (``rope_window`` /
+    each head of q and k where the block has the gains for it (``blk``'s
+    ``q_norm``, ``k_norm`` [head_dim]; a block without those leaves has no
+    QK-norm), then by the layer's kind the rotary embedding (``rope_window`` /
     ``rope_global``: a ``theta``, a :class:`RopeTable`, or None for no
     position encoding) and the flash kernels, with ``window`` on a window
     layer (key j visible to query i iff ``0 <= i - j < window``) and plain
@@ -305,8 +313,10 @@ def gqa_heads(blk, a, *, n_head: int, n_kv_head: int, head_dim: int,
             window=window if windowed else None,
             block_q=block_q or None, block_k=block_k or None)
 
-    q = rms_norm(heads(a @ blk["wq"], n_head), blk["q_norm"], eps)
-    k = rms_norm(heads(a @ blk["wk"], n_kv_head), blk["k_norm"], eps)
+    q, k = heads(a @ blk["wq"], n_head), heads(a @ blk["wk"], n_kv_head)
+    if "q_norm" in blk:
+        q = rms_norm(q, blk["q_norm"], eps)
+        k = rms_norm(k, blk["k_norm"], eps)
     v = heads(a @ blk["wv"], n_kv_head)
 
     def either(forward):

@@ -260,11 +260,14 @@ def _report_ga_bytes(fused: int, unfused: int) -> None:
     """How the step being built accumulates its parameters' gradients:
     bytes added inside the layer loop / by the tree-wide add. What the
     walks of that layer loop keep of their attention
-    (``models/layers.py:scan_blocks``) they add as they are traced."""
+    (``models/layers.py:scan_blocks``), and the selective-scan kernels'
+    forward calls and held chunk-boundary states
+    (``ops/pallas/selective_scan.py``), are added as they are traced."""
     metrics().gauge("ga_fused_bytes").set(fused)
     metrics().gauge("ga_unfused_bytes").set(unfused)
-    metrics().gauge("attn_kept_calls").set(0)
-    metrics().gauge("attn_kept_bytes").set(0)
+    for traced in ("attn_kept_calls", "attn_kept_bytes", "ssm_scan_calls",
+                   "ssm_boundary_bytes"):
+        metrics().gauge(traced).set(0)
 
 
 def build_ga_step(

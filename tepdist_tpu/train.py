@@ -424,6 +424,14 @@ def plan_training(
              "backward pass, which does not run it again",
              metrics().gauge("attn_kept_calls").value,
              metrics().gauge("attn_kept_bytes").value)
+    # Set while the step's selective scans were traced
+    # (ops/pallas/selective_scan.py); both 0 for a model without one.
+    log.info("selective scan: %.0f forward kernel calls a micro batch (a "
+             "rematerialised layer's second run counted), %.0f bytes of "
+             "chunk-boundary states held from a call's forward to its "
+             "backward",
+             metrics().gauge("ssm_scan_calls").value or 0,
+             metrics().gauge("ssm_boundary_bytes").value or 0)
     # Set while the loss was traced (models/layers.py:cross_entropy).
     log.info("chunked cross entropy: %.0f chunks a loss call make their "
              "gradients in the forward chunk loop",

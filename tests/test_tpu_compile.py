@@ -368,3 +368,73 @@ def test_a_walked_block_keeps_its_flash_forward_in_the_compiled_step(
                        for c in calls if f"tepdist_flash_{which}__" in c)
         assert kinds == ["__h4__kv2", "__h4__w512__kv2"], (which, calls)
     assert f"bf16[3,1,4,{T},128]" in text
+
+
+def test_the_jamba_cells_step_compiles_for_v5e(v5e_devices, monkeypatch):
+    """``jamba2-3b.train.s8192``'s step from the cell's own files (4 micro
+    batches of one 8192-token sequence; Mamba x 7, attention, Mamba x 6 as
+    three walks; ``adamw_bf16``), kernels not interpreted: every walk's
+    leaves accumulate inside the backward layer loop, the attention layer's
+    flash forward is kept, the scan's forward runs twice a Mamba layer and
+    micro batch, the scan is in its kernels and nowhere as a whole-sequence
+    array, and the compiler's peak fits the chip."""
+    import json
+
+    from benchmark.lib import cells
+    from tepdist_tpu.parallel.sync_free import build_ga_step
+    from tepdist_tpu.telemetry import metrics
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmark")
+    with open(os.path.join(bench, "configs", "jamba2-3b.json")) as f:
+        config = json.load(f)
+    builder = cells.load_module(os.path.join(bench, "builders", "jamba.py"),
+                                "bench_builder_jamba_compile")
+    loss = builder.program_loss_fn(config)
+    tx = builder.program_optimizer(config)
+
+    def apply_fn(p, s, g):
+        updates, s = tx.update(g, s, p)
+        return optax.apply_updates(p, updates), s
+
+    one_chip = SingleDeviceSharding(v5e_devices[0])
+    params = jax.eval_shape(lambda: builder.make_params(config, 1))
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (params, jax.eval_shape(tx.init, params),
+         jax.ShapeDtypeStruct((4, 8193), jnp.int32)))
+    step = build_ga_step(lambda p, t: jax.value_and_grad(loss)(p, t),
+                         apply_fn, 4, loss_fn=loss)
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
+
+    gauge = lambda n: metrics().gauge(n).value              # noqa: E731
+    fused, unfused = gauge("ga_fused_bytes"), gauge("ga_unfused_bytes")
+    stacks = sum(a.size * a.dtype.itemsize for name, run in params.items()
+                 if name not in ("tok_emb", "norm_f")
+                 for a in jax.tree_util.tree_leaves(run))
+    assert fused == stacks and 2 * 1_430_781_376 < stacks < 2.87e9
+    assert fused / (fused + unfused) == pytest.approx(0.9715, abs=5e-4)
+    assert gauge("attn_kept_calls") == 1
+    assert gauge("attn_kept_bytes") == 20 * 8192 * (128 * 2 + 4)
+    assert gauge("ssm_scan_calls") == 26
+    assert gauge("ssm_boundary_bytes") == 128 * 16 * 5120 * 4
+
+    text = compiled.as_text()
+    calls = [line.split(" = ", 1)[0].strip() for line in text.splitlines()
+             if " custom-call(" in line]
+    # A walk of 7 and a walk of 6: each the forward in the walk and in its
+    # recomputation, and the backward; the attention layer's three kernels,
+    # the forward once.
+    assert sum("tepdist_ssm_fwd" in c for c in calls) == 4, calls
+    assert sum("tepdist_ssm_bwd" in c for c in calls) == 2, calls
+    for which in ("fwd", "dq", "dkv"):
+        names = [c for c in calls if f"tepdist_flash_{which}__" in c]
+        assert len(names) == 1 and "__h20__kv1" in names[0], (which, calls)
+    # No array of the step has the sequence, the channels and the states
+    # together: no whole-sequence scan.
+    shapes = set(re.findall(r"\w+\[([\d,]+)\]", text))
+    whole = [s for s in shapes
+             if {"8192", "5120", "16"} <= set(s.split(","))]
+    assert not whole, whole
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    assert 12.5e9 < peak < 15.75e9, peak
