@@ -25,6 +25,7 @@ import numpy as np
 
 from tepdist_tpu.ops.grouped_matmul import (
     at_rows,
+    counting_rows_sum,
     layout_index,
     layout_rows,
     route,
@@ -52,7 +53,9 @@ class BlockGradSink:
     the backward layer loop and hands the sum back as the accumulator's
     cotangent, and counts in ``attn_kept`` the flash calls whose forward
     pass it keeps and their bytes (the gauges ``attn_kept_calls`` /
-    ``attn_kept_bytes``, summed over the walks of one loss)."""
+    ``attn_kept_bytes``, summed over the walks of one loss) and in
+    ``rows_sum_calls`` the calls of the expert layers' row-copy kernel (the
+    gauge ``moe_rows_sum_calls``)."""
 
     def __init__(self, leaves: Dict[int, int],
                  acc: Optional[Dict[int, jax.Array]] = None):
@@ -60,6 +63,7 @@ class BlockGradSink:
         self.acc = acc
         self.walks: List[Tuple[int, ...]] = []
         self.attn_kept = [0, 0]
+        self.rows_sum_calls = 0
 
     def __enter__(self):
         self._token = _SINK.set(self)
@@ -99,7 +103,9 @@ def scan_blocks(body, x, blocks, kinds=None):
     layer as the inputs are, and the recomputation takes them back: the
     forward kernel runs once a layer and micro batch, not twice, for ``o``'s
     and ``lse``'s bytes held from a micro batch's forward to its backward
-    (the gauges ``attn_kept_calls`` / ``attn_kept_bytes``). The same values:
+    (the gauges ``attn_kept_calls`` / ``attn_kept_bytes``; the walk also
+    counts the calls a layer's expert part makes of its row-copy kernel,
+    ``moe_rows_sum_calls``). The same values:
     they are the arrays the second run would make. A body wrapped in
     :func:`rematerialised_whole` keeps nothing. The plain scan (no sink: one
     micro batch, or a body that closes over a traced value) is left as it
@@ -161,12 +167,17 @@ def _walk_accumulating(body, x, blocks, acc, kinds, sink):
         return jax.lax.scan(body, x, layers_of(blocks))
 
     def fwd(x, blocks, acc):
+        rows_sum_calls = []     # one entry a trace of the body: a layer's
+
         def step(h, layer):
-            with KeptForward() as keep:
+            with KeptForward() as keep, counting_rows_sum() as calls:
                 out, y = body(h, layer)
+            rows_sum_calls.append(calls[0])
             return out, (h, y, keep.kept)
 
         out, (inputs, ys, kept) = jax.lax.scan(step, x, layers_of(blocks))
+        sink.rows_sum_calls += inputs.shape[0] * rows_sum_calls[0]
+        metrics().gauge("moe_rows_sum_calls").set(sink.rows_sum_calls)
         sink.attn_kept[0] += inputs.shape[0] * len(kept)
         sink.attn_kept[1] += sum(
             a.nbytes for a in jax.tree_util.tree_leaves(kept))
@@ -346,8 +357,11 @@ def held_routing_stats(ids, num_experts: int, tile_m: int,
     whose routing took the worst-case size) and gauges
     ``moe_held_rows_max``, ``moe_held_rows_mean`` (rows one held expert got
     in one layer), ``moe_layout_live_share`` (rows holding an assignment
-    over the rows of the worst case) and ``moe_layout_rows_share`` (rows of
-    the size each layer takes over the worst case's, mean over layers). Each
+    over the rows of the worst case), ``moe_layout_rows_share`` (rows of
+    the size each layer takes over the worst case's, mean over layers) and
+    ``moe_rows_fetched_share`` (rows the row-copy kernel out of the layout
+    copies over the ``S * k`` the XLA gathers fetched: the held assignments
+    over all). Each
     layer is laid out at the size ``routed_experts`` chooses for it on the
     device (``layout_rows``, ``layout_index``)."""
     S, k = ids.shape[1:]
@@ -369,7 +383,8 @@ def held_routing_stats(ids, num_experts: int, tile_m: int,
            "moe_held_rows_max": int(sizes.max()),
            "moe_held_rows_mean": float(sizes.mean()),
            "moe_layout_live_share": n_held / (len(rows) * ladder[-1]),
-           "moe_layout_rows_share": sum(rows) / (len(rows) * ladder[-1])}
+           "moe_layout_rows_share": sum(rows) / (len(rows) * ladder[-1]),
+           "moe_rows_fetched_share": n_held / int(ids.size)}
     for name, value in out.items():
         if name.startswith("moe_assignments") or name in (
                 "moe_tokens_dropped", "moe_layout_worst_case"):

@@ -16,7 +16,11 @@ rows and sums them. Each is the other's transpose, and each is the other's
 custom VJP: the transpose of a permutation is the inverse permutation, which
 ``route`` already holds, while autodiff's scatter-adds of 2048-wide rows are
 the slow way around on a TPU. ``dispatch_values`` carries one value an
-assignment (the router's weights) into the layout.
+assignment (the router's weights) into the layout. The rows out of the
+layout (``combine``, and ``dispatch``'s backward) are ``k`` XLA gathers
+for a whole layer and, under a share of the experts, a row-copy kernel that
+fetches the rows under the layout's live bound and none for a choice
+elsewhere (``_rows_out``, ``ops/pallas/rows_sum.py``).
 
 **What a pad row holds: zeros, and nothing selects them.** ``dispatch`` (and
 ``combine``'s backward) gather from the array with one zero row appended,
@@ -83,14 +87,17 @@ grouped matmuls with the gated activation between them, rows out.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from tepdist_tpu.ops.pallas.grouped_matmul import grouped_matmul
+from tepdist_tpu.ops.pallas.rows_sum import rows_sum
 
 
 class Routing(NamedTuple):
@@ -108,6 +115,10 @@ class Routing(NamedTuple):
     #                             an expert elsewhere: a row no tile reaches)
     tile_group: jax.Array       # [M / tile_m] expert of each row tile
     n_tiles: jax.Array          # [1] live row tiles
+    live_rows: Optional[jax.Array]  # [1] ``n_tiles * tile_m`` under a share
+    #                             of the experts: a ``dest`` at or past it is
+    #                             a choice elsewhere (``_rows_out`` skips
+    #                             it); None for a whole layer, which has none
     group_sizes: jax.Array      # [E] assignments per expert
     sort_key: jax.Array         # [M] what sorts assignments, then pad
     #                             candidates, into the layout (stable)
@@ -214,8 +225,10 @@ def route(expert_ids, num_experts: int, tile_m: int, held=None,
     r = Routing(
         row_token=row_token, row_assignment=row_assignment,
         dest=dest.reshape(S, k), tile_group=tile_group,
-        n_tiles=tile_end[-1:].astype(jnp.int32), group_sizes=group_sizes,
-        sort_key=sort_key)
+        n_tiles=tile_end[-1:].astype(jnp.int32),
+        live_rows=(tile_end[-1:] * tile_m).astype(jnp.int32) if share
+        else None,
+        group_sizes=group_sizes, sort_key=sort_key)
     return r if rows is None else at_rows(r, rows, tile_m)
 
 
@@ -255,38 +268,74 @@ def _sum_of_rows(y, dest):
     return total.astype(y.dtype)
 
 
+_KERNEL_CALLS: contextvars.ContextVar[Optional[list]] = \
+    contextvars.ContextVar("tepdist_rows_sum_calls", default=None)
+
+
+@contextlib.contextmanager
+def counting_rows_sum():
+    """Yields a one-element list to which every :func:`routed_experts`
+    traced inside adds the calls of the row-copy kernel it makes a micro
+    batch once differentiated: 2 (its ``combine``'s and its ``dispatch``'s
+    backward's), 0 where the layer keeps the XLA gathers. Who walks a stack
+    of layers multiplies by them (``models/layers.py:scan_blocks``: the
+    gauge ``moe_rows_sum_calls``)."""
+    calls = [0]
+    token = _KERNEL_CALLS.set(calls)
+    try:
+        yield calls
+    finally:
+        _KERNEL_CALLS.reset(token)
+
+
+def _rows_out(y, dest, live_rows):
+    """``_sum_of_rows(y, dest)``, bit for bit. Handed the layout's live
+    bound (``Routing.live_rows``: a share of the experts) it is the row-copy
+    kernel (``ops/pallas/rows_sum.py``), which fetches the rows under the
+    bound and no row for a choice elsewhere, three choices in four of one
+    expert-parallel rank of four; a whole layer (None) has no choice to
+    skip and keeps the ``k`` XLA gathers: alone at OLMoE's shape the kernels
+    take 36 ns a row with the relayout of ``y`` (335 MB more beside a peak
+    of 14.5e9 bytes) and the gathers 39, 34 inside the step (PERF.md
+    section 6, PR 39)."""
+    if live_rows is None:
+        return _sum_of_rows(y, dest)
+    return rows_sum(y, dest, live_rows)
+
+
 @jax.custom_vjp
-def dispatch(x, source, dest):
+def dispatch(x, source, dest, live_rows=None):
     """Into the tile-aligned layout: x [S, d] -> [M, d] by ``source`` [M]
     (``route``'s ``row_token``); ``dest`` [S, k] holds the rows that name
-    each x row. Pad rows are zero."""
+    each x row and ``live_rows`` the layout's live bound (``_rows_out``):
+    the backward's. Pad rows are zero."""
     return _rows(x, source)
 
 
-def _dispatch_fwd(x, source, dest):
-    return _rows(x, source), dest
+def _dispatch_fwd(x, source, dest, live_rows):
+    return _rows(x, source), (dest, live_rows)
 
 
-def _dispatch_bwd(dest, g):
-    return _sum_of_rows(g, dest), None, None
+def _dispatch_bwd(res, g):
+    return _rows_out(g, *res), None, None, None
 
 
 dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
-def combine(y, source, dest):
-    """Out of the layout: each token's k rows summed, y [M, d] -> [S, d];
-    ``dispatch``'s transpose."""
-    return _sum_of_rows(y, dest)
+def combine(y, source, dest, live_rows=None):
+    """Out of the layout: each token's k rows summed, y [M, d] -> [S, d]
+    (``_rows_out``); ``dispatch``'s transpose."""
+    return _rows_out(y, dest, live_rows)
 
 
-def _combine_fwd(y, source, dest):
-    return _sum_of_rows(y, dest), source
+def _combine_fwd(y, source, dest, live_rows):
+    return _rows_out(y, dest, live_rows), source
 
 
 def _combine_bwd(source, g):
-    return _rows(g, source), None, None
+    return _rows(g, source), None, None, None
 
 
 combine.defvjp(_combine_fwd, _combine_bwd)
@@ -359,7 +408,7 @@ def routed_experts_at(h, weights, r: Routing, w_gate, w_up, w_down,
     worst case's, or that cut to a size that holds the routing
     (``at_rows``)."""
     with jax.named_scope("moe_dispatch"):
-        x = dispatch(h, r.row_token, r.dest)
+        x = dispatch(h, r.row_token, r.dest, r.live_rows)
         row_weight = dispatch_values(weights, r)
     with jax.named_scope("moe_experts"):
         def gmm(a, w):
@@ -371,7 +420,7 @@ def routed_experts_at(h, weights, r: Routing, w_gate, w_up, w_down,
         act = gated(gmm(x, w_gate), gmm(x, w_up), row_weight)
         out_rows = gmm(act, w_down)
     with jax.named_scope("moe_combine"):
-        return combine(out_rows, r.row_token, r.dest)
+        return combine(out_rows, r.row_token, r.dest, r.live_rows)
 
 
 def routed_experts(h, weights, experts, w_gate, w_up, w_down,
@@ -389,6 +438,9 @@ def routed_experts(h, weights, experts, w_gate, w_up, w_down,
     whole layer has one size and no ``switch``."""
     with jax.named_scope("moe_dispatch"):
         r = route(experts, num_experts, tile_m, held)
+    calls = _KERNEL_CALLS.get()
+    if calls is not None and r.live_rows is not None:
+        calls[0] += 2
     sizes = layout_rows(*experts.shape, _held(num_experts, held)[1],
                         num_experts, tile_m)
     if len(sizes) == 1:

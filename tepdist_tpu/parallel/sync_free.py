@@ -260,13 +260,14 @@ def _report_ga_bytes(fused: int, unfused: int) -> None:
     """How the step being built accumulates its parameters' gradients:
     bytes added inside the layer loop / by the tree-wide add. What the
     walks of that layer loop keep of their attention
-    (``models/layers.py:scan_blocks``), and the selective-scan kernels'
-    forward calls and held chunk-boundary states
-    (``ops/pallas/selective_scan.py``), are added as they are traced."""
+    (``models/layers.py:scan_blocks``), the calls of their expert layers'
+    row-copy kernel, and the selective-scan kernels' forward calls and held
+    chunk-boundary states (``ops/pallas/selective_scan.py``), are added as
+    they are traced."""
     metrics().gauge("ga_fused_bytes").set(fused)
     metrics().gauge("ga_unfused_bytes").set(unfused)
     for traced in ("attn_kept_calls", "attn_kept_bytes", "ssm_scan_calls",
-                   "ssm_boundary_bytes"):
+                   "ssm_boundary_bytes", "moe_rows_sum_calls"):
         metrics().gauge(traced).set(0)
 
 

@@ -432,6 +432,11 @@ def plan_training(
              "backward",
              metrics().gauge("ssm_scan_calls").value or 0,
              metrics().gauge("ssm_boundary_bytes").value or 0)
+    # Set while the step's walks were differentiated: 2 a routed layer that
+    # holds a share of the experts, 0 where the XLA gathers stayed.
+    log.info("rows out of the expert layout: %.0f calls of the row-copy "
+             "kernel a micro batch (ops/pallas/rows_sum.py)",
+             metrics().gauge("moe_rows_sum_calls").value or 0)
     # Set while the loss was traced (models/layers.py:cross_entropy).
     log.info("chunked cross entropy: %.0f chunks a loss call make their "
              "gradients in the forward chunk loop",

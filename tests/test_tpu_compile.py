@@ -223,6 +223,38 @@ def test_a_held_share_with_its_switch_compiles_for_v5e(v5e_devices,
     assert "tepdist_unwritten" in text
 
 
+# The rows out of the layout at the three expert cells' shapes: OLMoE's whole
+# layer (81,920 rows of 2048), and both sizes a held share's layout may take
+# in Trinity (2048 wide, 8,192 tokens) and in Mellum2 (2304 wide, a row
+# padded to 24 x 128, 16,384 tokens); 8 choices a token.
+@pytest.mark.parametrize("M,d,S", [
+    (81920, 2048, 8192), (33024, 2048, 8192), (73984, 2048, 8192),
+    (53504, 2304, 16384), (135424, 2304, 16384)])
+def test_rows_sum_kernels_compile_for_v5e(v5e_devices, M, d, S):
+    """The relayout of the live rows and the row-copy sum, not interpreted:
+    a row copied by its leading index out of ``[M, r, 128]``, and no XLA
+    copy, pad or gather of a layout-sized array beside the two kernels."""
+    from tepdist_tpu.ops.pallas.rows_sum import rows_sum
+    one_chip = SingleDeviceSharding(v5e_devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(lambda y, dest, bound: rows_sum(
+        y, dest, bound, interpret=False)).lower(
+            sds((M, d), jnp.bfloat16), sds((S, 8), jnp.int32),
+            sds((1,), jnp.int32)).compile().as_text()
+    r = -(-d // 1024) * 8
+    calls = {name: line for line in text.splitlines()
+             for name in ("tepdist_rows_tiled", "tepdist_rows_sum")
+             if " custom-call(" in line
+             and line.strip().removeprefix("ROOT ").startswith(f"%{name}")}
+    assert f"= bf16[{M},{r},128]" in calls["tepdist_rows_tiled"]
+    assert f"= bf16[{S},{d}]" in calls["tepdist_rows_sum"]
+    assert not re.findall(r"= bf16\[\d+,[\d,]+\]\S* (?:copy|pad|gather|fusion)\(",
+                          text)
+
+
 def test_flash_bf16_compiles_under_highest_matmul_precision(v5e_devices):
     """A global ``jax_default_matmul_precision`` must not reach the bf16
     kernels: Mosaic refuses a float32-precision matmul on bf16 operands."""
@@ -361,6 +393,10 @@ def test_a_walked_block_keeps_its_flash_forward_in_the_compiled_step(
     assert metrics().gauge("attn_kept_calls").value == 3
     assert metrics().gauge("attn_kept_bytes").value \
         == 3 * 4 * T * (128 * 2 + 4)
+    # Each layer holds a share of the experts: its rows out of the layout
+    # go through the row-copy kernel, forward and in ``dispatch``'s backward.
+    assert metrics().gauge("moe_rows_sum_calls").value == 2 * 3
+    assert "tepdist_rows_sum" in text and "tepdist_rows_tiled" in text
     calls = [line.split(" = ", 1)[0] for line in text.splitlines()
              if " custom-call(" in line and "tepdist_flash_" in line]
     for which in ("fwd", "dq", "dkv"):
@@ -417,6 +453,7 @@ def test_the_jamba_cells_step_compiles_for_v5e(v5e_devices, monkeypatch):
     assert gauge("attn_kept_calls") == 1
     assert gauge("attn_kept_bytes") == 20 * 8192 * (128 * 2 + 4)
     assert gauge("ssm_scan_calls") == 26
+    assert gauge("moe_rows_sum_calls") == 0                # no expert layer
     assert gauge("ssm_boundary_bytes") == 128 * 16 * 5120 * 4
 
     text = compiled.as_text()
