@@ -432,6 +432,17 @@ def plan_training(
              "backward",
              metrics().gauge("ssm_scan_calls").value or 0,
              metrics().gauge("ssm_boundary_bytes").value or 0)
+    # Set while the step's linear and block top-k attention layers were
+    # traced (ops/pallas/lightning_attention.py, block_topk_attention.py);
+    # all 0 for a model without them.
+    log.info("linear attention: %.0f forward kernel calls a micro batch; "
+             "block top-k attention: %.0f (a rematerialised layer's second "
+             "run counted), %.1f keys a query on average, %.0f layers run "
+             "as plain causal attention (at or under dense_len)",
+             metrics().gauge("lin_attn_calls").value or 0,
+             metrics().gauge("topk_attn_calls").value or 0,
+             metrics().gauge("topk_attn_keys_per_query").value or 0,
+             metrics().gauge("topk_attn_dense_calls").value or 0)
     # Set while the step's walks were differentiated: 2 a routed layer that
     # holds a share of the experts, 0 where the XLA gathers stayed.
     log.info("rows out of the expert layout: %.0f calls of the row-copy "

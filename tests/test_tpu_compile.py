@@ -475,3 +475,131 @@ def test_the_jamba_cells_step_compiles_for_v5e(v5e_devices, monkeypatch):
     assert not whole, whole
     peak = compiled.memory_analysis().peak_memory_in_bytes
     assert 12.5e9 < peak < 15.75e9, peak
+
+
+# The MiniCPM-SALA cell's two mechanisms at its tiling: 32 heads of 128 over
+# 32,768 positions, the linear attention at its chunk of 256, the block
+# top-k attention over 2 key/value groups with a group's keys, values and
+# float32 gradients resident in VMEM (16 + 32 MiB).
+def test_sala_kernels_compile_for_v5e(v5e_devices):
+    """Forward and backward of the linear-attention and of the block top-k
+    attention kernels, and the choice beside them, not interpreted: five
+    kernels under their names, the lightning operands read in the
+    projections' own ``[T, heads * D]`` layout (no copy around a call), and
+    no ``[T, T]`` array anywhere."""
+    from tepdist_tpu.ops.pallas import block_topk_attention as bt
+    from tepdist_tpu.ops.pallas.lightning_attention import lightning_attention
+    one_chip = SingleDeviceSharding(v5e_devices[0])
+    T, H, G, D = 32768, 32, 2, 128
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def calls_of(text):
+        return [line.split(" = ", 1)[0].strip() for line in text.splitlines()
+                if " custom-call(" in line]
+
+    def lightning(q, k, v, ld, do):
+        out, vjp = jax.vjp(lambda q, k, v: lightning_attention(
+            q, k, v, ld, interpret=False), q, k, v)
+        return (out,) + vjp(do)
+
+    x = sds((1, T, H * D), jnp.bfloat16)
+    compiled = jax.jit(lightning).lower(
+        x, x, x, sds((H,), jnp.float32), x).compile()
+    names = calls_of(compiled.as_text())
+    for kernel in ("tepdist_lightning_fwd", "tepdist_lightning_bwd_dq",
+                   "tepdist_lightning_bwd_dkv"):
+        assert sum(kernel in n for n in names) == 1, names
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+    geo = bt.BlockGeometry()
+
+    def sparse(q, k, v, do):
+        idx = bt.select_blocks(q, k, geo)
+        out, vjp = jax.vjp(lambda q, k, v: bt.topk_attention(
+            q, k, v, idx, geo, interpret=False), q, k, v)
+        return (out,) + vjp(do)
+
+    q, kv = sds((1, T, H, D), jnp.bfloat16), sds((1, T, G, D), jnp.bfloat16)
+    text = jax.jit(sparse).lower(q, kv, kv, q).compile().as_text()
+    names = calls_of(text)
+    for kernel in ("tepdist_topk_attn_fwd", "tepdist_topk_attn_bwd"):
+        assert sum(kernel in n for n in names) == 1, names
+    assert f"s32[1,{G},{T},64]" in text or f"s32[{G * T * 64}]" in text
+    shapes = set(re.findall(r"\w+\[([\d,]+)\]", text))
+    square = [s for s in shapes if s.split(",").count(str(T)) > 1]
+    assert not square, square
+
+
+def test_the_minicpm_sala_cells_step_compiles_for_v5e(v5e_devices,
+                                                      monkeypatch):
+    """``minicpm-sala.train.s32768``'s step from the cell's own files (2
+    micro batches of one 32,768-token sequence; a sparse layer and three
+    lightning layers as two walks; ``adamw_bf16``), kernels not interpreted:
+    both walks' leaves accumulate inside the backward layer loop, each
+    mixing kernel's forward runs twice a layer and micro batch, the sparse
+    layer visits chosen blocks (no plain causal flash call, no ``[T, T]``
+    array), no array is as wide as ``[T, intermediate]`` (the block's
+    token-wise parts run in chunks), and the compiler's peak fits the
+    chip."""
+    import json
+
+    from benchmark.lib import cells
+    from tepdist_tpu.parallel.sync_free import build_ga_step
+    from tepdist_tpu.telemetry import metrics
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmark")
+    with open(os.path.join(bench, "configs", "minicpm-sala.json")) as f:
+        config = json.load(f)
+    builder = cells.load_module(
+        os.path.join(bench, "builders", "minicpm_sala.py"),
+        "bench_builder_minicpm_sala_compile")
+    loss = builder.program_loss_fn(config)
+    tx = builder.program_optimizer(config)
+
+    def apply_fn(p, s, g):
+        updates, s = tx.update(g, s, p)
+        return optax.apply_updates(p, updates), s
+
+    T = 32768
+    one_chip = SingleDeviceSharding(v5e_devices[0])
+    params = jax.eval_shape(lambda: builder.make_params(config, 1))
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (params, jax.eval_shape(tx.init, params),
+         jax.ShapeDtypeStruct((2, T + 1), jnp.int32)))
+    step = build_ga_step(lambda p, t: jax.value_and_grad(loss)(p, t),
+                         apply_fn, 2, loss_fn=loss)
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
+
+    gauge = lambda n: metrics().gauge(n).value              # noqa: E731
+    fused, unfused = gauge("ga_fused_bytes"), gauge("ga_unfused_bytes")
+    stacks = sum(a.size * a.dtype.itemsize for name, run in params.items()
+                 if name not in ("tok_emb", "lm_head", "norm_f")
+                 for a in jax.tree_util.tree_leaves(run))
+    assert fused == stacks and 2 * 1_109_393_408 < stacks < 2.22e9
+    assert fused / (fused + unfused) == pytest.approx(0.8802, abs=5e-4)
+    assert gauge("lin_attn_calls") == 6         # 3 layers, each twice
+    assert gauge("topk_attn_calls") == 2
+    assert gauge("topk_attn_dense_calls") == 0
+    assert gauge("topk_attn_keys_per_query") == 3812.5
+    assert gauge("attn_kept_calls") == 0 and gauge("ssm_scan_calls") == 0
+
+    text = compiled.as_text()
+    calls = [line.split(" = ", 1)[0].strip() for line in text.splitlines()
+             if " custom-call(" in line]
+    for kernel, times in (("tepdist_lightning_fwd", 2),
+                          ("tepdist_lightning_bwd_dq", 1),
+                          ("tepdist_lightning_bwd_dkv", 1),
+                          ("tepdist_topk_attn_fwd", 2),
+                          ("tepdist_topk_attn_bwd", 1)):
+        assert sum(kernel in c for c in calls) == times, (kernel, calls)
+    assert not [c for c in calls if "tepdist_flash_" in c], calls
+    shapes = set(re.findall(r"\w+\[([\d,]+)\]", text))
+    wide = [s for s in shapes if s.split(",").count(str(T)) > 1
+            or {str(T), "16384"} <= set(s.split(","))]
+    assert not wide, wide
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    assert 12.5e9 < peak < 15.5e9, peak
