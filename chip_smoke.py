@@ -156,8 +156,8 @@ def _plan(cfg_name: str, batch: int, seq: int, devices, **plan_kwargs):
         # Parameter bytes whose gradients a GA step accumulates inside the
         # loss's layer loop / by the tree-wide add (both 0: one micro batch);
         # chunks of the loss whose gradients its forward loop makes (0: the
-        # dense loss); flash calls a micro batch whose forward pass the
-        # backward does not repeat, and their kept bytes (both 0: GPT-2's
+        # dense loss); calls a micro batch whose forward pass the walk
+        # keeps from the backward, and their kept bytes (both 0: GPT-2's
         # "full" rematerialisation); forward selective-scan kernel calls a
         # micro batch and the chunk-boundary states a call holds (both 0: no
         # state-space layer); calls of the expert layers' row-copy kernel a

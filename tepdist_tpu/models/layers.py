@@ -51,8 +51,10 @@ class BlockGradSink:
     ``acc`` (key -> accumulator leaf, an input of the differentiation) a
     noted walk adds each layer's weight gradient into the accumulator inside
     the backward layer loop and hands the sum back as the accumulator's
-    cotangent, and counts in ``attn_kept`` the flash calls whose forward
-    pass it keeps and their bytes (the gauges ``attn_kept_calls`` /
+    cotangent, and counts in ``attn_kept`` the calls whose forward pass it
+    keeps (``flash_attention.hand_over``: a flash or block top-k kernel's
+    forward, a sparse layer's choice) and their bytes (the gauges
+    ``attn_kept_calls`` /
     ``attn_kept_bytes``, summed over the walks of one loss) and in
     ``rows_sum_calls`` the calls of the expert layers' row-copy kernel (the
     gauge ``moe_rows_sum_calls``)."""
@@ -99,10 +101,12 @@ def scan_blocks(body, x, blocks, kinds=None):
     gradient is rounded to its dtype, then the sum to the accumulator's.
 
     That walk saves, beside a block's input, the ``(o, lse)`` of every flash
-    call in it (``ops/pallas/flash_attention.py:KeptForward``), stacked a
-    layer as the inputs are, and the recomputation takes them back: the
-    forward kernel runs once a layer and micro batch, not twice, for ``o``'s
-    and ``lse``'s bytes held from a micro batch's forward to its backward
+    call in it (``ops/pallas/flash_attention.py:KeptForward``) and of every
+    block top-k attention call with its chosen sets
+    (``ops/pallas/block_topk_attention.py``), stacked a layer as the inputs
+    are, and the recomputation takes them back: the forward kernel (and the
+    choice) runs once a layer and micro batch, not twice, for the kept
+    arrays' bytes held from a micro batch's forward to its backward
     (the gauges ``attn_kept_calls`` / ``attn_kept_bytes``; the walk also
     counts the calls a layer's expert part makes of its row-copy kernel,
     ``moe_rows_sum_calls``). The same values:

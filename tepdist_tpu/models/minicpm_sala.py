@@ -60,7 +60,7 @@ import numpy as np
 from tepdist_tpu.models.layers import cross_entropy, rms_norm, scan_blocks
 from tepdist_tpu.ops.pallas.block_topk_attention import (
     BlockGeometry,
-    select_blocks,
+    kept_choice,
     topk_attention,
 )
 from tepdist_tpu.ops.pallas.flash_attention import flash_attention
@@ -308,7 +308,7 @@ def sparse_attention(q, k, v, cfg: MiniCPMSALAConfig):
             block_k=cfg.flash_block_k or None).transpose(0, 2, 1, 3)
     else:
         with jax.named_scope("topk_select"):
-            idx = select_blocks(q, k, cfg.sparse)
+            idx = kept_choice(q, k, cfg.sparse)
         with jax.named_scope("topk_attend"):
             o = topk_attention(q, k, v, idx, cfg.sparse)
     return o.reshape(B, T, H * D)

@@ -50,11 +50,21 @@ sequences are refused, not run some other way.
 
 Kernel names ``tepdist_topk_attn_fwd`` / ``tepdist_topk_attn_bwd``. Gauges,
 set while a step is traced (``parallel/sync_free.py:build_ga_step`` zeroes
-them): ``topk_attn_calls`` forward kernel calls a micro batch (a
-rematerialised block's second run too; a call inside
-``lightning_attention.stands_for`` as many as the layers it stands for),
-``topk_attn_keys_per_query`` the
-mean keys a query visits (a function of ``T`` and the geometry).
+them): ``topk_attn_calls`` forward kernel calls a micro batch (a call
+inside ``lightning_attention.stands_for`` as many as the layers it stands
+for; a block rematerialised under ``jax.checkpoint`` runs the kernel again
+and counts twice, a block that ``models/layers.py:scan_blocks`` walks hands
+its forward pass to the walk and counts once: below),
+``topk_attn_keys_per_query`` the mean keys a query visits (a function of
+``T`` and the geometry).
+
+**Inside a walked block** (``ops/pallas/flash_attention.py:KeptForward``)
+the walk keeps what the layer's forward pass made and the backward pass's
+recomputation of the block takes it back: :func:`topk_attention` hands over
+the forward kernel's ``(o, lse)`` and is then the attention from a saved
+forward (the primal is ``o``, no kernel; the backward kernel on the same
+operands, bit for bit), and :func:`kept_choice` hands over the sets, which
+carry no gradient. Neither the forward kernel nor the choice runs twice.
 """
 
 from __future__ import annotations
@@ -69,6 +79,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tepdist_tpu.ops.pallas.flash_attention import hand_over
 from tepdist_tpu.ops.pallas.lightning_attention import layers_stood_for
 from tepdist_tpu.telemetry import metrics
 
@@ -230,6 +241,18 @@ def select_blocks(q, k, geo: BlockGeometry):
 
     idx = jax.lax.map(chunk, (jnp.arange(0, T, Tc), jnp.moveaxis(q, 1, 0)))
     return jnp.moveaxis(idx, 0, 2).reshape(B, G, T, -1)   # [n,B,G,Tc,K] ->
+
+
+def kept_choice(q, k, geo: BlockGeometry):
+    """:func:`select_blocks`, handed to the walk of the block it is traced
+    in (``flash_attention.hand_over``): the recomputation takes the sets
+    back and does not choose again. Outside any walk the choice itself."""
+    def choose(saved):
+        if saved:
+            return saved[0]
+        idx = select_blocks(q, k, geo)
+        return idx if saved is None else (idx,)
+    return hand_over(choose)
 
 
 # -- the kernels ------------------------------------------------------------
@@ -491,20 +514,53 @@ def _attend_bwd(block_size, interpret, res, do):
 _attend.defvjp(_attend_fwd, _attend_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _attend_from(q, k, v, idx, o, lse, block_size, interpret):
+    """``_attend`` where the forward kernel's two outputs are already in
+    hand: the primal is ``o`` as given (no kernel), the backward is
+    ``_attend``'s on the residuals ``_attend_fwd`` would have saved."""
+    return o
+
+
+def _attend_from_fwd(q, k, v, idx, o, lse, block_size, interpret):
+    return o, (q, k, v, idx, o, lse)
+
+
+def _attend_from_bwd(block_size, interpret, res, do):
+    return _attend_bwd(block_size, interpret, res, do) + (None, None)
+
+
+_attend_from.defvjp(_attend_from_fwd, _attend_from_bwd)
+
+
 def topk_attention(q, k, v, idx, geo: BlockGeometry, *,
                    interpret: Optional[bool] = None):
     """q [B, T, H, D], k, v [B, T, G, D] (query head ``h`` reads group ``h
     // (H / G)``), ``idx`` [B, G, T, K] from :func:`select_blocks` (sorted,
     module docstring) -> [B, T, H, D] in ``q``'s dtype. Differentiable in
-    ``q, k, v``."""
+    ``q, k, v``. Inside a walked block the call hands its forward pass to
+    the walk (module docstring); the values and the backward kernel are the
+    same."""
     B, T, H, D = q.shape
     if k.shape != v.shape or k.shape[:2] != (B, T) or k.shape[3] != D \
             or H % k.shape[2] or idx.shape[:3] != (B, k.shape[2], T) \
             or T % geo.block_size or idx.shape[3] > geo.topk:
         raise ValueError(f"topk_attention: q {q.shape}, k {k.shape}, "
                          f"v {v.shape}, idx {idx.shape}, {geo}")
-    calls = metrics().gauge("topk_attn_calls")
-    calls.set((calls.value or 0) + layers_stood_for())
     metrics().gauge("topk_attn_keys_per_query").set(
         mean_keys_per_query(T, geo._replace(topk=idx.shape[3])))
-    return _attend(q, k, v, idx, geo.block_size, _interpret(interpret))
+    bs, interpret = geo.block_size, _interpret(interpret)
+
+    def attend(saved):
+        if saved:
+            # Other arrays to the compiler than the forward pass's: what it
+            # made of those there (``o`` laid out for the output projection,
+            # as large again) is made anew here and not held all the while.
+            o, lse = jax.lax.optimization_barrier(saved)
+            return _attend_from(q, k, v, idx, o, lse, bs, interpret)
+        calls = metrics().gauge("topk_attn_calls")
+        calls.set((calls.value or 0) + layers_stood_for())
+        if saved is None:
+            return _attend(q, k, v, idx, bs, interpret)
+        return forward(q, k, v, idx, block_size=bs, interpret=interpret)
+    return hand_over(attend)

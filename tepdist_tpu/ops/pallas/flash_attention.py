@@ -624,21 +624,25 @@ def _dense_attention(q, k, v, causal: bool, scale: float):
 
 class KeptForward:
     """What a walk over rematerialised blocks
-    (``models/layers.py:scan_blocks``) is to the flash calls of the block it
-    traces (``with KeptForward(...)``): the place their forward kernel's
-    ``(o, lse)`` is handed out to and taken back from, so that the backward
-    pass's recomputation of the block does not run that kernel again.
+    (``models/layers.py:scan_blocks``) is to the kernel calls of the block
+    it traces (``with KeptForward(...)``): the place a call's forward pass
+    is handed out to and taken back from, so that the backward pass's
+    recomputation of the block does not run it again. A flash call hands
+    over its forward kernel's ``(o, lse)``, the block top-k attention
+    (``ops/pallas/block_topk_attention.py``) its own ``(o, lse)`` and, in a
+    hand-over of its own, the chosen sets: whatever tuple of arrays a call
+    gives (:func:`hand_over`).
 
-    Recording (``saved=None``; the walk's forward pass): a call runs the
-    forward kernel alone and leaves its ``(o, lse)`` in ``kept``. Replaying
-    (``saved``: what the recording of the same block kept; the recomputation
-    under ``jax.vjp``): the calls take the pairs back in order and are
-    attention from a saved forward, no forward kernel and today's two
-    backward kernels. Only a call traced where the context was entered
-    takes part (:func:`hand_over`): the branches of a ``lax.cond``, an inner
-    loop or ``jit`` can hand no array out, so a call in one runs as it does
-    outside any walk, in both passes, unless its caller does the hand-over
-    around the ``cond`` (``models/layers.py:gqa_heads``)."""
+    Recording (``saved=None``; the walk's forward pass): a call runs its
+    forward alone and leaves the tuple in ``kept``. Replaying (``saved``:
+    what the recording of the same block kept; the recomputation under
+    ``jax.vjp``): the calls take their tuples back in order and are the
+    call from a saved forward, no forward kernel and today's backward
+    kernels. Only a call traced where the context was entered takes part
+    (:func:`hand_over`): the branches of a ``lax.cond``, an inner loop or
+    ``jit`` can hand no array out, so a call in one runs as it does outside
+    any walk, in both passes, unless its caller does the hand-over around
+    the ``cond`` (``models/layers.py:gqa_heads``)."""
 
     def __init__(self, saved=None):
         self.saved = saved
@@ -659,8 +663,8 @@ _KEPT: contextvars.ContextVar[Optional[KeptForward]] = \
 
 @contextlib.contextmanager
 def nothing_kept():
-    """Flash calls traced inside run as outside any walk: a block whose
-    recipe pins the rematerialisation of all of it."""
+    """Calls traced inside run as outside any walk: a block whose recipe
+    pins the rematerialisation of all of it."""
     token = _KEPT.set(None)
     try:
         yield
@@ -669,20 +673,24 @@ def nothing_kept():
 
 
 def hand_over(attend):
-    """``attend(forward)`` is one flash call, or a ``lax.cond`` over flash
-    calls of one shape, as :func:`flash_attention_kept` takes ``forward``.
-    Outside a :class:`KeptForward` (or under another trace than the one it
-    was entered in) this is ``attend(None)``; recording, ``attend(())``'s
-    ``(o, lse)`` is kept and ``o`` returned; replaying, ``attend((o, lse))``
-    of the next saved pair."""
+    """``attend(forward)`` is one call whose forward pass a walk may keep: a
+    flash call, or a ``lax.cond`` over flash calls of one shape, as
+    :func:`flash_attention_kept` takes ``forward``; any other kernel family
+    under the same three cases. Outside a :class:`KeptForward` (or under
+    another trace than the one it was entered in) this is ``attend(None)``;
+    recording, ``attend(())`` gives a tuple of arrays whose first entry is
+    the call's result, all of it is kept and the result returned; replaying,
+    ``attend(saved)`` of the next saved tuple, in the recording's order."""
     keep = _KEPT.get()
     if keep is None or keep._trace != jax.core.get_opaque_trace_state():
         return attend(None)
     if keep.saved is None:
-        o, lse = attend(())
-        keep.kept.append((o, lse))
-        return o
-    return attend(keep.saved[len(keep.kept)])
+        kept = tuple(attend(()))
+        keep.kept.append(kept)
+        return kept[0]
+    saved = keep.saved[len(keep.kept)]
+    keep.kept.append(saved)
+    return attend(saved)
 
 
 def flash_attention(q, k, v, causal: bool = True,

@@ -419,9 +419,11 @@ def plan_training(
              metrics().gauge("ga_unfused_bytes").value, num_micro_batches)
     # Set while the step's walks were differentiated
     # (models/layers.py:scan_blocks).
-    log.info("attention kept: %.0f flash calls a micro batch hand their "
-             "forward pass (%.0f bytes of output and log-sum-exp) to the "
-             "backward pass, which does not run it again",
+    log.info("attention kept: %.0f calls a micro batch (a flash or block "
+             "top-k kernel's forward, a sparse layer's choice) hand what "
+             "their forward pass made (%.0f bytes of output, log-sum-exp "
+             "and chosen sets) to the backward pass, which does not run it "
+             "again",
              metrics().gauge("attn_kept_calls").value,
              metrics().gauge("attn_kept_bytes").value)
     # Set while the step's selective scans were traced
@@ -435,10 +437,11 @@ def plan_training(
     # Set while the step's linear and block top-k attention layers were
     # traced (ops/pallas/lightning_attention.py, block_topk_attention.py);
     # all 0 for a model without them.
-    log.info("linear attention: %.0f forward kernel calls a micro batch; "
-             "block top-k attention: %.0f (a rematerialised layer's second "
-             "run counted), %.1f keys a query on average, %.0f layers run "
-             "as plain causal attention (at or under dense_len)",
+    log.info("linear attention: %.0f forward kernel calls a micro batch (a "
+             "rematerialised layer's second run counted); block top-k "
+             "attention: %.0f (a walked layer's forward is kept and runs "
+             "once), %.1f keys a query on average, %.0f layers run as plain "
+             "causal attention (at or under dense_len)",
              metrics().gauge("lin_attn_calls").value or 0,
              metrics().gauge("topk_attn_calls").value or 0,
              metrics().gauge("topk_attn_keys_per_query").value or 0,

@@ -5,7 +5,9 @@ flash kernel's ``(o, lse)`` a layer, so that the backward pass's
 recomputation of a block runs no forward kernel. The step is bit for bit the
 step that rematerialises everything, each forward kernel is in the program
 once a kind and a walk where it was twice, and the gauges ``attn_kept_calls``
-/ ``attn_kept_bytes`` say what is held.
+/ ``attn_kept_bytes`` say what is held. The hand-over carries whatever tuple
+a call gives: the block top-k attention's pair and its sets beside flash's
+pairs (``tests/test_minicpm_sala.py`` holds that layer's walked step).
 """
 
 import collections
@@ -17,7 +19,8 @@ import numpy as np
 import optax
 import pytest
 
-from tepdist_tpu.models import afmoe, gpt2, mellum, olmoe
+from tepdist_tpu.models import afmoe, gpt2, mellum, minicpm_sala, olmoe
+from tepdist_tpu.ops.pallas import flash_attention as fa
 from tepdist_tpu.parallel.sync_free import build_ga_step
 from tepdist_tpu.telemetry import metrics
 
@@ -82,10 +85,10 @@ def _gauges(*names):
     return tuple(metrics().gauge(n).value for n in names)
 
 
-def _flash_kernels(fn, *args):
-    """How often each flash kernel is in ``fn``'s program: the names of the
+def _kernels(fn, *args):
+    """How often each kernel is in ``fn``'s program: the names of the
     ``pallas_call`` equations of its jaxpr, nested jaxprs included (a loop's
-    body counts once), cut to ``fwd`` / ``dq`` / ``dkv``."""
+    body counts once)."""
     def sub_jaxprs(eqn):
         for v in eqn.params.values():
             for j in v if isinstance(v, (list, tuple)) else (v,):
@@ -101,9 +104,14 @@ def _flash_kernels(fn, *args):
             for sub in sub_jaxprs(eqn):
                 yield from names(sub)
 
-    return collections.Counter(
-        n for n in names(jax.make_jaxpr(fn)(*args).jaxpr)
-        if n.startswith("tepdist_flash_"))
+    return collections.Counter(names(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+def _flash_kernels(fn, *args):
+    """:func:`_kernels`, the flash kernels alone (``fwd`` / ``dq`` /
+    ``dkv``)."""
+    return collections.Counter({n: c for n, c in _kernels(fn, *args).items()
+                                if n.startswith("tepdist_flash_")})
 
 
 def _count(kernels, which):
@@ -161,3 +169,70 @@ def test_walked_blocks_keep_their_flash_forward(case):
                     jax.tree_util.tree_leaves(want), strict=True):
         assert a.dtype == b.dtype
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _pallas_names(fn, *args):
+    return sorted(n.split("__")[0] for n in _kernels(fn, *args).elements())
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, BF16])
+def test_a_hand_over_carries_any_tuple_and_replays_in_order(dtype):
+    """One block with four hand-overs of three lengths: a sparse layer at or
+    under ``dense_len`` (a flash call's pair), a call of the test's own that
+    gives three arrays, and a sparse layer past ``dense_len`` (the sets
+    alone, then the top-k kernel's pair). Replaying, each call gets its own
+    tuple back in the recording's order, no forward kernel and no choice is
+    traced, and output and gradients are those outside any walk."""
+    cfg = dataclasses.replace(minicpm_sala.CONFIGS["test"], dtype=dtype)
+    H, G, D = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    ks = jax.random.split(jax.random.PRNGKey(4), 3)
+    q = jax.random.normal(ks[0], (1, 128, H, D)).astype(dtype)
+    k, v = (jax.random.normal(key, (1, 128, G, D)).astype(dtype)
+            for key in ks[1:])
+    taken = []
+
+    def three(saved):
+        if saved:
+            taken.append(saved)
+            return saved[0]
+        a = q[:, :32].reshape(1, 32, H * D) * 2
+        return a if saved is None else (a, a + 1, a + 2)
+
+    def block(q, k, v):
+        short = [x[:, :cfg.sparse.dense_len] for x in (q, k, v)]
+        return (minicpm_sala.sparse_attention(*short, cfg)
+                + fa.hand_over(three),
+                minicpm_sala.sparse_attention(q, k, v, cfg))
+
+    def total(q, k, v):
+        return sum(o.astype(jnp.float32).sum() for o in block(q, k, v))
+
+    want = block(q, k, v)
+    want_grads = jax.grad(total, argnums=(0, 1, 2))(q, k, v)
+    with fa.KeptForward() as keep:
+        recorded = block(q, k, v)
+    assert [len(t) for t in keep.kept] == [2, 3, 1, 2]
+    assert keep.kept[2][0].dtype == jnp.int32
+    assert keep.kept[3][0].shape == q.shape
+
+    def replayed(q, k, v):
+        with fa.KeptForward(keep.kept):
+            return block(q, k, v)
+
+    def replayed_total(q, k, v):
+        return sum(o.astype(jnp.float32).sum() for o in replayed(q, k, v))
+
+    got = replayed(q, k, v)
+    assert len(taken) == 1 and all(
+        a is b for a, b in zip(taken[0], keep.kept[1], strict=True))
+    grads = jax.grad(replayed_total, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip((*recorded, *got, *grads), (*want, *want, *want_grads),
+                    strict=True):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert _pallas_names(replayed, q, k, v) == []
+    assert "bitcast_convert_type" not in str(jax.make_jaxpr(replayed)(q, k, v))
+    assert "bitcast_convert_type" in str(jax.make_jaxpr(block)(q, k, v))
+    assert _pallas_names(jax.grad(replayed_total, argnums=(0, 1, 2)),
+                         q, k, v) == [
+        "tepdist_flash_dkv", "tepdist_flash_dq", "tepdist_topk_attn_bwd"]

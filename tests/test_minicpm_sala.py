@@ -6,6 +6,7 @@ against the sequential recurrence, the sparse layer's choice and its kernels
 against the reference's sets and mask, the block's token-wise parts in
 chunks, and the walks under gradient accumulation."""
 
+import collections
 import dataclasses
 import os
 
@@ -21,6 +22,7 @@ from benchmark.kernels import lightning_check
 from benchmark.reference import minicpm_sala as ref
 from tepdist_tpu.models import minicpm_sala as sala
 from tepdist_tpu.ops.pallas import block_topk_attention as bt
+from tepdist_tpu.ops.pallas import flash_attention as fa
 from tepdist_tpu.ops.pallas import lightning_attention as la
 from tepdist_tpu.optim import make_optimizer
 from tepdist_tpu.parallel.sync_free import build_ga_step
@@ -403,6 +405,30 @@ def test_the_choice_carries_no_gradient():
         bt.select_blocks(q[:, :100], k[:, :100], GEO)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_attention_from_a_saved_forward_is_the_kernels_own(dtype):
+    """What a walk's recomputation runs (``_attend_from``: the forward
+    kernel's ``(o, lse)`` handed in) has ``_attend``'s output and VJP bit
+    for bit, runs the backward kernel alone, and takes no gradient into the
+    saved pair or the sets."""
+    q, k, v, do = sparse_inputs(128, seed=3, dtype=dtype)
+    idx = bt.select_blocks(q, k, GEO)
+    bs = GEO.block_size
+    want, want_pull = jax.vjp(
+        lambda *a: bt._attend(*a, idx, bs, True), q, k, v)
+    o, lse = bt.forward(q, k, v, idx, block_size=bs, interpret=True)
+    assert lse.shape == (2, 2, 2, 128) and lse.dtype == jnp.float32
+    from_saved = lambda *a: bt._attend_from(*a, idx, o, lse, bs, True)  # noqa: E731,E501
+    got, pull = jax.vjp(from_saved, q, k, v)
+    for a, b in zip((got,) + pull(do), (want,) + want_pull(do), strict=True):
+        assert a.dtype == b.dtype == dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert kernel_counts(from_saved, q, k, v) == {}
+    assert kernel_counts(
+        jax.grad(lambda *a: from_saved(*a).astype(jnp.float32).sum(),
+                 argnums=(0, 1, 2)), q, k, v) == {"tepdist_topk_attn_bwd": 1}
+
+
 # Scores apart, scores on a grid of four values (ties inside and at the
 # K-th place), all equal, and infinities of both signs among them.
 @pytest.mark.parametrize("levels,K", [(0, 4), (4, 4), (4, 7), (1, 5),
@@ -491,8 +517,10 @@ def nbytes(tree):
 
 def test_every_walk_accumulates_in_the_layer_loop_and_counts_its_kernels():
     """Two micro batches: all four stacks' leaves are found by the sink
-    (embedding, head and final norm are outside the blocks), and each mixing
-    kernel's forward runs twice a layer (the walk and its recomputation)."""
+    (embedding, head and final norm are outside the blocks), the linear
+    attention's forward runs twice a layer (the walk and its recomputation)
+    and the block top-k attention's once: each sparse layer hands the walk
+    its sets and its forward kernel's ``(o, lse)``."""
     cfg = dataclasses.replace(CFG, remat=True, loss_chunk=48)
     params = sala.stacked_init_params(cfg, KEY)
     step, tx = ga_step(cfg, 2)
@@ -504,11 +532,126 @@ def test_every_walk_accumulates_in_the_layer_loop_and_counts_its_kernels():
     assert gauge("ga_fused_bytes") == nbytes(stacks)
     assert gauge("ga_unfused_bytes") == nbytes(params) - nbytes(stacks)
     assert gauge("lin_attn_calls") == 2 * 3
-    assert gauge("topk_attn_calls") == 2 * 2
+    assert gauge("topk_attn_calls") == 2 * 1
     assert gauge("topk_attn_dense_calls") == 0
     assert gauge("topk_attn_keys_per_query") == pytest.approx(
         bt.mean_keys_per_query(128, GEO))
-    assert gauge("ssm_scan_calls") == 0 and gauge("attn_kept_calls") == 0
+    assert gauge("ssm_scan_calls") == 0
+    # A micro batch of one sequence, two sparse layers, two hand-overs each:
+    # o float32 [128, 4, 16] with lse [2, 2, 128], and the sets [2, 128, 4].
+    assert gauge("attn_kept_calls") == 2 * 2
+    assert gauge("attn_kept_bytes") == 2 * 4 * 128 * (4 * 16 + 4 + 2 * 4)
+
+
+def equations(fn, *args):
+    """Every equation of ``fn``'s jaxpr, nested jaxprs included (a loop's
+    body once)."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for v in eqn.params.values():
+                for j in v if isinstance(v, (list, tuple)) else (v,):
+                    j = getattr(j, "jaxpr", j)
+                    if hasattr(j, "eqns"):
+                        yield from walk(j)
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+def kernel_counts(fn, *args):
+    return dict(collections.Counter(
+        e.params["name"] for e in equations(fn, *args)
+        if e.primitive.name == "pallas_call"))
+
+
+def choices(fn, *args):
+    """How often ``select_blocks`` is in ``fn``'s program: the one place of
+    the model that reads a float's bits as an integer
+    (``block_topk_attention._ordered_bits``)."""
+    return sum(e.primitive.name == "bitcast_convert_type"
+               for e in equations(fn, *args))
+
+
+def whole_remat(monkeypatch):
+    """Every block under ``nothing_kept``: all of it rematerialised, as
+    before a walk kept anything."""
+    block = sala.block
+
+    def whole(*args, **kwargs):
+        with fa.nothing_kept():
+            return block(*args, **kwargs)
+    monkeypatch.setattr(sala, "block", whole)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_a_walked_sparse_layer_keeps_its_forward_and_its_choice(dtype,
+                                                                monkeypatch):
+    """Two micro batches over the stacked model: each sparse layer's forward
+    kernel and choice are in the step once (the walk; its recomputation
+    takes them back) where a block rematerialised whole holds them twice,
+    the other kernels as often as there, and two steps leave loss,
+    parameters and optimizer state bit for bit the same."""
+    cfg = dataclasses.replace(CFG, remat=True, loss_chunk=48, dtype=dtype)
+    params = sala.stacked_init_params(cfg, KEY, std=0.05)
+    tokens = sala.fake_batch(cfg, 2, 128, seed=3)
+    step, tx = ga_step(cfg, 2)
+    state = tx.init(params)
+    kept = kernel_counts(step, params, state, tokens)
+    kept_choices = choices(step, params, state, tokens)
+    got = (params, state)
+    for _ in range(2):
+        loss_got, *got = jax.jit(step)(*got, tokens)
+
+    whole_remat(monkeypatch)
+    step, _ = ga_step(cfg, 2)
+    whole = kernel_counts(step, params, state, tokens)
+    assert metrics().gauge("attn_kept_calls").value == 0
+    assert metrics().gauge("topk_attn_calls").value == 2 * 2
+    want = (params, state)
+    for _ in range(2):
+        loss_want, *want = jax.jit(step)(*want, tokens)
+
+    sparse_layers = cfg.mixer_types.count(sala.SPARSE)
+    assert kept.pop("tepdist_topk_attn_fwd") == sparse_layers
+    assert whole.pop("tepdist_topk_attn_fwd") == 2 * sparse_layers
+    assert kept == whole and kept["tepdist_topk_attn_bwd"] == sparse_layers
+    assert kept_choices == sparse_layers
+    assert choices(step, params, state, tokens) == 2 * sparse_layers
+    assert float(loss_got) == float(loss_want)
+    assert np.isfinite(float(loss_got))
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want), strict=True):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("stacked,remat,micro", [
+    (True, False, 2),       # a plain scan, nothing rematerialised
+    (False, True, 2),       # l0.. blocks, each under jax.checkpoint
+    (True, True, 1)])       # one micro batch: scan_blocks' plain scan
+def test_outside_a_walk_nothing_is_handed_over(stacked, remat, micro,
+                                               monkeypatch):
+    """Where no walk keeps anything the step is the program of a model with
+    no hand-over in it, equation for equation, and the gauges read 0."""
+    cfg = dataclasses.replace(CFG, remat=remat, loss_chunk=48)
+    init = sala.stacked_init_params if stacked else sala.init_params
+    params = init(cfg, KEY)
+    tokens = sala.fake_batch(cfg, 2, 128)
+    step, tx = ga_step(cfg, micro)
+    state = tx.init(params)
+    here = [str(e.primitive) for e in equations(step, params, state, tokens)]
+    assert metrics().gauge("attn_kept_calls").value == 0
+    assert metrics().gauge("attn_kept_bytes").value == 0
+    # Two sparse layers; a block under ``jax.checkpoint`` holds its forward
+    # kernel again in the backward pass.
+    assert kernel_counts(step, params, state, tokens)[
+        "tepdist_topk_attn_fwd"] == 2 * (2 if remat else 1)
+    for module in (bt, fa):
+        monkeypatch.setattr(module, "hand_over",
+                            lambda attend: attend(None))
+    step, _ = ga_step(cfg, micro)
+    assert [str(e.primitive) for e in equations(
+        step, params, state, tokens)] == here
+    assert "optimization_barrier" not in here
 
 
 @pytest.mark.parametrize("stacked", [False, True])

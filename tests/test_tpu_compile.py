@@ -537,10 +537,12 @@ def test_the_minicpm_sala_cells_step_compiles_for_v5e(v5e_devices,
     """``minicpm-sala.train.s32768``'s step from the cell's own files (2
     micro batches of one 32,768-token sequence; a sparse layer and three
     lightning layers as two walks; ``adamw_bf16``), kernels not interpreted:
-    both walks' leaves accumulate inside the backward layer loop, each
-    mixing kernel's forward runs twice a layer and micro batch, the sparse
-    layer visits chosen blocks (no plain causal flash call, no ``[T, T]``
-    array), no array is as wide as ``[T, intermediate]`` (the block's
+    both walks' leaves accumulate inside the backward layer loop, the
+    linear-attention kernel's forward runs twice a layer and micro batch and
+    the block top-k attention's once (the walk keeps its ``(o, lse)`` and
+    the chosen sets: two hand-overs, 289,406,976 bytes a micro batch), the
+    sparse layer visits chosen blocks (no plain causal flash call, no ``[T,
+    T]`` array), no array is as wide as ``[T, intermediate]`` (the block's
     token-wise parts run in chunks), and the compiler's peak fits the
     chip."""
     import json
@@ -582,10 +584,14 @@ def test_the_minicpm_sala_cells_step_compiles_for_v5e(v5e_devices,
     assert fused == stacks and 2 * 1_109_393_408 < stacks < 2.22e9
     assert fused / (fused + unfused) == pytest.approx(0.8802, abs=5e-4)
     assert gauge("lin_attn_calls") == 6         # 3 layers, each twice
-    assert gauge("topk_attn_calls") == 2
+    assert gauge("topk_attn_calls") == 1        # kept: not run again
     assert gauge("topk_attn_dense_calls") == 0
     assert gauge("topk_attn_keys_per_query") == 3812.5
-    assert gauge("attn_kept_calls") == 0 and gauge("ssm_scan_calls") == 0
+    # The sparse layer's two hand-overs: o bf16 [1, T, 32, 128] with lse
+    # float32 [1, 2, 16, T], and the sets int32 [1, 2, T, 64].
+    assert gauge("attn_kept_calls") == 2 and gauge("ssm_scan_calls") == 0
+    assert gauge("attn_kept_bytes") == T * (32 * 128 * 2 + 32 * 4) \
+        + 2 * T * 64 * 4 == 289_406_976
 
     text = compiled.as_text()
     calls = [line.split(" = ", 1)[0].strip() for line in text.splitlines()
@@ -593,7 +599,7 @@ def test_the_minicpm_sala_cells_step_compiles_for_v5e(v5e_devices,
     for kernel, times in (("tepdist_lightning_fwd", 2),
                           ("tepdist_lightning_bwd_dq", 1),
                           ("tepdist_lightning_bwd_dkv", 1),
-                          ("tepdist_topk_attn_fwd", 2),
+                          ("tepdist_topk_attn_fwd", 1),
                           ("tepdist_topk_attn_bwd", 1)):
         assert sum(kernel in c for c in calls) == times, (kernel, calls)
     assert not [c for c in calls if "tepdist_flash_" in c], calls
