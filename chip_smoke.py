@@ -159,8 +159,9 @@ def _plan(cfg_name: str, batch: int, seq: int, devices, **plan_kwargs):
         # dense loss); calls a micro batch whose forward pass the walk
         # keeps from the backward, and their kept bytes (both 0: GPT-2's
         # "full" rematerialisation); forward selective-scan kernel calls a
-        # micro batch and the chunk-boundary states a call holds (both 0: no
-        # state-space layer); calls of the expert layers' row-copy kernel a
+        # micro batch, the chunk-boundary states a call holds and the forward
+        # calls of the conv before the scan (all 0: no state-space layer);
+        # calls of the expert layers' row-copy kernel a
         # micro batch (0: no expert layer that holds a share); forward calls
         # of the linear and the block top-k attention kernels (0: no such
         # layer).
@@ -168,8 +169,9 @@ def _plan(cfg_name: str, batch: int, seq: int, devices, **plan_kwargs):
            for k in ("ga_fused_bytes", "ga_unfused_bytes",
                      "ce_fused_chunks", "attn_kept_calls",
                      "attn_kept_bytes", "ssm_scan_calls",
-                     "ssm_boundary_bytes", "moe_rows_sum_calls",
-                     "lin_attn_calls", "topk_attn_calls")},
+                     "ssm_boundary_bytes", "ssm_conv_calls",
+                     "moe_rows_sum_calls", "lin_attn_calls",
+                     "topk_attn_calls")},
         "cache_hit": in_plan["plan_cache_hits"] > 0
         and in_plan["plan_cache_writes"] == 0}
 

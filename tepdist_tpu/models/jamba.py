@@ -26,7 +26,8 @@ Mamba mixer, for a sequence ``a_1..a_T``:
 with ``x0 = tok_emb[tokens]``, a final RMSNorm and logits through the tied
 embedding. The recurrence runs in ``ops/pallas/selective_scan.py`` (chunked
 over the sequence, the state carried in VMEM; float32 state, ``delta``,
-``exp`` and accumulation); the conv is four shifted multiply-adds.
+``exp`` and accumulation), the conv with its SiLU in
+``ops/pallas/causal_conv.py`` (float32 products, sums and SiLU).
 
 bf16 weights and activations; norms, ``delta``, the scan, softmax statistics
 and the loss in float32; ``A_log``, ``D`` and ``dt_bias`` float32 leaves.
@@ -58,6 +59,7 @@ from tepdist_tpu.models.layers import (
     rms_norm,
     scan_blocks,
 )
+from tepdist_tpu.ops.pallas.causal_conv import causal_conv
 from tepdist_tpu.ops.pallas.selective_scan import (
     BLOCK_D,
     CHUNK,
@@ -226,23 +228,12 @@ def attention(blk, a, cfg: JambaConfig):
     return o @ blk["wo"]
 
 
-# The three below are elementwise work over the mixer's and the MLP's widest
+# The two below are elementwise work over the mixer's and the MLP's widest
 # arrays with float32 intermediates. Each is rematerialised inside the
 # block's own backward pass (its float32 intermediates are made again from
-# its bf16 operands, not held: 0.6e9 bytes of a block's working set at 8192
-# tokens), which changes no value.
-@jax.checkpoint
-def causal_conv(u, w, b):
-    """Depth-wise causal convolution and SiLU: u [B, T, Di], w [d_conv, Di],
-    b [Di] -> ``silu(b + sum_j w[j] * u[t - (d_conv - 1) + j])``, zeros
-    before the sequence; float32 sums, back in u's dtype."""
-    K, T = w.shape[0], u.shape[1]
-    padded = jnp.pad(u, ((0, 0), (K - 1, 0), (0, 0))).astype(jnp.float32)
-    total = b.astype(jnp.float32) + sum(
-        w[j].astype(jnp.float32) * padded[:, j:j + T] for j in range(K))
-    return jax.nn.silu(total).astype(u.dtype)
-
-
+# its bf16 operands, not held), which changes no value. The convolution
+# before the scan is a kernel pair whose backward does the same
+# (``ops/pallas/causal_conv.py``).
 @jax.checkpoint
 def step_sizes(r, dt_proj, dt_bias):
     """``delta = softplus(r W_dt + b_dt)`` in float32."""

@@ -85,6 +85,11 @@ def stands_for(layers: int):
         _LAYERS.reset(token)
 
 
+def layers_stood_for() -> int:
+    """What ``stands_for`` is set to where this is called (1 outside it)."""
+    return _LAYERS.get()
+
+
 def _count_forward(times: int) -> None:
     calls = metrics().gauge("ssm_scan_calls")
     calls.set((calls.value or 0) + times)

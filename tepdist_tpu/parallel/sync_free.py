@@ -262,15 +262,16 @@ def _report_ga_bytes(fused: int, unfused: int) -> None:
     walks of that layer loop keep of their attention
     (``models/layers.py:scan_blocks``), the calls of their expert layers'
     row-copy kernel, the selective-scan kernels' forward calls and held
-    chunk-boundary states (``ops/pallas/selective_scan.py``), and the
-    linear-attention and block top-k attention kernels' forward calls
+    chunk-boundary states (``ops/pallas/selective_scan.py``), the forward
+    calls of the conv before that scan (``ops/pallas/causal_conv.py``), and
+    the linear-attention and block top-k attention kernels' forward calls
     (``ops/pallas/lightning_attention.py``, ``block_topk_attention.py``),
     are added as they are traced."""
     metrics().gauge("ga_fused_bytes").set(fused)
     metrics().gauge("ga_unfused_bytes").set(unfused)
     for traced in ("attn_kept_calls", "attn_kept_bytes", "ssm_scan_calls",
-                   "ssm_boundary_bytes", "moe_rows_sum_calls",
-                   "lin_attn_calls", "topk_attn_calls",
+                   "ssm_boundary_bytes", "ssm_conv_calls",
+                   "moe_rows_sum_calls", "lin_attn_calls", "topk_attn_calls",
                    "topk_attn_keys_per_query", "topk_attn_dense_calls"):
         metrics().gauge(traced).set(0)
 

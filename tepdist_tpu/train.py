@@ -426,14 +426,16 @@ def plan_training(
              "again",
              metrics().gauge("attn_kept_calls").value,
              metrics().gauge("attn_kept_bytes").value)
-    # Set while the step's selective scans were traced
-    # (ops/pallas/selective_scan.py); both 0 for a model without one.
+    # Set while the step's selective scans and the convs before them were
+    # traced (ops/pallas/selective_scan.py, causal_conv.py); all 0 for a
+    # model without one.
     log.info("selective scan: %.0f forward kernel calls a micro batch (a "
              "rematerialised layer's second run counted), %.0f bytes of "
              "chunk-boundary states held from a call's forward to its "
-             "backward",
+             "backward; %.0f forward calls of the conv before it",
              metrics().gauge("ssm_scan_calls").value or 0,
-             metrics().gauge("ssm_boundary_bytes").value or 0)
+             metrics().gauge("ssm_boundary_bytes").value or 0,
+             metrics().gauge("ssm_conv_calls").value or 0)
     # Set while the step's linear and block top-k attention layers were
     # traced (ops/pallas/lightning_attention.py, block_topk_attention.py);
     # all 0 for a model without them.

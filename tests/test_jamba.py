@@ -17,6 +17,7 @@ import pytest
 
 from benchmark.reference import jamba as ref
 from tepdist_tpu.models import jamba
+from tepdist_tpu.ops.pallas import causal_conv as conv
 from tepdist_tpu.ops.pallas import selective_scan as ssm
 from tepdist_tpu.optim import make_optimizer
 from tepdist_tpu.parallel.sync_free import build_ga_step
@@ -273,6 +274,123 @@ def test_the_kernels_state_their_cost_to_the_planner():
         * elements
 
 
+# -- the conv kernels against the jax.numpy form ------------------------------
+
+CONV_NAMES = ("c", "du", "dw", "db")
+
+
+def conv_inputs(batch, T, Di, dtype, seed=0, K=4):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(ks[0], (batch, T, Di)).astype(dtype),
+            (0.5 * jax.random.normal(ks[1], (K, Di))).astype(dtype),
+            (0.1 * jax.random.normal(ks[2], (Di,))).astype(dtype),
+            jax.random.normal(ks[3], (batch, T, Di)).astype(dtype))
+
+
+def out_and_gradients(fn, u, w, b, dc):
+    out, pull = jax.vjp(fn, u, w, b)
+    return (out,) + pull(dc)
+
+
+def hold_conv(got, want, limit, block_t=conv.STRIP):
+    """``got`` (c, du, dw, db) to ``want``, and the rows a halo fills (the
+    first of the sequence and of every later time block) on their own."""
+    for name, g, w_ in zip(CONV_NAMES, got, want):
+        assert g.shape == w_.shape and g.dtype == w_.dtype, name
+        assert rel_l2(g, w_) < limit, name
+    for name, g, w_ in zip(CONV_NAMES[:2], got, want):
+        for at in range(0, g.shape[1] - 4, block_t):
+            edge = slice(max(at - 4, 0), at + 4)
+            assert rel_l2(g[:, edge], w_[:, edge]) < limit, (name, at)
+
+
+S = conv.STRIP      # the least time block
+
+
+# Two time blocks with the sequence ending inside the second; four blocks
+# and two channel blocks, two sequences; one block longer than the sequence;
+# a block of several strips; three taps.
+@pytest.mark.parametrize("dtype,limit", [(jnp.float32, 2e-6),
+                                         (jnp.bfloat16, 4e-3)])
+@pytest.mark.parametrize("batch,T,Di,K,block_t,block_d", [
+    (1, S + 8, 128, 4, S, 128), (2, 3 * S + 36, 256, 4, S, 128),
+    (1, 20, 128, 4, 512, 512), (1, 3 * S, 256, 4, 3 * S, 256),
+    (1, S + 18, 128, 3, S, 128)])
+def test_conv_kernels_match_the_jax_numpy_form(dtype, limit, batch, T, Di, K,
+                                               block_t, block_d):
+    """Values and the gradients of ``u``, ``w``, ``b``: zeros before the
+    sequence, the rows before a block carried into it (forward) and the rows
+    after it (backward), the padded rows past the end adding nothing to the
+    sums."""
+    args = conv_inputs(batch, T, Di, dtype, K=K)
+    got = out_and_gradients(lambda *a: conv.causal_conv(
+        *a, block_t=block_t, block_d=block_d), *args)
+    hold_conv(got, out_and_gradients(conv.reference, *args), limit, block_t)
+
+
+def test_conv_sums_are_float32_under_bf16_operands():
+    """bf16 operands over 4096 rows: the taps' and the bias's gradients are
+    sums of 4096 products each, within bf16's rounding of the float32 form's
+    results (a bf16 accumulator would stand 1e-2 off)."""
+    args = conv_inputs(1, 4096, 128, jnp.bfloat16, seed=4)
+    got = out_and_gradients(conv.causal_conv, *args)
+    want = out_and_gradients(
+        conv.reference, *(a.astype(jnp.float32) for a in args))
+    for name, g, w_ in zip(CONV_NAMES, got, want):
+        assert g.dtype == jnp.bfloat16, name
+        assert rel_l2(g, w_) < 4e-3, name
+
+
+@pytest.mark.parametrize("which", ["forward", "backward"])
+def test_a_dropped_halo_fails_the_conv_comparison(which, monkeypatch):
+    """The control of the comparison above: the same kernels with what one
+    time block hands the next zeroed (the last rows of ``u`` going forward,
+    the first rows of ``g`` going backward)."""
+    name, carried = {"forward": ("_fwd_kernel", 4),     # the scratch's place
+                     "backward": ("_bwd_kernel", 7)}[which]
+    real = getattr(conv, name)
+
+    def dropped(*refs, **how):
+        refs[carried][...] = jnp.zeros(refs[carried].shape, jnp.float32)
+        real(*refs, **how)
+
+    monkeypatch.setattr(conv, name, dropped)
+    u, w, b, dc = conv_inputs(1, 2 * S, 128, jnp.float32, seed=6)
+    how = dict(block_t=S, block_d=128, interpret=True)
+    # Not through the jitted entry points: their traces are cached.
+    c = conv._fwd_call.__wrapped__(u, w, b, **how)
+    du, dw, db = conv._bwd_call.__wrapped__(u, w, b, dc, **how)
+    want = out_and_gradients(conv.reference, u, w, b, dc)
+    with pytest.raises(AssertionError):
+        hold_conv((c, du, dw, db), want, 2e-6)
+    # What the fault does not touch is where it was.
+    sound = (du, dw, db) if which == "forward" else (c,)
+    for g, w_ in zip(sound, want[1:] if which == "forward" else want[:1]):
+        assert rel_l2(g, w_) < 2e-6
+
+
+def test_the_conv_refuses_shapes_it_cannot_tile():
+    u, w, b, _ = conv_inputs(1, 16, 128, jnp.float32)
+    with pytest.raises(ValueError):
+        conv.causal_conv(u[..., :64], w[:, :64], b[:64])
+    with pytest.raises(ValueError):
+        conv.causal_conv(u, jnp.zeros((9, 128)), b)
+    with pytest.raises(ValueError):
+        conv.causal_conv(u, w, b[:64])
+
+
+def test_the_conv_kernels_state_their_cost_to_the_planner():
+    from tepdist_tpu.graph.cost import jaxpr_flops
+    u, w, b, dc = conv_inputs(1, 32, 128, jnp.float32)
+    fwd = jax.make_jaxpr(conv.causal_conv)(u, w, b)
+    both = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(conv.causal_conv(*a) * dc), argnums=(0, 1, 2)))(
+        u, w, b)
+    assert jaxpr_flops(fwd.jaxpr) >= conv.FWD_FLOPS * u.size
+    assert jaxpr_flops(both.jaxpr) >= (conv.FWD_FLOPS + conv.BWD_FLOPS) \
+        * u.size
+
+
 # -- the order of the layers -------------------------------------------------
 
 def test_28_layers_run_in_the_period_rules_order():
@@ -346,8 +464,8 @@ def nbytes(tree):
 def test_every_walk_accumulates_in_the_layer_loop_and_attention_is_kept():
     """Four micro batches: all three stacks' leaves are found by the sink
     (the embedding and the final norm are outside the blocks), the attention
-    layer's flash forward is kept, and the scan's forward runs twice a Mamba
-    layer (the walk and its recomputation)."""
+    layer's flash forward is kept, and the scan's and the conv's forward run
+    twice a Mamba layer (the walk and its recomputation)."""
     cfg = dataclasses.replace(CFG, remat=True, loss_chunk=16)
     params = jamba.stacked_init_params(cfg, KEY)
     step, tx = ga_step(cfg, 4)
@@ -360,6 +478,7 @@ def test_every_walk_accumulates_in_the_layer_loop_and_attention_is_kept():
     assert gauge("ga_unfused_bytes") == nbytes(params) - nbytes(stacks)
     assert gauge("attn_kept_calls") == 1
     assert gauge("ssm_scan_calls") == 2 * 4            # 4 Mamba layers
+    assert gauge("ssm_conv_calls") == 2 * 4
     assert gauge("ssm_boundary_bytes") == ssm.boundary_bytes(
         1, 32, cfg.d_inner, cfg.mamba_d_state, cfg.ssm_chunk) \
         == 2 * 8 * 128 * 4
@@ -418,7 +537,7 @@ def test_the_mixers_parts_carry_their_scopes():
     text = jax.jit(jamba.loss_fn, static_argnums=2).lower(
         params, tokens, cfg).as_text(debug_info=True)
     for scope in ("ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_out_proj",
-                  "tepdist_ssm_fwd"):
+                  "tepdist_ssm_fwd", "tepdist_conv_fwd"):
         assert scope in text, scope
 
 
@@ -487,5 +606,7 @@ def test_a_narrow_jambas_step_compiles_for_a_described_v5e(v5e_chip,
     # backward.
     assert sum("tepdist_ssm_fwd" in c for c in calls) == 4, calls
     assert sum("tepdist_ssm_bwd" in c for c in calls) == 2, calls
+    assert sum("tepdist_conv_fwd" in c for c in calls) == 4, calls
+    assert sum("tepdist_conv_bwd" in c for c in calls) == 2, calls
     assert sum("tepdist_flash_fwd" in c for c in calls) == 1, calls
     assert compiled.memory_analysis().peak_memory_in_bytes > 0
