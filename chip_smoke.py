@@ -140,7 +140,7 @@ def _plan(cfg_name: str, batch: int, seq: int, devices, **plan_kwargs):
     ``cache_hit``: the step program's first compile in this process (the
     winner's post-check, inside ``plan_training``) was read from the
     persistent cache and nothing that long had to be written."""
-    from tepdist_tpu.telemetry import metrics
+    from tepdist_tpu.telemetry import traced
     from tepdist_tpu.train import plan_training
 
     cfg, params, tokens, tx = _model(cfg_name, batch, seq)
@@ -153,25 +153,9 @@ def _plan(cfg_name: str, batch: int, seq: int, devices, **plan_kwargs):
                for k, v in _cache_traffic().items()}
     return tplan, tokens, {
         "setup_planner_seconds": seconds, **in_plan,
-        # Parameter bytes whose gradients a GA step accumulates inside the
-        # loss's layer loop / by the tree-wide add (both 0: one micro batch);
-        # chunks of the loss whose gradients its forward loop makes (0: the
-        # dense loss); calls a micro batch whose forward pass the walk
-        # keeps from the backward, and their kept bytes (both 0: GPT-2's
-        # "full" rematerialisation); forward selective-scan kernel calls a
-        # micro batch, the chunk-boundary states a call holds and the forward
-        # calls of the conv before the scan (all 0: no state-space layer);
-        # calls of the expert layers' row-copy kernel a
-        # micro batch (0: no expert layer that holds a share); forward calls
-        # of the linear and the block top-k attention kernels (0: no such
-        # layer).
-        **{k: metrics().gauge(k).value
-           for k in ("ga_fused_bytes", "ga_unfused_bytes",
-                     "ce_fused_chunks", "attn_kept_calls",
-                     "attn_kept_bytes", "ssm_scan_calls",
-                     "ssm_boundary_bytes", "ssm_conv_calls",
-                     "moe_rows_sum_calls", "lin_attn_calls",
-                     "topk_attn_calls")},
+        # The gauges set while the step was traced, each described where it
+        # is counted (tepdist_tpu/telemetry/traced.py: GROUP).
+        **traced.values(),
         "cache_hit": in_plan["plan_cache_hits"] > 0
         and in_plan["plan_cache_writes"] == 0}
 

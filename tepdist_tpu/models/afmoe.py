@@ -52,20 +52,22 @@ the body chooses by ``lax.cond`` on its layer's kind
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from tepdist_tpu.models.layers import (
-    cross_entropy,
-    gqa_heads,
-    held_routing_stats,
-    rms_norm,
-    scan_blocks,
+from tepdist_tpu.models import decoder
+from tepdist_tpu.models.decoder import (
+    fake_batch,  # noqa: F401 (the model's, as every decoder's)
+    held_weights,
+    layer_dicts,
+    stack_layers,
+    walk_layers,
 )
+from tepdist_tpu.models.layers import cross_entropy, gqa_heads, rms_norm
 from tepdist_tpu.ops.grouped_matmul import routed_experts
 
 WINDOW, GLOBAL = "sliding_attention", "full_attention"
@@ -174,28 +176,16 @@ def init_params(cfg: AfmoeConfig, key, std: float = 0.02) -> Dict[str, Any]:
 
 
 def _stacks(cfg: AfmoeConfig):
-    """(name, first layer, one past the last) of each stack of layers."""
+    """(name, first layer, layers) of each stack of layers."""
     n, L = cfg.num_dense_layers, cfg.num_hidden_layers
-    return [s for s in (("dense", 0, n), ("blocks", n, L)) if s[1] < s[2]]
+    return [s for s in (("dense", 0, n), ("blocks", n, L - n)) if s[2]]
 
 
 def stacked_init_params(cfg: AfmoeConfig, key, std: float = 0.02):
     """``init_params`` with the layers stacked: ``dense`` and ``blocks``,
     [layers of that kind, ...] each."""
-    params = init_params(cfg, key, std)
-    out = {k: params[k] for k in _OUTSIDE_BLOCKS}
-    for name, lo, hi in _stacks(cfg):
-        out[name] = {k: jnp.stack([params[f"l{i}"][k] for i in range(lo, hi)])
-                     for k in params[f"l{lo}"]}
-    return out
-
-
-def _layers(params, cfg: AfmoeConfig):
-    """Every layer's own dict, whichever the layout."""
-    if "l0" in params:
-        return [params[f"l{i}"] for i in range(cfg.num_hidden_layers)]
-    return [jax.tree_util.tree_map(lambda a, i=i - lo: a[i], params[name])
-            for name, lo, hi in _stacks(cfg) for i in range(lo, hi)]
+    return stack_layers(init_params(cfg, key, std), _stacks(cfg),
+                        _OUTSIDE_BLOCKS)
 
 
 def attention(blk, a, cfg: AfmoeConfig, window):
@@ -263,11 +253,6 @@ def router(blk, h, cfg: AfmoeConfig):
         experts
 
 
-def held_mask(experts, cfg: AfmoeConfig):
-    first, count = cfg.experts_held
-    return (experts >= first) & (experts < first + count)
-
-
 def swiglu(h, w_gate, w_up, w_down):
     g = (h @ w_gate).astype(jnp.float32)
     return (jax.nn.silu(g) * (h @ w_up).astype(jnp.float32)).astype(
@@ -281,8 +266,8 @@ def moe(blk, x, cfg: AfmoeConfig):
     h = x.reshape(B * T, d)
     with jax.named_scope("moe_router"):
         _, weights, experts = router(blk, h, cfg)
-        if cfg.experts_held[1] < cfg.num_experts:
-            weights = jnp.where(held_mask(experts, cfg), weights, 0.0)
+        weights = held_weights(weights, experts, cfg.experts_held,
+                               cfg.num_experts)
     y = routed_experts(h, weights, experts, blk["w_gate"], blk["w_up"],
                        blk["w_down"], cfg.num_experts, cfg.moe_tile_m,
                        held=cfg.experts_held)
@@ -308,32 +293,10 @@ def hidden_states(params, tokens, cfg: AfmoeConfig):
     """tokens int32 [B, T] -> final normalised hidden [B, T, d]."""
     x = (params["tok_emb"][tokens]
          * math.sqrt(cfg.hidden_size)).astype(cfg.dtype)
-    windowed = [t == WINDOW for t in cfg.layer_types]
-    if "l0" in params:
-        step = jax.checkpoint(block, static_argnums=(2, 3)) if cfg.remat \
-            else block
-        for i in range(cfg.num_hidden_layers):
-            x = step(params[f"l{i}"], x, cfg, windowed[i])
-    else:
-        for name, lo, hi in _stacks(cfg):
-            kinds = windowed[lo:hi]
-            if len(set(kinds)) == 1:
-                x = _walk(lambda h, blk, w=kinds[0]: (block(blk, h, cfg, w),
-                                                      None),
-                          x, params[name], None, cfg)
-            else:
-                x = _walk(lambda h, blk, w: (block(blk, h, cfg, w), None),
-                          x, params[name], np.asarray(kinds, np.int32), cfg)
+    x = walk_layers(lambda blk, h, window: block(blk, h, cfg, window), x,
+                    params, _stacks(cfg),
+                    [t == WINDOW for t in cfg.layer_types], cfg.remat)
     return rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
-
-
-def _walk(body, x, stack, kinds, cfg: AfmoeConfig):
-    if cfg.remat:
-        return scan_blocks(body, x, stack, kinds)[0]
-    if kinds is None:
-        return jax.lax.scan(body, x, stack)[0]
-    return jax.lax.scan(lambda h, layer: body(h, *layer), x,
-                        (stack, kinds))[0]
 
 
 def forward(params, tokens, cfg: AfmoeConfig):
@@ -358,7 +321,8 @@ def expert_choices(params, tokens, cfg: AfmoeConfig):
          * math.sqrt(cfg.hidden_size)).astype(cfg.dtype)
     S = x.shape[0] * x.shape[1]
     ids = []
-    for blk, kind in zip(_layers(params, cfg), cfg.layer_types):
+    for blk, kind in zip(layer_dicts(params, _stacks(cfg)),
+                         cfg.layer_types):
         if "router" in blk:
             a = rms_norm(x, blk["input_ln"], eps)
             mid = x + rms_norm(attention(blk, a, cfg, kind == WINDOW),
@@ -369,18 +333,6 @@ def expert_choices(params, tokens, cfg: AfmoeConfig):
     return jnp.stack(ids)
 
 
-def routing_stats(params, tokens, cfg: AfmoeConfig) -> dict:
-    """What the routers did with ``tokens`` [B, T+1], outside any step: the
-    expert ids of every expert layer (``experts`` [layers, S, k]), the rows
-    each held expert got (``held_rows`` [layers, count]) and the counters
-    and gauges of ``models/layers.py:held_routing_stats``."""
-    return held_routing_stats(
-        expert_choices(params, tokens[:, :-1], cfg), cfg.num_experts,
-        cfg.moe_tile_m, cfg.experts_held)
-
-
-def fake_batch(cfg: AfmoeConfig, batch_size: int, seq_len: int,
-               seed: int = 0):
-    return jax.random.randint(jax.random.PRNGKey(seed),
-                              (batch_size, seq_len + 1), 0, cfg.vocab_size,
-                              dtype=jnp.int32)
+# What the routers did with ``tokens`` [B, T+1], outside any step
+# (``models/decoder.py:routing_stats`` over this model's choices).
+routing_stats = functools.partial(decoder.routing_stats, expert_choices)

@@ -48,23 +48,24 @@ small leaves of a walk without its matrices. ``loss_fn`` takes either layout.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from tepdist_tpu.models.layers import (
-    cross_entropy,
-    gqa_heads,
-    rms_norm,
-    scan_blocks,
+from tepdist_tpu.models.decoder import (
+    fake_batch,  # noqa: F401 (the model's, as every decoder's)
+    run_stacks,
+    runs,
+    stack_layers,
+    walk_layers,
 )
+from tepdist_tpu.models.layers import cross_entropy, gqa_heads, rms_norm
 from tepdist_tpu.ops.pallas.causal_conv import causal_conv
 from tepdist_tpu.ops.pallas.selective_scan import (
     BLOCK_D,
     CHUNK,
     selective_scan,
-    stands_for,
 )
 
 MAMBA, ATTENTION = "mamba", "attention"
@@ -114,13 +115,7 @@ class JambaConfig:
     @property
     def runs(self) -> Tuple[Tuple[str, int, int], ...]:
         """(kind, first layer, layers) of each run of one kind, in order."""
-        out: List[Tuple[str, int, int]] = []
-        for i, kind in enumerate(self.layer_kinds):
-            if out and out[-1][0] == kind:
-                out[-1] = (kind, out[-1][1], out[-1][2] + 1)
-            else:
-                out.append((kind, i, 1))
-        return tuple(out)
+        return runs(self.layer_kinds)
 
 
 CONFIGS: Dict[str, JambaConfig] = {
@@ -134,22 +129,12 @@ CONFIGS: Dict[str, JambaConfig] = {
 }
 
 _OUTSIDE_BLOCKS = ("tok_emb", "norm_f")
-# The stacked layout's groups of a run's leaves, and the leaves that are not
-# in the first.
+# The stacked layout's groups of a run's leaves (``models/decoder.py``), and
+# the leaves that are not in the first.
 GROUPS = ("run", "vec", "decay")
-_VEC = ("input_ln", "ff_ln", "conv_w", "conv_b", "dt_norm", "b_norm",
-        "c_norm", "dt_bias", "D")
-_DECAY = ("A_log",)
-
-
-def _group(leaf: str) -> str:
-    return "vec" if leaf in _VEC else "decay" if leaf in _DECAY else "run"
-
-
-def run_blocks(params, r: int) -> Dict[str, Any]:
-    """The stacked leaves of run ``r``, its groups side by side: the tree's
-    own leaves, so a gradient-accumulation step finds the walk over them."""
-    return {k: v for g in GROUPS for k, v in params.get(f"{g}{r}", {}).items()}
+_GROUP_OF = {**dict.fromkeys(
+    ("input_ln", "ff_ln", "conv_w", "conv_b", "dt_norm", "b_norm", "c_norm",
+     "dt_bias", "D"), "vec"), "A_log": "decay"}
 
 
 def _layer_params(cfg: JambaConfig, kind: str, key, std: float):
@@ -208,14 +193,9 @@ def stacked_init_params(cfg: JambaConfig, key, std: float = 0.02):
     """``init_params`` with each run of one kind stacked, [layers of the
     run, ...] a leaf, in the run's groups (``run{r}``, ``vec{r}``,
     ``decay{r}``)."""
-    params = init_params(cfg, key, std)
-    out = {k: params[k] for k in _OUTSIDE_BLOCKS}
-    for r, (_, first, count) in enumerate(cfg.runs):
-        layers = [params[f"l{i}"] for i in range(first, first + count)]
-        for k in layers[0]:
-            out.setdefault(f"{_group(k)}{r}", {})[k] = jnp.stack(
-                [blk[k] for blk in layers])
-    return out
+    return stack_layers(init_params(cfg, key, std),
+                        run_stacks(cfg.layer_kinds), _OUTSIDE_BLOCKS, GROUPS,
+                        _GROUP_OF)
 
 
 def attention(blk, a, cfg: JambaConfig):
@@ -280,21 +260,9 @@ def block(blk, x, cfg: JambaConfig, kind: str):
 def hidden_states(params, tokens, cfg: JambaConfig):
     """tokens int32 [B, T] -> final normalised hidden [B, T, d]."""
     x = params["tok_emb"][tokens].astype(cfg.dtype)
-    if "l0" in params:
-        step = jax.checkpoint(block, static_argnums=(2, 3)) if cfg.remat \
-            else block
-        for i, kind in enumerate(cfg.layer_kinds):
-            x = step(params[f"l{i}"], x, cfg, kind)
-    else:
-        for r, (kind, _, count) in enumerate(cfg.runs):
-            def body(h, blk, kind=kind, count=count):
-                # One trace stands for every layer of the run in what the
-                # scan kernel counts of its calls (``ssm_scan_calls``).
-                with stands_for(count):
-                    return block(blk, h, cfg, kind), None
-
-            walk = scan_blocks if cfg.remat else jax.lax.scan
-            x = walk(body, x, run_blocks(params, r))[0]
+    x = walk_layers(lambda blk, h, kind: block(blk, h, cfg, kind), x,
+                    params, run_stacks(cfg.layer_kinds), cfg.layer_kinds,
+                    cfg.remat, GROUPS)
     return rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
 
 
@@ -308,10 +276,3 @@ def loss_fn(params, tokens, cfg: JambaConfig):
     """Cross entropy of tokens [B, T+1] over the tied embedding."""
     x = hidden_states(params, tokens[:, :-1], cfg)
     return cross_entropy(x, params["tok_emb"], tokens[:, 1:], cfg.loss_chunk)
-
-
-def fake_batch(cfg: JambaConfig, batch_size: int, seq_len: int,
-               seed: int = 0):
-    return jax.random.randint(jax.random.PRNGKey(seed),
-                              (batch_size, seq_len + 1), 0, cfg.vocab_size,
-                              dtype=jnp.int32)

@@ -2,9 +2,11 @@
 (the plain table and YaRN's), grouped-query attention over layers of two
 kinds (QK-norm where the block has the gains for it: ``q_norm`` / ``k_norm``
 among its leaves), the (optionally chunked) next-token cross entropy, the
-walk over stacked blocks and the counters of an expert layer that holds a
-share of the experts. One copy, so that a change for one model is seen by the
-others' tests and benchmark cells.
+walk over stacked blocks, a block's token-wise parts in chunks of the
+sequence and the counters of an expert layer that holds a share of the
+experts. One copy, so that a change for one model is seen by the others'
+tests and benchmark cells. What decoders share of their bookkeeping (runs of
+layers, the two parameter layouts, held experts) is ``models/decoder.py``.
 
 A model may interleave walks of different shape: layers of one shape and
 unequal kind share a stack (``scan_blocks(..., kinds=)``), layers of unequal
@@ -36,7 +38,22 @@ from tepdist_tpu.ops.pallas.flash_attention import (
     hand_over,
     nothing_kept,
 )
-from tepdist_tpu.telemetry import metrics
+from tepdist_tpu.telemetry import metrics, traced
+
+traced.declare(
+    "attn_kept_calls", "calls a micro batch (a flash or block top-k "
+    "kernel's forward, a sparse layer's choice) that hand what their forward "
+    "pass made to the backward pass, which does not run it again")
+traced.declare(
+    "attn_kept_bytes", "bytes of output, log-sum-exp and chosen sets those "
+    "calls keep from a micro batch's forward to its backward")
+traced.declare(
+    "moe_rows_sum_calls", "calls a micro batch of the expert layers' "
+    "row-copy kernel (ops/pallas/rows_sum.py): 2 a walked layer that holds "
+    "a share of the experts, 0 where the XLA gathers stayed")
+traced.declare(
+    "ce_fused_chunks", "chunks of the loss whose gradients its forward chunk "
+    "loop makes (0: the dense loss, or a call nobody differentiates)")
 
 
 class BlockGradSink:
@@ -51,21 +68,13 @@ class BlockGradSink:
     ``acc`` (key -> accumulator leaf, an input of the differentiation) a
     noted walk adds each layer's weight gradient into the accumulator inside
     the backward layer loop and hands the sum back as the accumulator's
-    cotangent, and counts in ``attn_kept`` the calls whose forward pass it
-    keeps (``flash_attention.hand_over``: a flash or block top-k kernel's
-    forward, a sparse layer's choice) and their bytes (the gauges
-    ``attn_kept_calls`` /
-    ``attn_kept_bytes``, summed over the walks of one loss) and in
-    ``rows_sum_calls`` the calls of the expert layers' row-copy kernel (the
-    gauge ``moe_rows_sum_calls``)."""
+    cotangent."""
 
     def __init__(self, leaves: Dict[int, int],
                  acc: Optional[Dict[int, jax.Array]] = None):
         self.leaves = leaves
         self.acc = acc
         self.walks: List[Tuple[int, ...]] = []
-        self.attn_kept = [0, 0]
-        self.rows_sum_calls = 0
 
     def __enter__(self):
         self._token = _SINK.set(self)
@@ -79,17 +88,21 @@ _SINK: contextvars.ContextVar[Optional[BlockGradSink]] = \
     contextvars.ContextVar("tepdist_block_grad_sink", default=None)
 
 
-def scan_blocks(body, x, blocks, kinds=None):
+def scan_blocks(body, x, blocks, kinds=None, remat: bool = True):
     """``jax.lax.scan(jax.checkpoint(body), x, blocks)``: ``body(h, block)
     -> (h, y)`` over blocks stacked on a leading layer dim, every block
     rematerialised in the backward pass but, where the backward is written
     out (below), for its attention kernels' output and log-sum-exp. Returns
-    ``(x, ys)``.
+    ``(x, ys)``. ``remat=False`` is the plain ``jax.lax.scan(body, ...)``.
 
     ``kinds`` (a NumPy array, one entry a layer, no parameter and no
     gradient) makes it ``body(h, block, kind)``: layers of one shape and
     unequal kind (a window here, none there) in one stack, the body
     choosing by ``lax.cond`` on its layer's entry.
+
+    One trace of ``body`` stands for every layer of the stack: it is traced
+    inside ``telemetry/traced.py:stands_for(layers)``, on every path, so
+    what a kernel counts of its calls counts once a layer.
 
     Under a :class:`BlockGradSink` that holds accumulators for ``blocks``
     (``parallel/sync_free.py:build_ga_step`` with several micro batches) the
@@ -107,9 +120,9 @@ def scan_blocks(body, x, blocks, kinds=None):
     are, and the recomputation takes them back: the forward kernel (and the
     choice) runs once a layer and micro batch, not twice, for the kept
     arrays' bytes held from a micro batch's forward to its backward
-    (the gauges ``attn_kept_calls`` / ``attn_kept_bytes``; the walk also
-    counts the calls a layer's expert part makes of its row-copy kernel,
-    ``moe_rows_sum_calls``). The same values:
+    (the gauges ``attn_kept_calls`` / ``attn_kept_bytes``, summed over the
+    walks of one loss; the walk also counts the calls a layer's expert part
+    makes of its row-copy kernel, ``moe_rows_sum_calls``). The same values:
     they are the arrays the second run would make. A body wrapped in
     :func:`rematerialised_whole` keeps nothing. The plain scan (no sink: one
     micro batch, or a body that closes over a traced value) is left as it
@@ -118,20 +131,29 @@ def scan_blocks(body, x, blocks, kinds=None):
     arrays from outside, only names on what the kernel's own VJP saves and
     a policy over them, and a body that declines would have to reach into
     that rule, which JAX traces after the body has returned."""
-    if kinds is not None:
-        # The entry rides beside the block; every path below sees a body of
-        # (h, (block, kind)) whose parameters are still ``blocks``' leaves.
-        plain, kinds = body, np.asarray(kinds)
-        body = lambda h, layer: plain(h, *layer)          # noqa: E731
-    sink = _SINK.get()
     leaves = jax.tree_util.tree_leaves(blocks)
+    # The entry rides beside the block; every path below sees a body of
+    # (h, block) or (h, (block, kind)) whose parameters are still
+    # ``blocks``' leaves.
+    plain, n = body, leaves[0].shape[0]
+    if kinds is not None:
+        kinds = np.asarray(kinds)
+
+    def body(h, layer):
+        with traced.stands_for(n):
+            return plain(h, layer) if kinds is None else plain(h, *layer)
+
+    if not remat:
+        return jax.lax.scan(body, x,
+                            blocks if kinds is None else (blocks, kinds))
+    sink = _SINK.get()
     keys = () if sink is None else tuple(
         sink.leaves.get(id(a)) for a in leaves)
     if keys and None not in keys and sink.acc is not None:
         sink.walks.append(keys)
         acc = jax.tree_util.tree_unflatten(
             jax.tree_util.tree_structure(blocks), [sink.acc[k] for k in keys])
-        return _walk_accumulating(body, x, blocks, acc, kinds, sink)
+        return _walk_accumulating(body, x, blocks, acc, kinds)
     if keys and None not in keys:
         # Recording. A body that closes over a traced value cannot be
         # differentiated by hand; that walk stays the plain scan.
@@ -143,7 +165,6 @@ def scan_blocks(body, x, blocks, kinds=None):
             aval(x), one if kinds is None else (one, aval(kinds, 1)))
         if not any(isinstance(c, jax.core.Tracer) for c in closed.consts):
             sink.walks.append(keys)
-            n = leaves[0].shape[0]
             return jnp.zeros(h.shape, h.dtype), jax.tree_util.tree_map(
                 lambda y: jnp.zeros((n,) + y.shape, y.dtype), ys)
     return jax.lax.scan(jax.checkpoint(body), x,
@@ -161,7 +182,7 @@ def rematerialised_whole(body):
     return whole
 
 
-def _walk_accumulating(body, x, blocks, acc, kinds, sink):
+def _walk_accumulating(body, x, blocks, acc, kinds):
     def layers_of(blocks):
         return blocks if kinds is None else (blocks, kinds)
 
@@ -180,14 +201,11 @@ def _walk_accumulating(body, x, blocks, acc, kinds, sink):
             return out, (h, y, keep.kept)
 
         out, (inputs, ys, kept) = jax.lax.scan(step, x, layers_of(blocks))
-        sink.rows_sum_calls += inputs.shape[0] * rows_sum_calls[0]
-        metrics().gauge("moe_rows_sum_calls").set(sink.rows_sum_calls)
-        sink.attn_kept[0] += inputs.shape[0] * len(kept)
-        sink.attn_kept[1] += sum(
-            a.nbytes for a in jax.tree_util.tree_leaves(kept))
-        for gauge, value in zip(("attn_kept_calls", "attn_kept_bytes"),
-                                sink.attn_kept):
-            metrics().gauge(gauge).set(value)
+        layers = inputs.shape[0]
+        traced.count("moe_rows_sum_calls", layers * rows_sum_calls[0])
+        traced.count("attn_kept_calls", layers * len(kept))
+        traced.count("attn_kept_bytes", sum(
+            a.nbytes for a in jax.tree_util.tree_leaves(kept)))
         return (out, ys), (inputs, kept, blocks, acc)
 
     def bwd(res, cts):
@@ -221,6 +239,45 @@ def _walk_accumulating(body, x, blocks, acc, kinds, sink):
 
     walk.defvjp(fwd, bwd)
     return walk(x, blocks, acc)
+
+
+# Elements of the widest array a chunk of the sequence may make (a chunk of
+# 2,048 tokens at an MLP 16,384 wide: 64 MiB in bf16).
+_CHUNK_ELEMENTS = 2 ** 25
+
+
+def tokens_a_chunk(B: int, T: int, widest: int) -> int:
+    """The largest divisor of ``T`` whose ``[B, chunk, widest]`` array stays
+    under ``_CHUNK_ELEMENTS`` (``T`` itself where it does, 1 at worst)."""
+    most = max(1, _CHUNK_ELEMENTS // (B * widest))
+    return next(c for c in range(min(T, most), 0, -1) if T % c == 0)
+
+
+def over_sequence(fn, widest: int, *xs):
+    """``fn(start, *chunks)`` over chunks of the sequence (axis 1 of every
+    ``x`` [B, T, ...]; ``start`` the chunk's first position), each chunk
+    rematerialised in the backward pass; the results [B, T, ...] again.
+    ``fn`` returns an array or a tuple of arrays. For a block's token-wise
+    parts (norms, projections, gates, an MLP), so that its working set holds
+    ``[T, hidden]`` arrays and never a ``[T, widest]`` one; gradients of a
+    weight are summed over the chunks in the weight's dtype."""
+    B, T = xs[0].shape[:2]
+    chunk = tokens_a_chunk(B, T, widest)
+    fn = jax.checkpoint(fn)
+    if chunk == T:
+        return fn(jnp.int32(0), *xs)
+    n = T // chunk
+
+    def cut(x):
+        return jnp.moveaxis(x.reshape(B, n, chunk, *x.shape[2:]), 1, 0)
+
+    def joined(y):
+        return jnp.moveaxis(y, 0, 1).reshape(B, T, *y.shape[3:])
+
+    out = jax.lax.map(lambda args: fn(*args),
+                      (jnp.arange(n, dtype=jnp.int32) * chunk,
+                       *(cut(x) for x in xs)))
+    return jax.tree_util.tree_map(joined, out)
 
 
 def rms_norm(x, g, eps: float = 1e-5):
@@ -427,8 +484,7 @@ def cross_entropy(x, head, targets, chunk: int = 0):
     undifferentiated call."""
     B, T, D = x.shape
     n_tokens = B * T
-    fused_chunks = metrics().gauge("ce_fused_chunks")
-    fused_chunks.set(0)
+    traced.note("ce_fused_chunks", 0)
     if chunk <= 0:
         logits = (x @ head.T).astype(jnp.float32)
         logz = jax.nn.logsumexp(logits, axis=-1)
@@ -470,7 +526,7 @@ def cross_entropy(x, head, targets, chunk: int = 0):
         return total / n_tokens
 
     def fwd(xf, head, tf, valid):
-        fused_chunks.set(n_chunks)
+        traced.note("ce_fused_chunks", n_chunks)
         # The dtype autodiff hands the logits' cotangent back in.
         d_dtype = jnp.result_type(xf.dtype, head.dtype)
 

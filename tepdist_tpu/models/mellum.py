@@ -40,19 +40,25 @@ the body chooses by ``lax.cond`` on its layer's kind
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
+from tepdist_tpu.models import decoder
+from tepdist_tpu.models.decoder import (
+    fake_batch,  # noqa: F401 (the model's, as every decoder's)
+    held_weights,
+    layer_dicts,
+    stack_layers,
+    walk_layers,
+)
 from tepdist_tpu.models.layers import (
     RopeTable,
     cross_entropy,
     gqa_heads,
-    held_routing_stats,
     rms_norm,
-    scan_blocks,
     yarn_table,
 )
 from tepdist_tpu.ops.grouped_matmul import routed_experts
@@ -158,21 +164,13 @@ def init_params(cfg: MellumConfig, key, std: float = 0.02) -> Dict[str, Any]:
 
 def stacked_init_params(cfg: MellumConfig, key, std: float = 0.02):
     """``init_params`` with the layers stacked: ``blocks`` [L, ...]."""
-    params = init_params(cfg, key, std)
-    out = {k: params[k] for k in _OUTSIDE_BLOCKS}
-    out["blocks"] = {
-        k: jnp.stack([params[f"l{i}"][k]
-                      for i in range(cfg.num_hidden_layers)])
-        for k in params["l0"]}
-    return out
+    return stack_layers(init_params(cfg, key, std), _stacks(cfg),
+                        _OUTSIDE_BLOCKS)
 
 
-def _layers(params, cfg: MellumConfig):
-    """Every layer's own dict, whichever the layout."""
-    if "l0" in params:
-        return [params[f"l{i}"] for i in range(cfg.num_hidden_layers)]
-    return [jax.tree_util.tree_map(lambda a, i=i: a[i], params["blocks"])
-            for i in range(cfg.num_hidden_layers)]
+def _stacks(cfg: MellumConfig):
+    """One stack, every layer: (name, first layer, layers)."""
+    return (("blocks", 0, cfg.num_hidden_layers),)
 
 
 def attention(blk, a, cfg: MellumConfig, window):
@@ -196,19 +194,14 @@ def router(blk, h, cfg: MellumConfig):
     return chosen / chosen.sum(-1, keepdims=True), experts
 
 
-def held_mask(experts, cfg: MellumConfig):
-    first, count = cfg.experts_held
-    return (experts >= first) & (experts < first + count)
-
-
 def moe(blk, x, cfg: MellumConfig):
     """x [B, T, d] -> the held routed experts' part of the layer's output."""
     B, T, d = x.shape
     h = x.reshape(B * T, d)
     with jax.named_scope("moe_router"):
         weights, experts = router(blk, h, cfg)
-        if cfg.experts_held[1] < cfg.num_experts:
-            weights = jnp.where(held_mask(experts, cfg), weights, 0.0)
+        weights = held_weights(weights, experts, cfg.experts_held,
+                               cfg.num_experts)
     y = routed_experts(h, weights, experts, blk["w_gate"], blk["w_up"],
                        blk["w_down"], cfg.num_experts, cfg.moe_tile_m,
                        held=cfg.experts_held)
@@ -224,23 +217,9 @@ def block(blk, x, cfg: MellumConfig, window):
 def hidden_states(params, tokens, cfg: MellumConfig):
     """tokens int32 [B, T] -> final normalised hidden [B, T, d]."""
     x = params["tok_emb"][tokens].astype(cfg.dtype)
-    windowed = [t == WINDOW for t in cfg.layer_types]
-    if "l0" in params:
-        step = jax.checkpoint(block, static_argnums=(2, 3)) if cfg.remat \
-            else block
-        for i in range(cfg.num_hidden_layers):
-            x = step(params[f"l{i}"], x, cfg, windowed[i])
-    else:
-        kinds = np.asarray(windowed, np.int32)
-
-        def body(h, blk, w):
-            return block(blk, h, cfg, w), None
-
-        if cfg.remat:
-            x = scan_blocks(body, x, params["blocks"], kinds)[0]
-        else:
-            x = jax.lax.scan(lambda h, layer: body(h, *layer), x,
-                             (params["blocks"], kinds))[0]
+    x = walk_layers(lambda blk, h, window: block(blk, h, cfg, window), x,
+                    params, _stacks(cfg),
+                    [t == WINDOW for t in cfg.layer_types], cfg.remat)
     return rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
 
 
@@ -264,7 +243,8 @@ def expert_choices(params, tokens, cfg: MellumConfig):
     x = params["tok_emb"][tokens].astype(cfg.dtype)
     S = x.shape[0] * x.shape[1]
     ids = []
-    for blk, kind in zip(_layers(params, cfg), cfg.layer_types):
+    for blk, kind in zip(layer_dicts(params, _stacks(cfg)),
+                         cfg.layer_types):
         mid = x + attention(blk, rms_norm(x, blk["input_ln"], eps), cfg,
                             kind == WINDOW)
         h = rms_norm(mid, blk["post_attn_ln"], eps)
@@ -273,18 +253,6 @@ def expert_choices(params, tokens, cfg: MellumConfig):
     return jnp.stack(ids)
 
 
-def routing_stats(params, tokens, cfg: MellumConfig) -> dict:
-    """What the routers did with ``tokens`` [B, T+1], outside any step: the
-    expert ids of every layer (``experts`` [L, S, k]), the rows each held
-    expert got (``held_rows`` [L, count]) and the counters and gauges of
-    ``models/layers.py:held_routing_stats``."""
-    return held_routing_stats(
-        expert_choices(params, tokens[:, :-1], cfg), cfg.num_experts,
-        cfg.moe_tile_m, cfg.experts_held)
-
-
-def fake_batch(cfg: MellumConfig, batch_size: int, seq_len: int,
-               seed: int = 0):
-    return jax.random.randint(jax.random.PRNGKey(seed),
-                              (batch_size, seq_len + 1), 0, cfg.vocab_size,
-                              dtype=jnp.int32)
+# What the routers did with ``tokens`` [B, T+1], outside any step
+# (``models/decoder.py:routing_stats`` over this model's choices).
+routing_stats = functools.partial(decoder.routing_stats, expert_choices)

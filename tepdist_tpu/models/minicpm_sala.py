@@ -30,11 +30,11 @@ for its key/value group (``ops/pallas/block_topk_attention.py``: the choice
 and the kernels); then ``out = (sigmoid(a Wg) * o) Wo``.
 
 **A block's token-wise parts run in chunks of the sequence** (norms,
-projections, rotary, gates, the MLP: :func:`over_sequence`), each chunk
-rematerialised in the block's own backward, so a block's working set holds
-``[T, hidden]`` arrays and never a ``[T, intermediate]`` one; only the two
-mixing kernels see the whole sequence. The chunk is chosen from the shapes
-(:func:`tokens_a_chunk`). Gradients of a weight are summed over the chunks
+projections, rotary, gates, the MLP: ``models/layers.py:over_sequence``),
+each chunk rematerialised in the block's own backward, so a block's working
+set holds ``[T, hidden]`` arrays and never a ``[T, intermediate]`` one; only
+the two mixing kernels see the whole sequence. The chunk is chosen from the
+shapes (``tokens_a_chunk``). Gradients of a weight are summed over the chunks
 in the weight's dtype, as a gradient-accumulation step sums micro batches.
 
 bf16 weights and activations; norms, rotary, gates' sigmoid, softmax
@@ -51,25 +51,32 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tepdist_tpu.models.layers import cross_entropy, rms_norm, scan_blocks
+from tepdist_tpu.models.decoder import (
+    fake_batch,  # noqa: F401 (the model's, as every decoder's)
+    run_stacks,
+    runs,
+    stack_layers,
+    walk_layers,
+)
+from tepdist_tpu.models.layers import cross_entropy, over_sequence, rms_norm
 from tepdist_tpu.ops.pallas.block_topk_attention import (
     BlockGeometry,
     kept_choice,
     topk_attention,
 )
 from tepdist_tpu.ops.pallas.flash_attention import flash_attention
-from tepdist_tpu.ops.pallas.lightning_attention import (
-    layers_stood_for,
-    lightning_attention,
-    stands_for,
-)
-from tepdist_tpu.telemetry import metrics
+from tepdist_tpu.ops.pallas.lightning_attention import lightning_attention
+from tepdist_tpu.telemetry import traced
+
+traced.declare(
+    "topk_attn_dense_calls", "sparse layers a micro batch run as plain "
+    "causal attention (a sequence at or under dense_len)")
 
 SPARSE, LIGHTNING = "minicpm4", "lightning-attn"
 
@@ -120,13 +127,7 @@ class MiniCPMSALAConfig:
     @property
     def runs(self) -> Tuple[Tuple[str, int, int], ...]:
         """(kind, first layer held, layers) of each run of one kind."""
-        out: List[Tuple[str, int, int]] = []
-        for i, kind in enumerate(self.mixer_types):
-            if out and out[-1][0] == kind:
-                out[-1] = (kind, out[-1][1], out[-1][2] + 1)
-            else:
-                out.append((kind, i, 1))
-        return tuple(out)
+        return runs(self.mixer_types)
 
 
 CONFIGS: Dict[str, MiniCPMSALAConfig] = {
@@ -147,14 +148,11 @@ CONFIGS: Dict[str, MiniCPMSALAConfig] = {
 }
 
 _OUTSIDE_BLOCKS = ("tok_emb", "lm_head", "norm_f")
+# The stacked layout's groups of a run's leaves (``models/decoder.py``), and
+# the leaves that are not in the first.
 GROUPS = ("run", "vec")
-_VEC = ("input_ln", "ff_ln", "q_norm", "k_norm", "o_norm")
-
-
-def run_blocks(params, r: int) -> Dict[str, Any]:
-    """The stacked leaves of run ``r``, its groups side by side: the tree's
-    own leaves, so a gradient-accumulation step finds the walk over them."""
-    return {k: v for g in GROUPS for k, v in params.get(f"{g}{r}", {}).items()}
+_GROUP_OF = dict.fromkeys(
+    ("input_ln", "ff_ln", "q_norm", "k_norm", "o_norm"), "vec")
 
 
 def log_decays(cfg: MiniCPMSALAConfig, layer: int) -> np.ndarray:
@@ -216,53 +214,9 @@ def init_params(cfg: MiniCPMSALAConfig, key, std: float = 0.02):
 def stacked_init_params(cfg: MiniCPMSALAConfig, key, std: float = 0.02):
     """``init_params`` with each run of one kind stacked, [layers of the
     run, ...] a leaf, in the run's groups (``run{r}``, ``vec{r}``)."""
-    params = init_params(cfg, key, std)
-    out = {k: params[k] for k in _OUTSIDE_BLOCKS}
-    for r, (_, first, count) in enumerate(cfg.runs):
-        layers = [params[f"l{i}"] for i in range(first, first + count)]
-        for k in layers[0]:
-            group = "vec" if k in _VEC else "run"
-            out.setdefault(f"{group}{r}", {})[k] = jnp.stack(
-                [blk[k] for blk in layers])
-    return out
-
-
-# -- a block's token-wise parts, in chunks of the sequence -------------------
-
-# Elements of the widest array a chunk may make (a chunk of 2,048 tokens at
-# an MLP 16,384 wide: 64 MiB in bf16).
-_CHUNK_ELEMENTS = 2 ** 25
-
-
-def tokens_a_chunk(B: int, T: int, widest: int) -> int:
-    """The largest divisor of ``T`` whose ``[B, chunk, widest]`` array stays
-    under ``_CHUNK_ELEMENTS`` (``T`` itself where it does, 1 at worst)."""
-    most = max(1, _CHUNK_ELEMENTS // (B * widest))
-    return next(c for c in range(min(T, most), 0, -1) if T % c == 0)
-
-
-def over_sequence(fn, widest: int, *xs):
-    """``fn(start, *chunks)`` over chunks of the sequence (axis 1 of every
-    ``x`` [B, T, ...]; ``start`` the chunk's first position), each chunk
-    rematerialised in the backward pass; the results [B, T, ...] again.
-    ``fn`` returns an array or a tuple of arrays."""
-    B, T = xs[0].shape[:2]
-    chunk = tokens_a_chunk(B, T, widest)
-    fn = jax.checkpoint(fn)
-    if chunk == T:
-        return fn(jnp.int32(0), *xs)
-    n = T // chunk
-
-    def cut(x):
-        return jnp.moveaxis(x.reshape(B, n, chunk, *x.shape[2:]), 1, 0)
-
-    def joined(y):
-        return jnp.moveaxis(y, 0, 1).reshape(B, T, *y.shape[3:])
-
-    out = jax.lax.map(lambda args: fn(*args),
-                      (jnp.arange(n, dtype=jnp.int32) * chunk,
-                       *(cut(x) for x in xs)))
-    return jax.tree_util.tree_map(joined, out)
+    return stack_layers(init_params(cfg, key, std),
+                        run_stacks(cfg.mixer_types), _OUTSIDE_BLOCKS, GROUPS,
+                        _GROUP_OF)
 
 
 def rope(x, start, theta: float):
@@ -300,8 +254,7 @@ def sparse_attention(q, k, v, cfg: MiniCPMSALAConfig):
     attention up to ``dense_len`` positions, the chosen blocks past it."""
     B, T, H, D = q.shape
     if T <= cfg.sparse.dense_len:
-        dense = metrics().gauge("topk_attn_dense_calls")
-        dense.set((dense.value or 0) + layers_stood_for())
+        traced.count("topk_attn_dense_calls")
         o = flash_attention(
             *(x.transpose(0, 2, 1, 3) for x in (q, k, v)), causal=True,
             block_q=cfg.flash_block_q or None,
@@ -353,26 +306,13 @@ def hidden_states(params, tokens, cfg: MiniCPMSALAConfig):
     ``hidden_size / dim_model_base`` as the head wants it."""
     x = (params["tok_emb"][tokens].astype(jnp.float32)
          * cfg.scale_emb).astype(cfg.dtype)
-    if "l0" in params:
-        step = jax.checkpoint(block, static_argnums=(2, 3)) if cfg.remat \
-            else block
-        for i, kind in enumerate(cfg.mixer_types):
-            x = step(params[f"l{i}"], x, cfg, kind,
-                     jnp.asarray(log_decays(cfg, i)))
-    else:
-        walk = scan_blocks if cfg.remat else \
-            (lambda body, h, blocks, kinds: jax.lax.scan(
-                lambda c, layer: body(c, *layer), h, (blocks, kinds)))
-        for r, (kind, first, count) in enumerate(cfg.runs):
-            def body(h, blk, log_decay, kind=kind, count=count):
-                # One trace stands for every layer of the run in what the
-                # mixing kernels count of their calls.
-                with stands_for(count):
-                    return block(blk, h, cfg, kind, log_decay), None
-
-            decays = np.stack([log_decays(cfg, first + i)
-                               for i in range(count)])
-            x = walk(body, x, run_blocks(params, r), decays)[0]
+    # A layer's kind by what its block holds (``_layer_params``).
+    x = walk_layers(
+        lambda blk, h, log_decay: block(
+            blk, h, cfg, LIGHTNING if "o_norm" in blk else SPARSE, log_decay),
+        x, params, run_stacks(cfg.mixer_types),
+        [log_decays(cfg, i) for i in range(cfg.num_hidden_layers)],
+        cfg.remat, GROUPS)
     x = rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
     return (x.astype(jnp.float32)
             / (cfg.hidden_size / cfg.dim_model_base)).astype(x.dtype)
@@ -388,10 +328,3 @@ def loss_fn(params, tokens, cfg: MiniCPMSALAConfig):
     """Cross entropy of tokens [B, T+1] through the untied head."""
     x = hidden_states(params, tokens[:, :-1], cfg)
     return cross_entropy(x, params["lm_head"], tokens[:, 1:], cfg.loss_chunk)
-
-
-def fake_batch(cfg: MiniCPMSALAConfig, batch_size: int, seq_len: int,
-               seed: int = 0):
-    return jax.random.randint(jax.random.PRNGKey(seed),
-                              (batch_size, seq_len + 1), 0, cfg.vocab_size,
-                              dtype=jnp.int32)

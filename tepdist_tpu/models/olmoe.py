@@ -35,11 +35,16 @@ float32. Parameters: ``l{i}`` per-layer dicts (``init_params``) or one
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 
+from tepdist_tpu.models.decoder import (
+    fake_batch,  # noqa: F401 (the model's, as every decoder's)
+    layer_dicts,
+    stack_layers,
+)
 from tepdist_tpu.models.layers import (
     cross_entropy,
     rms_norm,
@@ -130,13 +135,13 @@ def init_params(cfg: OlmoeConfig, key, std: float = 0.02) -> Dict[str, Any]:
 
 def stacked_init_params(cfg: OlmoeConfig, key, std: float = 0.02):
     """``init_params`` with the layers stacked: ``blocks`` [L, ...]."""
-    params = init_params(cfg, key, std)
-    out = {k: params[k] for k in _OUTSIDE_BLOCKS}
-    out["blocks"] = {
-        k: jnp.stack([params[f"l{i}"][k]
-                      for i in range(cfg.num_hidden_layers)])
-        for k in params["l0"]}
-    return out
+    return stack_layers(init_params(cfg, key, std), _stacks(cfg),
+                        _OUTSIDE_BLOCKS)
+
+
+def _stacks(cfg: OlmoeConfig):
+    """One stack, every layer: (name, first layer, layers)."""
+    return (("blocks", 0, cfg.num_hidden_layers),)
 
 
 def attention(blk, x, cfg: OlmoeConfig):
@@ -204,8 +209,7 @@ def hidden_states(params, tokens, cfg: OlmoeConfig):
         return h, (lb, zl)
 
     if "blocks" in params:
-        walk = scan_blocks if cfg.remat else jax.lax.scan
-        x, aux = walk(body, x, params["blocks"])
+        x, aux = scan_blocks(body, x, params["blocks"], remat=cfg.remat)
         lb, zl = (a.mean() for a in aux)
     else:
         if cfg.remat:
@@ -250,10 +254,7 @@ def routing_stats(params, tokens, cfg: OlmoeConfig) -> dict:
     x = params["tok_emb"][tokens[:, :-1]].astype(cfg.dtype)
     S = x.shape[0] * x.shape[1]
     ids, sizes, placed = [], [], 0
-    layers = ([jax.tree_util.tree_map(lambda a, i=i: a[i], params["blocks"])
-               for i in range(cfg.num_hidden_layers)] if "blocks" in params
-              else [params[f"l{i}"] for i in range(cfg.num_hidden_layers)])
-    for blk in layers:
+    for blk in layer_dicts(params, _stacks(cfg)):
         x = x + attention(
             blk, rms_norm(x, blk["attn_norm"], cfg.rms_norm_eps), cfg)
         h = rms_norm(x, blk["ffn_norm"], cfg.rms_norm_eps)
@@ -274,11 +275,3 @@ def routing_stats(params, tokens, cfg: OlmoeConfig) -> dict:
     metrics().gauge("moe_expert_rows_max").set(out["moe_expert_rows_max"])
     metrics().gauge("moe_expert_rows_mean").set(out["moe_expert_rows_mean"])
     return {**out, "experts": jnp.stack(ids)}
-
-
-def fake_batch(cfg: OlmoeConfig, batch_size: int,
-               seq_len: Optional[int] = None, seed: int = 0):
-    T = seq_len or cfg.max_position_embeddings
-    return jax.random.randint(jax.random.PRNGKey(seed),
-                              (batch_size, T + 1), 0, cfg.vocab_size,
-                              dtype=jnp.int32)

@@ -96,6 +96,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from tepdist_tpu.ops.pallas import _interpret
 from tepdist_tpu.ops.pallas.grouped_matmul import grouped_matmul
 from tepdist_tpu.ops.pallas.rows_sum import rows_sum
 
@@ -520,7 +521,7 @@ def _unwritten(aval):
         lambda out: None, name="tepdist_unwritten",
         out_shape=jax.ShapeDtypeStruct(aval.shape, aval.dtype),
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        interpret=jax.default_backend() == "cpu")()
+        interpret=_interpret(None))()
 
 
 # A branch is traced once a shape and a process, however many passes (the

@@ -49,14 +49,8 @@ gradients of one group must fit (``T * D * 12`` bytes under about 100 MiB:
 sequences are refused, not run some other way.
 
 Kernel names ``tepdist_topk_attn_fwd`` / ``tepdist_topk_attn_bwd``. Gauges,
-set while a step is traced (``parallel/sync_free.py:build_ga_step`` zeroes
-them): ``topk_attn_calls`` forward kernel calls a micro batch (a call
-inside ``lightning_attention.stands_for`` as many as the layers it stands
-for; a block rematerialised under ``jax.checkpoint`` runs the kernel again
-and counts twice, a block that ``models/layers.py:scan_blocks`` walks hands
-its forward pass to the walk and counts once: below),
-``topk_attn_keys_per_query`` the mean keys a query visits (a function of
-``T`` and the geometry).
+set while a step is traced (``telemetry/traced.py``): ``topk_attn_calls``
+and ``topk_attn_keys_per_query``, declared below.
 
 **Inside a walked block** (``ops/pallas/flash_attention.py:KeptForward``)
 the walk keeps what the layer's forward pass made and the backward pass's
@@ -79,9 +73,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tepdist_tpu.ops.pallas import _interpret
 from tepdist_tpu.ops.pallas.flash_attention import hand_over
-from tepdist_tpu.ops.pallas.lightning_attention import layers_stood_for
-from tepdist_tpu.telemetry import metrics
+from tepdist_tpu.telemetry import traced
 
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -131,8 +125,14 @@ def mean_keys_per_query(T: int, geo: BlockGeometry) -> float:
                                   np)))
 
 
-def _interpret(flag):
-    return jax.default_backend() == "cpu" if flag is None else flag
+traced.declare(
+    "topk_attn_calls", "forward block top-k attention kernel calls a micro "
+    "batch (a block rematerialised under jax.checkpoint runs the kernel "
+    "again and counts twice; a walked block hands its forward to the walk "
+    "and counts once)")
+traced.declare(
+    "topk_attn_keys_per_query", "mean keys a query of the block top-k "
+    "attention visits (a function of T and the geometry)")
 
 
 # -- the choice -------------------------------------------------------------
@@ -547,8 +547,8 @@ def topk_attention(q, k, v, idx, geo: BlockGeometry, *,
             or T % geo.block_size or idx.shape[3] > geo.topk:
         raise ValueError(f"topk_attention: q {q.shape}, k {k.shape}, "
                          f"v {v.shape}, idx {idx.shape}, {geo}")
-    metrics().gauge("topk_attn_keys_per_query").set(
-        mean_keys_per_query(T, geo._replace(topk=idx.shape[3])))
+    traced.note("topk_attn_keys_per_query",
+                mean_keys_per_query(T, geo._replace(topk=idx.shape[3])))
     bs, interpret = geo.block_size, _interpret(interpret)
 
     def attend(saved):
@@ -558,8 +558,7 @@ def topk_attention(q, k, v, idx, geo: BlockGeometry, *,
             # as large again) is made anew here and not held all the while.
             o, lse = jax.lax.optimization_barrier(saved)
             return _attend_from(q, k, v, idx, o, lse, bs, interpret)
-        calls = metrics().gauge("topk_attn_calls")
-        calls.set((calls.value or 0) + layers_stood_for())
+        traced.count("topk_attn_calls")
         if saved is None:
             return _attend(q, k, v, idx, bs, interpret)
         return forward(q, k, v, idx, block_size=bs, interpret=interpret)

@@ -67,15 +67,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tepdist_tpu.ops.pallas import _interpret
 from tepdist_tpu.ops.pallas.selective_scan import (
     LANES,
     _block_d,
-    _interpret,
     _pad,
     _padded,
-    layers_stood_for,
 )
-from tepdist_tpu.telemetry import metrics
+from tepdist_tpu.telemetry import traced
 
 TILE = 8                    # rows of a float32 tile
 STRIP = 64                  # rows a loop trip; whole packed 16-bit tiles
@@ -91,9 +90,9 @@ _F32 = jnp.float32
 FWD_FLOPS, BWD_FLOPS = 10, 25
 
 
-def _count_forward(times: int) -> None:
-    calls = metrics().gauge("ssm_conv_calls")
-    calls.set((calls.value or 0) + times)
+traced.declare(
+    "ssm_conv_calls", "forward calls a micro batch of the conv before the "
+    "selective scan (a rematerialised layer's second run counted)")
 
 
 def _rolls(tile, shifts):
@@ -283,20 +282,20 @@ def _bwd_call(u, w, b, dc, *, block_t, block_d, interpret):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _conv(u, w, b, block_t, block_d, interpret, times):
-    _count_forward(times)
+def _conv(u, w, b, block_t, block_d, interpret, layers):
+    traced.count("ssm_conv_calls", layers=layers)
     return _fwd_call(u, w, b, block_t=block_t, block_d=block_d,
                      interpret=interpret)
 
 
-def _conv_fwd(u, w, b, block_t, block_d, interpret, times):
-    _count_forward(times)
+def _conv_fwd(u, w, b, block_t, block_d, interpret, layers):
+    traced.count("ssm_conv_calls", layers=layers)
     c = _fwd_call(u, w, b, block_t=block_t, block_d=block_d,
                   interpret=interpret)
     return c, (u, w, b)
 
 
-def _conv_bwd(block_t, block_d, interpret, times, res, dc):
+def _conv_bwd(block_t, block_d, interpret, layers, res, dc):
     u, w, b = res
     du, dw, db = _bwd_call(u, w, b, dc, block_t=block_t, block_d=block_d,
                            interpret=interpret)
@@ -314,18 +313,15 @@ def causal_conv(u, w, b, *, block_t: int = BLOCK_T, block_d: int = BLOCK_D,
     Differentiable in all three. Any ``T``: the last block is padded with
     zero rows. ``block_t`` rows and ``block_d`` channels a grid step.
 
-    Adds, while it is traced, to the gauge ``ssm_conv_calls`` each forward
-    kernel call (a rematerialised block's second run too; a call inside
-    ``selective_scan.stands_for`` as many as the layers it stands for); who
-    reports it zeroes it before it traces its step
-    (``parallel/sync_free.py:build_ga_step``)."""
+    Counts, while it is traced, each forward kernel call in
+    ``ssm_conv_calls`` (``telemetry/traced.py``)."""
     if u.ndim != 3 or w.ndim != 2 or w.shape[1] != u.shape[2] \
             or b.shape != u.shape[2:] or u.shape[2] % LANES \
             or not 2 <= w.shape[0] <= TILE:
         raise ValueError(
             f"causal_conv: u {u.shape}, w {w.shape}, b {b.shape}")
     return _conv(u, w, b, block_t, block_d, _interpret(interpret),
-                 layers_stood_for())
+                 traced.stood_for())
 
 
 def reference(u, w, b):

@@ -59,6 +59,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tepdist_tpu.ops.pallas import _interpret
+
 log = logging.getLogger(__name__)
 
 _NEG_INF = -1e30
@@ -573,9 +575,8 @@ def flash_attention_with_lse(q, k, v, causal: bool = True,
                        v.astype(jnp.float32))
         return o.astype(q.dtype), (m + jnp.log(jnp.maximum(l, 1e-30)))[..., 0]
     block_q, block_k = blocks
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    return _flash_o_lse(q, k, v, causal, scale, block_q, block_k, interpret)
+    return _flash_o_lse(q, k, v, causal, scale, block_q, block_k,
+                        _interpret(interpret))
 
 
 def _default_block(T: int) -> Optional[int]:
@@ -789,9 +790,7 @@ def flash_attention_kept(q, k, v, forward, causal: bool = True,
         return _dense_attention(q, jnp.repeat(k, group, axis=1),
                                 jnp.repeat(v, group, axis=1), causal, scale)
     block_q, block_k = blocks
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    static = (causal, scale, block_q, block_k, interpret, window)
+    static = (causal, scale, block_q, block_k, _interpret(interpret), window)
     if forward is None:
         return _flash(q, k, v, *static)
     if not forward:

@@ -37,6 +37,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tepdist_tpu.ops.pallas import _interpret
+
 _NN = (((1,), (0,)), ((), ()))
 _NT = (((1,), (1,)), ((), ()))
 _TN = (((0,), (0,)), ((), ()))
@@ -70,10 +72,6 @@ def _live(i, n_tiles):
     """Row tile ``i``, or the last live one past it (nothing new is fetched
     for a skipped tile)."""
     return jnp.minimum(i, n_tiles[0] - 1)
-
-
-def _interpret(flag):
-    return jax.default_backend() == "cpu" if flag is None else flag
 
 
 def _gmm_kernel(tile_group, n_tiles, x_ref, w_ref, o_ref, *, dims):

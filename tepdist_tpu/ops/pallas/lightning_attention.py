@@ -50,8 +50,6 @@ them alone.
 
 from __future__ import annotations
 
-import contextlib
-import contextvars
 import functools
 from typing import Optional
 
@@ -60,7 +58,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tepdist_tpu.telemetry import metrics
+from tepdist_tpu.ops.pallas import _interpret
+from tepdist_tpu.telemetry import traced
 
 CHUNK = 256                 # tokens a grid step
 _F32 = jnp.float32
@@ -70,32 +69,9 @@ _NN = (((1,), (0,)), ((), ()))      # a @ b
 _NT = (((1,), (1,)), ((), ()))      # a @ b^T
 _TN = (((0,), (0,)), ((), ()))      # a^T @ b
 
-# How many layers one trace of the caller stands for: a walk over stacked
-# blocks traces its body once for all of them.
-_LAYERS = contextvars.ContextVar("tepdist_lightning_layers", default=1)
-
-
-@contextlib.contextmanager
-def stands_for(layers: int):
-    """Calls traced inside count ``layers`` times in ``lin_attn_calls``."""
-    token = _LAYERS.set(layers)
-    try:
-        yield
-    finally:
-        _LAYERS.reset(token)
-
-
-def layers_stood_for() -> int:
-    return _LAYERS.get()
-
-
-def _count_forward(times: int) -> None:
-    calls = metrics().gauge("lin_attn_calls")
-    calls.set((calls.value or 0) + times)
-
-
-def _interpret(flag):
-    return jax.default_backend() == "cpu" if flag is None else flag
+traced.declare(
+    "lin_attn_calls", "forward linear-attention kernel calls a micro batch "
+    "(a rematerialised layer's second run counted)")
 
 
 def _parts(x, narrow: bool):
@@ -263,17 +239,17 @@ def backward(q, k, v, log_decay, do, *, chunk: int = CHUNK, interpret=None,
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _attend(q, k, v, log_decay, chunk, interpret, times):
-    _count_forward(times)
+def _attend(q, k, v, log_decay, chunk, interpret, layers):
+    traced.count("lin_attn_calls", layers=layers)
     return forward(q, k, v, log_decay, chunk=chunk, interpret=interpret)
 
 
-def _attend_fwd(q, k, v, log_decay, chunk, interpret, times):
-    return _attend(q, k, v, log_decay, chunk, interpret, times), \
+def _attend_fwd(q, k, v, log_decay, chunk, interpret, layers):
+    return _attend(q, k, v, log_decay, chunk, interpret, layers), \
         (q, k, v, log_decay)
 
 
-def _attend_bwd(chunk, interpret, times, res, do):
+def _attend_bwd(chunk, interpret, layers, res, do):
     q, k, v, log_decay = res
     # The decay is a convention of the model and no parameter: no gradient.
     return backward(q, k, v, log_decay, do, chunk=chunk,
@@ -291,11 +267,8 @@ def lightning_attention(q, k, v, log_decay, *, chunk: int = CHUNK,
     ``q, k, v``; ``log_decay`` gets a zero gradient. No scale is applied:
     the caller's ``q`` carries it.
 
-    Adds, while it is traced, to the gauge ``lin_attn_calls`` each forward
-    kernel call (a rematerialised block's second run too; a call inside
-    :func:`stands_for` as many as the layers it stands for); who reports it
-    zeroes it before it traces its step
-    (``parallel/sync_free.py:build_ga_step``)."""
+    Counts, while it is traced, each forward kernel call in
+    ``lin_attn_calls`` (``telemetry/traced.py``)."""
     if q.shape != k.shape or q.shape != v.shape or q.ndim != 3 \
             or log_decay.ndim != 1 or q.shape[2] % log_decay.shape[0] \
             or chunk % 8:
@@ -304,4 +277,4 @@ def lightning_attention(q, k, v, log_decay, *, chunk: int = CHUNK,
             f"log_decay {log_decay.shape}, chunk {chunk}")
     chunk = min(chunk, -(-q.shape[1] // 8) * 8)
     return _attend(q, k, v, log_decay, chunk, _interpret(interpret),
-                   _LAYERS.get())
+                   traced.stood_for())

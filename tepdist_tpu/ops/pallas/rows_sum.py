@@ -58,16 +58,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tepdist_tpu.ops.pallas import _interpret
+
 LANES = 128
 BLOCK = 128                        # tokens a grid step of the sum
 TILE_ROWS = 256                    # rows a grid step of the relayout
 _CHUNK = 8                         # tokens summed at a time (in registers)
 _UNROLL = 8                        # copies started, or awaited, a loop trip
 _VMEM_LIMIT = 64 * 1024 * 1024     # of 128 MiB; the default scope is 16 MiB
-
-
-def _interpret(flag):
-    return jax.default_backend() == "cpu" if flag is None else flag
 
 
 def _traced_once(fn):

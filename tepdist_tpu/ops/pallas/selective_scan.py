@@ -44,8 +44,6 @@ and chunk.
 
 from __future__ import annotations
 
-import contextlib
-import contextvars
 import functools
 from typing import Optional
 
@@ -54,7 +52,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tepdist_tpu.telemetry import metrics
+from tepdist_tpu.ops.pallas import _interpret
+from tepdist_tpu.telemetry import traced
 
 LANES = 128
 CHUNK = 64                  # time steps a grid step; a state is saved a chunk
@@ -70,33 +69,12 @@ _F32 = jnp.float32
 FWD_FLOPS, BWD_FLOPS = 6, 22
 
 
-# How many layers one trace of the caller stands for: a walk over stacked
-# blocks traces its body once for all of them (models/jamba.py says so).
-_LAYERS = contextvars.ContextVar("tepdist_ssm_layers", default=1)
-
-
-@contextlib.contextmanager
-def stands_for(layers: int):
-    """Calls traced inside count ``layers`` times in ``ssm_scan_calls``."""
-    token = _LAYERS.set(layers)
-    try:
-        yield
-    finally:
-        _LAYERS.reset(token)
-
-
-def layers_stood_for() -> int:
-    """What ``stands_for`` is set to where this is called (1 outside it)."""
-    return _LAYERS.get()
-
-
-def _count_forward(times: int) -> None:
-    calls = metrics().gauge("ssm_scan_calls")
-    calls.set((calls.value or 0) + times)
-
-
-def _interpret(flag):
-    return jax.default_backend() == "cpu" if flag is None else flag
+traced.declare(
+    "ssm_scan_calls", "forward selective-scan kernel calls a micro batch (a "
+    "rematerialised layer's second run counted)")
+traced.declare(
+    "ssm_boundary_bytes", "bytes of chunk-boundary states one differentiated "
+    "selective-scan call holds from its forward to its backward")
 
 
 def _block_d(Di: int, want: int) -> int:
@@ -414,25 +392,25 @@ def _operands(c, delta, A, B, C, D, z, chunk):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
-def _scan(c, delta, A, B, C, D, z, chunk, block_d, interpret, times):
-    _count_forward(times)
+def _scan(c, delta, A, B, C, D, z, chunk, block_d, interpret, layers):
+    traced.count("ssm_scan_calls", layers=layers)
     out, = _fwd_call(*_operands(c, delta, A, B, C, D, z, chunk), chunk=chunk,
                      block_d=block_d, save=False, interpret=interpret)
     return out[:, :c.shape[1]]
 
 
-def _scan_fwd(c, delta, A, B, C, D, z, chunk, block_d, interpret, times):
-    _count_forward(times)
-    held = metrics().gauge("ssm_boundary_bytes")
-    held.set(max(held.value or 0, boundary_bytes(
-        c.shape[0], c.shape[1], c.shape[2], A.shape[1], chunk)))
+def _scan_fwd(c, delta, A, B, C, D, z, chunk, block_d, interpret, layers):
+    traced.count("ssm_scan_calls", layers=layers)
+    traced.note("ssm_boundary_bytes", max(
+        traced.values()["ssm_boundary_bytes"], boundary_bytes(
+            c.shape[0], c.shape[1], c.shape[2], A.shape[1], chunk)))
     ops = _operands(c, delta, A, B, C, D, z, chunk)
     out, y, starts = _fwd_call(*ops, chunk=chunk, block_d=block_d, save=True,
                                interpret=interpret)
     return out[:, :c.shape[1]], (ops, y, starts, A, B, C, D)
 
 
-def _scan_bwd(chunk, block_d, interpret, times, res, do):
+def _scan_bwd(chunk, block_d, interpret, layers, res, do):
     ops, y, starts, A, B, C, D = res
     T, Tp = do.shape[1], y.shape[1]
     dc, ddl, dz, dbb, dcb, da, dd = _bwd_call(
@@ -466,12 +444,9 @@ def selective_scan(c, delta, A, B, C, D, z, *, chunk: int = CHUNK,
     Any ``T``: the last chunk is padded with steps that leave the state as
     it is. ``chunk`` time steps and ``block_d`` channels a grid step.
 
-    Adds, while it is traced, to the gauge ``ssm_scan_calls`` each forward
-    kernel call (a rematerialised block's second run too; a call inside
-    :func:`stands_for` as many as the layers it stands for) and raises
-    ``ssm_boundary_bytes`` to what a differentiated call holds from its
-    forward to its backward; who reports them zeroes them before it traces
-    its step (``parallel/sync_free.py:build_ga_step``)."""
+    Counts, while it is traced, each forward kernel call in
+    ``ssm_scan_calls`` and raises ``ssm_boundary_bytes`` to what a
+    differentiated call holds (``telemetry/traced.py``)."""
     if c.shape != delta.shape or c.shape != z.shape \
             or A.shape != (c.shape[2], B.shape[2]) or B.shape != C.shape \
             or B.shape[:2] != c.shape[:2] or D.shape != c.shape[2:] \
@@ -480,4 +455,4 @@ def selective_scan(c, delta, A, B, C, D, z, *, chunk: int = CHUNK,
             f"selective_scan: c {c.shape}, delta {delta.shape}, z {z.shape},"
             f" A {A.shape}, B {B.shape}, C {C.shape}, D {D.shape}")
     return _scan(c, delta, A, B, C, D, z, chunk, block_d,
-                 _interpret(interpret), _LAYERS.get())
+                 _interpret(interpret), traced.stood_for())

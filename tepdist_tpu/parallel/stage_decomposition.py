@@ -50,8 +50,8 @@ class StageModule:
         return [i for i, src in self.input_def_map.items() if src[0] == "stage"]
 
 
-def _interpret(eqns, invars: Sequence[Var], constmap: Dict[Var, Any],
-               outvars: Sequence[Var]) -> Callable:
+def _run_eqns(eqns, invars: Sequence[Var], constmap: Dict[Var, Any],
+              outvars: Sequence[Var]) -> Callable:
     """Build a callable evaluating an equation slice (jit-friendly)."""
 
     def fn(*args):
@@ -150,7 +150,7 @@ class StageDecomposition:
     # ------------------------------------------------------------------
     def stage_fn(self, s: int) -> Callable:
         m = self.stages[s]
-        return _interpret(m.eqns, m.invars, self._const_env, m.outvars)
+        return _run_eqns(m.eqns, m.invars, self._const_env, m.outvars)
 
     def forward_fns(self) -> List[Callable]:
         return [self.stage_fn(s) for s in range(self.num_stages)]

@@ -41,10 +41,17 @@ from tepdist_tpu.models.layers import BlockGradSink
 from tepdist_tpu.parallel.performance_utils import chip_spec
 from tepdist_tpu.parallel.strategy_utils import StrategyUtil
 from tepdist_tpu.core.dist_spec import DimStrategy
-from tepdist_tpu.telemetry import metrics
+from tepdist_tpu.telemetry import traced
 
 Var = jexcore.Var
 log = logging.getLogger(__name__)
+
+traced.declare(
+    "ga_fused_bytes", "parameter bytes whose gradients a gradient-"
+    "accumulation step adds inside the loss's own layer loop")
+traced.declare(
+    "ga_unfused_bytes", "parameter bytes whose gradients it adds by the "
+    "tree-wide add (both 0: one micro batch)")
 
 
 @dataclasses.dataclass
@@ -258,22 +265,13 @@ def walked_leaves(loss_fn: Callable, params, *batch) -> Tuple[int, ...]:
 
 def _report_ga_bytes(fused: int, unfused: int) -> None:
     """How the step being built accumulates its parameters' gradients:
-    bytes added inside the layer loop / by the tree-wide add. What the
-    walks of that layer loop keep of their attention
-    (``models/layers.py:scan_blocks``), the calls of their expert layers'
-    row-copy kernel, the selective-scan kernels' forward calls and held
-    chunk-boundary states (``ops/pallas/selective_scan.py``), the forward
-    calls of the conv before that scan (``ops/pallas/causal_conv.py``), and
-    the linear-attention and block top-k attention kernels' forward calls
-    (``ops/pallas/lightning_attention.py``, ``block_topk_attention.py``),
-    are added as they are traced."""
-    metrics().gauge("ga_fused_bytes").set(fused)
-    metrics().gauge("ga_unfused_bytes").set(unfused)
-    for traced in ("attn_kept_calls", "attn_kept_bytes", "ssm_scan_calls",
-                   "ssm_boundary_bytes", "ssm_conv_calls",
-                   "moe_rows_sum_calls", "lin_attn_calls", "topk_attn_calls",
-                   "topk_attn_keys_per_query", "topk_attn_dense_calls"):
-        metrics().gauge(traced).set(0)
+    bytes added inside the layer loop / by the tree-wide add. Every other
+    gauge of the group that is set while a step is traced
+    (``telemetry/traced.py``: what the walks keep, the kernels' calls)
+    starts from 0 and is counted as the step is traced."""
+    traced.reset()
+    traced.note("ga_fused_bytes", fused)
+    traced.note("ga_unfused_bytes", unfused)
 
 
 def build_ga_step(

@@ -25,7 +25,7 @@ import jax
 
 from tepdist_tpu.core.mesh import MeshTopology
 from tepdist_tpu.core.service_env import ServiceEnv
-from tepdist_tpu.telemetry import metrics, span
+from tepdist_tpu.telemetry import metrics, span, traced
 
 log = logging.getLogger(__name__)
 
@@ -412,51 +412,12 @@ def plan_training(
         step_fn, topology, params, opt_state, *example_batch,
         annotations=annotations, mode=mode, state_alias=state_alias,
         var_mem_limit=var_mem_limit, zero_invars=zero_invars)
-    # Set while auto_parallel traced the step (sync_free.build_ga_step).
-    log.info("gradient accumulation: %.0f parameter bytes added inside the "
-             "layer loop, %.0f by the tree-wide add (%d micro batches)",
-             metrics().gauge("ga_fused_bytes").value,
-             metrics().gauge("ga_unfused_bytes").value, num_micro_batches)
-    # Set while the step's walks were differentiated
-    # (models/layers.py:scan_blocks).
-    log.info("attention kept: %.0f calls a micro batch (a flash or block "
-             "top-k kernel's forward, a sparse layer's choice) hand what "
-             "their forward pass made (%.0f bytes of output, log-sum-exp "
-             "and chosen sets) to the backward pass, which does not run it "
-             "again",
-             metrics().gauge("attn_kept_calls").value,
-             metrics().gauge("attn_kept_bytes").value)
-    # Set while the step's selective scans and the convs before them were
-    # traced (ops/pallas/selective_scan.py, causal_conv.py); all 0 for a
-    # model without one.
-    log.info("selective scan: %.0f forward kernel calls a micro batch (a "
-             "rematerialised layer's second run counted), %.0f bytes of "
-             "chunk-boundary states held from a call's forward to its "
-             "backward; %.0f forward calls of the conv before it",
-             metrics().gauge("ssm_scan_calls").value or 0,
-             metrics().gauge("ssm_boundary_bytes").value or 0,
-             metrics().gauge("ssm_conv_calls").value or 0)
-    # Set while the step's linear and block top-k attention layers were
-    # traced (ops/pallas/lightning_attention.py, block_topk_attention.py);
-    # all 0 for a model without them.
-    log.info("linear attention: %.0f forward kernel calls a micro batch (a "
-             "rematerialised layer's second run counted); block top-k "
-             "attention: %.0f (a walked layer's forward is kept and runs "
-             "once), %.1f keys a query on average, %.0f layers run as plain "
-             "causal attention (at or under dense_len)",
-             metrics().gauge("lin_attn_calls").value or 0,
-             metrics().gauge("topk_attn_calls").value or 0,
-             metrics().gauge("topk_attn_keys_per_query").value or 0,
-             metrics().gauge("topk_attn_dense_calls").value or 0)
-    # Set while the step's walks were differentiated: 2 a routed layer that
-    # holds a share of the experts, 0 where the XLA gathers stayed.
-    log.info("rows out of the expert layout: %.0f calls of the row-copy "
-             "kernel a micro batch (ops/pallas/rows_sum.py)",
-             metrics().gauge("moe_rows_sum_calls").value or 0)
-    # Set while the loss was traced (models/layers.py:cross_entropy).
-    log.info("chunked cross entropy: %.0f chunks a loss call make their "
-             "gradients in the forward chunk loop",
-             metrics().gauge("ce_fused_chunks").value)
+    # Set while auto_parallel traced the step: the group's gauges, each
+    # described where it is counted (telemetry/traced.py: GROUP).
+    log.info("the traced step (%d micro batches): %s", num_micro_batches,
+             ", ".join(f"{name}={value:g}"
+                       for name, value in traced.values().items() if value)
+             or "every gauge 0")
     if (len(devices) > 1 and not plan.sharding_plan.constraints
             and not any(ax for spec in plan.sharding_plan.in_specs
                         for ax in spec)

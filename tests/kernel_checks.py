@@ -1,0 +1,52 @@
+"""What a model's tests and its kernels' tests both use: distances, and the
+kernels of a traced function."""
+
+import collections
+
+import jax
+import numpy as np
+
+
+def rel_l2(got, want) -> float:
+    got, want = (np.asarray(x, np.float64) for x in (got, want))
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+def leaves_close(got, want, limit):
+    want = dict(jax.tree_util.tree_flatten_with_path(want)[0])
+    for path, g in jax.tree_util.tree_flatten_with_path(got)[0]:
+        assert np.linalg.norm(np.asarray(want[path], np.float64)) > 0, path
+        assert rel_l2(g, want[path]) < limit, jax.tree_util.keystr(path)
+
+
+def equations(fn, *args):
+    """Every equation of ``fn``'s jaxpr, nested jaxprs included (a loop's
+    body once)."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for v in eqn.params.values():
+                for j in v if isinstance(v, (list, tuple)) else (v,):
+                    j = getattr(j, "jaxpr", j)
+                    if hasattr(j, "eqns"):
+                        yield from walk(j)
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+def kernel_counts(fn, *args):
+    return dict(collections.Counter(
+        e.params["name"] for e in equations(fn, *args)
+        if e.primitive.name == "pallas_call"))
+
+
+def sala_hyper(cfg):
+    """``benchmark/reference/minicpm_sala.py``'s view of a program
+    configuration (``models/minicpm_sala.py:MiniCPMSALAConfig``)."""
+    from benchmark.reference import minicpm_sala as ref
+    return ref.Hyper(
+        n_head=cfg.num_attention_heads, n_kv_head=cfg.num_key_value_heads,
+        lightning_heads=cfg.lightning_nh, mixer_types=cfg.mixer_types,
+        first_layer=cfg.first_layer, published_layers=cfg.published_layers,
+        scale_emb=cfg.scale_emb, scale_depth=cfg.scale_depth,
+        dim_model_base=cfg.dim_model_base, rope_theta=cfg.rope_theta,
+        eps=cfg.rms_norm_eps, **cfg.sparse._asdict())

@@ -13,7 +13,7 @@ import optax
 import pytest
 
 from benchmark.reference import afmoe as ref
-from tepdist_tpu.models import afmoe, olmoe
+from tepdist_tpu.models import afmoe, decoder, olmoe
 from tepdist_tpu.ops import grouped_matmul as gm
 from tepdist_tpu.ops.pallas import flash_attention as fa
 from tepdist_tpu.optim import make_optimizer
@@ -252,7 +252,7 @@ def test_no_assignment_to_a_held_expert_is_dropped(send):
         assert stats["moe_assignments_elsewhere"] == 0
     if send == "none_held":
         assert stats["moe_assignments_held"] == 0
-    held = np.asarray(afmoe.held_mask(stats["experts"], cfg)).sum()
+    held = np.asarray(decoder.held_mask(stats["experts"], cfg.experts_held)).sum()
     assert held == stats["moe_assignments_held"]
     assert metrics().gauge("moe_held_rows_max").value \
         == stats["moe_held_rows_max"]

@@ -249,7 +249,8 @@ def test_plan_training_hands_its_loss_to_the_step(remat_policy_env, caplog):
         ServiceEnv.reset({"REMAT_POLICY": remat_policy_env, "OPT_LEVEL": "1"})
         with caplog.at_level("INFO", logger="tepdist_tpu.train"):
             got_loss, got, gauges = run()
-        assert "gradient accumulation:" in caplog.text
+        assert "the traced step" in caplog.text \
+            and "ga_unfused_bytes=" in caplog.text
         with pytest.MonkeyPatch.context() as patch:
             patch.setattr(sync_free, "walked_leaves", lambda *a: ())
             want_loss, want, off = run()

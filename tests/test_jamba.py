@@ -16,8 +16,8 @@ import optax
 import pytest
 
 from benchmark.reference import jamba as ref
-from tepdist_tpu.models import jamba
-from tepdist_tpu.ops.pallas import causal_conv as conv
+from kernel_checks import leaves_close, rel_l2
+from tepdist_tpu.models import decoder, jamba
 from tepdist_tpu.ops.pallas import selective_scan as ssm
 from tepdist_tpu.optim import make_optimizer
 from tepdist_tpu.parallel.sync_free import build_ga_step
@@ -51,18 +51,6 @@ def to_reference(params, cfg):
     out = {k: params[k] for k in ("tok_emb", "norm_f")}
     out["layers"] = [params[f"l{i}"] for i in range(cfg.num_hidden_layers)]
     return out
-
-
-def rel_l2(got, want) -> float:
-    got, want = (np.asarray(x, np.float64) for x in (got, want))
-    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
-
-
-def leaves_close(got, want, limit):
-    want = dict(jax.tree_util.tree_flatten_with_path(want)[0])
-    for path, g in jax.tree_util.tree_flatten_with_path(got)[0]:
-        assert np.linalg.norm(np.asarray(want[path], np.float64)) > 0, path
-        assert rel_l2(g, want[path]) < limit, jax.tree_util.keystr(path)
 
 
 # -- the program against the reference ---------------------------------------
@@ -119,278 +107,6 @@ def test_a_doubled_micro_batch_shows():
     assert abs(float(even) - float(want)) > 1e-4
 
 
-# -- the kernel against the sequential scan ----------------------------------
-
-def scan_inputs(batch, T, Di, N, seed=0, dtype=jnp.float32):
-    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
-    shape = (batch, T, Di)
-    delta = jax.nn.softplus(jax.random.normal(ks[1], shape) - 2.0)
-    A = -jnp.exp(0.5 * jax.random.normal(ks[2], (Di, N)))
-    return (jax.random.normal(ks[0], shape).astype(dtype), delta, A,
-            jax.random.normal(ks[3], (batch, T, N)).astype(dtype),
-            jax.random.normal(ks[4], (batch, T, N)).astype(dtype),
-            jax.random.normal(ks[5], (Di,)),
-            jax.random.normal(ks[6], shape).astype(dtype)), \
-        jax.random.normal(ks[7], shape)
-
-
-def sequential(c, delta, A, B, C, D, z):
-    f32 = jnp.float32
-    c, delta, B, C, z = (x.astype(f32) for x in (c, delta, B, C, z))
-    y = jnp.stack([ref.recurrence(c[i], delta[i], A, B[i], C[i])
-                   for i in range(c.shape[0])])
-    return (y + D * c) * jax.nn.silu(z)
-
-
-NAMES = ("c", "delta", "A", "B", "C", "D", "z")
-
-
-# Several whole chunks; a length the chunk does not divide (the last chunk
-# is padded with steps that leave the state alone); two channel blocks of
-# two lane tiles each; one chunk longer than the sequence.
-@pytest.mark.parametrize("T,Di,N,chunk,block_d", [
-    (48, 256, 16, 16, 128), (37, 128, 16, 16, 128), (40, 512, 8, 8, 256),
-    (12, 128, 8, 16, 128)])
-def test_kernel_matches_the_sequential_scan(T, Di, N, chunk, block_d):
-    args, w = scan_inputs(2, T, Di, N)
-
-    def through(scan):
-        return jax.value_and_grad(
-            lambda *a: jnp.sum(scan(*a) * w), argnums=tuple(range(7)))(*args)
-
-    got_out = ssm.selective_scan(*args, chunk=chunk, block_d=block_d)
-    want_out = sequential(*args)
-    assert rel_l2(got_out, want_out) < 1e-6
-    (_, got), (_, want) = through(lambda *a: ssm.selective_scan(
-        *a, chunk=chunk, block_d=block_d)), through(sequential)
-    for name, g, w_ in zip(NAMES, got, want):
-        assert g.shape == w_.shape and g.dtype == w_.dtype, name
-        assert rel_l2(g, w_) < 2e-6, name
-
-
-def test_state_is_carried_across_chunks_bit_for_bit():
-    """The same float32 sequence in chunks of 8, 16 and 64 steps (3, 2 and
-    1 chunks with padding): the steps are the same steps in the same order
-    whatever the chunk, so the output and every gradient but ``A``'s and
-    ``D``'s agree bit for bit; those two are sums over the sequence taken a
-    chunk at a time, and regroup."""
-    args, w = scan_inputs(1, 24, 128, 8, seed=2)
-
-    def run(chunk):
-        out, pull = jax.vjp(lambda *a: ssm.selective_scan(
-            *a, chunk=chunk, block_d=128), *args)
-        return (out,) + pull(w)
-
-    first = run(8)
-    assert float(jnp.abs(first[0]).max()) > 0.1
-    for chunk in (16, 64):
-        for name, a, b in zip(("out",) + NAMES, first, run(chunk)):
-            if name in ("A", "D"):
-                assert rel_l2(b, a) < 1e-6, name
-            else:
-                np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
-                                              err_msg=name)
-
-
-def test_state_and_accumulation_are_float32_under_bf16_operands():
-    """bf16 ``c``, ``z``, ``B``, ``C``: against the sequential float32 scan
-    of the same (rounded) operands the kernel differs by the rounding of its
-    bf16 results alone, a sequence of 96 steps long."""
-    args, w = scan_inputs(1, 96, 128, 16, seed=3, dtype=jnp.bfloat16)
-    out, pull = jax.vjp(lambda *a: ssm.selective_scan(*a, chunk=16), *args)
-    want, want_pull = jax.vjp(sequential, *args)
-    assert out.dtype == jnp.bfloat16
-    assert rel_l2(out, want) < 4e-3
-    for name, g, w_ in zip(NAMES, pull(w.astype(jnp.bfloat16)),
-                           want_pull(w.astype(jnp.bfloat16)
-                                     .astype(jnp.float32))):
-        assert g.dtype == w_.dtype, name
-        assert rel_l2(g, w_) < (4e-3 if g.dtype == jnp.bfloat16 else 1e-5), \
-            name
-
-
-def test_a_bfloat16_state_would_fail_the_kernels_comparison():
-    """The control of the tests above: the sequential scan with its state
-    rounded to bfloat16 after every step, one precision below the float32
-    the configuration states, in the kernel's place. At Mamba-1's step sizes
-    (``delta`` from 1e-3 to 1e-1 against ``A`` = -1..-16, so a state sums a
-    thousand steps) it stands thousands of times further from the float32
-    scan than the 2e-6 the kernel is held to, in the output and in every
-    gradient the state reaches."""
-    T, Di, N = 256, 128, 16
-    ks = jax.random.split(jax.random.PRNGKey(5), 6)
-    c, z = (jax.random.normal(k, (T, Di)) for k in ks[:2])
-    B, C = (jax.random.normal(k, (T, N)) for k in ks[2:4])
-    delta = jnp.exp(jax.random.uniform(
-        ks[4], (T, Di), minval=jnp.log(1e-3), maxval=jnp.log(1e-1)))
-    A = -jnp.broadcast_to(jnp.arange(1.0, N + 1), (Di, N))
-    w = jax.random.normal(ks[5], (T, Di))
-
-    def rounded(c, delta, A, B, C):
-        def step(h, x):
-            c_t, d_t, B_t, C_t = x
-            h = jnp.exp(d_t[None] * A.T) * h + B_t[:, None] * (d_t * c_t)[None]
-            h = h.astype(jnp.bfloat16).astype(jnp.float32)
-            return h, jnp.sum(h * C_t[:, None], axis=0)
-        return jax.lax.scan(step, jnp.zeros((N, Di)), (c, delta, B, C))[1]
-
-    def through(scan):
-        return jax.value_and_grad(lambda *a: jnp.sum(scan(*a) * w),
-                                  argnums=(0, 1, 2, 3, 4))(c, delta, A, B, C)
-
-    (_, want), (_, low) = through(ref.recurrence), through(rounded)
-    assert rel_l2(rounded(c, delta, A, B, C),
-                  ref.recurrence(c, delta, A, B, C)) > 1e-3
-    for name, g, w_ in zip(NAMES, low, want):
-        assert rel_l2(g, w_) > 1e-3, name
-    # And the kernel, on the same inputs, is where the tests above hold it.
-    args = (c[None], delta[None], A, B[None], C[None], jnp.ones((Di,)),
-            z[None])
-    assert rel_l2(ssm.selective_scan(*args, chunk=64),
-                  sequential(*args)) < 2e-6
-
-
-def test_the_kernel_refuses_shapes_it_cannot_tile():
-    args, _ = scan_inputs(1, 16, 128, 8)
-    with pytest.raises(ValueError):
-        ssm.selective_scan(*args, chunk=12)
-    with pytest.raises(ValueError):
-        ssm.selective_scan(args[0][..., :64], args[1][..., :64],
-                           args[2][:64], *args[3:5], args[5][:64],
-                           args[6][..., :64])
-
-
-def test_the_kernels_state_their_cost_to_the_planner():
-    """``graph/cost.py`` prices a ``pallas_call`` by its ``cost_estimate``:
-    the scan is not read as free."""
-    from tepdist_tpu.graph.cost import jaxpr_flops
-    args, w = scan_inputs(1, 32, 128, 8)
-    fwd = jax.make_jaxpr(lambda *a: ssm.selective_scan(*a, chunk=16))(*args)
-    both = jax.make_jaxpr(jax.grad(
-        lambda *a: jnp.sum(ssm.selective_scan(*a, chunk=16) * w)))(*args)
-    elements = 32 * 128 * 8
-    assert jaxpr_flops(fwd.jaxpr) >= ssm.FWD_FLOPS * elements
-    assert jaxpr_flops(both.jaxpr) >= (ssm.FWD_FLOPS + ssm.BWD_FLOPS) \
-        * elements
-
-
-# -- the conv kernels against the jax.numpy form ------------------------------
-
-CONV_NAMES = ("c", "du", "dw", "db")
-
-
-def conv_inputs(batch, T, Di, dtype, seed=0, K=4):
-    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
-    return (jax.random.normal(ks[0], (batch, T, Di)).astype(dtype),
-            (0.5 * jax.random.normal(ks[1], (K, Di))).astype(dtype),
-            (0.1 * jax.random.normal(ks[2], (Di,))).astype(dtype),
-            jax.random.normal(ks[3], (batch, T, Di)).astype(dtype))
-
-
-def out_and_gradients(fn, u, w, b, dc):
-    out, pull = jax.vjp(fn, u, w, b)
-    return (out,) + pull(dc)
-
-
-def hold_conv(got, want, limit, block_t=conv.STRIP):
-    """``got`` (c, du, dw, db) to ``want``, and the rows a halo fills (the
-    first of the sequence and of every later time block) on their own."""
-    for name, g, w_ in zip(CONV_NAMES, got, want):
-        assert g.shape == w_.shape and g.dtype == w_.dtype, name
-        assert rel_l2(g, w_) < limit, name
-    for name, g, w_ in zip(CONV_NAMES[:2], got, want):
-        for at in range(0, g.shape[1] - 4, block_t):
-            edge = slice(max(at - 4, 0), at + 4)
-            assert rel_l2(g[:, edge], w_[:, edge]) < limit, (name, at)
-
-
-S = conv.STRIP      # the least time block
-
-
-# Two time blocks with the sequence ending inside the second; four blocks
-# and two channel blocks, two sequences; one block longer than the sequence;
-# a block of several strips; three taps.
-@pytest.mark.parametrize("dtype,limit", [(jnp.float32, 2e-6),
-                                         (jnp.bfloat16, 4e-3)])
-@pytest.mark.parametrize("batch,T,Di,K,block_t,block_d", [
-    (1, S + 8, 128, 4, S, 128), (2, 3 * S + 36, 256, 4, S, 128),
-    (1, 20, 128, 4, 512, 512), (1, 3 * S, 256, 4, 3 * S, 256),
-    (1, S + 18, 128, 3, S, 128)])
-def test_conv_kernels_match_the_jax_numpy_form(dtype, limit, batch, T, Di, K,
-                                               block_t, block_d):
-    """Values and the gradients of ``u``, ``w``, ``b``: zeros before the
-    sequence, the rows before a block carried into it (forward) and the rows
-    after it (backward), the padded rows past the end adding nothing to the
-    sums."""
-    args = conv_inputs(batch, T, Di, dtype, K=K)
-    got = out_and_gradients(lambda *a: conv.causal_conv(
-        *a, block_t=block_t, block_d=block_d), *args)
-    hold_conv(got, out_and_gradients(conv.reference, *args), limit, block_t)
-
-
-def test_conv_sums_are_float32_under_bf16_operands():
-    """bf16 operands over 4096 rows: the taps' and the bias's gradients are
-    sums of 4096 products each, within bf16's rounding of the float32 form's
-    results (a bf16 accumulator would stand 1e-2 off)."""
-    args = conv_inputs(1, 4096, 128, jnp.bfloat16, seed=4)
-    got = out_and_gradients(conv.causal_conv, *args)
-    want = out_and_gradients(
-        conv.reference, *(a.astype(jnp.float32) for a in args))
-    for name, g, w_ in zip(CONV_NAMES, got, want):
-        assert g.dtype == jnp.bfloat16, name
-        assert rel_l2(g, w_) < 4e-3, name
-
-
-@pytest.mark.parametrize("which", ["forward", "backward"])
-def test_a_dropped_halo_fails_the_conv_comparison(which, monkeypatch):
-    """The control of the comparison above: the same kernels with what one
-    time block hands the next zeroed (the last rows of ``u`` going forward,
-    the first rows of ``g`` going backward)."""
-    name, carried = {"forward": ("_fwd_kernel", 4),     # the scratch's place
-                     "backward": ("_bwd_kernel", 7)}[which]
-    real = getattr(conv, name)
-
-    def dropped(*refs, **how):
-        refs[carried][...] = jnp.zeros(refs[carried].shape, jnp.float32)
-        real(*refs, **how)
-
-    monkeypatch.setattr(conv, name, dropped)
-    u, w, b, dc = conv_inputs(1, 2 * S, 128, jnp.float32, seed=6)
-    how = dict(block_t=S, block_d=128, interpret=True)
-    # Not through the jitted entry points: their traces are cached.
-    c = conv._fwd_call.__wrapped__(u, w, b, **how)
-    du, dw, db = conv._bwd_call.__wrapped__(u, w, b, dc, **how)
-    want = out_and_gradients(conv.reference, u, w, b, dc)
-    with pytest.raises(AssertionError):
-        hold_conv((c, du, dw, db), want, 2e-6)
-    # What the fault does not touch is where it was.
-    sound = (du, dw, db) if which == "forward" else (c,)
-    for g, w_ in zip(sound, want[1:] if which == "forward" else want[:1]):
-        assert rel_l2(g, w_) < 2e-6
-
-
-def test_the_conv_refuses_shapes_it_cannot_tile():
-    u, w, b, _ = conv_inputs(1, 16, 128, jnp.float32)
-    with pytest.raises(ValueError):
-        conv.causal_conv(u[..., :64], w[:, :64], b[:64])
-    with pytest.raises(ValueError):
-        conv.causal_conv(u, jnp.zeros((9, 128)), b)
-    with pytest.raises(ValueError):
-        conv.causal_conv(u, w, b[:64])
-
-
-def test_the_conv_kernels_state_their_cost_to_the_planner():
-    from tepdist_tpu.graph.cost import jaxpr_flops
-    u, w, b, dc = conv_inputs(1, 32, 128, jnp.float32)
-    fwd = jax.make_jaxpr(conv.causal_conv)(u, w, b)
-    both = jax.make_jaxpr(jax.grad(
-        lambda *a: jnp.sum(conv.causal_conv(*a) * dc), argnums=(0, 1, 2)))(
-        u, w, b)
-    assert jaxpr_flops(fwd.jaxpr) >= conv.FWD_FLOPS * u.size
-    assert jaxpr_flops(both.jaxpr) >= (conv.FWD_FLOPS + conv.BWD_FLOPS) \
-        * u.size
-
-
 # -- the order of the layers -------------------------------------------------
 
 def test_28_layers_run_in_the_period_rules_order():
@@ -421,7 +137,7 @@ def test_the_stacks_follow_the_runs():
     assert all(v.ndim == 3 for v in params["run2"].values())
     assert list(params["decay2"]) == ["A_log"]
     assert params["decay2"]["A_log"].shape == (2, 128, 8)
-    assert sorted(jamba.run_blocks(params, 2)) \
+    assert sorted(decoder.run_blocks(params, 2, jamba.GROUPS)) \
         == sorted(jamba.init_params(CFG, KEY)["l4"])
     layered = jamba.init_params(CFG, KEY)
     np.testing.assert_array_equal(np.asarray(params["run2"]["out_proj"][1]),
@@ -563,20 +279,17 @@ def test_a_planned_step_binds_the_scan_kernels_for_four_devices(devices):
 
 @pytest.fixture(scope="module")
 def v5e_chip():
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
+    import contextlib
+
     from jax.sharding import SingleDeviceSharding
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
-        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e!r}")
-    enabled = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", enabled)
-    compilation_cache.reset_cache()
+
+    from tools.described_chip import described_v5e
+    with contextlib.ExitStack() as stack:
+        try:
+            devices = stack.enter_context(described_v5e())
+        except Exception as e:  # noqa: BLE001 — no TPU compiler here
+            pytest.skip(f"cannot describe a v5e:2x2 topology here: {e!r}")
+        yield SingleDeviceSharding(devices[0])
 
 
 def test_a_narrow_jambas_step_compiles_for_a_described_v5e(v5e_chip,

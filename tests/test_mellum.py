@@ -12,7 +12,7 @@ import optax
 import pytest
 
 from benchmark.reference import mellum as ref
-from tepdist_tpu.models import layers, mellum
+from tepdist_tpu.models import decoder, layers, mellum
 from tepdist_tpu.ops import grouped_matmul as gm
 from tepdist_tpu.optim import make_optimizer
 from tepdist_tpu.parallel.sync_free import build_ga_step
@@ -238,7 +238,7 @@ def test_no_assignment_to_a_held_expert_is_dropped(send):
         assert stats["moe_assignments_held"] == 0
     if send == "mixed":
         assert 0 < stats["moe_assignments_held"] < L * S * k
-    held = np.asarray(mellum.held_mask(stats["experts"], cfg)).sum()
+    held = np.asarray(decoder.held_mask(stats["experts"], cfg.experts_held)).sum()
     assert held == stats["moe_assignments_held"]
     assert stats["held_rows"].shape == (L, 4)
     assert metrics().gauge("moe_held_rows_max").value \
@@ -304,8 +304,9 @@ def test_the_layers_values_do_not_depend_on_the_size_taken(send, monkeypatch):
 
 def test_both_held_share_models_report_through_one_implementation():
     from tepdist_tpu.models import afmoe
-    assert afmoe.held_routing_stats is mellum.held_routing_stats \
-        is layers.held_routing_stats
+    assert afmoe.routing_stats.func is mellum.routing_stats.func \
+        is decoder.routing_stats
+    assert afmoe.held_weights is mellum.held_weights is decoder.held_weights
     assert afmoe.gqa_heads is mellum.gqa_heads is layers.gqa_heads
     ids = jnp.asarray([[[0, 5], [4, 5], [7, 1]]], jnp.int32)  # [1, 3, 2]
     stats = layers.held_routing_stats(ids, 8, 4, (4, 4))

@@ -7,6 +7,7 @@ partitioner. Interpret-mode tests cannot see any of that. A compile that
 passes is NOT a chip run: nothing executes here.
 """
 
+import contextlib
 import dataclasses
 import os
 import re
@@ -17,8 +18,6 @@ import jax
 import jax.numpy as jnp
 import optax
 import pytest
-from jax.experimental import topologies
-from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
 from tepdist_tpu.ops.pallas.flash_attention import flash_attention
@@ -26,20 +25,14 @@ from tepdist_tpu.ops.pallas.flash_attention import flash_attention
 
 @pytest.fixture(scope="module")
 def v5e_devices():
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
-        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e!r}")
-    # A compile for a described chip is written to the persistent cache but
-    # cannot be read back without the chip (the next one warns): keep these
-    # out of it.
-    enabled = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield list(topo.devices)
-    jax.config.update("jax_enable_compilation_cache", enabled)
-    compilation_cache.reset_cache()
+    """The devices of a described v5e:2x2 (``tools/described_chip.py``)."""
+    from tools.described_chip import described_v5e
+    with contextlib.ExitStack() as stack:
+        try:
+            devices = stack.enter_context(described_v5e())
+        except Exception as e:  # noqa: BLE001 — no TPU compiler here
+            pytest.skip(f"cannot describe a v5e:2x2 topology here: {e!r}")
+        yield devices
 
 
 # A row statistic stored one to a row, [.., T, 1], is tiled (8, 128) in HBM:
