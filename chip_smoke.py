@@ -1,6 +1,6 @@
 """Quickest proof that the main path still starts on the chip.
 
-    python chip_smoke.py            # one chip: phases A and B
+    python chip_smoke.py            # one chip: phases A, B and M
     python chip_smoke.py --chips 4  # one host, four chips: that phase only
 
 Drives GPT-2 117M at published widths (12 x 768 x 12 heads, vocab 50257,
@@ -14,6 +14,13 @@ through the entry points a user calls:
            seed and batch and takes 5 steps; asserts the pallas kernel is in
            the compiled step and that the planner's chip table describes the
            attached device.
+  phase M  a child process that calls ``plan_training`` on the newest model
+           of the zoo at its ``smoke`` preset (``models/sarvam_mla.py``: a
+           rank's 2 of 4 latent-attention heads at the published head widths,
+           4 of 16 sigmoid-routed experts), 2 micro batches of one
+           1024-token sequence, 5 steps; asserts its three attention kernels
+           and the grouped matmuls are in the compiled step and that the
+           walk kept one forward a layer.
   --chips 4  one child owning all four chips: ``plan_training(explore=True)``
            over ``jax.devices()`` at batch 16, 5 steps, then the same 5 steps
            on ``devices[:1]``; every device must hold a shard and the
@@ -274,6 +281,45 @@ def phase_b(cfg_name: str = "117M", batch: int = 8, seq: int = 1024,
 
 
 # ---------------------------------------------------------------------------
+# Phase M: the zoo's newest model through plan_training, its kernels compiled.
+# ---------------------------------------------------------------------------
+
+def phase_mla(preset: str = "smoke", batch: int = 2, seq: int = 1024,
+              platform: str = "tpu") -> dict:
+    from tepdist_tpu.models import sarvam_mla
+    from tepdist_tpu.optim import make_optimizer
+    from tepdist_tpu.telemetry import traced
+    from tepdist_tpu.train import plan_training
+
+    devices = _own_devices(platform)[:1]
+    configure_compile_cache()
+    cfg = dataclasses.replace(sarvam_mla.CONFIGS[preset], remat=True)
+    params = sarvam_mla.stacked_init_params(cfg, jax.random.PRNGKey(SEED))
+    tokens = sarvam_mla.fake_batch(cfg, batch, seq, seed=SEED)
+    tplan = plan_training(
+        lambda p, t: sarvam_mla.loss_fn(p, t, cfg),
+        make_optimizer({"name": "adamw_bf16_router_bias",
+                        "learning_rate": 1e-3, "bias_rate": 0.001}),
+        params, tokens, devices=devices, explore=False, num_micro_batches=2)
+    gauges = traced.values()
+    _check(gauges["attn_kept_calls"] == gauges["mla_fwd_calls"]
+           == cfg.num_hidden_layers,
+           f"phase M: the walk kept {gauges['attn_kept_calls']} forward "
+           f"passes of {cfg.num_hidden_layers} layers")
+    text = _compiled_text_with_kernel(tplan, platform, "phase M")
+    if platform == "tpu":
+        for kernel in ("tepdist_mla_fwd", "tepdist_mla_dq",
+                       "tepdist_mla_dkv", "tepdist_gmm_fwd"):
+            _check(kernel in text,
+                   f"phase M: no {kernel} in the compiled step")
+    losses, first = _take_steps(lambda: tplan.step(tokens), "phase M")
+    return {"phase": "M", "entry": "plan_training",
+            "model": f"sarvam_mla-{preset}", "batch": batch, "seq": seq,
+            **_device_record(devices), "losses": losses, **gauges,
+            "setup_first_step_seconds": first}
+
+
+# ---------------------------------------------------------------------------
 # Four chips: explored layout over the host's devices vs the same steps on
 # one of them, in one process that owns all four.
 # ---------------------------------------------------------------------------
@@ -333,7 +379,8 @@ def phase_four(cfg_name: str = "117M", batch: int = 16, seq: int = 1024,
     }
 
 
-CHILD_PHASES = {"phase_b": phase_b, "phase_four": phase_four}
+CHILD_PHASES = {"phase_b": phase_b, "phase_four": phase_four,
+                "phase_mla": phase_mla}
 
 
 def _run_child(phase: str) -> dict:
@@ -379,6 +426,7 @@ def main() -> None:
                f"step-0 loss of phase A {a['losses'][0]} and phase B "
                f"{holder['losses'][0]} differ by more than 1e-2 relative "
                "(same weights, same tokens, no update yet)")
+        _emit(_run_child("phase_mla"))
     _check(holder["platform"] == "tpu" and holder["n_devices"] == args.chips,
            f"ran on {holder['n_devices']} {holder['platform']} device(s), "
            f"wanted {args.chips} tpu")

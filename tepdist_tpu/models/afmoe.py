@@ -231,7 +231,7 @@ def _count_bwd(res, g):
 count_choices.defvjp(_count_fwd, _count_bwd)
 
 
-def router(blk, h, cfg: AfmoeConfig):
+def router(blk, h, cfg):
     """h [S, d] -> (float32 scores [S, E], weights [S, k], expert ids
     [S, k]): top-k of ``sigmoid(h Wr) + b``, weights from the unbiased
     scores, normalised over the k chosen and scaled."""
@@ -259,9 +259,11 @@ def swiglu(h, w_gate, w_up, w_down):
         h.dtype) @ w_down
 
 
-def moe(blk, x, cfg: AfmoeConfig):
+def moe(blk, x, cfg):
     """x [B, T, d] -> the shared expert's output plus the held routed
-    experts' part of the layer's."""
+    experts' part of the layer's. ``cfg``: whatever has ``num_experts``,
+    ``experts_held``, ``num_experts_per_tok``, ``route_scale`` and
+    ``moe_tile_m`` (``models/sarvam_mla.py``'s runs this layer too)."""
     B, T, d = x.shape
     h = x.reshape(B * T, d)
     with jax.named_scope("moe_router"):

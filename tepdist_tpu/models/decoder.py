@@ -2,8 +2,8 @@
 brings its mixers, its block and its configuration and nothing else: the two
 parameter layouts (``l{i}`` per-layer dicts, or the layers stacked: a stack
 each run of one kind, or each of the model's own names) with the loop over
-the layers of either, the experts a layer holds of its router's, and the
-batch of random tokens. The pieces of the layers themselves are
+the layers of either, the experts a layer holds of its router's, the heads a
+layer holds of the model's, and the batch of random tokens. The pieces of the layers themselves are
 ``models/layers.py``.
 
 A stack is ``(name, first layer, layers)``; its leaves lie under the
@@ -146,6 +146,18 @@ def held_weights(weights, experts, held: Tuple[int, int], num_experts: int):
     if held[1] >= num_experts:
         return weights
     return jnp.where(held_mask(experts, held), weights, 0.0)
+
+
+# -- an attention layer that holds a share of the heads -----------------------
+
+def held_heads(w, held: Tuple[int, int], width: int, axis: int = -1):
+    """The held heads' part of a matrix laid out a head after a head along
+    ``axis``, ``width`` entries each: the columns of a projection to the
+    heads (``axis`` -1) or the rows of the one back from them (0), for
+    ``held = (first, count)`` of the model's heads."""
+    first, count = held
+    return jax.lax.slice_in_dim(w, first * width, (first + count) * width,
+                                axis=axis % w.ndim)
 
 
 def routing_stats(expert_choices: Callable, params, tokens, cfg) -> dict:

@@ -330,8 +330,9 @@ def yarn_table(head_dim: int, theta: float, factor: float,
                      float(attention_factor), "rope_yarn")
 
 
-def rope(x, table: Union[float, RopeTable]):
-    """Rotary embedding over [B, H, T, hd] (rotate-half formulation).
+def rope(x, table: Union[float, RopeTable], start=0):
+    """Rotary embedding over [B, H, T, hd] (rotate-half formulation) at
+    positions ``start ..`` (a chunk of a sequence: its first position).
     ``table``: the plain table's ``theta`` (pair ``i`` turns by ``theta **
     (-i / half)`` a position), or a :class:`RopeTable`."""
     B, H, T, hd = x.shape
@@ -343,7 +344,10 @@ def rope(x, table: Union[float, RopeTable]):
                                      / half))
         else:
             freqs = jnp.asarray(table.inv_freq, jnp.float32)
-        angles = jnp.arange(T, dtype=jnp.float32)[:, None] * freqs[None, :]
+        positions = jnp.arange(T, dtype=jnp.float32)
+        if not (isinstance(start, int) and start == 0):
+            positions = positions + jnp.asarray(start, jnp.float32)
+        angles = positions[:, None] * freqs[None, :]
         cos = jnp.cos(angles)[None, None, :, :]
         sin = jnp.sin(angles)[None, None, :, :]
         if not plain and table.scale != 1.0:

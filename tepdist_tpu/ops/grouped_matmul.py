@@ -436,7 +436,14 @@ def routed_experts(h, weights, experts, w_gate, w_up, w_down,
     for it. No token is dropped (``route``). A share of the experts is laid
     out once and runs everything wide at the smallest of ``layout_rows``'
     sizes that its routing fits, chosen by a ``switch`` on the device; the
-    whole layer has one size and no ``switch``."""
+    whole layer has one size and no ``switch``. Where the experts' weights
+    outweigh the rows the largest size keeps for its backward (``[rows, d]``
+    and three ``[rows, f]``: few tokens a call under many wide experts, a
+    chunk of sarvam-105b's sequence) that ``switch`` keeps the weights out of
+    its residual slots (``_switch(lean=)``: 6 x the weights' bytes less held
+    from a layer's forward to its backward); elsewhere they stay, the form
+    the compiler schedules better (as the rule the lean form cost Trinity's
+    cell 1.35% and Mellum2's 1.15%: PERF.md section 6, PR 45)."""
     with jax.named_scope("moe_dispatch"):
         r = route(experts, num_experts, tile_m, held)
     calls = _KERNEL_CALLS.get()
@@ -446,15 +453,17 @@ def routed_experts(h, weights, experts, w_gate, w_up, w_down,
                         num_experts, tile_m)
     if len(sizes) == 1:
         return routed_experts_at(h, weights, r, w_gate, w_up, w_down, tile_m)
+    d, f = w_gate.shape[1:]
+    lean = w_gate.size + w_up.size + w_down.size > sizes[-1] * (d + 3 * f)
     return _switch(
         layout_index(r.n_tiles, sizes, tile_m),
         [lambda r, h, weights, *w, m=m: _branch(
             h, weights, at_rows(r, m, tile_m), *w, tile_m=tile_m)
          for m in sizes],
-        r, h, weights, w_gate, w_up, w_down)
+        r, h, weights, w_gate, w_up, w_down, lean=lean)
 
 
-def _switch(index, branches, r: Routing, *args):
+def _switch(index, branches, r: Routing, *args, lean: bool = False):
     """``jax.lax.switch(index, branches, r, *args)`` (each branch
     ``routed_experts_at`` at one size; differentiable in ``args``) whose
     backward hands each size's residuals over in slots of their
@@ -475,14 +484,22 @@ def _switch(index, branches, r: Routing, *args):
 
     # Slot i holds the leaves of size i's pullback; what puts them together
     # again is static, made while the forward rule is traced and read by
-    # the backward rule, which is traced after it.
-    trees = {}
+    # the backward rule, which is traced after it. ``lean``: a leaf that is
+    # one of ``args`` as it came (the experts' weights, which every size's
+    # pullback reads) goes in no slot and the backward rule takes it from
+    # ``args``: a conditional returns no operand without copying it, so a
+    # slot holds a copy for the size taken and an array as large, unwritten,
+    # for every other.
+    trees, passed = {}, {}
 
     def fwd(index, r, *args):
         def residuals(i, r, *args):
             out, pull = jax.vjp(functools.partial(branches[i], r), *args)
             leaves, trees[i] = jax.tree_util.tree_flatten(pull)
-            return out, leaves
+            passed[i] = [next((n for n, a in enumerate(args) if a is leaf),
+                              None) if lean else None for leaf in leaves]
+            return out, [leaf for leaf, n in zip(leaves, passed[i])
+                         if n is None]
 
         # Traced once a size: the shapes first, the branch from the cache.
         sized = [jax.jit(functools.partial(residuals, i), inline=True)
@@ -497,13 +514,19 @@ def _switch(index, branches, r: Routing, *args):
 
         out, slots = jax.lax.switch(
             index, [functools.partial(branch, i) for i in taken], r, *args)
-        return out, (index, slots)
+        return out, (index, slots, args if lean else ())
 
     def bwd(residuals, g):
-        index, slots = residuals
+        index, slots, args = residuals
+
+        def pull(i, slots, args, g):
+            kept = iter(slots[i])
+            return jax.tree_util.tree_unflatten(trees[i], [
+                next(kept) if n is None else args[n] for n in passed[i]])(g)
+
         return (None, None) + jax.lax.switch(
-            index, [lambda slots, g, i=i: jax.tree_util.tree_unflatten(
-                trees[i], slots[i])(g) for i in taken], slots, g)
+            index, [functools.partial(pull, i) for i in taken], slots, args,
+            g)
 
     chosen.defvjp(fwd, bwd)
     return chosen(index, r, *args)

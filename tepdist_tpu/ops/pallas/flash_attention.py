@@ -101,7 +101,8 @@ def _fold_scale(dtype, scale: float) -> bool:
     return dtype == jnp.float32 or math.frexp(scale)[0] == 0.5
 
 
-def _scores_t(k, q, scale, fold, causal_from=None, window_from=None):
+def _scores_t(k, q, scale, fold, causal_from=None, window_from=None,
+              shared=None):
     """Transposed scores [keys, queries] in float32. Keys on the rows and
     queries on the lanes: the softmax statistics are then reductions over
     rows (vector maxima and adds, no cross-lane work) and [1, queries]
@@ -110,8 +111,13 @@ def _scores_t(k, q, scale, fold, causal_from=None, window_from=None):
     ``st[0, 0]`` where the diagonal may cross the tile: keys after the
     query's own position go to -inf. ``window_from`` is (key, query,
     window) where the window's far edge may cross it: keys ``window`` or
-    more positions before the query go to -inf too."""
+    more positions before the query go to -inf too. ``shared`` is a second
+    ``(k, q)`` pair whose product joins the scores before the scale: the key
+    part all heads of a latent-attention layer read
+    (``ops/pallas/mla_attention.py``)."""
     st = _dot(k, q, _NT)
+    if shared is not None:
+        st = st + _dot(*shared, _NT)
     if not fold:
         st = st * scale
     if causal_from is not None or window_from is not None:
@@ -175,6 +181,42 @@ def _over_key_blocks(step, carry, causal, qi, block_q, block_k, n_blocks,
     return jax.lax.fori_loop(
         0, hi, lambda j, carry: step(j, carry, (j * block_k, qi * block_q)),
         carry)
+
+
+def _over_query_blocks(step, carry, causal, ki, block_q, k_block, n_blocks,
+                       window=None):
+    """``step(i, carry, causal_from)`` over the Q blocks that see K/V block
+    ``ki``: :func:`_over_key_blocks`' cases from the key's side, from the
+    block's own diagonal on (to its window's far edge, with a window)."""
+    def plain(i, carry):
+        return step(i, carry, None)
+
+    if window is not None and block_q == k_block and window % k_block == 0:
+        far = ki + window // k_block
+        carry = jax.lax.fori_loop(ki + 1, jnp.minimum(far, n_blocks), plain,
+                                  step(ki, carry, (0, 0)))
+        return _once_if(
+            far < n_blocks, far,
+            lambda i, c: step(i, c, None, (0, window, window)), carry)
+    if window is not None:
+        k0 = ki * k_block
+        hi = jnp.minimum((k0 + k_block + window + block_q - 2) // block_q,
+                         n_blocks)
+        return jax.lax.fori_loop(
+            k0 // block_q, hi,
+            lambda i, c: step(i, c, (k0, i * block_q),
+                              (k0, i * block_q, window)), carry)
+    if not causal:
+        return jax.lax.fori_loop(0, n_blocks, plain, carry)
+    if block_q == k_block:
+        # As in _over_key_blocks: Q blocks before this K block see none of
+        # it, its own sees it across the diagonal, those after see it all.
+        return jax.lax.fori_loop(ki + 1, n_blocks, plain,
+                                 step(ki, carry, (0, 0)))
+    lo = (ki * k_block) // block_q
+    return jax.lax.fori_loop(
+        lo, n_blocks,
+        lambda i, carry: step(i, carry, (ki * k_block, i * block_q)), carry)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
@@ -250,14 +292,12 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, *, block_q: int, causal: bool, scale: float,
                 k_block: int, seq_len: int, window: Optional[int] = None):
     """One K/V block: dV = sum_i P_i^T @ dO_i, dK = scale * sum_i dS_i^T @
-    Q_i, over the Q blocks from its diagonal on (to its window's far edge,
-    with a window: ``_over_key_blocks``' cases from the key's side)."""
+    Q_i, over the Q blocks that see it (``_over_query_blocks``)."""
     ki = pl.program_id(1)
     k = k_ref[0]                                      # [bk, D]
     v = v_ref[0]
     bk, D = k.shape
     fold = _fold_scale(k.dtype, scale)
-    n_blocks = seq_len // block_q
 
     def step(i, carry, causal_from, window_from=None):
         dk, dv = carry
@@ -271,39 +311,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dst = pt * (_dot(v, do, _NT) - delta_ref[0, i])   # dS^T
         return dk + _dot(dst.astype(q.dtype), q), dv
 
-    def plain(i, carry):
-        return step(i, carry, None)
-
-    carry = (jnp.zeros((bk, D), jnp.float32), jnp.zeros((bk, D), jnp.float32))
-    if window is not None and block_q == k_block and window % k_block == 0:
-        far = ki + window // k_block
-        carry = jax.lax.fori_loop(ki + 1, jnp.minimum(far, n_blocks), plain,
-                                  step(ki, carry, (0, 0)))
-        carry = _once_if(
-            far < n_blocks, far,
-            lambda i, c: step(i, c, None, (0, window, window)), carry)
-    elif window is not None:
-        k0 = ki * k_block
-        hi = jnp.minimum((k0 + k_block + window + block_q - 2) // block_q,
-                         n_blocks)
-        carry = jax.lax.fori_loop(
-            k0 // block_q, hi,
-            lambda i, c: step(i, c, (k0, i * block_q),
-                              (k0, i * block_q, window)), carry)
-    elif not causal:
-        carry = jax.lax.fori_loop(0, n_blocks, plain, carry)
-    elif block_q == k_block:
-        # As in _over_key_blocks: Q blocks before this K block see none of
-        # it, its own sees it across the diagonal, those after see it all.
-        carry = jax.lax.fori_loop(ki + 1, n_blocks, plain,
-                                  step(ki, carry, (0, 0)))
-    else:
-        lo = (ki * k_block) // block_q
-        carry = jax.lax.fori_loop(
-            lo, n_blocks,
-            lambda i, carry: step(i, carry, (ki * k_block, i * block_q)),
-            carry)
-    dk, dv = carry
+    dk, dv = _over_query_blocks(
+        step, (jnp.zeros((bk, D), jnp.float32),
+               jnp.zeros((bk, D), jnp.float32)),
+        causal, ki, block_q, k_block, seq_len // block_q, window)
     # With the scale folded into q, dk already carries it.
     if not fold:
         dk = dk * scale
