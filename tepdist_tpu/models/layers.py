@@ -400,10 +400,13 @@ def gqa_heads(blk, a, *, n_head: int, n_kv_head: int, head_dim: int,
         # the ``cond``: both kinds' ``(o, lse)`` have one shape.
         if isinstance(windowed, (bool, np.bool_)):
             return attend(forward, q, k, v, bool(windowed))
-        return jax.lax.cond(windowed != 0,
-                            functools.partial(attend, windowed=True),
-                            functools.partial(attend, windowed=False),
-                            forward, q, k, v)
+        # Both branches are traced and one runs a layer: what each counts of
+        # its calls (``flash_bwd_calls``) counts half, a layer's sum once.
+        with traced.stands_for(1 / 2):
+            return jax.lax.cond(windowed != 0,
+                                functools.partial(attend, windowed=True),
+                                functools.partial(attend, windowed=False),
+                                forward, q, k, v)
 
     o = hand_over(either)
     return o.transpose(0, 2, 1, 3).reshape(B, T, n_head * head_dim)

@@ -3,9 +3,12 @@
 The intra-device hot op: online-softmax blockwise attention computed in VMEM
 (one pass over K/V blocks per Q block). Training-ready via
 ``jax.custom_vjp``: the forward saves (O, LSE) residuals and the backward
-recomputes P blockwise — two kernels, one accumulating dQ over K blocks, one
-accumulating dK/dV over Q blocks — so no [T, T] matrix is ever materialised
-in HBM in either direction.
+recomputes P blockwise in one kernel, which walks a K/V block's Q blocks,
+makes each pair's ``P^T`` and ``dS^T`` once and adds them into dK, dV and the
+head's dQ^T, so no [T, T] matrix is ever materialised in HBM in either
+direction and a pair's scores, exponentials and ``dO V^T`` are computed once
+(two kernels, one a gradient side, computed them twice: seven matmuls a pair
+for the backward pass's five; PERF.md, PR 46).
 
 Precision follows the inputs' dtype and nothing else. The MXU takes q, k, v
 and dO as they arrive and P and dS rounded to that dtype (as the model's
@@ -16,7 +19,7 @@ inputs get float32 matmuls. (On the v5e the compiler already fed float32
 operands to the MXU in one bf16 pass: bf16 results were identical to the
 last digit before and after; PERF.md, PR 25.)
 
-All three kernels compute the scores transposed, ``S^T = K Q^T`` [bk, bq],
+Both kernels compute the scores transposed, ``S^T = K Q^T`` [bk, bq],
 keys on the rows and queries on the lanes. The softmax statistics are then
 reductions over rows (elementwise maxima and adds of vector registers
 instead of a cross-lane reduction per eight rows, which took 40% of the
@@ -31,18 +34,21 @@ the per-block compute of ring attention. Runs in interpret mode off-TPU
 fused attention at all (SURVEY.md §5.7); this is TPU-native surplus.
 
 Sequence length: each grid step holds a whole ``(1, T, D)`` K and V block
-(forward, dQ) or Q and dO block (dK/dV) in VMEM and only tiles the other
-operand, so VMEM use grows with T. Under the v5e compiler's default limit
-for one kernel (16 MiB of scoped VMEM) forward+backward compile up to
-(B,H,T,D) = (1,12,8192,64) in bf16 and float32, (1,32,8192,128) and
-(1,12,16384,64) in bf16: those calls are compiled as they always were. Past
-that (the two whole operands, double-buffered, over 8 MiB: T = 16384 at
-D = 128 in bf16, where all three kernels want 16.5 MiB) the call asks for
-the limit it needs (``_compiler_params``), of the chip's 128 MiB. What still
-does not fit is a compile error, never a wrong answer; longer sequences go
-through ring attention, which calls this kernel per T/P block.
-tests/test_tpu_compile.py compiles the main-path shapes for a described
-v5e; tools/flash_bench.py times the three kernels alone on the chip.
+(forward) or Q and dO block (backward) in VMEM and only tiles the other
+operand, and the backward holds the head's dQ^T in float32 and its ``(1, T,
+D)`` dQ block beside them, so VMEM use grows with T. Under the v5e
+compiler's default limit for one kernel (16 MiB of scoped VMEM) the forward
+compiles up to (B,H,T,D) = (1,12,8192,64) in bf16 and float32,
+(1,32,8192,128) and (1,12,16384,64) in bf16, and the backward to half those
+lengths: those calls are compiled as they always were. Past that (what a
+grid step holds whole, double-buffered where it is an operand or a result,
+over 8 MiB) the call asks for the limit it needs (``_compiler_params``), of
+the chip's 128 MiB: 32 MiB forward and 48 backward at T = 16384 and D = 128
+in bf16, 32 backward at T = 8192. What still does not fit is a compile
+error, never a wrong answer; longer sequences go through ring attention,
+which calls this kernel per T/P block. tests/test_tpu_compile.py compiles
+the main-path shapes for a described v5e; tools/flash_bench.py times the
+two kernels alone on the chip.
 """
 
 from __future__ import annotations
@@ -60,22 +66,34 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tepdist_tpu.ops.pallas import _interpret
+from tepdist_tpu.telemetry import traced
 
 log = logging.getLogger(__name__)
+
+traced.declare(
+    "flash_bwd_calls", "differentiated flash calls a micro batch: each one's "
+    "backward pass is one kernel (``tepdist_flash_dkv``: dq, dk and dv)")
 
 _NEG_INF = -1e30
 _MIB = 2 ** 20
 
 
-def _compiler_params(T: int, D: int, itemsize: int):
+def _compiler_params(T: int, D: int, itemsize: int, more: int = 0):
     """None (the compiler's default scoped-VMEM limit, 16 MiB a kernel)
     where the two whole-sequence operands of a grid step, double-buffered,
-    leave room under it for the tiles, accumulators and score blocks; past
-    that, a limit of their size and 16 MiB more."""
-    whole = 2 * 2 * T * D * itemsize
+    and ``more`` bytes the call holds beside them (:func:`_bwd_holds`) leave
+    room under it for the tiles, accumulators and score blocks; past that, a
+    limit of their size and 16 MiB more."""
+    whole = 2 * 2 * T * D * itemsize + more
     if whole <= 8 * _MIB:
         return None
     return pltpu.CompilerParams(vmem_limit_bytes=whole + 16 * _MIB)
+
+
+def _bwd_holds(T: int, D: int, itemsize: int) -> int:
+    """Bytes the backward call holds whole beside q and dO: the head's dQ^T
+    in float32 and its dQ result block, double-buffered."""
+    return T * D * 4 + 2 * T * D * itemsize
 
 
 _NT = (((1,), (1,)), ((), ()))    # a @ b.T: contract the last dim of both
@@ -259,45 +277,29 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
     lse_ref[0, 0] = m + jnp.log(l)                    # [1, bq]
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
-               block_k: int, causal: bool, scale: float, q_block: int,
-               seq_len: int, window: Optional[int] = None):
-    """One Q block: dQ = scale * sum_j dS_j @ K_j, with P recomputed from
-    the saved LSE (no renormalisation pass needed). Tiled as the forward:
-    dQ accumulates as dQ^T [D, bq]."""
-    qi = pl.program_id(1)
-    q = q_ref[0]                                      # [bq, D]
-    bq, D = q.shape
-    fold = _fold_scale(q.dtype, scale)
-    if fold:
-        q = q * scale
-    do = do_ref[0]                                    # [bq, D]
-    lse = lse_ref[0, 0]                               # [1, bq]
-    delta = delta_ref[0, 0]
-
-    def step(j, dqt, causal_from, window_from=None):
-        k = k_ref[0, pl.dslice(j * block_k, block_k)]
-        v = v_ref[0, pl.dslice(j * block_k, block_k)]
-        st = _scores_t(k, q, scale, fold, causal_from, window_from)
-        pt = jnp.exp(st - lse)                        # exact softmax probs
-        dst = pt * (_dot(v, do, _NT) - delta)         # dS^T [bk, bq]
-        return dqt + _dot(k, dst.astype(k.dtype), _TN)
-
-    dqt = _over_key_blocks(step, jnp.zeros((D, bq), jnp.float32), causal,
-                           qi, q_block, block_k, seq_len // block_k, window)
-    dq_ref[0] = (dqt * scale).T.astype(dq_ref.dtype)
-
-
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, *, block_q: int, causal: bool, scale: float,
-                k_block: int, seq_len: int, window: Optional[int] = None):
-    """One K/V block: dV = sum_i P_i^T @ dO_i, dK = scale * sum_i dS_i^T @
-    Q_i, over the Q blocks that see it (``_over_query_blocks``)."""
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dqt_ref, *, block_q: int,
+                causal: bool, scale: float, k_block: int, seq_len: int,
+                window: Optional[int] = None):
+    """One K/V block against the Q blocks that see it
+    (``_over_query_blocks``), with P recomputed from the saved LSE (no
+    renormalisation pass needed). ``P_i^T`` and ``dS_i^T`` are made once a
+    pair and used three times: dV = sum_i P_i^T @ dO_i, dK = scale * sum_i
+    dS_i^T @ Q_i, and Q block ``i``'s dQ^T += K^T @ dS_i^T into
+    ``dqt_ref`` [T/bq, D, bq], the head's whole dQ^T in float32, which stays
+    in VMEM over the head's key blocks (the grid's inner axis, in rising
+    order: the order a walk over a Q block's key blocks sums in): zeroed at
+    the first, scaled, transposed and written as dQ [T, D] at the last."""
     ki = pl.program_id(1)
     k = k_ref[0]                                      # [bk, D]
     v = v_ref[0]
     bk, D = k.shape
     fold = _fold_scale(k.dtype, scale)
+    n_q = seq_len // block_q
+
+    @pl.when(ki == 0)
+    def _():
+        dqt_ref[...] = jnp.zeros(dqt_ref.shape, jnp.float32)
 
     def step(i, carry, causal_from, window_from=None):
         dk, dv = carry
@@ -306,28 +308,41 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             q = q * scale
         do = do_ref[0, pl.dslice(i * block_q, block_q)]
         st = _scores_t(k, q, scale, fold, causal_from, window_from)
-        pt = jnp.exp(st - lse_ref[0, i])              # P^T [bk, bq]
+        pt = jnp.exp(st - lse_ref[0, i])              # P^T [bk, bq], exact
         dv = dv + _dot(pt.astype(do.dtype), do)
         dst = pt * (_dot(v, do, _NT) - delta_ref[0, i])   # dS^T
-        return dk + _dot(dst.astype(q.dtype), q), dv
+        dst = dst.astype(q.dtype)
+        dqt_ref[i] += _dot(k_ref[0], dst, _TN)
+        return dk + _dot(dst, q), dv
 
     dk, dv = _over_query_blocks(
         step, (jnp.zeros((bk, D), jnp.float32),
                jnp.zeros((bk, D), jnp.float32)),
-        causal, ki, block_q, k_block, seq_len // block_q, window)
+        causal, ki, block_q, k_block, n_q, window)
     # With the scale folded into q, dk already carries it.
     if not fold:
         dk = dk * scale
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
+    @pl.when(ki == seq_len // k_block - 1)
+    def _():
+        def write(i, _):
+            dq_ref[0, pl.dslice(i * block_q, block_q)] = \
+                (dqt_ref[i] * scale).T.astype(dq_ref.dtype)
+            return _
+
+        jax.lax.fori_loop(0, n_q, write, None)
+
 
 def _kernel_name(which: str, causal, scale, heads: int,
                  window: Optional[int] = None, kv_heads: int = 0) -> str:
     """A stable name for each kernel: a device trace and the compiled HLO
-    show it, so forward, dQ and dK/dV are told apart by name. A window and
-    a smaller number of key/value heads follow the fields every call has
-    (``...__h32__w2048__kv4``); a call with neither is named as before."""
+    show it, so forward and backward are told apart by name (the backward
+    keeps ``dkv``, the name its dK/dV half had: trace readers go by it). A
+    window and a smaller number of key/value heads follow the fields every
+    call has (``...__h32__w2048__kv4``); a call with neither is named as
+    before."""
     name = f"tepdist_flash_{which}__c{int(causal)}__s{scale!r}__h{heads}"
     if window is not None:
         name += f"__w{window}"
@@ -416,32 +431,16 @@ def _bwd_call(causal, scale, block_q, block_k, interpret, res, do,
     delta = delta.reshape(rows)
 
     full_spec = pl.BlockSpec((1, T, D), lambda b, i: (b, 0, 0))
-    kv_full = _kv_spec((1, T, D), group, tiled=False)
     kv_block = _kv_spec((1, block_k, D), group, tiled=True)
-    row_block = pl.BlockSpec((1, 1, 1, block_q), lambda b, i: (b, i, 0, 0))
     row_full = pl.BlockSpec((1,) + rows[1:], lambda b, i: (b, 0, 0, 0))
-    vmem = _compiler_params(T, D, q.dtype.itemsize)
+    itemsize = q.dtype.itemsize
+    vmem = _compiler_params(T, D, itemsize, _bwd_holds(T, D, itemsize))
 
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, block_k=block_k, causal=causal,
-                          scale=scale, q_block=block_q, seq_len=T,
-                          window=window),
-        name=_kernel_name("dq", causal, scale, H, window, Hkv),
-        grid=(BH, T // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-            kv_full, kv_full,
-            pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-            row_block, row_block,
-        ],
-        out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
-        compiler_params=vmem,
-        interpret=interpret,
-    )(qf, kf, vf, dof, lsef, delta)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, block_q=block_q, causal=causal,
+    # One call for the whole backward pass, under the name and with the
+    # operands the dK/dV kernel had (a device trace's readers find it by
+    # them); dq joins its results, first.
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_bwd_kernel, block_q=block_q, causal=causal,
                           scale=scale, k_block=block_k, seq_len=T,
                           window=window),
         name=_kernel_name("dkv", causal, scale, H, window, Hkv),
@@ -451,13 +450,16 @@ def _bwd_call(causal, scale, block_q, block_k, interpret, res, do,
             full_spec, row_full, row_full,
         ],
         out_specs=[
+            full_spec,
             pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
+            jax.ShapeDtypeStruct((BH, T, D), q.dtype),
             jax.ShapeDtypeStruct((BH, T, D), k.dtype),
             jax.ShapeDtypeStruct((BH, T, D), v.dtype),
         ],
+        scratch_shapes=[pltpu.VMEM((T // block_q, D, block_q), jnp.float32)],
         compiler_params=vmem,
         interpret=interpret,
     )(qf, kf, vf, dof, lsef, delta)
@@ -473,20 +475,28 @@ def _bwd_call(causal, scale, block_q, block_k, interpret, res, do,
     return dq.reshape(shape), dk.reshape(shape), dv.reshape(shape)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, causal, scale, block_q, block_k, interpret, window):
+# ``layers``, the last static argument of the three calls below: the runs one
+# trace of the call stands for (``traced.stood_for()`` where it is called:
+# JAX may trace a rule after a walk's body has returned), for the forward
+# rules' count of ``flash_bwd_calls``.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, causal, scale, block_q, block_k, interpret, window,
+           layers):
     o, _ = _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
                      window)
     return o
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret, window):
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret, window,
+               layers):
+    traced.count("flash_bwd_calls", layers=layers)
     o, lse = _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
                        window)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res, do):
+def _flash_bwd(causal, scale, block_q, block_k, interpret, window, layers,
+               res, do):
     return _bwd_call(causal, scale, block_q, block_k, interpret, res, do,
                      window=window)
 
@@ -494,9 +504,9 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res, do):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def _flash_from(q, k, v, o, lse, causal, scale, block_q, block_k, interpret,
-                window):
+                window, layers):
     """``_flash`` where the forward kernel's two outputs are already in
     hand: the primal is ``o`` as given (no kernel), the backward is
     ``_flash``'s on the residuals ``_flash_fwd`` would have saved."""
@@ -504,32 +514,35 @@ def _flash_from(q, k, v, o, lse, causal, scale, block_q, block_k, interpret,
 
 
 def _flash_from_fwd(q, k, v, o, lse, causal, scale, block_q, block_k,
-                    interpret, window):
+                    interpret, window, layers):
+    traced.count("flash_bwd_calls", layers=layers)
     return o, (q, k, v, o, lse)
 
 
-def _flash_from_bwd(causal, scale, block_q, block_k, interpret, window, res,
-                    do):
-    return _flash_bwd(causal, scale, block_q, block_k, interpret, window,
-                      res, do) + (None, None)
+def _flash_from_bwd(*static_res_do):
+    return _flash_bwd(*static_res_do) + (None, None)
 
 
 _flash_from.defvjp(_flash_from_fwd, _flash_from_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_o_lse(q, k, v, causal, scale, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_o_lse(q, k, v, causal, scale, block_q, block_k, interpret,
+                 layers):
     """(o, lse) flash: the LSE is a first-class differentiable output —
     the per-block form ring attention merges across hops."""
     return _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret)
 
 
-def _flash_o_lse_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
+def _flash_o_lse_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+                     layers):
+    traced.count("flash_bwd_calls", layers=layers)
     o, lse = _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret)
     return (o, lse), (q, k, v, o, lse)
 
 
-def _flash_o_lse_bwd(causal, scale, block_q, block_k, interpret, res, cts):
+def _flash_o_lse_bwd(causal, scale, block_q, block_k, interpret, layers, res,
+                     cts):
     do, dlse = cts
     return _bwd_call(causal, scale, block_q, block_k, interpret, res, do,
                      dlse=dlse)
@@ -587,7 +600,7 @@ def flash_attention_with_lse(q, k, v, causal: bool = True,
         return o.astype(q.dtype), (m + jnp.log(jnp.maximum(l, 1e-30)))[..., 0]
     block_q, block_k = blocks
     return _flash_o_lse(q, k, v, causal, scale, block_q, block_k,
-                        _interpret(interpret))
+                        _interpret(interpret), traced.stood_for())
 
 
 def _default_block(T: int) -> Optional[int]:
@@ -650,7 +663,7 @@ class KeptForward:
     what the recording of the same block kept; the recomputation under
     ``jax.vjp``): the calls take their tuples back in order and are the
     call from a saved forward, no forward kernel and today's backward
-    kernels. Only a call traced where the context was entered takes part
+    kernel. Only a call traced where the context was entered takes part
     (:func:`hand_over`): the branches of a ``lax.cond``, an inner loop or
     ``jit`` can hand no array out, so a call in one runs as it does outside
     any walk, in both passes, unless its caller does the hand-over around
@@ -718,7 +731,7 @@ def flash_attention(q, k, v, causal: bool = True,
     key/value head ``h // (H / Hkv)`` through the kernels' index maps, so no
     broadcast copy of k or v is made; their gradients are the sums over each
     group. ``window`` (causal only): key j is visible to query i iff ``0 <=
-    i - j < window``; the three kernels skip the blocks wholly outside it. A
+    i - j < window``; both kernels skip the blocks wholly outside it. A
     window that reaches every earlier key (``window >= T``) is no window.
     Equal tiles that divide the window (the benchmark's: 512 in 2048) mask
     its far edge at a fixed place; the computed mask serves every other
@@ -730,7 +743,7 @@ def flash_attention(q, k, v, causal: bool = True,
 
     Inside a block that ``models/layers.py:scan_blocks`` walks, a call
     whose kernels run hands its forward pass to the walk
-    (:class:`KeptForward`); the values and the backward kernels are the
+    (:class:`KeptForward`); the values and the backward kernel are the
     same."""
     def attend(forward):
         return flash_attention_kept(q, k, v, forward, causal, scale, block_q,
@@ -756,8 +769,8 @@ def flash_attention_kept(q, k, v, forward, causal: bool = True,
       float32)``, not differentiable;
     - ``(o, lse)`` as that gave them for the same ``q, k, v``: attention
       from its saved forward. The primal is ``o`` (no kernel runs); its VJP
-      runs the dQ and dK/dV kernels on ``(q, k, v, o, lse)`` as
-      ``flash_attention``'s does, under the same names, bit for bit."""
+      runs the backward kernel on ``(q, k, v, o, lse)`` as
+      ``flash_attention``'s does, under the same name, bit for bit."""
     B, H, T, D = q.shape
     if k.shape != v.shape or k.shape[0] != B or k.shape[2:] != (T, D) \
             or H % k.shape[1]:
@@ -802,8 +815,9 @@ def flash_attention_kept(q, k, v, forward, causal: bool = True,
                                 jnp.repeat(v, group, axis=1), causal, scale)
     block_q, block_k = blocks
     static = (causal, scale, block_q, block_k, _interpret(interpret), window)
+    if forward == ():
+        return _fwd_call(q, k, v, *static)
+    static += (traced.stood_for(),)
     if forward is None:
         return _flash(q, k, v, *static)
-    if not forward:
-        return _fwd_call(q, k, v, *static)
     return _flash_from(q, k, v, *forward, *static)

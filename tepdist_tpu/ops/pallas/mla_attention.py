@@ -23,7 +23,8 @@ Three kernels, ``tepdist_mla_fwd__…``, ``tepdist_mla_dq__…`` and
 ``tepdist_mla_dkv__…`` (causal flag, scale and heads in the name as the
 flash kernels carry them; not ``tepdist_flash_*``, whose readers cost a
 call by one ``D``), under one ``jax.custom_vjp``. They are the flash
-kernels' algorithm on two more operands and share its pieces
+kernels' algorithm on two more operands (its backward as the pair of
+kernels it was before it became one: ROADMAP S17a) and share its pieces
 (``flash_attention.py``: the transposed score tile and its masks
 ``_scores_t``, the block walks ``_over_key_blocks`` /
 ``_over_query_blocks``, ``_resolve_blocks``, the row statistics' ``[B*H,

@@ -119,6 +119,7 @@ def primitive_by_name(name: str):
 import dataclasses as _dc
 
 import jax._src.pallas.core as _pl_core
+import jax._src.pallas.mosaic.core as _pl_tpu_core
 from jax import lax as _lax
 from jax._src.frozen_dict import FrozenDict as _FrozenDict
 
@@ -132,6 +133,9 @@ _ENUMS = {
     "Precision": _lax.Precision,
     "RandomAlgorithm": _lax.RandomAlgorithm,
     "PallasMemorySpace": _pl_core.MemorySpace,
+    # A kernel's ``scratch_shapes=[pltpu.VMEM(...)]`` (the flash backward's
+    # dQ^T accumulator): the scratch Ref's aval names the TPU's own enum.
+    "PallasTpuMemorySpace": _pl_tpu_core.MemorySpace,
 }
 
 

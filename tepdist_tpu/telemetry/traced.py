@@ -31,9 +31,10 @@ def declare(name: str, what: str) -> None:
 
 
 @contextlib.contextmanager
-def stands_for(layers: int):
+def stands_for(layers):
     """One trace of the code inside stands for ``layers`` runs of it; inside
-    another ``stands_for`` for that many of each of the outer's."""
+    another ``stands_for`` for that many of each of the outer's. (1 / 2
+    round a ``lax.cond``: each of two traced branches, of which one runs.)"""
     token = _LAYERS.set(_LAYERS.get() * layers)
     try:
         yield
@@ -41,7 +42,7 @@ def stands_for(layers: int):
         _LAYERS.reset(token)
 
 
-def stood_for() -> int:
+def stood_for():
     """The runs one trace stands for where this is called (1 outside any
     walk). A ``custom_vjp`` reads it where it is called and hands it to its
     rules, which JAX may trace after the walk's body has returned."""

@@ -552,14 +552,14 @@ def test_kernel_names_are_unchanged_without_a_window_and_carry_one_with():
         return jax.grad(f, argnums=(0, 1, 2))
 
     assert _kernel_names(grad_of(), q, q, q) == [
-        f"tepdist_flash_{w}__c1__s0.25__h4" for w in ("dkv", "dq", "fwd")]
+        f"tepdist_flash_{w}__c1__s0.25__h4" for w in ("dkv", "fwd")]
     assert _kernel_names(grad_of(window=64), q, q, q) == [
-        f"tepdist_flash_{w}__c1__s0.25__h4" for w in ("dkv", "dq", "fwd")]
+        f"tepdist_flash_{w}__c1__s0.25__h4" for w in ("dkv", "fwd")]
     assert _kernel_names(grad_of(window=32), q, kv, kv) == [
         f"tepdist_flash_{w}__c1__s0.25__h4__w32__kv2"
-        for w in ("dkv", "dq", "fwd")]
+        for w in ("dkv", "fwd")]
     assert _kernel_names(grad_of(window=32), q, q, q) == [
-        f"tepdist_flash_{w}__c1__s0.25__h4__w32" for w in ("dkv", "dq", "fwd")]
+        f"tepdist_flash_{w}__c1__s0.25__h4__w32" for w in ("dkv", "fwd")]
     # The plain call's program is the old one: three operands forward, six
     # backward, no window in any kernel's parameters.
     text = str(jax.make_jaxpr(grad_of())(q, q, q))
@@ -611,7 +611,7 @@ def test_attention_from_a_saved_forward_is_flash_attention(T, window, H, Hkv,
     assert "pallas_call" not in str(jax.make_jaxpr(part((o, lse)))(q, k, v))
     grads = jax.grad(lambda *a: jnp.sum(part((o, lse))(*a)), argnums=(0, 1, 2))
     assert [n.split("__")[0] for n in _kernel_names(grads, q, k, v)] == [
-        "tepdist_flash_dkv", "tepdist_flash_dq"]
+        "tepdist_flash_dkv"]
     assert set(_kernel_names(grads, q, k, v)) < set(_kernel_names(
         jax.grad(lambda *a: jnp.sum(flash(*a)), argnums=(0, 1, 2)), q, k, v))
 

@@ -108,8 +108,7 @@ def _kernels(fn, *args):
 
 
 def _flash_kernels(fn, *args):
-    """:func:`_kernels`, the flash kernels alone (``fwd`` / ``dq`` /
-    ``dkv``)."""
+    """:func:`_kernels`, the flash kernels alone (``fwd`` / ``dkv``)."""
     return collections.Counter({n: c for n, c in _kernels(fn, *args).items()
                                 if n.startswith("tepdist_flash_")})
 
@@ -140,10 +139,11 @@ def test_walked_blocks_keep_their_flash_forward(case):
     old_kernels = _flash_kernels(whole_remat, params, state, batch)
     assert _gauges("attn_kept_calls", "attn_kept_bytes") == (0, 0)
     assert _count(kernels, "fwd") == fwd_kernels
-    assert _count(old_kernels, "fwd") == 2 * _count(old_kernels, "dq")
-    for which in ("dq", "dkv"):
-        assert _count(kernels, which) == _count(old_kernels, which) \
-            == _count(old_kernels, "fwd") // 2
+    assert _count(old_kernels, "fwd") == 2 * _count(old_kernels, "dkv")
+    # One backward kernel a kind (PR 46): no dQ kernel on either path.
+    assert _count(kernels, "dq") == _count(old_kernels, "dq") == 0
+    assert _count(kernels, "dkv") == _count(old_kernels, "dkv") \
+        == _count(old_kernels, "fwd") // 2
     assert {n for n in kernels if "_fwd__" not in n} \
         == {n for n in old_kernels if "_fwd__" not in n}
 
@@ -235,4 +235,4 @@ def test_a_hand_over_carries_any_tuple_and_replays_in_order(dtype):
     assert "bitcast_convert_type" in str(jax.make_jaxpr(block)(q, k, v))
     assert _pallas_names(jax.grad(replayed_total, argnums=(0, 1, 2)),
                          q, k, v) == [
-        "tepdist_flash_dkv", "tepdist_flash_dq", "tepdist_topk_attn_bwd"]
+        "tepdist_flash_dkv", "tepdist_topk_attn_bwd"]

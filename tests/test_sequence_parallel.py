@@ -210,14 +210,20 @@ def test_flash_attention_backward_matches_reference():
                                        atol=3e-4, rtol=1e-3)
 
 
-def _dense_f32_attention(q, k, v, causal):
-    """(o, lse) of plain attention in float32, whatever the inputs' dtype."""
+def _dense_f32_attention(q, k, v, causal, window=None):
+    """(o, lse) of plain attention in float32, whatever the inputs' dtype;
+    k and v may have fewer heads than q (each read by a group of q's), and
+    under ``window`` key j is visible to query i iff ``0 <= i - j <
+    window``."""
     q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    k, v = (jnp.repeat(x, q.shape[1] // x.shape[1], axis=1) for x in (k, v))
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest")
     s = s / np.sqrt(q.shape[-1])
     if causal:
-        T = q.shape[2]
-        s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -1e30)
+        ahead = np.arange(q.shape[2])[:, None] - np.arange(q.shape[2])
+        seen = (ahead >= 0) if window is None else \
+            (ahead >= 0) & (ahead < window)
+        s = jnp.where(seen, s, -1e30)
     lse = jax.nn.logsumexp(s, axis=-1)
     p = jnp.exp(s - lse[..., None])
     return jnp.einsum("bhqk,bhkd->bhqd", p, v, precision="highest"), lse
@@ -242,8 +248,11 @@ def _rel_l2(got, want):
 
 
 # (T, block_q, block_k): one block, several blocks, block_q != block_k
-# either way round.
-_FLASH_TILINGS = [(64, 64, 64), (128, 32, 32), (128, 64, 32), (128, 32, 64)]
+# either way round; two and eight blocks (the backward kernel's dQ^T
+# accumulator is zeroed at a head's first key block and written at its last:
+# one step, neighbours, far apart).
+_FLASH_TILINGS = [(64, 64, 64), (128, 32, 32), (128, 64, 32), (128, 32, 64),
+                  (128, 64, 64), (256, 32, 32)]
 
 
 def _bf16_case(T, seed=5):
@@ -310,6 +319,60 @@ def test_flash_attention_with_lse_bf16(causal, tiling):
     for g, w in zip(got, want):
         assert g.dtype == jnp.bfloat16
         assert _rel_l2(g, w) < 1e-2
+
+
+# (T, block_q, block_k, window, query heads, key/value heads, causal): a
+# window the tiles divide (far edge and diagonal at fixed places) under 8
+# query heads a key/value head, one they do not divide, unequal tiles under a
+# window, 20 heads over 1 without one over eight key blocks, no causal mask
+# under grouped heads, and one and two key blocks.
+_FUSED_BACKWARD_CASES = [
+    (256, 64, 64, 128, 8, 1, True),
+    (256, 64, 64, 100, 4, 2, True),
+    (256, 32, 64, 96, 4, 2, True),
+    (256, 64, 32, 64, 2, 2, True),
+    (256, 32, 32, None, 20, 1, True),
+    (128, 32, 32, None, 8, 1, False),
+    (64, 64, 64, None, 4, 2, True),
+    (128, 64, 64, 64, 4, 1, True),
+]
+
+
+@pytest.mark.parametrize(
+    "case", _FUSED_BACKWARD_CASES,
+    ids=lambda c: "T%d-bq%d-bk%d-w%s-h%dkv%d-c%d" % c)
+def test_flash_attention_one_backward_kernel_bf16(case):
+    """dq, dk and dv of the one backward kernel (each (key block, query
+    block) pair's P^T and dS^T made once and used for all three) against
+    dense float32 attention of the same bf16 inputs, under the bound the
+    log-sum-exp test holds its gradients to; the program of the gradient
+    holds the forward and that one kernel."""
+    from tepdist_tpu.ops.pallas.flash_attention import flash_attention
+
+    T, bq, bk, window, H, Hkv, causal = case
+    ks = jax.random.split(jax.random.PRNGKey(T + H), 4)
+    q, do = (jax.random.normal(kk, (1, H, T, 32), jnp.float32)
+             .astype(jnp.bfloat16) for kk in ks[:2])
+    k, v = (jax.random.normal(kk, (1, Hkv, T, 32), jnp.float32)
+            .astype(jnp.bfloat16) for kk in ks[2:])
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, block_q=bq,
+                               block_k=bk, window=window, interpret=True)
+
+    o, vjp = jax.vjp(flash, q, k, v)
+    got = vjp(do)
+    ro, rvjp = jax.vjp(
+        lambda q, k, v: _dense_f32_attention(q, k, v, causal, window)[0],
+        *(x.astype(jnp.float32) for x in (q, k, v)))
+    assert _rel_l2(o, ro) < 5e-3
+    for name, g, w, x in zip(("dq", "dk", "dv"), got,
+                             rvjp(do.astype(jnp.float32)), (q, k, v)):
+        assert g.dtype == jnp.bfloat16 and g.shape == x.shape, name
+        assert _rel_l2(g, w) < 1e-2, name
+    text = str(jax.make_jaxpr(lambda *a: jax.vjp(flash, *a)[1](do))(q, k, v))
+    assert text.count("tepdist_flash_dkv__") == 1
+    assert "tepdist_flash_dq__" not in text
 
 
 def test_gpt2_flash_config_trains_like_einsum():

@@ -73,8 +73,8 @@ def _flash_call_shapes(text):
 ], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple)
     else getattr(v, "__name__", str(v)))
 def test_flash_kernels_compile_for_v5e(v5e_devices, shape, dtype, block):
-    """Forward, dQ and dK/dV kernels (via jax.grad), not interpreted; no
-    operand or result of a kernel is a row statistic padded 128-fold."""
+    """The forward and the backward kernel (via jax.grad), not interpreted;
+    no operand or result of a kernel is a row statistic padded 128-fold."""
     one_chip = SingleDeviceSharding(v5e_devices[0])
     x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
@@ -85,7 +85,7 @@ def test_flash_kernels_compile_for_v5e(v5e_devices, shape, dtype, block):
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x).compile().as_text()
     calls = _flash_call_shapes(text)
-    assert len(calls) == 3, sorted(calls)
+    assert len(calls) == 2, sorted(calls)
     for name, shapes in calls.items():
         assert "?" not in shapes, (name, shapes)
         assert not _PADDED_ROWS.search(shapes), (name, shapes)
@@ -99,14 +99,22 @@ def test_flash_kernels_compile_for_v5e(v5e_devices, shape, dtype, block):
 # key blocks wide) and none. There the two whole-sequence operands of a grid
 # step, double-buffered, are 16 MiB, the compiler's default limit for one
 # kernel: the calls ask for more (``flash_attention._compiler_params``), and
-# no call of the shapes above does.
+# no forward call of the shapes above does. The backward call holds the
+# head's dQ^T in float32 and its dQ block beside them (``_bwd_holds``): at
+# 8192 that is 16 MiB and it asks for 32, at 16384 for 48.
 @pytest.mark.parametrize("T,window", [(8192, 2048), (8192, None),
                                       (8192, 1000), (16384, 1024),
                                       (16384, None)])
 def test_windowed_grouped_query_flash_compiles_for_v5e(v5e_devices, T,
                                                        window):
-    from tepdist_tpu.ops.pallas.flash_attention import _compiler_params
+    from tepdist_tpu.ops.pallas.flash_attention import (
+        _bwd_holds,
+        _compiler_params,
+    )
     assert (_compiler_params(T, 128, 2) is None) == (T == 8192)
+    assert _bwd_holds(T, 128, 2) == T * 128 * (4 + 2 * 2)
+    assert _compiler_params(T, 128, 2, _bwd_holds(T, 128, 2)) \
+        .vmem_limit_bytes == (32 if T == 8192 else 48) * 2 ** 20
     one_chip = SingleDeviceSharding(v5e_devices[0])
     q = jax.ShapeDtypeStruct((1, 32, T, 128), jnp.bfloat16,
                              sharding=one_chip)
@@ -121,13 +129,50 @@ def test_windowed_grouped_query_flash_compiles_for_v5e(v5e_devices, T,
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, kv, kv).compile().as_text()
     calls = _flash_call_shapes(text)
-    assert len(calls) == 3, sorted(calls)
+    assert len(calls) == 2, sorted(calls)
     tag = ("" if window is None else f"__w{window}") + "__kv4"
     for name, shapes in calls.items():
         assert tag in name and "?" not in shapes, (name, shapes)
         assert not _PADDED_ROWS.search(shapes), (name, shapes)
         # k and v reach the kernels at their own head count: no broadcast.
         assert f"bf16[4,{T},128]" in shapes, (name, shapes)
+
+
+def test_the_backward_pass_is_one_kernel_at_16k_for_v5e(v5e_devices):
+    """Mellum2's global layer, [1, 32, 16384, 128] over 4 key/value heads:
+    the backward pass is the one ``tepdist_flash_dkv`` call, six operands
+    with q first and dq, dk, dv as its results, each a query head's
+    [T, D] in bf16 (no float32 dQ crosses HBM); it asks for 48 MiB of
+    scoped VMEM (q and dO whole, double-buffered, 16; the head's dQ^T in
+    float32 and its dQ block 16; 16 for the rest) where the forward asks
+    for 32; the compiled module holds no ``tepdist_flash_dq``."""
+    one_chip = SingleDeviceSharding(v5e_devices[0])
+    T = 16384
+    q = jax.ShapeDtypeStruct((1, 32, T, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 4, T, 128), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, block_q=512, block_k=512,
+                                       interpret=False).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    assert "tepdist_flash_dq" not in text
+    calls = _flash_call_shapes(text)
+    (name, shapes), = [c for c in calls.items() if "tepdist_flash_dkv" in c[0]]
+    results, _, operands = shapes.partition(" <- ")
+    assert re.findall(r"\w+\[[\d,]+\]", results) \
+        == [f"bf16[32,{T},128]"] * 3, shapes
+    assert re.findall(r"\w+\[[\d,]+\]", operands) == [
+        f"bf16[32,{T},128]", f"bf16[4,{T},128]", f"bf16[4,{T},128]",
+        f"bf16[32,{T},128]", "f32[32,32,1,512]", "f32[32,32,1,512]"], shapes
+    asked = {name.split("tepdist_flash_")[1][:3]: int(size) for name, size
+             in re.findall(r"^\s*(?:ROOT )?(%\S*tepdist_flash_\S+) = .*?"
+                           r'scoped_memory_configs":\[\{"memory_space":"1",'
+                           r'"offset":"0","size":"(\d+)"', text, re.M)}
+    assert asked == {"fwd": 32 * 2 ** 20, "dkv": 48 * 2 ** 20}, asked
 
 
 # The OLMoE cell's grouped matmuls: 8192 tokens x 8 experts a token in the
@@ -262,7 +307,7 @@ def test_flash_bf16_compiles_under_highest_matmul_precision(v5e_devices):
     with jax.default_matmul_precision("highest"):
         text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
             x, x, x).compile().as_text()
-    assert len(_flash_call_shapes(text)) == 3
+    assert len(_flash_call_shapes(text)) == 2
 
 
 def test_planned_step_with_kernel_in_scan_compiles_for_mesh(v5e_devices):
@@ -350,9 +395,9 @@ def test_a_walked_block_keeps_its_flash_forward_in_the_compiled_step(
     """A gradient-accumulation step over a stack of window, global, window
     layers (Mellum2's kinds at a reduced width, its head size, kernels not
     interpreted): the compiled step holds each kind's forward kernel once
-    (the layer loop's; the backward loop's recomputation runs none), its dQ
-    and dK/dV kernels once, and the kept ``o`` rides the walk's stack at the
-    kernel's own shape, a layer a slot."""
+    (the layer loop's; the backward loop's recomputation runs none), its one
+    backward kernel once and no dQ kernel, and the kept ``o`` rides the
+    walk's stack at the kernel's own shape, a layer a slot."""
     from tepdist_tpu.models import mellum
     from tepdist_tpu.parallel.sync_free import build_ga_step
     from tepdist_tpu.telemetry import metrics
@@ -384,6 +429,7 @@ def test_a_walked_block_keeps_its_flash_forward_in_the_compiled_step(
     text = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile() \
         .as_text()
     assert metrics().gauge("attn_kept_calls").value == 3
+    assert metrics().gauge("flash_bwd_calls").value == 3
     assert metrics().gauge("attn_kept_bytes").value \
         == 3 * 4 * T * (128 * 2 + 4)
     # Each layer holds a share of the experts: its rows out of the layout
@@ -392,10 +438,11 @@ def test_a_walked_block_keeps_its_flash_forward_in_the_compiled_step(
     assert "tepdist_rows_sum" in text and "tepdist_rows_tiled" in text
     calls = [line.split(" = ", 1)[0] for line in text.splitlines()
              if " custom-call(" in line and "tepdist_flash_" in line]
-    for which in ("fwd", "dq", "dkv"):
+    for which in ("fwd", "dkv"):
         kinds = sorted(re.sub(r".*(__h4(__w512)?__kv2).*", r"\1", c)
                        for c in calls if f"tepdist_flash_{which}__" in c)
         assert kinds == ["__h4__kv2", "__h4__w512__kv2"], (which, calls)
+    assert not [c for c in calls if "tepdist_flash_dq" in c], calls
     assert f"bf16[3,1,4,{T},128]" in text
 
 
@@ -445,7 +492,7 @@ def test_the_jamba_cells_step_compiles_for_v5e(v5e_devices, monkeypatch):
                  for a in jax.tree_util.tree_leaves(run))
     assert fused == stacks and 2 * 1_430_781_376 < stacks < 2.87e9
     assert fused / (fused + unfused) == pytest.approx(0.9715, abs=5e-4)
-    assert gauge("attn_kept_calls") == 1
+    assert gauge("attn_kept_calls") == 1 and gauge("flash_bwd_calls") == 1
     assert gauge("attn_kept_bytes") == 20 * 8192 * (128 * 2 + 4)
     assert gauge("ssm_scan_calls") == 26
     assert gauge("ssm_conv_calls") == 26
@@ -456,15 +503,16 @@ def test_the_jamba_cells_step_compiles_for_v5e(v5e_devices, monkeypatch):
     calls = [line.split(" = ", 1)[0].strip() for line in text.splitlines()
              if " custom-call(" in line]
     # A walk of 7 and a walk of 6: each the forward in the walk and in its
-    # recomputation, and the backward; the attention layer's three kernels,
+    # recomputation, and the backward; the attention layer's two kernels,
     # the forward once.
     assert sum("tepdist_ssm_fwd" in c for c in calls) == 4, calls
     assert sum("tepdist_ssm_bwd" in c for c in calls) == 2, calls
     assert sum("tepdist_conv_fwd" in c for c in calls) == 4, calls
     assert sum("tepdist_conv_bwd" in c for c in calls) == 2, calls
-    for which in ("fwd", "dq", "dkv"):
+    for which in ("fwd", "dkv"):
         names = [c for c in calls if f"tepdist_flash_{which}__" in c]
         assert len(names) == 1 and "__h20__kv1" in names[0], (which, calls)
+    assert not [c for c in calls if "tepdist_flash_dq" in c], calls
     # No array of the step has the sequence, the channels and the states
     # together: no whole-sequence scan.
     shapes = set(re.findall(r"\w+\[([\d,]+)\]", text))
@@ -589,6 +637,7 @@ def test_the_minicpm_sala_cells_step_compiles_for_v5e(v5e_devices,
     # The sparse layer's two hand-overs: o bf16 [1, T, 32, 128] with lse
     # float32 [1, 2, 16, T], and the sets int32 [1, 2, T, 64].
     assert gauge("attn_kept_calls") == 2 and gauge("ssm_scan_calls") == 0
+    assert gauge("flash_bwd_calls") == 0
     assert gauge("attn_kept_bytes") == T * (32 * 128 * 2 + 32 * 4) \
         + 2 * T * 64 * 4 == 289_406_976
 

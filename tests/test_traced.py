@@ -78,7 +78,8 @@ def test_every_declared_gauge_says_what_it_counts():
                  "ssm_boundary_bytes", "ssm_conv_calls",
                  "moe_rows_sum_calls", "lin_attn_calls", "topk_attn_calls",
                  "topk_attn_keys_per_query", "topk_attn_dense_calls",
-                 "ce_fused_chunks", "ga_fused_bytes", "ga_unfused_bytes"):
+                 "ce_fused_chunks", "ga_fused_bytes", "ga_unfused_bytes",
+                 "flash_bwd_calls"):
         assert len(traced.GROUP[name]) > 20, name
 
 
@@ -130,3 +131,29 @@ def test_a_plan_reports_a_gauge_nobody_above_the_loss_names(group, caplog):
                   lambda p, s, g: (p, s), 1)
     assert traced.values()[name] == 0
     assert metrics().gauge(name).value == 0
+
+
+def test_a_plan_logs_how_many_flash_calls_it_differentiates(group, caplog):
+    """``flash_bwd_calls`` (declared beside the kernel): a walked stack of 3
+    layers, each one flash call, differentiated once a micro batch's trace:
+    3 in the plan's log line, whose backward pass is one kernel a call."""
+    from tepdist_tpu.ops.pallas.flash_attention import flash_attention
+    from tepdist_tpu.train import plan_training
+
+    def loss(p, x):
+        def body(h, w):
+            q = (h @ w).reshape(-1, 16, 2, 8).transpose(0, 2, 1, 3)
+            o = flash_attention(q, q, q, block_q=8, block_k=8)
+            return h + o.transpose(0, 2, 1, 3).reshape(h.shape), None
+        return jnp.mean(scan_blocks(body, x, p["blocks"])[0] ** 2)
+
+    params = {"blocks": 0.1 * jnp.ones((3, 16, 16)), "bias": jnp.zeros((16,))}
+    x = jnp.ones((4, 16, 16))
+    with caplog.at_level("INFO", logger="tepdist_tpu.train"):
+        plan_training(lambda p, x: loss(p, x + p["bias"]), optax.sgd(1e-2),
+                      params, x, devices=jax.devices()[:1], explore=False,
+                      num_micro_batches=2)
+    line = next(r.getMessage() for r in caplog.records
+                if "the traced step" in r.getMessage())
+    assert re.search(r"\bflash_bwd_calls=3\b", line), line
+    assert re.search(r"\battn_kept_calls=3\b", line), line
