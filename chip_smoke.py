@@ -18,9 +18,9 @@ through the entry points a user calls:
            of the zoo at its ``smoke`` preset (``models/sarvam_mla.py``: a
            rank's 2 of 4 latent-attention heads at the published head widths,
            4 of 16 sigmoid-routed experts), 2 micro batches of one
-           1024-token sequence, 5 steps; asserts its three attention kernels
-           and the grouped matmuls are in the compiled step and that the
-           walk kept one forward a layer.
+           1024-token sequence, 5 steps; asserts its two attention kernels
+           (forward; the backward pass in one) and the grouped matmuls are in
+           the compiled step and that the walk kept one forward a layer.
   --chips 4  one child owning all four chips: ``plan_training(explore=True)``
            over ``jax.devices()`` at batch 16, 5 steps, then the same 5 steps
            on ``devices[:1]``; every device must hold a shard and the
@@ -307,11 +307,16 @@ def phase_mla(preset: str = "smoke", batch: int = 2, seq: int = 1024,
            f"phase M: the walk kept {gauges['attn_kept_calls']} forward "
            f"passes of {cfg.num_hidden_layers} layers")
     text = _compiled_text_with_kernel(tplan, platform, "phase M")
+    _check(gauges["mla_bwd_calls"] == cfg.num_hidden_layers,
+           f"phase M: {gauges['mla_bwd_calls']} backward calls counted for "
+           f"{cfg.num_hidden_layers} layers")
     if platform == "tpu":
-        for kernel in ("tepdist_mla_fwd", "tepdist_mla_dq",
-                       "tepdist_mla_dkv", "tepdist_gmm_fwd"):
+        for kernel in ("tepdist_mla_fwd", "tepdist_mla_dkv",
+                       "tepdist_gmm_fwd"):
             _check(kernel in text,
                    f"phase M: no {kernel} in the compiled step")
+        _check("tepdist_mla_dq" not in text,
+               "phase M: the backward pass is not one kernel")
     losses, first = _take_steps(lambda: tplan.step(tokens), "phase M")
     return {"phase": "M", "entry": "plan_training",
             "model": f"sarvam_mla-{preset}", "batch": batch, "seq": seq,

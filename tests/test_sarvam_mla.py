@@ -199,14 +199,57 @@ def test_kernels_in_bf16_stay_at_the_flash_kernels_distance():
         assert rel_l2(g, w) < 0.005
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("B,H,T,bq,bk,causal", [
+    (1, 2, 512, 64, 128, True), (1, 2, 512, 128, 64, True),
+    (1, 2, 384, 64, 128, False), (2, 3, 256, 128, 64, False),
+    (2, 3, 512, 128, 256, True)],
+    ids=["bq<bk", "bq>bk", "not-causal", "not-causal-heads", "heads"])
+def test_the_one_backward_call_gives_all_five_gradients(B, H, T, bq, bk,
+                                                        causal, dtype):
+    """Several query blocks and several key blocks a head, unequal tiles:
+    the head's dQ^T is zeroed at its first key block, gathers every key
+    block's part over the grid's inner axis and is written at the last; with
+    ``B * H > 1`` heads follow each other on the grid and each starts from
+    zero. All five gradients against dense float32 attention."""
+    *ops, do = operands(B, H, T, 32, 16, 24, dtype, seed=6)
+    assert T // bq > 1 and T // bk > 1 and bq != bk
+
+    def kernel(*xs):
+        return mla.mla_attention(*xs, causal=causal, scale=0.2, block_q=bq,
+                                 block_k=bk)
+
+    text = str(jax.make_jaxpr(lambda *xs: jax.vjp(kernel, *xs)[1](do))(*ops))
+    assert text.count("tepdist_mla_dkv__") == 1 \
+        and "tepdist_mla_dq" not in text
+    got = jax.vjp(kernel, *ops)[1](do)
+    f32 = [x.astype(jnp.float32) for x in ops]
+    want = jax.vjp(lambda *xs: dense_attention(*xs, 0.2, causal), *f32)[1](
+        do.astype(jnp.float32))
+    for name, g, w in zip(("dq_nope", "dq_rope", "dk_nope", "dk_rope", "dv"),
+                          got, want):
+        assert g.shape == w.shape and g.dtype == dtype, name
+        assert rel_l2(g, w) < (0.005 if dtype == jnp.bfloat16 else 1e-5), name
+    # A head's gradients do not depend on the heads before it on the grid.
+    if B * H > 1:
+        last = [x[-1:, -1:] if x.shape[1] > 1 else x[-1:] for x in ops]
+        alone = jax.vjp(kernel, *last)[1](do[-1:, -1:])
+        for i in (0, 1, 2, 4):       # dk_rope is a sum over the heads
+            np.testing.assert_array_equal(np.asarray(alone[i][0, 0]),
+                                          np.asarray(got[i][-1, -1]))
+
+
 def test_kernel_names_and_what_the_flash_kernels_keep():
-    """``tepdist_mla_<fwd|dq|dkv>__c1__s<scale>__h<heads>``, never
+    """``tepdist_mla_<fwd|dkv>__c1__s<scale>__h<heads>`` (the backward pass
+    is the one ``dkv`` call: no ``tepdist_mla_dq``), never
     ``tepdist_flash_*``; the flash kernels' names as they were."""
     *ops, do = operands(1, 2, 128, 16, 8, 12)
     text = str(jax.make_jaxpr(lambda *xs: jax.vjp(
         lambda *ys: mla.mla_attention(*ys, scale=0.25), *xs)[1](do))(*ops))
-    for which in ("fwd", "dq", "dkv"):
+    for which in ("fwd", "dkv"):
         assert f"tepdist_mla_{which}__c1__s0.25__h2" in text, which
+    assert "tepdist_mla_dq" not in text
     assert "tepdist_flash_" not in text
     assert fa._kernel_name("fwd", True, 0.125, 12) \
         == "tepdist_flash_fwd__c1__s0.125__h12"
@@ -378,21 +421,24 @@ def _ga_step(cfg, micro):
 def test_a_walk_keeps_one_forward_a_layer_and_the_gauges_say_so():
     """Two micro batches, three layers in two walks: the forward kernel
     runs once a layer and micro batch (its ``(o, lse)`` handed over, 3 calls
-    and their bytes), every walked leaf accumulates inside the layer loop,
+    and their bytes), each layer's backward pass is one kernel
+    (``mla_bwd_calls`` 3), every walked leaf accumulates inside the layer loop,
     and the noted gauges hold the heads held and one layer's latent."""
     cfg = dataclasses.replace(CFG, remat=True, loss_chunk=16)
     params = sarvam.stacked_init_params(cfg, KEY)
     tokens = sarvam.fake_batch(cfg, 4, 32, seed=8)
     tx, step = _ga_step(cfg, 2)
-    def kernels(step):       # fwd, dq, dkv: the places each stands in
+    def kernels(step):       # fwd, dkv: the places each stands in
         found = kernel_counts(step, params, tx.init(params), tokens)
+        assert not [name for name in found if "tepdist_mla_dq" in name]
         return [sum(n for name, n in found.items()
                     if name.startswith(f"tepdist_mla_{which}__"))
-                for which in ("fwd", "dq", "dkv")]
+                for which in ("fwd", "dkv")]
 
     standing = kernels(step)
     gauge = lambda n: metrics().gauge(n).value              # noqa: E731
     assert gauge("mla_fwd_calls") == 3 and gauge("attn_kept_calls") == 3
+    assert gauge("mla_bwd_calls") == 3
     Hh, Dv = cfg.heads_held[1], cfg.v_head_dim
     assert gauge("attn_kept_bytes") == 3 * 2 * Hh * 32 * (Dv * 4 + 4)
     assert gauge("mla_heads_held") == 2
@@ -404,13 +450,14 @@ def test_a_walk_keeps_one_forward_a_layer_and_the_gauges_say_so():
     assert fused == stacks and unfused > 0
     # A stack's walk traces its body once: the forward kernel stands once
     # in each walk's forward loop and nowhere in its backward loop.
-    assert standing == [2, 2, 2]
+    assert standing == [2, 2]
     # One micro batch: the plain checkpointed scan keeps nothing, and the
     # forward kernel stands in the forward loop and in the backward loop's
     # recomputation.
     _, plain = _ga_step(cfg, 1)
-    assert kernels(plain) == [4, 2, 2]
+    assert kernels(plain) == [4, 2]
     assert gauge("attn_kept_calls") == 0 and gauge("mla_fwd_calls") >= 3
+    assert gauge("mla_bwd_calls") == 3
 
 
 def test_the_projections_carry_their_scopes():

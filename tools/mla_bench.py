@@ -1,21 +1,29 @@
 """Time the latent-attention kernels alone, on the chip.
 
 Heads, length, the three head widths and tiles in; device microseconds a call
-of ``tepdist_mla_fwd``, ``_dq`` and ``_dkv`` out, from one ``jax.profiler``
-trace a tiling reduced by ``benchmark/trace_reduce.py``, each beside its
-roofline time (``benchmark/kernels/mla_cost.py``, ``benchmark/peaks.json``),
-and the relative L2 distance of ``o`` and the five gradients from dense
-float32 attention of the same inputs (the plain form: the shared rotary key
-joined to every head's keys, an explicit mask, a block of queries at a time
-so that no ``[T, T]`` array is held). The kernels are found and costed as the
-benchmark finds and costs them (``benchmark/layer_metrics/_mla.py``), so a
-call this tool cannot read is one the benchmark's readers cannot read either.
+of the forward (``tepdist_mla_fwd``) and of the backward pass (one kernel,
+``tepdist_mla_dkv``, since PR 47; the dQ and the dK/dV kernel of an older
+copy, each and summed) out, from one ``jax.profiler`` trace a tiling and copy
+reduced by ``benchmark/trace_reduce.py``, each kernel beside its roofline
+time as the benchmark costs it (``benchmark/layer_metrics/_mla.py``,
+``benchmark/kernels/mla_cost.py``, ``benchmark/peaks.json``: a call this
+tool cannot read is one the benchmark's readers cannot read either) and the
+backward pass beside the **whole** pass's (``backward_dq`` +
+``backward_dkv`` operations, every operand and result across HBM once), and
+the relative L2 distance of ``o`` and the five gradients from dense float32
+attention of the same inputs (the plain form: the shared rotary key joined
+to every head's keys, an explicit mask, a block of queries at a time so that
+no ``[T, T]`` array is held).
 
 No benchmark cell runs this; it is for work on the kernels. There is no CPU
-fallback: without a TPU it exits 2.
+fallback: without a TPU it exits 2. ``--impl`` times another copy of
+``mla_attention.py`` (the parent commit's, say) in the same process, so that
+two versions are read on one chip in one call, and says whether its ``o``
+and five gradients are the first copy's bit for bit.
 
 Run: chiprun -- python tools/mla_bench.py [--heads 1,16,16384]
      [--widths 128,64,128] [--dtype bf16] [--block 512,256,512x1024]
+     [--impl old=path/to/mla_attention.py]
 """
 
 from __future__ import annotations
@@ -30,6 +38,19 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 QUERY_BLOCK = 512
+NAMES = ("o", "dq_nope", "dq_rope", "dk_nope", "dk_rope", "dv")
+
+
+def whole_backward(heads, widths, dtype_bytes: int) -> dict:
+    """Operations and bytes of the whole backward pass: both halves'
+    matmuls, the dK/dV half's traffic and the two dQ results."""
+    from benchmark.kernels import mla_cost
+    B, H, T = heads
+    dq, dkv = (getattr(mla_cost, kind)(heads, widths, dtype_bytes)
+               for kind in ("backward_dq", "backward_dkv"))
+    return {"ops": dq["ops"] + dkv["ops"],
+            "bytes": dkv["bytes"]
+            + B * H * T * (widths[0] + widths[1]) * dtype_bytes}
 
 
 def dense_reference(operands, do, scale: float):
@@ -71,31 +92,35 @@ def dense_reference(operands, do, scale: float):
     return (o,) + vjp(do.astype(jnp.float32))
 
 
-def time_tiling(block_q, block_k, operands, do, scale, want, args, peaks,
-                trace_root):
+def time_tiling(label, module, block_q, block_k, operands, do, scale, want,
+                args, peaks, trace_root, first):
     """One traced window of ``args.iters`` gradient calls (each runs the
-    forward, the dQ and the dK/dV kernel once) at one tiling."""
+    forward and the backward pass once) of one copy at one tiling.
+    ``first``: the first copy's record and results at this tiling, or
+    None."""
     import jax
+    import jax.numpy as jnp
 
     from benchmark import trace_reduce
     from benchmark.kernels import mla_cost
     from benchmark.layer_metrics import _mla
     from benchmark.lib import tracing
-    from tepdist_tpu.ops.pallas.mla_attention import mla_attention
     from tools.flash_bench import rel_l2
+
+    heads = operands[0].shape[:3]
+    widths = tuple(operands[i].shape[-1] for i in (0, 1, 4))
 
     @jax.jit
     def fwd_bwd(operands, do):
-        o, vjp = jax.vjp(lambda *xs: mla_attention(
+        o, vjp = jax.vjp(lambda *xs: module.mla_attention(
             *xs, causal=True, scale=scale, block_q=block_q, block_k=block_k,
             interpret=False), *operands)
         return (o,) + vjp(do)
 
     got = jax.block_until_ready(fwd_bwd(operands, do))   # compiles
-    errors = want and {n: rel_l2(g, w) for n, g, w in zip(
-        ("o", "dq_nope", "dq_rope", "dk_nope", "dk_rope", "dv"), got, want)}
+    errors = want and {n: rel_l2(g, w) for n, g, w in zip(NAMES, got, want)}
 
-    path = os.path.join(trace_root, f"{block_q}x{block_k}")
+    path = os.path.join(trace_root, f"{label}_{block_q}x{block_k}")
     tracing.discard(path)
     jax.profiler.start_trace(path)
     for _ in range(args.iters):
@@ -105,10 +130,11 @@ def time_tiling(block_q, block_k, operands, do, scale, want, args, peaks,
     summary = tracing.reduce_trace(path)
     tracing.discard(path)
 
-    record = {"block_q": block_q, "block_k": block_k, "heads": args.heads,
-              "widths": args.widths, "dtype": args.dtype,
-              "iters": args.iters, "rel_l2_vs_dense_f32": errors,
-              "kernels": {}}
+    record = {"impl": label, "block_q": block_q, "block_k": block_k,
+              "heads": args.heads, "widths": args.widths,
+              "dtype": args.dtype, "iters": args.iters,
+              "rel_l2_vs_dense_f32": errors, "kernels": {}}
+    passes = {}
     for text, secs, calls in summary.ops(_mla.is_mla):
         found = _mla.call_cost(text)
         if found is None:
@@ -118,10 +144,30 @@ def time_tiling(block_q, block_k, operands, do, scale, want, args, peaks,
         least = mla_cost.roofline_seconds(cost, peaks)
         record["kernels"][kind] = {
             "calls": calls, "us_per_call": 1e6 * secs / calls,
+            "results": text.partition(" custom-call(")[0].count("["),
             "ops": cost["ops"], "bytes": cost["bytes"],
             "roofline_us": 1e6 * least["seconds"], "bound": least["bound"],
             "roofline_share_pct": 100.0 * least["seconds"] * calls / secs,
             "name": trace_reduce.short_name(text)}
+        which = "forward" if kind == "forward" else "backward"
+        passes[which] = passes.get(which, 0.0) + secs / calls
+    itemsize = jnp.dtype(do.dtype).itemsize
+    whole = {"forward": mla_cost.forward(heads, widths, itemsize),
+             "backward": whole_backward(heads, widths, itemsize)}
+    for which, secs in passes.items():
+        least = mla_cost.roofline_seconds(whole[which], peaks)
+        record[which] = {
+            "us_per_call": 1e6 * secs, "ops": whole[which]["ops"],
+            "roofline_us": 1e6 * least["seconds"], "bound": least["bound"],
+            "roofline_share_pct": 100.0 * least["seconds"] / secs}
+    if first is not None:
+        record["same_bits_as_first"] = {
+            n: bool(jnp.array_equal(g, f))
+            for n, g, f in zip(NAMES, got, first[1])}
+        record["first_over_this"] = {
+            which: first[0][which]["us_per_call"]
+            / record[which]["us_per_call"]
+            for which in passes if which in first[0]}
     # Everything else the gradient call runs on the device (``delta``, the
     # sum of the shared key's gradient over the heads, layout copies).
     others = sorted(summary.ops(lambda t: not _mla.is_mla(t)),
@@ -130,7 +176,7 @@ def time_tiling(block_q, block_k, operands, do, scale, want, args, peaks,
         1e6 * sum(s for _, s, _ in others) / args.iters
     record["other_ops"] = [[text[:160], 1e6 * s / args.iters]
                            for text, s, _ in others[:4]]
-    return record
+    return record, got
 
 
 def main(argv=None) -> int:
@@ -146,6 +192,11 @@ def main(argv=None) -> int:
     ap.add_argument("--check", type=int, default=1,
                     help="0 skips the dense float32 reference")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--impl", action="append", default=[],
+                    metavar="LABEL=FILE",
+                    help="another mla_attention.py to time beside the "
+                         "checkout's own and compare with it bit for bit "
+                         "(repeatable)")
     ap.add_argument("--out", default=None, help="also write the records "
                     "as JSON lines to this file")
     args = ap.parse_args(argv)
@@ -154,6 +205,7 @@ def main(argv=None) -> int:
     import jax.numpy as jnp
 
     from benchmark.lib import device
+    from tools.flash_bench import load_impl
 
     devices = device.own_chips(1)
     peaks = device.peaks_for(devices[0].device_kind,
@@ -170,22 +222,32 @@ def main(argv=None) -> int:
     want = jax.block_until_ready(jax.jit(dense_reference, static_argnums=2)(
         operands, do, scale)) if args.check else None
     trace_root = os.path.join(ROOT, ".bench_trace", "mla_bench")
+    impls = [("tree", os.path.join(ROOT, "tepdist_tpu", "ops", "pallas",
+                                   "mla_attention.py"))]
+    impls += [tuple(item.split("=", 1)) for item in args.impl]
+    modules = [(label, load_impl(label, path)) for label, path in impls]
     for item in args.block.split(","):
         bq, _, bk = item.partition("x")
-        try:
-            record = time_tiling(int(bq), int(bk or bq), tuple(operands), do,
-                                 scale, want, args, peaks, trace_root)
-        except Exception as e:  # noqa: BLE001 — one refused tiling must
-            # not cost the call that times the others
-            record = {"block": item, "error": repr(e)[:2000]}
-        record["device"] = devices[0].device_kind
-        line = json.dumps(record)
-        print(line, flush=True)
-        if args.out:
-            os.makedirs(os.path.dirname(os.path.abspath(args.out)),
-                        exist_ok=True)
-            with open(args.out, "a") as f:
-                f.write(line + "\n")
+        first = None
+        for label, module in modules:
+            try:
+                record, got = time_tiling(
+                    label, module, int(bq), int(bk or bq), tuple(operands),
+                    do, scale, want, args, peaks, trace_root, first)
+                first = (record, got) if first is None else first
+                del got
+            except Exception as e:  # noqa: BLE001 — one refused variant
+                # must not cost the call that times the others
+                record = {"impl": label, "block": item,
+                          "error": repr(e)[:2000]}
+            record["device"] = devices[0].device_kind
+            line = json.dumps(record)
+            print(line, flush=True)
+            if args.out:
+                os.makedirs(os.path.dirname(os.path.abspath(args.out)),
+                            exist_ok=True)
+                with open(args.out, "a") as f:
+                    f.write(line + "\n")
     return 0
 
 
