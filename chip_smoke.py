@@ -1,6 +1,6 @@
 """Quickest proof that the main path still starts on the chip.
 
-    python chip_smoke.py            # one chip: phases A, B and M
+    python chip_smoke.py            # one chip: phases A, B, M and Z
     python chip_smoke.py --chips 4  # one host, four chips: that phase only
 
 Drives GPT-2 117M at published widths (12 x 768 x 12 heads, vocab 50257,
@@ -21,6 +21,12 @@ through the entry points a user calls:
            1024-token sequence, 5 steps; asserts its two attention kernels
            (forward; the backward pass in one) and the grouped matmuls are in
            the compiled step and that the walk kept one forward a layer.
+  phase Z  the same for ``models/zaya.py`` at its ``smoke`` preset (8 query
+           heads over 2 of the published 128, 16 experts, one a token), 2
+           micro batches of one 1024-token sequence, 5 steps; asserts the
+           mixing kernel pair, the flash kernels at 8 heads over 2 and the
+           grouped matmuls are in the compiled step, that the walk carried
+           the router's state and kept one flash forward a layer.
   --chips 4  one child owning all four chips: ``plan_training(explore=True)``
            over ``jax.devices()`` at batch 16, 5 steps, then the same 5 steps
            on ``devices[:1]``; every device must hold a shard and the
@@ -284,44 +290,88 @@ def phase_b(cfg_name: str = "117M", batch: int = 8, seq: int = 1024,
 # Phase M: the zoo's newest model through plan_training, its kernels compiled.
 # ---------------------------------------------------------------------------
 
-def phase_mla(preset: str = "smoke", batch: int = 2, seq: int = 1024,
-              platform: str = "tpu") -> dict:
-    from tepdist_tpu.models import sarvam_mla
+def _plan_zoo_model(model, cfg, batch: int, seq: int, platform: str):
+    """``plan_training`` of one of the zoo's expert models (stacked
+    parameters from the fixed seed, 2 micro batches, the bias's optimizer)
+    on one chip: (devices, plan, tokens, the traced step's gauges)."""
     from tepdist_tpu.optim import make_optimizer
     from tepdist_tpu.telemetry import traced
     from tepdist_tpu.train import plan_training
 
     devices = _own_devices(platform)[:1]
     configure_compile_cache()
-    cfg = dataclasses.replace(sarvam_mla.CONFIGS[preset], remat=True)
-    params = sarvam_mla.stacked_init_params(cfg, jax.random.PRNGKey(SEED))
-    tokens = sarvam_mla.fake_batch(cfg, batch, seq, seed=SEED)
+    params = model.stacked_init_params(cfg, jax.random.PRNGKey(SEED))
+    tokens = model.fake_batch(cfg, batch, seq, seed=SEED)
     tplan = plan_training(
-        lambda p, t: sarvam_mla.loss_fn(p, t, cfg),
+        lambda p, t: model.loss_fn(p, t, cfg),
         make_optimizer({"name": "adamw_bf16_router_bias",
                         "learning_rate": 1e-3, "bias_rate": 0.001}),
         params, tokens, devices=devices, explore=False, num_micro_batches=2)
-    gauges = traced.values()
-    _check(gauges["attn_kept_calls"] == gauges["mla_fwd_calls"]
-           == cfg.num_hidden_layers,
-           f"phase M: the walk kept {gauges['attn_kept_calls']} forward "
-           f"passes of {cfg.num_hidden_layers} layers")
-    text = _compiled_text_with_kernel(tplan, platform, "phase M")
-    _check(gauges["mla_bwd_calls"] == cfg.num_hidden_layers,
-           f"phase M: {gauges['mla_bwd_calls']} backward calls counted for "
-           f"{cfg.num_hidden_layers} layers")
+    return devices, tplan, tokens, traced.values()
+
+
+def _step_zoo_model(phase: str, model: str, devices, tplan, tokens, gauges,
+                    platform: str, kernels) -> dict:
+    """``kernels`` are in the compiled step (on the chip); five steps; the
+    phase's record."""
+    text = _compiled_text_with_kernel(tplan, platform, f"phase {phase}")
     if platform == "tpu":
-        for kernel in ("tepdist_mla_fwd", "tepdist_mla_dkv",
-                       "tepdist_gmm_fwd"):
+        for kernel in kernels:
             _check(kernel in text,
-                   f"phase M: no {kernel} in the compiled step")
-        _check("tepdist_mla_dq" not in text,
-               "phase M: the backward pass is not one kernel")
-    losses, first = _take_steps(lambda: tplan.step(tokens), "phase M")
-    return {"phase": "M", "entry": "plan_training",
-            "model": f"sarvam_mla-{preset}", "batch": batch, "seq": seq,
+                   f"phase {phase}: no {kernel} in the compiled step")
+        _check("tepdist_mla_dq" not in text and "tepdist_flash_dq" not in text,
+               f"phase {phase}: a backward pass is not one kernel")
+    losses, first = _take_steps(lambda: tplan.step(tokens), f"phase {phase}")
+    return {"phase": phase, "entry": "plan_training", "model": model,
+            "batch": tokens.shape[0], "seq": tokens.shape[1] - 1,
             **_device_record(devices), "losses": losses, **gauges,
             "setup_first_step_seconds": first}
+
+
+def phase_mla(preset: str = "smoke", batch: int = 2, seq: int = 1024,
+              platform: str = "tpu") -> dict:
+    from tepdist_tpu.models import sarvam_mla
+
+    cfg = dataclasses.replace(sarvam_mla.CONFIGS[preset], remat=True)
+    devices, tplan, tokens, gauges = _plan_zoo_model(
+        sarvam_mla, cfg, batch, seq, platform)
+    layers = cfg.num_hidden_layers
+    _check(gauges["attn_kept_calls"] == gauges["mla_fwd_calls"]
+           == gauges["mla_bwd_calls"] == layers,
+           f"phase M: the walk kept {gauges['attn_kept_calls']} forward "
+           f"passes and counted {gauges['mla_bwd_calls']} backward calls "
+           f"of {layers} layers")
+    return _step_zoo_model(
+        "M", f"sarvam_mla-{preset}", devices, tplan, tokens, gauges, platform,
+        ("tepdist_mla_fwd", "tepdist_mla_dkv", "tepdist_gmm_fwd"))
+
+
+# ---------------------------------------------------------------------------
+# Phase Z: compressed attention's mixing kernels and a top-1 expert layer
+# whose router's state the walk carries.
+# ---------------------------------------------------------------------------
+
+def phase_zaya(preset: str = "smoke", batch: int = 2, seq: int = 1024,
+               platform: str = "tpu") -> dict:
+    from tepdist_tpu.models import zaya
+
+    cfg = zaya.CONFIGS[preset]
+    devices, tplan, tokens, gauges = _plan_zoo_model(
+        zaya, cfg, batch, seq, platform)
+    layers = cfg.num_hidden_layers
+    _check(gauges["attn_kept_calls"] == layers
+           and gauges["cca_mix_calls"] == 2 * layers,
+           f"phase Z: the walk kept {gauges['attn_kept_calls']} forward "
+           f"passes and ran the mixing {gauges['cca_mix_calls']} times a "
+           f"micro batch over {layers} layers")
+    _check(gauges["router_carry_bytes"]
+           == batch // 2 * seq * cfg.router_hidden_size * 4,
+           f"phase Z: the router's carry reads "
+           f"{gauges['router_carry_bytes']} bytes")
+    return _step_zoo_model(
+        "Z", f"zaya-{preset}", devices, tplan, tokens, gauges, platform,
+        ("tepdist_cca_mix_fwd", "tepdist_cca_mix_bwd", "tepdist_flash_fwd",
+         "tepdist_gmm_fwd"))
 
 
 # ---------------------------------------------------------------------------
@@ -385,7 +435,7 @@ def phase_four(cfg_name: str = "117M", batch: int = 16, seq: int = 1024,
 
 
 CHILD_PHASES = {"phase_b": phase_b, "phase_four": phase_four,
-                "phase_mla": phase_mla}
+                "phase_mla": phase_mla, "phase_zaya": phase_zaya}
 
 
 def _run_child(phase: str) -> dict:
@@ -432,6 +482,7 @@ def main() -> None:
                f"{holder['losses'][0]} differ by more than 1e-2 relative "
                "(same weights, same tokens, no update yet)")
         _emit(_run_child("phase_mla"))
+        _emit(_run_child("phase_zaya"))
     _check(holder["platform"] == "tpu" and holder["n_devices"] == args.chips,
            f"ran on {holder['n_devices']} {holder['platform']} device(s), "
            f"wanted {args.chips} tpu")

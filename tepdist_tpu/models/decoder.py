@@ -102,7 +102,9 @@ def walk_layers(layer: Callable, x, params, stacks: Sequence[Stack],
     """``x`` through every layer in order, whichever the layout:
     ``layer(blk, h, row) -> h`` with ``rows[i]`` what layer ``i`` is given
     besides its parameters: its kind (hashable), or a NumPy row of numbers
-    that are no parameter and have no gradient.
+    that are no parameter and have no gradient. ``x`` (and every ``h``) is
+    the walk's carry, an array or a pytree of arrays (``scan_blocks``): a
+    model whose layers hand on more than the residual stream walks the pair.
 
     ``l{i}`` dicts: a Python loop, each layer under ``jax.checkpoint`` with
     ``remat``; a kind is static, a row of numbers an array. Stacked: a

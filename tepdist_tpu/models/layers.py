@@ -95,6 +95,12 @@ def scan_blocks(body, x, blocks, kinds=None, remat: bool = True):
     out (below), for its attention kernels' output and log-sum-exp. Returns
     ``(x, ys)``. ``remat=False`` is the plain ``jax.lax.scan(body, ...)``.
 
+    **The carry ``x`` is a pytree of arrays**, as ``lax.scan``'s is: one
+    array for most models, ``(x, r)`` for one whose layers hand a second
+    state on (``models/zaya.py``: the router's). Every path below treats it
+    by ``tree_map`` and asks nothing of its structure; a block's kept input
+    is then the whole carry as the block received it.
+
     ``kinds`` (a NumPy array, one entry a layer, no parameter and no
     gradient) makes it ``body(h, block, kind)``: layers of one shape and
     unequal kind (a window here, none there) in one stack, the body
@@ -107,9 +113,11 @@ def scan_blocks(body, x, blocks, kinds=None, remat: bool = True):
     Under a :class:`BlockGradSink` that holds accumulators for ``blocks``
     (``parallel/sync_free.py:build_ga_step`` with several micro batches) the
     backward pass is written out: a reverse scan that recomputes the block
-    under ``jax.vjp`` from its saved input, carries ``(dx, accumulators)``
-    and adds layer ``l``'s weight gradient into slice ``l`` in place, so the
-    stacked gradient of a micro batch is never built. Same values as the
+    under ``jax.vjp`` from its saved input (the carry, all its arrays),
+    carries ``(dx, accumulators)`` with ``dx`` the carry's cotangent, array
+    for array, and adds layer ``l``'s weight gradient into slice ``l`` in
+    place, so the stacked gradient of a micro batch is never built. Same
+    values as the
     plain scan's gradient added to the accumulator afterwards: the layer's
     gradient is rounded to its dtype, then the sum to the accumulator's.
 
@@ -162,11 +170,14 @@ def scan_blocks(body, x, blocks, kinds=None, remat: bool = True):
 
         one = jax.tree_util.tree_map(lambda a: aval(a, 1), blocks)
         closed, (h, ys) = jax.make_jaxpr(body, return_shape=True)(
-            aval(x), one if kinds is None else (one, aval(kinds, 1)))
+            jax.tree_util.tree_map(aval, x),
+            one if kinds is None else (one, aval(kinds, 1)))
         if not any(isinstance(c, jax.core.Tracer) for c in closed.consts):
             sink.walks.append(keys)
-            return jnp.zeros(h.shape, h.dtype), jax.tree_util.tree_map(
-                lambda y: jnp.zeros((n,) + y.shape, y.dtype), ys)
+            return jax.tree_util.tree_map(
+                lambda a: jnp.zeros(a.shape, a.dtype), h), \
+                jax.tree_util.tree_map(
+                    lambda y: jnp.zeros((n,) + y.shape, y.dtype), ys)
     return jax.lax.scan(jax.checkpoint(body), x,
                         blocks if kinds is None else (blocks, kinds))
 
@@ -183,6 +194,11 @@ def rematerialised_whole(body):
 
 
 def _walk_accumulating(body, x, blocks, acc, kinds):
+    """:func:`scan_blocks` with the backward written out. ``x``: the carry,
+    a pytree; the forward keeps each block's input carry whole (stacked a
+    layer, array for array) and the backward carries its cotangent."""
+    n_layers = jax.tree_util.tree_leaves(blocks)[0].shape[0]
+
     def layers_of(blocks):
         return blocks if kinds is None else (blocks, kinds)
 
@@ -201,9 +217,8 @@ def _walk_accumulating(body, x, blocks, acc, kinds):
             return out, (h, y, keep.kept)
 
         out, (inputs, ys, kept) = jax.lax.scan(step, x, layers_of(blocks))
-        layers = inputs.shape[0]
-        traced.count("moe_rows_sum_calls", layers * rows_sum_calls[0])
-        traced.count("attn_kept_calls", layers * len(kept))
+        traced.count("moe_rows_sum_calls", n_layers * rows_sum_calls[0])
+        traced.count("attn_kept_calls", n_layers * len(kept))
         traced.count("attn_kept_bytes", sum(
             a.nbytes for a in jax.tree_util.tree_leaves(kept)))
         return (out, ys), (inputs, kept, blocks, acc)
@@ -211,7 +226,7 @@ def _walk_accumulating(body, x, blocks, acc, kinds):
     def bwd(res, cts):
         inputs, kept, blocks, acc = res
         d_out, d_ys = cts
-        layers = jnp.arange(inputs.shape[0])
+        layers = jnp.arange(n_layers)
 
         def step(carry, per_layer):
             dh, acc = carry
@@ -330,13 +345,18 @@ def yarn_table(head_dim: int, theta: float, factor: float,
                      float(attention_factor), "rope_yarn")
 
 
-def rope(x, table: Union[float, RopeTable], start=0):
+def rope(x, table: Union[float, RopeTable], start=0,
+         rotary_dim: Optional[int] = None):
     """Rotary embedding over [B, H, T, hd] (rotate-half formulation) at
     positions ``start ..`` (a chunk of a sequence: its first position).
     ``table``: the plain table's ``theta`` (pair ``i`` turns by ``theta **
-    (-i / half)`` a position), or a :class:`RopeTable`."""
+    (-i / half)`` a position), or a :class:`RopeTable`. ``rotary_dim``: the
+    head's first channels that are rotated, ``half = rotary_dim / 2`` pairs
+    (channel ``i`` with ``i + half``); the channels past them pass as they
+    are (a partial rotary embedding). None: the whole head."""
     B, H, T, hd = x.shape
-    half = hd // 2
+    rotary_dim = hd if rotary_dim is None else rotary_dim
+    half = rotary_dim // 2
     plain = not isinstance(table, RopeTable)
     with jax.named_scope("rope_plain" if plain else table.name):
         if plain:
@@ -352,11 +372,13 @@ def rope(x, table: Union[float, RopeTable], start=0):
         sin = jnp.sin(angles)[None, None, :, :]
         if not plain and table.scale != 1.0:
             cos, sin = cos * table.scale, sin * table.scale
-        x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(
-            jnp.float32)
+        x1, x2 = x[..., :half].astype(jnp.float32), \
+            x[..., half:rotary_dim].astype(jnp.float32)
         out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
-                              axis=-1)
-        return out.astype(x.dtype)
+                              axis=-1).astype(x.dtype)
+        if rotary_dim < hd:
+            out = jnp.concatenate([out, x[..., rotary_dim:]], axis=-1)
+        return out
 
 
 def gqa_heads(blk, a, *, n_head: int, n_kv_head: int, head_dim: int,

@@ -23,7 +23,8 @@ VMEM scratch while the channel block's time blocks go by. The residuals of
 the ``custom_vjp`` are the operands ``u``, ``w``, ``b``; the backward makes
 the pre-activation again.
 
-**How the halo is carried.** The grid is ``(batch, channel blocks, time
+**How the halo is carried** (the tile helpers are ``_tiles.py``'s, shared
+with ``cca_mix.py``). The grid is ``(batch, channel blocks, time
 blocks)``, time innermost and sequential. Inside a grid step a ``fori_loop``
 walks the block in strips of ``STRIP`` rows, a strip as float32 ``[8, bd]``
 tiles in registers. A row shifted down by ``s`` is a sublane roll of its tile
@@ -68,6 +69,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tepdist_tpu.ops.pallas import _interpret
+from tepdist_tpu.ops.pallas._tiles import (
+    STRIP,
+    TILE,
+    _down,
+    _rolls,
+    _row,
+    _strip,
+    _tiles,
+    _up,
+)
 from tepdist_tpu.ops.pallas.selective_scan import (
     LANES,
     _block_d,
@@ -76,8 +87,6 @@ from tepdist_tpu.ops.pallas.selective_scan import (
 )
 from tepdist_tpu.telemetry import traced
 
-TILE = 8                    # rows of a float32 tile
-STRIP = 64                  # rows a loop trip; whole packed 16-bit tiles
 HALO = 16                   # rows of the backward's second block of ``u``
 BLOCK_T = 2048              # rows a grid step (tools/ssm_bench.py)
 BLOCK_D = 256               # channels a grid step
@@ -95,30 +104,18 @@ traced.declare(
     "selective scan (a rematerialised layer's second run counted)")
 
 
-def _rolls(tile, shifts):
-    return tuple(pltpu.roll(tile, s, 0) for s in shifts)
-
-
-def _tiles(x):
-    return [x[i:i + TILE] for i in range(0, x.shape[0], TILE)]
-
-
 def _total(tile, rolled, before, taps, bias):
     """The pre-activation of one ``[8, bd]`` tile and the rows it was made
     of: ``rolled`` / ``before`` the rolls by 1..K-1 of this tile / of the
     tile before. ``xs[s]`` holds row ``t - s`` at row ``t``."""
     K = len(taps)
-    row = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 0)
-    xs = [tile] + [jnp.where(row < s, before[s - 1], rolled[s - 1])
+    row = _row(tile)
+    xs = [tile] + [_down(row, s, before[s - 1], rolled[s - 1])
                    for s in range(1, K)]
     acc = taps[0] * xs[K - 1]
     for j in range(1, K):
         acc = acc + taps[j] * xs[K - 1 - j]
     return bias + acc, xs
-
-
-def _strip(i):
-    return pl.ds(pl.multiple_of(i * STRIP, STRIP), STRIP)
 
 
 def _fwd_kernel(u_ref, w_ref, b_ref, c_ref, tail_scr, *, K: int, bt: int):
@@ -173,7 +170,7 @@ def _bwd_kernel(u_ref, halo_ref, dc_ref, w_ref, b_ref, du_ref, dwb_ref,
         before = u_ref[0, pl.ds(behind, HALO), :].astype(_F32)[HALO - TILE:]
         before = jnp.where(at == 0, halo, before)
         rolled = [_rolls(t, down) for t in [before] + tiles]
-        row = jax.lax.broadcasted_iota(jnp.int32, before.shape, 0)
+        row = _row(before)
         sums = [None] * (K + 1)
         dus = [None] * len(tiles)
         for t in reversed(range(len(tiles))):
@@ -187,8 +184,8 @@ def _bwd_kernel(u_ref, halo_ref, dc_ref, w_ref, b_ref, du_ref, dwb_ref,
             lifted = _rolls(g, up)
             du = taps[K - 1] * g
             for s in down:               # row t + s at row t
-                du = du + taps[K - 1 - s] * jnp.where(
-                    row >= TILE - s, after[s - 1], lifted[s - 1])
+                du = du + taps[K - 1 - s] * _up(
+                    row, s, after[s - 1], lifted[s - 1])
             dus[t] = du
             after = lifted
         du_ref[0, _strip(at), :] = jnp.concatenate(dus, 0).astype(

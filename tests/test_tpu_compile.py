@@ -812,3 +812,120 @@ def test_the_sarvam_cells_step_compiles_for_v5e(v5e_devices, monkeypatch):
     # No higher than before the backward pass became one kernel (PR 46's
     # step: 15,973,381,120): its results in HBM are the pair's five.
     assert 10 * n_params < peak <= 15_973_381_120, peak
+
+
+def test_cca_mix_kernels_compile_for_v5e(v5e_devices):
+    """The compressed attention's mixing kernels at the zaya1-8b cell's shape
+    (one 8,192-token sequence, 8 query and 2 key heads of 128 in bf16, blocks
+    of 1,024 rows), not interpreted: two kernels under their own names, the
+    latents token-major and the results head-major in their operands, the
+    latents' gradients in bf16 and the weights' sums in float32 (three
+    ``[128, 128]`` and four ``[128]`` a head), no padded and no float32 copy
+    of a latent anywhere in the program."""
+    from tepdist_tpu.ops.pallas.cca_mix import cca_mix
+    T, H, Hkv, D = 8192, 8, 2, 128
+    N = H + Hkv
+    one_chip = SingleDeviceSharding(v5e_devices[0])
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(*operands):
+        q, k = cca_mix(*operands, interpret=False)
+        return jnp.sum(q.astype(jnp.float32)) + jnp.sum(k.astype(jnp.float32))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(6)))).lower(
+        sds((1, T, H * D)), sds((1, T, Hkv * D)),
+        sds((2, N * D), jnp.float32), sds((N * D,), jnp.float32),
+        sds((2, N, D, D)), sds((N * D,), jnp.float32)).compile().as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if " custom-call(" in line and "tepdist_cca_mix_" in line]
+    assert len(calls) == 2, calls
+    fwd = next(c for c in calls if "tepdist_cca_mix_fwd" in c)
+    bwd = next(c for c in calls if "tepdist_cca_mix_bwd" in c)
+    latents = f"bf16[1,{T},{H * D}]{{2,1,0}}, bf16[1,{T},{Hkv * D}]{{2,1,0}}"
+    for call in calls:
+        assert call.split("operand_layout_constraints={", 1)[1].startswith(
+            latents), call
+    assert re.findall(r"\w+\[[\d,]+\]", fwd.partition(" custom-call(")[0]) \
+        == [f"bf16[1,{H},{T},{D}]", f"bf16[1,{Hkv},{T},{D}]"], fwd
+    assert re.findall(r"\w+\[[\d,]+\]", bwd.partition(" custom-call(")[0]) \
+        == [f"bf16[1,{T},{H * D}]", f"bf16[1,{T},{Hkv * D}]",
+            f"f32[1,{H},3,{D},{D}]", f"f32[1,{Hkv},3,{D},{D}]",
+            f"f32[1,{H},4,{D}]", f"f32[1,{Hkv},4,{D}]"], bwd
+    wide = set(re.findall(rf"f32\[1,{T},(?:{H * D}|{N * D}|{Hkv * D})\]",
+                          text))
+    assert not wide, wide
+
+
+def test_the_zaya_cells_step_compiles_for_v5e(v5e_devices, monkeypatch):
+    """``zaya1-8b.train.s8192``'s step from the cell's own files (8 micro
+    batches of one 8,192-token sequence; five layers in one walk whose carry
+    is the pair ``(x, r)``; ``adamw_bf16_router_bias``), kernels not
+    interpreted: the walk's leaves accumulate inside the backward layer
+    loop, the flash forward at 8 heads over 2 runs once a layer and micro
+    batch (the walk keeps its ``(o, lse)``), the mixing's forward twice (a
+    walked block recomputes it: ``cca_mix_calls`` 10), the top-1 layout has
+    its one size of 10,240 rows, and the compiler's peak is under 13e9
+    bytes."""
+    import json
+
+    from benchmark.lib import cells
+    from tepdist_tpu.parallel.sync_free import build_ga_step
+    from tepdist_tpu.telemetry import metrics
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmark")
+    with open(os.path.join(bench, "configs", "zaya1-8b.json")) as f:
+        config = json.load(f)
+    builder = cells.load_module(os.path.join(bench, "builders", "zaya.py"),
+                                "bench_builder_zaya_compile")
+    loss = builder.program_loss_fn(config)
+    tx = builder.program_optimizer(config)
+
+    def apply_fn(p, s, g):
+        updates, s = tx.update(g, s, p)
+        return optax.apply_updates(p, updates), s
+
+    T = 8192
+    one_chip = SingleDeviceSharding(v5e_devices[0])
+    params = jax.eval_shape(lambda: builder.make_params(config, 1))
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (params, jax.eval_shape(tx.init, params),
+         jax.ShapeDtypeStruct((8, T + 1), jnp.int32)))
+    step = build_ga_step(lambda p, t: jax.value_and_grad(loss)(p, t),
+                         apply_fn, 8, loss_fn=loss)
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
+
+    gauge = lambda n: metrics().gauge(n).value              # noqa: E731
+    n_params = sum(a.size for a in jax.tree_util.tree_leaves(params))
+    assert n_params == 1_104_975_450
+    stack = sum(a.size * a.dtype.itemsize
+                for a in jax.tree_util.tree_leaves(params["blocks"]))
+    assert gauge("ga_fused_bytes") == stack
+    assert gauge("ga_unfused_bytes") == 32784 * 2048 * 2 + 2048 * 4
+    assert gauge("cca_mix_calls") == 10         # a walked block's, twice
+    assert gauge("attn_kept_calls") == 5        # kept: not run again
+    assert gauge("attn_kept_bytes") == 5 * 8 * T * (128 * 2 + 4)
+    assert gauge("cca_latent_bytes") == T * (1024 + 256 + 256) * 2
+    assert gauge("router_carry_bytes") == T * 256 * 4
+    assert gauge("moe_top1_rows") == 10240
+    assert gauge("moe_rows_sum_calls") == 0     # every expert is resident
+
+    text = compiled.as_text()
+    calls = [line.split(" = ", 1)[0].strip() for line in text.splitlines()
+             if " custom-call(" in line]
+    # One walk: the mixing's forward in its forward loop and in the backward
+    # loop's recomputation, the flash forward in the forward loop alone.
+    assert len([c for c in calls if "tepdist_cca_mix_fwd" in c]) == 2, calls
+    assert len([c for c in calls if "tepdist_cca_mix_bwd" in c]) == 1, calls
+    for which in ("fwd", "dkv"):
+        names = [c for c in calls if f"tepdist_flash_{which}__" in c]
+        assert len(names) == 1 and "__h8" in names[0] \
+            and "__kv2" in names[0], calls
+    assert not [c for c in calls if "tepdist_flash_dq" in c], calls
+    assert [c for c in calls if "tepdist_gmm_" in c], calls
+    # The router's state rides in float32 beside x, a layer's kept input.
+    assert f"f32[5,1,{T},256]" in text and f"bf16[5,1,{T},2048]" in text
+    assert compiled.memory_analysis().peak_memory_in_bytes < 13e9
