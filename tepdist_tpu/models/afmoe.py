@@ -61,6 +61,7 @@ import jax.numpy as jnp
 
 from tepdist_tpu.models import decoder
 from tepdist_tpu.models.decoder import (
+    EXPERT_LEAVES,
     fake_batch,  # noqa: F401 (the model's, as every decoder's)
     held_weights,
     layer_dicts,
@@ -297,7 +298,8 @@ def hidden_states(params, tokens, cfg: AfmoeConfig):
          * math.sqrt(cfg.hidden_size)).astype(cfg.dtype)
     x = walk_layers(lambda blk, h, window: block(blk, h, cfg, window), x,
                     params, _stacks(cfg),
-                    [t == WINDOW for t in cfg.layer_types], cfg.remat)
+                    [t == WINDOW for t in cfg.layer_types], cfg.remat,
+                    experts=EXPERT_LEAVES)
     return rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
 
 

@@ -31,6 +31,9 @@ import numpy as np
 from tepdist_tpu.models.layers import held_routing_stats, scan_blocks
 
 Stack = Tuple[Any, int, int]
+# The leaves of a SwiGLU expert layer, [experts, ...] each: what a model
+# names to ``walk_layers`` for the grouped-matmul kernels to read in place.
+EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
 
 
 def fake_batch(cfg, batch_size: int, seq_len: Optional[int] = None,
@@ -98,7 +101,8 @@ def layer_dicts(params, stacks: Sequence[Stack],
 
 
 def walk_layers(layer: Callable, x, params, stacks: Sequence[Stack],
-                rows: Sequence, remat: bool, groups: Sequence[str] = ("",)):
+                rows: Sequence, remat: bool, groups: Sequence[str] = ("",),
+                experts: Sequence[str] = ()):
     """``x`` through every layer in order, whichever the layout:
     ``layer(blk, h, row) -> h`` with ``rows[i]`` what layer ``i`` is given
     besides its parameters: its kind (hashable), or a NumPy row of numbers
@@ -112,7 +116,15 @@ def walk_layers(layer: Callable, x, params, stacks: Sequence[Stack],
     the Python value; one of unequal kinds (of one shape: a window here,
     none there) hands each layer its entry of the int32 array of them,
     traced, for the layer to branch on by ``lax.cond``; rows of numbers
-    ride beside the blocks likewise."""
+    ride beside the blocks likewise.
+
+    ``experts``: the names of the leaves that hold a layer's experts'
+    weights ``[experts, K, N]`` and that ``layer`` hands to
+    ``ops/grouped_matmul.py:routed_experts`` as they are and uses nowhere
+    else. Where a stack has them (``[layers, experts, K, N]``; a dense
+    stack's leaf of the same name is no such) a walk that accumulates
+    gradients gives the layer an ``ExpertStack`` for each
+    (``scan_blocks(in_place=)``)."""
     if "l0" in params:
         for i, row in enumerate(rows):
             static = isinstance(row, Hashable)
@@ -126,9 +138,11 @@ def walk_layers(layer: Callable, x, params, stacks: Sequence[Stack],
         kinds = isinstance(mine[0], Hashable)
         ride = None if kinds and len(set(mine)) == 1 else np.asarray(
             mine, np.int32 if kinds else None)
+        blocks = run_blocks(params, name, groups)
         x = scan_blocks(
             lambda h, blk, row=mine[0]: (layer(blk, h, row), None), x,
-            run_blocks(params, name, groups), ride, remat)[0]
+            blocks, ride, remat, tuple(
+                k for k in experts if k in blocks and blocks[k].ndim == 4))[0]
     return x
 
 

@@ -27,11 +27,12 @@ import numpy as np
 
 from tepdist_tpu.ops.grouped_matmul import (
     at_rows,
-    counting_rows_sum,
+    counting_kernel_calls,
     layout_index,
     layout_rows,
     route,
 )
+from tepdist_tpu.ops.pallas.grouped_matmul import ExpertStack
 from tepdist_tpu.ops.pallas.flash_attention import (
     KeptForward,
     flash_attention_kept,
@@ -51,6 +52,11 @@ traced.declare(
     "moe_rows_sum_calls", "calls a micro batch of the expert layers' "
     "row-copy kernel (ops/pallas/rows_sum.py): 2 a walked layer that holds "
     "a share of the experts, 0 where the XLA gathers stayed")
+traced.declare(
+    "moe_stack_in_place_calls", "grouped-matmul calls a micro batch that "
+    "read their weights out of the layers' stack or added into its "
+    "accumulator where they lie: 12 a walked expert layer, 0 where the walk "
+    "hands the kernels slices")
 traced.declare(
     "ce_fused_chunks", "chunks of the loss whose gradients its forward chunk "
     "loop makes (0: the dense loss, or a call nobody differentiates)")
@@ -88,7 +94,8 @@ _SINK: contextvars.ContextVar[Optional[BlockGradSink]] = \
     contextvars.ContextVar("tepdist_block_grad_sink", default=None)
 
 
-def scan_blocks(body, x, blocks, kinds=None, remat: bool = True):
+def scan_blocks(body, x, blocks, kinds=None, remat: bool = True,
+                in_place: Tuple[str, ...] = ()):
     """``jax.lax.scan(jax.checkpoint(body), x, blocks)``: ``body(h, block)
     -> (h, y)`` over blocks stacked on a leading layer dim, every block
     rematerialised in the backward pass but, where the backward is written
@@ -121,6 +128,24 @@ def scan_blocks(body, x, blocks, kinds=None, remat: bool = True):
     plain scan's gradient added to the accumulator afterwards: the layer's
     gradient is rounded to its dtype, then the sum to the accumulator's.
 
+    ``in_place`` names the leaves of ``blocks`` (a dict) that hold a layer's
+    experts' weights, ``[layers, experts, K, N]``, and that the body hands to
+    ``ops/grouped_matmul.py:routed_experts`` as they are and uses nowhere
+    else. That walk does not scan them: its forward loop, its recomputation
+    and its backward give the body an ``ExpertStack`` (the whole stack, which
+    no loop changes, the loop's layer index and the stack's accumulator) in
+    the leaf's place, the kernels read the layer's tiles where they lie, and
+    the backward step takes the accumulator the block's pullback returns (the
+    weight gradient added into slice ``l`` by the kernel) in place of ``a[l]
+    + g``: no copy of a layer's experts out of the stack, none of their
+    gradient in, the same two roundings (``ops/pallas/grouped_matmul.py``;
+    the gauge ``moe_stack_in_place_calls``). Every other leaf and every
+    other path (the plain scan, the recording pass, ``remat=False``) takes
+    slices as before. Not for a body that runs its expert layer more than
+    once a layer (``models/sarvam_mla.py``: inside ``over_sequence``'s
+    chunks, where an accumulator handed back as a cotangent would be summed
+    once a chunk).
+
     That walk saves, beside a block's input, the ``(o, lse)`` of every flash
     call in it (``ops/pallas/flash_attention.py:KeptForward``) and of every
     block top-k attention call with its chosen sets
@@ -130,7 +155,8 @@ def scan_blocks(body, x, blocks, kinds=None, remat: bool = True):
     arrays' bytes held from a micro batch's forward to its backward
     (the gauges ``attn_kept_calls`` / ``attn_kept_bytes``, summed over the
     walks of one loss; the walk also counts the calls a layer's expert part
-    makes of its row-copy kernel, ``moe_rows_sum_calls``). The same values:
+    makes of its row-copy kernel, ``moe_rows_sum_calls``, and of the grouped
+    matmuls over a stack, ``moe_stack_in_place_calls``). The same values:
     they are the arrays the second run would make. A body wrapped in
     :func:`rematerialised_whole` keeps nothing. The plain scan (no sink: one
     micro batch, or a body that closes over a traced value) is left as it
@@ -161,7 +187,7 @@ def scan_blocks(body, x, blocks, kinds=None, remat: bool = True):
         sink.walks.append(keys)
         acc = jax.tree_util.tree_unflatten(
             jax.tree_util.tree_structure(blocks), [sink.acc[k] for k in keys])
-        return _walk_accumulating(body, x, blocks, acc, kinds)
+        return _walk_accumulating(body, x, blocks, acc, kinds, in_place)
     if keys and None not in keys:
         # Recording. A body that closes over a traced value cannot be
         # differentiated by hand; that walk stays the plain scan.
@@ -193,31 +219,55 @@ def rematerialised_whole(body):
     return whole
 
 
-def _walk_accumulating(body, x, blocks, acc, kinds):
+def _walk_accumulating(body, x, blocks, acc, kinds, in_place=()):
     """:func:`scan_blocks` with the backward written out. ``x``: the carry,
     a pytree; the forward keeps each block's input carry whole (stacked a
-    layer, array for array) and the backward carries its cotangent."""
+    layer, array for array) and the backward carries its cotangent.
+    ``in_place``: the keys of ``blocks`` (a dict) whose layer the body is
+    handed as an :class:`ExpertStack` and not as a slice."""
     n_layers = jax.tree_util.tree_leaves(blocks)[0].shape[0]
+    layers = jnp.arange(n_layers)
 
-    def layers_of(blocks):
-        return blocks if kinds is None else (blocks, kinds)
+    def apart(tree):
+        """(what is scanned or added to a slice at a time, what stays whole)
+        of ``blocks`` or of their accumulators."""
+        if not in_place:
+            return tree, {}
+        return {k: v for k, v in tree.items() if k not in in_place}, \
+            {k: tree[k] for k in in_place}
+
+    def whole(layer, block, kind, stacks, into):
+        """What the body takes for a layer: its scanned leaves (and entry)
+        with the handles of what stayed whole."""
+        if in_place:
+            index = layer.astype(jnp.int32).reshape(1)
+            block = {**block, **{k: ExpertStack(stacks[k], index, into[k])
+                                 for k in in_place}}
+        return block if kinds is None else (block, kind)
 
     @jax.custom_vjp
     def walk(x, blocks, acc):
         del acc
-        return jax.lax.scan(body, x, layers_of(blocks))
+        return jax.lax.scan(body, x,
+                            blocks if kinds is None else (blocks, kinds))
 
     def fwd(x, blocks, acc):
-        rows_sum_calls = []     # one entry a trace of the body: a layer's
+        kernel_calls = []       # one entry a trace of the body: a layer's
+        scanned, stacks = apart(blocks)
+        into = apart(acc)[1]    # no forward pass writes it
 
-        def step(h, layer):
-            with KeptForward() as keep, counting_rows_sum() as calls:
-                out, y = body(h, layer)
-            rows_sum_calls.append(calls[0])
+        def step(h, per_layer):
+            with KeptForward() as keep, counting_kernel_calls() as calls:
+                out, y = body(h, whole(*per_layer, stacks, into))
+            kernel_calls.append(calls)
             return out, (h, y, keep.kept)
 
-        out, (inputs, ys, kept) = jax.lax.scan(step, x, layers_of(blocks))
-        traced.count("moe_rows_sum_calls", n_layers * rows_sum_calls[0])
+        out, (inputs, ys, kept) = jax.lax.scan(
+            step, x, (layers, scanned, kinds))
+        traced.count("moe_rows_sum_calls",
+                     n_layers * kernel_calls[0]["rows_sum"])
+        traced.count("moe_stack_in_place_calls",
+                     n_layers * kernel_calls[0]["stack_in_place"])
         traced.count("attn_kept_calls", n_layers * len(kept))
         traced.count("attn_kept_bytes", sum(
             a.nbytes for a in jax.tree_util.tree_leaves(kept)))
@@ -226,30 +276,31 @@ def _walk_accumulating(body, x, blocks, acc, kinds):
     def bwd(res, cts):
         inputs, kept, blocks, acc = res
         d_out, d_ys = cts
-        layers = jnp.arange(n_layers)
+        scanned, stacks = apart(blocks)
 
         def step(carry, per_layer):
             dh, acc = carry
-            layer, h, saved, block, d_y = per_layer
-            if kinds is not None:
-                block, kind = block
+            layer, h, saved, block, kind, d_y = per_layer
+            acc, into = apart(acc)
 
-            def recompute(h, block):
+            def recompute(h, block, into):
                 with KeptForward(saved):
-                    return body(h, block if kinds is None else (block, kind))
+                    return body(h, whole(layer, block, kind, stacks, into))
 
-            _, pull = jax.vjp(recompute, h, block)
-            dh, d_block = pull((dh, d_y))
+            _, pull = jax.vjp(recompute, h, block, into)
+            # A stack's accumulator comes back with its layer's gradient
+            # added where it lies; the other leaves' are added here.
+            dh, d_block, into = pull((dh, d_y))
             acc = jax.tree_util.tree_map(
                 lambda a, g: jax.lax.dynamic_update_index_in_dim(
                     a, jax.lax.dynamic_index_in_dim(a, layer, keepdims=False)
                     + g.astype(a.dtype), layer, 0),
                 acc, d_block)
-            return (dh, acc), None
+            return (dh, {**acc, **into} if in_place else acc), None
 
         (dx, acc), _ = jax.lax.scan(
             step, (d_out, acc),
-            (layers, inputs, kept, layers_of(blocks), d_ys), reverse=True)
+            (layers, inputs, kept, scanned, kinds, d_ys), reverse=True)
         return dx, None, acc
 
     walk.defvjp(fwd, bwd)

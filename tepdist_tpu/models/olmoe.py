@@ -209,6 +209,11 @@ def hidden_states(params, tokens, cfg: OlmoeConfig):
         return h, (lb, zl)
 
     if "blocks" in params:
+        # The experts' leaves are scanned a slice a layer. The kernels can
+        # read them in place (``scan_blocks(in_place=)``, as the decoders of
+        # ``models/decoder.py:walk_layers`` do); this walk takes that form
+        # once the benchmark's reader of the grouped matmuls' roofline
+        # follows a rank-4 weight operand (PERF.md section 7 (0)).
         x, aux = scan_blocks(body, x, params["blocks"], remat=cfg.remat)
         lb, zl = (a.mean() for a in aux)
     else:

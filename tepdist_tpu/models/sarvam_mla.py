@@ -363,6 +363,10 @@ def block(blk, x, cfg: SarvamMLAConfig):
 def hidden_states(params, tokens, cfg: SarvamMLAConfig):
     """tokens int32 [B, T] -> final normalised hidden [B, T, d]."""
     x = params["tok_emb"][tokens].astype(cfg.dtype)
+    # No ``experts=``: the expert layer runs once a chunk of the sequence
+    # (``block``'s ``over_sequence``), and a gradient accumulator handed
+    # back as a cotangent would be summed once a chunk; the kernels take
+    # slices here.
     x = walk_layers(lambda blk, h, _: block(blk, h, cfg), x, params,
                     _stacks(cfg), [None] * cfg.num_hidden_layers, cfg.remat)
     return rms_norm(x, params["norm_f"], cfg.rms_norm_eps)

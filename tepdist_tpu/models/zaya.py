@@ -343,7 +343,8 @@ def hidden_states(params, tokens, cfg: ZayaConfig):
     """tokens int32 [B, T] -> final normalised hidden [B, T, d]."""
     x, _ = walk_layers(lambda blk, carry, _: block(blk, carry, cfg),
                        _start(params, tokens, cfg), params, _stacks(cfg),
-                       [None] * cfg.num_hidden_layers, cfg.remat)
+                       [None] * cfg.num_hidden_layers, cfg.remat,
+                       experts=decoder.EXPERT_LEAVES)
     return rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
 
 

@@ -97,7 +97,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from tepdist_tpu.ops.pallas import _interpret
-from tepdist_tpu.ops.pallas.grouped_matmul import grouped_matmul
+from tepdist_tpu.ops.pallas.grouped_matmul import ExpertStack, grouped_matmul
 from tepdist_tpu.ops.pallas.rows_sum import rows_sum
 
 
@@ -269,19 +269,23 @@ def _sum_of_rows(y, dest):
     return total.astype(y.dtype)
 
 
-_KERNEL_CALLS: contextvars.ContextVar[Optional[list]] = \
-    contextvars.ContextVar("tepdist_rows_sum_calls", default=None)
+_KERNEL_CALLS: contextvars.ContextVar[Optional[dict]] = \
+    contextvars.ContextVar("tepdist_expert_kernel_calls", default=None)
 
 
 @contextlib.contextmanager
-def counting_rows_sum():
-    """Yields a one-element list to which every :func:`routed_experts`
-    traced inside adds the calls of the row-copy kernel it makes a micro
-    batch once differentiated: 2 (its ``combine``'s and its ``dispatch``'s
-    backward's), 0 where the layer keeps the XLA gathers. Who walks a stack
-    of layers multiplies by them (``models/layers.py:scan_blocks``: the
-    gauge ``moe_rows_sum_calls``)."""
-    calls = [0]
+def counting_kernel_calls():
+    """Yields a dict to which every :func:`routed_experts` traced inside
+    adds the kernel calls it makes a micro batch once a walk differentiates
+    it. ``rows_sum``: of the row-copy kernel, 2 (its ``combine``'s and its
+    ``dispatch``'s backward's), 0 where the layer keeps the XLA gathers.
+    ``stack_in_place``: of the grouped matmuls that read their weights out
+    of the layers' stack (:class:`ExpertStack`), 12 (three matmuls, each
+    forward, recomputed, its input's and its weight's gradient), 0 where
+    the layer was handed slices. Who walks a stack of layers multiplies by
+    them (``models/layers.py:scan_blocks``: the gauges ``moe_rows_sum_calls``
+    and ``moe_stack_in_place_calls``)."""
+    calls = {"rows_sum": 0, "stack_in_place": 0}
     token = _KERNEL_CALLS.set(calls)
     try:
         yield calls
@@ -447,8 +451,10 @@ def routed_experts(h, weights, experts, w_gate, w_up, w_down,
     with jax.named_scope("moe_dispatch"):
         r = route(experts, num_experts, tile_m, held)
     calls = _KERNEL_CALLS.get()
-    if calls is not None and r.live_rows is not None:
-        calls[0] += 2
+    if calls is not None:
+        calls["rows_sum"] += 2 * (r.live_rows is not None)
+        calls["stack_in_place"] += 4 * sum(
+            isinstance(w, ExpertStack) for w in (w_gate, w_up, w_down))
     sizes = layout_rows(*experts.shape, _held(num_experts, held)[1],
                         num_experts, tile_m)
     if len(sizes) == 1:
@@ -475,8 +481,19 @@ def _switch(index, branches, r: Routing, *args, lean: bool = False):
     returns the leaves of its ``jax.vjp`` pullback (its residuals) in slot
     ``i`` and arrays no kernel wrote in the others; the backward rule is a
     ``switch`` whose branch ``i`` calls slot ``i``'s pullback. The gradients
-    are autodiff's of the size taken."""
+    are autodiff's of the size taken; an :class:`ExpertStack` among ``args``
+    gets its accumulator's cotangent and no other."""
     taken = range(len(branches))
+    tree = jax.tree_util.tree_structure(args)
+    # Of an ``ExpertStack`` among ``args`` no leaf goes in a slot, whatever
+    # ``lean`` (a slot would hold a copy of every layer's experts), and its
+    # stack and layer index are closed over by what is differentiated: a
+    # pullback hands back zeros for an operand nothing reaches, as large.
+    roles = [role for a in args for role in (
+        ExpertStack("fixed", "fixed", "moving") if isinstance(a, ExpertStack)
+        else (None,) * len(jax.tree_util.tree_leaves(a)))]
+    handed = {n for n, role in enumerate(roles) if role}
+    moving = [n for n, role in enumerate(roles) if role != "fixed"]
 
     @jax.custom_vjp
     def chosen(index, r, *args):
@@ -485,7 +502,7 @@ def _switch(index, branches, r: Routing, *args, lean: bool = False):
     # Slot i holds the leaves of size i's pullback; what puts them together
     # again is static, made while the forward rule is traced and read by
     # the backward rule, which is traced after it. ``lean``: a leaf that is
-    # one of ``args`` as it came (the experts' weights, which every size's
+    # one of ``args``' as it came (the experts' weights, which every size's
     # pullback reads) goes in no slot and the backward rule takes it from
     # ``args``: a conditional returns no operand without copying it, so a
     # slot holds a copy for the size taken and an array as large, unwritten,
@@ -494,10 +511,19 @@ def _switch(index, branches, r: Routing, *args, lean: bool = False):
 
     def fwd(index, r, *args):
         def residuals(i, r, *args):
-            out, pull = jax.vjp(functools.partial(branches[i], r), *args)
+            flat = jax.tree_util.tree_leaves(args)
+
+            def branch(*moved):
+                leaves = list(flat)
+                for n, a in zip(moving, moved):
+                    leaves[n] = a
+                return branches[i](r, *tree.unflatten(leaves))
+
+            out, pull = jax.vjp(branch, *(flat[n] for n in moving))
             leaves, trees[i] = jax.tree_util.tree_flatten(pull)
-            passed[i] = [next((n for n, a in enumerate(args) if a is leaf),
-                              None) if lean else None for leaf in leaves]
+            passed[i] = [next((n for n, a in enumerate(flat) if a is leaf
+                               and (lean or n in handed)), None)
+                         for leaf in leaves]
             return out, [leaf for leaf, n in zip(leaves, passed[i])
                          if n is None]
 
@@ -514,19 +540,23 @@ def _switch(index, branches, r: Routing, *args, lean: bool = False):
 
         out, slots = jax.lax.switch(
             index, [functools.partial(branch, i) for i in taken], r, *args)
-        return out, (index, slots, args if lean else ())
+        return out, (index, slots, [
+            a if lean or n in handed else None
+            for n, a in enumerate(jax.tree_util.tree_leaves(args))])
 
     def bwd(residuals, g):
-        index, slots, args = residuals
+        index, slots, flat = residuals
 
-        def pull(i, slots, args, g):
+        def pull(i, slots, flat, g):
             kept = iter(slots[i])
             return jax.tree_util.tree_unflatten(trees[i], [
-                next(kept) if n is None else args[n] for n in passed[i]])(g)
+                next(kept) if n is None else flat[n] for n in passed[i]])(g)
 
-        return (None, None) + jax.lax.switch(
-            index, [functools.partial(pull, i) for i in taken], slots, args,
-            g)
+        cts = dict(zip(moving, jax.lax.switch(
+            index, [functools.partial(pull, i) for i in taken], slots, flat,
+            g)))
+        return (None, None) + tuple(tree.unflatten(
+            [cts.get(n) for n in range(len(flat))]))
 
     chosen.defvjp(fwd, bwd)
     return chosen(index, r, *args)

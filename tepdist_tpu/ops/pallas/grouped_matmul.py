@@ -22,6 +22,26 @@ is fetched for them, and their output rows are written as zeros.
   over the group's tiles in VMEM, written once a group.
 * ``grouped_matmul``: ``gmm`` with a custom VJP made of the two.
 
+**The weight is one layer's ``[E, K, N]``, or every layer's ``[L, E, K, N]``
+with the layer's index.** A Pallas call's operand is a whole buffer, so a
+walk over stacked layers that hands a kernel ``stack[l]`` makes XLA copy the
+slice out before every call, and ``acc[l] += dw`` after the weight gradient
+is a copy out, an add and a copy in: twelve copies of a layer's experts a
+layer and micro batch, a fifth of the ZAYA1 cell's step (PERF.md section 6,
+PR 49). Given the stack and ``layer`` (int32 [1], one more scalar-prefetch
+operand that the weight's index map puts before the expert's) ``gmm`` reads
+the layer's tiles where they lie, and ``tgmm`` given the accumulator stack
+``into`` writes ``into[layer, g] + round(x_g^T dy_g)`` over slice ``layer``
+of ``into``'s own buffer (``input_output_aliases``; no other slice is read
+or written): the group's float32 sum rounded to the weight's dtype, then
+added to the accumulator's block in float32 and rounded to the accumulator's
+dtype, the two roundings of ``acc[l] + dw``, so the sums are the sliced
+walk's bit for bit. The form follows the operand's rank; a rank-3 call is
+the custom call it always was. ``grouped_matmul`` takes the stack as an
+:class:`ExpertStack` ``(stack, layer, into)``: its backward hands the
+updated accumulator back as ``into``'s cotangent and the stack gets none
+(``models/layers.py:scan_blocks`` is who builds one).
+
 Zero padding rows contribute nothing to ``tgmm``; their ``gmm`` outputs are
 zero rows nobody gathers. Kernel names ``tepdist_gmm_fwd`` / ``_dx`` /
 ``_dw`` show in a device trace and in the compiled HLO. Runs in interpret
@@ -31,6 +51,8 @@ mode off-TPU (tests), compiled on TPU.
 from __future__ import annotations
 
 import functools
+import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -74,7 +96,8 @@ def _live(i, n_tiles):
     return jnp.minimum(i, n_tiles[0] - 1)
 
 
-def _gmm_kernel(tile_group, n_tiles, x_ref, w_ref, o_ref, *, dims):
+def _gmm_kernel(tile_group, n_tiles, *refs, dims):
+    x_ref, w_ref, o_ref = refs[-3:]      # after the layer's index, if any
     i = pl.program_id(1)
 
     @pl.when(i < n_tiles[0])
@@ -94,46 +117,62 @@ def _gmm_kernel(tile_group, n_tiles, x_ref, w_ref, o_ref, *, dims):
 @functools.partial(
     jax.jit, inline=True, static_argnames=(
         "tile_m", "transpose_rhs", "block_n", "name", "interpret"))
-def gmm(x, w, tile_group, n_tiles, *, tile_m: int, transpose_rhs=False,
-        block_n: int = 1024, name: str = "tepdist_gmm_fwd", interpret=None):
+def gmm(x, w, tile_group, n_tiles, layer=None, *, tile_m: int,
+        transpose_rhs=False, block_n: int = 1024,
+        name: str = "tepdist_gmm_fwd", interpret=None):
     """x [M, K] in the tile-aligned layout times its tile's group's weight:
     w [E, K, N] -> [M, N], or with ``transpose_rhs`` w [E, N, K] -> [M, N]
-    contracted over w's last dim."""
+    contracted over w's last dim. ``w`` [L, E, ...] with ``layer`` int32 [1]:
+    the same over ``w[layer[0]]``, read where it lies."""
     M, K = x.shape
-    N = w.shape[1] if transpose_rhs else w.shape[2]
-    if w.shape[2 if transpose_rhs else 1] != K or M % tile_m:
-        raise ValueError(f"gmm: x {x.shape}, w {w.shape}, "
+    stacked = layer is not None
+    E = w.shape[-3]
+    N = w.shape[-2] if transpose_rhs else w.shape[-1]
+    if w.ndim != 3 + stacked or M % tile_m \
+            or w.shape[-1 if transpose_rhs else -2] != K:
+        raise ValueError(f"gmm: x {x.shape}, w {w.shape} with layer "
+                         f"{'given' if stacked else 'None'}, "
                          f"transpose_rhs={transpose_rhs}, tile_m={tile_m}")
     bn = _block_n(N, block_n)
 
-    if transpose_rhs:
-        w_spec = pl.BlockSpec(
-            (1, bn, K), lambda j, i, tg, n: (tg[_live(i, n)], j, 0))
-    else:
-        w_spec = pl.BlockSpec(
-            (1, K, bn), lambda j, i, tg, n: (tg[_live(i, n)], 0, j))
+    # The weight's block and where it lies: (group of row tile i, column
+    # block j), behind the layer's index where the weight is a stack (the
+    # scalar operands follow the grid's indices: tile_group, n_tiles, layer).
+    block = (1, bn, K) if transpose_rhs else (1, K, bn)
+
+    def at(j, i, tg, n, *layer):
+        g = tg[_live(i, n)]
+        return tuple(l[0] for l in layer) + (
+            (g, j, 0) if transpose_rhs else (g, 0, j))
+
+    w_spec = pl.BlockSpec((None,) * stacked + block, at)
+    scalars = (tile_group, n_tiles) + ((layer,) if stacked else ())
     # Row tiles innermost: consecutive tiles of one group keep their weight
     # block in VMEM, so each expert's weights cross HBM once a column block.
     return pl.pallas_call(
         functools.partial(_gmm_kernel, dims=_NT if transpose_rhs else _NN),
         name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(N // bn, M // tile_m),
+            num_scalar_prefetch=len(scalars), grid=(N // bn, M // tile_m),
             in_specs=[pl.BlockSpec((tile_m, K),
-                                   lambda j, i, tg, n: (_live(i, n), 0)),
+                                   lambda j, i, tg, n, *_: (_live(i, n), 0)),
                       w_spec],
             out_specs=pl.BlockSpec((tile_m, bn),
-                                   lambda j, i, tg, n: (i, j))),
+                                   lambda j, i, tg, n, *_: (i, j))),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        cost_estimate=_cost(M, K, N, w.shape[0], x.dtype.itemsize),
+        cost_estimate=_cost(M, K, N, E, x.dtype.itemsize),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(interpret),
-    )(tile_group, n_tiles, x, w)
+    )(*scalars, x, w)
 
 
-def _tgmm_kernel(tile_group, n_tiles, x_ref, dy_ref, o_ref, acc_ref):
+def _tgmm_kernel(tile_group, n_tiles, *refs):
+    if len(refs) == 4:
+        (x_ref, dy_ref, o_ref, acc_ref), into_ref = refs, None
+    else:       # behind the layer's index, with the accumulator's block
+        _, x_ref, dy_ref, into_ref, o_ref, acc_ref = refs
     i = pl.program_id(2)
     last = n_tiles[0] - 1
     g = tile_group[jnp.minimum(i, last)]
@@ -157,48 +196,105 @@ def _tgmm_kernel(tile_group, n_tiles, x_ref, dy_ref, o_ref, acc_ref):
 
         @pl.when(closes)
         def _():
-            o_ref[0] = acc_ref[...].astype(o_ref.dtype)
+            dw = acc_ref[...].astype(x_ref.dtype)
+            if into_ref is not None:
+                # The two roundings of ``acc[layer] + dw``: the group's sum
+                # to the weight's dtype, the new sum to the accumulator's.
+                dw = into_ref[0].astype(jnp.float32) + dw.astype(jnp.float32)
+            o_ref[0] = dw.astype(o_ref.dtype)
 
 
 @functools.partial(
     jax.jit, inline=True, static_argnames=(
         "num_groups", "tile_m", "block_n", "name", "interpret"))
-def tgmm(x, dy, tile_group, n_tiles, num_groups: int, *, tile_m: int,
-         block_n: int = 1024,
+def tgmm(x, dy, tile_group, n_tiles, num_groups: int, into=None, layer=None,
+         *, tile_m: int, block_n: int = 1024,
          name: str = "tepdist_gmm_dw", interpret=None):
     """Per-group ``x.T @ dy``: x [M, K], dy [M, N] in the tile-aligned
     layout -> [num_groups, K, N]. Every group owns at least one tile, so
-    every output block is written."""
+    every output block is written. With ``into`` [L, num_groups, K, N] and
+    ``layer`` int32 [1]: ``into`` with slice ``layer[0]`` plus that (rounded
+    to x's dtype first, the sum to ``into``'s), written over ``into``'s own
+    buffer; no other slice is read or written."""
     M, K = x.shape
     N = dy.shape[1]
     if dy.shape[0] != M or M % tile_m:
         raise ValueError(f"tgmm: x {x.shape}, dy {dy.shape}, tile_m={tile_m}")
+    if (into is None) != (layer is None) or (
+            into is not None and into.shape[1:] != (num_groups, K, N)):
+        raise ValueError(f"tgmm: into {getattr(into, 'shape', None)} for "
+                         f"{(num_groups, K, N)} with layer "
+                         f"{'given' if layer is not None else 'None'}")
+    stacked = into is not None
     bk, bn = _block_n(K, _TGMM_BLOCK_K), _block_n(N, block_n)
 
+    def at(a, b, i, tg, n, *layer):
+        return tuple(l[0] for l in layer) + (tg[_live(i, n)], a, b)
+
+    dw_spec = pl.BlockSpec((None,) * stacked + (1, bk, bn), at)
+    scalars = (tile_group, n_tiles) + ((layer,) if stacked else ())
+    out = jax.ShapeDtypeStruct((num_groups, K, N), x.dtype) \
+        if into is None else jax.ShapeDtypeStruct(into.shape, into.dtype)
     return pl.pallas_call(
         _tgmm_kernel, name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(K // bk, N // bn, M // tile_m),
-            in_specs=[pl.BlockSpec((tile_m, bk),
-                                   lambda a, b, i, tg, n: (_live(i, n), a)),
-                      pl.BlockSpec((tile_m, bn),
-                                   lambda a, b, i, tg, n: (_live(i, n), b))],
-            out_specs=pl.BlockSpec(
-                (1, bk, bn), lambda a, b, i, tg, n: (tg[_live(i, n)], a, b)),
+            num_scalar_prefetch=len(scalars),
+            grid=(K // bk, N // bn, M // tile_m),
+            in_specs=[pl.BlockSpec(
+                          (tile_m, bk),
+                          lambda a, b, i, tg, n, *_: (_live(i, n), a)),
+                      pl.BlockSpec(
+                          (tile_m, bn),
+                          lambda a, b, i, tg, n, *_: (_live(i, n), b))]
+            + [dw_spec] * stacked,
+            out_specs=dw_spec,
             scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((num_groups, K, N), x.dtype),
-        cost_estimate=_cost(M, K, N, num_groups, x.dtype.itemsize),
+        out_shape=out,
+        # The accumulator (the last operand, behind the scalars, x and dy)
+        # is the result: the slices this call does not visit stay as they
+        # are.
+        input_output_aliases={len(scalars) + 2: 0} if stacked else {},
+        cost_estimate=_cost(M, K, N, num_groups * (1 + stacked),
+                            x.dtype.itemsize),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(interpret),
-    )(tile_group, n_tiles, x, dy)
+    )(*scalars, x, dy, *((into,) if stacked else ()))
+
+
+class ExpertStack(NamedTuple):
+    """One layer's expert weights where they lie: what a walk over stacked
+    layers hands a block in place of the slice ``stack[layer]``
+    (``models/layers.py:scan_blocks``). ``into``: the stack's gradient
+    accumulator, which the weight gradient is added into."""
+    stack: jax.Array        # [L, E, K, N], every layer's
+    layer: jax.Array        # int32 [1]
+    into: jax.Array         # [L, E, K, N]
+
+    @property
+    def shape(self):
+        """The layer's own ``[E, K, N]``."""
+        return self.stack.shape[1:]
+
+    @property
+    def size(self) -> int:
+        return math.prod(self.shape)
+
+
+def grouped_matmul(x, w, tile_group, n_tiles, tile_m: int):
+    """x [M, K] (tile-aligned layout) @ w[group of the row's tile] [E, K, N]
+    -> [M, N]; differentiable in x and w. ``w`` an :class:`ExpertStack`: the
+    same over ``w.stack[w.layer]`` read where it lies, differentiable in x
+    and in ``w.into``, **whose cotangent is ``w.into`` with the weight's
+    gradient added into slice ``w.layer``** (the stack's own is none)."""
+    if isinstance(w, ExpertStack):
+        return _grouped_in_place(x, *w, tile_group, n_tiles, tile_m)
+    return _grouped(x, w, tile_group, n_tiles, tile_m)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def grouped_matmul(x, w, tile_group, n_tiles, tile_m: int):
-    """x [M, K] (tile-aligned layout) @ w[group of the row's tile] [E, K, N]
-    -> [M, N]; differentiable in x and w."""
+def _grouped(x, w, tile_group, n_tiles, tile_m):
     return gmm(x, w, tile_group, n_tiles, tile_m=tile_m)
 
 
@@ -215,4 +311,27 @@ def _grouped_bwd(tile_m, res, dy):
     return dx, dw, None, None
 
 
-grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
+_grouped.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _grouped_in_place(x, stack, layer, into, tile_group, n_tiles, tile_m):
+    del into
+    return gmm(x, stack, tile_group, n_tiles, layer, tile_m=tile_m)
+
+
+def _in_place_fwd(x, stack, layer, into, tile_group, n_tiles, tile_m):
+    return gmm(x, stack, tile_group, n_tiles, layer, tile_m=tile_m), \
+        (x, stack, layer, into, tile_group, n_tiles)
+
+
+def _in_place_bwd(tile_m, res, dy):
+    x, stack, layer, into, tile_group, n_tiles = res
+    dx = gmm(dy, stack, tile_group, n_tiles, layer, tile_m=tile_m,
+             transpose_rhs=True, name="tepdist_gmm_dx")
+    into = tgmm(x, dy, tile_group, n_tiles, stack.shape[1], into, layer,
+                tile_m=tile_m)
+    return dx, None, None, into, None, None
+
+
+_grouped_in_place.defvjp(_in_place_fwd, _in_place_bwd)

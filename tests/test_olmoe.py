@@ -137,6 +137,116 @@ def test_grouped_matmul_against_a_per_expert_loop(sizes):
         assert np.allclose(got, want, atol=1e-5)
 
 
+def _stack_case(sizes, dtype, acc_dtype=None, layers=3):
+    """A layout over ``sizes`` (its static size holds tiles past the live
+    ones), rows, cotangents, a stack of ``layers`` layers' weights and an
+    accumulator of random values."""
+    E, tile, K, N, k = len(sizes), 4, 16, 128, 2
+    flat = np.repeat(np.arange(E), sizes)
+    ids = jnp.asarray(np.random.default_rng(0).permutation(flat)
+                      .reshape(-1, k), jnp.int32)
+    r = gm.route(ids, E, tile)
+    assert int(r.n_tiles[0]) < r.tile_group.shape[0]
+    M = r.row_token.shape[0]
+    kx, kd, kw, ka = jax.random.split(jax.random.PRNGKey(3), 4)
+    x = jax.random.normal(kx, (M, K), dtype)
+    dy = jax.random.normal(kd, (M, N), dtype)
+    w = jax.random.normal(kw, (layers, E, K, N), dtype)
+    acc = jax.random.normal(ka, (layers, E, K, N), acc_dtype or dtype)
+    return r, tile, x, dy, w, acc
+
+
+def _bits(a):
+    return np.asarray(a).view(np.uint16 if a.dtype == jnp.bfloat16
+                              else np.uint32)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("sizes", [(5, 0, 9, 2), (0, 0, 16, 0), (4, 4, 4, 4),
+                                   (0, 7, 0, 9)])
+def test_the_stack_forms_are_the_sliced_calls_bit_for_bit(sizes, dtype):
+    """``gmm``, ``gmm(transpose_rhs)`` and ``tgmm`` over ``[L, E, K, N]``
+    and a layer's index against the rank-3 call on ``stack[l]``, for every
+    layer, with empty groups and tiles past ``n_tiles``; the weight
+    gradient into an accumulator is ``into[l] + tgmm(...)`` in the
+    accumulator's dtype and every other slice is left as it was."""
+    r, tile, x, dy, w, acc = _stack_case(sizes, dtype)
+    E = len(sizes)
+    for l in range(w.shape[0]):
+        layer = jnp.asarray([l], jnp.int32)
+        kw = dict(tile_m=tile)
+        for a, t in ((x, False), (dy, True)):
+            got = gmk.gmm(a, w, r.tile_group, r.n_tiles, layer,
+                          transpose_rhs=t, **kw)
+            want = gmk.gmm(a, w[l], r.tile_group, r.n_tiles,
+                           transpose_rhs=t, **kw)
+            np.testing.assert_array_equal(_bits(got), _bits(want))
+        got = gmk.tgmm(x, dy, r.tile_group, r.n_tiles, E, acc, layer, **kw)
+        dw = gmk.tgmm(x, dy, r.tile_group, r.n_tiles, E, **kw)
+        np.testing.assert_array_equal(_bits(got),
+                                      _bits(acc.at[l].set(acc[l] + dw)))
+
+
+def test_an_accumulator_of_another_dtype_takes_both_roundings():
+    """bf16 rows into a float32 accumulator: the group's float32 sum is
+    rounded to bf16 (the weight gradient as the sliced walk makes it), then
+    added in float32."""
+    sizes = (5, 0, 9, 2)
+    r, tile, x, dy, _, acc = _stack_case(sizes, jnp.bfloat16, jnp.float32)
+    layer = jnp.asarray([1], jnp.int32)
+    got = gmk.tgmm(x, dy, r.tile_group, r.n_tiles, len(sizes), acc, layer,
+                   tile_m=tile)
+    dw = gmk.tgmm(x, dy, r.tile_group, r.n_tiles, len(sizes), tile_m=tile)
+    assert got.dtype == jnp.float32 and dw.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(
+        _bits(got), _bits(acc.at[1].set(acc[1] + dw.astype(jnp.float32))))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_grouped_matmul_over_an_expert_stack(dtype):
+    """The custom VJP's form over ``(stack, layer, accumulator)``: the value
+    and the input gradient are the sliced form's, the accumulator's
+    cotangent is the accumulator with the weight gradient added into the
+    layer's slice, and the stack gets none."""
+    r, tile, x, dy, w, acc = _stack_case((5, 0, 9, 2), dtype)
+    layer = jnp.asarray([2], jnp.int32)
+
+    def sliced(x, w_l):
+        return gmk.grouped_matmul(x, w_l, r.tile_group, r.n_tiles, tile)
+
+    def in_place(x, into):
+        return gmk.grouped_matmul(x, gmk.ExpertStack(w, layer, into),
+                                  r.tile_group, r.n_tiles, tile)
+
+    want, pull = jax.vjp(sliced, x, w[2])
+    got, pull_in_place = jax.vjp(in_place, x, acc)
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    (dx, dw), (dx_in_place, into) = pull(dy), pull_in_place(dy)
+    np.testing.assert_array_equal(_bits(dx_in_place), _bits(dx))
+    np.testing.assert_array_equal(_bits(into),
+                                  _bits(acc.at[2].set(acc[2] + dw)))
+    stack_ct = jax.grad(lambda w: jnp.sum(gmk.grouped_matmul(
+        x, gmk.ExpertStack(w, layer, acc), r.tile_group, r.n_tiles,
+        tile).astype(jnp.float32)))(w)
+    assert not np.asarray(stack_ct, np.float32).any()
+
+
+def test_a_stack_wants_its_layer_and_a_slice_takes_none():
+    r, tile, x, dy, w, acc = _stack_case((4, 4, 4, 4), jnp.float32)
+    layer = jnp.asarray([0], jnp.int32)
+    with pytest.raises(ValueError, match="layer"):
+        gmk.gmm(x, w, r.tile_group, r.n_tiles, tile_m=tile)
+    with pytest.raises(ValueError, match="layer"):
+        gmk.gmm(x, w[0], r.tile_group, r.n_tiles, layer, tile_m=tile)
+    with pytest.raises(ValueError, match="into"):
+        gmk.tgmm(x, dy, r.tile_group, r.n_tiles, 4, acc, tile_m=tile)
+    with pytest.raises(ValueError, match="into"):
+        gmk.tgmm(x, dy, r.tile_group, r.n_tiles, 4, acc[:, :2], layer,
+                 tile_m=tile)
+
+
 @pytest.mark.parametrize("spec", ["balanced", "skewed"])
 def test_gmm_bench_check_reference_is_the_kernels_answer(spec):
     """``tools/gmm_bench.py --check`` on the chip compares the compiled

@@ -220,6 +220,47 @@ def test_grouped_matmul_kernels_compile_for_v5e(v5e_devices, K, N, E, rows):
                 if " copy(" in line and f"= bf16[{E}," in line]
 
 
+# The stack forms at the two cells that walk the widest expert stacks: ZAYA1's
+# five layers of 16 experts of 2048 x 2048 (8,192 rows and a tile's pads an
+# expert, tile 128) and Trinity's four of 32 of 2048 x 1024 (the smaller of
+# its layout's sizes, tile 256).
+@pytest.mark.parametrize("L,E,K,N,tile,rows", [
+    (5, 16, 2048, 2048, 128, 8192 + 16 * 128),
+    (4, 32, 2048, 1024, 256, 33024)])
+def test_grouped_matmul_stack_forms_compile_for_v5e(v5e_devices, L, E, K, N,
+                                                    tile, rows):
+    """Forward, input gradient and weight gradient over ``[L, E, K, N]``
+    and the layer's index, not interpreted: the kernels' weight operand is
+    the whole stack, the weight gradient's result is its accumulator's own
+    buffer, and nothing beside the three kernels makes an ``[E, K, N]`` or
+    an ``[L, E, K, N]`` array (no slice out, no update in, no copy)."""
+    from tepdist_tpu.ops.pallas import grouped_matmul as gmm
+    one_chip = SingleDeviceSharding(v5e_devices[0])
+    tiles = rows // tile
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def three(x, dy, w, into, tile_group, n_tiles, layer):
+        kw = dict(tile_m=tile, interpret=False)
+        return (gmm.gmm(x, w, tile_group, n_tiles, layer, **kw),
+                gmm.gmm(dy, w, tile_group, n_tiles, layer,
+                        transpose_rhs=True, name="tepdist_gmm_dx", **kw),
+                gmm.tgmm(x, dy, tile_group, n_tiles, E, into, layer, **kw))
+
+    text = jax.jit(three, donate_argnums=3).lower(
+        sds((rows, K)), sds((rows, N)), sds((L, E, K, N)),
+        sds((L, E, K, N)), sds((tiles,), jnp.int32), sds((1,), jnp.int32),
+        sds((1,), jnp.int32)).compile().as_text()
+    for name in ("tepdist_gmm_fwd", "tepdist_gmm_dx", "tepdist_gmm_dw"):
+        assert f"%{name}" in text, name
+    stack = rf"bf16\[{L},{E},\d+,\d+\]"
+    made = [line.strip() for line in text.splitlines() if re.search(
+        rf"= (?:{stack}|bf16\[{E},\d+,\d+\])\S* (?!parameter\()", line)]
+    assert len(made) == 1 and "%tepdist_gmm_dw" in made[0] \
+        and "output_to_operand_aliasing" in made[0], made
+
+
 def test_a_held_share_with_its_switch_compiles_for_v5e(v5e_devices,
                                                        monkeypatch):
     """``routed_experts`` at the Mellum2 cell's shapes (16,384 tokens, 16 of
@@ -436,6 +477,16 @@ def test_a_walked_block_keeps_its_flash_forward_in_the_compiled_step(
     # go through the row-copy kernel, forward and in ``dispatch``'s backward.
     assert metrics().gauge("moe_rows_sum_calls").value == 2 * 3
     assert "tepdist_rows_sum" in text and "tepdist_rows_tiled" in text
+    # ... and its kernels read the experts out of the stack through both
+    # sizes' branches of the ``switch``: every weight gradient's result is
+    # its accumulator's stack, and no update and no copy makes one (a
+    # conditional that handed a stack on by copying it would).
+    assert metrics().gauge("moe_stack_in_place_calls").value == 12 * 3
+    stack = r" = bf16\[3,4,(?:256,128|128,256)\]\S* "
+    made = [line.split(" = ", 1)[0].strip() for line in text.splitlines()
+            if re.search(stack + r"(?:custom-call|copy|dynamic-update-slice)"
+                         r"\(", line)]
+    assert len(made) == 6 and all("tepdist_gmm_dw" in m for m in made), made
     calls = [line.split(" = ", 1)[0] for line in text.splitlines()
              if " custom-call(" in line and "tepdist_flash_" in line]
     for which in ("fwd", "dkv"):
@@ -788,6 +839,7 @@ def test_the_sarvam_cells_step_compiles_for_v5e(v5e_devices, monkeypatch):
     assert gauge("mla_heads_held") == 16
     assert gauge("mla_latent_bytes") == T * (512 + 64) * 2
     assert gauge("moe_rows_sum_calls") == 8     # 2 a walked expert layer
+    assert gauge("moe_stack_in_place_calls") == 0   # a layer's chunks: slices
 
     text = compiled.as_text()
     calls = [line.split(" = ", 1)[0].strip() for line in text.splitlines()
@@ -912,10 +964,23 @@ def test_the_zaya_cells_step_compiles_for_v5e(v5e_devices, monkeypatch):
     assert gauge("router_carry_bytes") == T * 256 * 4
     assert gauge("moe_top1_rows") == 10240
     assert gauge("moe_rows_sum_calls") == 0     # every expert is resident
+    assert gauge("moe_stack_in_place_calls") == 5 * 12
 
     text = compiled.as_text()
     calls = [line.split(" = ", 1)[0].strip() for line in text.splitlines()
              if " custom-call(" in line]
+    # The experts' weights are read and summed where they lie: nothing in
+    # the step makes one layer's [16, 2048, 2048] (no slice out of a stack,
+    # no gradient on its way in), and of the kernels only the weight
+    # gradient's result is a whole stack, its accumulator's own buffer.
+    made = [line.split(" = ", 1) for line in text.splitlines()
+            if re.search(r" = bf16\[(?:1,)?16,2048,2048\]\S* (?!parameter)",
+                         line)]
+    assert not made, made[:3]
+    whole = [c for c in calls if re.search(
+        rf"{re.escape(c)} = bf16\[5,16,2048,2048\]", text)]
+    assert len(whole) == 3 and all("tepdist_gmm_dw" in c for c in whole), \
+        whole
     # One walk: the mixing's forward in its forward loop and in the backward
     # loop's recomputation, the flash forward in the forward loop alone.
     assert len([c for c in calls if "tepdist_cca_mix_fwd" in c]) == 2, calls

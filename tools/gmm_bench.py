@@ -20,11 +20,19 @@ read back as zeros, an empty group's weight gradient exactly zero. A result
 rounded once to bf16 is within 2**-8 of the float32 one; a form above that
 ends the run with 1.
 
+``--stack L`` times the kernels' other form beside each: the weight as one
+layer of ``[L, groups, K, N]`` read through the layer's index
+(``forward.stack``, ``input_grad.stack``), and the weight gradient added
+into an accumulator of that shape where it lies (``weight_grad.into``)
+against the weight gradient with XLA's slice, add and update of the
+accumulator (``weight_grad.sliced``: what a walk over slices runs a layer).
+With ``--check 1`` each is held to its counterpart bit for bit.
+
 No benchmark cell runs this; it is for work on the kernels. No CPU fallback.
 
 Run: chiprun -- python tools/gmm_bench.py [--rows 65536] [--k 2048]
      [--n 1024] [--groups 64] [--sizes balanced] [--tile-m 128,256,512]
-     [--block-n 1024] [--check 0]
+     [--block-n 1024] [--check 0] [--stack 0]
 """
 
 from __future__ import annotations
@@ -40,16 +48,25 @@ sys.path.insert(0, ROOT)
 CHECK_LIMIT = 2.0 ** -8
 
 
-def traced_us(fn, args, iters, path):
+def traced_us(fn, args, iters, path, carried=None):
     """Device microseconds a call of jitted ``fn``, and its four longest
-    operations."""
+    operations. ``carried``: the position of the operand a call's result
+    replaces (an accumulator the call was given to write over)."""
     import jax
     from benchmark.lib import tracing
-    jax.block_until_ready(fn(*args))                    # compiles
+    args = list(args)
+
+    def call():
+        out = fn(*args)
+        if carried is not None:
+            args[carried] = out
+        return out
+
+    jax.block_until_ready(call())                       # compiles
     tracing.discard(path)
     jax.profiler.start_trace(path)
     for _ in range(iters):
-        out = fn(*args)
+        out = call()
     jax.block_until_ready(out)
     jax.profiler.stop_trace()
     summary = tracing.reduce_trace(path)
@@ -105,6 +122,8 @@ def main(argv=None) -> int:
     ap.add_argument("--tile-m", default="128,256,512")
     ap.add_argument("--block-n", default="1024")
     ap.add_argument("--check", type=int, default=1)
+    ap.add_argument("--stack", type=int, default=0,
+                    help="layers of a stack to time the other forms on")
     ap.add_argument("--iters", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=None)
@@ -129,6 +148,13 @@ def main(argv=None) -> int:
     kx, kw, ky = jax.random.split(jax.random.PRNGKey(args.seed), 3)
     w = (jax.random.normal(kw, (G, K, N), jnp.float32) * 0.02).astype(bf16)
     records, sound = [], True
+    if args.stack:      # the weight is the middle layer of the stack
+        at = args.stack // 2
+        layer = jnp.asarray([at], jnp.int32)
+        ks, ka = jax.random.split(jax.random.PRNGKey(args.seed + 1))
+        stack = (jax.random.normal(ks, (args.stack, G, K, N), jnp.float32)
+                 * 0.02).astype(bf16).at[at].set(w)
+        acc = jax.random.normal(ka, (args.stack, G, K, N), bf16)
 
     for label, sizes in size_sets(args.sizes, args.rows, G, rng):
         R = int(sizes.sum())
@@ -159,6 +185,35 @@ def main(argv=None) -> int:
                 }
                 operands = {"forward": (xp, w), "input_grad": (dyp, w),
                             "weight_grad": (xp, dyp)}
+                carried = {}
+                if args.stack:
+                    def sliced(x, dy, acc, dw=forms["weight_grad"]):
+                        one = jax.lax.dynamic_index_in_dim(
+                            acc, layer[0], keepdims=False)
+                        return jax.lax.dynamic_update_index_in_dim(
+                            acc, one + dw(x, dy), layer[0], 0)
+
+                    forms.update({
+                        "forward.stack": jax.jit(lambda x, s: kernels.gmm(
+                            x, s, tg, nt, layer, tile_m=tm, block_n=bn,
+                            interpret=False)),
+                        "input_grad.stack": jax.jit(lambda dy, s: kernels.gmm(
+                            dy, s, tg, nt, layer, tile_m=tm, block_n=bn,
+                            transpose_rhs=True, name="tepdist_gmm_dx",
+                            interpret=False)),
+                        "weight_grad.into": jax.jit(
+                            lambda x, dy, acc: kernels.tgmm(
+                                x, dy, tg, nt, G, acc, layer, tile_m=tm,
+                                block_n=bn, interpret=False),
+                            donate_argnums=2),
+                        "weight_grad.sliced": jax.jit(sliced,
+                                                      donate_argnums=2)})
+                    operands.update({
+                        "forward.stack": (xp, stack),
+                        "input_grad.stack": (dyp, stack),
+                        "weight_grad.into": (xp, dyp, jnp.copy(acc)),
+                        "weight_grad.sliced": (xp, dyp, jnp.copy(acc))})
+                    carried = {"weight_grad.into": 2, "weight_grad.sliced": 2}
                 rec = {"impl": "pallas", "sizes": label, "rows": R, "K": K,
                        "N": N, "groups": G, "rows_max": int(sizes.max()),
                        "rows_min": int(sizes.min()), "tile_m": tm,
@@ -171,8 +226,10 @@ def main(argv=None) -> int:
                     if args.check:
                         pad = np.asarray(r.row_token) >= R
                         dest = np.asarray(r.dest)[:, 0]
-                        got = {f: np.asarray(fn(*operands[f]), np.float32)
-                               for f, fn in forms.items()}
+                        got = {f: np.asarray(forms[f](*operands[f]),
+                                             np.float32)
+                               for f in ("forward", "input_grad",
+                                         "weight_grad")}
                         empty = np.flatnonzero(sizes == 0)
                         rec["check"] = {
                             "rel_l2": {
@@ -194,17 +251,35 @@ def main(argv=None) -> int:
                             max(c["rel_l2"].values()) < CHECK_LIMIT
                             and c["pad_rows_zero"]
                             and c["empty_groups_dw_zero"])
+                        if args.stack:
+                            def same(f):
+                                return bool(jnp.array_equal(
+                                    forms[f](*operands[f]),
+                                    forms[f + ".stack"](
+                                        *operands[f + ".stack"])))
+
+                            c["stack_bit_for_bit"] = {
+                                "forward": same("forward"),
+                                "input_grad": same("input_grad"),
+                                "weight_grad": bool(jnp.array_equal(
+                                    forms["weight_grad.into"](
+                                        xp, dyp, jnp.copy(acc)),
+                                    forms["weight_grad.sliced"](
+                                        xp, dyp, jnp.copy(acc))))}
+                            c["sound"] = c["sound"] and all(
+                                c["stack_bit_for_bit"].values())
                         sound = sound and c["sound"]
                         del got
                     total = 0.0
                     for f, fn in forms.items():
                         us, ops = traced_us(
                             fn, operands[f], args.iters, os.path.join(
-                                trace_root, f"{label}_{tm}_{bn}_{f}"))
+                                trace_root, f"{label}_{tm}_{bn}_{f}"),
+                            carried.get(f))
                         rec[f] = {"us_per_call": us, "top_ops": ops,
                                   "roofline_share_pct":
                                       100.0 * rec["roofline_us"] / us}
-                        total += us
+                        total += us * ("." not in f)
                     rec["three_forms_us"] = total
                 except Exception as e:  # noqa: BLE001 — one refused variant
                     # must not cost the call that times the others
