@@ -1,0 +1,14 @@
+"""Share of the traced window's device self seconds under the program's
+``part_mlp`` scope: the dense blocks' MLPs with their norms (``_scopes.py``;
+the six parts and ``unscoped`` sum to 100), mean over the chips used."""
+
+from benchmark.layer_metrics import _scopes
+
+NAME, UNIT, LAYER = "scope_mlp_share.train", "%", "models"
+MOVES = "train_tokens_per_s_chip"
+KINDS = ("train",)
+SOURCE = "device_trace"
+
+
+def read(trace, host, cell):
+    return _scopes.part_share(trace, cell, "mlp")

@@ -1,0 +1,15 @@
+"""Share of the traced window's device self seconds under the program's
+``part_moe`` scope: the expert layers whole: router, dispatch, grouped
+matmuls, combine, shared expert (``_scopes.py``; the six parts and
+``unscoped`` sum to 100), mean over the chips used."""
+
+from benchmark.layer_metrics import _scopes
+
+NAME, UNIT, LAYER = "scope_moe_share.train", "%", "models"
+MOVES = "train_tokens_per_s_chip"
+KINDS = ("train",)
+SOURCE = "device_trace"
+
+
+def read(trace, host, cell):
+    return _scopes.part_share(trace, cell, "moe")

@@ -107,7 +107,15 @@ def inline_calls(jaxpr, max_depth: int = 16):
                         fresh = Var(ov.aval)
                         inner_env[ov] = fresh
                         new_outvars.append(fresh)
-                new_eqns.append(sub_eqn.replace(invars=new_invars, outvars=new_outvars))
+                # The call's own name stack goes before the inlined
+                # equation's, which is relative to it: the scopes round a
+                # ``jit`` or ``custom_vjp`` call stay on what it held.
+                info = sub_eqn.source_info
+                new_eqns.append(sub_eqn.replace(
+                    invars=new_invars, outvars=new_outvars,
+                    source_info=info.replace(
+                        name_stack=eqn.source_info.name_stack
+                        + info.name_stack)))
             # Wire sub outputs to the call's outvars.
             for call_out, sub_out in zip(eqn.outvars, sub.outvars):
                 if type(call_out).__name__ == "DropVar":

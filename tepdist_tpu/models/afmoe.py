@@ -68,7 +68,12 @@ from tepdist_tpu.models.decoder import (
     stack_layers,
     walk_layers,
 )
-from tepdist_tpu.models.layers import cross_entropy, gqa_heads, rms_norm
+from tepdist_tpu.models.layers import (
+    cross_entropy,
+    gqa_heads,
+    part,
+    rms_norm,
+)
 from tepdist_tpu.ops.grouped_matmul import routed_experts
 
 WINDOW, GLOBAL = "sliding_attention", "full_attention"
@@ -283,24 +288,28 @@ def moe(blk, x, cfg):
 def block(blk, x, cfg: AfmoeConfig, window):
     """One layer; dense or routed by what ``blk`` holds."""
     eps = cfg.rms_norm_eps
-    a = rms_norm(x, blk["input_ln"], eps)
-    x = x + rms_norm(attention(blk, a, cfg, window), blk["post_attn_ln"],
-                     eps)
-    h = rms_norm(x, blk["pre_mlp_ln"], eps)
-    y = moe(blk, h, cfg) if "router" in blk else swiglu(
-        h, blk["w_gate"], blk["w_up"], blk["w_down"])
-    return x + rms_norm(y, blk["post_mlp_ln"], eps)
+    with part("mixer"):
+        a = rms_norm(x, blk["input_ln"], eps)
+        x = x + rms_norm(attention(blk, a, cfg, window),
+                         blk["post_attn_ln"], eps)
+    with part("moe" if "router" in blk else "mlp"):
+        h = rms_norm(x, blk["pre_mlp_ln"], eps)
+        y = moe(blk, h, cfg) if "router" in blk else swiglu(
+            h, blk["w_gate"], blk["w_up"], blk["w_down"])
+        return x + rms_norm(y, blk["post_mlp_ln"], eps)
 
 
 def hidden_states(params, tokens, cfg: AfmoeConfig):
     """tokens int32 [B, T] -> final normalised hidden [B, T, d]."""
-    x = (params["tok_emb"][tokens]
-         * math.sqrt(cfg.hidden_size)).astype(cfg.dtype)
+    with part("embed"):
+        x = (params["tok_emb"][tokens]
+             * math.sqrt(cfg.hidden_size)).astype(cfg.dtype)
     x = walk_layers(lambda blk, h, window: block(blk, h, cfg, window), x,
                     params, _stacks(cfg),
                     [t == WINDOW for t in cfg.layer_types], cfg.remat,
                     experts=EXPERT_LEAVES)
-    return rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
+    with part("head_loss"):
+        return rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
 
 
 def forward(params, tokens, cfg: AfmoeConfig):

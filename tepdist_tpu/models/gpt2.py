@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from tepdist_tpu.models.layers import (
     cross_entropy,
+    part,
     rematerialised_whole,
     scan_blocks,
 )
@@ -192,17 +193,30 @@ def _remat_kwargs(cfg: GPT2Config) -> dict:
 
 
 def transformer_block(block, x, cfg: GPT2Config, attn_impl=None):
-    x = x + attention(block, _layer_norm(x, block["ln1_g"], block["ln1_b"]),
-                      cfg, attn_impl)
-    x = x + mlp(block, _layer_norm(x, block["ln2_g"], block["ln2_b"]))
+    with part("mixer"):
+        x = x + attention(
+            block, _layer_norm(x, block["ln1_g"], block["ln1_b"]), cfg,
+            attn_impl)
+    with part("mlp"):
+        x = x + mlp(block, _layer_norm(x, block["ln2_g"], block["ln2_b"]))
     return x
+
+
+def _embedded(params, tokens, cfg: GPT2Config):
+    """tokens int32 [B, T] -> token plus position embeddings [B, T, D]."""
+    with part("embed"):
+        x = params["wte"][tokens] + params["wpe"][:tokens.shape[1]]
+        return x.astype(cfg.dtype)
+
+
+def _final_norm(params, x):
+    with part("head_loss"):
+        return _layer_norm(x, params["ln_f_g"], params["ln_f_b"])
 
 
 def hidden_states(params, tokens, cfg: GPT2Config, attn_impl=None):
     """tokens: int32 [B, T] -> final (ln_f-normalised) hidden [B, T, D]."""
-    B, T = tokens.shape
-    x = params["wte"][tokens] + params["wpe"][:T]
-    x = x.astype(cfg.dtype)
+    x = _embedded(params, tokens, cfg)
     block_fn = transformer_block
     if cfg.remat:
         block_fn = jax.checkpoint(
@@ -213,7 +227,7 @@ def hidden_states(params, tokens, cfg: GPT2Config, attn_impl=None):
     else:
         for i in range(cfg.n_layer):
             x = block_fn(params[f"h{i}"], x, cfg, attn_impl)
-    return _layer_norm(x, params["ln_f_g"], params["ln_f_b"])
+    return _final_norm(params, x)
 
 
 def forward(params, tokens, cfg: GPT2Config, attn_impl=None):
@@ -246,9 +260,7 @@ def stacked_init_params(cfg: GPT2Config, key):
 def hidden_states_stacked(params, tokens, cfg: GPT2Config, attn_impl=None):
     """tokens: int32 [B, T] -> final hidden [B, T, D], scanning the
     stacked block params (one layer's HLO traced once)."""
-    B, T = tokens.shape
-    x = params["wte"][tokens] + params["wpe"][:T]
-    x = x.astype(cfg.dtype)
+    x = _embedded(params, tokens, cfg)
 
     def body(h, layer_params):
         return transformer_block(layer_params, h, cfg, attn_impl), None
@@ -266,7 +278,7 @@ def hidden_states_stacked(params, tokens, cfg: GPT2Config, attn_impl=None):
         if cfg.remat:
             body = jax.checkpoint(body, **_remat_kwargs(cfg))
         x, _ = jax.lax.scan(body, x, params["blocks"])
-    return _layer_norm(x, params["ln_f_g"], params["ln_f_b"])
+    return _final_norm(params, x)
 
 
 def forward_stacked(params, tokens, cfg: GPT2Config, attn_impl=None):
@@ -395,8 +407,7 @@ def pipelined_loss_fn(params, stacked_blocks, tokens, cfg: GPT2Config,
     T = Tfull - 1
     inputs = tokens[:, :-1]
     targets = tokens[:, 1:]
-    x = params["wte"][inputs] + params["wpe"][:T]
-    x = x.astype(cfg.dtype)
+    x = _embedded(params, inputs, cfg)
     # Micro-batch the embedded activations: [M, mb, T, D].
     mb = B // num_micro
     x_micro = x.reshape(num_micro, mb, T, cfg.n_embd)
@@ -405,8 +416,8 @@ def pipelined_loss_fn(params, stacked_blocks, tokens, cfg: GPT2Config,
         model_axis=model_axis)
     y_micro = pipelined(stacked_blocks, x_micro)
     y = y_micro.reshape(B, T, cfg.n_embd)
-    y = _layer_norm(y, params["ln_f_g"], params["ln_f_b"])
-    return cross_entropy(y, params["wte"], targets, cfg.loss_chunk)
+    return cross_entropy(_final_norm(params, y), params["wte"], targets,
+                         cfg.loss_chunk)
 
 
 def fake_batch(cfg: GPT2Config, batch_size: int, seq_len: Optional[int] = None,

@@ -60,7 +60,12 @@ from tepdist_tpu.models.decoder import (
     stack_layers,
     walk_layers,
 )
-from tepdist_tpu.models.layers import cross_entropy, gqa_heads, rms_norm
+from tepdist_tpu.models.layers import (
+    cross_entropy,
+    gqa_heads,
+    part,
+    rms_norm,
+)
 from tepdist_tpu.ops.pallas.causal_conv import causal_conv
 from tepdist_tpu.ops.pallas.selective_scan import (
     BLOCK_D,
@@ -253,17 +258,21 @@ def mlp(blk, a):
 def block(blk, x, cfg: JambaConfig, kind: str):
     eps = cfg.rms_norm_eps
     mixer = attention if kind == ATTENTION else mamba_mixer
-    x = x + mixer(blk, rms_norm(x, blk["input_ln"], eps), cfg)
-    return x + mlp(blk, rms_norm(x, blk["ff_ln"], eps))
+    with part("mixer"):
+        x = x + mixer(blk, rms_norm(x, blk["input_ln"], eps), cfg)
+    with part("mlp"):
+        return x + mlp(blk, rms_norm(x, blk["ff_ln"], eps))
 
 
 def hidden_states(params, tokens, cfg: JambaConfig):
     """tokens int32 [B, T] -> final normalised hidden [B, T, d]."""
-    x = params["tok_emb"][tokens].astype(cfg.dtype)
+    with part("embed"):
+        x = params["tok_emb"][tokens].astype(cfg.dtype)
     x = walk_layers(lambda blk, h, kind: block(blk, h, cfg, kind), x,
                     params, run_stacks(cfg.layer_kinds), cfg.layer_kinds,
                     cfg.remat, GROUPS)
-    return rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
+    with part("head_loss"):
+        return rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
 
 
 def forward(params, tokens, cfg: JambaConfig):

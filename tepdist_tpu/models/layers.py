@@ -41,6 +41,29 @@ from tepdist_tpu.ops.pallas.flash_attention import (
 )
 from tepdist_tpu.telemetry import metrics, traced
 
+# The step's device time by the program's own parts: every model puts its
+# work under one of these six ``jax.named_scope``s and the walk below puts its
+# three phases round a block's runs, so a profile groups a step's operations
+# the same way on every model (xprof's framework-operation view; the
+# benchmark's ``scope_*_share.train`` readers). A scope is spelt ``part_<name>``
+# and a phase ``walk_<name>``: JAX wraps scopes in its transforms' names
+# (``transpose(jvp(part_mixer/mla_q))/dot_general``), so a reader looks for the
+# whole word anywhere in an operation's name stack, and no primitive, einsum
+# string or other scope of this repo is spelt so. Metadata only: a scope changes
+# no instruction of the compiled step.
+PARTS = ("embed", "mixer", "mlp", "moe", "head_loss", "optimizer")
+PHASES = WALK_FWD, WALK_RECOMPUTE, WALK_BWD = (
+    "walk_fwd", "walk_recompute", "walk_bwd")
+
+
+def part(name: str):
+    """``with part("mixer"):`` the operations traced inside belong to that
+    part of the step (one of :data:`PARTS`)."""
+    if name not in PARTS:
+        raise ValueError(f"{name!r} is no part of a step: {PARTS}")
+    return jax.named_scope("part_" + name)
+
+
 traced.declare(
     "attn_kept_calls", "calls a micro batch (a flash or block top-k "
     "kernel's forward, a sparse layer's choice) that hand what their forward "
@@ -248,8 +271,9 @@ def _walk_accumulating(body, x, blocks, acc, kinds, in_place=()):
     @jax.custom_vjp
     def walk(x, blocks, acc):
         del acc
-        return jax.lax.scan(body, x,
-                            blocks if kinds is None else (blocks, kinds))
+        with jax.named_scope(WALK_FWD):
+            return jax.lax.scan(body, x,
+                                blocks if kinds is None else (blocks, kinds))
 
     def fwd(x, blocks, acc):
         kernel_calls = []       # one entry a trace of the body: a layer's
@@ -257,7 +281,8 @@ def _walk_accumulating(body, x, blocks, acc, kinds, in_place=()):
         into = apart(acc)[1]    # no forward pass writes it
 
         def step(h, per_layer):
-            with KeptForward() as keep, counting_kernel_calls() as calls:
+            with KeptForward() as keep, counting_kernel_calls() as calls, \
+                    jax.named_scope(WALK_FWD):
                 out, y = body(h, whole(*per_layer, stacks, into))
             kernel_calls.append(calls)
             return out, (h, y, keep.kept)
@@ -287,15 +312,19 @@ def _walk_accumulating(body, x, blocks, acc, kinds, in_place=()):
                 with KeptForward(saved):
                     return body(h, whole(layer, block, kind, stacks, into))
 
-            _, pull = jax.vjp(recompute, h, block, into)
+            with jax.named_scope(WALK_RECOMPUTE):
+                _, pull = jax.vjp(recompute, h, block, into)
             # A stack's accumulator comes back with its layer's gradient
             # added where it lies; the other leaves' are added here.
-            dh, d_block, into = pull((dh, d_y))
-            acc = jax.tree_util.tree_map(
-                lambda a, g: jax.lax.dynamic_update_index_in_dim(
-                    a, jax.lax.dynamic_index_in_dim(a, layer, keepdims=False)
-                    + g.astype(a.dtype), layer, 0),
-                acc, d_block)
+            with jax.named_scope(WALK_BWD):
+                dh, d_block, into = pull((dh, d_y))
+                with part("optimizer"):     # gradient accumulation
+                    acc = jax.tree_util.tree_map(
+                        lambda a, g: jax.lax.dynamic_update_index_in_dim(
+                            a, jax.lax.dynamic_index_in_dim(
+                                a, layer, keepdims=False) + g.astype(a.dtype),
+                            layer, 0),
+                        acc, d_block)
             return (dh, {**acc, **into} if in_place else acc), None
 
         (dx, acc), _ = jax.lax.scan(
@@ -561,7 +590,12 @@ def cross_entropy(x, head, targets, chunk: int = 0):
 
     The gauge ``ce_fused_chunks`` is set while the call is traced: the
     chunks whose gradients the forward loop makes, 0 for a dense or an
-    undifferentiated call."""
+    undifferentiated call. All of it is the step's ``head_loss`` part."""
+    with part("head_loss"):
+        return _cross_entropy(x, head, targets, chunk)
+
+
+def _cross_entropy(x, head, targets, chunk):
     B, T, D = x.shape
     n_tokens = B * T
     traced.note("ce_fused_chunks", 0)
@@ -629,8 +663,9 @@ def cross_entropy(x, head, targets, chunk: int = 0):
 
     def bwd(residuals, g):
         dx, dhead = residuals
-        return ((dx * g).astype(dx.dtype), (dhead * g).astype(dhead.dtype),
-                None, None)
+        with part("head_loss"):     # traced by the backward pass, later
+            return ((dx * g).astype(dx.dtype),
+                    (dhead * g).astype(dhead.dtype), None, None)
 
     mean_loss.defvjp(fwd, bwd)
     return mean_loss(xf, head, tf, valid)

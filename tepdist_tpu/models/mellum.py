@@ -59,6 +59,7 @@ from tepdist_tpu.models.layers import (
     RopeTable,
     cross_entropy,
     gqa_heads,
+    part,
     rms_norm,
     yarn_table,
 )
@@ -211,18 +212,23 @@ def moe(blk, x, cfg: MellumConfig):
 
 def block(blk, x, cfg: MellumConfig, window):
     eps = cfg.rms_norm_eps
-    x = x + attention(blk, rms_norm(x, blk["input_ln"], eps), cfg, window)
-    return x + moe(blk, rms_norm(x, blk["post_attn_ln"], eps), cfg)
+    with part("mixer"):
+        x = x + attention(blk, rms_norm(x, blk["input_ln"], eps), cfg,
+                          window)
+    with part("moe"):
+        return x + moe(blk, rms_norm(x, blk["post_attn_ln"], eps), cfg)
 
 
 def hidden_states(params, tokens, cfg: MellumConfig):
     """tokens int32 [B, T] -> final normalised hidden [B, T, d]."""
-    x = params["tok_emb"][tokens].astype(cfg.dtype)
+    with part("embed"):
+        x = params["tok_emb"][tokens].astype(cfg.dtype)
     x = walk_layers(lambda blk, h, window: block(blk, h, cfg, window), x,
                     params, _stacks(cfg),
                     [t == WINDOW for t in cfg.layer_types], cfg.remat,
                     experts=EXPERT_LEAVES)
-    return rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
+    with part("head_loss"):
+        return rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
 
 
 def forward(params, tokens, cfg: MellumConfig):

@@ -64,7 +64,12 @@ from tepdist_tpu.models.decoder import (
     stack_layers,
     walk_layers,
 )
-from tepdist_tpu.models.layers import cross_entropy, over_sequence, rms_norm
+from tepdist_tpu.models.layers import (
+    cross_entropy,
+    over_sequence,
+    part,
+    rms_norm,
+)
 from tepdist_tpu.ops.pallas.block_topk_attention import (
     BlockGeometry,
     kept_choice,
@@ -277,25 +282,28 @@ def block(blk, x, cfg: MiniCPMSALAConfig, kind: str, log_decay=None):
         return mixer_inputs(blk, rms_norm(xc, blk["input_ln"], eps), cfg,
                             kind, start)
 
-    with jax.named_scope("mixer_in"):
-        q, k, v = over_sequence(before, widest, x)
-    if kind == SPARSE:
-        o = sparse_attention(q, k, v, cfg)
-    else:
-        with jax.named_scope("lin_attn"):
-            o = lightning_attention(q, k, v, log_decay)
+    with part("mixer"):
+        with jax.named_scope("mixer_in"):
+            q, k, v = over_sequence(before, widest, x)
+        if kind == SPARSE:
+            o = sparse_attention(q, k, v, cfg)
+        else:
+            with jax.named_scope("lin_attn"):
+                o = lightning_attention(q, k, v, log_decay)
 
     def after(start, xc, oc):
         del start
-        gate = jax.nn.sigmoid((rms_norm(xc, blk["input_ln"], eps)
-                               @ blk["wg"]).astype(jnp.float32))
-        if kind == LIGHTNING:
-            oc = rms_norm(oc, blk["o_norm"], eps)
-        xc = xc + (r * ((gate * oc).astype(xc.dtype) @ blk["wo"])).astype(
-            xc.dtype)
-        a = rms_norm(xc, blk["ff_ln"], eps)
-        up = jax.nn.silu(a @ blk["w_gate"]) * (a @ blk["w_up"])
-        return xc + (r * (up @ blk["w_down"])).astype(xc.dtype)
+        with part("mixer"):
+            gate = jax.nn.sigmoid((rms_norm(xc, blk["input_ln"], eps)
+                                   @ blk["wg"]).astype(jnp.float32))
+            if kind == LIGHTNING:
+                oc = rms_norm(oc, blk["o_norm"], eps)
+            xc = xc + (r * ((gate * oc).astype(xc.dtype)
+                            @ blk["wo"])).astype(xc.dtype)
+        with part("mlp"):
+            a = rms_norm(xc, blk["ff_ln"], eps)
+            up = jax.nn.silu(a @ blk["w_gate"]) * (a @ blk["w_up"])
+            return xc + (r * (up @ blk["w_down"])).astype(xc.dtype)
 
     with jax.named_scope("mixer_out_mlp"):
         return over_sequence(after, widest, x, o)
@@ -304,8 +312,9 @@ def block(blk, x, cfg: MiniCPMSALAConfig, kind: str, log_decay=None):
 def hidden_states(params, tokens, cfg: MiniCPMSALAConfig):
     """tokens int32 [B, T] -> final normalised hidden [B, T, d], divided by
     ``hidden_size / dim_model_base`` as the head wants it."""
-    x = (params["tok_emb"][tokens].astype(jnp.float32)
-         * cfg.scale_emb).astype(cfg.dtype)
+    with part("embed"):
+        x = (params["tok_emb"][tokens].astype(jnp.float32)
+             * cfg.scale_emb).astype(cfg.dtype)
     # A layer's kind by what its block holds (``_layer_params``).
     x = walk_layers(
         lambda blk, h, log_decay: block(
@@ -313,9 +322,10 @@ def hidden_states(params, tokens, cfg: MiniCPMSALAConfig):
         x, params, run_stacks(cfg.mixer_types),
         [log_decays(cfg, i) for i in range(cfg.num_hidden_layers)],
         cfg.remat, GROUPS)
-    x = rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
-    return (x.astype(jnp.float32)
-            / (cfg.hidden_size / cfg.dim_model_base)).astype(x.dtype)
+    with part("head_loss"):
+        x = rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
+        return (x.astype(jnp.float32)
+                / (cfg.hidden_size / cfg.dim_model_base)).astype(x.dtype)
 
 
 def forward(params, tokens, cfg: MiniCPMSALAConfig):

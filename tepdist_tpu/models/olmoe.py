@@ -47,6 +47,7 @@ from tepdist_tpu.models.decoder import (
 )
 from tepdist_tpu.models.layers import (
     cross_entropy,
+    part,
     rms_norm,
     rope,
     scan_blocks,
@@ -194,15 +195,18 @@ def moe(blk, x, cfg: OlmoeConfig):
 
 def block(blk, x, cfg: OlmoeConfig):
     eps = cfg.rms_norm_eps
-    x = x + attention(blk, rms_norm(x, blk["attn_norm"], eps), cfg)
-    y, lb, zl = moe(blk, rms_norm(x, blk["ffn_norm"], eps), cfg)
-    return x + y, lb, zl
+    with part("mixer"):
+        x = x + attention(blk, rms_norm(x, blk["attn_norm"], eps), cfg)
+    with part("moe"):
+        y, lb, zl = moe(blk, rms_norm(x, blk["ffn_norm"], eps), cfg)
+        return x + y, lb, zl
 
 
 def hidden_states(params, tokens, cfg: OlmoeConfig):
     """tokens int32 [B, T] -> (final normalised hidden [B, T, d],
     load-balancing loss, router z-loss), the losses averaged over layers."""
-    x = params["tok_emb"][tokens].astype(cfg.dtype)
+    with part("embed"):
+        x = params["tok_emb"][tokens].astype(cfg.dtype)
 
     def body(h, blk):
         h, lb, zl = block(blk, h, cfg)
@@ -224,7 +228,8 @@ def hidden_states(params, tokens, cfg: OlmoeConfig):
             x, layer_aux = body(x, params[f"l{i}"])
             aux.append(layer_aux)
         lb, zl = (sum(a) / len(aux) for a in zip(*aux))
-    return rms_norm(x, params["norm_f"], cfg.rms_norm_eps), lb, zl
+    with part("head_loss"):
+        return rms_norm(x, params["norm_f"], cfg.rms_norm_eps), lb, zl
 
 
 def forward(params, tokens, cfg: OlmoeConfig):

@@ -83,6 +83,7 @@ from tepdist_tpu.models.layers import (
     RopeTable,
     cross_entropy,
     over_sequence,
+    part,
     rms_norm,
     rope,
     yarn_table,
@@ -348,28 +349,32 @@ def block(blk, x, cfg: SarvamMLAConfig):
 
     def after(start, xc, oc):
         del start
-        with jax.named_scope("mla_out"):
+        with part("mixer"), jax.named_scope("mla_out"):
             xc = xc + oc @ blk["wo"]
-        h = rms_norm(xc, blk["post_attn_ln"], eps)
-        if "router" in blk:
-            return xc + moe(blk, h, cfg)
-        return xc + swiglu(h, blk["w_gate"], blk["w_up"], blk["w_down"])
+        with part("moe" if "router" in blk else "mlp"):
+            h = rms_norm(xc, blk["post_attn_ln"], eps)
+            if "router" in blk:
+                return xc + moe(blk, h, cfg)
+            return xc + swiglu(h, blk["w_gate"], blk["w_up"], blk["w_down"])
 
-    o = attend(blk, x, cfg)
+    with part("mixer"):
+        o = attend(blk, x, cfg)
     with jax.named_scope("mla_out_mlp"):
         return over_sequence(after, _widest(cfg), x, o)
 
 
 def hidden_states(params, tokens, cfg: SarvamMLAConfig):
     """tokens int32 [B, T] -> final normalised hidden [B, T, d]."""
-    x = params["tok_emb"][tokens].astype(cfg.dtype)
+    with part("embed"):
+        x = params["tok_emb"][tokens].astype(cfg.dtype)
     # No ``experts=``: the expert layer runs once a chunk of the sequence
     # (``block``'s ``over_sequence``), and a gradient accumulator handed
     # back as a cotangent would be summed once a chunk; the kernels take
     # slices here.
     x = walk_layers(lambda blk, h, _: block(blk, h, cfg), x, params,
                     _stacks(cfg), [None] * cfg.num_hidden_layers, cfg.remat)
-    return rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
+    with part("head_loss"):
+        return rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
 
 
 def forward(params, tokens, cfg: SarvamMLAConfig):

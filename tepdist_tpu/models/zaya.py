@@ -79,7 +79,7 @@ from tepdist_tpu.models.decoder import (
     stack_layers,
     walk_layers,
 )
-from tepdist_tpu.models.layers import cross_entropy, rms_norm, rope
+from tepdist_tpu.models.layers import cross_entropy, part, rms_norm, rope
 from tepdist_tpu.ops.grouped_matmul import layout_rows, routed_experts
 from tepdist_tpu.ops.pallas import cca_mix
 from tepdist_tpu.ops.pallas.flash_attention import flash_attention
@@ -328,15 +328,19 @@ def expert_layer(blk, x, r_before, cfg: ZayaConfig):
 def block(blk, carry, cfg: ZayaConfig):
     """One layer: ``(x, r)`` in, ``(x, r)`` out."""
     x, r = carry
-    x = x + attention(blk, x, cfg)
-    y, r, _ = expert_layer(blk, x, r, cfg)
-    return x + y, r
+    with part("mixer"):
+        x = x + attention(blk, x, cfg)
+    with part("moe"):
+        y, r, _ = expert_layer(blk, x, r, cfg)
+        return x + y, r
 
 
 def _start(params, tokens, cfg: ZayaConfig):
     """The walk's first carry: the embeddings and ``r_{-1} = 0``."""
-    x = params["tok_emb"][tokens].astype(cfg.dtype)
-    return x, jnp.zeros(x.shape[:2] + (cfg.router_hidden_size,), jnp.float32)
+    with part("embed"):
+        x = params["tok_emb"][tokens].astype(cfg.dtype)
+        return x, jnp.zeros(x.shape[:2] + (cfg.router_hidden_size,),
+                            jnp.float32)
 
 
 def hidden_states(params, tokens, cfg: ZayaConfig):
@@ -345,7 +349,8 @@ def hidden_states(params, tokens, cfg: ZayaConfig):
                        _start(params, tokens, cfg), params, _stacks(cfg),
                        [None] * cfg.num_hidden_layers, cfg.remat,
                        experts=decoder.EXPERT_LEAVES)
-    return rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
+    with part("head_loss"):
+        return rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
 
 
 def forward(params, tokens, cfg: ZayaConfig):

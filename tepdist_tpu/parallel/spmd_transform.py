@@ -18,6 +18,7 @@ constraints woven in — so the executed program is exactly the analyzed one.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
@@ -111,9 +112,27 @@ def _retrace_for_mesh(closed, mesh: Mesh):
     return jax.make_jaxpr(body)(*avals)
 
 
+def _under_its_scopes(eqn) -> contextlib.ExitStack:
+    """The name stack ``eqn`` was traced under, entered again. An equation
+    bound anew takes the name stack of where it is bound, and the graph's own
+    (``part_optimizer`` on the update, ``jvp(...)`` where a transform put it)
+    would be lost to the compiled step's operation metadata, which is where a
+    device trace finds the program's scopes (``models/layers.py:PARTS``)."""
+    stack = contextlib.ExitStack()
+    for name in filter(None, str(eqn.source_info.name_stack).split("/")):
+        stack.enter_context(jax.named_scope(name))
+    return stack
+
+
 def bind_for_mesh(eqn, vals, mesh: Mesh) -> list:
     """``eqn.primitive.bind`` on ``vals``, as a list of outputs, with any
-    pallas kernel in it (or in its bodies) bound for ``mesh``."""
+    pallas kernel in it (or in its bodies) bound for ``mesh``, under the
+    scopes the equation was traced under."""
+    with _under_its_scopes(eqn):
+        return _bind_for_mesh(eqn, vals, mesh)
+
+
+def _bind_for_mesh(eqn, vals, mesh: Mesh) -> list:
     # get_bind_params: staged params -> bindable form (how eval_jaxpr
     # re-binds pjit/shard_map/custom_* eqns).
     subfuns, params = eqn.primitive.get_bind_params(eqn.params)

@@ -37,7 +37,7 @@ from jax.extend import core as jexcore
 from tepdist_tpu.core.service_env import ServiceEnv
 from tepdist_tpu.graph.cost import aval_bytes
 from tepdist_tpu.graph.jaxpr_graph import JaxprGraph
-from tepdist_tpu.models.layers import BlockGradSink
+from tepdist_tpu.models.layers import BlockGradSink, part
 from tepdist_tpu.parallel.performance_utils import chip_spec
 from tepdist_tpu.parallel.strategy_utils import StrategyUtil
 from tepdist_tpu.core.dist_spec import DimStrategy
@@ -422,7 +422,8 @@ def build_ga_step(
 
         # GAInit: zero accumulators shaped like the gradients (fp32 even
         # under FP16_COMM: only the per-micro contributions are compressed).
-        acc0 = jax.tree_util.tree_map(jnp.zeros_like, params)
+        with part("optimizer"):
+            acc0 = jax.tree_util.tree_map(jnp.zeros_like, params)
         leaves, treedef = jax.tree_util.tree_flatten(params)
         walked = ()
         if loss_fn is not None and not (int8 or compress):
@@ -437,9 +438,10 @@ def build_ga_step(
             micro_index, mb = xs
             acc, loss_sum = carry
             loss, grads = grad_fn(params, *mb)
-            grads = maybe_compress(grads, micro_index)
-            acc = jax.tree_util.tree_map(
-                lambda a, g: a + g.astype(a.dtype), acc, grads)
+            with part("optimizer"):
+                grads = maybe_compress(grads, micro_index)
+                acc = jax.tree_util.tree_map(
+                    lambda a, g: a + g.astype(a.dtype), acc, grads)
             return (acc, loss_sum + loss), None
 
         def body_walked(carry, xs):
@@ -468,8 +470,9 @@ def build_ga_step(
             loss, (grads, walked_acc) = jax.value_and_grad(
                 loss_of, argnums=(0, 1))(
                     [leaves[i] for i in rest], [acc[i] for i in walked])
-            for i, g in zip(rest, grads):
-                acc[i] = acc[i] + g.astype(acc[i].dtype)
+            with part("optimizer"):
+                for i, g in zip(rest, grads):
+                    acc[i] = acc[i] + g.astype(acc[i].dtype)
             for i, a in zip(walked, walked_acc):
                 acc[i] = a
             return (treedef.unflatten(acc), loss_sum + loss), None
@@ -479,7 +482,8 @@ def build_ga_step(
             body_walked if walked else body, (acc0, jnp.zeros(())),
             (micro_index, micro_batches))
         inv = 1.0 / num_micro_batches
-        grads = jax.tree_util.tree_map(lambda g: g * inv, acc)
+        with part("optimizer"):
+            grads = jax.tree_util.tree_map(lambda g: g * inv, acc)
         # AG: apply-gradients slice (or the ZeRO RS->apply->AG update).
         params, opt_state = do_apply(params, opt_state, grads)
         return loss_sum * inv, params, opt_state

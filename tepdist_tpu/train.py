@@ -25,6 +25,7 @@ import jax
 
 from tepdist_tpu.core.mesh import MeshTopology
 from tepdist_tpu.core.service_env import ServiceEnv
+from tepdist_tpu.models.layers import part
 from tepdist_tpu.telemetry import metrics, span, traced
 
 log = logging.getLogger(__name__)
@@ -324,9 +325,10 @@ def plan_training(
         return jax.value_and_grad(loss_fn)(p, *b)
 
     def apply_fn(p, s, g):
-        updates, s = optimizer.update(g, s, p)
         import optax as _o
-        return _o.apply_updates(p, updates), s
+        with part("optimizer"):
+            updates, s = optimizer.update(g, s, p)
+            return _o.apply_updates(p, updates), s
 
     # ---- pipeline path ------------------------------------------------
     if num_stages > 1:

@@ -53,13 +53,22 @@ def compiled_step(cell_name: str, device):
     return jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
 
 
+def kernel(name: str) -> str:
+    """A custom call's kernel: its instruction's name less XLA's numbering.
+    A Pallas kernel's instruction is named from its place in the name stack
+    (``jvp_tepdist_flash_fwd__c1__s0.125__h25_`` under one scope,
+    ``tepdist_flash_fwd__c1__s0.125__h25`` under another), so of such a name
+    the kernel's own part counts and not what stands round it."""
+    own = re.search(r"tepdist_.*", re.sub(r"\.\d+$", "", name))
+    return own[0].rstrip("_") if own else re.sub(r"[.\d]+$", "", name)
+
+
 def histogram(text: str) -> dict:
-    """"opcode shape [kernel]" -> count; a custom call's kernel is its
-    instruction's name less XLA's numbering."""
+    """"opcode shape [kernel]" -> count."""
     counts = collections.Counter()
     for m in filter(None, map(_INSTRUCTION.match, text.splitlines())):
-        kernel = re.sub(r"[.\d]+$", "", m["name"]) * (m["op"] == "custom-call")
-        counts[" ".join(filter(None, (m["op"], m["shape"], kernel)))] += 1
+        name = kernel(m["name"]) if m["op"] == "custom-call" else ""
+        counts[" ".join(filter(None, (m["op"], m["shape"], name)))] += 1
     return dict(sorted(counts.items()))
 
 
