@@ -124,7 +124,9 @@ def walk_layers(layer: Callable, x, params, stacks: Sequence[Stack],
     else. Where a stack has them (``[layers, experts, K, N]``; a dense
     stack's leaf of the same name is no such) a walk that accumulates
     gradients gives the layer an ``ExpertStack`` for each
-    (``scan_blocks(in_place=)``)."""
+    (``scan_blocks(in_place=)``). A layer that runs its experts once a chunk
+    of the sequence hands them to ``over_sequence(weights=)`` and closes
+    over none of them (``models/sarvam_mla.py:block``)."""
     if "l0" in params:
         for i, row in enumerate(rows):
             static = isinstance(row, Hashable)

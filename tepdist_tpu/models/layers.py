@@ -78,8 +78,10 @@ traced.declare(
 traced.declare(
     "moe_stack_in_place_calls", "grouped-matmul calls a micro batch that "
     "read their weights out of the layers' stack or added into its "
-    "accumulator where they lie: 12 a walked expert layer, 0 where the walk "
-    "hands the kernels slices")
+    "accumulator where they lie: 12 a walked expert layer (a layer whose "
+    "token-wise parts run in chunks of the sequence counts once: a chunk's "
+    "trace stands for its chunks), 0 where the walk hands the kernels "
+    "slices")
 traced.declare(
     "ce_fused_chunks", "chunks of the loss whose gradients its forward chunk "
     "loop makes (0: the dense loss, or a call nobody differentiates)")
@@ -164,10 +166,11 @@ def scan_blocks(body, x, blocks, kinds=None, remat: bool = True,
     gradient in, the same two roundings (``ops/pallas/grouped_matmul.py``;
     the gauge ``moe_stack_in_place_calls``). Every other leaf and every
     other path (the plain scan, the recording pass, ``remat=False``) takes
-    slices as before. Not for a body that runs its expert layer more than
-    once a layer (``models/sarvam_mla.py``: inside ``over_sequence``'s
-    chunks, where an accumulator handed back as a cotangent would be summed
-    once a chunk).
+    slices as before. A body must hand each accumulator back as a cotangent
+    once: one that runs its expert layer once a chunk of the sequence
+    (``models/sarvam_mla.py``) hands the layer's weights to
+    :func:`over_sequence` (``weights=``), whose backward carries the
+    accumulator from chunk to chunk where autodiff would sum one a chunk.
 
     That walk saves, beside a block's input, the ``(o, lse)`` of every flash
     call in it (``ops/pallas/flash_attention.py:KeptForward``) and of every
@@ -348,19 +351,29 @@ def tokens_a_chunk(B: int, T: int, widest: int) -> int:
     return next(c for c in range(min(T, most), 0, -1) if T % c == 0)
 
 
-def over_sequence(fn, widest: int, *xs):
+def over_sequence(fn, widest: int, *xs, weights=None):
     """``fn(start, *chunks)`` over chunks of the sequence (axis 1 of every
     ``x`` [B, T, ...]; ``start`` the chunk's first position), each chunk
     rematerialised in the backward pass; the results [B, T, ...] again.
     ``fn`` returns an array or a tuple of arrays. For a block's token-wise
     parts (norms, projections, gates, an MLP), so that its working set holds
     ``[T, hidden]`` arrays and never a ``[T, widest]`` one; gradients of a
-    weight are summed over the chunks in the weight's dtype."""
+    weight are summed over the chunks in the weight's dtype.
+
+    ``weights`` (a pytree) hands ``fn`` its parameters, ``fn(weights, start,
+    *chunks)``, where it would close over them. One algorithm either way: a
+    ``jax.lax.map`` over a ``jax.checkpoint`` of the chunk, whose transpose
+    starts every weight's gradient at zeros and adds a chunk's to it, last
+    chunk first. Where one of ``weights`` is an :class:`ExpertStack` and the
+    sequence is more than one chunk that backward is written out
+    (:func:`_chunks_carrying`), the same sums for every plain leaf, because
+    a stack's accumulator may not be summed: the weight-gradient kernel
+    hands it back with the chunk's gradient added where it lies, and that
+    array is the next chunk's accumulator, so a layer inside a walk that
+    accumulates gradients (``scan_blocks(in_place=)``) adds each chunk's
+    expert gradients straight into the walk's accumulator."""
     B, T = xs[0].shape[:2]
     chunk = tokens_a_chunk(B, T, widest)
-    fn = jax.checkpoint(fn)
-    if chunk == T:
-        return fn(jnp.int32(0), *xs)
     n = T // chunk
 
     def cut(x):
@@ -369,10 +382,93 @@ def over_sequence(fn, widest: int, *xs):
     def joined(y):
         return jnp.moveaxis(y, 0, 1).reshape(B, T, *y.shape[3:])
 
-    out = jax.lax.map(lambda args: fn(*args),
-                      (jnp.arange(n, dtype=jnp.int32) * chunk,
-                       *(cut(x) for x in xs)))
+    starts = jnp.arange(n, dtype=jnp.int32) * chunk
+    if n > 1 and any(map(_is_stack, jax.tree_util.tree_leaves(
+            weights, is_leaf=_is_stack))):
+        out = _chunks_carrying(fn, weights, starts, tuple(map(cut, xs)))
+        return jax.tree_util.tree_map(joined, out)
+    if weights is not None:
+        fn = functools.partial(fn, weights)
+    fn = jax.checkpoint(fn)
+    if n == 1:
+        return fn(jnp.int32(0), *xs)
+    out = jax.lax.map(lambda args: fn(*args), (starts, *map(cut, xs)))
     return jax.tree_util.tree_map(joined, out)
+
+
+def _is_stack(w) -> bool:
+    return isinstance(w, ExpertStack)
+
+
+def _chunks_carrying(fn, weights, starts, xs):
+    """``jax.lax.map(lambda start, *chunks: fn(weights, start, *chunks),
+    (starts, *xs))`` (``xs``: the chunks stacked, [n, B, chunk, ...]) with
+    the backward written out: a scan over the chunks, last first as the
+    transposed ``lax.map`` takes them, that makes a chunk again under
+    ``jax.vjp`` of its ``jax.checkpoint`` and carries
+
+    * for every plain leaf of ``weights`` the sum of the chunks' gradients
+      in the leaf's dtype, from zeros (what the transposed loop carries);
+    * for every :class:`ExpertStack` its ``into``: the chunk's pullback
+      returns the accumulator with that chunk's gradient added into slice
+      ``layer`` by ``tepdist_gmm_dw``, and that array, not a sum of such
+      arrays, is the next chunk's. After the last chunk it is the cotangent
+      of the stack's accumulator, which is what
+      ``_walk_accumulating``'s backward step expects of a body.
+
+    The stacks and their layer indices are closed over by what is
+    differentiated and never its operands (a pullback makes zeros, as large,
+    for an operand nothing reaches), as in ``ops/grouped_matmul.py:_switch``.
+    Against the sums of the plain form an expert leaf's total loses one
+    rounding a layer and micro batch: there the chunks' gradients are added
+    to a zeroed carry and the total to the accumulator, here each straight
+    to the accumulator."""
+    ws, tree = jax.tree_util.tree_flatten(weights, is_leaf=_is_stack)
+
+    def whole(plain, fixed, intos):
+        """``weights`` again: its plain leaves, its stacks with their layer
+        indices, and the stacks' accumulators."""
+        plain = iter(plain)
+        handed = (ExpertStack(*at, into) for at, into in zip(fixed, intos))
+        return tree.unflatten([
+            next(handed if _is_stack(w) else plain) for w in ws])
+
+    def over(plain, fixed, intos, xs):
+        weights = whole(plain, fixed, intos)
+        return jax.lax.map(lambda args: fn(weights, *args), (starts, *xs))
+
+    chunked = jax.custom_vjp(over)
+
+    def fwd(plain, fixed, intos, xs):
+        return over(plain, fixed, intos, xs), (plain, fixed, intos, xs)
+
+    def bwd(res, d_out):
+        plain, fixed, intos, xs = res
+
+        def step(carry, per_chunk):
+            sums, intos = carry
+            start, chunks, d = per_chunk
+            _, pull = jax.vjp(
+                jax.checkpoint(lambda plain, intos, *chunks: fn(
+                    whole(plain, fixed, intos), start, *chunks)),
+                plain, intos, *chunks)
+            # A stack's accumulator comes back with this chunk's gradient
+            # added where it lies; the plain leaves' are added here.
+            d_plain, intos, *d_chunks = pull(d)
+            with part("optimizer"):     # gradient accumulation
+                sums = [s + g for s, g in zip(sums, d_plain)]
+            return (sums, intos), tuple(d_chunks)
+
+        (sums, intos), d_xs = jax.lax.scan(
+            step, ([jnp.zeros_like(w) for w in plain], intos),
+            (starts, xs, d_out), reverse=True)
+        return sums, None, intos, d_xs
+
+    chunked.defvjp(fwd, bwd)
+    return chunked(
+        [w for w in ws if not _is_stack(w)],
+        [(w.stack, w.layer) for w in ws if _is_stack(w)],
+        [w.into for w in ws if _is_stack(w)], xs)
 
 
 def rms_norm(x, g, eps: float = 1e-5):

@@ -52,7 +52,11 @@ whole model's.
 The attention core is ``ops/pallas/mla_attention.py``: no key or value wider
 than published, one ``k_rope`` read by all heads. **A block's token-wise
 parts run in chunks of the sequence** (:func:`block`,
-``models/layers.py:over_sequence``), as MiniCPM-SALA's. bf16 weights
+``models/layers.py:over_sequence``), as MiniCPM-SALA's; the half that holds
+the expert layer is handed its weights, so that a walk which accumulates
+gradients gives it the experts' stacks where they lie (``ExpertStack``) and
+the chunk loop's backward adds each chunk's expert gradients into the
+walk's accumulator, carried from chunk to chunk. bf16 weights
 and activations; norms, rotary, the router's sigmoid, softmax statistics and
 the loss in float32. Parameters: ``l{i}`` per-layer dicts (``init_params``)
 or the layers stacked by what they hold (``stacked_init_params``): ``dense``
@@ -189,6 +193,9 @@ CONFIGS["smoke"] = dataclasses.replace(
     dtype=jnp.bfloat16, remat=True, loss_chunk=256, moe_tile_m=128)
 
 _OUTSIDE_BLOCKS = ("tok_emb", "norm_f", "lm_head")
+# A layer's leaves that the first half of ``block`` reads (``attend``); the
+# second half is handed the rest.
+_ATTEND_LEAVES = ("input_ln", "kv_ln", "wq", "wkva", "wkvb")
 
 
 def init_params(cfg: SarvamMLAConfig, key, std: float = 0.02):
@@ -344,10 +351,11 @@ def block(blk, x, cfg: SarvamMLAConfig):
     (``over_sequence``): a block's working set holds ``[T, heads, D]``
     arrays for the kernels and never a ``[T, intermediate_size]`` one nor
     the whole sequence's worst-case expert layout. Routing is a token's own,
-    so the chunks change no value."""
+    so the chunks change no value. ``blk``'s expert leaves may be
+    ``ExpertStack``s (``walk_layers(experts=)``)."""
     eps = cfg.rms_norm_eps
 
-    def after(start, xc, oc):
+    def after(blk, start, xc, oc):
         del start
         with part("mixer"), jax.named_scope("mla_out"):
             xc = xc + oc @ blk["wo"]
@@ -359,20 +367,21 @@ def block(blk, x, cfg: SarvamMLAConfig):
 
     with part("mixer"):
         o = attend(blk, x, cfg)
+    # The second half is handed its weights: a walk that accumulates
+    # gradients gives an expert layer ``ExpertStack``s, whose accumulators
+    # the chunk loop's backward has to carry and not sum.
     with jax.named_scope("mla_out_mlp"):
-        return over_sequence(after, _widest(cfg), x, o)
+        return over_sequence(after, _widest(cfg), x, o, weights={
+            k: w for k, w in blk.items() if k not in _ATTEND_LEAVES})
 
 
 def hidden_states(params, tokens, cfg: SarvamMLAConfig):
     """tokens int32 [B, T] -> final normalised hidden [B, T, d]."""
     with part("embed"):
         x = params["tok_emb"][tokens].astype(cfg.dtype)
-    # No ``experts=``: the expert layer runs once a chunk of the sequence
-    # (``block``'s ``over_sequence``), and a gradient accumulator handed
-    # back as a cotangent would be summed once a chunk; the kernels take
-    # slices here.
     x = walk_layers(lambda blk, h, _: block(blk, h, cfg), x, params,
-                    _stacks(cfg), [None] * cfg.num_hidden_layers, cfg.remat)
+                    _stacks(cfg), [None] * cfg.num_hidden_layers, cfg.remat,
+                    experts=decoder.EXPERT_LEAVES)
     with part("head_loss"):
         return rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
 

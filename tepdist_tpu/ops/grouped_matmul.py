@@ -440,14 +440,7 @@ def routed_experts(h, weights, experts, w_gate, w_up, w_down,
     for it. No token is dropped (``route``). A share of the experts is laid
     out once and runs everything wide at the smallest of ``layout_rows``'
     sizes that its routing fits, chosen by a ``switch`` on the device; the
-    whole layer has one size and no ``switch``. Where the experts' weights
-    outweigh the rows the largest size keeps for its backward (``[rows, d]``
-    and three ``[rows, f]``: few tokens a call under many wide experts, a
-    chunk of sarvam-105b's sequence) that ``switch`` keeps the weights out of
-    its residual slots (``_switch(lean=)``: 6 x the weights' bytes less held
-    from a layer's forward to its backward); elsewhere they stay, the form
-    the compiler schedules better (as the rule the lean form cost Trinity's
-    cell 1.35% and Mellum2's 1.15%: PERF.md section 6, PR 45)."""
+    whole layer has one size and no ``switch``."""
     with jax.named_scope("moe_dispatch"):
         r = route(experts, num_experts, tile_m, held)
     calls = _KERNEL_CALLS.get()
@@ -459,17 +452,15 @@ def routed_experts(h, weights, experts, w_gate, w_up, w_down,
                         num_experts, tile_m)
     if len(sizes) == 1:
         return routed_experts_at(h, weights, r, w_gate, w_up, w_down, tile_m)
-    d, f = w_gate.shape[1:]
-    lean = w_gate.size + w_up.size + w_down.size > sizes[-1] * (d + 3 * f)
     return _switch(
         layout_index(r.n_tiles, sizes, tile_m),
         [lambda r, h, weights, *w, m=m: _branch(
             h, weights, at_rows(r, m, tile_m), *w, tile_m=tile_m)
          for m in sizes],
-        r, h, weights, w_gate, w_up, w_down, lean=lean)
+        r, h, weights, w_gate, w_up, w_down)
 
 
-def _switch(index, branches, r: Routing, *args, lean: bool = False):
+def _switch(index, branches, r: Routing, *args):
     """``jax.lax.switch(index, branches, r, *args)`` (each branch
     ``routed_experts_at`` at one size; differentiable in ``args``) whose
     backward hands each size's residuals over in slots of their
@@ -485,14 +476,14 @@ def _switch(index, branches, r: Routing, *args, lean: bool = False):
     gets its accumulator's cotangent and no other."""
     taken = range(len(branches))
     tree = jax.tree_util.tree_structure(args)
-    # Of an ``ExpertStack`` among ``args`` no leaf goes in a slot, whatever
-    # ``lean`` (a slot would hold a copy of every layer's experts), and its
-    # stack and layer index are closed over by what is differentiated: a
-    # pullback hands back zeros for an operand nothing reaches, as large.
+    # Of an ``ExpertStack`` among ``args`` no leaf goes in a slot (a slot
+    # would hold a copy of every layer's experts), and its stack and layer
+    # index are closed over by what is differentiated: a pullback hands back
+    # zeros for an operand nothing reaches, as large.
     roles = [role for a in args for role in (
         ExpertStack("fixed", "fixed", "moving") if isinstance(a, ExpertStack)
         else (None,) * len(jax.tree_util.tree_leaves(a)))]
-    handed = {n for n, role in enumerate(roles) if role}
+    handed = [n for n, role in enumerate(roles) if role]
     moving = [n for n, role in enumerate(roles) if role != "fixed"]
 
     @jax.custom_vjp
@@ -501,12 +492,13 @@ def _switch(index, branches, r: Routing, *args, lean: bool = False):
 
     # Slot i holds the leaves of size i's pullback; what puts them together
     # again is static, made while the forward rule is traced and read by
-    # the backward rule, which is traced after it. ``lean``: a leaf that is
-    # one of ``args``' as it came (the experts' weights, which every size's
-    # pullback reads) goes in no slot and the backward rule takes it from
-    # ``args``: a conditional returns no operand without copying it, so a
-    # slot holds a copy for the size taken and an array as large, unwritten,
-    # for every other.
+    # the backward rule, which is traced after it. A leaf that is one of an
+    # ``ExpertStack``'s as it came goes in no slot and the backward rule
+    # takes it from ``args``: a conditional returns no operand without
+    # copying it, so a slot holds a copy for the size taken and an array as
+    # large, unwritten, for every other. (Weights handed as a layer's own
+    # ``[E, K, N]`` slices go in the slots with the rows: the form the
+    # compiler schedules better, PERF.md section 6, PR 45.)
     trees, passed = {}, {}
 
     def fwd(index, r, *args):
@@ -521,8 +513,7 @@ def _switch(index, branches, r: Routing, *args, lean: bool = False):
 
             out, pull = jax.vjp(branch, *(flat[n] for n in moving))
             leaves, trees[i] = jax.tree_util.tree_flatten(pull)
-            passed[i] = [next((n for n, a in enumerate(flat) if a is leaf
-                               and (lean or n in handed)), None)
+            passed[i] = [next((n for n in handed if flat[n] is leaf), None)
                          for leaf in leaves]
             return out, [leaf for leaf, n in zip(leaves, passed[i])
                          if n is None]
@@ -541,7 +532,7 @@ def _switch(index, branches, r: Routing, *args, lean: bool = False):
         out, slots = jax.lax.switch(
             index, [functools.partial(branch, i) for i in taken], r, *args)
         return out, (index, slots, [
-            a if lean or n in handed else None
+            a if n in handed else None
             for n, a in enumerate(jax.tree_util.tree_leaves(args))])
 
     def bwd(residuals, g):

@@ -7,9 +7,17 @@ import os
 
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
-    os.environ["XLA_FLAGS"] = (
-        _flags + " --xla_force_host_platform_device_count=8"
-    ).strip()
+    _flags += " --xla_force_host_platform_device_count=8"
+# The suite compiles some thousands of small programs for the CPU and runs
+# each once or twice: LLVM's optimisation of them was a quarter of a model
+# file's time and buys nothing at these sizes. Level 1 and not 0: at 0 the
+# instruction selector changes, fused multiply-adds form in one program and
+# not in its twin, and two bit-for-bit comparisons fail (ROADMAP D17).
+for _flag in ("--xla_backend_optimization_level=1",
+              "--xla_llvm_disable_expensive_passes=true"):
+    if _flag.split("=")[0] not in _flags:
+        _flags += " " + _flag
+os.environ["XLA_FLAGS"] = _flags.strip()
 os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax  # noqa: E402
@@ -35,3 +43,27 @@ def mesh8(devices):
 
     topo = MeshTopology([("data", 2), ("model", 4)])
     return topo.to_jax_mesh(devices)
+
+
+# Under ``--dist loadfile`` a file is one worker's chain, and files are dealt
+# out in the order they are collected, by name: the long files late in the
+# alphabet (the described-chip compiles, three models' files) began last and
+# were the run's tail, some hundred seconds in which most workers stood idle
+# (ROADMAP D17). Longest first, the rest as collected: the same cases, every
+# worker busy to the end. By the summed case seconds of a run under six
+# workers (over 120 s a file).
+_LONGEST_FIRST = (
+    "test_mellum.py", "test_tpu_compile.py", "test_sarvam_mla.py",
+    "test_stack_in_place.py", "test_minicpm_sala.py", "test_afmoe.py",
+    "test_jamba.py", "test_multiworker.py", "test_jaxpr_serde.py",
+    "test_evaluator_measured.py", "test_sequence_parallel.py",
+    "test_zaya_walk.py", "test_models.py", "test_zaya.py",
+    "test_collective_pipeline.py", "test_olmoe.py", "test_seq_planner.py",
+    "test_attn_kept.py", "test_serving_fleet.py", "test_serving_chaos.py",
+    "test_ga_fused.py", "test_rpc.py", "test_rpc_explore.py",
+)
+
+
+def pytest_collection_modifyitems(items):
+    rank = {name: i for i, name in enumerate(_LONGEST_FIRST)}
+    items.sort(key=lambda item: rank.get(item.path.name, len(rank)))

@@ -19,18 +19,21 @@ def leaves_close(got, want, limit):
         assert rel_l2(g, want[path]) < limit, jax.tree_util.keystr(path)
 
 
+def equations_of(jaxpr):
+    """Every equation of ``jaxpr``, nested jaxprs included (a loop's body
+    once)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for j in v if isinstance(v, (list, tuple)) else (v,):
+                j = getattr(j, "jaxpr", j)
+                if hasattr(j, "eqns"):
+                    yield from equations_of(j)
+
+
 def equations(fn, *args):
-    """Every equation of ``fn``'s jaxpr, nested jaxprs included (a loop's
-    body once)."""
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            yield eqn
-            for v in eqn.params.values():
-                for j in v if isinstance(v, (list, tuple)) else (v,):
-                    j = getattr(j, "jaxpr", j)
-                    if hasattr(j, "eqns"):
-                        yield from walk(j)
-    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+    """Every equation of ``fn``'s jaxpr (:func:`equations_of`)."""
+    return list(equations_of(jax.make_jaxpr(fn)(*args).jaxpr))
 
 
 def kernel_counts(fn, *args):

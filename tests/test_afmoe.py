@@ -79,7 +79,8 @@ def test_loss_and_every_gradient_match_the_reference(stacked, remat):
     assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
     tree_close(grads, want)
     # Where a gradient would be, the bias holds its layer's counts.
-    counts = ref.expert_counts(to_reference(params, cfg), tokens, hyper(cfg))
+    counts = jax.jit(lambda p, t: ref.expert_counts(p, t, hyper(cfg)))(
+        to_reference(params, cfg), tokens)
     got = grads["blocks"]["router_bias"] if stacked else jnp.stack(
         [grads[f"l{i}"]["router_bias"] for i in LAYERS])
     np.testing.assert_array_equal(np.asarray(got), np.asarray(counts))
@@ -133,9 +134,9 @@ def test_two_steps_do_not_depend_on_the_accumulation_split(stacked):
     # Each step's update is the reference's, from that step's own counts:
     # the second routes with the first's bias and weights.
     bias = np.zeros_like(biases(params))
+    expert_counts = jax.jit(lambda p, t: ref.expert_counts(p, t, hyper(cfg)))
     for before, tokens, after in zip([params] + p_one, batches, p_one):
-        counts = ref.expert_counts(to_reference(before, cfg), tokens,
-                                   hyper(cfg))
+        counts = expert_counts(to_reference(before, cfg), tokens)
         bias = np.asarray(ref.bias_update(bias, counts, OPT["bias_rate"]))
         np.testing.assert_allclose(biases(after), bias, atol=1e-9)
     assert np.abs(bias).max() > 0

@@ -27,7 +27,18 @@ CFG = jamba.CONFIGS["test"]          # Mamba x 2, attention, Mamba x 2;
 #                                      128 channels of 8 states, chunks of 16
 KEY = jax.random.PRNGKey(0)
 OPT = {"name": "adamw_bf16", "learning_rate": 1e-3}
+# Traced and compiled once a (shapes, configuration), not run operation by
+# operation: the program and the reference (``hp`` a tuple of plain numbers).
 loss_and_grads = jax.jit(jax.value_and_grad(jamba.loss_fn), static_argnums=2)
+loss_of = jax.jit(jamba.loss_fn, static_argnums=2)
+forward = jax.jit(jamba.forward, static_argnums=2)
+ref_logits = jax.jit(lambda p, t, hp: ref.logits(p, t, hp), static_argnums=2)
+ref_loss = jax.jit(
+    lambda p, t, hp, weights=None: ref.loss(p, t, hp, ref.identity, weights),
+    static_argnums=2)
+ref_loss_and_grads = jax.jit(
+    jax.value_and_grad(lambda p, t, hp: ref.loss(p, t, hp)),
+    static_argnums=2)
 
 
 @pytest.fixture(autouse=True)
@@ -63,12 +74,12 @@ def test_logits_loss_and_every_gradient_match_the_reference(stacked, remat):
     params = init(cfg, KEY)
     tokens = jamba.fake_batch(cfg, 2, 40, seed=1)      # 2.5 chunks of 16
     as_ref, hp = to_reference(params, cfg), hyper(cfg)
-    logits = ref.logits(as_ref, tokens[:, :-1], hp)
+    logits = ref_logits(as_ref, tokens[:, :-1], hp)
     np.testing.assert_allclose(
-        np.asarray(jamba.forward(params, tokens[:, :-1], cfg)),
+        np.asarray(forward(params, tokens[:, :-1], cfg)),
         np.asarray(logits), rtol=0, atol=2e-5 * float(jnp.abs(logits).max()))
     loss, grads = loss_and_grads(params, tokens, cfg)
-    want_loss, want = jax.value_and_grad(ref.loss)(as_ref, tokens, hp)
+    want_loss, want = ref_loss_and_grads(as_ref, tokens, hp)
     assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
     leaves_close(to_reference(grads, cfg), want, 2e-5)
 
@@ -85,7 +96,7 @@ def test_bf16_program_stays_near_the_float32_reference():
     params = jamba.stacked_init_params(cfg, KEY)
     tokens = jamba.fake_batch(cfg, 2, 40, seed=1)
     loss, grads = loss_and_grads(params, tokens, cfg)
-    want_loss, want = jax.value_and_grad(ref.loss)(params, tokens, hyper(cfg))
+    want_loss, want = ref_loss_and_grads(params, tokens, hyper(cfg))
     assert float(loss) == pytest.approx(float(want_loss), rel=2e-3)
     for name in ("tok_emb", "norm_f"):
         assert rel_l2(grads[name], want[name]) < 0.03, name
@@ -99,11 +110,10 @@ def test_a_doubled_micro_batch_shows():
     params = jamba.stacked_init_params(CFG, KEY)
     unique = jamba.fake_batch(CFG, 2, 32, seed=3)
     batch = unique[jnp.asarray([0, 1, 1, 1])]
-    want = ref.loss(params, unique, hyper(CFG), ref.identity,
-                    jnp.asarray([0.25, 0.75]))
-    assert float(jamba.loss_fn(params, batch, CFG)) \
+    want = ref_loss(params, unique, hyper(CFG), jnp.asarray([0.25, 0.75]))
+    assert float(loss_of(params, batch, CFG)) \
         == pytest.approx(float(want), rel=1e-5)
-    even = ref.loss(params, unique, hyper(CFG))
+    even = ref_loss(params, unique, hyper(CFG))
     assert abs(float(even) - float(want)) > 1e-4
 
 

@@ -74,8 +74,11 @@ def test_logits_loss_and_every_gradient_match_the_reference(stacked, remat):
     params = init(cfg, KEY)
     tokens = mellum.fake_batch(cfg, 2, 32, seed=1)
     hp = hyper(cfg)
-    logits = mellum.forward(params, tokens[:, :-1], cfg)
-    want_logits = ref.logits(to_reference(params, cfg), tokens[:, :-1], hp)
+    # Each traced and compiled once, not run operation by operation.
+    logits = jax.jit(mellum.forward, static_argnums=2)(
+        params, tokens[:, :-1], cfg)
+    want_logits = jax.jit(lambda p, t: ref.logits(p, t, hp))(
+        to_reference(params, cfg), tokens[:, :-1])
     np.testing.assert_allclose(np.asarray(logits), np.asarray(want_logits),
                                rtol=0, atol=2e-6)
     loss, grads = loss_and_grads(params, tokens, cfg)
@@ -255,7 +258,8 @@ def test_no_assignment_to_a_held_expert_is_dropped(send):
     loss, grads = loss_and_grads(params, tokens, cfg)
     assert all(np.isfinite(np.asarray(g)).all()
                for g in jax.tree_util.tree_leaves(grads))
-    want_loss = ref.loss(to_reference(params, cfg), tokens, hyper(cfg))
+    want_loss = jax.jit(lambda p, t: ref.loss(p, t, hyper(cfg)))(
+        to_reference(params, cfg), tokens)
     assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
 
 

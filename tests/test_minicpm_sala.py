@@ -32,7 +32,18 @@ CFG = sala.CONFIGS["test"]
 GEO = CFG.sparse
 KEY = jax.random.PRNGKey(0)
 OPT = {"name": "adamw_bf16", "learning_rate": 1e-3}
+# Traced and compiled once a (shapes, configuration), not run operation by
+# operation: the program and the reference (``hp`` a tuple of plain values).
 loss_and_grads = jax.jit(jax.value_and_grad(sala.loss_fn), static_argnums=2)
+loss_of = jax.jit(sala.loss_fn, static_argnums=2)
+forward = jax.jit(sala.forward, static_argnums=2)
+ref_logits = jax.jit(lambda p, t, hp: ref.logits(p, t, hp), static_argnums=2)
+ref_loss = jax.jit(
+    lambda p, t, hp, weights=None: ref.loss(p, t, hp, ref.identity, weights),
+    static_argnums=2)
+ref_loss_and_grads = jax.jit(
+    jax.value_and_grad(lambda p, t, hp: ref.loss(p, t, hp)),
+    static_argnums=2)
 
 
 @pytest.fixture(autouse=True)
@@ -76,12 +87,12 @@ def test_logits_loss_and_every_gradient_match_the_reference(stacked, remat,
     params = uneven_gains(init(cfg, KEY, std=0.05))
     tokens = sala.fake_batch(cfg, 2, T, seed=1)
     as_ref, hp = to_reference(params, cfg), hyper(cfg)
-    logits = ref.logits(as_ref, tokens[:, :-1], hp)
+    logits = ref_logits(as_ref, tokens[:, :-1], hp)
     np.testing.assert_allclose(
-        np.asarray(sala.forward(params, tokens[:, :-1], cfg)),
+        np.asarray(forward(params, tokens[:, :-1], cfg)),
         np.asarray(logits), rtol=0, atol=2e-5 * float(jnp.abs(logits).max()))
     loss, grads = loss_and_grads(params, tokens, cfg)
-    want_loss, want = jax.value_and_grad(ref.loss)(as_ref, tokens, hp)
+    want_loss, want = ref_loss_and_grads(as_ref, tokens, hp)
     assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
     leaves_close(to_reference(grads, cfg), want, 2e-5)
 
@@ -111,7 +122,7 @@ def test_bf16_program_stays_near_the_float32_reference():
     params = sala.stacked_init_params(cfg, KEY)
     tokens = sala.fake_batch(cfg, 2, 128, seed=1)
     loss, grads = loss_and_grads(params, tokens, cfg)
-    want_loss, want = jax.value_and_grad(ref.loss)(params, tokens, hyper(cfg))
+    want_loss, want = ref_loss_and_grads(params, tokens, hyper(cfg))
     assert float(loss) == pytest.approx(float(want_loss), rel=2e-3)
     for name in ("tok_emb", "lm_head", "norm_f"):
         assert rel_l2(grads[name], want[name]) < 0.05, name
@@ -125,11 +136,10 @@ def test_a_doubled_micro_batch_shows():
     params = sala.stacked_init_params(CFG, KEY)
     unique = sala.fake_batch(CFG, 2, 64, seed=3)
     batch = unique[jnp.asarray([0, 1, 1, 1])]
-    want = ref.loss(params, unique, hyper(CFG), ref.identity,
-                    jnp.asarray([0.25, 0.75]))
-    assert float(sala.loss_fn(params, batch, CFG)) \
+    want = ref_loss(params, unique, hyper(CFG), jnp.asarray([0.25, 0.75]))
+    assert float(loss_of(params, batch, CFG)) \
         == pytest.approx(float(want), rel=1e-5)
-    even = ref.loss(params, unique, hyper(CFG))
+    even = ref_loss(params, unique, hyper(CFG))
     assert abs(float(even) - float(want)) > 1e-4
 
 
@@ -141,11 +151,13 @@ def test_the_blocks_token_wise_parts_in_chunks_change_nothing(monkeypatch):
     cfg = dataclasses.replace(CFG, remat=True)
     params = uneven_gains(sala.stacked_init_params(cfg, KEY, std=0.05))
     tokens = sala.fake_batch(cfg, 2, 128, seed=4)
-    whole = jax.value_and_grad(sala.loss_fn)(params, tokens, cfg)
+    whole = loss_and_grads(params, tokens, cfg)
     monkeypatch.setattr(layers, "_CHUNK_ELEMENTS",
                         2 * 16 * cfg.intermediate_size)
     assert layers.tokens_a_chunk(2, 128, cfg.intermediate_size) == 16
-    loss, grads = jax.value_and_grad(sala.loss_fn)(params, tokens, cfg)
+    # Traced anew: the chunk's size is read while the loss is traced.
+    loss, grads = jax.jit(jax.value_and_grad(sala.loss_fn),
+                          static_argnums=2)(params, tokens, cfg)
     assert float(loss) == pytest.approx(float(whole[0]), rel=1e-6)
     leaves_close(grads, whole[1], 1e-5)
     text = str(jax.make_jaxpr(lambda p, t: sala.loss_fn(p, t, cfg))(

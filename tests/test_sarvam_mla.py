@@ -6,6 +6,7 @@ softmax scale against the formula written out, a planned step against a
 plain loop, and the gauges."""
 
 import dataclasses
+import functools
 import math
 
 import jax
@@ -31,8 +32,31 @@ OPT = {"name": "adamw_bf16_router_bias", "learning_rate": 1e-3,
        "bias_rate": 0.001}
 WHOLE = dataclasses.replace(CFG, heads_held=(0, CFG.num_attention_heads),
                             experts_held=(0, CFG.num_experts))
+# Traced once a (shapes, configuration) and a module, not once a test: the
+# program, the reference (``hp`` a tuple of plain numbers) and the optimizer.
 loss_and_grads = jax.jit(jax.value_and_grad(sarvam.loss_fn),
                          static_argnums=2)
+forward = jax.jit(sarvam.forward, static_argnums=2)
+ref_logits = jax.jit(lambda p, t, hp: ref.logits(p, t, hp), static_argnums=2)
+ref_loss_and_grads = jax.jit(
+    jax.value_and_grad(lambda p, t, hp: ref.loss(p, t, hp)),
+    static_argnums=2)
+loss_of = jax.jit(sarvam.loss_fn, static_argnums=2)
+ref_loss = jax.jit(lambda p, t, hp: ref.loss(p, t, hp), static_argnums=2)
+ref_expert_counts = jax.jit(lambda p, t, hp: ref.expert_counts(p, t, hp),
+                            static_argnums=2)
+
+
+@functools.lru_cache(maxsize=None)
+def _init(cfg, stacked):
+    init = sarvam.stacked_init_params if stacked else sarvam.init_params
+    return init(cfg, KEY)
+
+
+def init_params(cfg, stacked=False):
+    """``cfg``'s parameters from ``KEY``, made once a preset (its sizes and
+    dtype) and layout. Shared: whoever donates them takes a copy."""
+    return _init(dataclasses.replace(cfg, remat=False, loss_chunk=0), stacked)
 
 
 @pytest.fixture(autouse=True)
@@ -60,6 +84,16 @@ def to_reference(params, cfg):
         return params
     out = {k: params[k] for k in ("tok_emb", "norm_f", "lm_head")}
     out["layers"] = [params[f"l{i}"] for i in range(cfg.num_hidden_layers)]
+    return out
+
+
+def from_reference(tree, cfg, stacked):
+    """``to_reference``'s way back, for the reference's gradients."""
+    if stacked:
+        return tree
+    out = {k: tree[k] for k in ("tok_emb", "norm_f", "lm_head")}
+    out.update({f"l{i}": tree["layers"][i]
+                for i in range(cfg.num_hidden_layers)})
     return out
 
 
@@ -92,21 +126,21 @@ def tree_close(got, want, rtol=2e-5, skip=("router_bias",)):
                          ids=["unstacked-plain", "stacked-remat"])
 def test_logits_loss_and_every_gradient_match_the_reference(stacked, remat):
     cfg = dataclasses.replace(CFG, remat=remat, loss_chunk=16 if remat else 0)
-    init = sarvam.stacked_init_params if stacked else sarvam.init_params
-    params = uneven(init(cfg, KEY))
+    params = uneven(init_params(cfg, stacked))
     tokens = sarvam.fake_batch(cfg, 2, 32, seed=1)
     hp = hyper(cfg)
     np.testing.assert_allclose(
-        np.asarray(sarvam.forward(params, tokens[:, :-1], cfg)),
-        np.asarray(ref.logits(to_reference(params, cfg), tokens[:, :-1],
+        np.asarray(forward(params, tokens[:, :-1], cfg)),
+        np.asarray(ref_logits(to_reference(params, cfg), tokens[:, :-1],
                               hp)), rtol=0, atol=2e-5)
     loss, grads = loss_and_grads(params, tokens, cfg)
-    want_loss, want = jax.jit(jax.value_and_grad(
-        lambda p: ref.loss(to_reference(p, cfg), tokens, hp)))(params)
+    want_loss, want = ref_loss_and_grads(to_reference(params, cfg), tokens,
+                                         hp)
+    want = from_reference(want, cfg, stacked)
     assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
     tree_close(grads, want)
     # The bias's "gradient" is the count of its router's choices.
-    counts = ref.expert_counts(to_reference(params, cfg), tokens, hp)
+    counts = ref_expert_counts(to_reference(params, cfg), tokens, hp)
     got = grads["blocks"]["router_bias"] if stacked else jnp.stack(
         [grads[f"l{i}"]["router_bias"] for i in (1, 2)])
     np.testing.assert_array_equal(np.asarray(got), np.asarray(counts))
@@ -115,11 +149,10 @@ def test_logits_loss_and_every_gradient_match_the_reference(stacked, remat):
 def test_bf16_program_stays_near_the_float32_reference():
     cfg = dataclasses.replace(CFG, dtype=jnp.bfloat16, remat=True,
                               loss_chunk=16)
-    params = sarvam.stacked_init_params(cfg, KEY)
+    params = init_params(cfg, stacked=True)
     tokens = sarvam.fake_batch(cfg, 2, 32, seed=2)
     loss, grads = loss_and_grads(params, tokens, cfg)
-    want_loss, want = jax.value_and_grad(
-        lambda p: ref.loss(p, tokens, hyper(cfg)))(params)
+    want_loss, want = ref_loss_and_grads(params, tokens, hyper(cfg))
     assert float(loss) == pytest.approx(float(want_loss), rel=2e-3)
     for k in ("tok_emb", "lm_head", "norm_f"):
         assert rel_l2(grads[k], want[k]) < 0.05, k
@@ -279,7 +312,7 @@ def test_attention_from_a_saved_forward_is_the_call(forward_kept):
 def test_the_heads_shares_add_up_to_the_uncut_layer():
     """The two shares of two heads each, through their rows of ``wo``, are
     the attention all four heads give: the uncut reference's."""
-    params = sarvam.init_params(WHOLE, KEY)
+    params = init_params(WHOLE)
     x = jax.random.normal(jax.random.PRNGKey(5), (2, 32, CFG.hidden_size))
     total = 0.0
     for first in (0, 2):
@@ -315,7 +348,7 @@ def test_the_expert_shares_add_up_with_the_shared_expert_counted_once():
     is Trinity's own function."""
     assert sarvam.moe is afmoe.moe and sarvam.swiglu is afmoe.swiglu \
         and sarvam.router is afmoe.router
-    params = uneven(sarvam.init_params(WHOLE, KEY))
+    params = uneven(init_params(WHOLE))
     blk = params["l1"]
     x = jax.random.normal(jax.random.PRNGKey(6), (2, 32, CFG.hidden_size))
     shared = afmoe.swiglu(x, blk["shared_gate"], blk["shared_up"],
@@ -334,15 +367,15 @@ def test_the_expert_shares_add_up_with_the_shared_expert_counted_once():
 def test_a_rank_of_the_whole_model_is_the_reference_at_the_same_share():
     """``rank_share`` of the whole model's parameters: the rank's loss is the
     reference's on the same heads and experts."""
-    params = sarvam.init_params(WHOLE, KEY)
+    params = init_params(WHOLE)
     share, cfg = sarvam.rank_share(params, WHOLE, CFG.heads_held,
                                    CFG.experts_held)
     assert cfg == CFG
     tokens = sarvam.fake_batch(cfg, 2, 32, seed=7)
-    want = ref.loss(to_reference(share, cfg), tokens, hyper(cfg))
-    assert float(sarvam.loss_fn(share, tokens, cfg)) \
+    want = ref_loss(to_reference(share, cfg), tokens, hyper(cfg))
+    assert float(loss_of(share, tokens, cfg)) \
         == pytest.approx(float(want), rel=1e-5)
-    whole = ref.loss(to_reference(params, WHOLE), tokens, hyper(WHOLE))
+    whole = ref_loss(to_reference(params, WHOLE), tokens, hyper(WHOLE))
     assert abs(float(whole) - float(want)) > 1e-5
 
 
@@ -394,13 +427,15 @@ def test_the_blocks_token_wise_parts_in_chunks_change_nothing(monkeypatch):
     assert sarvam._widest(big) == 16384
     assert layers.tokens_a_chunk(1, 16384, sarvam._widest(big)) == 2048
     cfg = dataclasses.replace(CFG, remat=True)
-    params = uneven(sarvam.stacked_init_params(cfg, KEY))
+    params = uneven(init_params(cfg, stacked=True))
     tokens = sarvam.fake_batch(cfg, 2, 32, seed=4)
-    whole = jax.value_and_grad(sarvam.loss_fn)(params, tokens, cfg)
+    whole = loss_and_grads(params, tokens, cfg)
     monkeypatch.setattr(layers, "_CHUNK_ELEMENTS",
                         2 * 8 * sarvam._widest(cfg))
     assert layers.tokens_a_chunk(2, 32, sarvam._widest(cfg)) == 8
-    loss, grads = jax.value_and_grad(sarvam.loss_fn)(params, tokens, cfg)
+    # Traced anew: the chunk's size is read while the loss is traced.
+    loss, grads = jax.jit(jax.value_and_grad(sarvam.loss_fn),
+                          static_argnums=2)(params, tokens, cfg)
     assert float(loss) == pytest.approx(float(whole[0]), rel=1e-6)
     tree_close(grads, whole[1], 1e-5, skip=())
 
@@ -425,7 +460,7 @@ def test_a_walk_keeps_one_forward_a_layer_and_the_gauges_say_so():
     (``mla_bwd_calls`` 3), every walked leaf accumulates inside the layer loop,
     and the noted gauges hold the heads held and one layer's latent."""
     cfg = dataclasses.replace(CFG, remat=True, loss_chunk=16)
-    params = sarvam.stacked_init_params(cfg, KEY)
+    params = init_params(cfg, stacked=True)
     tokens = sarvam.fake_batch(cfg, 4, 32, seed=8)
     tx, step = _ga_step(cfg, 2)
     def kernels(step):       # fwd, dkv: the places each stands in
@@ -462,7 +497,7 @@ def test_a_walk_keeps_one_forward_a_layer_and_the_gauges_say_so():
 
 def test_the_projections_carry_their_scopes():
     cfg = dataclasses.replace(CFG, remat=True)
-    params = sarvam.stacked_init_params(cfg, KEY)
+    params = init_params(cfg, stacked=True)
     tokens = sarvam.fake_batch(cfg, 1, 32)
     text = jax.jit(sarvam.loss_fn, static_argnums=2).lower(
         params, tokens, cfg).as_text(debug_info=True)
@@ -482,19 +517,25 @@ def test_two_planned_steps_are_a_plain_grad_and_optimizer_loop(stacked,
     reference's update of each step's counts."""
     from tepdist_tpu.train import plan_training
     cfg = dataclasses.replace(CFG, remat=True, loss_chunk=16)
-    init = sarvam.stacked_init_params if stacked else sarvam.init_params
-    params = init(cfg, KEY)
+    params = init_params(cfg, stacked)
     batches = [sarvam.fake_batch(cfg, 4, 32, seed=s) for s in (2, 3)]
     tx = make_optimizer(dict(OPT))
-    plan = plan_training(lambda p, t: sarvam.loss_fn(p, t, cfg), tx, params,
+    # The plan's first step donates the arrays it was given.
+    plan = plan_training(lambda p, t: sarvam.loss_fn(p, t, cfg), tx,
+                         jax.tree_util.tree_map(jnp.copy, params),
                          batches[0], devices=devices[:1], explore=False,
                          num_micro_batches=2)
+
+    @jax.jit
+    def by_hand(p, state, grads):
+        updates, state = tx.update(grads, state, p)
+        return optax.apply_updates(p, updates), state
+
     state, p, bias = tx.init(params), params, None
     for tokens in batches:
         want_loss, grads = loss_and_grads(p, tokens, cfg)
-        counts = ref.expert_counts(to_reference(p, cfg), tokens, hyper(cfg))
-        updates, state = tx.update(grads, state, p)
-        p = optax.apply_updates(p, updates)
+        counts = ref_expert_counts(to_reference(p, cfg), tokens, hyper(cfg))
+        p, state = by_hand(p, state, grads)
         assert plan.step(tokens) == pytest.approx(float(want_loss), rel=2e-6)
         bias = ref.bias_update(0.0 if bias is None else bias, counts,
                                OPT["bias_rate"])
@@ -508,45 +549,3 @@ def test_two_planned_steps_are_a_plain_grad_and_optimizer_loop(stacked,
     np.testing.assert_allclose(np.asarray(after), np.asarray(bias),
                                atol=1e-9)
     assert np.abs(np.asarray(bias)).max() > 0
-
-
-def test_few_tokens_keep_the_weights_out_of_the_switchs_slots(monkeypatch):
-    """``routed_experts``: where the experts' weights outweigh the rows the
-    largest layout keeps (few tokens a call), the layout switch hands the
-    weights through no residual slot; the output and every gradient are the
-    other form's bit for bit."""
-    from tepdist_tpu.ops import grouped_matmul as gm
-    E, G, k, d, f = 16, 4, 2, 32, 16
-    keys = jax.random.split(KEY, 6)
-    w = [0.1 * jax.random.normal(kk, shape) for kk, shape in zip(
-        keys[1:4], ((G, d, f), (G, d, f), (G, f, d)))]
-
-    def case(S):
-        h = jax.random.normal(keys[0], (S, d))
-        experts = jax.random.randint(keys[4], (S, k), 0, E)
-        weights = decoder.held_weights(jax.random.uniform(keys[5], (S, k)),
-                                       experts, (4, G), E)
-
-        def loss(h, weights, *w):
-            return jnp.sum(gm.routed_experts(h, weights, experts, *w, E, 8,
-                                             held=(4, G)) ** 2)
-        return loss, (h, weights, *w)
-
-    def weight_shaped(loss, args):
-        text = str(jax.make_jaxpr(jax.grad(loss, argnums=(2, 3, 4)))(*args))
-        return text.count(f"f32[{G},{d},{f}]") + text.count(
-            f"f32[{G},{f},{d}]")
-
-    few, many = case(16), case(64)
-    assert 3 * G * d * f > gm.layout_rows(16, k, G, E, 8)[-1] * (d + 3 * f)
-    assert 3 * G * d * f < gm.layout_rows(64, k, G, E, 8)[-1] * (d + 3 * f)
-    lean = weight_shaped(*few)
-    got = jax.value_and_grad(few[0], argnums=(0, 1, 2, 3, 4))(*few[1])
-    plain_switch = gm._switch
-    monkeypatch.setattr(gm, "_switch", lambda *a, lean: plain_switch(
-        *a, lean=False))
-    assert lean < weight_shaped(*few) == weight_shaped(*many)
-    want = jax.value_and_grad(few[0], argnums=(0, 1, 2, 3, 4))(*few[1])
-    for a, b in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -7,6 +7,7 @@ partitioner. Interpret-mode tests cannot see any of that. A compile that
 passes is NOT a chip run: nothing executes here.
 """
 
+import collections
 import contextlib
 import dataclasses
 import os
@@ -839,7 +840,8 @@ def test_the_sarvam_cells_step_compiles_for_v5e(v5e_devices, monkeypatch):
     assert gauge("mla_heads_held") == 16
     assert gauge("mla_latent_bytes") == T * (512 + 64) * 2
     assert gauge("moe_rows_sum_calls") == 8     # 2 a walked expert layer
-    assert gauge("moe_stack_in_place_calls") == 0   # a layer's chunks: slices
+    # 12 a walked expert layer, a chunk's trace standing for its 8 chunks.
+    assert gauge("moe_stack_in_place_calls") == 4 * 12
 
     text = compiled.as_text()
     calls = [line.split(" = ", 1)[0].strip() for line in text.splitlines()
@@ -860,10 +862,29 @@ def test_the_sarvam_cells_step_compiles_for_v5e(v5e_devices, monkeypatch):
     # choices and 9 tiles of 128, never the sequence's 131,072.
     assert "[17536,4096]" in text
     assert not [s for s in shapes if s.split(",")[0] in ("132224", "133376")]
+    # The experts' stacks where they lie (PR 51): every grouped matmul takes
+    # the ``[4, 8, K, N]`` stack, no array of one layer's experts is left
+    # anywhere (no slice out of a stack, no update into one, no chunk's
+    # weight gradient summed into a loop's carry), and a whole stack is the
+    # result of the weight-gradient kernels (three a layout size), of the
+    # optimizer and of the accumulators' zero fill, never of a copy.
+    stack = r"bf16\[4,8,(?:4096,2048|2048,4096)\]"
+    gmm = [line for line in text.splitlines() if " custom-call(" in line
+           and "tepdist_gmm_" in line.split(" = ", 1)[0]]
+    assert gmm and all(re.search(stack, line) for line in gmm)
+    assert not re.search(r"bf16\[(?:1,)?8,(?:4096,2048|2048,4096)\]", text)
+    makers = collections.Counter(re.findall(
+        rf"= {stack}\S* ([\w\-]+)\(", text))
+    assert makers["custom-call"] == 6 and not set(makers) - {
+        "custom-call", "fusion", "broadcast", "convert", "parameter",
+        "get-tuple-element"}, makers
     peak = compiled.memory_analysis().peak_memory_in_bytes
-    # No higher than before the backward pass became one kernel (PR 46's
-    # step: 15,973,381,120): its results in HBM are the pair's five.
-    assert 10 * n_params < peak <= 15_973_381_120, peak
+    # What the described chip reports since the chunk loop carries the
+    # accumulators (PR 51: 15,178,642,944, from PR 46's 15,973,381,120):
+    # three chunk gradients, three loop carries and the slices of a layer's
+    # experts are no longer live beside a layer's backward. The optimizer's
+    # 10 bytes a parameter stay under it.
+    assert 10 * n_params < peak <= 15_178_642_944, peak
 
 
 def test_cca_mix_kernels_compile_for_v5e(v5e_devices):

@@ -25,7 +25,15 @@ KEY = jax.random.PRNGKey(0)
 WIDE = dataclasses.replace(CFG, hidden_size=64, head_dim=128,
                            moe_intermediate_size=32, remat=True,
                            loss_chunk=16)
+# Traced and compiled once a (shapes, configuration), not run operation by
+# operation: the program and the reference (``hp`` a tuple of plain numbers).
 loss_and_grads = jax.jit(jax.value_and_grad(zaya.loss_fn), static_argnums=2)
+loss_of = jax.jit(zaya.loss_fn, static_argnums=2)
+forward = jax.jit(zaya.forward, static_argnums=2)
+attention = jax.jit(zaya.attention, static_argnums=2)
+ref_logits = jax.jit(lambda p, t, hp: ref.logits(p, t, hp), static_argnums=2)
+ref_expert_counts = jax.jit(lambda p, t, hp: ref.expert_counts(p, t, hp),
+                            static_argnums=2)
 
 
 @pytest.fixture(autouse=True)
@@ -104,8 +112,8 @@ def test_logits_loss_and_every_gradient_match_the_reference(stacked, remat):
     tokens = zaya.fake_batch(cfg, 2, 32, seed=1)
     hp = hyper(cfg)
     np.testing.assert_allclose(
-        np.asarray(zaya.forward(params, tokens[:, :-1], cfg)),
-        np.asarray(ref.logits(to_reference(params, cfg), tokens[:, :-1],
+        np.asarray(forward(params, tokens[:, :-1], cfg)),
+        np.asarray(ref_logits(to_reference(params, cfg), tokens[:, :-1],
                               hp)), rtol=0, atol=2e-5)
     loss, grads = loss_and_grads(params, tokens, cfg)
     want_loss, want = jax.jit(jax.value_and_grad(
@@ -123,7 +131,7 @@ def test_logits_loss_and_every_gradient_match_the_reference(stacked, remat):
     assert np.any(np.asarray(later))
     # The bias's "gradient" is the count of its router's choices; more than
     # one expert is chosen.
-    counts = ref.expert_counts(to_reference(params, cfg), tokens, hp)
+    counts = ref_expert_counts(to_reference(params, cfg), tokens, hp)
     got = grads["blocks"]["router_bias"] if stacked else jnp.stack(
         [grads[f"l{i}"]["router_bias"] for i in range(3)])
     np.testing.assert_array_equal(np.asarray(got), np.asarray(counts))
@@ -151,12 +159,12 @@ def test_no_mixing_crosses_from_one_sequence_to_the_next(cfg):
     sequence leaves the second as it was."""
     blk = uneven(zaya.init_params(cfg, KEY))["l1"]
     x = jax.random.normal(jax.random.PRNGKey(4), (2, 24, cfg.hidden_size))
-    both = zaya.attention(blk, x, cfg)
+    both = attention(blk, x, cfg)
     for i in range(2):
         np.testing.assert_allclose(
-            np.asarray(zaya.attention(blk, x[i:i + 1], cfg)[0]),
+            np.asarray(attention(blk, x[i:i + 1], cfg)[0]),
             np.asarray(both[i]), rtol=0, atol=2e-6)
-    other = zaya.attention(blk, x.at[0].set(-x[0]), cfg)
+    other = attention(blk, x.at[0].set(-x[0]), cfg)
     np.testing.assert_array_equal(np.asarray(other[1]), np.asarray(both[1]))
     # ... and the shift is by one position, zeros first.
     s = zaya.shifted(x)
@@ -175,9 +183,11 @@ def test_the_models_kernels_are_its_jax_numpy_mixing(monkeypatch):
     small = zaya.stacked_init_params(CFG, KEY)
     assert "tepdist_cca_mix_fwd" not in kernel_counts(
         lambda p: zaya.loss_fn(p, tokens, CFG), small)
-    loss, grads = jax.value_and_grad(zaya.loss_fn)(params, tokens, WIDE)
+    loss, grads = loss_and_grads(params, tokens, WIDE)
     monkeypatch.setattr(zaya.cca_mix, "cca_mix", zaya.cca_mix.reference)
-    want_loss, want = jax.value_and_grad(zaya.loss_fn)(params, tokens, WIDE)
+    # Traced anew: which mixing runs is read while the loss is traced.
+    want_loss, want = jax.jit(jax.value_and_grad(zaya.loss_fn),
+                              static_argnums=2)(params, tokens, WIDE)
     assert float(loss) == pytest.approx(float(want_loss), rel=1e-6)
     tree_close(grads, want, 1e-5, skip=())
 
@@ -212,10 +222,10 @@ def test_the_routers_state_is_carried_from_layer_to_layer():
     cfg = dataclasses.replace(CFG, remat=True)
     params = uneven(zaya.init_params(cfg, KEY))
     tokens = zaya.fake_batch(cfg, 2, 32, seed=5)
-    loss = float(zaya.loss_fn(params, tokens, cfg))
+    loss = float(loss_of(params, tokens, cfg))
     cut = {k: ({**v, "router_gamma": 0 * v["router_gamma"]}
                if k.startswith("l") else v) for k, v in params.items()}
-    assert abs(float(zaya.loss_fn(cut, tokens, cfg)) - loss) > 1e-4
+    assert abs(float(loss_of(cut, tokens, cfg)) - loss) > 1e-4
     # The carry by hand: layer 2's state holds layer 1's, times gamma.
     block = jax.jit(lambda blk, carry: zaya.block(blk, carry, cfg))
     x, r = zaya._start(params, tokens[:, :-1], cfg)
