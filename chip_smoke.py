@@ -1,6 +1,6 @@
 """Quickest proof that the main path still starts on the chip.
 
-    python chip_smoke.py            # one chip: phases A, B, M and Z
+    python chip_smoke.py            # one chip: phases A, B, M, Z and K
     python chip_smoke.py --chips 4  # one host, four chips: that phase only
 
 Drives GPT-2 117M at published widths (12 x 768 x 12 heads, vocab 50257,
@@ -27,6 +27,14 @@ through the entry points a user calls:
            mixing kernel pair, the flash kernels at 8 heads over 2 and the
            grouped matmuls are in the compiled step, that the walk carried
            the router's state and kept one flash forward a layer.
+  phase K  the same for ``models/kimi_linear.py`` at its ``smoke`` preset
+           (three delta-rule layers of 2 heads of the published 128 to one
+           latent-attention layer without rotary at 128 + 64 / 128, 16 of 32
+           sigmoid-routed experts, 8 a token), 2 micro batches of one
+           1024-token sequence, 5 steps; asserts the three delta-rule
+           kernels, the conv pair, the latent kernels and the grouped
+           matmuls are in the compiled step and that the delta rule's
+           forward ran twice a KDA layer and the latent layer's once.
   --chips 4  one child owning all four chips: ``plan_training(explore=True)``
            over ``jax.devices()`` at batch 16, 5 steps, then the same 5 steps
            on ``devices[:1]``; every device must hold a shard and the
@@ -375,6 +383,37 @@ def phase_zaya(preset: str = "smoke", batch: int = 2, seq: int = 1024,
 
 
 # ---------------------------------------------------------------------------
+# Phase K: the delta-rule kernels beside a latent-attention layer without
+# rotary, four walks of unequal shape.
+# ---------------------------------------------------------------------------
+
+def phase_kimi(preset: str = "smoke", batch: int = 2, seq: int = 1024,
+               platform: str = "tpu") -> dict:
+    from tepdist_tpu.models import kimi_linear
+
+    cfg = kimi_linear.CONFIGS[preset]
+    devices, tplan, tokens, gauges = _plan_zoo_model(
+        kimi_linear, cfg, batch, seq, platform)
+    kda = cfg.mixers.count(kimi_linear.KDA)
+    latent = cfg.num_hidden_layers - kda
+    _check(gauges["kda_calls"] == 2 * kda
+           and gauges["mla_fwd_calls"] == gauges["attn_kept_calls"] == latent,
+           f"phase K: the delta rule's forward ran {gauges['kda_calls']} "
+           f"times a micro batch over {kda} layers and the latent layer's "
+           f"{gauges['mla_fwd_calls']} over {latent}")
+    _check(gauges["kda_state_bytes"]
+           == batch // 2 * cfg.kda_num_heads * cfg.kda_head_dim ** 2 * 4,
+           f"phase K: a layer's state reads {gauges['kda_state_bytes']} "
+           "bytes")
+    return _step_zoo_model(
+        "K", f"kimi_linear-{preset}", devices, tplan, tokens, gauges,
+        platform,
+        ("tepdist_kda_fwd", "tepdist_kda_bwd", "tepdist_conv_fwd",
+         "tepdist_conv_bwd", "tepdist_mla_fwd", "tepdist_mla_dkv",
+         "tepdist_gmm_fwd"))
+
+
+# ---------------------------------------------------------------------------
 # Four chips: explored layout over the host's devices vs the same steps on
 # one of them, in one process that owns all four.
 # ---------------------------------------------------------------------------
@@ -435,7 +474,8 @@ def phase_four(cfg_name: str = "117M", batch: int = 16, seq: int = 1024,
 
 
 CHILD_PHASES = {"phase_b": phase_b, "phase_four": phase_four,
-                "phase_mla": phase_mla, "phase_zaya": phase_zaya}
+                "phase_mla": phase_mla, "phase_zaya": phase_zaya,
+                "phase_kimi": phase_kimi}
 
 
 def _run_child(phase: str) -> dict:
@@ -483,6 +523,7 @@ def main() -> None:
                "(same weights, same tokens, no update yet)")
         _emit(_run_child("phase_mla"))
         _emit(_run_child("phase_zaya"))
+        _emit(_run_child("phase_kimi"))
     _check(holder["platform"] == "tpu" and holder["n_devices"] == args.chips,
            f"ran on {holder['n_devices']} {holder['platform']} device(s), "
            f"wanted {args.chips} tpu")

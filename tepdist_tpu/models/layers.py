@@ -355,7 +355,11 @@ def over_sequence(fn, widest: int, *xs, weights=None):
     """``fn(start, *chunks)`` over chunks of the sequence (axis 1 of every
     ``x`` [B, T, ...]; ``start`` the chunk's first position), each chunk
     rematerialised in the backward pass; the results [B, T, ...] again.
-    ``fn`` returns an array or a tuple of arrays. For a block's token-wise
+    ``fn`` returns an array or a tuple of arrays, of any number, trailing
+    widths and dtypes: a block's first half hands the mixer between the
+    halves its inputs this way (a latent-attention layer's three, a KDA
+    layer's five: the projections, the float32 log decays and ``beta``
+    [B, T, heads]). For a block's token-wise
     parts (norms, projections, gates, an MLP), so that its working set holds
     ``[T, hidden]`` arrays and never a ``[T, widest]`` one; gradients of a
     weight are summed over the chunks in the weight's dtype.

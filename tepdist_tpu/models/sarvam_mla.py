@@ -69,7 +69,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -285,7 +285,7 @@ def rank_share(params, cfg: SarvamMLAConfig, heads_held: Tuple[int, int],
                                     experts_held=tuple(experts_held))
 
 
-def _widest(cfg: SarvamMLAConfig) -> int:
+def _widest(cfg) -> int:
     """What sizes the chunks of a block's token-wise parts
     (``over_sequence``), one size for every layer: a dense layer's MLP
     width, or half of a token's ``k`` rows of an expert layer's dropless
@@ -298,15 +298,19 @@ def _widest(cfg: SarvamMLAConfig) -> int:
                cfg.num_experts_per_tok * cfg.hidden_size // 2)
 
 
-def attention_inputs(blk, a, cfg: SarvamMLAConfig, start=0):
+def attention_inputs(blk, a, cfg, table: Optional[RopeTable], start=0):
     """a [B, T, d] (the normed input of positions ``start ..``) -> q_nope,
     q_rope, k_nope [B, T, Hh, .], k_rope [B, T, 1, Dr], v [B, T, Hh, Dv] of
-    the held heads, the rotary parts rotated."""
+    the held heads, the rotary parts rotated under ``table``; as they are
+    where it is None (a model whose latent layers carry no position:
+    ``models/kimi_linear.py``, whose ``cfg`` this also takes)."""
     B, T, _ = a.shape
     Hh, R = cfg.heads_held[1], cfg.kv_lora_rank
-    Dn, table = cfg.qk_nope_head_dim, cfg.rope_table
+    Dn = cfg.qk_nope_head_dim
 
     def rotated(t):          # [B, T, H, Dr], by the position
+        if table is None:
+            return t
         return rope(t.transpose(0, 2, 1, 3), table,
                     start).transpose(0, 2, 1, 3)
 
@@ -323,10 +327,12 @@ def attention_inputs(blk, a, cfg: SarvamMLAConfig, start=0):
     return q[..., :Dn], q_rope, kv[..., :Dn], k_rope, kv[..., Dn:]
 
 
-def attend(blk, x, cfg: SarvamMLAConfig):
+def attend(blk, x, cfg):
     """x [B, T, d] -> the held heads' outputs side by side [B, T, Hh * Dv],
     before ``wo``: the projections in chunks of the sequence, the kernels
-    over the whole of it."""
+    over the whole of it. ``cfg``: a :class:`SarvamMLAConfig` or whatever
+    has its head widths, ``heads_held``, ``rope_table`` (which may be None),
+    ``softmax_scale`` and the widths :func:`_widest` reads."""
     B, T, _ = x.shape
     traced.note("mla_heads_held", cfg.heads_held[1])
     traced.note("mla_latent_bytes",
@@ -336,7 +342,7 @@ def attend(blk, x, cfg: SarvamMLAConfig):
         operands = over_sequence(
             lambda start, xc: attention_inputs(
                 blk, rms_norm(xc, blk["input_ln"], cfg.rms_norm_eps), cfg,
-                start), _widest(cfg), x)
+                cfg.rope_table, start), _widest(cfg), x)
     o = mla_attention(*(t.transpose(0, 2, 1, 3) for t in operands),
                       causal=True, scale=cfg.softmax_scale,
                       block_q=cfg.flash_block_q or None,

@@ -100,8 +100,9 @@ FWD_FLOPS, BWD_FLOPS = 10, 25
 
 
 traced.declare(
-    "ssm_conv_calls", "forward calls a micro batch of the conv before the "
-    "selective scan (a rematerialised layer's second run counted)")
+    "ssm_conv_calls", "forward calls a micro batch of the short conv before "
+    "a sequence mixer, the selective scan's or the delta rule's three (a "
+    "rematerialised layer's second run counted)")
 
 
 def _total(tile, rolled, before, taps, bias):
@@ -118,13 +119,15 @@ def _total(tile, rolled, before, taps, bias):
     return bias + acc, xs
 
 
-def _fwd_kernel(u_ref, w_ref, b_ref, c_ref, tail_scr, *, K: int, bt: int):
+def _fwd_kernel(u_ref, w_ref, *rest, K: int, bt: int, biased: bool):
+    b_ref, c_ref, tail_scr = rest if biased else (None,) + rest
+
     @pl.when(pl.program_id(2) == 0)
     def _():
         tail_scr[...] = jnp.zeros(tail_scr.shape, _F32)
 
     taps = [w_ref[j:j + 1, :] for j in range(K)]
-    bias = b_ref[...]
+    bias = b_ref[...] if biased else 0.0
     down = range(1, K)
 
     def trip(i, before):
@@ -143,8 +146,10 @@ def _fwd_kernel(u_ref, w_ref, b_ref, c_ref, tail_scr, *, K: int, bt: int):
         tail_scr[s] = before[s]
 
 
-def _bwd_kernel(u_ref, halo_ref, dc_ref, w_ref, b_ref, du_ref, dwb_ref,
-                g_scr, sum_scr, *, K: int, bt: int):
+def _bwd_kernel(u_ref, halo_ref, dc_ref, w_ref, *rest, K: int, bt: int,
+                biased: bool):
+    b_ref, du_ref, dwb_ref, g_scr, sum_scr = rest if biased \
+        else (None,) + rest
     k = pl.program_id(2)                 # 0 is the sequence's last block
     first = pl.num_programs(2) - 1       # ... and this its first
 
@@ -154,7 +159,7 @@ def _bwd_kernel(u_ref, halo_ref, dc_ref, w_ref, b_ref, du_ref, dwb_ref,
         sum_scr[...] = jnp.zeros(sum_scr.shape, _F32)
 
     taps = [w_ref[j:j + 1, :] for j in range(K)]
-    bias = b_ref[...]
+    bias = b_ref[...] if biased else 0.0
     down = range(1, K)
     up = [TILE - s for s in down]        # a roll by 8 - s shifts up by s
     # The tile before the block: zeros before the sequence.
@@ -213,6 +218,16 @@ def _blocks(T: int, Di: int, block_t: int, block_d: int):
     return bt, _block_d(Di, block_d), _padded(T, bt)
 
 
+def _bias_spec(b, bd: int) -> list:
+    """The bias row's block, where there is a bias."""
+    return [] if b is None else [
+        pl.BlockSpec((1, bd), lambda i, j, k: (0, j))]
+
+
+def _bias_row(b) -> list:
+    return [] if b is None else [b.astype(_F32)[None, :]]
+
+
 def _params():
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
@@ -221,17 +236,18 @@ def _params():
 @functools.partial(jax.jit, inline=True, static_argnames=(
     "block_t", "block_d", "interpret"))
 def _fwd_call(u, w, b, *, block_t, block_d, interpret):
-    """``u`` [B, T, Di], ``w`` [K, Di], ``b`` [Di] -> ``c`` [B, T, Di]."""
+    """``u`` [B, T, Di], ``w`` [K, Di], ``b`` [Di] or None -> ``c`` [B, T,
+    Di]."""
     B, T, Di = u.shape
     K = w.shape[0]
     bt, bd, Tp = _blocks(T, Di, block_t, block_d)
     wide = pl.BlockSpec((1, bt, bd), lambda i, j, k: (i, k, j))
     c = pl.pallas_call(
-        functools.partial(_fwd_kernel, K=K, bt=bt),
+        functools.partial(_fwd_kernel, K=K, bt=bt, biased=b is not None),
         name="tepdist_conv_fwd",
         grid=(B, Di // bd, Tp // bt),
-        in_specs=[wide, pl.BlockSpec((K, bd), lambda i, j, k: (0, j)),
-                  pl.BlockSpec((1, bd), lambda i, j, k: (0, j))],
+        in_specs=[wide, pl.BlockSpec((K, bd), lambda i, j, k: (0, j))]
+        + _bias_spec(b, bd),
         out_specs=wide,
         out_shape=jax.ShapeDtypeStruct((B, Tp, Di), u.dtype),
         scratch_shapes=[pltpu.VMEM((K - 1, TILE, bd), _F32)],
@@ -239,7 +255,7 @@ def _fwd_call(u, w, b, *, block_t, block_d, interpret):
             flops=FWD_FLOPS * B * T * Di, transcendentals=B * T * Di,
             bytes_accessed=2 * B * T * Di * u.dtype.itemsize),
         compiler_params=_params(), interpret=interpret,
-    )(_pad(u, Tp), w.astype(_F32), b.astype(_F32)[None, :])
+    )(_pad(u, Tp), w.astype(_F32), *_bias_row(b))
     return c[:, :T]
 
 
@@ -247,7 +263,8 @@ def _fwd_call(u, w, b, *, block_t, block_d, interpret):
     "block_t", "block_d", "interpret"))
 def _bwd_call(u, w, b, dc, *, block_t, block_d, interpret):
     """-> ``du`` [B, T, Di] and the float32 sums ``dw`` [K, Di], ``db``
-    [Di]."""
+    [Di] (the sum of the pre-activation's gradient, whether or not there is
+    a bias to take it)."""
     B, T, Di = u.shape
     K = w.shape[0]
     bt, bd, Tp = _blocks(T, Di, block_t, block_d)
@@ -257,12 +274,12 @@ def _bwd_call(u, w, b, dc, *, block_t, block_d, interpret):
         i, jnp.maximum((nt - 1 - k) * (bt // HALO) - 1, 0), j))
     u = _pad(u, Tp)
     du, dwb = pl.pallas_call(
-        functools.partial(_bwd_kernel, K=K, bt=bt),
+        functools.partial(_bwd_kernel, K=K, bt=bt, biased=b is not None),
         name="tepdist_conv_bwd",
         grid=(B, nd, nt),
         in_specs=[wide, halo, wide,
-                  pl.BlockSpec((K, bd), lambda i, j, k: (0, j)),
-                  pl.BlockSpec((1, bd), lambda i, j, k: (0, j))],
+                  pl.BlockSpec((K, bd), lambda i, j, k: (0, j))]
+        + _bias_spec(b, bd),
         out_specs=[wide, pl.BlockSpec((1, 1, K + 1, bd),
                                       lambda i, j, k: (i, j, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((B, Tp, Di), u.dtype),
@@ -273,7 +290,7 @@ def _bwd_call(u, w, b, dc, *, block_t, block_d, interpret):
             flops=BWD_FLOPS * B * T * Di, transcendentals=B * T * Di,
             bytes_accessed=3 * B * T * Di * u.dtype.itemsize),
         compiler_params=_params(), interpret=interpret,
-    )(u, u, _pad(dc, Tp), w.astype(_F32), b.astype(_F32)[None, :])
+    )(u, u, _pad(dc, Tp), w.astype(_F32), *_bias_row(b))
     dwb = dwb.sum(0).transpose(1, 0, 2).reshape(K + 1, Di)
     return du[:, :T], dwb[:K], dwb[K]
 
@@ -296,36 +313,40 @@ def _conv_bwd(block_t, block_d, interpret, layers, res, dc):
     u, w, b = res
     du, dw, db = _bwd_call(u, w, b, dc, block_t=block_t, block_d=block_d,
                            interpret=interpret)
-    return du, dw.astype(w.dtype), db.astype(b.dtype)
+    return du, dw.astype(w.dtype), None if b is None else db.astype(b.dtype)
 
 
 _conv.defvjp(_conv_fwd, _conv_bwd)
 
 
-def causal_conv(u, w, b, *, block_t: int = BLOCK_T, block_d: int = BLOCK_D,
-                interpret: Optional[bool] = None):
+def causal_conv(u, w, b=None, *, block_t: int = BLOCK_T,
+                block_d: int = BLOCK_D, interpret: Optional[bool] = None):
     """``silu(b + sum_j w[j] * u[t - (K - 1) + j])``, zeros before the
     sequence: ``u`` [batch, T, Di], ``w`` [K, Di] with ``2 <= K <= 8``, ``b``
-    [Di] -> [batch, T, Di] in ``u``'s dtype, ``Di`` a multiple of 128.
+    [Di] or None (a conv without a bias: the kernels then take no such
+    operand) -> [batch, T, Di] in ``u``'s dtype, ``Di`` a multiple of 128.
     Differentiable in all three. Any ``T``: the last block is padded with
     zero rows. ``block_t`` rows and ``block_d`` channels a grid step.
 
     Counts, while it is traced, each forward kernel call in
-    ``ssm_conv_calls`` (``telemetry/traced.py``)."""
+    ``ssm_conv_calls`` (``telemetry/traced.py``): the short convs of any
+    sequence mixer, a state-space layer's or not."""
     if u.ndim != 3 or w.ndim != 2 or w.shape[1] != u.shape[2] \
-            or b.shape != u.shape[2:] or u.shape[2] % LANES \
+            or (b is not None and b.shape != u.shape[2:]) \
+            or u.shape[2] % LANES \
             or not 2 <= w.shape[0] <= TILE:
         raise ValueError(
-            f"causal_conv: u {u.shape}, w {w.shape}, b {b.shape}")
+            f"causal_conv: u {u.shape}, w {w.shape}, b "
+            f"{None if b is None else b.shape}")
     return _conv(u, w, b, block_t, block_d, _interpret(interpret),
                  traced.stood_for())
 
 
-def reference(u, w, b):
+def reference(u, w, b=None):
     """The same function in ``jax.numpy``, what the kernels are held to
     (tests, ``tools/ssm_bench.py``): pad, widen, add ``K`` shifted slices."""
     K, T = w.shape[0], u.shape[1]
     padded = jnp.pad(u, ((0, 0), (K - 1, 0), (0, 0))).astype(_F32)
-    total = b.astype(_F32) + sum(
+    total = (0.0 if b is None else b.astype(_F32)) + sum(
         w[j].astype(_F32) * padded[:, j:j + T] for j in range(K))
     return jax.nn.silu(total).astype(u.dtype)

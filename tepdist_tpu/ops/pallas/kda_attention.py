@@ -1,0 +1,493 @@
+"""Pallas TPU Kimi Delta Attention (the gated delta rule with a decay for
+every key channel; Kimi Linear, arXiv:2510.26692), chunked, forward and
+backward.
+
+For one head with ``K`` key and ``V`` value channels, a sequence ``q_t, k_t
+[K]``, ``v_t [V]``, log decays ``g_t [K] <= 0`` (``alpha_t = exp(g_t)``) and
+write strengths ``beta_t`` in [0, 1]:
+
+    S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t                                  S [K, V],  S_0 = 0
+
+(decay the state's rows, read ``r = S^T k_t`` from the decayed state, add
+``beta_t k_t (v_t - r)^T``). The state is read before it is written, so a
+chunk of ``C`` tokens is a triangular system. With ``G_i`` the running sum
+of ``g`` inside the chunk, ``Gamma = exp(G)`` and ``S`` the state before it:
+
+    A_ij  = beta_i sum_c k_ic k_jc exp(G_ic - G_jc)   (j < i)
+    Tm    = (I + A)^-1 Diag(beta)
+    W     = Tm (k * Gamma)        U = Tm v            v' = U - W S
+    o     = (q * Gamma) S + P v'  P_ij = sum_c q_ic k_jc exp(G_ic - G_jc)
+                                                      (j <= i)
+    S'    = Diag(Gamma_C) S + (k * Gamma_C / Gamma)^T v'
+
+**No factor overflows.** ``exp(G_i - G_j)`` does not split into ``Gamma_i /
+Gamma_j`` over a whole chunk (``1 / Gamma`` passes float32 at a decay of
+``exp(-1.4)`` a token over 64). The scores are made a sub-block of ``SUB`` =
+16 query rows at a time against a reference row of their own, the
+sub-block's first: the rows carry ``exp(G_i - G_ref) <= 1``, the keys
+``exp(min(G_ref - G_j, 80))``, which is at most 1 for a key before the
+sub-block, at most ``exp(15 |g|)`` inside it (``g`` = -5 a token still fits)
+and whatever it is for a key after it, where the mask drops the product.
+Everything else carries ``Gamma`` or ``Gamma_C / Gamma``, at most 1.
+
+**The inverse by doubling**: ``A`` is strictly lower, so ``(I + A)^-1 = (I -
+A)(I + A^2)(I + A^4) ...`` up to ``A^(C/2)``: two matmuls a factor, no
+substitution row by row.
+
+**The grid is ``(batch, chunks, heads)``**, the chunks sequential and the
+heads innermost, every head's state ``[V, K]`` (the transpose, so that a
+decay of the key channels scales lanes) in one float32 VMEM scratch ``[H, V,
+K]`` from chunk to chunk: ``beta`` and its gradient are then ``[chunk, H]``
+blocks as the projection leaves them, read and written once a chunk.
+``q, k, v, g`` are ``[batch, T, H * K]``, a projection's own layout, head
+``h`` lane block ``h``.
+
+**The backward keeps the operands and the state before every chunk**
+(``[batch, chunks, H, V, K]`` float32, 134e6 bytes at 8,192 tokens of 32
+heads in chunks of 128): a forward pass that is differentiated writes them
+as a second result of its one sweep, and inside a walked block, which is
+made again in the backward pass, they live from that second run to the
+layer's own backward and no longer. ``tepdist_kda_bwd`` walks the chunks
+last to first with the state's gradient carried, makes the chunk's ``G``,
+scores, ``Tm``, ``W``, ``U``, ``v'`` again from the operands and that state,
+and writes ``dq, dk, dv, dg, dbeta``. (:func:`backward` without the states
+makes them again by the forward's sweep under the name
+``tepdist_kda_bwd_states``: 5.8 ms a call at the cell's shape, which is what
+keeping them saves.) ``d Tm`` needs no inverse of its own: with ``X = [W | U] = (I +
+A)^-1 B``, ``dB = (I + A)^-T dX`` and ``dA = -dB X^T``.
+
+Precision (``_linear.py``): the state, ``G``, every decay factor and every
+accumulation are float32; a float32 operand goes to the matrix unit as two
+bf16 parts. ``G`` is summed by doubling over sublane rolls, float32 adds.
+With float32 operands (the CPU tests) every matmul is float32.
+
+Any ``T``: the last chunk is padded with zero rows (``g`` = 0, ``beta`` = 0:
+the state passes through them). Kernel names ``tepdist_kda_fwd`` /
+``tepdist_kda_bwd`` (and ``tepdist_kda_bwd_states``) show in a device trace
+and in the compiled HLO. Interpret mode off the TPU (tests), compiled on it (``K
+= V = 128`` there). :func:`chunked` is the same chunked form in plain
+``jax.numpy`` under a ``lax.scan`` (what the kernels were written from and
+are held to, with the token-by-token recurrence of
+``benchmark/reference/kimi_linear.py``); ``tools/kda_bench.py`` times the
+kernels alone.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from tepdist_tpu.ops.pallas import _interpret
+from tepdist_tpu.ops.pallas._linear import (
+    _BF16,
+    _F32,
+    _HIGHEST,
+    _NN,
+    _NT,
+    _TN,
+    _carried,
+    _dot,
+    _padded,
+)
+from tepdist_tpu.telemetry import traced
+
+CHUNK = 64                  # tokens a grid step
+SUB = 16                    # query rows that share a reference row
+_CAP = 80.0                 # exp(80) fits float32
+
+traced.declare(
+    "kda_calls", "forward delta-rule kernel calls a micro batch (a "
+    "rematerialised layer's second run counted)")
+
+
+def _prefix(x, reverse: bool = False):
+    """Running sums down the rows of ``x`` [C, K] (up them: ``reverse``),
+    each row's own included, by doubling."""
+    C = x.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    s = 1
+    while s < C:
+        if reverse:
+            x = x + jnp.where(row < C - s, pltpu.roll(x, C - s, 0), 0.0)
+        else:
+            x = x + jnp.where(row >= s, pltpu.roll(x, s, 0), 0.0)
+        s *= 2
+    return x
+
+
+def _ij(C: int):
+    return (jax.lax.broadcasted_iota(jnp.int32, (C, C), 0),
+            jax.lax.broadcasted_iota(jnp.int32, (C, C), 1))
+
+
+def _factors(G, lo: int):
+    """Sub-block ``lo``'s decays against its reference row: the query rows'
+    [SUB, K] and every key row's [C, K]."""
+    ref = G[lo:lo + 1]
+    return jnp.exp(G[lo:lo + SUB] - ref), \
+        jnp.exp(jnp.minimum(ref - G, _CAP))
+
+
+def _scores(q, k, G, narrow):
+    """``P`` and ``kk`` [C, C]: ``sum_c x_ic k_jc exp(G_ic - G_jc)`` for ``x``
+    the queries and the keys; right where ``j <= i``, finite elsewhere."""
+    k32 = k.astype(_F32)
+    rows_q, rows_k = [], []
+    for lo in range(0, k.shape[0], SUB):
+        rows, keys = _factors(G, lo)
+        both = jnp.concatenate([q[lo:lo + SUB].astype(_F32) * rows,
+                                k32[lo:lo + SUB] * rows], axis=0)
+        s = _dot(both, k32 * keys, _NT, narrow)             # [2 SUB, C]
+        rows_q.append(s[:SUB])
+        rows_k.append(s[SUB:])
+    return jnp.concatenate(rows_q, axis=0), jnp.concatenate(rows_k, axis=0)
+
+
+def _scores_backward(q, k, G, dP, dkk, narrow):
+    """The gradients of :func:`_scores`' two results (masked already) in
+    ``q``, ``k`` and ``G``. The reference rows are held fixed: a score does
+    not depend on its reference."""
+    k32 = k.astype(_F32)
+    dq, dk_rows = [], []
+    dk = jnp.zeros(k32.shape, _F32)
+    dG_rows = []
+    dG = jnp.zeros(k32.shape, _F32)
+    for lo in range(0, k.shape[0], SUB):
+        rows, keys = _factors(G, lo)
+        qt = q[lo:lo + SUB].astype(_F32) * rows
+        kr = k32[lo:lo + SUB] * rows
+        kc = k32 * keys
+        d_both = _dot(jnp.concatenate([dP[lo:lo + SUB], dkk[lo:lo + SUB]],
+                                      axis=0), kc, _NN, narrow)  # [2 SUB, K]
+        d_qt, d_kr = d_both[:SUB], d_both[SUB:]
+        d_kc = _dot(dP[lo:lo + SUB], qt, _TN, narrow) \
+            + _dot(dkk[lo:lo + SUB], kr, _TN, narrow)            # [C, K]
+        dq.append(d_qt * rows)
+        dk_rows.append(d_kr * rows)
+        dk = dk + d_kc * keys
+        dG_rows.append(d_qt * qt + d_kr * kr)
+        dG = dG - d_kc * kc
+    return jnp.concatenate(dq, axis=0), \
+        dk + jnp.concatenate(dk_rows, axis=0), \
+        dG + jnp.concatenate(dG_rows, axis=0)
+
+
+def _inverse(A, narrow):
+    """``(I + A)^-1`` of a strictly lower ``A`` [C, C], by doubling."""
+    C = A.shape[0]
+    i, j = _ij(C)
+    inv = jnp.where(i == j, 1.0, 0.0) - A
+    power, n = A, 2
+    while n < C:                    # A^C = 0
+        power = _dot(power, power, _NN, narrow)
+        inv = inv + _dot(inv, power, _NN, narrow)
+        n *= 2
+    return inv
+
+
+def _column(b, h):
+    """Head ``h``'s column [C, 1] of a ``[C, H]`` block."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, b.shape, 1)
+    return jnp.sum(jnp.where(lane == h, b.astype(_F32), 0.0), axis=1,
+                   keepdims=True)
+
+
+def _chunk(q, k, v, g, beta, state_t, narrow):
+    """What the forward and the backward both make of a chunk: a dict."""
+    C = q.shape[0]
+    G = _prefix(g.astype(_F32))
+    last = G[C - 1:C]
+    gamma, tail = jnp.exp(G), jnp.exp(last - G)
+    P, kk = _scores(q, k, G, narrow)
+    i, j = _ij(C)
+    P = jnp.where(j <= i, P, 0.0)
+    kk = jnp.where(j < i, kk, 0.0)
+    inv = _inverse(kk * beta, narrow)
+    k32 = k.astype(_F32)
+    kg = k32 * gamma
+    W = _dot(inv, kg * beta, _NN, narrow)
+    U = _dot(inv, v.astype(_F32) * beta, _NN, narrow)
+    vp = U - _dot(W, state_t, _NT, narrow)
+    return dict(G=G, last=last, gamma=gamma, tail=tail, P=P, kk=kk, inv=inv,
+                kg=kg, qg=q.astype(_F32) * gamma, kd=k32 * tail, W=W, U=U,
+                vp=vp)
+
+
+def _next_state(c, state_t, narrow, state_dtype):
+    return _carried(state_t * jnp.exp(c["last"])
+                    + _dot(c["vp"], c["kd"], _TN, narrow), state_dtype)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, *rest, want, narrow,
+                state_dtype):
+    """First chunk to last, every head's state carried. ``want``: which of
+    the output ``"o"`` and the state before the chunk ``"states"`` are the
+    results, in that order."""
+    outs, s_scr = dict(zip(want, rest)), rest[-1]
+    h = pl.program_id(2)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        s_scr[h] = jnp.zeros(s_scr.shape[1:], _F32)
+
+    state_t = s_scr[h]
+    c = _chunk(q_ref[...], k_ref[...], v_ref[...], g_ref[...],
+               _column(b_ref[...], h), state_t, narrow)
+    if "states" in outs:
+        outs["states"][...] = state_t
+    if "o" in outs:
+        out = _dot(c["qg"], state_t, _NT, narrow) \
+            + _dot(c["P"], c["vp"], _NN, narrow)
+        outs["o"][...] = out.astype(outs["o"].dtype)
+    s_scr[h] = _next_state(c, state_t, narrow, state_dtype)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, s_ref, dq_ref,
+                dk_ref, dv_ref, dg_ref, db_ref, ds_scr, *, narrow,
+                state_dtype):
+    """Last chunk to first with the state's gradient carried."""
+    h = pl.program_id(2)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        ds_scr[h] = jnp.zeros(ds_scr.shape[1:], _F32)
+
+    @pl.when(h == 0)
+    def _():
+        db_ref[...] = jnp.zeros(db_ref.shape, db_ref.dtype)
+
+    q, k, v, do = q_ref[...], k_ref[...], v_ref[...], do_ref[...]
+    beta = _column(b_ref[...], h)
+    state_t, d_next = s_ref[...], ds_scr[h]
+    c = _chunk(q, k, v, g_ref[...], beta, state_t, narrow)
+    C = q.shape[0]
+    i, j = _ij(C)
+    decay = jnp.exp(c["last"])                              # Gamma_C [1, K]
+
+    d_vp = _dot(c["P"], do, _TN, narrow) + _dot(c["kd"], d_next, _NT, narrow)
+    dP = jnp.where(j <= i, _dot(do, c["vp"], _NT, narrow), 0.0)
+    d_qg = _dot(do, state_t, _NN, narrow)
+    d_kd = _dot(c["vp"], d_next, _NN, narrow)
+    d_last = decay * jnp.sum(state_t * d_next, axis=0, keepdims=True)
+    dW = -_dot(d_vp, state_t, _NN, narrow)
+    # X = [W | U] = (I + A)^-1 B:  dB = (I + A)^-T dX,  dA = -dB X^T.
+    dBw = _dot(c["inv"], dW, _TN, narrow)
+    dBu = _dot(c["inv"], d_vp, _TN, narrow)
+    dA = -jnp.where(j < i, _dot(dBw, c["W"], _NT, narrow)
+                    + _dot(dBu, c["U"], _NT, narrow), 0.0)
+    v32 = v.astype(_F32)
+    d_beta = jnp.sum(dBw * c["kg"], axis=1, keepdims=True) \
+        + jnp.sum(dBu * v32, axis=1, keepdims=True) \
+        + jnp.sum(dA * c["kk"], axis=1, keepdims=True)
+    d_kg = dBw * beta
+    dq, dk, dG = _scores_backward(q, k, c["G"], dP, dA * beta, narrow)
+    dq = dq + d_qg * c["gamma"]
+    dk = dk + d_kg * c["gamma"] + d_kd * c["tail"]
+    through_tail = d_kd * c["kd"]
+    dG = dG + d_kg * c["kg"] + d_qg * c["qg"] - through_tail
+    row = jax.lax.broadcasted_iota(jnp.int32, dG.shape, 0)
+    dG = dG + jnp.where(
+        row == C - 1,
+        d_last + jnp.sum(through_tail, axis=0, keepdims=True), 0.0)
+
+    dq_ref[...] = dq.astype(dq_ref.dtype)
+    dk_ref[...] = dk.astype(dk_ref.dtype)
+    dv_ref[...] = (dBu * beta).astype(dv_ref.dtype)
+    dg_ref[...] = _prefix(dG, reverse=True).astype(dg_ref.dtype)
+    lane = jax.lax.broadcasted_iota(jnp.int32, db_ref.shape, 1)
+    db_ref[...] = jnp.where(lane == h, d_beta.astype(db_ref.dtype),
+                            db_ref[...])
+    ds_scr[h] = _carried(
+        d_next * decay + _dot(do, c["qg"], _TN, narrow)
+        - _dot(d_vp, c["W"], _TN, narrow), state_dtype)
+
+
+def _call(kernel, name, operands, outs, *, chunk, reverse, matmuls,
+          interpret, state_dtype=None):
+    """One sweep over the chunks. ``operands``: ``(kind, array)`` each, the
+    kinds ``wide`` ``[B, T, H * K]``, ``beta`` ``[B, T, H]`` and ``states``
+    ``[B, chunks, H, V, K]`` (whole chunks: the caller pads); ``outs``:
+    ``(kind, dtype)`` of each result."""
+    B, T, HK = operands[0][1].shape
+    H = next(x.shape[2] for kind, x in operands if kind == "beta")
+    K = HK // H
+    nc = T // chunk
+    at = (lambda c: nc - 1 - c) if reverse else (lambda c: c)
+    specs = {
+        "wide": pl.BlockSpec((None, chunk, K), lambda b, c, h: (b, at(c), h)),
+        "beta": pl.BlockSpec((None, chunk, H), lambda b, c, h: (b, at(c), 0)),
+        "states": pl.BlockSpec((None, None, None, K, K),
+                               lambda b, c, h: (b, at(c), h, 0, 0)),
+    }
+    shapes = {"wide": (B, T, HK), "beta": (B, T, H),
+              "states": (B, nc, H, K, K)}
+    out_shape = [jax.ShapeDtypeStruct(shapes[kind], dtype)
+                 for kind, dtype in outs]
+    return pl.pallas_call(
+        functools.partial(kernel, narrow=operands[0][1].dtype == _BF16,
+                          state_dtype=state_dtype),
+        name=name,
+        grid=(B, nc, H),
+        in_specs=[specs[kind] for kind, _ in operands],
+        out_specs=[specs[kind] for kind, _ in outs],
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((H, K, K), _F32)],
+        cost_estimate=pl.CostEstimate(
+            flops=2 * matmuls * B * H * T * K * (chunk + K) // 2,
+            transcendentals=B * H * T * K * (3 + chunk // SUB),
+            bytes_accessed=sum(
+                x.size * jnp.dtype(x.dtype).itemsize
+                for x in [x for _, x in operands] + out_shape)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(*(x for _, x in operands))
+
+
+def _operands(chunk, q, k, v, g, beta, *more):
+    """The sweeps' operands by kind, padded to whole chunks."""
+    wide = [q, k, v, g.astype(_F32)] + list(more)
+    return [("wide", _padded(x, chunk)) for x in wide[:4]] \
+        + [("beta", _padded(beta.astype(_F32), chunk))] \
+        + [("wide", _padded(x, chunk)) for x in wide[4:]]
+
+
+def forward(q, k, v, g, beta, *, chunk: int = CHUNK, interpret=None,
+            out_dtype=None, state_dtype=None, states: bool = False):
+    """The forward kernel alone; with ``states`` also the state before
+    every chunk, ``[B, chunks, H, V, K]`` float32 (what the backward kernel
+    reads), as a second result of the same sweep. A check's ``out_dtype``
+    (the result in float32, not rounded to the operands' dtype) and
+    ``state_dtype`` (the carried state through a narrower dtype: the check's
+    control)."""
+    want = ("o", "states") if states else ("o",)
+    out = _call(functools.partial(_fwd_kernel, want=want),
+                "tepdist_kda_fwd", _operands(chunk, q, k, v, g, beta),
+                [("wide", out_dtype or q.dtype), ("states", _F32)][:len(want)],
+                chunk=chunk, reverse=False, matmuls=23,
+                interpret=_interpret(interpret), state_dtype=state_dtype)
+    o = out[0][:, :q.shape[1]]
+    return (o, out[1]) if states else o
+
+
+def backward(q, k, v, g, beta, do, *, states=None, chunk: int = CHUNK,
+             interpret=None, out_dtype=None, state_dtype=None):
+    """``(dq, dk, dv, dg, dbeta)``; ``dg`` and ``dbeta`` float32. ``states``:
+    the states before every chunk as :func:`forward` hands them over
+    (``states=True``); None makes them again from the operands, a sweep of
+    its own (``tepdist_kda_bwd_states``)."""
+    interpret = _interpret(interpret)
+    operands = _operands(chunk, q, k, v, g, beta, do)
+    if states is None:
+        states, = _call(functools.partial(_fwd_kernel, want=("states",)),
+                        "tepdist_kda_bwd_states", operands[:5],
+                        [("states", _F32)], chunk=chunk, reverse=False,
+                        matmuls=21, interpret=interpret,
+                        state_dtype=state_dtype)
+    dtype = out_dtype or q.dtype
+    out = _call(_bwd_kernel, "tepdist_kda_bwd",
+                operands + [("states", states)],
+                [("wide", dtype)] * 3 + [("wide", _F32), ("beta", _F32)],
+                chunk=chunk, reverse=True, matmuls=45, interpret=interpret,
+                state_dtype=state_dtype)
+    return tuple(x[:, :q.shape[1]] for x in out)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _attend(q, k, v, g, beta, chunk, interpret, layers):
+    traced.count("kda_calls", layers=layers)
+    return forward(q, k, v, g, beta, chunk=chunk, interpret=interpret)
+
+
+def _attend_fwd(q, k, v, g, beta, chunk, interpret, layers):
+    traced.count("kda_calls", layers=layers)
+    o, states = forward(q, k, v, g, beta, chunk=chunk, interpret=interpret,
+                        states=True)
+    return o, (q, k, v, g, beta, states)
+
+
+def _attend_bwd(chunk, interpret, layers, res, do):
+    q, k, v, g, beta, states = res
+    dq, dk, dv, dg, dbeta = backward(q, k, v, g, beta, do, states=states,
+                                     chunk=chunk, interpret=interpret)
+    return dq, dk, dv, dg.astype(g.dtype), dbeta.astype(beta.dtype)
+
+
+_attend.defvjp(_attend_fwd, _attend_bwd)
+
+
+def kda_attention(q, k, v, g, beta, *, chunk: int = CHUNK,
+                  interpret: Optional[bool] = None):
+    """The gated delta rule over ``q, k, v`` [batch, T, heads * K] (``V =
+    K``), ``g`` [batch, T, heads * K] (float32 log decays, at most 0) and
+    ``beta`` [batch, T, heads] -> ``o`` [batch, T, heads * K] in ``q``'s
+    dtype. Differentiable in all five. No scale and no norm is applied: the
+    caller's ``q`` and ``k`` carry them. The state starts at zero for every
+    row of the batch.
+
+    Counts, while it is traced, each forward kernel call in ``kda_calls``
+    (``telemetry/traced.py``)."""
+    if not (q.shape == k.shape == v.shape == g.shape) or q.ndim != 3 \
+            or beta.shape[:2] != q.shape[:2] or beta.ndim != 3 \
+            or q.shape[2] % beta.shape[2] or chunk % SUB:
+        raise ValueError(
+            f"kda_attention: q {q.shape}, k {k.shape}, v {v.shape}, g "
+            f"{g.shape}, beta {beta.shape}, chunk {chunk}")
+    chunk = min(chunk, -(-q.shape[1] // SUB) * SUB)
+    return _attend(q, k, v, g, beta, chunk, _interpret(interpret),
+                   traced.stood_for())
+
+
+def chunked(q, k, v, g, beta, *, chunk: int = CHUNK):
+    """:func:`kda_attention` in plain ``jax.numpy``: the chunked form above
+    under a ``lax.scan`` over the chunks, float32 at the highest matmul
+    precision, the decays of a chunk's pairs as one ``[C, C, K]`` array a
+    head (masked before the exponential, so nothing overflows) and the
+    triangular system by ``solve_triangular``. Differentiable by autodiff;
+    what the kernels are held to beside the recurrence."""
+    B, T, HK = q.shape
+    H = beta.shape[2]
+    C = min(chunk, T)
+    nc = -(-T // C)
+
+    def heads(x):            # [B, T, H * n] -> [chunks, B, H, C, n]
+        x = _padded(x.astype(_F32), C)
+        return x.reshape(B, nc, C, H, -1).transpose(1, 0, 3, 2, 4)
+
+    i, j = _ij(C)
+    eye = jnp.eye(C, dtype=_F32)
+    dot = functools.partial(jnp.einsum, precision=_HIGHEST)
+
+    def step(S, xs):         # S [B, H, K, V]
+        q, k, v, g, b = xs
+        G = jnp.cumsum(g, axis=-2)
+        diff = G[..., :, None, :] - G[..., None, :, :]       # [.., C, C, K]
+        decay = jnp.exp(jnp.where((j <= i)[..., None], diff, -jnp.inf))
+        P = dot("bhik,bhjk,bhijk->bhij", q, k, decay)
+        A = jnp.where(j < i, dot("bhik,bhjk,bhijk->bhij", k, k, decay),
+                      0.0) * b
+        gamma = jnp.exp(G)
+        WU = jax.scipy.linalg.solve_triangular(
+            eye + A, jnp.concatenate([k * gamma * b, v * b], axis=-1),
+            lower=True)
+        W, U = WU[..., :k.shape[-1]], WU[..., k.shape[-1]:]
+        vp = U - dot("bhck,bhkv->bhcv", W, S)
+        o = dot("bhck,bhkv->bhcv", q * gamma, S) \
+            + dot("bhij,bhjv->bhiv", P, vp)
+        last = G[..., -1:, :]
+        S = S * jnp.exp(last).swapaxes(-1, -2) \
+            + dot("bhck,bhcv->bhkv", k * jnp.exp(last - G), vp)
+        return S, o
+
+    K = HK // H
+    _, o = jax.lax.scan(     # a chunk's [C, C, K] decays are made again
+        jax.checkpoint(step), jnp.zeros((B, H, K, K), _F32),
+        tuple(map(heads, (q, k, v, g, beta))))
+    o = o.transpose(1, 0, 3, 2, 4).reshape(B, nc * C, HK)
+    return o[:, :T].astype(q.dtype)

@@ -53,7 +53,9 @@ def mesh8(devices):
 # worker busy to the end. By the summed case seconds of a run under six
 # workers (over 120 s a file).
 _LONGEST_FIRST = (
-    "test_mellum.py", "test_tpu_compile.py", "test_sarvam_mla.py",
+    "test_mellum.py", "test_tpu_compile.py", "test_kimi_linear.py",
+    "test_kimi_linear_walk.py", "test_sarvam_mla.py",
+    "test_tpu_compile_kimi.py",
     "test_stack_in_place.py", "test_minicpm_sala.py", "test_afmoe.py",
     "test_jamba.py", "test_multiworker.py", "test_jaxpr_serde.py",
     "test_evaluator_measured.py", "test_sequence_parallel.py",

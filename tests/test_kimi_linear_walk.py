@@ -1,0 +1,211 @@
+"""Kimi Linear's walks: the four runs of unequal shape through
+``scan_blocks`` by the checkpointed scan and by the written-out backward
+equal to the ``l{i}`` Python loop, the expert leaves an ``ExpertStack`` in
+each expert run, what the gauges of a traced step say, the scopes, and two
+steps through ``plan_training`` against a plain ``jax.grad`` and optimizer
+loop."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from kernel_checks import kernel_counts
+from test_kimi_linear import (
+    CFG,
+    OUTSIDE,
+    biases,
+    hyper,
+    init_params,
+    ref_expert_counts,
+    to_reference,
+    tree_close,
+)
+
+from benchmark.reference import kimi_linear as ref
+from tepdist_tpu.models import afmoe, decoder, sarvam_mla
+from tepdist_tpu.models import kimi_linear as kimi
+from tepdist_tpu.ops.pallas.grouped_matmul import ExpertStack
+from tepdist_tpu.optim import make_optimizer
+from tepdist_tpu.parallel.sync_free import build_ga_step
+from tepdist_tpu.telemetry import metrics
+
+OPT = {"name": "adamw_bf16_router_bias", "learning_rate": 1e-3,
+       "bias_rate": 0.001}
+
+
+@pytest.fixture(autouse=True)
+def _highest():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def _ga_step(cfg, micro):
+    tx = make_optimizer(dict(OPT))
+    loss = lambda p, t: kimi.loss_fn(p, t, cfg)             # noqa: E731
+
+    def apply_fn(p, s, g):
+        updates, s = tx.update(g, s, p)
+        return optax.apply_updates(p, updates), s
+
+    return tx, build_ga_step(
+        lambda p, t: jax.value_and_grad(loss)(p, t), apply_fn, micro,
+        loss_fn=loss)
+
+
+def _unstacked(tree, cfg):
+    """A stacked tree as ``l{i}`` dicts."""
+    out = {k: tree[k] for k in OUTSIDE}
+    for i, blk in enumerate(decoder.layer_dicts(
+            tree, decoder.run_stacks(cfg.kinds), kimi.GROUPS)):
+        out[f"l{i}"] = blk
+    return out
+
+
+_STEPS = {}
+
+
+def _jitted_step(cfg, micro):
+    """``(optimizer, jitted step)`` of ``micro`` micro batches, one a
+    (configuration, micro): the planned steps' plain loop below is the
+    stacked walk's one-micro-batch step, compiled once."""
+    if (cfg, micro) not in _STEPS:
+        tx, step = _ga_step(cfg, micro)
+        _STEPS[cfg, micro] = tx, jax.jit(step)
+    return _STEPS[cfg, micro]
+
+
+def _one_step(cfg, micro, stacked, tokens):
+    params = jax.tree_util.tree_map(jnp.copy, init_params(cfg, stacked))
+    tx, step = _jitted_step(cfg, micro)
+    loss, new, _ = step(params, tx.init(params), tokens)
+    return loss, new
+
+
+_LOOP = {}
+
+
+def _loop_step(cfg, tokens):
+    """The ``l{i}`` loop's step (one micro batch), made once."""
+    if "step" not in _LOOP:
+        _LOOP["step"] = _one_step(cfg, 1, False, tokens)
+    return _LOOP["step"]
+
+
+@pytest.mark.parametrize("micro", [1, 2], ids=["plain", "accumulating"])
+def test_the_stacked_walk_is_the_layer_loop(micro, monkeypatch):
+    """One optimizer step over the four runs against the ``l{i}`` loop's,
+    without accumulation (the plain checkpointed scan) and with (the
+    written-out backward, the expert leaves an ``ExpertStack`` in each of
+    the three expert runs)."""
+    cfg = dataclasses.replace(CFG, remat=True, loss_chunk=16)
+    tokens = kimi.fake_batch(cfg, 4, 32, seed=9)
+    loss_l, loop = _loop_step(cfg, tokens)
+    handed = []
+    moe = afmoe.moe
+
+    def watched(blk, h, c):
+        handed.append(tuple(type(blk[k]) for k in decoder.EXPERT_LEAVES))
+        return moe(blk, h, c)
+
+    monkeypatch.setattr(kimi, "moe", watched)
+    monkeypatch.setattr(sarvam_mla, "moe", watched)
+    loss_s, stack = _one_step(cfg, micro, True, tokens)
+    assert float(loss_s) == pytest.approx(float(loss_l), rel=2e-6)
+    # Adam's first step is sign-like: where a gradient is next to nothing
+    # the order of the sums shows in the update.
+    tree_close(_unstacked(stack, cfg), loop, 5e-4, skip=())
+    stacks = [kinds for kinds in handed if kinds == (ExpertStack,) * 3]
+    if micro == 2:
+        # Each of the three expert runs' bodies met stacks under the
+        # written-out backward.
+        assert len(stacks) >= 3, handed
+        assert metrics().gauge("moe_stack_in_place_calls").value == 4 * 12
+    else:
+        assert not stacks
+
+
+def test_the_gauges_of_a_traced_step():
+    """Two micro batches, five layers in four walks: the delta-rule forward
+    runs twice a KDA layer and micro batch (a walked block makes its mixer
+    again: 8), the latent layer's forward once (kept), the convs three
+    times a run of the mixer."""
+    cfg = dataclasses.replace(CFG, remat=True, loss_chunk=16)
+    params = init_params(cfg, stacked=True)
+    tokens = kimi.fake_batch(cfg, 4, 32, seed=8)
+    tx, step = _ga_step(cfg, 2)
+    found = kernel_counts(step, params, tx.init(params), tokens)
+    gauge = lambda n: metrics().gauge(n).value              # noqa: E731
+    assert gauge("kda_calls") == 8
+    assert gauge("mla_fwd_calls") == 1 and gauge("attn_kept_calls") == 1
+    assert gauge("mla_bwd_calls") == 1
+    assert gauge("ssm_conv_calls") == 4 * 3 * 2
+    assert gauge("kda_state_bytes") == 2 * 4 * 32 * 32 * 4
+    assert gauge("kda_decay_bytes") == 2 * 32 * 4 * 32 * 4
+    assert gauge("mla_heads_held") == 2
+    assert gauge("mla_latent_bytes") == 2 * 32 * (24 + 8) * 4
+    assert gauge("moe_rows_sum_calls") == 2 * 4
+    names = "".join(found)
+    assert "tepdist_kda_bwd_states" not in names    # the states are kept
+    for kernel in ("tepdist_kda_fwd", "tepdist_kda_bwd",
+                   "tepdist_conv_fwd", "tepdist_conv_bwd",
+                   "tepdist_mla_fwd", "tepdist_mla_dkv", "tepdist_gmm_"):
+        assert kernel in names, (kernel, sorted(found))
+    stacks = sum(a.nbytes for r in range(4)
+                 for a in jax.tree_util.tree_leaves(params[f"run{r}"]))
+    assert gauge("ga_fused_bytes") == stacks
+
+
+def test_the_mixers_parts_carry_their_scopes():
+    cfg = dataclasses.replace(CFG, remat=True)
+    params = init_params(cfg, stacked=True)
+    tokens = kimi.fake_batch(cfg, 1, 32)
+    text = jax.jit(kimi.loss_fn, static_argnums=2).lower(
+        params, tokens, cfg).as_text(debug_info=True)
+    for scope in ("kda_in", "kda_conv", "kda_gates", "kda_core", "kda_out",
+                  "kda_out_mlp", "mla_q", "mla_kv_down", "mla_kv_up",
+                  "mla_out", "moe_router", "moe_shared", "part_mixer",
+                  "part_mlp", "part_moe", "tepdist_kda_fwd",
+                  "tepdist_mla_fwd"):
+        assert scope in text, scope
+    assert "rope_yarn" not in text and "rope_plain" not in text
+
+
+@pytest.mark.parametrize("stacked", [True], ids=["stacked"])
+def test_two_planned_steps_are_a_plain_grad_and_optimizer_loop(stacked,
+                                                               devices):
+    """``plan_training`` with 2 micro batches accumulated in one program
+    against ``jax.grad`` of the whole batch and the optimizer by hand: the
+    same losses, the same parameters, the selection bias moved by the
+    reference's update of each step's counts."""
+    from tepdist_tpu.train import plan_training
+    cfg = dataclasses.replace(CFG, remat=True, loss_chunk=16)
+    params = init_params(cfg, stacked)
+    batches = [kimi.fake_batch(cfg, 4, 32, seed=s) for s in (2, 3)]
+    tx = make_optimizer(dict(OPT))
+    # The plan's first step donates the arrays it was given.
+    plan = plan_training(lambda p, t: kimi.loss_fn(p, t, cfg), tx,
+                         jax.tree_util.tree_map(jnp.copy, params),
+                         batches[0], devices=devices[:1], explore=False,
+                         num_micro_batches=2)
+
+    # The plain loop: ``jax.value_and_grad`` of the whole batch and the
+    # optimizer, one micro batch (``build_ga_step(..., 1)`` is that).
+    _, plain = _jitted_step(cfg, 1)
+    state, p, bias = tx.init(params), params, None
+    for tokens in batches:
+        counts = ref_expert_counts(to_reference(p, cfg), tokens, hyper(cfg))
+        want_loss, p, state = plain(p, state, tokens)
+        assert plan.step(tokens) == pytest.approx(float(want_loss), rel=2e-6)
+        bias = ref.bias_update(0.0 if bias is None else bias, counts,
+                               OPT["bias_rate"])
+    got, _ = jax.tree_util.tree_unflatten(plan._state_tree,
+                                          plan._device_state())
+    # Adam's first steps are sign-like: where a gradient is next to nothing
+    # the order of the accumulation's sums shows in the update.
+    tree_close(got, p, 5e-4, skip=())
+    np.testing.assert_allclose(np.asarray(biases(got, stacked)),
+                               np.asarray(bias), atol=1e-9)
+    assert np.abs(np.asarray(bias)).max() > 0

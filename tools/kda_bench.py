@@ -1,0 +1,216 @@
+"""Time the Kimi Delta Attention kernels alone, on the chip.
+
+``ops/pallas/kda_attention.py`` at the Kimi Linear cell's ``[1, 8192, 32 x
+128]`` in bf16 with float32 log decays and ``beta``: device microseconds a
+call of the forward kernel (under differentiation it also writes the states
+before every chunk) and of the backward kernel, for each chunk asked for,
+from one ``jax.profiler`` trace a variant reduced by ``benchmark/trace_reduce.py``,
+each beside its roofline time (``benchmark/kernels/kda_cost.py``); the
+relative L2 distance of the output and the five gradients (asked for in
+float32) from the token-by-token float32 recurrence of
+``benchmark/reference/kimi_linear.py`` and from the chunked ``jax.numpy`` form
+(``kda_attention.chunked``, which is also timed, forward and backward, as
+what the kernels replace); ``--state-dtype bf16`` reads the same with the
+carried state rounded to bf16 (a control: what a narrower carry costs).
+
+Operands as a KDA layer hands them over: ``q`` and ``k`` unit L2 norm a
+head, ``q`` over ``sqrt(K)``, ``v`` a unit-variance projection, ``g = -exp(A)
+softplus(.)`` over the initialisation's range (``A = log U(1, 16)`` a head,
+the softplus ``exp(U(log 1e-3, log 1e-1))`` a channel and token, times
+``--decay-scale``), ``beta`` a sigmoid of a unit normal.
+
+The kernels are found as the benchmark finds them
+(``benchmark/layer_metrics/_kda.py``). No benchmark cell runs this; there is
+no CPU fallback: without a TPU it exits 2.
+
+Run: chiprun -- python tools/kda_bench.py [--tokens 8192] [--chunk 64,128]
+     [--state-dtype f32] [--decay-scale 1] [--check 1]
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+NAMES = ("out", "dq", "dk", "dv", "dg", "dbeta")
+
+
+def make_inputs(T: int, H: int, K: int, dtype, seed: int, decay_scale=1.0):
+    """``q, k, v, g, beta, do``: ``[1, T, H * K]`` but ``beta`` ``[1, T,
+    H]``."""
+    import jax
+    import jax.numpy as jnp
+    ks = jax.random.split(jax.random.PRNGKey(seed % 2 ** 31), 7)
+    f32 = jnp.float32
+
+    def unit(k):
+        x = jax.random.normal(k, (1, T, H, K), f32)
+        return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True))
+
+    def flat(x):
+        return x.reshape(1, T, H * K)
+
+    rate = jax.random.uniform(ks[3], (H, 1), f32, 1.0, 16.0)
+    step = jnp.exp(jax.random.uniform(ks[4], (1, T, H, K), f32,
+                                      jnp.log(1e-3), jnp.log(1e-1)))
+    return (flat(unit(ks[0]) * K ** -0.5).astype(dtype),
+            flat(unit(ks[1])).astype(dtype),
+            flat(jax.random.normal(ks[2], (1, T, H, K), f32)).astype(dtype),
+            flat(-decay_scale * rate * step),
+            jax.nn.sigmoid(jax.random.normal(ks[5], (1, T, H), f32)),
+            flat(jax.random.normal(ks[6], (1, T, H, K), f32)).astype(dtype))
+
+
+def _out_and_gradients(fn, inputs):
+    """``fn(q, k, v, g, beta) -> o`` and its five gradients under ``do``,
+    everything float32."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def run(*x):
+        out, vjp = jax.vjp(fn, *x[:5])
+        return (out,) + vjp(x[5])
+    return jax.block_until_ready(run(*(x.astype(jnp.float32)
+                                       for x in inputs)))
+
+
+def variants(args, peaks, trace_root):
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import trace_reduce
+    from benchmark.kernels import kda_cost
+    from benchmark.kernels.ssm_check import rel_l2
+    from benchmark.layer_metrics import _kda
+    from benchmark.reference import kimi_linear as ref
+    from tepdist_tpu.ops.pallas import kda_attention as kda
+    from tools.sala_bench import _traced
+
+    H, K, T = args.heads, args.head_dim, args.tokens
+    inputs = make_inputs(T, H, K, jnp.bfloat16, args.seed, args.decay_scale)
+    want = chunked = None
+    if args.check:
+        def heads(x):
+            return x[0].reshape(T, H, -1)
+
+        with jax.default_matmul_precision("highest"):
+            want = _out_and_gradients(
+                lambda q, k, v, g, b: ref.recurrence(
+                    heads(q), heads(k), heads(v), heads(g), b[0]).reshape(
+                        1, T, H * K), inputs)
+            chunked = _out_and_gradients(
+                lambda *a: kda.chunked(*a, chunk=64), inputs)
+        yield {"what": "chunked jax.numpy form against the recurrence",
+               "rel_l2": {n: rel_l2(c, w)
+                          for n, c, w in zip(NAMES, chunked, want)}}
+
+        @jax.jit
+        def plain(q, k, v, g, beta, do):
+            out, vjp = jax.vjp(lambda *a: kda.chunked(*a, chunk=64),
+                               q, k, v, g, beta)
+            return (out,) + vjp(do)
+        try:
+            summary = _traced("chunked", lambda: plain(*inputs), args.iters,
+                              trace_root)
+            yield {"what": "chunked jax.numpy form, forward and backward",
+                   "device_us_per_iter": 1e6 * sum(
+                       s for _, s, _ in summary.ops(lambda t: True))
+                   / args.iters}
+        except Exception as e:  # noqa: BLE001 — the kernels are still timed
+            yield {"what": "chunked jax.numpy form, forward and backward",
+                   "error": repr(e)[:2000]}
+    state = {"f32": None, "bf16": jnp.bfloat16}[args.state_dtype]
+    for chunk in (int(c) for c in args.chunk.split(",")):
+        record = {"what": "kda", "chunk": chunk, "tokens": T, "heads": H,
+                  "state_dtype": args.state_dtype, "iters": args.iters,
+                  "decay_scale": args.decay_scale}
+        try:
+            if want is not None:
+                how = dict(chunk=chunk,
+                           out_dtype=jnp.float32, state_dtype=state)
+                got = jax.block_until_ready(jax.jit(
+                    lambda *x: (kda.forward(*x[:5], **how),)
+                    + kda.backward(*x, **how))(*inputs))
+                record["rel_l2_vs_recurrence_f32"] = {
+                    n: rel_l2(a, w) for n, a, w in zip(NAMES, got, want)}
+                record["rel_l2_vs_chunked_f32"] = {
+                    n: rel_l2(a, w) for n, a, w in zip(NAMES, got, chunked)}
+
+            @jax.jit
+            def grad(q, k, v, g, beta, do, chunk=chunk):
+                out, vjp = jax.vjp(lambda *a: kda.kda_attention(
+                    *a, chunk=chunk), q, k, v, g, beta)
+                return (out,) + vjp(do)
+
+            summary = _traced(f"kda-{chunk}", lambda: grad(*inputs),
+                              args.iters, trace_root)
+            kernels = {}
+            for text, secs, calls in summary.ops(_kda.is_kda):
+                parsed = _kda.parse(text)
+                name = trace_reduce.short_name(text)
+                if parsed is None:
+                    kernels[name] = {"unparsed": text[:300]}
+                    continue
+                least = kda_cost.roofline_seconds(_kda.call_cost(parsed),
+                                                  peaks)
+                kernels[name] = {
+                    "calls": calls, "us_per_call": 1e6 * secs / calls,
+                    "roofline_us": 1e6 * least["seconds"],
+                    "bound": least["bound"],
+                    "roofline_share_pct":
+                        100.0 * least["seconds"] * calls / secs}
+            record["kernels"] = kernels
+            record["other_device_us_per_iter"] = 1e6 * sum(
+                s for _, s, _ in summary.ops(
+                    lambda t: not _kda.is_kda(t))) / args.iters
+        except Exception as e:  # noqa: BLE001 — one refused variant must
+            # not cost the call that times the others
+            record["error"] = repr(e)[:2000]
+        yield record
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--tokens", type=int, default=8192)
+    ap.add_argument("--heads", type=int, default=32)
+    ap.add_argument("--head-dim", type=int, default=128)
+    ap.add_argument("--chunk", default="64", help="chunks, a comma between "
+                    "them")
+    ap.add_argument("--state-dtype", default="f32", choices=("f32", "bf16"))
+    ap.add_argument("--decay-scale", type=float, default=1.0,
+                    help="multiplies every log decay (3: down to -4.8 a "
+                    "token)")
+    ap.add_argument("--iters", type=int, default=3)
+    ap.add_argument("--check", type=int, default=1,
+                    help="0 skips the float32 references")
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--out", default=None, help="also write the records "
+                    "as JSON lines to this file")
+    args = ap.parse_args(argv)
+
+    from benchmark.lib import device
+
+    devices = device.own_chips(1)
+    peaks = device.peaks_for(devices[0].device_kind,
+                             os.path.join(ROOT, "benchmark"))
+    trace_root = os.path.join(ROOT, ".bench_trace", "kda_bench")
+    for record in variants(args, peaks, trace_root):
+        record["device"] = devices[0].device_kind
+        line = json.dumps(record)
+        print(line, flush=True)
+        if args.out:
+            os.makedirs(os.path.dirname(os.path.abspath(args.out)),
+                        exist_ok=True)
+            with open(args.out, "a") as f:
+                f.write(line + "\n")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
