@@ -396,11 +396,12 @@ def phase_kimi(preset: str = "smoke", batch: int = 2, seq: int = 1024,
         kimi_linear, cfg, batch, seq, platform)
     kda = cfg.mixers.count(kimi_linear.KDA)
     latent = cfg.num_hidden_layers - kda
-    _check(gauges["kda_calls"] == 2 * kda
-           and gauges["mla_fwd_calls"] == gauges["attn_kept_calls"] == latent,
+    _check(gauges["kda_calls"] == kda and gauges["mla_fwd_calls"] == latent
+           and gauges["attn_kept_calls"] == kda + latent,
            f"phase K: the delta rule's forward ran {gauges['kda_calls']} "
            f"times a micro batch over {kda} layers and the latent layer's "
-           f"{gauges['mla_fwd_calls']} over {latent}")
+           f"{gauges['mla_fwd_calls']} over {latent}; the walks kept "
+           f"{gauges['attn_kept_calls']} calls' forward")
     _check(gauges["kda_state_bytes"]
            == batch // 2 * cfg.kda_num_heads * cfg.kda_head_dim ** 2 * 4,
            f"phase K: a layer's state reads {gauges['kda_state_bytes']} "

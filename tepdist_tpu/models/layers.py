@@ -65,12 +65,13 @@ def part(name: str):
 
 
 traced.declare(
-    "attn_kept_calls", "calls a micro batch (a flash or block top-k "
-    "kernel's forward, a sparse layer's choice) that hand what their forward "
+    "attn_kept_calls", "calls a micro batch (a flash, block top-k, latent or "
+    "delta-rule call, a sparse layer's choice) that hand what their forward "
     "pass made to the backward pass, which does not run it again")
 traced.declare(
-    "attn_kept_bytes", "bytes of output, log-sum-exp and chosen sets those "
-    "calls keep from a micro batch's forward to its backward")
+    "attn_kept_bytes", "bytes of output, log-sum-exp, chosen sets, states "
+    "and chunk inverses those calls keep from a micro batch's forward to its "
+    "backward")
 traced.declare(
     "moe_rows_sum_calls", "calls a micro batch of the expert layers' "
     "row-copy kernel (ops/pallas/rows_sum.py): 2 a walked layer that holds "
@@ -173,11 +174,15 @@ def scan_blocks(body, x, blocks, kinds=None, remat: bool = True,
     accumulator from chunk to chunk where autodiff would sum one a chunk.
 
     That walk saves, beside a block's input, the ``(o, lse)`` of every flash
-    call in it (``ops/pallas/flash_attention.py:KeptForward``) and of every
-    block top-k attention call with its chosen sets
-    (``ops/pallas/block_topk_attention.py``), stacked a layer as the inputs
-    are, and the recomputation takes them back: the forward kernel (and the
-    choice) runs once a layer and micro batch, not twice, for the kept
+    and latent-attention call in it
+    (``ops/pallas/flash_attention.py:KeptForward``), of every block top-k
+    attention call with its chosen sets
+    (``ops/pallas/block_topk_attention.py``), and the ``(o, states, inv)`` of
+    every delta-rule call (``ops/pallas/kda_attention.py``: the state before
+    every chunk and the chunk's inverse, which its backward kernel reads),
+    stacked a layer as the inputs are, and the recomputation takes them
+    back: the forward kernel (and the choice) runs once a layer and micro
+    batch, not twice, for the kept
     arrays' bytes held from a micro batch's forward to its backward
     (the gauges ``attn_kept_calls`` / ``attn_kept_bytes``, summed over the
     walks of one loss; the walk also counts the calls a layer's expert part

@@ -43,19 +43,24 @@ blocks as the projection leaves them, read and written once a chunk.
 ``q, k, v, g`` are ``[batch, T, H * K]``, a projection's own layout, head
 ``h`` lane block ``h``.
 
-**The backward keeps the operands and the state before every chunk**
-(``[batch, chunks, H, V, K]`` float32, 134e6 bytes at 8,192 tokens of 32
-heads in chunks of 128): a forward pass that is differentiated writes them
-as a second result of its one sweep, and inside a walked block, which is
-made again in the backward pass, they live from that second run to the
-layer's own backward and no longer. ``tepdist_kda_bwd`` walks the chunks
-last to first with the state's gradient carried, makes the chunk's ``G``,
-scores, ``Tm``, ``W``, ``U``, ``v'`` again from the operands and that state,
-and writes ``dq, dk, dv, dg, dbeta``. (:func:`backward` without the states
-makes them again by the forward's sweep under the name
-``tepdist_kda_bwd_states``: 5.8 ms a call at the cell's shape, which is what
-keeping them saves.) ``d Tm`` needs no inverse of its own: with ``X = [W | U] = (I +
-A)^-1 B``, ``dB = (I + A)^-T dX`` and ``dA = -dB X^T``.
+**The forward is made once.** A forward pass that is differentiated writes,
+beside ``o``, the state before every chunk (``[batch, chunks, H, V, K]``
+float32) and the chunk's inverse ``(I + A)^-1`` (``[batch, chunks, H, C,
+C]`` float32; 134e6 bytes each at 8,192 tokens of 32 heads in chunks of 128)
+as a second and a third result of its one sweep, and the backward keeps them
+with the operands. ``tepdist_kda_bwd`` walks the chunks last to first with
+the state's gradient carried, makes the chunk's ``G``, scores, ``W``, ``U``,
+``v'`` again from the operands, that state and that inverse (the doubling
+was 3.6 of the kernel's 11.0 ms a call at the cell's shape: twelve products
+that each wait for the last), and writes ``dq, dk, dv, dg, dbeta``. ``d Tm`` needs no inverse of its own: with ``X = [W | U] =
+(I + A)^-1 B``, ``dB = (I + A)^-T dX`` and ``dA = -dB X^T``. (:func:`backward`
+without the pair makes both again by the forward's sweep under the name
+``tepdist_kda_bwd_states``.) Inside a block that
+``models/layers.py:scan_blocks`` walks the call hands ``(o, states, inv)`` to
+the walk (``flash_attention.hand_over``), so the recomputation of the block
+in the backward pass runs no forward kernel: 335.5e6 bytes a layer and micro
+batch at that shape, held from the micro batch's forward to the layer's
+backward.
 
 Precision (``_linear.py``): the state, ``G``, every decay factor and every
 accumulation are float32; a float32 operand goes to the matrix unit as two
@@ -95,6 +100,7 @@ from tepdist_tpu.ops.pallas._linear import (
     _dot,
     _padded,
 )
+from tepdist_tpu.ops.pallas.flash_attention import hand_over
 from tepdist_tpu.telemetry import traced
 
 CHUNK = 64                  # tokens a grid step
@@ -102,8 +108,7 @@ SUB = 16                    # query rows that share a reference row
 _CAP = 80.0                 # exp(80) fits float32
 
 traced.declare(
-    "kda_calls", "forward delta-rule kernel calls a micro batch (a "
-    "rematerialised layer's second run counted)")
+    "kda_calls", "forward delta-rule kernel calls a micro batch")
 
 
 def _prefix(x, reverse: bool = False):
@@ -198,8 +203,9 @@ def _column(b, h):
                    keepdims=True)
 
 
-def _chunk(q, k, v, g, beta, state_t, narrow):
-    """What the forward and the backward both make of a chunk: a dict."""
+def _chunk(q, k, v, g, beta, state_t, narrow, inv=None):
+    """What the forward and the backward both make of a chunk: a dict.
+    ``inv``: ``(I + A)^-1`` as the forward wrote it; None makes it."""
     C = q.shape[0]
     G = _prefix(g.astype(_F32))
     last = G[C - 1:C]
@@ -208,7 +214,8 @@ def _chunk(q, k, v, g, beta, state_t, narrow):
     i, j = _ij(C)
     P = jnp.where(j <= i, P, 0.0)
     kk = jnp.where(j < i, kk, 0.0)
-    inv = _inverse(kk * beta, narrow)
+    if inv is None:
+        inv = _inverse(kk * beta, narrow)
     k32 = k.astype(_F32)
     kg = k32 * gamma
     W = _dot(inv, kg * beta, _NN, narrow)
@@ -227,8 +234,8 @@ def _next_state(c, state_t, narrow, state_dtype):
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, *rest, want, narrow,
                 state_dtype):
     """First chunk to last, every head's state carried. ``want``: which of
-    the output ``"o"`` and the state before the chunk ``"states"`` are the
-    results, in that order."""
+    the output ``"o"``, the state before the chunk ``"states"`` and the
+    chunk's ``(I + A)^-1`` ``"inv"`` are the results, in that order."""
     outs, s_scr = dict(zip(want, rest)), rest[-1]
     h = pl.program_id(2)
 
@@ -241,6 +248,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, *rest, want, narrow,
                _column(b_ref[...], h), state_t, narrow)
     if "states" in outs:
         outs["states"][...] = state_t
+    if "inv" in outs:
+        outs["inv"][...] = c["inv"]
     if "o" in outs:
         out = _dot(c["qg"], state_t, _NT, narrow) \
             + _dot(c["P"], c["vp"], _NN, narrow)
@@ -248,10 +257,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, *rest, want, narrow,
     s_scr[h] = _next_state(c, state_t, narrow, state_dtype)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, s_ref, dq_ref,
-                dk_ref, dv_ref, dg_ref, db_ref, ds_scr, *, narrow,
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, s_ref, inv_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_scr, *, narrow,
                 state_dtype):
-    """Last chunk to first with the state's gradient carried."""
+    """Last chunk to first with the state's gradient carried; the state
+    before the chunk and its ``(I + A)^-1`` as the forward's sweep wrote
+    them."""
     h = pl.program_id(2)
 
     @pl.when(pl.program_id(1) == 0)
@@ -265,7 +276,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, s_ref, dq_ref,
     q, k, v, do = q_ref[...], k_ref[...], v_ref[...], do_ref[...]
     beta = _column(b_ref[...], h)
     state_t, d_next = s_ref[...], ds_scr[h]
-    c = _chunk(q, k, v, g_ref[...], beta, state_t, narrow)
+    c = _chunk(q, k, v, g_ref[...], beta, state_t, narrow, inv_ref[...])
     C = q.shape[0]
     i, j = _ij(C)
     decay = jnp.exp(c["last"])                              # Gamma_C [1, K]
@@ -311,9 +322,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, s_ref, dq_ref,
 def _call(kernel, name, operands, outs, *, chunk, reverse, matmuls,
           interpret, state_dtype=None):
     """One sweep over the chunks. ``operands``: ``(kind, array)`` each, the
-    kinds ``wide`` ``[B, T, H * K]``, ``beta`` ``[B, T, H]`` and ``states``
-    ``[B, chunks, H, V, K]`` (whole chunks: the caller pads); ``outs``:
-    ``(kind, dtype)`` of each result."""
+    kinds ``wide`` ``[B, T, H * K]``, ``beta`` ``[B, T, H]``, ``states``
+    ``[B, chunks, H, V, K]`` and ``inv`` ``[B, chunks, H, chunk, chunk]``
+    (whole chunks: the caller pads); ``outs``: ``(kind, dtype)`` of each
+    result."""
     B, T, HK = operands[0][1].shape
     H = next(x.shape[2] for kind, x in operands if kind == "beta")
     K = HK // H
@@ -324,9 +336,11 @@ def _call(kernel, name, operands, outs, *, chunk, reverse, matmuls,
         "beta": pl.BlockSpec((None, chunk, H), lambda b, c, h: (b, at(c), 0)),
         "states": pl.BlockSpec((None, None, None, K, K),
                                lambda b, c, h: (b, at(c), h, 0, 0)),
+        "inv": pl.BlockSpec((None, None, None, chunk, chunk),
+                            lambda b, c, h: (b, at(c), h, 0, 0)),
     }
     shapes = {"wide": (B, T, HK), "beta": (B, T, H),
-              "states": (B, nc, H, K, K)}
+              "states": (B, nc, H, K, K), "inv": (B, nc, H, chunk, chunk)}
     out_shape = [jax.ShapeDtypeStruct(shapes[kind], dtype)
                  for kind, dtype in outs]
     return pl.pallas_call(
@@ -360,41 +374,44 @@ def _operands(chunk, q, k, v, g, beta, *more):
 
 def forward(q, k, v, g, beta, *, chunk: int = CHUNK, interpret=None,
             out_dtype=None, state_dtype=None, states: bool = False):
-    """The forward kernel alone; with ``states`` also the state before
-    every chunk, ``[B, chunks, H, V, K]`` float32 (what the backward kernel
-    reads), as a second result of the same sweep. A check's ``out_dtype``
-    (the result in float32, not rounded to the operands' dtype) and
-    ``state_dtype`` (the carried state through a narrower dtype: the check's
-    control)."""
-    want = ("o", "states") if states else ("o",)
+    """The forward kernel alone; with ``states`` ``(o, states, inv)``: also
+    the state before every chunk, ``[B, chunks, H, V, K]``, and the chunk's
+    ``(I + A)^-1``, ``[B, chunks, H, chunk, chunk]``, both float32 (what the
+    backward kernel reads), as two more results of the same sweep. A check's
+    ``out_dtype`` (the result in float32, not rounded to the operands' dtype)
+    and ``state_dtype`` (the carried state through a narrower dtype: the
+    check's control)."""
+    want = ("o", "states", "inv") if states else ("o",)
     out = _call(functools.partial(_fwd_kernel, want=want),
                 "tepdist_kda_fwd", _operands(chunk, q, k, v, g, beta),
-                [("wide", out_dtype or q.dtype), ("states", _F32)][:len(want)],
+                [("wide", out_dtype or q.dtype), ("states", _F32),
+                 ("inv", _F32)][:len(want)],
                 chunk=chunk, reverse=False, matmuls=23,
                 interpret=_interpret(interpret), state_dtype=state_dtype)
     o = out[0][:, :q.shape[1]]
-    return (o, out[1]) if states else o
+    return (o, *out[1:]) if states else o
 
 
-def backward(q, k, v, g, beta, do, *, states=None, chunk: int = CHUNK,
+def backward(q, k, v, g, beta, do, *, kept=None, chunk: int = CHUNK,
              interpret=None, out_dtype=None, state_dtype=None):
-    """``(dq, dk, dv, dg, dbeta)``; ``dg`` and ``dbeta`` float32. ``states``:
-    the states before every chunk as :func:`forward` hands them over
-    (``states=True``); None makes them again from the operands, a sweep of
-    its own (``tepdist_kda_bwd_states``)."""
+    """``(dq, dk, dv, dg, dbeta)``; ``dg`` and ``dbeta`` float32. ``kept``:
+    ``(states, inv)`` as :func:`forward` hands them over (``states=True``);
+    None makes both again from the operands, a sweep of its own
+    (``tepdist_kda_bwd_states``)."""
     interpret = _interpret(interpret)
     operands = _operands(chunk, q, k, v, g, beta, do)
-    if states is None:
-        states, = _call(functools.partial(_fwd_kernel, want=("states",)),
-                        "tepdist_kda_bwd_states", operands[:5],
-                        [("states", _F32)], chunk=chunk, reverse=False,
-                        matmuls=21, interpret=interpret,
-                        state_dtype=state_dtype)
+    if kept is None:
+        kept = _call(functools.partial(_fwd_kernel, want=("states", "inv")),
+                     "tepdist_kda_bwd_states", operands[:5],
+                     [("states", _F32), ("inv", _F32)], chunk=chunk,
+                     reverse=False, matmuls=21, interpret=interpret,
+                     state_dtype=state_dtype)
+    states, inv = kept
     dtype = out_dtype or q.dtype
     out = _call(_bwd_kernel, "tepdist_kda_bwd",
-                operands + [("states", states)],
+                operands + [("states", states), ("inv", inv)],
                 [("wide", dtype)] * 3 + [("wide", _F32), ("beta", _F32)],
-                chunk=chunk, reverse=True, matmuls=45, interpret=interpret,
+                chunk=chunk, reverse=True, matmuls=33, interpret=interpret,
                 state_dtype=state_dtype)
     return tuple(x[:, :q.shape[1]] for x in out)
 
@@ -407,19 +424,38 @@ def _attend(q, k, v, g, beta, chunk, interpret, layers):
 
 def _attend_fwd(q, k, v, g, beta, chunk, interpret, layers):
     traced.count("kda_calls", layers=layers)
-    o, states = forward(q, k, v, g, beta, chunk=chunk, interpret=interpret,
-                        states=True)
-    return o, (q, k, v, g, beta, states)
+    o, *kept = forward(q, k, v, g, beta, chunk=chunk, interpret=interpret,
+                       states=True)
+    return o, (q, k, v, g, beta, *kept)
 
 
 def _attend_bwd(chunk, interpret, layers, res, do):
-    q, k, v, g, beta, states = res
-    dq, dk, dv, dg, dbeta = backward(q, k, v, g, beta, do, states=states,
+    q, k, v, g, beta, *kept = res
+    dq, dk, dv, dg, dbeta = backward(q, k, v, g, beta, do, kept=kept,
                                      chunk=chunk, interpret=interpret)
     return dq, dk, dv, dg.astype(g.dtype), dbeta.astype(beta.dtype)
 
 
 _attend.defvjp(_attend_fwd, _attend_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _attend_from(q, k, v, g, beta, o, states, inv, chunk, interpret):
+    """``_attend`` where the forward kernel's three results are already in
+    hand: the primal is ``o`` as given (no kernel), the backward is
+    ``_attend``'s on the residuals ``_attend_fwd`` would have saved."""
+    return o
+
+
+def _attend_from_fwd(q, k, v, g, beta, o, states, inv, chunk, interpret):
+    return o, (q, k, v, g, beta, states, inv)
+
+
+def _attend_from_bwd(chunk, interpret, res, do):
+    return _attend_bwd(chunk, interpret, None, res, do) + (None, None, None)
+
+
+_attend_from.defvjp(_attend_from_fwd, _attend_from_bwd)
 
 
 def kda_attention(q, k, v, g, beta, *, chunk: int = CHUNK,
@@ -431,8 +467,11 @@ def kda_attention(q, k, v, g, beta, *, chunk: int = CHUNK,
     caller's ``q`` and ``k`` carry them. The state starts at zero for every
     row of the batch.
 
-    Counts, while it is traced, each forward kernel call in ``kda_calls``
-    (``telemetry/traced.py``)."""
+    Inside a block that ``models/layers.py:scan_blocks`` walks the call
+    hands its forward pass, ``(o, states, inv)``, to the walk
+    (``flash_attention.KeptForward``): the values and the backward kernel
+    are the same. Counts, while it is traced, each forward kernel call in
+    ``kda_calls`` (``telemetry/traced.py``)."""
     if not (q.shape == k.shape == v.shape == g.shape) or q.ndim != 3 \
             or beta.shape[:2] != q.shape[:2] or beta.ndim != 3 \
             or q.shape[2] % beta.shape[2] or chunk % SUB:
@@ -440,8 +479,24 @@ def kda_attention(q, k, v, g, beta, *, chunk: int = CHUNK,
             f"kda_attention: q {q.shape}, k {k.shape}, v {v.shape}, g "
             f"{g.shape}, beta {beta.shape}, chunk {chunk}")
     chunk = min(chunk, -(-q.shape[1] // SUB) * SUB)
-    return _attend(q, k, v, g, beta, chunk, _interpret(interpret),
-                   traced.stood_for())
+    interpret = _interpret(interpret)
+
+    def attend(saved):
+        """The call in the part a ``KeptForward`` asks of it
+        (``flash_attention.hand_over``): None the whole of it with its
+        custom VJP, ``()`` the forward kernel alone (not differentiable),
+        ``(o, states, inv)`` as that gave them the call from its saved
+        forward."""
+        if saved:
+            return _attend_from(q, k, v, g, beta, *saved, chunk, interpret)
+        if saved is None:
+            return _attend(q, k, v, g, beta, chunk, interpret,
+                           traced.stood_for())
+        traced.count("kda_calls")
+        return forward(q, k, v, g, beta, chunk=chunk, interpret=interpret,
+                       states=True)
+
+    return hand_over(attend)
 
 
 def chunked(q, k, v, g, beta, *, chunk: int = CHUNK):

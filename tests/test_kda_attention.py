@@ -4,15 +4,18 @@ token-by-token recurrence of ``benchmark/reference/kimi_linear.py``: values
 and all five gradients, the custom VJP, a padded last chunk and a sequence
 shorter than a chunk, decays at both ends of the initialisation's range and
 at ``g`` = -5 a token, the rule's two limits, rows of a batch that do not
-meet, the gauge and the shapes refused."""
+meet, the gauge and the shapes refused; what a differentiated forward hands
+on (the states and each chunk's inverse) and the three cases of a hand-over
+to a walk (``ops/pallas/flash_attention.py:KeptForward``)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from kernel_checks import rel_l2
+from kernel_checks import kernel_counts, rel_l2
 
 from benchmark.reference import kimi_linear as ref
+from tepdist_tpu.ops.pallas import flash_attention as fa
 from tepdist_tpu.ops.pallas import kda_attention as kda
 from tepdist_tpu.telemetry import metrics, traced
 from tools.kda_bench import make_inputs
@@ -139,6 +142,82 @@ def test_the_custom_vjp_is_the_kernels_backward_and_counts_its_calls():
     for a, w in zip(got, kernels(16)(*x)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(w))
     assert got[4].dtype == jnp.float32 and got[5].dtype == jnp.float32
+
+
+def _equal(got, want):
+    for a, w in zip(got, want, strict=True):
+        assert a.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(w))
+
+
+# 40 positions in chunks of 16: the last chunk is padded.
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_the_backward_from_a_handed_pair_is_the_backward_that_makes_it(dtype):
+    x = inputs(2, 40, 2, 16, seed=6, dtype=dtype)
+    o, states, inv = kda.forward(*x[:5], chunk=16, states=True)
+    assert o.dtype == dtype and o.shape == x[0].shape
+    assert states.shape == (2, 3, 2, 16, 16) and inv.shape == states.shape
+    assert states.dtype == inv.dtype == jnp.float32
+    _equal([o], [kda.forward(*x[:5], chunk=16)])
+    _equal(kda.backward(*x, kept=(states, inv), chunk=16),
+           kda.backward(*x, chunk=16))
+
+
+def test_the_handed_inverse_is_the_inverse_of_the_chunks_system():
+    """``A`` by its definition, a chunk and head at a time in float64: the
+    third result is ``_inverse`` of it and inverts ``I + A``; a padded row
+    (``beta`` = 0) is the identity's."""
+    T, C, H, K = 40, 16, 2, 16
+    q, k, v, g, beta, _ = inputs(1, T, H, K, seed=8)
+    inv = np.asarray(kda.forward(q, k, v, g, beta, chunk=C, states=True)[2])
+
+    def chunks(x):           # [1, T, H * n] -> [chunks, H, C, n] float64
+        x = np.pad(np.asarray(x[0], np.float64), ((0, 3 * C - T), (0, 0)))
+        return x.reshape(3, C, H, -1).transpose(0, 2, 1, 3)
+
+    ks, G, b = chunks(k), np.cumsum(chunks(g), axis=2), chunks(beta)
+    decay = np.exp(G[..., :, None, :] - G[..., None, :, :])  # [.., i, j, K]
+    A = np.tril(np.einsum("nhic,nhjc,nhijc->nhij", ks, ks, decay) * b, -1)
+    eye = np.eye(C)
+    np.testing.assert_allclose(inv[0] @ (eye + A), np.broadcast_to(
+        eye, A.shape), atol=2e-6)
+    doubled = jax.vmap(jax.vmap(lambda a: kda._inverse(a, False)))(
+        jnp.asarray(A, jnp.float32))
+    np.testing.assert_allclose(inv[0], np.asarray(doubled), atol=2e-6)
+    np.testing.assert_array_equal(inv[0, 2, :, T - 2 * C:],
+                                  np.broadcast_to(eye[T - 2 * C:], (H, 8, C)))
+
+
+def test_the_three_cases_of_a_hand_over_are_one_call():
+    """Outside any walk, recording and replaying (``flash_attention.
+    hand_over``): the same ``o`` and gradients bit for bit; the recording
+    runs the forward kernel alone and keeps ``(o, states, inv)``, the replay
+    runs the backward kernel and no other."""
+    x = inputs(1, 40, 2, 16, seed=4)
+
+    def attend(*a):
+        return kda.kda_attention(*a, chunk=16)
+
+    want = out_and_gradients(attend, x)
+    traced.reset()
+    with fa.KeptForward() as keep:
+        recorded = attend(*x[:5])
+    assert metrics().gauge("kda_calls").value == 1
+    (kept,) = keep.kept
+    assert [a.shape for a in kept] == [
+        (1, 40, 32), (1, 3, 2, 16, 16), (1, 3, 2, 16, 16)]
+
+    def replayed(*a):
+        with fa.KeptForward(keep.kept):
+            return attend(*a)
+
+    _equal((recorded,) + out_and_gradients(replayed, x)[1:], want)
+    assert metrics().gauge("kda_calls").value == 1
+    assert kernel_counts(lambda *a: out_and_gradients(replayed, a),
+                         *x) == {"tepdist_kda_bwd": 1}
+    assert kernel_counts(lambda *a: out_and_gradients(attend, a), *x) == {
+        "tepdist_kda_fwd": 1, "tepdist_kda_bwd": 1}
 
 
 def test_bf16_operands_keep_a_float32_state():

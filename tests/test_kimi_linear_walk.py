@@ -129,17 +129,23 @@ def test_the_stacked_walk_is_the_layer_loop(micro, monkeypatch):
 
 def test_the_gauges_of_a_traced_step():
     """Two micro batches, five layers in four walks: the delta-rule forward
-    runs twice a KDA layer and micro batch (a walked block makes its mixer
-    again: 8), the latent layer's forward once (kept), the convs three
-    times a run of the mixer."""
+    runs once a KDA layer and micro batch and the latent layer's once (the
+    walks keep both: ``(o, states, inv)`` and ``(o, lse)``), the convs
+    three times a run of the mixer, and a walked block makes its mixer's
+    other parts again."""
     cfg = dataclasses.replace(CFG, remat=True, loss_chunk=16)
     params = init_params(cfg, stacked=True)
     tokens = kimi.fake_batch(cfg, 4, 32, seed=8)
     tx, step = _ga_step(cfg, 2)
     found = kernel_counts(step, params, tx.init(params), tokens)
     gauge = lambda n: metrics().gauge(n).value              # noqa: E731
-    assert gauge("kda_calls") == 8
-    assert gauge("mla_fwd_calls") == 1 and gauge("attn_kept_calls") == 1
+    assert gauge("kda_calls") == 4
+    assert gauge("mla_fwd_calls") == 1 and gauge("attn_kept_calls") == 1 + 4
+    # A micro batch of 2 x 32 tokens in float32: a KDA layer's o [B, T, 4 x
+    # 32], its states [B, 2 chunks, 4, 32, 32] and inverses [B, 2, 4, 16,
+    # 16]; the latent layer's o [B, 2, T, 12] and lse [B, 2, T].
+    assert gauge("attn_kept_bytes") == 4 * 2 * 4 * (
+        32 * 4 * 32 + 2 * 4 * (32 * 32 + 16 * 16)) + 2 * 2 * 32 * 4 * (12 + 1)
     assert gauge("mla_bwd_calls") == 1
     assert gauge("ssm_conv_calls") == 4 * 3 * 2
     assert gauge("kda_state_bytes") == 2 * 4 * 32 * 32 * 4
@@ -149,6 +155,7 @@ def test_the_gauges_of_a_traced_step():
     assert gauge("moe_rows_sum_calls") == 2 * 4
     names = "".join(found)
     assert "tepdist_kda_bwd_states" not in names    # the states are kept
+    assert found["tepdist_kda_fwd"] == found["tepdist_kda_bwd"] == 3
     for kernel in ("tepdist_kda_fwd", "tepdist_kda_bwd",
                    "tepdist_conv_fwd", "tepdist_conv_bwd",
                    "tepdist_mla_fwd", "tepdist_mla_dkv", "tepdist_gmm_"):
@@ -156,6 +163,28 @@ def test_the_gauges_of_a_traced_step():
     stacks = sum(a.nbytes for r in range(4)
                  for a in jax.tree_util.tree_leaves(params[f"run{r}"]))
     assert gauge("ga_fused_bytes") == stacks
+
+
+def test_a_kda_block_rematerialised_whole_keeps_nothing(monkeypatch):
+    """``tests/test_attn_kept.py``'s declining case for the delta rule: a
+    KDA block under ``rematerialised_whole`` (a recipe that pins the
+    rematerialisation of all of it) hands nothing to its walk, so the
+    forward kernel is in each of the three walks' recomputation again and
+    the latent layer's pair is all that is kept."""
+    from tepdist_tpu.models.layers import rematerialised_whole
+    monkeypatch.setattr(kimi, "kda_block",
+                        rematerialised_whole(kimi.kda_block))
+    cfg = dataclasses.replace(CFG, remat=True, loss_chunk=16)
+    params = init_params(cfg, stacked=True)
+    tx, step = _ga_step(cfg, 2)
+    found = kernel_counts(step, params, tx.init(params),
+                          kimi.fake_batch(cfg, 4, 32, seed=8))
+    gauge = lambda n: metrics().gauge(n).value              # noqa: E731
+    assert gauge("kda_calls") == 8
+    assert gauge("attn_kept_calls") == 1
+    assert gauge("attn_kept_bytes") == 2 * 2 * 32 * 4 * (12 + 1)
+    assert found["tepdist_kda_fwd"] == 6 and found["tepdist_kda_bwd"] == 3
+    assert "tepdist_kda_bwd_states" not in found
 
 
 def test_the_mixers_parts_carry_their_scopes():

@@ -21,10 +21,10 @@ def test_kda_kernels_compile_for_v5e(v5e_devices):
     """Forward and backward of the delta-rule kernels at the Kimi Linear
     cell's ``[1, 8192, 32 x 128]``, chunks of 64 and of 128, not
     interpreted: two kernels under their names (the differentiated forward
-    writes the states before every chunk as its second result, so no sweep
-    makes them again), the operands read in the projections' own ``[T, heads
-    * K]`` layout and ``beta`` as ``[T, heads]`` (no copy around a call), and
-    nothing held but those states."""
+    writes the states before every chunk and the chunks' inverses as its
+    second and third result, so no sweep makes them again), the operands
+    read in the projections' own ``[T, heads * K]`` layout and ``beta`` as
+    ``[T, heads]`` (no copy around a call), and nothing held but that pair."""
     from tepdist_tpu.ops.pallas.kda_attention import kda_attention
     one_chip = SingleDeviceSharding(v5e_devices[0])
     T, H, K = 8192, 32, 128
@@ -49,8 +49,10 @@ def test_kda_kernels_compile_for_v5e(v5e_devices):
             assert sum(kernel in n for n in names) == 1, names
         assert not [n for n in names if "tepdist_kda_bwd_states" in n]
         assert f"f32[1,{T // chunk},{H},{K},{K}]" in text
-        states = T // chunk * H * K * K * 4
-        assert compiled.memory_analysis().temp_size_in_bytes < states + 2 ** 20
+        assert f"f32[1,{T // chunk},{H},{chunk},{chunk}]" in text
+        # (An inverse of 64 columns is tiled to the 128 lanes.)
+        pair = T // chunk * H * (K * K + chunk * max(chunk, 128)) * 4
+        assert compiled.memory_analysis().temp_size_in_bytes < pair + 2 ** 20
         wide = [line for line in text.splitlines()
                 if f"{T},{H * K}]" in line.split(" = ", 1)[-1][:60]
                 and (" copy(" in line or " transpose(" in line)]
@@ -63,10 +65,10 @@ def test_the_kimi_linear_cells_step_compiles_for_v5e(v5e_devices,
     (8 micro batches of one 8,192-token sequence; five layers in four walks
     of unequal shape; ``adamw_bf16_router_bias``), kernels not interpreted:
     every walk's leaves accumulate inside its backward layer loop, the
-    delta rule's forward runs twice a KDA layer and micro batch (a walked
-    block makes its mixer again: ``kda_calls`` 8) and the latent layer's
-    once (kept), the experts' stacks are read where they lie in all three
-    expert runs, and the compiler's peak is under 13e9 bytes."""
+    delta rule's forward and the latent layer's run once a layer and micro
+    batch (the walks keep ``(o, states, inv)`` and ``(o, lse)``:
+    ``kda_calls`` 4), the experts' stacks are read where they lie in all
+    three expert runs, and the compiler's peak is under 13e9 bytes."""
     import json
 
     from benchmark.lib import cells
@@ -106,8 +108,12 @@ def test_the_kimi_linear_cells_step_compiles_for_v5e(v5e_devices,
                  for a in jax.tree_util.tree_leaves(params[f"run{r}"]))
     assert gauge("ga_fused_bytes") == stacks
     assert gauge("ga_unfused_bytes") == 2 * 20480 * 2304 * 2 + 2304 * 4
-    assert gauge("kda_calls") == 8              # a walked block's, twice
-    assert gauge("mla_fwd_calls") == 1 and gauge("attn_kept_calls") == 1
+    assert gauge("kda_calls") == 4              # kept: once a layer
+    assert gauge("mla_fwd_calls") == 1 and gauge("attn_kept_calls") == 1 + 4
+    # A KDA layer's o in bf16, its 64 chunks' states and inverses [32, 128,
+    # 128] float32 each; the latent layer's o in bf16 and float32 lse.
+    assert gauge("attn_kept_bytes") == 4 * (
+        T * 4096 * 2 + 2 * 64 * 32 * 128 * 128 * 4) + 32 * T * (128 * 2 + 4)
     assert gauge("mla_bwd_calls") == 1 and gauge("mla_heads_held") == 32
     assert gauge("ssm_conv_calls") == 24        # three a mixer's run
     assert gauge("kda_state_bytes") == 32 * 128 * 128 * 4
@@ -126,8 +132,8 @@ def test_the_kimi_linear_cells_step_compiles_for_v5e(v5e_devices,
                          r"(?!parameter)", line)]
     assert not made, made[:3]
     # Three walks hold KDA layers: the forward in each one's forward loop
-    # and again, with the states, in its backward loop's recomputation.
-    assert len([c for c in calls if "tepdist_kda_fwd" in c]) == 6, calls
+    # and nowhere in its backward loop's recomputation.
+    assert len([c for c in calls if "tepdist_kda_fwd" in c]) == 3, calls
     assert len([c for c in calls if "tepdist_kda_bwd" in c]) == 3, calls
     assert not [c for c in calls if "tepdist_kda_bwd_states" in c], calls
     for which in ("fwd", "dkv"):

@@ -3,8 +3,9 @@
 ``ops/pallas/kda_attention.py`` at the Kimi Linear cell's ``[1, 8192, 32 x
 128]`` in bf16 with float32 log decays and ``beta``: device microseconds a
 call of the forward kernel (under differentiation it also writes the states
-before every chunk) and of the backward kernel, for each chunk asked for,
-from one ``jax.profiler`` trace a variant reduced by ``benchmark/trace_reduce.py``,
+before every chunk and the chunks' inverses, which the backward kernel
+reads) and of the backward kernel, for each chunk asked for, from one
+``jax.profiler`` trace a variant reduced by ``benchmark/trace_reduce.py``,
 each beside its roofline time (``benchmark/kernels/kda_cost.py``); the
 relative L2 distance of the output and the five gradients (asked for in
 float32) from the token-by-token float32 recurrence of
@@ -12,6 +13,11 @@ float32) from the token-by-token float32 recurrence of
 (``kda_attention.chunked``, which is also timed, forward and backward, as
 what the kernels replace); ``--state-dtype bf16`` reads the same with the
 carried state rounded to bf16 (a control: what a narrower carry costs).
+``--impl`` times another copy of ``kda_attention.py`` in the same process,
+beside the checkout's own (a parent's, whose forward writes the states alone
+and whose backward makes the inverse again: the two halves of PR 53 apart),
+and says whether its output and five gradients are the checkout's bit for
+bit.
 
 Operands as a KDA layer hands them over: ``q`` and ``k`` unit L2 norm a
 head, ``q`` over ``sqrt(K)``, ``v`` a unit-variance projection, ``g = -exp(A)
@@ -25,6 +31,7 @@ no CPU fallback: without a TPU it exits 2.
 
 Run: chiprun -- python tools/kda_bench.py [--tokens 8192] [--chunk 64,128]
      [--state-dtype f32] [--decay-scale 1] [--check 1]
+     [--impl parent=path/to/kda_attention.py]
 """
 
 from __future__ import annotations
@@ -80,21 +87,20 @@ def _out_and_gradients(fn, inputs):
                                        for x in inputs)))
 
 
-def variants(args, peaks, trace_root):
+def variants(args, peaks, trace_root, impls):
+    """``impls``: ``(label, module)`` of each copy of ``kda_attention.py`` to
+    time, the checkout's own first."""
     import jax
     import jax.numpy as jnp
 
-    from benchmark import trace_reduce
-    from benchmark.kernels import kda_cost
     from benchmark.kernels.ssm_check import rel_l2
-    from benchmark.layer_metrics import _kda
     from benchmark.reference import kimi_linear as ref
-    from tepdist_tpu.ops.pallas import kda_attention as kda
     from tools.sala_bench import _traced
 
     H, K, T = args.heads, args.head_dim, args.tokens
     inputs = make_inputs(T, H, K, jnp.bfloat16, args.seed, args.decay_scale)
     want = chunked = None
+    kda = impls[0][1]
     if args.check:
         def heads(x):
             return x[0].reshape(T, H, -1)
@@ -125,54 +131,88 @@ def variants(args, peaks, trace_root):
         except Exception as e:  # noqa: BLE001 — the kernels are still timed
             yield {"what": "chunked jax.numpy form, forward and backward",
                    "error": repr(e)[:2000]}
-    state = {"f32": None, "bf16": jnp.bfloat16}[args.state_dtype]
     for chunk in (int(c) for c in args.chunk.split(",")):
-        record = {"what": "kda", "chunk": chunk, "tokens": T, "heads": H,
-                  "state_dtype": args.state_dtype, "iters": args.iters,
-                  "decay_scale": args.decay_scale}
-        try:
-            if want is not None:
-                how = dict(chunk=chunk,
-                           out_dtype=jnp.float32, state_dtype=state)
-                got = jax.block_until_ready(jax.jit(
-                    lambda *x: (kda.forward(*x[:5], **how),)
-                    + kda.backward(*x, **how))(*inputs))
-                record["rel_l2_vs_recurrence_f32"] = {
-                    n: rel_l2(a, w) for n, a, w in zip(NAMES, got, want)}
-                record["rel_l2_vs_chunked_f32"] = {
-                    n: rel_l2(a, w) for n, a, w in zip(NAMES, got, chunked)}
+        first = None
+        for label, module in impls:
+            record, got = _time_impl(label, module, chunk, inputs, want,
+                                     chunked, first, args, peaks, trace_root)
+            first = got if first is None else first
+            yield record
 
-            @jax.jit
-            def grad(q, k, v, g, beta, do, chunk=chunk):
-                out, vjp = jax.vjp(lambda *a: kda.kda_attention(
-                    *a, chunk=chunk), q, k, v, g, beta)
-                return (out,) + vjp(do)
 
-            summary = _traced(f"kda-{chunk}", lambda: grad(*inputs),
-                              args.iters, trace_root)
-            kernels = {}
-            for text, secs, calls in summary.ops(_kda.is_kda):
-                parsed = _kda.parse(text)
-                name = trace_reduce.short_name(text)
-                if parsed is None:
-                    kernels[name] = {"unparsed": text[:300]}
-                    continue
-                least = kda_cost.roofline_seconds(_kda.call_cost(parsed),
-                                                  peaks)
-                kernels[name] = {
-                    "calls": calls, "us_per_call": 1e6 * secs / calls,
-                    "roofline_us": 1e6 * least["seconds"],
-                    "bound": least["bound"],
-                    "roofline_share_pct":
-                        100.0 * least["seconds"] * calls / secs}
-            record["kernels"] = kernels
-            record["other_device_us_per_iter"] = 1e6 * sum(
-                s for _, s, _ in summary.ops(
-                    lambda t: not _kda.is_kda(t))) / args.iters
-        except Exception as e:  # noqa: BLE001 — one refused variant must
-            # not cost the call that times the others
-            record["error"] = repr(e)[:2000]
-        yield record
+def _time_impl(label, kda, chunk, inputs, want, chunked, first, args, peaks,
+               trace_root):
+    """One copy's kernels at one chunk: ``(record, the output and five
+    gradients of its differentiated call)``. ``want``, ``chunked``: the two
+    float32 references' results, or None; ``first``: the results of the
+    first copy timed at this chunk, or None."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import trace_reduce
+    from benchmark.kernels import kda_cost
+    from benchmark.kernels.ssm_check import rel_l2
+    from benchmark.layer_metrics import _kda
+    from benchmark.layer_metrics._sala import _operands
+    from tools.sala_bench import _traced
+
+    record = {"what": "kda", "impl": label, "chunk": chunk,
+              "tokens": args.tokens, "heads": args.heads,
+              "state_dtype": args.state_dtype, "iters": args.iters,
+              "decay_scale": args.decay_scale}
+    got = None
+    try:
+        if want is not None:
+            how = dict(chunk=chunk, out_dtype=jnp.float32, state_dtype={
+                "f32": None, "bf16": jnp.bfloat16}[args.state_dtype])
+            alone = jax.block_until_ready(jax.jit(
+                lambda *x: (kda.forward(*x[:5], **how),)
+                + kda.backward(*x, **how))(*inputs))
+            record["rel_l2_vs_recurrence_f32"] = {
+                n: rel_l2(a, w) for n, a, w in zip(NAMES, alone, want)}
+            record["rel_l2_vs_chunked_f32"] = {
+                n: rel_l2(a, w) for n, a, w in zip(NAMES, alone, chunked)}
+
+        @jax.jit
+        def grad(q, k, v, g, beta, do):
+            out, vjp = jax.vjp(lambda *a: kda.kda_attention(
+                *a, chunk=chunk), q, k, v, g, beta)
+            return (out,) + vjp(do)
+
+        got = jax.block_until_ready(grad(*inputs))
+        if first is not None:
+            record["same_bits_as_first"] = {
+                n: bool(jnp.array_equal(a, f))
+                for n, a, f in zip(NAMES, got, first)}
+        summary = _traced(f"kda-{label}-{chunk}", lambda: grad(*inputs),
+                          args.iters, trace_root)
+        kernels = {}
+        for text, secs, calls in summary.ops(_kda.is_kda):
+            parsed = _kda.parse(text)
+            name = trace_reduce.short_name(text)
+            if parsed is None:
+                kernels[name] = {"unparsed": text[:300]}
+                continue
+            least = kda_cost.roofline_seconds(_kda.call_cost(parsed), peaks)
+            # Operands and results say which kernel of the two halves this
+            # is: a forward of 2 results writes the states alone, of 3 the
+            # inverses too; a backward of 8 operands reads them.
+            kernels[name] = {
+                "calls": calls, "us_per_call": 1e6 * secs / calls,
+                "results": text.partition(" custom-call(")[0].count("["),
+                "operands": len(_operands(text)),
+                "roofline_us": 1e6 * least["seconds"],
+                "bound": least["bound"],
+                "roofline_share_pct":
+                    100.0 * least["seconds"] * calls / secs}
+        record["kernels"] = kernels
+        record["other_device_us_per_iter"] = 1e6 * sum(
+            s for _, s, _ in summary.ops(
+                lambda t: not _kda.is_kda(t))) / args.iters
+    except Exception as e:  # noqa: BLE001 — one refused variant must not
+        # cost the call that times the others
+        record["error"] = repr(e)[:2000]
+    return record, got
 
 
 def main(argv=None) -> int:
@@ -190,17 +230,27 @@ def main(argv=None) -> int:
     ap.add_argument("--check", type=int, default=1,
                     help="0 skips the float32 references")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--impl", action="append", default=[],
+                    metavar="LABEL=FILE",
+                    help="another kda_attention.py to time beside the "
+                         "checkout's own and compare with it bit for bit "
+                         "(repeatable)")
     ap.add_argument("--out", default=None, help="also write the records "
                     "as JSON lines to this file")
     args = ap.parse_args(argv)
 
     from benchmark.lib import device
+    from tools.flash_bench import load_impl
 
     devices = device.own_chips(1)
     peaks = device.peaks_for(devices[0].device_kind,
                              os.path.join(ROOT, "benchmark"))
     trace_root = os.path.join(ROOT, ".bench_trace", "kda_bench")
-    for record in variants(args, peaks, trace_root):
+    impls = [("tree", os.path.join(ROOT, "tepdist_tpu", "ops", "pallas",
+                                   "kda_attention.py"))]
+    impls += [tuple(item.partition("=")[::2]) for item in args.impl]
+    impls = [(label, load_impl(label, path)) for label, path in impls]
+    for record in variants(args, peaks, trace_root, impls):
         record["device"] = devices[0].device_kind
         line = json.dumps(record)
         print(line, flush=True)
