@@ -35,6 +35,15 @@ through the entry points a user calls:
            kernels, the conv pair, the latent kernels and the grouped
            matmuls are in the compiled step and that the delta rule's
            forward ran twice a KDA layer and the latent layer's once.
+  phase Q  the same for ``models/qwen3_next.py`` at its ``smoke`` preset
+           (three scalar-decay delta-rule layers of one key head under two
+           value heads of the published 128 to one gated attention layer of
+           2 heads of the published 256 with 64 channels rotated, 16 of 32
+           softmax-routed experts, 10 a token, a gated shared one), 2 micro
+           batches of one 1024-token sequence, 5 steps; asserts the two
+           scalar-decay kernels, the conv pair, the flash kernels and the
+           grouped matmuls are in the compiled step and that the delta
+           rule's forward ran once a Gated-DeltaNet layer.
   --chips 4  one child owning all four chips: ``plan_training(explore=True)``
            over ``jax.devices()`` at batch 16, 5 steps, then the same 5 steps
            on ``devices[:1]``; every device must hold a shard and the
@@ -415,6 +424,38 @@ def phase_kimi(preset: str = "smoke", batch: int = 2, seq: int = 1024,
 
 
 # ---------------------------------------------------------------------------
+# Phase Q: the scalar-decay delta-rule kernels over shared key heads beside a
+# gated attention layer at a head width of 256, two walks of unequal shape.
+# ---------------------------------------------------------------------------
+
+def phase_qwen(preset: str = "smoke", batch: int = 2, seq: int = 1024,
+               platform: str = "tpu") -> dict:
+    from tepdist_tpu.models import qwen3_next
+
+    cfg = qwen3_next.CONFIGS[preset]
+    devices, tplan, tokens, gauges = _plan_zoo_model(
+        qwen3_next, cfg, batch, seq, platform)
+    gdn = cfg.kinds.count(qwen3_next.GDN)
+    _check(gauges["gdn_calls"] == gdn and gauges["kda_calls"] == 0
+           and gauges["attn_kept_calls"] == cfg.num_hidden_layers,
+           f"phase Q: the delta rule's forward ran {gauges['gdn_calls']} "
+           f"times a micro batch over {gdn} layers; the walks kept "
+           f"{gauges['attn_kept_calls']} calls' forward of "
+           f"{cfg.num_hidden_layers} layers")
+    _check(gauges["gdn_state_bytes"] == batch // 2
+           * cfg.linear_num_value_heads * cfg.linear_key_head_dim ** 2 * 4
+           and gauges["attn_rotary_dim"] == cfg.rotary_dim,
+           f"phase Q: a layer's state reads {gauges['gdn_state_bytes']} "
+           f"bytes and {gauges['attn_rotary_dim']} channels are rotated")
+    return _step_zoo_model(
+        "Q", f"qwen3_next-{preset}", devices, tplan, tokens, gauges,
+        platform,
+        ("tepdist_gdn_fwd", "tepdist_gdn_bwd", "tepdist_conv_fwd",
+         "tepdist_conv_bwd", "tepdist_flash_fwd", "tepdist_flash_dkv",
+         "tepdist_gmm_fwd"))
+
+
+# ---------------------------------------------------------------------------
 # Four chips: explored layout over the host's devices vs the same steps on
 # one of them, in one process that owns all four.
 # ---------------------------------------------------------------------------
@@ -476,7 +517,7 @@ def phase_four(cfg_name: str = "117M", batch: int = 16, seq: int = 1024,
 
 CHILD_PHASES = {"phase_b": phase_b, "phase_four": phase_four,
                 "phase_mla": phase_mla, "phase_zaya": phase_zaya,
-                "phase_kimi": phase_kimi}
+                "phase_kimi": phase_kimi, "phase_qwen": phase_qwen}
 
 
 def _run_child(phase: str) -> dict:
@@ -525,6 +566,7 @@ def main() -> None:
         _emit(_run_child("phase_mla"))
         _emit(_run_child("phase_zaya"))
         _emit(_run_child("phase_kimi"))
+        _emit(_run_child("phase_qwen"))
     _check(holder["platform"] == "tpu" and holder["n_devices"] == args.chips,
            f"ran on {holder['n_devices']} {holder['platform']} device(s), "
            f"wanted {args.chips} tpu")

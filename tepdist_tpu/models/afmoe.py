@@ -69,6 +69,7 @@ from tepdist_tpu.models.decoder import (
     walk_layers,
 )
 from tepdist_tpu.models.layers import (
+    attn_gate,
     cross_entropy,
     gqa_heads,
     part,
@@ -205,10 +206,7 @@ def attention(blk, a, cfg: AfmoeConfig, window):
         eps=cfg.rms_norm_eps, window=cfg.sliding_window, windowed=window,
         rope_window=cfg.rope_theta, rope_global=None,
         block_q=cfg.flash_block_q, block_k=cfg.flash_block_k)
-    with jax.named_scope("attn_gate"):
-        gate = jax.nn.sigmoid((a @ blk["wa"]).astype(jnp.float32))
-        o = (o.astype(jnp.float32) * gate).astype(o.dtype)
-    return o @ blk["wo"]
+    return attn_gate(o, a, blk["wa"]) @ blk["wo"]
 
 
 @jax.custom_vjp

@@ -330,14 +330,14 @@ def l2_norm(x, heads: int, scale: float = 1.0, eps: float = 1e-6):
     return (x32 * scale if scale != 1.0 else x32).astype(x.dtype)
 
 
-def gated_norm(o, gate, g, heads: int, eps: float):
-    """``rms(o_h; g) * sigmoid(gate_h)`` over each head's channels: o, gate
-    [B, T, heads * D], g [D] (one gain, every head's); float32 inside, back
-    in o's dtype."""
+def gated_norm(o, gate, g, heads: int, eps: float, act=jax.nn.sigmoid):
+    """``rms(o_h; g) * act(gate_h)`` over each head's channels: o, gate
+    [B, T, heads * D], g [D] (one gain, every head's), ``act`` the gate's
+    function (the sigmoid here, ``silu`` in ``models/qwen3_next.py``);
+    float32 inside, back in o's dtype."""
     normed = _scaled_by_head(o.astype(jnp.float32), heads, eps, mean=True) \
         * jnp.tile(g.astype(jnp.float32), heads)
-    return (normed * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(
-        o.dtype)
+    return (normed * act(gate.astype(jnp.float32))).astype(o.dtype)
 
 
 def kda_inputs(blk, a, cfg: KimiLinearConfig):

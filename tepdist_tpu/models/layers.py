@@ -487,6 +487,23 @@ def rms_norm(x, g, eps: float = 1e-5):
     return (x32 * scale * g).astype(x.dtype)
 
 
+def rms_norm0(x, w, eps: float = 1e-6):
+    """The zero-centred RMSNorm: ``x / rms(x) * (1 + w)`` over the last dim,
+    the leaf ``w`` starting at 0. The gain is made in float32 before the
+    product (``1 + w`` in bf16 is 1 for ``|w| < 2^-8``); back in x's
+    dtype."""
+    return rms_norm(x, 1.0 + w.astype(jnp.float32), eps)
+
+
+def attn_gate(o, a, wa):
+    """The heads' outputs ``o`` [B, T, heads * D] times ``sigmoid(a wa)``,
+    element by element, float32 inside: an output gate from the layer's
+    normed input ``a`` (Trinity's, Qwen3-Next's)."""
+    with jax.named_scope("attn_gate"):
+        gate = jax.nn.sigmoid((a @ wa).astype(jnp.float32))
+        return (o.astype(jnp.float32) * gate).astype(o.dtype)
+
+
 class RopeTable(NamedTuple):
     """A rotary table that is not the plain one: the angle a position
     advances in each of the ``head_dim / 2`` rotated pairs, and what cos and
@@ -568,7 +585,8 @@ def rope(x, table: Union[float, RopeTable], start=0,
 
 def gqa_heads(blk, a, *, n_head: int, n_kv_head: int, head_dim: int,
               eps: float, window: int, windowed, rope_window=None,
-              rope_global=None, block_q: int = 0, block_k: int = 0):
+              rope_global=None, block_q: int = 0, block_k: int = 0,
+              rotary_dim: Optional[int] = None, norm=rms_norm):
     """a [B, T, d] (the normed input) -> the attention heads' outputs side
     by side [B, T, n_head * head_dim], before any gate and before ``wo``:
     ``n_head`` query heads over ``n_kv_head`` key/value heads, RMSNorm over
@@ -576,10 +594,12 @@ def gqa_heads(blk, a, *, n_head: int, n_kv_head: int, head_dim: int,
     ``q_norm``, ``k_norm`` [head_dim]; a block without those leaves has no
     QK-norm), then by the layer's kind the rotary embedding (``rope_window`` /
     ``rope_global``: a ``theta``, a :class:`RopeTable`, or None for no
-    position encoding) and the flash kernels, with ``window`` on a window
-    layer (key j visible to query i iff ``0 <= i - j < window``) and plain
-    causal on a global one. ``windowed``: this layer's kind, a bool or a
-    traced scalar (a stack of both kinds: the branch is a ``lax.cond``).
+    position encoding; ``rotary_dim``: the head's first channels that are
+    rotated, None the whole head) and the flash kernels, with ``window`` on a
+    window layer (key j visible to query i iff ``0 <= i - j < window``) and
+    plain causal on a global one. ``windowed``: this layer's kind, a bool or
+    a traced scalar (a stack of both kinds: the branch is a ``lax.cond``).
+    ``norm``: the QK-norm, :func:`rms_norm` or :func:`rms_norm0`.
     Inside a block that :func:`scan_blocks` walks the call hands its
     forward pass to the walk as ``flash_attention`` does."""
     B, T, _ = a.shape
@@ -590,17 +610,21 @@ def gqa_heads(blk, a, *, n_head: int, n_kv_head: int, head_dim: int,
     def attend(forward, q, k, v, windowed: bool):
         table = rope_window if windowed else rope_global
         if table is not None:
-            q, k = rope(q, table), rope(k, table)
-        return flash_attention_kept(
-            q, k, v, forward, causal=True,
-            window=window if windowed else None,
-            block_q=block_q or None, block_k=block_k or None)
+            with jax.named_scope("attn_rope"):
+                q = rope(q, table, rotary_dim=rotary_dim)
+                k = rope(k, table, rotary_dim=rotary_dim)
+        with jax.named_scope("attn_core"):
+            return flash_attention_kept(
+                q, k, v, forward, causal=True,
+                window=window if windowed else None,
+                block_q=block_q or None, block_k=block_k or None)
 
-    q, k = heads(a @ blk["wq"], n_head), heads(a @ blk["wk"], n_kv_head)
-    if "q_norm" in blk:
-        q = rms_norm(q, blk["q_norm"], eps)
-        k = rms_norm(k, blk["k_norm"], eps)
-    v = heads(a @ blk["wv"], n_kv_head)
+    with jax.named_scope("attn_qkv"):
+        q, k = heads(a @ blk["wq"], n_head), heads(a @ blk["wk"], n_kv_head)
+        if "q_norm" in blk:
+            q = norm(q, blk["q_norm"], eps)
+            k = norm(k, blk["k_norm"], eps)
+        v = heads(a @ blk["wv"], n_kv_head)
 
     def either(forward):
         # What a walk keeps of this call goes in and comes out here, around

@@ -1,0 +1,211 @@
+"""What the chunked delta-rule kernels share (``kda_attention.py``: a decay
+for every key channel; ``gdn_attention.py``: one decay a value head, value
+heads in groups over a key head): the running sum inside a chunk, the
+inverse of the chunk's triangular system and the way back through it, a
+head's column of a ``[chunk, heads]`` block, the one sweep over ``(batch,
+chunks, heads)`` with every head's float32 state in a VMEM scratch, and the
+call's three forms for a walk that keeps its forward pass.
+
+**The inverse by doubling**: ``A`` is strictly lower, so ``(I + A)^-1 = (I -
+A)(I + A^2)(I + A^4) ...`` up to ``A^(C/2)``: two matmuls a factor, no
+substitution row by row. **Back through it**: with ``X = [W | U] = (I +
+A)^-1 B``, ``dB = (I + A)^-T dX`` and ``dA = -dB X^T``: no inverse of its
+own.
+
+**The sweep**: the grid is ``(batch, chunks, heads)``, the chunks sequential
+(last to first: ``reverse``) and the heads innermost, the state ``[V, K]`` of
+every head in one float32 scratch ``[H, V, K]`` from chunk to chunk, so that
+``beta``, a decay a head and their gradients are ``[chunk, H]`` blocks as a
+projection leaves them, read and written once a chunk.
+
+**The call** (:func:`differentiable`): a forward that is differentiated
+writes, beside ``o``, the state before every chunk and the chunk's inverse;
+the backward kernel reads them; inside a block that
+``models/layers.py:scan_blocks`` walks the three go to the walk
+(``flash_attention.hand_over``) and the block's recomputation runs no
+forward kernel."""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from tepdist_tpu.ops.pallas._linear import _F32, _NN, _NT, _TN, _dot
+from tepdist_tpu.ops.pallas.flash_attention import hand_over
+from tepdist_tpu.telemetry import traced
+
+
+def _prefix(x, reverse: bool = False):
+    """Running sums down the rows of ``x`` [C, K] (up them: ``reverse``),
+    each row's own included, by doubling."""
+    C = x.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    s = 1
+    while s < C:
+        if reverse:
+            x = x + jnp.where(row < C - s, pltpu.roll(x, C - s, 0), 0.0)
+        else:
+            x = x + jnp.where(row >= s, pltpu.roll(x, s, 0), 0.0)
+        s *= 2
+    return x
+
+
+def _ij(C: int):
+    return (jax.lax.broadcasted_iota(jnp.int32, (C, C), 0),
+            jax.lax.broadcasted_iota(jnp.int32, (C, C), 1))
+
+
+def _inverse(A, narrow):
+    """``(I + A)^-1`` of a strictly lower ``A`` [C, C], by doubling."""
+    C = A.shape[0]
+    i, j = _ij(C)
+    inv = jnp.where(i == j, 1.0, 0.0) - A
+    power, n = A, 2
+    while n < C:                    # A^C = 0
+        power = _dot(power, power, _NN, narrow)
+        inv = inv + _dot(inv, power, _NN, narrow)
+        n *= 2
+    return inv
+
+
+def _through_inverse(inv, dW, dU, W, U, narrow):
+    """``X = [W | U] = (I + A)^-1 B``: ``(dB``'s two halves, ``dA)`` from
+    ``dX``'s; ``dA`` strictly lower."""
+    i, j = _ij(inv.shape[0])
+    dBw = _dot(inv, dW, _TN, narrow)
+    dBu = _dot(inv, dU, _TN, narrow)
+    dA = -jnp.where(j < i, _dot(dBw, W, _NT, narrow)
+                    + _dot(dBu, U, _NT, narrow), 0.0)
+    return dBw, dBu, dA
+
+
+def _column(b, h):
+    """Head ``h``'s column [C, 1] of a ``[C, H]`` block."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, b.shape, 1)
+    return jnp.sum(jnp.where(lane == h, b.astype(_F32), 0.0), axis=1,
+                   keepdims=True)
+
+
+def sweep(kernel, name, operands, outs, *, chunk, reverse, flops,
+          transcendentals, interpret, group=None):
+    """One sweep over the chunks. ``operands``: ``(kind, array)`` each;
+    ``outs``: ``(kind, dtype)`` of each result (whole chunks: the caller
+    pads). The kinds, for ``H`` heads of ``K`` channels, ``group`` of them a
+    grid step (None: one, its axis squeezed out of a block): ``wide`` ``[B,
+    T, H * K]``, ``group * K`` lanes a step; ``key`` ``[B, T, H / group *
+    K]``, the ``K`` lanes the step's heads share; ``beta`` ``[B, T, H]``,
+    the whole block every step; ``states`` ``[B, chunks, H, V, K]`` and
+    ``inv`` ``[B, chunks, H, chunk, chunk]``, the step's heads'."""
+    B, T, H = next(x.shape for kind, x in operands if kind == "beta")
+    r = group or 1
+    K = next(x.shape[2] for kind, x in operands if kind == "wide") // H
+    nc = T // chunk
+    at = (lambda c: nc - 1 - c) if reverse else (lambda c: c)
+
+    def per_head(*tail):
+        return pl.BlockSpec((None, None, group) + tail,
+                            lambda b, c, h: (b, at(c), h, 0, 0))
+
+    specs = {
+        "wide": pl.BlockSpec((None, chunk, r * K),
+                             lambda b, c, h: (b, at(c), h)),
+        "key": pl.BlockSpec((None, chunk, K), lambda b, c, h: (b, at(c), h)),
+        "beta": pl.BlockSpec((None, chunk, H), lambda b, c, h: (b, at(c), 0)),
+        "states": per_head(K, K),
+        "inv": per_head(chunk, chunk),
+    }
+    shapes = {"wide": (B, T, H * K), "key": (B, T, H // r * K),
+              "beta": (B, T, H), "states": (B, nc, H, K, K),
+              "inv": (B, nc, H, chunk, chunk)}
+    out_shape = [jax.ShapeDtypeStruct(shapes[kind], dtype)
+                 for kind, dtype in outs]
+    return pl.pallas_call(
+        kernel,
+        name=name,
+        grid=(B, nc, H // r),
+        in_specs=[specs[kind] for kind, _ in operands],
+        out_specs=[specs[kind] for kind, _ in outs],
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((H, K, K), _F32)],
+        cost_estimate=pl.CostEstimate(
+            flops=flops, transcendentals=transcendentals,
+            bytes_accessed=sum(
+                x.size * jnp.dtype(x.dtype).itemsize
+                for x in [x for _, x in operands] + out_shape)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(*(x for _, x in operands))
+
+
+def differentiable(forward, backward, counter: str):
+    """``call(q, k, v, g, beta, chunk, interpret) -> o``, differentiable in
+    all five, of a kernel pair ``forward(q, k, v, g, beta, chunk=,
+    interpret=, states=)`` (``o``, or with ``states`` ``(o, states, inv)``)
+    and ``backward(q, k, v, g, beta, do, kept=(states, inv), chunk=,
+    interpret=)`` (five gradients, the last two float32). Inside a block
+    that ``models/layers.py:scan_blocks`` walks the call hands its forward
+    pass, ``(o, states, inv)``, to the walk (``flash_attention.
+    KeptForward``): the values and the backward kernel are the same. Counts,
+    while it is traced, each forward kernel call in the gauge ``counter``
+    (``telemetry/traced.py``)."""
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+    def _attend(q, k, v, g, beta, chunk, interpret, layers):
+        traced.count(counter, layers=layers)
+        return forward(q, k, v, g, beta, chunk=chunk, interpret=interpret)
+
+    def _attend_fwd(q, k, v, g, beta, chunk, interpret, layers):
+        traced.count(counter, layers=layers)
+        o, *kept = forward(q, k, v, g, beta, chunk=chunk,
+                           interpret=interpret, states=True)
+        return o, (q, k, v, g, beta, *kept)
+
+    def _attend_bwd(chunk, interpret, layers, res, do):
+        q, k, v, g, beta, *kept = res
+        dq, dk, dv, dg, dbeta = backward(q, k, v, g, beta, do, kept=kept,
+                                         chunk=chunk, interpret=interpret)
+        return dq, dk, dv, dg.astype(g.dtype), dbeta.astype(beta.dtype)
+
+    _attend.defvjp(_attend_fwd, _attend_bwd)
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+    def _attend_from(q, k, v, g, beta, o, states, inv, chunk, interpret):
+        """``_attend`` where the forward kernel's three results are already
+        in hand: the primal is ``o`` as given (no kernel), the backward is
+        ``_attend``'s on the residuals ``_attend_fwd`` would have saved."""
+        return o
+
+    def _attend_from_fwd(q, k, v, g, beta, o, states, inv, chunk, interpret):
+        return o, (q, k, v, g, beta, states, inv)
+
+    def _attend_from_bwd(chunk, interpret, res, do):
+        return _attend_bwd(chunk, interpret, None, res, do) \
+            + (None, None, None)
+
+    _attend_from.defvjp(_attend_from_fwd, _attend_from_bwd)
+
+    def call(q, k, v, g, beta, chunk, interpret):
+        def attend(saved):
+            """The call in the part a ``KeptForward`` asks of it
+            (``flash_attention.hand_over``): None the whole of it with its
+            custom VJP, ``()`` the forward kernel alone (not
+            differentiable), ``(o, states, inv)`` as that gave them the call
+            from its saved forward."""
+            if saved:
+                return _attend_from(q, k, v, g, beta, *saved, chunk,
+                                    interpret)
+            if saved is None:
+                return _attend(q, k, v, g, beta, chunk, interpret,
+                               traced.stood_for())
+            traced.count(counter)
+            return forward(q, k, v, g, beta, chunk=chunk,
+                           interpret=interpret, states=True)
+
+        return hand_over(attend)
+
+    return call

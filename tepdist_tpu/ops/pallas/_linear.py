@@ -1,7 +1,7 @@
 """What the kernels of a linear-attention state share
-(``lightning_attention.py``, ``kda_attention.py``): a float32 matmul on a
-matrix unit that takes bf16, the state as it goes from chunk to chunk, and
-the sequence padded to whole chunks.
+(``lightning_attention.py``, ``kda_attention.py``, ``gdn_attention.py``): a
+float32 matmul on a matrix unit that takes bf16, the state as it goes from
+chunk to chunk, and the sequence padded to whole chunks.
 
 Precision: the state, the decay factors and every accumulation are float32.
 The matrix unit takes bf16, so a float32 operand of a matmul goes in as two

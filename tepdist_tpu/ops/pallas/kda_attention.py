@@ -31,15 +31,25 @@ sub-block, at most ``exp(15 |g|)`` inside it (``g`` = -5 a token still fits)
 and whatever it is for a key after it, where the mask drops the product.
 Everything else carries ``Gamma`` or ``Gamma_C / Gamma``, at most 1.
 
-**The inverse by doubling**: ``A`` is strictly lower, so ``(I + A)^-1 = (I -
-A)(I + A^2)(I + A^4) ...`` up to ``A^(C/2)``: two matmuls a factor, no
-substitution row by row.
+**Which rule is where.** This file computes the rule with a decay for every
+key channel (``g [T, H * K]``, as many key heads as value heads). The rule
+with ONE decay a value head (``g [T, H]``; Gated DeltaNet), value heads in
+groups over a key head, is ``gdn_attention.py``: there the decay is a ``[C,
+C]`` mask on a plain product and none of the sub-blocks below is needed. A
+call here with ``g`` spread over a head's channels and ``q, k`` repeated
+computes that rule too (a test holds the two equal); it pays for the general
+case. What the two share is ``_delta_rule.py``: the running sum, the inverse
+by doubling (``A`` is strictly lower, so ``(I + A)^-1 = (I - A)(I + A^2)(I +
+A^4) ...`` up to ``A^(C/2)``: two matmuls a factor, no substitution row by
+row) and the way back through it, a head's column of a ``[chunk, H]`` block,
+the sweep and the call's three forms for a walk.
 
-**The grid is ``(batch, chunks, heads)``**, the chunks sequential and the
-heads innermost, every head's state ``[V, K]`` (the transpose, so that a
-decay of the key channels scales lanes) in one float32 VMEM scratch ``[H, V,
-K]`` from chunk to chunk: ``beta`` and its gradient are then ``[chunk, H]``
-blocks as the projection leaves them, read and written once a chunk.
+**The grid is ``(batch, chunks, heads)``** (``_delta_rule.sweep``), the chunks
+sequential and the heads innermost, every head's state ``[V, K]`` (the
+transpose, so that a decay of the key channels scales lanes) in one float32
+VMEM scratch ``[H, V, K]`` from chunk to chunk: ``beta`` and its gradient are
+then ``[chunk, H]`` blocks as the projection leaves them, read and written
+once a chunk.
 ``q, k, v, g`` are ``[batch, T, H * K]``, a projection's own layout, head
 ``h`` lane block ``h``.
 
@@ -86,9 +96,17 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from tepdist_tpu.ops.pallas import _interpret
+from tepdist_tpu.ops.pallas._delta_rule import (
+    _column,
+    _ij,
+    _inverse,
+    _prefix,
+    _through_inverse,
+    differentiable,
+    sweep,
+)
 from tepdist_tpu.ops.pallas._linear import (
     _BF16,
     _F32,
@@ -100,7 +118,6 @@ from tepdist_tpu.ops.pallas._linear import (
     _dot,
     _padded,
 )
-from tepdist_tpu.ops.pallas.flash_attention import hand_over
 from tepdist_tpu.telemetry import traced
 
 CHUNK = 64                  # tokens a grid step
@@ -109,26 +126,6 @@ _CAP = 80.0                 # exp(80) fits float32
 
 traced.declare(
     "kda_calls", "forward delta-rule kernel calls a micro batch")
-
-
-def _prefix(x, reverse: bool = False):
-    """Running sums down the rows of ``x`` [C, K] (up them: ``reverse``),
-    each row's own included, by doubling."""
-    C = x.shape[0]
-    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
-    s = 1
-    while s < C:
-        if reverse:
-            x = x + jnp.where(row < C - s, pltpu.roll(x, C - s, 0), 0.0)
-        else:
-            x = x + jnp.where(row >= s, pltpu.roll(x, s, 0), 0.0)
-        s *= 2
-    return x
-
-
-def _ij(C: int):
-    return (jax.lax.broadcasted_iota(jnp.int32, (C, C), 0),
-            jax.lax.broadcasted_iota(jnp.int32, (C, C), 1))
 
 
 def _factors(G, lo: int):
@@ -181,26 +178,6 @@ def _scores_backward(q, k, G, dP, dkk, narrow):
     return jnp.concatenate(dq, axis=0), \
         dk + jnp.concatenate(dk_rows, axis=0), \
         dG + jnp.concatenate(dG_rows, axis=0)
-
-
-def _inverse(A, narrow):
-    """``(I + A)^-1`` of a strictly lower ``A`` [C, C], by doubling."""
-    C = A.shape[0]
-    i, j = _ij(C)
-    inv = jnp.where(i == j, 1.0, 0.0) - A
-    power, n = A, 2
-    while n < C:                    # A^C = 0
-        power = _dot(power, power, _NN, narrow)
-        inv = inv + _dot(inv, power, _NN, narrow)
-        n *= 2
-    return inv
-
-
-def _column(b, h):
-    """Head ``h``'s column [C, 1] of a ``[C, H]`` block."""
-    lane = jax.lax.broadcasted_iota(jnp.int32, b.shape, 1)
-    return jnp.sum(jnp.where(lane == h, b.astype(_F32), 0.0), axis=1,
-                   keepdims=True)
 
 
 def _chunk(q, k, v, g, beta, state_t, narrow, inv=None):
@@ -287,11 +264,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, s_ref, inv_ref,
     d_kd = _dot(c["vp"], d_next, _NN, narrow)
     d_last = decay * jnp.sum(state_t * d_next, axis=0, keepdims=True)
     dW = -_dot(d_vp, state_t, _NN, narrow)
-    # X = [W | U] = (I + A)^-1 B:  dB = (I + A)^-T dX,  dA = -dB X^T.
-    dBw = _dot(c["inv"], dW, _TN, narrow)
-    dBu = _dot(c["inv"], d_vp, _TN, narrow)
-    dA = -jnp.where(j < i, _dot(dBw, c["W"], _NT, narrow)
-                    + _dot(dBu, c["U"], _NT, narrow), 0.0)
+    dBw, dBu, dA = _through_inverse(c["inv"], dW, d_vp, c["W"], c["U"],
+                                    narrow)
     v32 = v.astype(_F32)
     d_beta = jnp.sum(dBw * c["kg"], axis=1, keepdims=True) \
         + jnp.sum(dBu * v32, axis=1, keepdims=True) \
@@ -321,47 +295,21 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, s_ref, inv_ref,
 
 def _call(kernel, name, operands, outs, *, chunk, reverse, matmuls,
           interpret, state_dtype=None):
-    """One sweep over the chunks. ``operands``: ``(kind, array)`` each, the
-    kinds ``wide`` ``[B, T, H * K]``, ``beta`` ``[B, T, H]``, ``states``
-    ``[B, chunks, H, V, K]`` and ``inv`` ``[B, chunks, H, chunk, chunk]``
-    (whole chunks: the caller pads); ``outs``: ``(kind, dtype)`` of each
-    result."""
-    B, T, HK = operands[0][1].shape
-    H = next(x.shape[2] for kind, x in operands if kind == "beta")
-    K = HK // H
-    nc = T // chunk
-    at = (lambda c: nc - 1 - c) if reverse else (lambda c: c)
-    specs = {
-        "wide": pl.BlockSpec((None, chunk, K), lambda b, c, h: (b, at(c), h)),
-        "beta": pl.BlockSpec((None, chunk, H), lambda b, c, h: (b, at(c), 0)),
-        "states": pl.BlockSpec((None, None, None, K, K),
-                               lambda b, c, h: (b, at(c), h, 0, 0)),
-        "inv": pl.BlockSpec((None, None, None, chunk, chunk),
-                            lambda b, c, h: (b, at(c), h, 0, 0)),
-    }
-    shapes = {"wide": (B, T, HK), "beta": (B, T, H),
-              "states": (B, nc, H, K, K), "inv": (B, nc, H, chunk, chunk)}
-    out_shape = [jax.ShapeDtypeStruct(shapes[kind], dtype)
-                 for kind, dtype in outs]
-    return pl.pallas_call(
-        functools.partial(kernel, narrow=operands[0][1].dtype == _BF16,
+    """One sweep over the chunks (``_delta_rule.sweep``), a head a grid
+    step. ``operands``: ``(kind, array)`` each, the kinds ``wide`` ``[B, T,
+    H * K]``, ``beta`` ``[B, T, H]``, ``states`` ``[B, chunks, H, V, K]`` and
+    ``inv`` ``[B, chunks, H, chunk, chunk]`` (whole chunks: the caller
+    pads); ``outs``: ``(kind, dtype)`` of each result."""
+    q, beta = operands[0][1], operands[4][1]
+    B, T, HK = q.shape
+    K = HK // beta.shape[2]
+    return sweep(
+        functools.partial(kernel, narrow=q.dtype == _BF16,
                           state_dtype=state_dtype),
-        name=name,
-        grid=(B, nc, H),
-        in_specs=[specs[kind] for kind, _ in operands],
-        out_specs=[specs[kind] for kind, _ in outs],
-        out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((H, K, K), _F32)],
-        cost_estimate=pl.CostEstimate(
-            flops=2 * matmuls * B * H * T * K * (chunk + K) // 2,
-            transcendentals=B * H * T * K * (3 + chunk // SUB),
-            bytes_accessed=sum(
-                x.size * jnp.dtype(x.dtype).itemsize
-                for x in [x for _, x in operands] + out_shape)),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
-        interpret=interpret,
-    )(*(x for _, x in operands))
+        name, operands, outs, chunk=chunk, reverse=reverse,
+        flops=2 * matmuls * B * T * HK * (chunk + K) // 2,
+        transcendentals=B * T * HK * (3 + chunk // SUB),
+        interpret=interpret)
 
 
 def _operands(chunk, q, k, v, g, beta, *more):
@@ -416,46 +364,7 @@ def backward(q, k, v, g, beta, do, *, kept=None, chunk: int = CHUNK,
     return tuple(x[:, :q.shape[1]] for x in out)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _attend(q, k, v, g, beta, chunk, interpret, layers):
-    traced.count("kda_calls", layers=layers)
-    return forward(q, k, v, g, beta, chunk=chunk, interpret=interpret)
-
-
-def _attend_fwd(q, k, v, g, beta, chunk, interpret, layers):
-    traced.count("kda_calls", layers=layers)
-    o, *kept = forward(q, k, v, g, beta, chunk=chunk, interpret=interpret,
-                       states=True)
-    return o, (q, k, v, g, beta, *kept)
-
-
-def _attend_bwd(chunk, interpret, layers, res, do):
-    q, k, v, g, beta, *kept = res
-    dq, dk, dv, dg, dbeta = backward(q, k, v, g, beta, do, kept=kept,
-                                     chunk=chunk, interpret=interpret)
-    return dq, dk, dv, dg.astype(g.dtype), dbeta.astype(beta.dtype)
-
-
-_attend.defvjp(_attend_fwd, _attend_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
-def _attend_from(q, k, v, g, beta, o, states, inv, chunk, interpret):
-    """``_attend`` where the forward kernel's three results are already in
-    hand: the primal is ``o`` as given (no kernel), the backward is
-    ``_attend``'s on the residuals ``_attend_fwd`` would have saved."""
-    return o
-
-
-def _attend_from_fwd(q, k, v, g, beta, o, states, inv, chunk, interpret):
-    return o, (q, k, v, g, beta, states, inv)
-
-
-def _attend_from_bwd(chunk, interpret, res, do):
-    return _attend_bwd(chunk, interpret, None, res, do) + (None, None, None)
-
-
-_attend_from.defvjp(_attend_from_fwd, _attend_from_bwd)
+_attention = differentiable(forward, backward, "kda_calls")
 
 
 def kda_attention(q, k, v, g, beta, *, chunk: int = CHUNK,
@@ -479,24 +388,7 @@ def kda_attention(q, k, v, g, beta, *, chunk: int = CHUNK,
             f"kda_attention: q {q.shape}, k {k.shape}, v {v.shape}, g "
             f"{g.shape}, beta {beta.shape}, chunk {chunk}")
     chunk = min(chunk, -(-q.shape[1] // SUB) * SUB)
-    interpret = _interpret(interpret)
-
-    def attend(saved):
-        """The call in the part a ``KeptForward`` asks of it
-        (``flash_attention.hand_over``): None the whole of it with its
-        custom VJP, ``()`` the forward kernel alone (not differentiable),
-        ``(o, states, inv)`` as that gave them the call from its saved
-        forward."""
-        if saved:
-            return _attend_from(q, k, v, g, beta, *saved, chunk, interpret)
-        if saved is None:
-            return _attend(q, k, v, g, beta, chunk, interpret,
-                           traced.stood_for())
-        traced.count("kda_calls")
-        return forward(q, k, v, g, beta, chunk=chunk, interpret=interpret,
-                       states=True)
-
-    return hand_over(attend)
+    return _attention(q, k, v, g, beta, chunk, _interpret(interpret))
 
 
 def chunked(q, k, v, g, beta, *, chunk: int = CHUNK):

@@ -51,18 +51,19 @@ def mesh8(devices):
 # were the run's tail, some hundred seconds in which most workers stood idle
 # (ROADMAP D17). Longest first, the rest as collected: the same cases, every
 # worker busy to the end. By the summed case seconds of a run under six
-# workers (over 120 s a file).
+# workers (100 s a file and over; PR 54's run: 7,358 s of cases in all, so
+# 1,226 s a worker at best).
 _LONGEST_FIRST = (
-    "test_mellum.py", "test_tpu_compile.py", "test_kimi_linear.py",
-    "test_kimi_linear_walk.py", "test_sarvam_mla.py",
-    "test_tpu_compile_kimi.py",
-    "test_stack_in_place.py", "test_minicpm_sala.py", "test_afmoe.py",
-    "test_jamba.py", "test_multiworker.py", "test_jaxpr_serde.py",
-    "test_evaluator_measured.py", "test_sequence_parallel.py",
-    "test_zaya_walk.py", "test_models.py", "test_zaya.py",
-    "test_collective_pipeline.py", "test_olmoe.py", "test_seq_planner.py",
-    "test_attn_kept.py", "test_serving_fleet.py", "test_serving_chaos.py",
-    "test_ga_fused.py", "test_rpc.py", "test_rpc_explore.py",
+    "test_sarvam_mla.py", "test_tpu_compile.py", "test_afmoe.py",
+    "test_minicpm_sala.py", "test_mellum.py", "test_stack_in_place.py",
+    "test_jaxpr_serde.py", "test_kimi_linear.py", "test_jamba.py",
+    "test_kimi_linear_walk.py", "test_sequence_parallel.py", "test_models.py",
+    "test_zaya_walk.py", "test_olmoe.py", "test_multiworker.py",
+    "test_evaluator_measured.py", "test_collective_pipeline.py",
+    "test_qwen3_next.py", "test_gdn_attention.py", "test_zaya.py",
+    "test_attn_kept.py", "test_qwen3_next_walk.py", "test_kda_attention.py",
+    "test_serving_paged.py", "test_serving_chaos.py", "test_ga_fused.py",
+    "test_tpu_compile_qwen3_next.py",
 )
 
 
