@@ -38,7 +38,10 @@ gradients are ``[chunk, Hv]`` float32 blocks. In the backward ``dq`` and
 ``dk`` are summed over the key head's value heads before they are written,
 and the two gradients of the shared products are summed as ``[C, C]``
 matrices first, so their products with ``q`` and ``k`` are made once a key
-head too.
+head too. In the forward the value heads' ``D`` and ``A`` are made first and
+their inverses side by side in one ``_delta_rule._inverse`` (a chain of
+dependent products, which leaves the matrix unit waiting when it walks
+alone), then each head's ``W``, ``U``, ``v'``, ``o`` and state.
 
 **The forward is made once**, as ``kda_attention.py``'s: differentiated, it
 writes the state before every chunk (``[batch, chunks, Hv, V, K]`` float32)
@@ -48,7 +51,8 @@ carried and reads them; inside a walked block the three go to the walk.
 
 Precision (``_linear.py``): ``G``, ``D``, the state and every accumulation
 float32; a float32 operand of a matmul goes to the matrix unit as two bf16
-parts; ``G`` by doubling over sublane rolls. With float32 operands (the CPU
+parts, four passes a product of two of them and three inside the inverse;
+``G`` by doubling over sublane rolls. With float32 operands (the CPU
 tests) every matmul is float32. Any ``T``: the last chunk is padded with zero
 rows (``g`` = 0, ``beta`` = 0). ``K = V``, a multiple of 128 on the chip.
 Kernel names ``tepdist_gdn_fwd`` / ``tepdist_gdn_bwd``. :func:`chunked` is
@@ -113,34 +117,39 @@ def _products(q, k, narrow):
             jax.lax.dot_general(k, k, _NT, preferred_element_type=_F32))
 
 
-def _chunk(qk, kk, q, k, v, G, beta, state_t, narrow, inv=None):
-    """What the forward and the backward both make of a chunk and one value
-    head: a dict. ``G`` and ``beta`` the head's columns [C, 1]; ``inv``:
-    ``(I + A)^-1`` as the forward wrote it; None makes it."""
-    C, K = k.shape
-    i, j = _ij(C)
+def _system(kk, G):
+    """A value head's ``D`` and ``A`` before ``beta`` [C, C] from the key
+    head's ``k k^T`` and the head's ``G`` [C, 1]."""
+    i, j = _ij(kk.shape[0])
     D = jnp.where(j <= i, jnp.exp(jnp.minimum(G - _row(G), 0.0)), 0.0)
+    return D, jnp.where(j < i, kk * D, 0.0)
+
+
+def _chunk(qk, q, k, v, G, beta, state_t, narrow, D, A, inv):
+    """What the forward and the backward both make of a chunk and one value
+    head: a dict. ``G`` and ``beta`` the head's columns [C, 1]; ``D, A``
+    its :func:`_system`; ``inv``: ``(I + A beta)^-1`` as the forward made
+    it."""
+    C, K = k.shape
     last = G[C - 1:C]
     gamma, tail = jnp.exp(G), jnp.exp(last - G)
-    P = qk * D
-    A = jnp.where(j < i, kk * D, 0.0)
-    if inv is None:
-        inv = _inverse(A * beta, narrow)
     k32, v32 = k.astype(_F32), v.astype(_F32)
     kg = k32 * gamma
     WU = _dot(inv, jnp.concatenate([kg * beta, v32 * beta], axis=1), _NN,
               narrow)
     W, U = WU[:, :K], WU[:, K:]
     vp = U - _dot(W, state_t, _NT, narrow)
-    return dict(D=D, last=last, gamma=gamma, tail=tail, P=P, A=A, inv=inv,
-                kg=kg, v32=v32, kd=k32 * tail, W=W, U=U, vp=vp)
+    return dict(D=D, last=last, gamma=gamma, tail=tail, P=qk * D, A=A,
+                inv=inv, kg=kg, v32=v32, kd=k32 * tail, W=W, U=U, vp=vp)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, *rest, want, narrow,
                 state_dtype):
-    """First chunk to last, every value head's state carried. ``want``:
-    which of the output ``"o"``, the state before the chunk ``"states"`` and
-    the chunk's ``(I + A)^-1`` ``"inv"`` are the results, in that order."""
+    """First chunk to last, every value head's state carried; the key
+    head's value heads' inverses in one :func:`_inverse`, side by side.
+    ``want``: which of the output ``"o"``, the state before the chunk
+    ``"states"`` and the chunk's ``(I + A)^-1`` ``"inv"`` are the results,
+    in that order."""
     outs, s_scr = dict(zip(want, rest)), rest[-1]
     V = s_scr.shape[1]
     r = v_ref.shape[1] // V
@@ -155,11 +164,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, *rest, want, narrow,
     qk, kk = _products(q, k, narrow)
     G = _prefix(g_ref[...].astype(_F32))
     b = b_ref[...]
+    Gs = [_column(G, first + n) for n in range(r)]
+    betas = [_column(b, first + n) for n in range(r)]
+    systems = [_system(kk, G) for G in Gs]
+    invs = _inverse([A * beta for (_, A), beta in zip(systems, betas)],
+                    narrow)
     for n in range(r):
         h = first + n
         state_t = s_scr[h]
-        c = _chunk(qk, kk, q, k, v_ref[:, n * V:(n + 1) * V], _column(G, h),
-                   _column(b, h), state_t, narrow)
+        c = _chunk(qk, q, k, v_ref[:, n * V:(n + 1) * V], Gs[n], betas[n],
+                   state_t, narrow, *systems[n], invs[n])
         if "states" in outs:
             outs["states"][n] = state_t
         if "inv" in outs:
@@ -213,7 +227,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, s_ref, inv_ref,
         beta = _column(b, h)
         v, do = v_ref[:, n * V:(n + 1) * V], do_ref[:, n * V:(n + 1) * V]
         state_t, d_next = s_ref[n], ds_scr[h]
-        c = _chunk(qk, kk, q, k, v, _column(G_all, h), beta, state_t, narrow,
+        G = _column(G_all, h)
+        c = _chunk(qk, q, k, v, G, beta, state_t, narrow, *_system(kk, G),
                    inv_ref[n])
         decay = jnp.exp(c["last"])                          # [1, 1]
         qg = q32 * c["gamma"]

@@ -40,16 +40,22 @@ call here with ``g`` spread over a head's channels and ``q, k`` repeated
 computes that rule too (a test holds the two equal); it pays for the general
 case. What the two share is ``_delta_rule.py``: the running sum, the inverse
 by doubling (``A`` is strictly lower, so ``(I + A)^-1 = (I - A)(I + A^2)(I +
-A^4) ...`` up to ``A^(C/2)``: two matmuls a factor, no substitution row by
-row) and the way back through it, a head's column of a ``[chunk, H]`` block,
-the sweep and the call's three forms for a walk.
+A^4) ...`` up to ``A^(C/2)``: two matmuls a factor, which share their right
+operand and go to the matrix unit as one, no substitution row by row) and the
+way back through it, a head's column of a ``[chunk, H]`` block, the sweep and
+the call's three forms for a walk.
 
 **The grid is ``(batch, chunks, heads)``** (``_delta_rule.sweep``), the chunks
 sequential and the heads innermost, every head's state ``[V, K]`` (the
 transpose, so that a decay of the key channels scales lanes) in one float32
 VMEM scratch ``[H, V, K]`` from chunk to chunk: ``beta`` and its gradient are
 then ``[chunk, H]`` blocks as the projection leaves them, read and written
-once a chunk.
+once a chunk. **The forward's sweep takes two heads a grid step** (the
+backward's one): the two heads' scores are made first, then their two
+inverses side by side in one ``_delta_rule._inverse``, whose chain of
+dependent products is the forward's longest piece and leaves the matrix unit
+waiting when it walks alone, then each head's ``W``, ``U``, ``v'``, ``o`` and
+state. An odd head count runs one head a step.
 ``q, k, v, g`` are ``[batch, T, H * K]``, a projection's own layout, head
 ``h`` lane block ``h``.
 
@@ -60,10 +66,11 @@ C]`` float32; 134e6 bytes each at 8,192 tokens of 32 heads in chunks of 128)
 as a second and a third result of its one sweep, and the backward keeps them
 with the operands. ``tepdist_kda_bwd`` walks the chunks last to first with
 the state's gradient carried, makes the chunk's ``G``, scores, ``W``, ``U``,
-``v'`` again from the operands, that state and that inverse (the doubling
-was 3.6 of the kernel's 11.0 ms a call at the cell's shape: twelve products
-that each wait for the last), and writes ``dq, dk, dv, dg, dbeta``. ``d Tm`` needs no inverse of its own: with ``X = [W | U] =
-(I + A)^-1 B``, ``dB = (I + A)^-T dX`` and ``dA = -dB X^T``. (:func:`backward`
+``v'`` again from the operands, that state and that inverse (making it
+again was 3.6 of the kernel's 11.0 ms a call at the cell's shape), and
+writes ``dq, dk, dv, dg, dbeta``. ``d Tm`` needs no inverse of its own: with
+``X = [W | U] = (I + A)^-1 B``, ``dB = (I + A)^-T dX`` and ``dA = -dB X^T``.
+(:func:`backward`
 without the pair makes both again by the forward's sweep under the name
 ``tepdist_kda_bwd_states``.) Inside a block that
 ``models/layers.py:scan_blocks`` walks the call hands ``(o, states, inv)`` to
@@ -74,7 +81,8 @@ backward.
 
 Precision (``_linear.py``): the state, ``G``, every decay factor and every
 accumulation are float32; a float32 operand goes to the matrix unit as two
-bf16 parts. ``G`` is summed by doubling over sublane rolls, float32 adds.
+bf16 parts, four passes a product of two of them and three inside the
+inverse. ``G`` is summed by doubling over sublane rolls, float32 adds.
 With float32 operands (the CPU tests) every matmul is float32.
 
 Any ``T``: the last chunk is padded with zero rows (``g`` = 0, ``beta`` = 0:
@@ -180,27 +188,29 @@ def _scores_backward(q, k, G, dP, dkk, narrow):
         dG + jnp.concatenate(dG_rows, axis=0)
 
 
-def _chunk(q, k, v, g, beta, state_t, narrow, inv=None):
-    """What the forward and the backward both make of a chunk: a dict.
-    ``inv``: ``(I + A)^-1`` as the forward wrote it; None makes it."""
+def _scored(q, k, g, narrow):
+    """A chunk's running sum, its decays and its masked scores: a dict.
+    ``kk * beta`` is the chunk's ``A``."""
     C = q.shape[0]
     G = _prefix(g.astype(_F32))
     last = G[C - 1:C]
-    gamma, tail = jnp.exp(G), jnp.exp(last - G)
     P, kk = _scores(q, k, G, narrow)
     i, j = _ij(C)
-    P = jnp.where(j <= i, P, 0.0)
-    kk = jnp.where(j < i, kk, 0.0)
-    if inv is None:
-        inv = _inverse(kk * beta, narrow)
+    return dict(G=G, last=last, gamma=jnp.exp(G), tail=jnp.exp(last - G),
+                P=jnp.where(j <= i, P, 0.0), kk=jnp.where(j < i, kk, 0.0))
+
+
+def _chunk(c, q, k, v, beta, state_t, narrow, inv):
+    """What the forward and the backward both make of a chunk: ``c``, its
+    :func:`_scored`, and what follows from ``inv``, ``(I + A)^-1`` as the
+    forward made it."""
     k32 = k.astype(_F32)
-    kg = k32 * gamma
+    kg = k32 * c["gamma"]
     W = _dot(inv, kg * beta, _NN, narrow)
     U = _dot(inv, v.astype(_F32) * beta, _NN, narrow)
     vp = U - _dot(W, state_t, _NT, narrow)
-    return dict(G=G, last=last, gamma=gamma, tail=tail, P=P, kk=kk, inv=inv,
-                kg=kg, qg=q.astype(_F32) * gamma, kd=k32 * tail, W=W, U=U,
-                vp=vp)
+    return dict(c, inv=inv, kg=kg, qg=q.astype(_F32) * c["gamma"],
+                kd=k32 * c["tail"], W=W, U=U, vp=vp)
 
 
 def _next_state(c, state_t, narrow, state_dtype):
@@ -210,28 +220,41 @@ def _next_state(c, state_t, narrow, state_dtype):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, *rest, want, narrow,
                 state_dtype):
-    """First chunk to last, every head's state carried. ``want``: which of
-    the output ``"o"``, the state before the chunk ``"states"`` and the
+    """First chunk to last, every head's state carried; the grid step's
+    heads' inverses in one :func:`_inverse`, side by side. ``want``: which
+    of the output ``"o"``, the state before the chunk ``"states"`` and the
     chunk's ``(I + A)^-1`` ``"inv"`` are the results, in that order."""
     outs, s_scr = dict(zip(want, rest)), rest[-1]
-    h = pl.program_id(2)
+    K = s_scr.shape[2]
+    r = q_ref.shape[1] // K
+    first = pl.program_id(2) * r
 
     @pl.when(pl.program_id(1) == 0)
     def _():
-        s_scr[h] = jnp.zeros(s_scr.shape[1:], _F32)
+        for n in range(r):
+            s_scr[first + n] = jnp.zeros(s_scr.shape[1:], _F32)
 
-    state_t = s_scr[h]
-    c = _chunk(q_ref[...], k_ref[...], v_ref[...], g_ref[...],
-               _column(b_ref[...], h), state_t, narrow)
-    if "states" in outs:
-        outs["states"][...] = state_t
-    if "inv" in outs:
-        outs["inv"][...] = c["inv"]
-    if "o" in outs:
-        out = _dot(c["qg"], state_t, _NT, narrow) \
-            + _dot(c["P"], c["vp"], _NN, narrow)
-        outs["o"][...] = out.astype(outs["o"].dtype)
-    s_scr[h] = _next_state(c, state_t, narrow, state_dtype)
+    b = b_ref[...]
+    lanes = [slice(n * K, (n + 1) * K) for n in range(r)]
+    betas = [_column(b, first + n) for n in range(r)]
+    scored = [_scored(q_ref[:, at], k_ref[:, at], g_ref[:, at], narrow)
+              for at in lanes]
+    invs = _inverse([c["kk"] * beta for c, beta in zip(scored, betas)],
+                    narrow)
+    for n, at in enumerate(lanes):
+        h = first + n
+        state_t = s_scr[h]
+        c = _chunk(scored[n], q_ref[:, at], k_ref[:, at], v_ref[:, at],
+                   betas[n], state_t, narrow, invs[n])
+        if "states" in outs:
+            outs["states"][n] = state_t
+        if "inv" in outs:
+            outs["inv"][n] = c["inv"]
+        if "o" in outs:
+            out = _dot(c["qg"], state_t, _NT, narrow) \
+                + _dot(c["P"], c["vp"], _NN, narrow)
+            outs["o"][:, at] = out.astype(outs["o"].dtype)
+        s_scr[h] = _next_state(c, state_t, narrow, state_dtype)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, s_ref, inv_ref,
@@ -253,7 +276,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, s_ref, inv_ref,
     q, k, v, do = q_ref[...], k_ref[...], v_ref[...], do_ref[...]
     beta = _column(b_ref[...], h)
     state_t, d_next = s_ref[...], ds_scr[h]
-    c = _chunk(q, k, v, g_ref[...], beta, state_t, narrow, inv_ref[...])
+    c = _chunk(_scored(q, k, g_ref[...], narrow), q, k, v, beta, state_t,
+               narrow, inv_ref[...])
     C = q.shape[0]
     i, j = _ij(C)
     decay = jnp.exp(c["last"])                              # Gamma_C [1, K]
@@ -293,13 +317,20 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, s_ref, inv_ref,
         - _dot(d_vp, c["W"], _TN, narrow), state_dtype)
 
 
+def _pairs(H: int):
+    """The heads a grid step of the forward's sweep: two, whose inverses
+    walk side by side; an odd count's one."""
+    return 1 if H % 2 else 2
+
+
 def _call(kernel, name, operands, outs, *, chunk, reverse, matmuls,
-          interpret, state_dtype=None):
-    """One sweep over the chunks (``_delta_rule.sweep``), a head a grid
-    step. ``operands``: ``(kind, array)`` each, the kinds ``wide`` ``[B, T,
-    H * K]``, ``beta`` ``[B, T, H]``, ``states`` ``[B, chunks, H, V, K]`` and
-    ``inv`` ``[B, chunks, H, chunk, chunk]`` (whole chunks: the caller
-    pads); ``outs``: ``(kind, dtype)`` of each result."""
+          interpret, state_dtype=None, group=None):
+    """One sweep over the chunks (``_delta_rule.sweep``), ``group`` heads a
+    grid step (None: one, its axis squeezed out of a block). ``operands``:
+    ``(kind, array)`` each, the kinds ``wide`` ``[B, T, H * K]``, ``beta``
+    ``[B, T, H]``, ``states`` ``[B, chunks, H, V, K]`` and ``inv`` ``[B,
+    chunks, H, chunk, chunk]`` (whole chunks: the caller pads); ``outs``:
+    ``(kind, dtype)`` of each result."""
     q, beta = operands[0][1], operands[4][1]
     B, T, HK = q.shape
     K = HK // beta.shape[2]
@@ -309,7 +340,7 @@ def _call(kernel, name, operands, outs, *, chunk, reverse, matmuls,
         name, operands, outs, chunk=chunk, reverse=reverse,
         flops=2 * matmuls * B * T * HK * (chunk + K) // 2,
         transcendentals=B * T * HK * (3 + chunk // SUB),
-        interpret=interpret)
+        interpret=interpret, group=group)
 
 
 def _operands(chunk, q, k, v, g, beta, *more):
@@ -335,7 +366,8 @@ def forward(q, k, v, g, beta, *, chunk: int = CHUNK, interpret=None,
                 [("wide", out_dtype or q.dtype), ("states", _F32),
                  ("inv", _F32)][:len(want)],
                 chunk=chunk, reverse=False, matmuls=23,
-                interpret=_interpret(interpret), state_dtype=state_dtype)
+                interpret=_interpret(interpret), state_dtype=state_dtype,
+                group=_pairs(beta.shape[2]))
     o = out[0][:, :q.shape[1]]
     return (o, *out[1:]) if states else o
 
@@ -353,7 +385,7 @@ def backward(q, k, v, g, beta, do, *, kept=None, chunk: int = CHUNK,
                      "tepdist_kda_bwd_states", operands[:5],
                      [("states", _F32), ("inv", _F32)], chunk=chunk,
                      reverse=False, matmuls=21, interpret=interpret,
-                     state_dtype=state_dtype)
+                     state_dtype=state_dtype, group=_pairs(beta.shape[2]))
     states, inv = kept
     dtype = out_dtype or q.dtype
     out = _call(_bwd_kernel, "tepdist_kda_bwd",

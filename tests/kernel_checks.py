@@ -53,3 +53,19 @@ def sala_hyper(cfg):
         scale_emb=cfg.scale_emb, scale_depth=cfg.scale_depth,
         dim_model_base=cfg.dim_model_base, rope_theta=cfg.rope_theta,
         eps=cfg.rms_norm_eps, **cfg.sparse._asdict())
+
+
+def inverses_side_by_side(inverse, A, narrow):
+    """``inverse`` (``_delta_rule._inverse``) of the list ``A`` of strictly
+    lower ``[C, C]`` systems: a list, each entry bit for bit the call's on
+    that system alone, strictly lower beside its diagonal of ones, and the
+    inverse of ``I + A``."""
+    both = inverse(A, narrow)
+    assert isinstance(both, list) and len(both) == len(A)
+    eye = np.eye(A[0].shape[0])
+    for a, inv in zip(A, both):
+        np.testing.assert_array_equal(np.asarray(inv),
+                                      np.asarray(inverse(a, narrow)))
+        back = np.asarray(inv, np.float64) @ (eye + np.asarray(a, np.float64))
+        assert rel_l2(back, eye) < 3e-6
+        assert not np.triu(np.asarray(inv), 1).any()

@@ -6,8 +6,9 @@ custom VJP, a sequence of several chunks (the last one padded) and one
 shorter than a chunk, decays at both ends of the initialisation's range and
 at ``g`` = -20 a token, **equal to ``kda_attention`` with ``g`` broadcast and
 ``q, k`` repeated** (what says the two rules are one), the rule's two
-limits, rows of a batch that do not meet, the gauge, the shapes refused, and
-the three cases of a hand-over to a walk
+limits, rows of a batch that do not meet, the gauge, the shapes refused, a
+key head's two inverses side by side against each alone, and the three cases
+of a hand-over to a walk
 (``ops/pallas/flash_attention.py:KeptForward``). 2 key heads under 4 value
 heads but for the one case at the published heads."""
 
@@ -15,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from kernel_checks import kernel_counts, rel_l2
+from kernel_checks import inverses_side_by_side, kernel_counts, rel_l2
 
 from benchmark.reference import qwen3_next as ref
 from tepdist_tpu.ops.pallas import flash_attention as fa
@@ -185,6 +186,27 @@ def test_the_custom_vjp_is_the_kernels_backward_and_counts_its_calls():
     assert states.shape == (2, 3, 4, 16, 16) and inv.shape == states.shape
     assert states.dtype == inv.dtype == jnp.float32
     _equal(gdn.backward(*x, kept=(states, inv), chunk=16), got[1:])
+
+
+def _systems(C, dtype):
+    """A key head's two value heads' ``A`` [C, C] of one chunk of ``C``
+    tokens, by the definition, from operands of ``dtype``'s values."""
+    _, k, _, g, beta, _ = inputs(1, C, 1, 2, 16, seed=21, dtype=dtype)
+    k = k[0].astype(jnp.float32)
+    G = jnp.cumsum(g[0].T, axis=1)                          # [2, C]
+    i, j = gdn._ij(C)
+    D = jnp.exp(jnp.where(j < i, G[:, :, None] - G[:, None], -jnp.inf))
+    return list((k @ k.T) * D * beta[0].T[..., None])
+
+
+# Chunks of 16 and of the cell's 128 in float32, and the cell's as the chip
+# runs it: operands of bf16 values, a product inside the inverse three bf16
+# passes.
+@pytest.mark.parametrize("C,narrow", [(16, False), (128, False), (128, True)])
+def test_a_key_heads_inverses_side_by_side_are_each_alone(C, narrow):
+    inverses_side_by_side(
+        gdn._inverse, _systems(C, jnp.bfloat16 if narrow else jnp.float32),
+        narrow)
 
 
 def test_the_three_cases_of_a_hand_over_are_one_call():

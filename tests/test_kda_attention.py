@@ -5,14 +5,15 @@ and all five gradients, the custom VJP, a padded last chunk and a sequence
 shorter than a chunk, decays at both ends of the initialisation's range and
 at ``g`` = -5 a token, the rule's two limits, rows of a batch that do not
 meet, the gauge and the shapes refused; what a differentiated forward hands
-on (the states and each chunk's inverse) and the three cases of a hand-over
-to a walk (``ops/pallas/flash_attention.py:KeptForward``)."""
+on (the states and each chunk's inverse), a grid step's two inverses side by
+side against each alone, an odd head count, and the three cases of a
+hand-over to a walk (``ops/pallas/flash_attention.py:KeptForward``)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from kernel_checks import kernel_counts, rel_l2
+from kernel_checks import inverses_side_by_side, kernel_counts, rel_l2
 
 from benchmark.reference import kimi_linear as ref
 from tepdist_tpu.ops.pallas import flash_attention as fa
@@ -187,6 +188,41 @@ def test_the_handed_inverse_is_the_inverse_of_the_chunks_system():
     np.testing.assert_allclose(inv[0], np.asarray(doubled), atol=2e-6)
     np.testing.assert_array_equal(inv[0, 2, :, T - 2 * C:],
                                   np.broadcast_to(eye[T - 2 * C:], (H, 8, C)))
+
+
+def _systems(C, dtype):
+    """Two heads' ``A`` [C, C] of one chunk of ``C`` tokens, by the
+    definition, from operands of ``dtype``'s values."""
+    _, k, _, g, beta, _ = inputs(1, C, 2, 16, seed=21, dtype=dtype)
+    k = k[0].astype(jnp.float32).reshape(C, 2, 16).transpose(1, 0, 2)
+    G = jnp.cumsum(g[0].reshape(C, 2, 16).transpose(1, 0, 2), axis=1)
+    i, j = kda._ij(C)
+    diff = jnp.where((j < i)[..., None], G[:, :, None] - G[:, None], -jnp.inf)
+    A = jnp.einsum("hic,hjc,hijc->hij", k, k, jnp.exp(diff))
+    return list(A * beta[0].T[..., None])
+
+
+# Chunks of 16 and of the cell's 128 in float32, and the cell's as the chip
+# runs it: operands of bf16 values, a product inside the inverse three bf16
+# passes.
+@pytest.mark.parametrize("C,narrow", [(16, False), (128, False), (128, True)])
+def test_a_grid_steps_inverses_side_by_side_are_each_alone(C, narrow):
+    inverses_side_by_side(
+        kda._inverse, _systems(C, jnp.bfloat16 if narrow else jnp.float32),
+        narrow)
+
+
+# The forward's sweep takes the heads two a grid step; an odd count's one a
+# step, as the backward's does.
+@pytest.mark.parametrize("H", [1, 3])
+def test_an_odd_head_count_runs_a_head_a_grid_step(H):
+    x = inputs(1, 40, H, 16, seed=17)
+    want = out_and_gradients(recurrence, x)
+    got = kernels(16)(*x)
+    assert max(distances(got, want).values()) < 3e-6, distances(got, want)
+    o, states, inv = kda.forward(*x[:5], chunk=16, states=True)
+    assert states.shape == inv.shape == (1, 3, H, 16, 16)
+    _equal(kda.backward(*x, kept=(states, inv), chunk=16), got[1:])
 
 
 def test_the_three_cases_of_a_hand_over_are_one_call():

@@ -15,7 +15,12 @@ distance of the output and the five gradients (asked for in float32) from
 the token-by-token float32 recurrence of ``benchmark/reference/qwen3_next.py``,
 from the chunked ``jax.numpy`` form (``gdn_attention.chunked``) and from that
 broadcast call. ``--state-dtype bf16`` reads the same with the carried state
-rounded to bf16 (a control: what a narrower carry costs).
+rounded to bf16 (a control: what a narrower carry costs). ``--impl`` times
+another copy of ``gdn_attention.py`` in the same process as
+``tools/kda_bench.py`` does (a file, or a directory that holds it, a
+``_delta_rule.py`` or both: ``kda_bench.load_impl``), prints its six
+distances from the recurrence too and says whether its output and five
+gradients are the checkout's bit for bit.
 
 Operands as a Gated-DeltaNet layer hands them over: ``q`` and ``k`` unit L2
 norm a key head, ``q`` over ``sqrt(K)``, ``v`` a unit-variance projection,
@@ -29,6 +34,7 @@ this; there is no CPU fallback: without a TPU it exits 2.
 
 Run: chiprun -- python tools/gdn_bench.py [--tokens 8192] [--chunk 64,128]
      [--state-dtype f32] [--decay-scale 1] [--check 1] [--kda 1]
+     [--impl parent=path/to/gdn_attention.py | a directory that holds it]
 """
 
 from __future__ import annotations
@@ -108,18 +114,15 @@ def _kernels(summary, is_mine, parse, cost, roofline, peaks):
     return out
 
 
-def variants(args, peaks, trace_root):
+def variants(args, peaks, trace_root, impls):
+    """``impls``: ``(label, module)`` of each copy of ``gdn_attention.py``
+    to time, the checkout's own first."""
     import jax
     import jax.numpy as jnp
 
-    from benchmark.kernels import gdn_cost, kda_cost
     from benchmark.kernels.ssm_check import rel_l2
-    from benchmark.layer_metrics import _gdn, _kda
     from benchmark.reference import qwen3_next as ref
-    from tepdist_tpu.ops.pallas import gdn_attention as gdn
-    from tepdist_tpu.ops.pallas.kda_attention import kda_attention
     from tools.kda_bench import _out_and_gradients
-    from tools.sala_bench import _traced
 
     Hk, Hv, K, T = args.key_heads, args.value_heads, args.head_dim, \
         args.tokens
@@ -127,6 +130,7 @@ def variants(args, peaks, trace_root):
                          args.decay_scale)
     want = chunked = None
     if args.check:
+        gdn = impls[0][1]
         with jax.default_matmul_precision("highest"):
             want = _out_and_gradients(
                 lambda q, k, v, g, b: ref.recurrence(
@@ -138,57 +142,89 @@ def variants(args, peaks, trace_root):
         yield {"what": "chunked jax.numpy form against the recurrence",
                "rel_l2": {n: rel_l2(c, w)
                           for n, c, w in zip(NAMES, chunked, want)}}
-
-    def grad_of(fn):
-        @jax.jit
-        def grad(q, k, v, g, beta, do):
-            out, vjp = jax.vjp(fn, q, k, v, g, beta)
-            return (out,) + vjp(do)
-        return grad
-
     for chunk in (int(c) for c in args.chunk.split(",")):
-        record = {"what": "gdn", "chunk": chunk, "tokens": T,
-                  "key_heads": Hk, "value_heads": Hv,
-                  "state_dtype": args.state_dtype, "iters": args.iters,
-                  "decay_scale": args.decay_scale}
-        try:
+        first = None
+        for label, module in impls:
+            record, got = _time_impl(label, module, chunk, inputs, want,
+                                     chunked, first, args, peaks, trace_root)
+            first = got if first is None else first
+            yield record
+
+
+def _grad_of(fn):
+    import jax
+
+    @jax.jit
+    def grad(q, k, v, g, beta, do):
+        out, vjp = jax.vjp(fn, q, k, v, g, beta)
+        return (out,) + vjp(do)
+    return grad
+
+
+def _time_impl(label, gdn, chunk, inputs, want, chunked, first, args, peaks,
+               trace_root):
+    """One copy's kernels at one chunk: ``(record, the output and five
+    gradients of its differentiated call)``. ``want``, ``chunked``: the two
+    float32 references' results, or None; ``first``: the results of the
+    first copy timed at this chunk, or None (this copy is the first: the
+    broadcast call of ``tepdist_kda_*`` is timed beside it)."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.kernels import gdn_cost, kda_cost
+    from benchmark.kernels.ssm_check import rel_l2
+    from benchmark.layer_metrics import _gdn, _kda
+    from tepdist_tpu.ops.pallas.kda_attention import kda_attention
+    from tools.sala_bench import _traced
+
+    record = {"what": "gdn", "impl": label, "chunk": chunk,
+              "tokens": args.tokens, "key_heads": args.key_heads,
+              "value_heads": args.value_heads,
+              "state_dtype": args.state_dtype, "iters": args.iters,
+              "decay_scale": args.decay_scale}
+    got = None
+    try:
+        if want is not None:
             how = dict(chunk=chunk, out_dtype=jnp.float32, state_dtype={
                 "f32": None, "bf16": jnp.bfloat16}[args.state_dtype])
             alone = jax.block_until_ready(jax.jit(
                 lambda *x: (gdn.forward(*x[:5], **how),)
                 + gdn.backward(*x, **how))(*inputs))
-            if want is not None:
-                record["rel_l2_vs_recurrence_f32"] = {
-                    n: rel_l2(a, w) for n, a, w in zip(NAMES, alone, want)}
-                record["rel_l2_vs_chunked_f32"] = {
-                    n: rel_l2(a, w) for n, a, w in zip(NAMES, alone, chunked)}
-            grad = grad_of(lambda *a: gdn.gdn_attention(*a, chunk=chunk))
-            got = jax.block_until_ready(grad(*inputs))
-            summary = _traced(f"gdn-{chunk}", lambda: grad(*inputs),
+            record["rel_l2_vs_recurrence_f32"] = {
+                n: rel_l2(a, w) for n, a, w in zip(NAMES, alone, want)}
+            record["rel_l2_vs_chunked_f32"] = {
+                n: rel_l2(a, w) for n, a, w in zip(NAMES, alone, chunked)}
+        grad = _grad_of(lambda *a: gdn.gdn_attention(*a, chunk=chunk))
+        got = jax.block_until_ready(grad(*inputs))
+        if first is not None:
+            record["same_bits_as_first"] = {
+                n: bool(jnp.array_equal(a, f))
+                for n, a, f in zip(NAMES, got, first)}
+        summary = _traced(f"gdn-{label}-{chunk}", lambda: grad(*inputs),
+                          args.iters, trace_root)
+        record["kernels"] = _kernels(
+            summary, _gdn.is_gdn, _gdn.parse, _gdn.call_cost,
+            gdn_cost.roofline_seconds, peaks)
+        record["other_device_us_per_iter"] = 1e6 * sum(
+            s for _, s, _ in summary.ops(
+                lambda t: not _gdn.is_gdn(t))) / args.iters
+        if args.kda and first is None:
+            wide = _grad_of(broadcast(kda_attention, args.key_heads, chunk))
+            through = jax.block_until_ready(wide(*inputs))
+            record["rel_l2_vs_broadcast_kda_bf16"] = {
+                n: rel_l2(a, w) for n, a, w in zip(NAMES, got, through)}
+            summary = _traced(f"kda-{chunk}", lambda: wide(*inputs),
                               args.iters, trace_root)
-            record["kernels"] = _kernels(
-                summary, _gdn.is_gdn, _gdn.parse, _gdn.call_cost,
-                gdn_cost.roofline_seconds, peaks)
-            record["other_device_us_per_iter"] = 1e6 * sum(
+            record["broadcast_kda_kernels"] = _kernels(
+                summary, _kda.is_kda, _kda.parse, _kda.call_cost,
+                kda_cost.roofline_seconds, peaks)
+            record["broadcast_other_device_us_per_iter"] = 1e6 * sum(
                 s for _, s, _ in summary.ops(
-                    lambda t: not _gdn.is_gdn(t))) / args.iters
-            if args.kda:
-                wide = grad_of(broadcast(kda_attention, Hk, chunk))
-                through = jax.block_until_ready(wide(*inputs))
-                record["rel_l2_vs_broadcast_kda_bf16"] = {
-                    n: rel_l2(a, w) for n, a, w in zip(NAMES, got, through)}
-                summary = _traced(f"kda-{chunk}", lambda: wide(*inputs),
-                                  args.iters, trace_root)
-                record["broadcast_kda_kernels"] = _kernels(
-                    summary, _kda.is_kda, _kda.parse, _kda.call_cost,
-                    kda_cost.roofline_seconds, peaks)
-                record["broadcast_other_device_us_per_iter"] = 1e6 * sum(
-                    s for _, s, _ in summary.ops(
-                        lambda t: not _kda.is_kda(t))) / args.iters
-        except Exception as e:  # noqa: BLE001 — one refused variant must
-            # not cost the call that times the others
-            record["error"] = repr(e)[:2000]
-        yield record
+                    lambda t: not _kda.is_kda(t))) / args.iters
+    except Exception as e:  # noqa: BLE001 — one refused variant must not
+        # cost the call that times the others
+        record["error"] = repr(e)[:2000]
+    return record, got
 
 
 def main(argv=None) -> int:
@@ -208,17 +244,28 @@ def main(argv=None) -> int:
     ap.add_argument("--kda", type=int, default=1,
                     help="0 skips the broadcast call of tepdist_kda_*")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--impl", action="append", default=[],
+                    metavar="LABEL=PATH",
+                    help="another gdn_attention.py, or a directory that "
+                         "holds one or a _delta_rule.py or both, to time "
+                         "beside the checkout's own and compare with it bit "
+                         "for bit (repeatable)")
     ap.add_argument("--out", default=None, help="also write the records "
                     "as JSON lines to this file")
     args = ap.parse_args(argv)
 
     from benchmark.lib import device
+    from tools.kda_bench import load_impl
 
     devices = device.own_chips(1)
     peaks = device.peaks_for(devices[0].device_kind,
                              os.path.join(ROOT, "benchmark"))
     trace_root = os.path.join(ROOT, ".bench_trace", "gdn_bench")
-    for record in variants(args, peaks, trace_root):
+    impls = [("tree", os.path.join(ROOT, "tepdist_tpu", "ops", "pallas"))]
+    impls += [tuple(item.partition("=")[::2]) for item in args.impl]
+    impls = [(label, load_impl(label, path, "gdn_attention.py"))
+             for label, path in impls]
+    for record in variants(args, peaks, trace_root, impls):
         record["device"] = devices[0].device_kind
         line = json.dumps(record)
         print(line, flush=True)

@@ -14,10 +14,13 @@ float32) from the token-by-token float32 recurrence of
 what the kernels replace); ``--state-dtype bf16`` reads the same with the
 carried state rounded to bf16 (a control: what a narrower carry costs).
 ``--impl`` times another copy of ``kda_attention.py`` in the same process,
-beside the checkout's own (a parent's, whose forward writes the states alone
-and whose backward makes the inverse again: the two halves of PR 53 apart),
-and says whether its output and five gradients are the checkout's bit for
-bit.
+beside the checkout's own (a parent's), prints its six distances from the
+recurrence too and says whether its output and five gradients are the
+checkout's bit for bit. A ``_delta_rule.py`` beside the copy is the copy's
+(:func:`load_impl`), so ``--impl parent=dir/`` times a parent's kernel file
+on the parent's inverse, and a directory that holds a ``_delta_rule.py``
+alone the checkout's kernel file on that one (PR 55: the lockstep with four
+passes a product beside the three).
 
 Operands as a KDA layer hands them over: ``q`` and ``k`` unit L2 norm a
 head, ``q`` over ``sqrt(K)``, ``v`` a unit-variance projection, ``g = -exp(A)
@@ -31,7 +34,7 @@ no CPU fallback: without a TPU it exits 2.
 
 Run: chiprun -- python tools/kda_bench.py [--tokens 8192] [--chunk 64,128]
      [--state-dtype f32] [--decay-scale 1] [--check 1]
-     [--impl parent=path/to/kda_attention.py]
+     [--impl parent=path/to/kda_attention.py | a directory that holds it]
 """
 
 from __future__ import annotations
@@ -45,6 +48,30 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 NAMES = ("out", "dq", "dk", "dv", "dg", "dbeta")
+
+
+def load_impl(label: str, path: str, kernel: str = "kda_attention.py"):
+    """A copy of the kernel file ``kernel`` as a module of its own: ``path``
+    the file or a directory that holds it (one without it: the checkout's
+    file). A ``_delta_rule.py`` in that directory stands in the copy's
+    imports where the checkout's would."""
+    import importlib
+
+    from tools.flash_bench import load_impl as load_file
+    shared = "tepdist_tpu.ops.pallas._delta_rule"
+    mine = importlib.import_module(shared)
+    folder = path if os.path.isdir(path) else os.path.dirname(path)
+    file = os.path.join(path, kernel) if os.path.isdir(path) else path
+    if not os.path.exists(file):
+        file = os.path.join(os.path.dirname(mine.__file__), kernel)
+    beside = os.path.join(folder, "_delta_rule.py")
+    try:
+        if os.path.exists(beside) \
+                and not os.path.samefile(beside, mine.__file__):
+            sys.modules[shared] = load_file(label + "_delta_rule", beside)
+        return load_file(label + "_" + kernel[:-3], file)
+    finally:
+        sys.modules[shared] = mine
 
 
 def make_inputs(T: int, H: int, K: int, dtype, seed: int, decay_scale=1.0):
@@ -231,17 +258,16 @@ def main(argv=None) -> int:
                     help="0 skips the float32 references")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--impl", action="append", default=[],
-                    metavar="LABEL=FILE",
-                    help="another kda_attention.py to time beside the "
-                         "checkout's own and compare with it bit for bit "
-                         "(repeatable)")
+                    metavar="LABEL=PATH",
+                    help="another kda_attention.py, or a directory that "
+                         "holds one or a _delta_rule.py or both, to time "
+                         "beside the checkout's own and compare with it bit "
+                         "for bit (repeatable)")
     ap.add_argument("--out", default=None, help="also write the records "
                     "as JSON lines to this file")
     args = ap.parse_args(argv)
 
     from benchmark.lib import device
-    from tools.flash_bench import load_impl
-
     devices = device.own_chips(1)
     peaks = device.peaks_for(devices[0].device_kind,
                              os.path.join(ROOT, "benchmark"))
