@@ -45,28 +45,34 @@ def mesh8(devices):
     return topo.to_jax_mesh(devices)
 
 
-# Under ``--dist loadfile`` a file is one worker's chain, and files are dealt
-# out in the order they are collected, by name: the long files late in the
-# alphabet (the described-chip compiles, three models' files) began last and
-# were the run's tail, some hundred seconds in which most workers stood idle
-# (ROADMAP D17). Longest first, the rest as collected: the same cases, every
-# worker busy to the end. By the summed case seconds of a run under six
-# workers (100 s a file and over; PR 54's run: 7,358 s of cases in all, so
-# 1,226 s a worker at best).
+# Under ``--dist loadfile`` a file is one worker's chain. pytest-xdist deals
+# the files out by their number of cases, most first, and keeps the collected
+# order among files of equal count: that is all this order decides. It puts
+# the files of two or three long cases (the walks, the described-chip
+# siblings) ahead of the short files that have as few, which were the run's
+# tail (ROADMAP D17). Turning xdist's re-sorting off so that this were the
+# whole order was tried at PR 56 and bought nothing: 1,376 s against 1,364
+# the same hour. By the summed case seconds of a run under six workers (60 s
+# a file and over; PR 56's runs: 5,539 s of cases on a quiet hour, 923 s a
+# worker at best, and 7,267 s on a loaded one).
 _LONGEST_FIRST = (
     "test_sarvam_mla.py", "test_tpu_compile.py", "test_afmoe.py",
-    "test_minicpm_sala.py", "test_mellum.py", "test_stack_in_place.py",
-    "test_jaxpr_serde.py", "test_kimi_linear.py", "test_jamba.py",
-    "test_kimi_linear_walk.py", "test_sequence_parallel.py", "test_models.py",
-    "test_zaya_walk.py", "test_olmoe.py", "test_multiworker.py",
-    "test_evaluator_measured.py", "test_collective_pipeline.py",
-    "test_qwen3_next.py", "test_gdn_attention.py", "test_zaya.py",
-    "test_attn_kept.py", "test_qwen3_next_walk.py", "test_kda_attention.py",
-    "test_serving_paged.py", "test_serving_chaos.py", "test_ga_fused.py",
-    "test_tpu_compile_qwen3_next.py",
+    "test_minicpm_sala.py", "test_mellum.py", "test_jamba.py",
+    "test_stack_in_place.py", "test_kimi_linear.py",
+    "test_kimi_linear_walk.py", "test_kda_attention.py",
+    "test_multiworker.py", "test_qwen3_next.py", "test_zaya.py",
+    "test_olmoe.py", "test_qwen3_next_walk.py", "test_models.py",
+    "test_attn_kept.py", "test_gdn_attention.py", "test_serving_chaos.py",
+    "test_sequence_parallel.py", "test_zaya_walk.py", "test_ga_fused.py",
+    "test_serving_fleet.py", "test_serving_paged.py",
+    "test_tpu_compile_qwen3_next.py", "test_rpc_explore.py",
+    "test_selective_scan.py", "test_rows_sum.py", "test_tpu_compile_kimi.py",
+    "test_evaluator_measured.py", "test_causal_conv.py",
+    "test_subgraph_dp.py",
 )
 
 
 def pytest_collection_modifyitems(items):
     rank = {name: i for i, name in enumerate(_LONGEST_FIRST)}
     items.sort(key=lambda item: rank.get(item.path.name, len(rank)))
+

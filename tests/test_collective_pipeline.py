@@ -33,11 +33,28 @@ def _setup(S=4, M=8, mb=4, d=32, seed=0):
     return stacked, x
 
 
+# Each side of a comparison is one ``jit``: called bare, ``jax.grad`` of a
+# pipelined loss dispatches (and compiles) the shard_map's scan and every
+# primitive round it one at a time, which was most of this file's seconds.
+@jax.jit
+def _sequential(stacked, x):
+    return sequential_reference(_stage_fn, stacked, x)
+
+
+@jax.jit
+def _sequential_grad(stacked, x):
+    return jax.grad(lambda p: (_sequential(p, x) ** 2).mean())(stacked)
+
+
+def _pipelined_grad(pipelined, params, x):
+    return jax.jit(jax.grad(lambda p: (pipelined(p, x) ** 2).mean()))(params)
+
+
 def test_pipeline_matches_sequential(stage_mesh):
     stacked, x = _setup()
     pipelined = collective_pipeline(_stage_fn, stage_mesh)
-    got = pipelined(stacked, x)
-    ref = sequential_reference(_stage_fn, stacked, x)
+    got = jax.jit(pipelined)(stacked, x)
+    ref = _sequential(stacked, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-6)
 
@@ -57,14 +74,8 @@ def test_pipeline_gradients_match(stage_mesh):
     stacked, x = _setup(M=4)
     pipelined = collective_pipeline(_stage_fn, stage_mesh)
 
-    def loss_pipe(p):
-        return (pipelined(p, x) ** 2).mean()
-
-    def loss_ref(p):
-        return (sequential_reference(_stage_fn, p, x) ** 2).mean()
-
-    g1 = jax.grad(loss_pipe)(stacked)
-    g2 = jax.grad(loss_ref)(stacked)
+    g1 = _pipelined_grad(pipelined, stacked, x)
+    g2 = _sequential_grad(stacked, x)
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6),
@@ -114,9 +125,9 @@ def test_gpt2_collective_pipeline_matches_dense(stage_mesh):
 
     embed, stacked = gpt2.shard_stacked_for_stages(params, cfg, stage_mesh)
 
-    ref = gpt2.loss_fn(params, tokens, cfg)
-    got = gpt2.pipelined_loss_fn(embed, stacked, tokens, cfg, stage_mesh,
-                                 num_micro=4)
+    ref = jax.jit(lambda p: gpt2.loss_fn(p, tokens, cfg))(params)
+    got = jax.jit(lambda e, b: gpt2.pipelined_loss_fn(
+        e, b, tokens, cfg, stage_mesh, num_micro=4))(embed, stacked)
     np.testing.assert_allclose(float(got), float(ref), rtol=2e-5)
 
     # One-jit training step over (embed, stacked blocks).
@@ -148,15 +159,13 @@ def test_pipeline_pp_x_dp_hybrid(devices):
                   axis_names=("stage", "data"))
     stacked, x = _setup(S=2, M=4, mb=8)
     pipelined = collective_pipeline(_stage_fn, mesh2d, data_axis="data")
-    got = pipelined(stacked, x)
-    ref = sequential_reference(_stage_fn, stacked, x)
+    got = jax.jit(pipelined)(stacked, x)
+    ref = _sequential(stacked, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-6)
     # Gradients too (the full PP x DP training path).
-    g1 = jax.grad(lambda p: (pipelined(p, x) ** 2).mean())(stacked)
-    g2 = jax.grad(
-        lambda p: (sequential_reference(_stage_fn, p, x) ** 2).mean())(
-        stacked)
+    g1 = _pipelined_grad(pipelined, stacked, x)
+    g2 = _sequential_grad(stacked, x)
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6),
@@ -179,13 +188,11 @@ def test_pipeline_pp_x_tp_hybrid(devices):
             stacked["b"], NamedSharding(mesh2d, P("stage", "model"))),
     }
     got = jax.jit(pipelined)(sharded, x)
-    ref = sequential_reference(_stage_fn, stacked, x)
+    ref = _sequential(stacked, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-6)
-    g1 = jax.grad(lambda p: (pipelined(p, x) ** 2).mean())(sharded)
-    g2 = jax.grad(
-        lambda p: (sequential_reference(_stage_fn, p, x) ** 2).mean())(
-        stacked)
+    g1 = _pipelined_grad(pipelined, sharded, x)
+    g2 = _sequential_grad(stacked, x)
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6),
@@ -208,13 +215,11 @@ def test_pipeline_pp_x_dp_x_tp_hybrid(devices):
             stacked["b"], NamedSharding(mesh3d, P("stage", "model"))),
     }
     got = jax.jit(pipelined)(sharded, x)
-    ref = sequential_reference(_stage_fn, stacked, x)
+    ref = _sequential(stacked, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-6)
-    g1 = jax.grad(lambda p: (pipelined(p, x) ** 2).mean())(sharded)
-    g2 = jax.grad(
-        lambda p: (sequential_reference(_stage_fn, p, x) ** 2).mean())(
-        stacked)
+    g1 = _pipelined_grad(pipelined, sharded, x)
+    g2 = _sequential_grad(stacked, x)
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6),
@@ -243,16 +248,16 @@ def test_gpt2_collective_pipeline_pp_x_tp_matches_dense(devices):
     l = jax.jit(lambda e, b, t: gpt2.pipelined_loss_fn(
         e, b, t, cfg, mesh, num_micro=2, model_axis="model"))(
         embed, stacked, tokens)
-    dense = gpt2.loss_fn(params, tokens, cfg)
+    dense = jax.jit(lambda p: gpt2.loss_fn(p, tokens, cfg))(params)
     np.testing.assert_allclose(float(l), float(dense), rtol=2e-5)
 
     # Gradients through the PP x TP pipeline equal the DENSE gradients
     # mapped onto the stacked [S, L/S, ...] layout (a wrong psum factor
     # on any sharded leaf would show here).
-    g = jax.grad(lambda b: gpt2.pipelined_loss_fn(
-        embed, b, tokens, cfg, mesh, num_micro=2, model_axis="model"))(
+    g = jax.jit(jax.grad(lambda b: gpt2.pipelined_loss_fn(
+        embed, b, tokens, cfg, mesh, num_micro=2, model_axis="model")))(
         stacked)
-    gd = jax.grad(lambda p: gpt2.loss_fn(p, tokens, cfg))(params)
+    gd = jax.jit(jax.grad(lambda p: gpt2.loss_fn(p, tokens, cfg)))(params)
     S = 2
     for k, gs in g.items():
         dense_stack = np.stack(

@@ -1,7 +1,10 @@
 """Measured validation of the exploration ranking (VERDICT r1 item 3 /
-r2 next #7): the Evaluator's analytic cost must agree with REAL step times
-on the CPU mesh for plans it is asked to rank — specifically on the
-property exploration actually consumes, the argmin.
+r2 next #7): the Evaluator's analytic cost must agree with the work the
+plans' compiled executables do on the CPU mesh, for plans it is asked to
+rank — specifically on the property exploration actually consumes, the
+argmin. No case reads a clock: the suite's workers share their machine, and
+a step of 50 ms alone read 255-382 ms beside five others, in the order of
+the load and not of the plans (``_compiled_work`` below is the ruler).
 
 Three genuinely different single-axis plans of the same training step
 (annotation-forced, so the cost planner cannot collapse them into one):
@@ -26,9 +29,10 @@ full-remat pricing (evaluator.py:_hidden_gather_time/_reshard_time;
 asserted below in test_cross_axis_conflict_priced_and_loses).
 """
 
+import collections
+import functools
 import math
 import re
-import time
 
 import jax
 import jax.numpy as jnp
@@ -91,13 +95,78 @@ def _collective_bytes(hlo_text):
     return total
 
 
-def _compiled_work(step, flat):
-    """(flops, bytes moved) of one device's step, by the ruler above."""
-    compiled = step.lower(*flat).compile()
+def _work_of(compiled):
+    """(flops, bytes moved) of one device's part of a compiled executable."""
     cost = compiled.cost_analysis()
     cost = cost[0] if isinstance(cost, (list, tuple)) else cost
-    return cost["flops"], (cost["bytes accessed"]
-                           + _collective_bytes(compiled.as_text()))
+    # A program that only writes (a stage's zeroed accumulators) has no flops.
+    return cost.get("flops", 0.0), (cost["bytes accessed"]
+                                    + _collective_bytes(compiled.as_text()))
+
+
+def _compiled_work(step, flat):
+    """(flops, bytes moved) of one device's step, by the ruler above."""
+    return _work_of(step.lower(*flat).compile())
+
+
+def _seconds(work, flops_per_s, bytes_per_s):
+    flops, moved = work
+    return flops / flops_per_s + moved / bytes_per_s
+
+
+def _spmd_step_work(plan, *batch):
+    """One device's work in the one executable an SPMD training plan steps."""
+    flat_batch = [jax.device_put(v, s) for v, s in
+                  zip(jax.tree_util.tree_leaves(batch),
+                      plan._batch_shardings)]
+    return _compiled_work(plan._step_fn, [*plan._state, *flat_batch])
+
+
+def _pipeline_step_work(plan, *batch):
+    """Each device's work in one step of a pipeline training plan.
+
+    The pipeline runtime steps no single executable: it walks its schedule
+    and calls one compiled program a task (``runtime/executor.py:step``). A
+    device's account is the sum, over the tasks the schedule gives it, of the
+    task program's own work, plus its share of the bytes its SEND and RECV
+    tasks move (the wire value is split over the stage's devices). Left out:
+    the four slices of the batch and the sum of the four losses."""
+    from tepdist_tpu.runtime.task_graph import TaskType
+
+    exe = plan._exe
+    # A stage's APPLY is jitted at its first call, not compiled ahead like
+    # the other task programs: step once, then keep what the next step's
+    # calls compile to.
+    plan.step(*batch)
+    applied = {}
+    for key, fn in list(exe._apply_jit.items()):    # (stage, contributors)
+        def keep(*args, stage=key[0], fn=fn):
+            applied[stage] = fn.lower(*args).compile()
+            return fn(*args)
+        exe._apply_jit[key] = keep
+    plan.step(*batch)
+    programs = {"fwd": exe._fwd_jit, "bwd": exe._bwd_jit,
+                TaskType.GAINIT: exe._gainit, TaskType.GA: exe._ga_jit,
+                TaskType.APPLY: applied}
+    work_of = functools.cache(_work_of)     # a stage's program runs M times
+    per_device = collections.defaultdict(lambda: [0.0, 0.0])
+    for tid in exe.schedule.order:
+        node = exe.dag.node(tid)
+        kind = node.task_type
+        if kind == TaskType.COMPUTE:
+            kind = node.name[:3]
+        if kind in (TaskType.SEND, TaskType.RECV):
+            flops, moved = 0.0, node.out_bytes / len(node.device_group)
+        elif kind in programs:
+            flops, moved = work_of(programs[kind][node.stage])
+        else:
+            assert kind in (TaskType.SPLIT, TaskType.INPUT,
+                            TaskType.MERGE), node.name     # no program
+            continue
+        for device in node.device_group:
+            per_device[device][0] += flops
+            per_device[device][1] += moved
+    return [tuple(w) for w in per_device.values()]
 
 
 def test_exploration_ranking_matches_measured_argmin(devices):
@@ -141,8 +210,8 @@ def test_exploration_ranking_matches_measured_argmin(devices):
     # the evaluator's pick stands within 20% of the true best.
     eval_best = min(evals, key=lambda k: evals[k].total_duration)
     for flops_per_s, bytes_per_s in _RATES:
-        meas = {k: flops / flops_per_s + moved / bytes_per_s
-                for k, (flops, moved) in work.items()}
+        meas = {k: _seconds(w, flops_per_s, bytes_per_s)
+                for k, w in work.items()}
         said = (f"evaluator picked {eval_best}: eval="
                 f"{ {k: round(v.total_duration, 8) for k, v in evals.items()} }"
                 f" meas={ {k: round(v * 1e3, 1) for k, v in meas.items()} }")
@@ -163,21 +232,25 @@ def test_exploration_ranking_matches_measured_argmin(devices):
 
 
 @pytest.mark.parametrize("n_devices,tol", [(2, 0.25), (4, 0.25), (8, 0.15)])
-def test_explore_candidate_ranking_vs_measured(devices, n_devices, tol,
-                                               monkeypatch):
+def test_explore_candidate_ranking_vs_measured(devices, n_devices, tol):
     """VERDICT r3 ask #9: the PIPELINE-vs-SPMD exploration ranking
     (train.explore_parallelism's candidate list) validated against
-    measured CPU-mesh step times on three topologies per device count,
-    with tolerance TIGHTENING as devices grow (a wrong call costs more
-    at scale). For each n, three genuinely different candidates are
-    measured — pure dp, dp x model, and a 2-stage pipeline — and the
-    evaluator's argmin must measure within tol of the true best.
+    what the candidates' compiled programs do on the CPU mesh, on three
+    topologies per device count, with tolerance TIGHTENING as devices grow
+    (a wrong call costs more at scale). For each n, three genuinely
+    different candidates are measured — pure dp, dp x model, and a 2-stage
+    pipeline — by the first test's ruler (the two SPMD candidates step one
+    executable each: ``_spmd_step_work``; the pipeline one a task:
+    ``_pipeline_step_work``), and the evaluator's argmin must measure
+    within tol of the true best at every pair of ``_RATES``.
 
-    n=4 carries the n=2 tolerance: dp and data2xmodel2 measure ~20%
-    apart on the 1-core CPU mesh and the gap flaps with host load
-    (observed both ways across rounds) — 25% keeps the bar meaningful
-    (a catastrophic misranking still fails) without pinning a
-    knife-edge."""
+    n=4 carries the n=2 tolerance: on a clock dp and data2xmodel2 read
+    ~20% apart and the gap flapped with host load (both ways across
+    rounds). By the ruler it does not flap: data2xmodel2 moves a third
+    fewer bytes than dp at 1% fewer flops, the evaluator picks dp, and
+    where bytes weigh (the two rates of 0.05 and 0.005 bytes a flop) dp
+    stands 29% and 44% over it: the case fails, and ROADMAP D6 has the
+    readings. The tolerance is the clock's and stays."""
     if len(devices) < n_devices:
         pytest.skip(f"needs {n_devices} devices")
     from tepdist_tpu.core.service_env import ServiceEnv
@@ -232,7 +305,8 @@ def test_explore_candidate_ranking_vs_measured(devices, n_devices, tol,
         chosen["pipe"] = c
     assert len(chosen) >= 3, f"missing candidates: {sorted(chosen)}"
 
-    def measure(c):
+    def devices_work(c):
+        """Each device's (flops, bytes moved) of one step of candidate c."""
         import numpy as _np
         fresh = jax.tree_util.tree_map(_np.array, params)
         if c["kind"] == "spmd":
@@ -240,36 +314,25 @@ def test_explore_candidate_ranking_vs_measured(devices, n_devices, tol,
                                  topology=c["topology"],
                                  num_micro_batches=1,
                                  devices=devices[:n_devices])
-        else:
-            plan = plan_training(loss, tx, fresh, tokens,
-                                 num_stages=c["num_stages"],
-                                 num_micro_batches=c["num_micro_batches"],
-                                 intra_stage_tp=c.get("intra_tp", 1),
-                                 devices=devices[:n_devices])
-        for _ in range(2):
-            plan.step(tokens)
-        best_t = None
-        for _ in range(2):
-            t0 = time.perf_counter()
-            for _ in range(3):
-                plan.step(tokens)
-            dt = (time.perf_counter() - t0) / 3
-            best_t = dt if best_t is None else min(best_t, dt)
-        return best_t
+            return [_spmd_step_work(plan, tokens)]
+        plan = plan_training(loss, tx, fresh, tokens,
+                             num_stages=c["num_stages"],
+                             num_micro_batches=c["num_micro_batches"],
+                             intra_stage_tp=c.get("intra_tp", 1),
+                             devices=devices[:n_devices])
+        return _pipeline_step_work(plan, tokens)
 
-    meas = {k: measure(c) for k, c in chosen.items()}
+    work = {k: devices_work(c) for k, c in chosen.items()}
     evals = {k: c["cost"].total_duration for k, c in chosen.items()}
     eval_best = min(evals, key=evals.get)
-    meas_best = min(meas.values())
-    if meas[eval_best] > (1.0 + tol) * meas_best:
-        # Transient host load can skew ms-scale CPU timings; one fresh
-        # round, keeping each candidate's best, before judging.
-        meas = {k: min(meas[k], measure(c)) for k, c in chosen.items()}
-        meas_best = min(meas.values())
-    assert meas[eval_best] <= (1.0 + tol) * meas_best, (
-        f"n={n_devices}: evaluator picked {eval_best}; "
-        f"eval={ {k: round(v, 6) for k, v in evals.items()} } "
-        f"meas={ {k: round(v * 1e3, 1) for k, v in meas.items()} }")
+    for rates in _RATES:
+        # A step lasts as long as its busiest device works.
+        meas = {k: max(_seconds(w, *rates) for w in per_device)
+                for k, per_device in work.items()}
+        assert meas[eval_best] <= (1.0 + tol) * min(meas.values()), (
+            f"n={n_devices} at {rates}: evaluator picked {eval_best}; "
+            f"eval={ {k: round(v, 6) for k, v in evals.items()} } "
+            f"meas={ {k: round(v * 1e3, 1) for k, v in meas.items()} }")
     # The analytic costs must discriminate across the candidate kinds
     # (the r2 degenerate state priced ALL candidates identically). The
     # bar is non-collapse, not a fixed spread: r5's balanced stage cuts +
@@ -282,7 +345,9 @@ def test_cross_axis_conflict_priced_and_loses(devices):
     """VERDICT r4 #6: a hybrid plan with a cross-axis produced/demanded
     conflict — h produced col-split on axis y (w1 pinned y-col) while its
     consumer's split lives on axis x (w2 pinned x-col) — must price ABOVE
-    the clean plan and lose the measured argmin at n=8. The pricing comes
+    the clean plan and lose the measured argmin at n=8 (measured by the
+    first test's ruler: the conflicted executable does more work at every
+    pair of ``_RATES``). The pricing comes
     from the r5 machinery: the y-gather of h is charged (hidden-gather
     pass / the planner's own comm objective, which the pass floors), and
     entangled partition-dim changes upgrade to full-remat pricing.
@@ -334,7 +399,9 @@ def test_cross_axis_conflict_priced_and_loses(devices):
     # measured step is comm-dominated).
     assert costs["conflict"].coll_ratio > 0.3
 
-    # And the measurement agrees: the conflict plan loses.
+    # And the compiled executables agree: the conflict plan loses. By the
+    # first test's ruler, not a clock (two steps of 5-12 ms on a machine
+    # that six test workers share read 11.6 ms clean against 4.7 conflicted).
     tx = optax.sgd(0.01)
     opt_state = tx.init(params)
 
@@ -344,37 +411,23 @@ def test_cross_axis_conflict_priced_and_loses(devices):
         return l, optax.apply_updates(params, u), opt_state
 
     n_state = len(jax.tree_util.tree_leaves((params, opt_state)))
-    meas = {}
+    work = {}
     for name, ann in [("conflict", conflict), ("clean", clean)]:
         plan = auto_parallel(train_step, topo, params, opt_state, x, y,
                              annotations=ann,
                              state_alias={1 + i: i
                                           for i in range(n_state)})
-        step = plan.executable()
         flat, _ = jax.tree_util.tree_flatten(
             ((params, opt_state, x, y), {}))
         flat = [jax.device_put(v, s)
                 for v, s in zip(flat, plan.input_shardings())]
-
-        def thread(flat, outs):
-            n = len(outs) - 1
-            return list(outs[1:]) + flat[n:]
-
-        for _ in range(2):
-            outs = step(*flat)
-            float(jax.device_get(outs[0]))
-            flat = thread(flat, outs)
-        best = None
-        for _ in range(3):
-            t0 = time.perf_counter()
-            for _ in range(10):
-                outs = step(*flat)
-                flat = thread(flat, outs)
-            float(jax.device_get(outs[0]))
-            dt = (time.perf_counter() - t0) / 10
-            best = dt if best is None else min(best, dt)
-        meas[name] = best
-    assert meas["conflict"] > meas["clean"], meas
+        work[name] = _compiled_work(plan.executable(), flat)
+    for rates in _RATES:
+        meas = {k: _seconds(w, *rates) for k, w in work.items()}
+        assert meas["conflict"] > meas["clean"], (
+            f"at {rates}: meas="
+            f"{ {k: round(v * 1e3, 4) for k, v in meas.items()} } "
+            f"work={work}")
 
 
 def test_lowering_diagnostics_see_involuntary_remat(devices):

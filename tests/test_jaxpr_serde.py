@@ -17,20 +17,24 @@ from tepdist_tpu.rpc.jaxpr_serde import (
 )
 
 
+def _run(closed, *flat):
+    """The outputs of a closed jaxpr, the whole of it compiled once. Evaluated
+    bare (``eval_jaxpr``, ``jaxpr_as_fun``) a jaxpr compiles and dispatches
+    one equation at a time, every primitive inside a ``shard_map`` and its
+    scan by itself: 76 s of ``test_shard_map_round_trip`` were that."""
+    return jax.jit(jexcore.jaxpr_as_fun(closed))(*flat)
+
+
 def _round_trip_eval(fn, *args):
     closed = jax.make_jaxpr(fn)(*args)
     data = serialize_closed_jaxpr(closed)
     back = deserialize_closed_jaxpr(data)
     flat = jax.tree_util.tree_leaves(args)
-    expected = jax.core.eval_jaxpr if False else None
-    # Evaluate both through the interpreter path.
-    from jax.extend.core import jaxpr_as_fun
-
-    out_ref = jaxpr_as_fun(jexcore.ClosedJaxpr(
+    out_ref = _run(jexcore.ClosedJaxpr(
         __import__("tepdist_tpu.graph.jaxpr_graph",
                    fromlist=["inline_calls"]).inline_calls(closed.jaxpr),
-        closed.consts))(*flat)
-    out_back = jaxpr_as_fun(back)(*flat)
+        closed.consts), *flat)
+    out_back = _run(back, *flat)
     for a, b in zip(out_ref, out_back):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-6, atol=1e-7)
@@ -269,8 +273,8 @@ def test_shard_map_round_trip(devices):
             closed = make()
             rt = deserialize_closed_jaxpr(
                 serialize_closed_jaxpr(closed, inline=False))
-            a = jax.core.eval_jaxpr(closed.jaxpr, closed.consts, q, k, v)
-            b = jax.core.eval_jaxpr(rt.jaxpr, rt.consts, q, k, v)
+            a = _run(closed, q, k, v)
+            b = _run(rt, q, k, v)
             for x, y in zip(a, b):
                 np.testing.assert_allclose(np.asarray(x), np.asarray(y),
                                            rtol=1e-5)
@@ -343,8 +347,8 @@ def test_pallas_flash_gpt2_train_step_round_trip():
     flat, _ = jax.tree_util.tree_flatten(((params, opt_state, tokens), {}))
     closed = jax.make_jaxpr(step)(params, opt_state, tokens)
     rt = deserialize_closed_jaxpr(serialize_closed_jaxpr(closed))
-    a = jax.core.eval_jaxpr(closed.jaxpr, closed.consts, *flat)
-    b = jax.core.eval_jaxpr(rt.jaxpr, rt.consts, *flat)
+    a = _run(closed, *flat)
+    b = _run(rt, *flat)
     for x, y in zip(a, b):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y),
                                    rtol=1e-5, atol=1e-6)
@@ -379,8 +383,8 @@ def test_ulysses_flash_inner_round_trip(devices):
                       (lambda: jax.make_jaxpr(jax.grad(f))(q, k, v), 1e-4)):
         closed = make()
         rt = deserialize_closed_jaxpr(serialize_closed_jaxpr(closed))
-        a = jax.core.eval_jaxpr(closed.jaxpr, closed.consts, q, k, v)
-        b = jax.core.eval_jaxpr(rt.jaxpr, rt.consts, q, k, v)
+        a = _run(closed, q, k, v)
+        b = _run(rt, q, k, v)
         for x, y in zip(a, b):
             np.testing.assert_allclose(np.asarray(x), np.asarray(y),
                                        rtol=tol, atol=1e-6)

@@ -321,10 +321,11 @@ def test_gpt2_chunked_cross_entropy_matches_dense(devices):
     params = gpt2.init_params(cfg, jax.random.PRNGKey(0))
     tokens = gpt2.fake_batch(cfg, 4, 31)
 
-    l_dense, g_dense = jax.value_and_grad(
-        lambda p: gpt2.loss_fn(p, tokens, cfg))(params)
-    l_chunk, g_chunk = jax.value_and_grad(
-        lambda p: gpt2.loss_fn(p, tokens, cfg_c))(params)
+    # Each form one jit, not an operation at a time.
+    l_dense, g_dense = jax.jit(jax.value_and_grad(
+        lambda p: gpt2.loss_fn(p, tokens, cfg)))(params)
+    l_chunk, g_chunk = jax.jit(jax.value_and_grad(
+        lambda p: gpt2.loss_fn(p, tokens, cfg_c)))(params)
     np.testing.assert_allclose(float(l_chunk), float(l_dense), rtol=1e-5)
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(
@@ -332,15 +333,15 @@ def test_gpt2_chunked_cross_entropy_matches_dense(devices):
         g_chunk, g_dense)
 
     sp = gpt2.stacked_init_params(cfg, jax.random.PRNGKey(0))
-    l_s = gpt2.loss_fn_stacked(sp, tokens, cfg)
-    l_sc = gpt2.loss_fn_stacked(sp, tokens, cfg_c)
+    l_s = jax.jit(lambda p: gpt2.loss_fn_stacked(p, tokens, cfg))(sp)
+    l_sc = jax.jit(lambda p: gpt2.loss_fn_stacked(p, tokens, cfg_c))(sp)
     np.testing.assert_allclose(float(l_sc), float(l_s), rtol=1e-5)
 
     # Non-dividing chunk: masked tail chunk, same value AND grads (120 %
     # 32 = 24 — this exercises the padded path end to end).
     cfg_nd = dataclasses.replace(cfg, loss_chunk=32)
-    l_nd, g_nd = jax.value_and_grad(
-        lambda p: gpt2.loss_fn(p, tokens, cfg_nd))(params)
+    l_nd, g_nd = jax.jit(jax.value_and_grad(
+        lambda p: gpt2.loss_fn(p, tokens, cfg_nd)))(params)
     np.testing.assert_allclose(float(l_nd), float(l_dense), rtol=1e-5)
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(
@@ -502,10 +503,10 @@ def test_llama_flash_attention_matches_einsum(devices):
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0,
                                 cfg.vocab_size)
 
-    l0, g0 = jax.value_and_grad(
-        lambda p: llama.loss_fn(p, tokens, cfg))(params)
-    l1, g1 = jax.value_and_grad(
-        lambda p: llama.loss_fn(p, tokens, cfgf))(params)
+    l0, g0 = jax.jit(jax.value_and_grad(
+        lambda p: llama.loss_fn(p, tokens, cfg)))(params)
+    l1, g1 = jax.jit(jax.value_and_grad(
+        lambda p: llama.loss_fn(p, tokens, cfgf)))(params)
     np.testing.assert_allclose(float(l0), float(l1), rtol=2e-3)
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(

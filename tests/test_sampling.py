@@ -28,8 +28,9 @@ def test_greedy_decode_matches_full_forward():
     out = jax.jit(lambda p, t: sampling.sample(
         p, t, CFG, max_new_tokens=6, greedy=True))(params, prompt)
     toks = np.asarray(prompt)
+    forward = jax.jit(lambda p, t: gpt2.forward(p, t, CFG))
     for _ in range(6):
-        logits = gpt2.forward(params, jnp.asarray(toks), CFG)
+        logits = forward(params, jnp.asarray(toks))
         nxt = np.asarray(jnp.argmax(logits[:, -1], -1)).astype(np.int32)
         toks = np.concatenate([toks, nxt[:, None]], axis=1)
     np.testing.assert_array_equal(np.asarray(out), toks)

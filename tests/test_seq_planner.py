@@ -61,8 +61,8 @@ def test_ring_rewrite_matches_dense_forward(devices):
     mesh = Mesh(np.array(devices[:4]).reshape(4), ("seq",))
     rw = build_ring_rewritten(graph, motifs, mesh, "seq")
     flat = jax.tree_util.tree_leaves(((params, toks), {}))
-    np.testing.assert_allclose(float(rw(*flat)[0]), float(loss(params, toks)),
-                               rtol=2e-5)
+    np.testing.assert_allclose(float(jax.jit(rw)(*flat)[0]),
+                               float(jax.jit(loss)(params, toks)), rtol=2e-5)
 
 
 def test_seq_plan_training_matches_dense(devices):
@@ -217,8 +217,8 @@ def test_flash_ring_rewrite_matches_dense_forward(devices):
     mesh = Mesh(np.array(devices[:4]).reshape(4), ("seq",))
     rw = build_ring_rewritten(graph, motifs, mesh, "seq")
     flat = jax.tree_util.tree_leaves(((params, toks), {}))
-    np.testing.assert_allclose(float(rw(*flat)[0]),
-                               float(loss(params, toks)), rtol=2e-5)
+    np.testing.assert_allclose(float(jax.jit(rw)(*flat)[0]),
+                               float(jax.jit(loss)(params, toks)), rtol=2e-5)
 
 
 def test_flash_seq_plan_training_matches_dense(devices):
@@ -331,6 +331,6 @@ def test_ulysses_lowering_matches_dense(devices):
         mesh = Mesh(np.array(devices[:4]).reshape(4), ("seq",))
         rw = build_ring_rewritten(graph, motifs, mesh, "seq")
         flat = jax.tree_util.tree_leaves(((params, toks), {}))
-        np.testing.assert_allclose(float(rw(*flat)[0]),
-                                   float(loss(params, toks)), rtol=2e-5,
-                                   err_msg=f"attn={attn}")
+        np.testing.assert_allclose(float(jax.jit(rw)(*flat)[0]),
+                                   float(jax.jit(loss)(params, toks)),
+                                   rtol=2e-5, err_msg=f"attn={attn}")

@@ -62,8 +62,10 @@ def test_ring_attention_grad_flows(seq_mesh):
     def loss_ref(q, k, v):
         return reference_attention(q, k, v).astype(jnp.float32).sum()
 
-    g1 = jax.grad(loss_sharded)(q, k, v)
-    g2 = jax.grad(loss_ref)(q, k, v)
+    # Each side one jit: bare, the ring's scan inside the shard_map is
+    # dispatched and compiled a primitive at a time.
+    g1 = jax.jit(jax.grad(loss_sharded))(q, k, v)
+    g2 = jax.jit(jax.grad(loss_ref))(q, k, v)
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g2),
                                rtol=1e-4, atol=1e-4)
 
@@ -80,8 +82,9 @@ def test_gpt2_with_ring_attention(devices):
     def attn_impl(q, k, v):
         return ring_attention(q, k, v, mesh, causal=True)
 
-    ref = gpt2.loss_fn(params, tokens, cfg)
-    got = gpt2.loss_fn(params, tokens, cfg, attn_impl=attn_impl)
+    ref = jax.jit(lambda p: gpt2.loss_fn(p, tokens, cfg))(params)
+    got = jax.jit(lambda p: gpt2.loss_fn(
+        p, tokens, cfg, attn_impl=attn_impl))(params)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-4)
 
@@ -386,10 +389,10 @@ def test_gpt2_flash_config_trains_like_einsum():
     cfgf = dataclasses.replace(cfg, attn="flash", remat=True)
     params = gpt2.init_params(cfg, jax.random.PRNGKey(0))
     toks = gpt2.fake_batch(cfg, 4, 32)
-    l1, g1 = jax.value_and_grad(
-        lambda p: gpt2.loss_fn(p, toks, cfg))(params)
-    l2, g2 = jax.value_and_grad(
-        lambda p: gpt2.loss_fn(p, toks, cfgf))(params)
+    l1, g1 = jax.jit(jax.value_and_grad(
+        lambda p: gpt2.loss_fn(p, toks, cfg)))(params)
+    l2, g2 = jax.jit(jax.value_and_grad(
+        lambda p: gpt2.loss_fn(p, toks, cfgf)))(params)
     np.testing.assert_allclose(float(l1), float(l2), rtol=2e-5)
     for a, b in zip(jax.tree_util.tree_leaves(g1),
                     jax.tree_util.tree_leaves(g2)):
@@ -406,6 +409,6 @@ def test_gpt2_stacked_scan_matches_unrolled():
     stacked = {k: params[k] for k in ("wte", "wpe", "ln_f_g", "ln_f_b")}
     stacked["blocks"] = gpt2.stack_block_params(params, cfg)
     toks = gpt2.fake_batch(cfg, 2, 16)
-    l1 = gpt2.loss_fn(params, toks, cfg)
-    l2 = gpt2.loss_fn_stacked(stacked, toks, cfg)
+    l1 = jax.jit(lambda p: gpt2.loss_fn(p, toks, cfg))(params)
+    l2 = jax.jit(lambda p: gpt2.loss_fn_stacked(p, toks, cfg))(stacked)
     np.testing.assert_allclose(float(l1), float(l2), rtol=1e-6)

@@ -22,6 +22,7 @@ transport (socketless, tier-1 fast). Covers the ISSUE acceptance gates:
     RPC answers with the ORIGINAL handoff list.
 """
 
+import itertools
 import time
 
 import jax
@@ -68,6 +69,22 @@ def _mix(n, seed=7, lo=3, hi=12, max_new=5):
     return prompts, [max_new] * n
 
 
+def _deal_out(sc, prompts, mnts):
+    """Submit request i with replica ``i % n`` first in its order; the ids.
+
+    ``ServeClient.submit`` draws n numbers of its round-robin counter a
+    request, so every request's order begins at replica 0 and another
+    replica sees a request only when replica 0 sheds one under load
+    (ROADMAP D19). A gate that needs every worker busy hands ``submit`` the
+    order itself: one draw or n, the order begins where the counter stands."""
+    n = len(sc.clients)
+    rids = []
+    for i, (p, m) in enumerate(zip(prompts, mnts)):
+        sc._rr = itertools.count(i % n)
+        rids.append(sc.submit(p, max_new_tokens=m)["request_id"])
+    return rids
+
+
 def _assert_matches_sample(params, prompts, mnts, results, rids):
     for p, m, rid in zip(prompts, mnts, rids):
         ref = np.asarray(sample(params, p[None], CFG, max_new_tokens=m,
@@ -80,15 +97,9 @@ def _assert_matches_sample(params, prompts, mnts, results, rids):
 # Acceptance: engine_crash + serve_fault mid-decode over RPC
 # ---------------------------------------------------------------------------
 
-@pytest.mark.xfail(
-    reason="serve_fault step counter is machine-timing sensitive: with a "
-           "fast paged pool worker 1 can drain before its 3rd decode, so "
-           "the injection-count assertion misses (exactly-once and "
-           "bit-identity assertions still execute and pass)",
-    strict=False)
 def test_serving_chaos_exactly_once_bit_identical(params):
     """THE serving chaos gate: worker 0's engine is killed at its 3rd
-    scheduler step, worker 1 takes a serve_fault on its 5th decode; the
+    scheduler step, worker 1 takes a serve_fault on its 3rd decode; the
     supervisors rebuild + replay, and every request still ends in exactly
     one "done" with tokens bit-identical to sequential sample()."""
     prompts, mnts = _mix(8, seed=7)
@@ -98,14 +109,14 @@ def test_serving_chaos_exactly_once_bit_identical(params):
     before = _counters()
     try:
         sc.load(params, CFG, slots=2, max_len=32, name="chaos")
-        # step=3 (not 5): the paged pool fits all of worker 1's requests
-        # in ONE admission wave (a page each), so its decode count per
-        # wave is lower than the slot engine's two-wave schedule.
+        # Both faults fire whatever the hour: each worker is dealt four
+        # requests of five tokens, and one such request alone takes a
+        # prefill and four decodes, so worker 0 reaches a third scheduler
+        # step and worker 1 a third decode before either can drain.
         faults.configure(
             "engine_crash:step=3,ti=0;"
             "serve_fault:op=decode,step=3,ti=1,seed=11")
-        rids = [sc.submit(p, max_new_tokens=m)["request_id"]
-                for p, m in zip(prompts, mnts)]
+        rids = _deal_out(sc, prompts, mnts)
         results = sc.wait(rids, timeout_s=300)
     finally:
         faults.configure(None)

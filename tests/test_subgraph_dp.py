@@ -101,11 +101,21 @@ def _gpt2_grad_graph():
     return fn, params, tokens
 
 
-@pytest.mark.xfail(
-    reason="ILP solve is wall-clock budgeted: under full-suite CPU "
-    "contention the whole-graph solve can time out and fall back, so "
-    "ilp_status != 'ilp'; passes in isolation", strict=False,
-    raises=AssertionError)
+def _whole_graph_plan(graph, topo):
+    """The whole-graph ILP's plan of the transformer graph, given the time
+    a loaded test worker needs to prove its optimum (3.5 s alone, 29 s
+    beside 26 busy processes on 8 cores; ``ILP_TIME_LIMIT``'s 5 s ran out
+    under the suite's own load and the solver fell back to a worse plan).
+    The solver stops at its optimum, so an idle run pays nothing for it.
+    The DP's segment solves are capped apart (0.8 s each, whatever this
+    says: ``cost_spmd_strategy.py:_solve_ilp``)."""
+    ServiceEnv.reset({"ILP_TIME_LIMIT": 120.0})
+    try:
+        return plan_axes(graph, topo)[0]
+    finally:
+        ServiceEnv.reset()
+
+
 @pytest.mark.parametrize("axes", [[("data", 8)], [("model", 8)]])
 def test_subgraph_dp_parity_on_transformer_grad_graph(axes):
     """Forced subgraph-DP (with one-segment lookahead) reproduces the
@@ -116,7 +126,7 @@ def test_subgraph_dp_parity_on_transformer_grad_graph(axes):
     topo = MeshTopology(axes)
 
     graph, _, _ = trace_graph(fn, params, tokens)
-    whole = plan_axes(graph, topo)[0]
+    whole = _whole_graph_plan(graph, topo)
     assert whole.ilp_status == "ilp"
 
     ServiceEnv.reset({"SUBGRAPH_NODES": "10"})
@@ -131,11 +141,6 @@ def test_subgraph_dp_parity_on_transformer_grad_graph(axes):
                                                 whole.total_cost)
 
 
-@pytest.mark.xfail(
-    reason="ILP solve is wall-clock budgeted: under full-suite CPU "
-    "contention the whole-graph solve can time out and fall back, so "
-    "ilp_status != 'ilp'; passes in isolation", strict=False,
-    raises=AssertionError)
 def test_subgraph_dp_beam_width_curve_on_transformer():
     """Beam-quality curve on the transformer graph, from data (recorded
     2026-07, GPT-2 4-block grad graph, data axis, with lookahead):
@@ -150,7 +155,7 @@ def test_subgraph_dp_beam_width_curve_on_transformer():
     fn, params, tokens = _gpt2_grad_graph()
     topo = MeshTopology([("data", 8)])
     graph, _, _ = trace_graph(fn, params, tokens)
-    whole = plan_axes(graph, topo)[0]
+    whole = _whole_graph_plan(graph, topo)
 
     costs = {}
     for beam in (1, 2):

@@ -498,7 +498,7 @@ def test_a_walked_block_keeps_its_flash_forward_in_the_compiled_step(
     assert f"bf16[3,1,4,{T},128]" in text
 
 
-def test_the_jamba_cells_step_compiles_for_v5e(v5e_devices, monkeypatch):
+def test_the_jamba_cells_step_compiles_for_v5e(v5e_devices):
     """``jamba2-3b.train.s8192``'s step from the cell's own files (4 micro
     batches of one 8192-token sequence; Mamba x 7, attention, Mamba x 6 as
     three walks; ``adamw_bf16``), kernels not interpreted: every walk's
@@ -508,34 +508,9 @@ def test_the_jamba_cells_step_compiles_for_v5e(v5e_devices, monkeypatch):
     whole-sequence array, the conv is in its kernels with no padded float32
     copy of its input, and the compiler's peak fits the chip, not above what
     the ``jax.numpy`` conv compiled to (14.63e9)."""
-    import json
-
-    from benchmark.lib import cells
-    from tepdist_tpu.parallel.sync_free import build_ga_step
     from tepdist_tpu.telemetry import metrics
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    bench = os.path.join(root, "benchmark")
-    with open(os.path.join(bench, "configs", "jamba2-3b.json")) as f:
-        config = json.load(f)
-    builder = cells.load_module(os.path.join(bench, "builders", "jamba.py"),
-                                "bench_builder_jamba_compile")
-    loss = builder.program_loss_fn(config)
-    tx = builder.program_optimizer(config)
-
-    def apply_fn(p, s, g):
-        updates, s = tx.update(g, s, p)
-        return optax.apply_updates(p, updates), s
-
-    one_chip = SingleDeviceSharding(v5e_devices[0])
-    params = jax.eval_shape(lambda: builder.make_params(config, 1))
-    args = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        (params, jax.eval_shape(tx.init, params),
-         jax.ShapeDtypeStruct((4, 8193), jnp.int32)))
-    step = build_ga_step(lambda p, t: jax.value_and_grad(loss)(p, t),
-                         apply_fn, 4, loss_fn=loss)
-    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
+    from tools.same_ops import compiled_step
+    compiled, params = compiled_step("jamba2-3b.train.s8192", v5e_devices[0])
 
     gauge = lambda n: metrics().gauge(n).value              # noqa: E731
     fused, unfused = gauge("ga_fused_bytes"), gauge("ga_unfused_bytes")
@@ -631,8 +606,7 @@ def test_sala_kernels_compile_for_v5e(v5e_devices):
     assert not square, square
 
 
-def test_the_minicpm_sala_cells_step_compiles_for_v5e(v5e_devices,
-                                                      monkeypatch):
+def test_the_minicpm_sala_cells_step_compiles_for_v5e(v5e_devices):
     """``minicpm-sala.train.s32768``'s step from the cell's own files (2
     micro batches of one 32,768-token sequence; a sparse layer and three
     lightning layers as two walks; ``adamw_bf16``), kernels not interpreted:
@@ -644,36 +618,11 @@ def test_the_minicpm_sala_cells_step_compiles_for_v5e(v5e_devices,
     T]`` array), no array is as wide as ``[T, intermediate]`` (the block's
     token-wise parts run in chunks), and the compiler's peak fits the
     chip."""
-    import json
-
-    from benchmark.lib import cells
-    from tepdist_tpu.parallel.sync_free import build_ga_step
     from tepdist_tpu.telemetry import metrics
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    bench = os.path.join(root, "benchmark")
-    with open(os.path.join(bench, "configs", "minicpm-sala.json")) as f:
-        config = json.load(f)
-    builder = cells.load_module(
-        os.path.join(bench, "builders", "minicpm_sala.py"),
-        "bench_builder_minicpm_sala_compile")
-    loss = builder.program_loss_fn(config)
-    tx = builder.program_optimizer(config)
-
-    def apply_fn(p, s, g):
-        updates, s = tx.update(g, s, p)
-        return optax.apply_updates(p, updates), s
-
+    from tools.same_ops import compiled_step
     T = 32768
-    one_chip = SingleDeviceSharding(v5e_devices[0])
-    params = jax.eval_shape(lambda: builder.make_params(config, 1))
-    args = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        (params, jax.eval_shape(tx.init, params),
-         jax.ShapeDtypeStruct((2, T + 1), jnp.int32)))
-    step = build_ga_step(lambda p, t: jax.value_and_grad(loss)(p, t),
-                         apply_fn, 2, loss_fn=loss)
-    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
+    compiled, params = compiled_step(
+        "minicpm-sala.train.s32768", v5e_devices[0])
 
     gauge = lambda n: metrics().gauge(n).value              # noqa: E731
     fused, unfused = gauge("ga_fused_bytes"), gauge("ga_unfused_bytes")
@@ -778,7 +727,7 @@ def test_mla_kernels_compile_for_v5e(v5e_devices):
     assert not square, square
 
 
-def test_the_sarvam_cells_step_compiles_for_v5e(v5e_devices, monkeypatch):
+def test_the_sarvam_cells_step_compiles_for_v5e(v5e_devices):
     """``sarvam-105b.train.s16384``'s step from the cell's own files (4
     micro batches of one 16,384-token sequence; a dense layer and four
     expert layers as two walks; ``adamw_bf16_router_bias``), kernels not
@@ -793,36 +742,11 @@ def test_the_sarvam_cells_step_compiles_for_v5e(v5e_devices, monkeypatch):
     the chip. The issue asked for a peak under 15.5e9 bytes: the
     accumulation scan's entry alone holds the state, the zeroed accumulators
     and the loop's own, 10 bytes a parameter, 15.05e9."""
-    import json
-
-    from benchmark.lib import cells
-    from tepdist_tpu.parallel.sync_free import build_ga_step
     from tepdist_tpu.telemetry import metrics
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    bench = os.path.join(root, "benchmark")
-    with open(os.path.join(bench, "configs", "sarvam-105b.json")) as f:
-        config = json.load(f)
-    builder = cells.load_module(
-        os.path.join(bench, "builders", "sarvam_mla.py"),
-        "bench_builder_sarvam_mla_compile")
-    loss = builder.program_loss_fn(config)
-    tx = builder.program_optimizer(config)
-
-    def apply_fn(p, s, g):
-        updates, s = tx.update(g, s, p)
-        return optax.apply_updates(p, updates), s
-
+    from tools.same_ops import compiled_step
     T = 16384
-    one_chip = SingleDeviceSharding(v5e_devices[0])
-    params = jax.eval_shape(lambda: builder.make_params(config, 1))
-    args = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        (params, jax.eval_shape(tx.init, params),
-         jax.ShapeDtypeStruct((4, T + 1), jnp.int32)))
-    step = build_ga_step(lambda p, t: jax.value_and_grad(loss)(p, t),
-                         apply_fn, 4, loss_fn=loss)
-    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
+    compiled, params = compiled_step(
+        "sarvam-105b.train.s16384", v5e_devices[0])
 
     gauge = lambda n: metrics().gauge(n).value              # noqa: E731
     n_params = sum(a.size for a in jax.tree_util.tree_leaves(params))
@@ -931,7 +855,7 @@ def test_cca_mix_kernels_compile_for_v5e(v5e_devices):
     assert not wide, wide
 
 
-def test_the_zaya_cells_step_compiles_for_v5e(v5e_devices, monkeypatch):
+def test_the_zaya_cells_step_compiles_for_v5e(v5e_devices):
     """``zaya1-8b.train.s8192``'s step from the cell's own files (8 micro
     batches of one 8,192-token sequence; five layers in one walk whose carry
     is the pair ``(x, r)``; ``adamw_bf16_router_bias``), kernels not
@@ -941,35 +865,10 @@ def test_the_zaya_cells_step_compiles_for_v5e(v5e_devices, monkeypatch):
     walked block recomputes it: ``cca_mix_calls`` 10), the top-1 layout has
     its one size of 10,240 rows, and the compiler's peak is under 13e9
     bytes."""
-    import json
-
-    from benchmark.lib import cells
-    from tepdist_tpu.parallel.sync_free import build_ga_step
     from tepdist_tpu.telemetry import metrics
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    bench = os.path.join(root, "benchmark")
-    with open(os.path.join(bench, "configs", "zaya1-8b.json")) as f:
-        config = json.load(f)
-    builder = cells.load_module(os.path.join(bench, "builders", "zaya.py"),
-                                "bench_builder_zaya_compile")
-    loss = builder.program_loss_fn(config)
-    tx = builder.program_optimizer(config)
-
-    def apply_fn(p, s, g):
-        updates, s = tx.update(g, s, p)
-        return optax.apply_updates(p, updates), s
-
+    from tools.same_ops import compiled_step
     T = 8192
-    one_chip = SingleDeviceSharding(v5e_devices[0])
-    params = jax.eval_shape(lambda: builder.make_params(config, 1))
-    args = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        (params, jax.eval_shape(tx.init, params),
-         jax.ShapeDtypeStruct((8, T + 1), jnp.int32)))
-    step = build_ga_step(lambda p, t: jax.value_and_grad(loss)(p, t),
-                         apply_fn, 8, loss_fn=loss)
-    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
+    compiled, params = compiled_step("zaya1-8b.train.s8192", v5e_devices[0])
 
     gauge = lambda n: metrics().gauge(n).value              # noqa: E731
     n_params = sum(a.size for a in jax.tree_util.tree_leaves(params))

@@ -12,7 +12,6 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
 import jax
 import jax.numpy as jnp
-import optax
 from jax.sharding import SingleDeviceSharding
 from test_tpu_compile import v5e_devices  # noqa: F401 — the fixture
 
@@ -59,8 +58,7 @@ def test_kda_kernels_compile_for_v5e(v5e_devices):
         assert not wide, wide[:2]
 
 
-def test_the_kimi_linear_cells_step_compiles_for_v5e(v5e_devices,
-                                                     monkeypatch):
+def test_the_kimi_linear_cells_step_compiles_for_v5e(v5e_devices):
     """``kimi-linear-48b-a3b.train.s8192``'s step from the cell's own files
     (8 micro batches of one 8,192-token sequence; five layers in four walks
     of unequal shape; ``adamw_bf16_router_bias``), kernels not interpreted:
@@ -69,37 +67,11 @@ def test_the_kimi_linear_cells_step_compiles_for_v5e(v5e_devices,
     batch (the walks keep ``(o, states, inv)`` and ``(o, lse)``:
     ``kda_calls`` 4), the experts' stacks are read where they lie in all
     three expert runs, and the compiler's peak is under 13e9 bytes."""
-    import json
-
-    from benchmark.lib import cells
-    from tepdist_tpu.parallel.sync_free import build_ga_step
     from tepdist_tpu.telemetry import metrics
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    bench = os.path.join(root, "benchmark")
-    with open(os.path.join(bench, "configs",
-                           "kimi-linear-48b-a3b.json")) as f:
-        config = json.load(f)
-    builder = cells.load_module(
-        os.path.join(bench, "builders", "kimi_linear.py"),
-        "bench_builder_kimi_linear_compile")
-    loss = builder.program_loss_fn(config)
-    tx = builder.program_optimizer(config)
-
-    def apply_fn(p, s, g):
-        updates, s = tx.update(g, s, p)
-        return optax.apply_updates(p, updates), s
-
+    from tools.same_ops import compiled_step
     T = 8192
-    one_chip = SingleDeviceSharding(v5e_devices[0])
-    params = jax.eval_shape(lambda: builder.make_params(config, 1))
-    args = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        (params, jax.eval_shape(tx.init, params),
-         jax.ShapeDtypeStruct((8, T + 1), jnp.int32)))
-    step = build_ga_step(lambda p, t: jax.value_and_grad(loss)(p, t),
-                         apply_fn, 8, loss_fn=loss)
-    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
+    compiled, params = compiled_step(
+        "kimi-linear-48b-a3b.train.s8192", v5e_devices[0])
 
     gauge = lambda n: metrics().gauge(n).value              # noqa: E731
     n_params = sum(a.size for a in jax.tree_util.tree_leaves(params))

@@ -5,7 +5,6 @@ v5e (no chip): ``tests/test_tpu_compile.py``'s cases for
 other than that file's takes them (the suite is dealt out a file at a time).
 A compile that passes is NOT a chip run: nothing executes here."""
 
-import json
 import os
 import re
 
@@ -13,7 +12,6 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
 import jax
 import jax.numpy as jnp
-import optax
 from jax.sharding import SingleDeviceSharding
 from test_tpu_compile import v5e_devices  # noqa: F401 — the fixture
 
@@ -88,8 +86,7 @@ def test_flash_at_a_head_width_of_256_compiles_for_v5e(v5e_devices):
     assert sum("tepdist_flash_dkv" in n for n in names) == 1, names
 
 
-def test_the_qwen3_next_cells_step_compiles_for_v5e(v5e_devices,
-                                                    monkeypatch):
+def test_the_qwen3_next_cells_step_compiles_for_v5e(v5e_devices):
     """``qwen3-next-80b-a3b.train.s8192``'s step from the cell's own files
     (8 micro batches of one 8,192-token sequence; four layers in two walks
     of unequal shape; ``adamw_bf16``), kernels not interpreted: every walk's
@@ -98,34 +95,10 @@ def test_the_qwen3_next_cells_step_compiles_for_v5e(v5e_devices,
     3) and the flash forward once, the experts' stacks are read where they
     lie, and the compiler's peak is under 15.0e9 bytes."""
     from benchmark.lib import cells
-    from tepdist_tpu.parallel.sync_free import build_ga_step
     from tepdist_tpu.telemetry import metrics
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    bench = os.path.join(root, "benchmark")
-    with open(os.path.join(bench, "configs",
-                           "qwen3-next-80b-a3b.json")) as f:
-        config = json.load(f)
-    builder = cells.load_module(
-        os.path.join(bench, "builders", "qwen3_next.py"),
-        "bench_builder_qwen3_next_compile")
-    loss = builder.program_loss_fn(config)
-    tx = builder.program_optimizer(config)
-
-    def apply_fn(p, s, g):
-        updates, s = tx.update(g, s, p)
-        return optax.apply_updates(p, updates), s
-
-    T = 8192
-    one_chip = SingleDeviceSharding(v5e_devices[0])
-    params = jax.eval_shape(lambda: builder.make_params(config, 1))
-    args = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        (params, jax.eval_shape(tx.init, params),
-         jax.ShapeDtypeStruct((8, T + 1), jnp.int32)))
-    step = build_ga_step(lambda p, t: jax.value_and_grad(loss)(p, t),
-                         apply_fn, 8, loss_fn=loss)
-    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
+    from tools.same_ops import compiled_step
+    T, cell = 8192, "qwen3-next-80b-a3b.train.s8192"
+    compiled, params = compiled_step(cell, v5e_devices[0])
     print("peak", compiled.memory_analysis().peak_memory_in_bytes)
 
     gauge = lambda n: metrics().gauge(n).value              # noqa: E731
@@ -138,7 +111,7 @@ def test_the_qwen3_next_cells_step_compiles_for_v5e(v5e_devices,
     assert gauge("gdn_calls") == 3              # kept: once a layer
     assert gauge("kda_calls") == 0
     assert gauge("attn_kept_calls") == 1 + 3
-    chunk = config["program"]["gdn_chunk"]
+    chunk = cells.load_cell(cell).config["program"]["gdn_chunk"]
     # A Gated-DeltaNet layer's o in bf16, its chunks' states [32, 128, 128]
     # and inverses float32; the attention layer's o in bf16 and float32 lse.
     assert gauge("attn_kept_bytes") == 3 * (

@@ -17,6 +17,7 @@ from test_zaya import (
     WIDE,
     hyper,
     loss_and_grads,
+    ref_expert_counts,
     to_reference,
     tree_close,
     uneven,
@@ -128,11 +129,15 @@ def test_two_planned_steps_are_a_plain_grad_and_optimizer_loop(stacked,
     plan = plan_training(lambda p, t: zaya.loss_fn(p, t, cfg), tx, params,
                          batches[0], devices=devices[:1], explore=False,
                          num_micro_batches=2)
+    @jax.jit
+    def apply(p, state, grads):
+        updates, state = tx.update(grads, state, p)
+        return optax.apply_updates(p, updates), state
+
     for tokens in batches:
         want_loss, grads = loss_and_grads(p, tokens, cfg)
-        counts = ref.expert_counts(to_reference(p, cfg), tokens, hyper(cfg))
-        updates, state = tx.update(grads, state, p)
-        p = optax.apply_updates(p, updates)
+        counts = ref_expert_counts(to_reference(p, cfg), tokens, hyper(cfg))
+        p, state = apply(p, state, grads)
         assert plan.step(tokens) == pytest.approx(float(want_loss), rel=2e-6)
         bias = ref.bias_update(bias, counts, OPT["bias_rate"])
     got, _ = jax.tree_util.tree_unflatten(plan._state_tree,

@@ -26,31 +26,38 @@ _INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%(?P<name>[\w.\-]+) = "
 
 def compiled_step(cell_name: str, device):
     """The cell's step as ``plan_training`` builds it on one chip, state
-    donated, compiled for ``device`` at the cell's own shapes."""
+    donated, compiled for ``device`` at the cell's own shapes; beside it the
+    shapes of the parameters it was built for."""
     import jax
     import optax
     from benchmark.lib import cells
     from tepdist_tpu.parallel.sync_free import build_ga_step
-    jax.default_backend = lambda: "tpu"     # kernels as on the chip
-    cell = cells.load_cell(cell_name)
-    builder, t = cells.builder_for(cell), cell.traffic
-    loss = builder.program_loss_fn(cell.config)
-    tx = builder.program_optimizer(cell.config)
+    backend, jax.default_backend = jax.default_backend, lambda: "tpu"
+    try:                                    # kernels as on the chip
+        cell = cells.load_cell(cell_name)
+        builder, t = cells.builder_for(cell), cell.traffic
+        loss = builder.program_loss_fn(cell.config)
+        tx = builder.program_optimizer(cell.config)
 
-    def apply_fn(p, s, g):
-        updates, s = tx.update(g, s, p)
-        return optax.apply_updates(p, updates), s
+        def apply_fn(p, s, g):
+            updates, s = tx.update(g, s, p)
+            return optax.apply_updates(p, updates), s
 
-    params = jax.eval_shape(lambda: builder.to_program(
-        builder.make_params(cell.config, 1), cell.config))
-    one_chip = jax.sharding.SingleDeviceSharding(device)
-    args = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        (params, jax.eval_shape(tx.init, params), jax.ShapeDtypeStruct(
-            (int(t["batch"]), int(t["seq"]) + 1), "int32")))
-    step = build_ga_step(lambda p, b: jax.value_and_grad(loss)(p, b),
-                         apply_fn, int(t["num_micro_batches"]), loss_fn=loss)
-    return jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
+        params = jax.eval_shape(lambda: builder.to_program(
+            builder.make_params(cell.config, 1), cell.config))
+        one_chip = jax.sharding.SingleDeviceSharding(device)
+        args = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            (params, jax.eval_shape(tx.init, params), jax.ShapeDtypeStruct(
+                (int(t["batch"]), int(t["seq"]) + 1), "int32")))
+        step = build_ga_step(lambda p, b: jax.value_and_grad(loss)(p, b),
+                             apply_fn, int(t["num_micro_batches"]),
+                             loss_fn=loss)
+        return (jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile(),
+                params)
+    finally:
+        jax.default_backend = backend
 
 
 def kernel(name: str) -> str:
@@ -79,7 +86,7 @@ def main() -> int:
     ap.add_argument("--against", help="compare with counts saved earlier")
     a = ap.parse_args()
     with described_v5e() as devices:
-        compiled = compiled_step(a.cell, devices[0])
+        compiled, _ = compiled_step(a.cell, devices[0])
     counts = histogram(compiled.as_text())
     out = {"cell": a.cell, "instructions": sum(counts.values()),
            "kinds": len(counts), "digest": hashlib.sha256(
