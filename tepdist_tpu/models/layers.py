@@ -547,6 +547,75 @@ def yarn_table(head_dim: int, theta: float, factor: float,
                      float(attention_factor), "rope_yarn")
 
 
+traced.declare(
+    "rope_calls", "differentiated rotary embeddings a micro batch, an array "
+    "of heads each (a layer's q and its k: 2 a layer that rotates, 0 in a "
+    "model without rotary)")
+
+
+def _rotate_half(hd: int, rotary_dim: int, dtype):
+    """``P`` [hd, hd] with ``x @ P`` the rotate-half of ``x``'s first
+    ``rotary_dim`` channels, ``[-x2, x1]``, and 0 past them: -1 where
+    channel ``i + half`` feeds channel ``i``, +1 where ``i`` feeds
+    ``i + half``. ``P^T = -P``. Made of two iotas, which the compiler
+    folds, and not a NumPy array: as an array constant it rode a walk's
+    loop as one more operand, and in that program the compiler inlined a
+    walk of one layer only after the passes that merge its recomputation
+    with its forward pass (Trinity's dense layer ran twice: seen in the
+    pass dumps and on the chip, gone with the iotas; which condition of
+    the loop simplifier the operand trips was not found)."""
+    half = rotary_dim // 2
+    source = jax.lax.broadcasted_iota(jnp.int32, (hd, hd), 0)
+    to = jax.lax.broadcasted_iota(jnp.int32, (hd, hd), 1)
+    return (((to == source + half) & (to < rotary_dim)).astype(dtype)
+            - ((source == to + half) & (source < rotary_dim)).astype(dtype))
+
+
+def _turn(x, cos, sin, rotary_dim: int):
+    """``x * cos + (x @ P) * sin`` in float32, cast to ``x.dtype`` once:
+    the head's lanes are never split or joined. The product is exact (a
+    signed permutation: one term a sum, entries 0 and +-1): for bfloat16 one
+    pass of the matrix unit, its result asked for in bfloat16; for any
+    wider dtype at the highest precision, where the chip's default would
+    round ``x`` to bfloat16. A channel past ``rotary_dim`` is ``x * 1 + 0 *
+    0``: ``x`` itself, but that a ``-0.0`` comes out ``+0.0``. A select
+    that kept the sign was tried and taken out: with it the CPU's compiled
+    float32 values differed by a unit in the last place between a scan and
+    its unrolled twin, without it they do not (why was not found: pinning
+    every rounding in here changed nothing)."""
+    exact = None if x.dtype == jnp.bfloat16 else jax.lax.Precision.HIGHEST
+    half_turned = jax.lax.dot_general(
+        x, _rotate_half(x.shape[-1], rotary_dim, x.dtype),
+        (((x.ndim - 1,), (0,)), ((), ())), precision=exact,
+        preferred_element_type=x.dtype)
+    return (x.astype(jnp.float32) * cos
+            + half_turned.astype(jnp.float32) * sin).astype(x.dtype)
+
+
+# ``layers``: the runs one trace of the call stands for, as ``_flash`` takes
+# it, for the forward rule's count of ``rope_calls``.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _rotated(x, cos, sin, rotary_dim, layers):
+    return _turn(x, cos, sin, rotary_dim)
+
+
+def _rotated_fwd(x, cos, sin, rotary_dim, layers):
+    traced.count("rope_calls", layers=layers)
+    return _turn(x, cos, sin, rotary_dim), (cos, sin)
+
+
+def _rotated_bwd(rotary_dim, layers, tables, g):
+    # The two channels of a pair share their angle, so ``(g * sin) @ P^T ==
+    # -(g @ P) * sin``: the cotangent is the rotation by minus the angle, on
+    # ``g`` in its own dtype (autodiff's transpose would hand the matrix
+    # unit a float32 ``g * sin``, which the chip rounds to bfloat16).
+    cos, sin = tables
+    return _turn(g, cos, -sin, rotary_dim), None, None
+
+
+_rotated.defvjp(_rotated_fwd, _rotated_bwd)
+
+
 def rope(x, table: Union[float, RopeTable], start=0,
          rotary_dim: Optional[int] = None):
     """Rotary embedding over [B, H, T, hd] (rotate-half formulation) at
@@ -555,17 +624,32 @@ def rope(x, table: Union[float, RopeTable], start=0,
     (-i / half)`` a position), or a :class:`RopeTable`. ``rotary_dim``: the
     head's first channels that are rotated, ``half = rotary_dim / 2`` pairs
     (channel ``i`` with ``i + half``); the channels past them pass as they
-    are (a partial rotary embedding). None: the whole head."""
+    are (a partial rotary embedding). None: the whole head.
+
+    cos and sin are made at the head's full width (channel ``i`` and
+    ``i + half`` carry one angle; past ``rotary_dim`` cos is 1 and sin 0)
+    and rotate-half is a product with a signed permutation (:func:`_turn`):
+    a reshape, slice or concatenate that splits a head's lanes is a relayout
+    on the chip. The values are those of ``x1 * cos - x2 * sin``, ``x1 * sin
+    + x2 * cos`` bit for bit, and so is the gradient (but for the sign of a
+    zero past ``rotary_dim``)."""
     B, H, T, hd = x.shape
     rotary_dim = hd if rotary_dim is None else rotary_dim
     half = rotary_dim // 2
     plain = not isinstance(table, RopeTable)
     with jax.named_scope("rope_plain" if plain else table.name):
+        channel = jnp.arange(hd, dtype=jnp.int32)
         if plain:
-            freqs = 1.0 / (table ** (jnp.arange(0, half, dtype=jnp.float32)
-                                     / half))
+            pair = jax.lax.rem(channel, half)
+            freqs = 1.0 / (table ** (pair.astype(jnp.float32) / half))
         else:
-            freqs = jnp.asarray(table.inv_freq, jnp.float32)
+            freqs = jnp.asarray(np.asarray(table.inv_freq, np.float32)[
+                np.arange(hd) % half])
+        scale = 1.0 if plain else table.scale
+        if rotary_dim < hd:     # past the rotary width: angle 0, scale 1
+            rotated = channel < rotary_dim
+            freqs = jnp.where(rotated, freqs, 0.0)
+            scale = jnp.where(rotated, jnp.float32(scale), 1.0)
         positions = jnp.arange(T, dtype=jnp.float32)
         if not (isinstance(start, int) and start == 0):
             positions = positions + jnp.asarray(start, jnp.float32)
@@ -573,14 +657,8 @@ def rope(x, table: Union[float, RopeTable], start=0,
         cos = jnp.cos(angles)[None, None, :, :]
         sin = jnp.sin(angles)[None, None, :, :]
         if not plain and table.scale != 1.0:
-            cos, sin = cos * table.scale, sin * table.scale
-        x1, x2 = x[..., :half].astype(jnp.float32), \
-            x[..., half:rotary_dim].astype(jnp.float32)
-        out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
-                              axis=-1).astype(x.dtype)
-        if rotary_dim < hd:
-            out = jnp.concatenate([out, x[..., rotary_dim:]], axis=-1)
-        return out
+            cos, sin = cos * scale, sin * scale
+        return _rotated(x, cos, sin, rotary_dim, traced.stood_for())
 
 
 def gqa_heads(blk, a, *, n_head: int, n_kv_head: int, head_dim: int,

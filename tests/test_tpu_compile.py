@@ -176,6 +176,34 @@ def test_the_backward_pass_is_one_kernel_at_16k_for_v5e(v5e_devices):
     assert asked == {"fwd": 32 * 2 ** 20, "dkv": 48 * 2 ** 20}, asked
 
 
+@pytest.mark.parametrize("direction", ["value", "vjp"])
+def test_rope_keeps_a_heads_lanes_whole_for_v5e(v5e_devices, direction):
+    """``models/layers.py:rope`` at [1, 4, 2048, 128] in bf16, a head of 128
+    lanes whose rotate-half pairs lane ``i`` with ``i + 64``: the optimised
+    HLO of its value and of its vjp holds no ``concatenate`` and no result
+    whose minor dimension is 64 (a head cut or joined at lane 64 is a
+    relayout on the chip), and the product with the signed permutation is
+    inside a fusion with its multiply-add."""
+    from tepdist_tpu.models.layers import rope
+    from tools.rope_bench import split_lanes
+    x = jax.ShapeDtypeStruct((1, 4, 2048, 128), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(v5e_devices[0]))
+
+    def value(x):
+        return rope(x, 10000.0)
+
+    def vjp(x, g):
+        return jax.vjp(value, x)[1](g)[0]
+
+    text = (jax.jit(value).lower(x) if direction == "value"
+            else jax.jit(vjp).lower(x, x)).compile().as_text()
+    assert split_lanes(text, 64) \
+        == {"concatenates": 0, "half_wide_results": 0}
+    assert "bf16[1,4,2048,128]" in text
+    entry = text[text.index("ENTRY "):]
+    assert "convolution(" in text and "convolution(" not in entry, entry
+
+
 # The OLMoE cell's grouped matmuls: 8192 tokens x 8 experts a token in the
 # tile-aligned layout (65536 rows + 64 tiles of pads), 64 experts of
 # 2048 x 1024 (gate, up) and 1024 x 2048 (down).
