@@ -35,6 +35,16 @@ through the entry points a user calls:
            kernels, the conv pair, the latent kernels and the grouped
            matmuls are in the compiled step and that the delta rule's
            forward ran twice a KDA layer and the latent layer's once.
+  phase N  the same for ``models/nemotron_h.py`` at its ``smoke`` preset
+           (the published pattern's first nine layers MEMEM*EME in the units
+           ME ME M*E ME: four Mamba-2 layers of 8 heads of the published 64
+           over 2 groups of 128 states, four layers of 8 of 32
+           sigmoid-routed squared-relu experts without a gate matrix, 6 a
+           token, one attention layer without positions at 2 heads of the
+           published 128), 2 micro batches of one 1024-token sequence, 5
+           steps; asserts the state-space pair, the conv pair, the flash
+           kernels and the grouped matmuls are in the compiled step and
+           that the state-space rule's forward ran twice a Mamba-2 layer.
   phase Q  the same for ``models/qwen3_next.py`` at its ``smoke`` preset
            (three scalar-decay delta-rule layers of one key head under two
            value heads of the published 128 to one gated attention layer of
@@ -456,6 +466,40 @@ def phase_qwen(preset: str = "smoke", batch: int = 2, seq: int = 1024,
 
 
 # ---------------------------------------------------------------------------
+# Phase N: the state-space-dual kernels (heads of 64, two a lane block, over
+# shared B/C groups), ungated experts and a layer that is one part alone, in
+# units of two and three layers a walk.
+# ---------------------------------------------------------------------------
+
+def phase_nemotron(preset: str = "smoke", batch: int = 2, seq: int = 1024,
+                   platform: str = "tpu") -> dict:
+    from tepdist_tpu.models import nemotron_h
+
+    cfg = nemotron_h.CONFIGS[preset]
+    devices, tplan, tokens, gauges = _plan_zoo_model(
+        nemotron_h, cfg, batch, seq, platform)
+    mamba = cfg.kinds.count(nemotron_h.MAMBA)
+    # The state-space forward runs in a unit's forward and again in its
+    # recomputation; the attention layer's forward is kept by its walk.
+    _check(gauges["ssd_calls"] == 2 * mamba
+           and gauges["ssm_conv_calls"] == 2 * mamba
+           and gauges["attn_kept_calls"] == cfg.kinds.count(nemotron_h.ATTN),
+           f"phase N: the state-space rule's forward ran "
+           f"{gauges['ssd_calls']} times a micro batch over {mamba} layers; "
+           f"the walks kept {gauges['attn_kept_calls']} calls' forward")
+    _check(gauges["ssd_state_bytes"] == batch // 2 * cfg.mamba_num_heads
+           * cfg.mamba_head_dim * cfg.ssm_state_size * 4,
+           f"phase N: a layer's state reads {gauges['ssd_state_bytes']} "
+           "bytes")
+    return _step_zoo_model(
+        "N", f"nemotron_h-{preset}", devices, tplan, tokens, gauges,
+        platform,
+        ("tepdist_ssd_fwd", "tepdist_ssd_bwd", "tepdist_conv_fwd",
+         "tepdist_conv_bwd", "tepdist_flash_fwd", "tepdist_flash_dkv",
+         "tepdist_gmm_fwd"))
+
+
+# ---------------------------------------------------------------------------
 # Four chips: explored layout over the host's devices vs the same steps on
 # one of them, in one process that owns all four.
 # ---------------------------------------------------------------------------
@@ -517,7 +561,8 @@ def phase_four(cfg_name: str = "117M", batch: int = 16, seq: int = 1024,
 
 CHILD_PHASES = {"phase_b": phase_b, "phase_four": phase_four,
                 "phase_mla": phase_mla, "phase_zaya": phase_zaya,
-                "phase_kimi": phase_kimi, "phase_qwen": phase_qwen}
+                "phase_kimi": phase_kimi, "phase_qwen": phase_qwen,
+                "phase_nemotron": phase_nemotron}
 
 
 def _run_child(phase: str) -> dict:
@@ -567,6 +612,7 @@ def main() -> None:
         _emit(_run_child("phase_zaya"))
         _emit(_run_child("phase_kimi"))
         _emit(_run_child("phase_qwen"))
+        _emit(_run_child("phase_nemotron"))
     _check(holder["platform"] == "tpu" and holder["n_devices"] == args.chips,
            f"ran on {holder['n_devices']} {holder['platform']} device(s), "
            f"wanted {args.chips} tpu")

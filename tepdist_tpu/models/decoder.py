@@ -32,7 +32,8 @@ from tepdist_tpu.models.layers import held_routing_stats, scan_blocks
 
 Stack = Tuple[Any, int, int]
 # The leaves of a SwiGLU expert layer, [experts, ...] each: what a model
-# names to ``walk_layers`` for the grouped-matmul kernels to read in place.
+# names to ``walk_layers`` for the grouped-matmul kernels to read in place
+# (an expert without a gate matrix has the last two).
 EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
 
 
@@ -56,6 +57,23 @@ def runs(kinds: Sequence) -> Tuple[Tuple[Any, int, int], ...]:
         else:
             out.append((kind, i, 1))
     return tuple(out)
+
+
+def units(kinds: Sequence[str], closing: str) -> Tuple[str, ...]:
+    """The layers of a model whose layer is one part alone (a mixer, or a
+    feed-forward part) in units a walk takes as its layers: a unit ends with
+    a part of kind ``closing`` (the feed-forward part, as every other
+    model's layer ends with one) or before a kind it already holds, so that
+    a unit's parts have leaves of different names and lie side by side in
+    one dict. A kind is a letter and a unit the string of its parts' kinds:
+    ``"MEMEM*EME"`` closing with ``"E"`` is ``("ME", "ME", "M*E", "ME")``,
+    three runs where the layers alone are nine."""
+    out = [""]
+    for kind in kinds:
+        if out[-1].endswith(closing) or kind in out[-1]:
+            out.append("")
+        out[-1] += kind
+    return tuple(u for u in out if u)
 
 
 def run_stacks(kinds: Sequence) -> Tuple[Stack, ...]:

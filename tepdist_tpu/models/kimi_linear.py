@@ -67,6 +67,7 @@ from tepdist_tpu.models.layers import (
     over_sequence,
     part,
     rms_norm,
+    scaled_by_head,
 )
 from tepdist_tpu.ops.pallas.causal_conv import causal_conv
 from tepdist_tpu.ops.pallas.kda_attention import CHUNK, kda_attention
@@ -300,33 +301,10 @@ def log_decays(blk, a, cfg: KimiLinearConfig):
     return -rate * jax.nn.softplus(raw)
 
 
-def _heads_matrix(width: int, heads: int):
-    """float32 [width, heads], 1 where a channel is its head's: a head's
-    sum as a matmul and a head's number spread over its channels as the
-    transposed one, so that a ``[T, heads * D]`` array is never reshaped to
-    ``[T, heads, D]`` (on the chip that is another tiling: a copy of the
-    array each way, 6.5 ms at the cell's ``[8192, 4096]`` in float32)."""
-    head = jnp.arange(width, dtype=jnp.int32) // (width // heads)
-    return (head[:, None] == jnp.arange(heads, dtype=jnp.int32)[None, :]
-            ).astype(jnp.float32)
-
-
-def _scaled_by_head(x32, heads: int, eps: float, mean: bool):
-    """x32 [B, T, heads * D] float32 times, a head, ``rsqrt`` of its
-    channels' sum of squares (their mean: ``mean``) plus ``eps``."""
-    ones = _heads_matrix(x32.shape[-1], heads)
-    highest = jax.lax.Precision.HIGHEST
-    total = jnp.einsum("btc,ch->bth", x32 * x32, ones, precision=highest)
-    if mean:
-        total = total / (x32.shape[-1] // heads)
-    return x32 * jnp.einsum("bth,ch->btc", jax.lax.rsqrt(total + eps), ones,
-                            precision=highest)
-
-
 def l2_norm(x, heads: int, scale: float = 1.0, eps: float = 1e-6):
     """x [B, T, heads * D] -> each head's D channels over their L2 norm,
     times ``scale``; float32 inside, back in x's dtype."""
-    x32 = _scaled_by_head(x.astype(jnp.float32), heads, eps, mean=False)
+    x32 = scaled_by_head(x.astype(jnp.float32), heads, eps, mean=False)
     return (x32 * scale if scale != 1.0 else x32).astype(x.dtype)
 
 
@@ -335,7 +313,7 @@ def gated_norm(o, gate, g, heads: int, eps: float, act=jax.nn.sigmoid):
     [B, T, heads * D], g [D] (one gain, every head's), ``act`` the gate's
     function (the sigmoid here, ``silu`` in ``models/qwen3_next.py``);
     float32 inside, back in o's dtype."""
-    normed = _scaled_by_head(o.astype(jnp.float32), heads, eps, mean=True) \
+    normed = scaled_by_head(o.astype(jnp.float32), heads, eps, mean=True) \
         * jnp.tile(g.astype(jnp.float32), heads)
     return (normed * act(gate.astype(jnp.float32))).astype(o.dtype)
 

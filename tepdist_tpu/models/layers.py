@@ -495,6 +495,30 @@ def rms_norm0(x, w, eps: float = 1e-6):
     return rms_norm(x, 1.0 + w.astype(jnp.float32), eps)
 
 
+def heads_matrix(width: int, heads: int):
+    """float32 [width, heads], 1 where a channel is its head's: a head's
+    sum as a matmul and a head's number spread over its channels as the
+    transposed one, so that a ``[T, heads * D]`` array is never reshaped to
+    ``[T, heads, D]`` (on the chip that is another tiling: a copy of the
+    array each way, 6.5 ms at the Kimi Linear cell's ``[8192, 4096]`` in
+    float32)."""
+    head = jnp.arange(width, dtype=jnp.int32) // (width // heads)
+    return (head[:, None] == jnp.arange(heads, dtype=jnp.int32)[None, :]
+            ).astype(jnp.float32)
+
+
+def scaled_by_head(x32, heads: int, eps: float, mean: bool):
+    """x32 [B, T, heads * D] float32 times, a head, ``rsqrt`` of its
+    channels' sum of squares (their mean: ``mean``) plus ``eps``."""
+    ones = heads_matrix(x32.shape[-1], heads)
+    highest = jax.lax.Precision.HIGHEST
+    total = jnp.einsum("btc,ch->bth", x32 * x32, ones, precision=highest)
+    if mean:
+        total = total / (x32.shape[-1] // heads)
+    return x32 * jnp.einsum("bth,ch->btc", jax.lax.rsqrt(total + eps), ones,
+                            precision=highest)
+
+
 def attn_gate(o, a, wa):
     """The heads' outputs ``o`` [B, T, heads * D] times ``sigmoid(a wa)``,
     element by element, float32 inside: an output gate from the layer's

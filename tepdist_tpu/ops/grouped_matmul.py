@@ -82,7 +82,9 @@ order; the rows that went held zeros). ``route`` takes the size as
 ``rows=``; alone it lays out the worst case.
 
 ``routed_experts`` is the layer around the layout: rows in, the three
-grouped matmuls with the gated activation between them, rows out.
+grouped matmuls with the gated activation between them, rows out. An expert
+with **no gate matrix** (``w_gate=None``: two stacks, ``relu(up)^2`` between
+them) runs on the same routing, layout, dispatch and combine.
 """
 
 from __future__ import annotations
@@ -281,8 +283,8 @@ def counting_kernel_calls():
     ``dispatch``'s backward's), 0 where the layer keeps the XLA gathers.
     ``stack_in_place``: of the grouped matmuls that read their weights out
     of the layers' stack (:class:`ExpertStack`), 12 (three matmuls, each
-    forward, recomputed, its input's and its weight's gradient), 0 where
-    the layer was handed slices. Who walks a stack of layers multiplies by
+    forward, recomputed, its input's and its weight's gradient; 8 for an
+    expert of two matrices), 0 where the layer was handed slices. Who walks a stack of layers multiplies by
     them (``models/layers.py:scan_blocks``: the gauges ``moe_rows_sum_calls``
     and ``moe_stack_in_place_calls``)."""
     calls = {"rows_sum": 0, "stack_in_place": 0}
@@ -407,6 +409,33 @@ def _gated_bwd(res, ct):
 gated.defvjp(_gated_fwd, _gated_bwd)
 
 
+def _relu2(up, row_weight):
+    u = jnp.maximum(up.astype(jnp.float32), 0.0)
+    return (u * u * row_weight).astype(up.dtype)
+
+
+relu2 = jax.custom_vjp(_relu2)
+relu2.__doc__ = """``relu(up)^2 * row_weight`` in float32, back in up's
+dtype: the activation of an expert without a gate matrix. As :func:`gated`,
+the backward recomputes from the operands, which is all it keeps."""
+
+
+def _relu2_fwd(up, row_weight):
+    return _relu2(up, row_weight), (up, row_weight)
+
+
+def _relu2_bwd(res, ct):
+    up, row_weight = res
+    u = jnp.maximum(up.astype(jnp.float32), 0.0)
+    ct = ct.astype(jnp.float32)
+    return ((ct * 2.0 * u * row_weight).astype(up.dtype),
+            jnp.sum(ct * u * u, axis=-1, keepdims=True).astype(
+                row_weight.dtype))
+
+
+relu2.defvjp(_relu2_fwd, _relu2_bwd)
+
+
 def routed_experts_at(h, weights, r: Routing, w_gate, w_up, w_down,
                       tile_m: int):
     """``routed_experts`` over the layout ``r`` as it is handed in: the
@@ -422,7 +451,8 @@ def routed_experts_at(h, weights, r: Routing, w_gate, w_up, w_down,
         # (W (w a) = w (W a)): the projected rows then need no keeping for
         # the weight's gradient, 320 MiB a micro batch at the 1B-7B sizes.
         # On a pad row it is exactly 0, as the row itself is.
-        act = gated(gmm(x, w_gate), gmm(x, w_up), row_weight)
+        act = relu2(gmm(x, w_up), row_weight) if w_gate is None else gated(
+            gmm(x, w_gate), gmm(x, w_up), row_weight)
         out_rows = gmm(act, w_down)
     with jax.named_scope("moe_combine"):
         return combine(out_rows, r.row_token, r.dest, r.live_rows)
@@ -434,7 +464,8 @@ def routed_experts(h, weights, experts, w_gate, w_up, w_down,
     ``weights`` and ``experts`` [S, k] over ``num_experts``, expert weights
     ``w_gate``, ``w_up`` [G, d, f] and ``w_down`` [G, f, d] -> [S, d], the
     sum over each token's choices j of ``weights_j . w_down[e_j] (silu(
-    w_gate[e_j] h) * w_up[e_j] h)``. With ``held = (first, count)`` the
+    w_gate[e_j] h) * w_up[e_j] h)``; with ``w_gate`` None, of ``weights_j .
+    w_down[e_j] relu(w_up[e_j] h)^2`` (an expert of two matrices). With ``held = (first, count)`` the
     layer holds experts ``first .. first + count - 1`` (``G = count``) and a
     choice of any other contributes nothing: the caller hands in weight 0
     for it. No token is dropped (``route``). A share of the experts is laid

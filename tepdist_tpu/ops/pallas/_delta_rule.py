@@ -4,7 +4,10 @@ heads in groups over a key head): the running sum inside a chunk, the
 inverse of the chunk's triangular system and the way back through it, a
 head's column of a ``[chunk, heads]`` block, the one sweep over ``(batch,
 chunks, heads)`` with every head's float32 state in a VMEM scratch, and the
-call's three forms for a walk that keeps its forward pass.
+call's three forms for a walk that keeps its forward pass. The running sum,
+the column, a column as a row and the sweep are also the state-space rule's
+(``ssd_attention.py``: no triangular system, a state of ``[values, states]``
+a head and two heads a lane block).
 
 **The inverse by doubling**: ``A`` is strictly lower, so ``(I + A)^-1 = (I -
 A)(I + A^2)(I + A^4) ...`` up to ``A^(C/2)``: two matmuls a factor, no
@@ -76,6 +79,18 @@ def _ij(C: int):
             jax.lax.broadcasted_iota(jnp.int32, (C, C), 1))
 
 
+def _row(col):
+    """A column [C, 1] as a row [1, C], exactly (a masked sum of zeros)."""
+    i, j = _ij(col.shape[0])
+    return jnp.sum(jnp.where(i == j, col, 0.0), axis=0, keepdims=True)
+
+
+def _col(row):
+    """A row [1, C] as a column [C, 1], exactly."""
+    i, j = _ij(row.shape[1])
+    return jnp.sum(jnp.where(i == j, row, 0.0), axis=1, keepdims=True)
+
+
 def _product(a, b):
     """``a @ b`` of two float32 operands, each in its two bf16 parts ``(hi,
     lo)``, in three passes of the matrix unit where ``_linear._dot`` makes
@@ -143,46 +158,63 @@ def _column(b, h):
 
 
 def sweep(kernel, name, operands, outs, *, chunk, reverse, flops,
-          transcendentals, interpret, group=None):
+          transcendentals, interpret, group=None, state=None):
     """One sweep over the chunks. ``operands``: ``(kind, array)`` each;
     ``outs``: ``(kind, dtype)`` of each result (whole chunks: the caller
     pads). The kinds, for ``H`` heads of ``K`` channels, ``group`` of them a
     grid step (None: one, its axis squeezed out of a block): ``wide`` ``[B,
     T, H * K]``, ``group * K`` lanes a step; ``key`` ``[B, T, H / group *
-    K]``, the ``K`` lanes the step's heads share; ``beta`` ``[B, T, H]``,
-    the whole block every step; ``states`` ``[B, chunks, H, V, K]`` and
-    ``inv`` ``[B, chunks, H, chunk, chunk]``, the step's heads'."""
+    cols]``, the ``cols`` lanes the step's heads share; ``beta`` ``[B, T,
+    H]``, the whole block every step; ``states`` ``[B, chunks, states, rows,
+    cols]`` and ``inv`` ``[B, chunks, H, chunk, chunk]``, the step's heads';
+    ``lanes`` ``[1, H * K]``, a number a lane whatever the token, the step's
+    ``group * K`` of them, and ``lanes_out`` ``[B, chunks, 1, H * K]``, one
+    such row a chunk.
+
+    ``state``: ``(n, rows, cols)``, the ``n`` float32 states ``[rows,
+    cols]`` a grid step carries in the scratch and reads or writes as
+    ``states`` (None: a ``[K, K]`` state a head, the delta rules';
+    ``ssd_attention.py``: a ``[heads a lane block * K, cols]`` state a lane
+    block)."""
     B, T, H = next(x.shape for kind, x in operands if kind == "beta")
     r = group or 1
     K = next(x.shape[2] for kind, x in operands if kind == "wide") // H
+    n, rows, cols = state or (group, K, K)
+    steps = H // r
+    states = steps * (n or 1)
     nc = T // chunk
     at = (lambda c: nc - 1 - c) if reverse else (lambda c: c)
 
-    def per_head(*tail):
-        return pl.BlockSpec((None, None, group) + tail,
+    def per_step(n, *tail):
+        return pl.BlockSpec((None, None, n) + tail,
                             lambda b, c, h: (b, at(c), h, 0, 0))
 
     specs = {
         "wide": pl.BlockSpec((None, chunk, r * K),
                              lambda b, c, h: (b, at(c), h)),
-        "key": pl.BlockSpec((None, chunk, K), lambda b, c, h: (b, at(c), h)),
+        "key": pl.BlockSpec((None, chunk, cols),
+                            lambda b, c, h: (b, at(c), h)),
         "beta": pl.BlockSpec((None, chunk, H), lambda b, c, h: (b, at(c), 0)),
-        "states": per_head(K, K),
-        "inv": per_head(chunk, chunk),
+        "states": per_step(n, rows, cols),
+        "inv": per_step(group, chunk, chunk),
+        "lanes": pl.BlockSpec((1, r * K), lambda b, c, h: (0, h)),
+        "lanes_out": pl.BlockSpec((None, None, 1, r * K),
+                                  lambda b, c, h: (b, at(c), 0, h)),
     }
-    shapes = {"wide": (B, T, H * K), "key": (B, T, H // r * K),
-              "beta": (B, T, H), "states": (B, nc, H, K, K),
-              "inv": (B, nc, H, chunk, chunk)}
+    shapes = {"wide": (B, T, H * K), "key": (B, T, steps * cols),
+              "beta": (B, T, H), "states": (B, nc, states, rows, cols),
+              "inv": (B, nc, H, chunk, chunk),
+              "lanes_out": (B, nc, 1, H * K)}
     out_shape = [jax.ShapeDtypeStruct(shapes[kind], dtype)
                  for kind, dtype in outs]
     return pl.pallas_call(
         kernel,
         name=name,
-        grid=(B, nc, H // r),
+        grid=(B, nc, steps),
         in_specs=[specs[kind] for kind, _ in operands],
         out_specs=[specs[kind] for kind, _ in outs],
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((H, K, K), _F32)],
+        scratch_shapes=[pltpu.VMEM((states, rows, cols), _F32)],
         cost_estimate=pl.CostEstimate(
             flops=flops, transcendentals=transcendentals,
             bytes_accessed=sum(

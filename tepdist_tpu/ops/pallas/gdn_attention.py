@@ -70,10 +70,12 @@ from jax.experimental import pallas as pl
 
 from tepdist_tpu.ops.pallas import _interpret
 from tepdist_tpu.ops.pallas._delta_rule import (
+    _col,
     _column,
     _ij,
     _inverse,
     _prefix,
+    _row,
     _through_inverse,
     differentiable,
     sweep,
@@ -95,18 +97,6 @@ CHUNK = 64                  # tokens a grid step
 
 traced.declare(
     "gdn_calls", "forward scalar-decay delta-rule kernel calls a micro batch")
-
-
-def _row(col):
-    """A column [C, 1] as a row [1, C], exactly (a masked sum of zeros)."""
-    i, j = _ij(col.shape[0])
-    return jnp.sum(jnp.where(i == j, col, 0.0), axis=0, keepdims=True)
-
-
-def _col(row):
-    """A row [1, C] as a column [C, 1], exactly."""
-    i, j = _ij(row.shape[1])
-    return jnp.sum(jnp.where(i == j, row, 0.0), axis=1, keepdims=True)
 
 
 def _products(q, k, narrow):

@@ -58,10 +58,12 @@ def mesh8(devices):
 _LONGEST_FIRST = (
     "test_sarvam_mla.py", "test_tpu_compile.py", "test_afmoe.py",
     "test_minicpm_sala.py", "test_mellum.py", "test_jamba.py",
-    "test_stack_in_place.py", "test_kimi_linear.py",
+    "test_stack_in_place.py", "test_nemotron_h.py", "test_kimi_linear.py",
     "test_kimi_linear_walk.py", "test_kda_attention.py",
     "test_multiworker.py", "test_qwen3_next.py", "test_zaya.py",
-    "test_olmoe.py", "test_qwen3_next_walk.py", "test_models.py",
+    "test_olmoe.py", "test_ssd_attention.py", "test_nemotron_h_walk.py",
+    "test_tpu_compile_nemotron_h.py", "test_qwen3_next_walk.py",
+    "test_models.py",
     "test_attn_kept.py", "test_gdn_attention.py", "test_serving_chaos.py",
     "test_sequence_parallel.py", "test_zaya_walk.py", "test_ga_fused.py",
     "test_serving_fleet.py", "test_serving_paged.py",

@@ -323,6 +323,7 @@ def test_the_entries_list_the_cells_that_have_the_part():
     for w in every:
         cell = cells.load_cell(w, ROOT)
         model = cell.config.get("model", cell.config)
-        experts = model.get("num_experts", 0)
+        # (The published key: ``n_routed_experts`` in a ``nemotron_h`` file.)
+        experts = model.get("num_experts", model.get("n_routed_experts", 0))
         assert (w in entries["scope_moe_share.train"]["workloads"]) == (
             experts > 1), w
