@@ -84,6 +84,12 @@ traced.declare(
     "trace stands for its chunks), 0 where the walk hands the kernels "
     "slices")
 traced.declare(
+    "moe_epilogue_calls", "grouped-matmul calls a micro batch that carry "
+    "an epilogue (ops/grouped_matmul.py:activation): 2 a walked expert "
+    "layer, the up projection's activation in the walk's first forward "
+    "and the second input gradient's addend; 1 where an expert has two "
+    "matrices and so one input gradient")
+traced.declare(
     "ce_fused_chunks", "chunks of the loss whose gradients its forward chunk "
     "loop makes (0: the dense loss, or a call nobody differentiates)")
 
@@ -186,8 +192,9 @@ def scan_blocks(body, x, blocks, kinds=None, remat: bool = True,
     arrays' bytes held from a micro batch's forward to its backward
     (the gauges ``attn_kept_calls`` / ``attn_kept_bytes``, summed over the
     walks of one loss; the walk also counts the calls a layer's expert part
-    makes of its row-copy kernel, ``moe_rows_sum_calls``, and of the grouped
-    matmuls over a stack, ``moe_stack_in_place_calls``). The same values:
+    makes of its row-copy kernel, ``moe_rows_sum_calls``, of the grouped
+    matmuls over a stack, ``moe_stack_in_place_calls``, and of those with an
+    epilogue, ``moe_epilogue_calls``). The same values:
     they are the arrays the second run would make. A body wrapped in
     :func:`rematerialised_whole` keeps nothing. The plain scan (no sink: one
     micro batch, or a body that closes over a traced value) is left as it
@@ -301,6 +308,8 @@ def _walk_accumulating(body, x, blocks, acc, kinds, in_place=()):
                      n_layers * kernel_calls[0]["rows_sum"])
         traced.count("moe_stack_in_place_calls",
                      n_layers * kernel_calls[0]["stack_in_place"])
+        traced.count("moe_epilogue_calls",
+                     n_layers * kernel_calls[0]["epilogue"])
         traced.count("attn_kept_calls", n_layers * len(kept))
         traced.count("attn_kept_bytes", sum(
             a.nbytes for a in jax.tree_util.tree_leaves(kept)))

@@ -99,7 +99,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from tepdist_tpu.ops.pallas import _interpret
-from tepdist_tpu.ops.pallas.grouped_matmul import ExpertStack, grouped_matmul
+from tepdist_tpu.ops.pallas.grouped_matmul import (
+    ExpertStack,
+    forward,
+    grouped_matmul,
+    input_grad,
+    relu_squared,
+    swiglu,
+    weight_grad,
+)
 from tepdist_tpu.ops.pallas.rows_sum import rows_sum
 
 
@@ -284,10 +292,15 @@ def counting_kernel_calls():
     ``stack_in_place``: of the grouped matmuls that read their weights out
     of the layers' stack (:class:`ExpertStack`), 12 (three matmuls, each
     forward, recomputed, its input's and its weight's gradient; 8 for an
-    expert of two matrices), 0 where the layer was handed slices. Who walks a stack of layers multiplies by
-    them (``models/layers.py:scan_blocks``: the gauges ``moe_rows_sum_calls``
-    and ``moe_stack_in_place_calls``)."""
-    calls = {"rows_sum": 0, "stack_in_place": 0}
+    expert of two matrices), 0 where the layer was handed slices.
+    ``epilogue``: of the grouped matmuls that carry an epilogue
+    (:func:`activation`), 2 (the up projection's activation in the walk's
+    first forward, which nothing differentiates, and its input gradient's
+    addend; 1 for an expert of two matrices, which has one input gradient).
+    Who walks a stack of layers multiplies by them
+    (``models/layers.py:scan_blocks``: the gauges ``moe_rows_sum_calls``,
+    ``moe_stack_in_place_calls`` and ``moe_epilogue_calls``)."""
+    calls = {"rows_sum": 0, "stack_in_place": 0, "epilogue": 0}
     token = _KERNEL_CALLS.set(calls)
     try:
         yield calls
@@ -379,9 +392,8 @@ dispatch_values.defvjp(_dispatch_values_fwd, _dispatch_values_bwd)
 
 
 def _gated(gate, up, row_weight):
-    g = gate.astype(jnp.float32)
-    return (jax.nn.silu(g) * up.astype(jnp.float32)
-            * row_weight).astype(gate.dtype)
+    return swiglu(gate.astype(jnp.float32), up.astype(jnp.float32),
+                  row_weight).astype(gate.dtype)
 
 
 gated = jax.custom_vjp(_gated)
@@ -410,8 +422,7 @@ gated.defvjp(_gated_fwd, _gated_bwd)
 
 
 def _relu2(up, row_weight):
-    u = jnp.maximum(up.astype(jnp.float32), 0.0)
-    return (u * u * row_weight).astype(up.dtype)
+    return relu_squared(up.astype(jnp.float32), row_weight).astype(up.dtype)
 
 
 relu2 = jax.custom_vjp(_relu2)
@@ -436,6 +447,58 @@ def _relu2_bwd(res, ct):
 relu2.defvjp(_relu2_fwd, _relu2_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def activation(x, w_gate, w_up, row_weight, tile_group, n_tiles, tile_m):
+    """The experts' first half over the layout's rows ``x`` [M, d]:
+    ``gated(x w_gate, x w_up, row_weight)``, with ``w_gate`` None
+    ``relu2(x w_up, row_weight)`` -> [M, f]; the weights ``[E, d, f]`` or
+    ``ExpertStack``s, differentiable as ``grouped_matmul``'s are.
+
+    **Not differentiated** (a rematerialised walk's first forward) the
+    activation is the up projection's epilogue, from the product's float32
+    tile: ``up`` is never written to HBM, read back and written again as
+    ``act``. **Differentiated**, the forward is the composition above as
+    it stands, two plain kernels and XLA's activation: the backward needs
+    ``up``, and the compiler folds the recomputed activation into the
+    backward's one fusion, where an epilogue would add a pass. The
+    backward is written out for the sake of ``x``, which feeds both
+    products: the up projection's input gradient adds the gate's inside
+    the kernel (``input_grad(add=)``: the sum in float32, rounded once,
+    over the first's buffer), where autodiff sums the two rounded
+    cotangents in a pass of its own, five passes over ``[M, d]`` for
+    three."""
+    tiles = (tile_group, n_tiles, tile_m)
+    if w_gate is None:
+        return forward(x, w_up, *tiles, act=(row_weight,))
+    return forward(x, w_up, *tiles,
+                   act=(forward(x, w_gate, *tiles), row_weight))
+
+
+def _activation_fwd(x, w_gate, w_up, row_weight, tile_group, n_tiles, tile_m):
+    tiles = (tile_group, n_tiles, tile_m)
+    gate = None if w_gate is None else forward(x, w_gate, *tiles)
+    up = forward(x, w_up, *tiles)
+    act = _relu2(up, row_weight) if w_gate is None \
+        else _gated(gate, up, row_weight)
+    return act, (x, w_gate, w_up, row_weight, gate, up, tile_group, n_tiles)
+
+
+def _activation_bwd(tile_m, res, ct):
+    x, w_gate, w_up, row_weight, gate, up, *tiles = res
+    dx = dw_gate = None
+    if w_gate is None:
+        d_up, d_weight = _relu2_bwd((up, row_weight), ct)
+    else:
+        d_gate, d_up, d_weight = _gated_bwd((gate, up, row_weight), ct)
+        dx = input_grad(d_gate, w_gate, *tiles, tile_m)
+        dw_gate = weight_grad(x, d_gate, w_gate, *tiles, tile_m)
+    return (input_grad(d_up, w_up, *tiles, tile_m, add=dx), dw_gate,
+            weight_grad(x, d_up, w_up, *tiles, tile_m), d_weight, None, None)
+
+
+activation.defvjp(_activation_fwd, _activation_bwd)
+
+
 def routed_experts_at(h, weights, r: Routing, w_gate, w_up, w_down,
                       tile_m: int):
     """``routed_experts`` over the layout ``r`` as it is handed in: the
@@ -445,15 +508,14 @@ def routed_experts_at(h, weights, r: Routing, w_gate, w_up, w_down,
         x = dispatch(h, r.row_token, r.dest, r.live_rows)
         row_weight = dispatch_values(weights, r)
     with jax.named_scope("moe_experts"):
-        def gmm(a, w):
-            return grouped_matmul(a, w, r.tile_group, r.n_tiles, tile_m)
         # The router's weight goes on the row before the down projection
         # (W (w a) = w (W a)): the projected rows then need no keeping for
         # the weight's gradient, 320 MiB a micro batch at the 1B-7B sizes.
         # On a pad row it is exactly 0, as the row itself is.
-        act = relu2(gmm(x, w_up), row_weight) if w_gate is None else gated(
-            gmm(x, w_gate), gmm(x, w_up), row_weight)
-        out_rows = gmm(act, w_down)
+        act = activation(x, w_gate, w_up, row_weight, r.tile_group,
+                         r.n_tiles, tile_m)
+        out_rows = grouped_matmul(act, w_down, r.tile_group, r.n_tiles,
+                                  tile_m)
     with jax.named_scope("moe_combine"):
         return combine(out_rows, r.row_token, r.dest, r.live_rows)
 
@@ -479,6 +541,7 @@ def routed_experts(h, weights, experts, w_gate, w_up, w_down,
         calls["rows_sum"] += 2 * (r.live_rows is not None)
         calls["stack_in_place"] += 4 * sum(
             isinstance(w, ExpertStack) for w in (w_gate, w_up, w_down))
+        calls["epilogue"] += 1 + (w_gate is not None)
     sizes = layout_rows(*experts.shape, _held(num_experts, held)[1],
                         num_experts, tile_m)
     if len(sizes) == 1:

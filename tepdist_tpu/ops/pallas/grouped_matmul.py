@@ -22,6 +22,17 @@ is fetched for them, and their output rows are written as zeros.
   over the group's tiles in VMEM, written once a group.
 * ``grouped_matmul``: ``gmm`` with a custom VJP made of the two.
 
+**A call may carry an epilogue**, applied to its float32 tile before the one
+rounding, where XLA would read the kernel's result back from HBM and write
+a third array: ``gmm(add=)`` adds an ``[M, N]`` array whose buffer becomes
+the result's (the second of two input gradients adds the first), and
+``gmm(act=)`` turns the product into the experts' activation, ``silu(gate)
+* product * row_weight`` for ``(gate, row_weight)`` and ``relu(product)^2 *
+row_weight`` for ``(row_weight,)`` (:func:`swiglu`, :func:`relu_squared`:
+the formulas ``ops/grouped_matmul.py`` composes from two calls' results
+where a pass is differentiated). Still one product a call, under the
+call's name; the extra operands are ``[M, .]`` like the rows.
+
 **The weight is one layer's ``[E, K, N]``, or every layer's ``[L, E, K, N]``
 with the layer's index.** A Pallas call's operand is a whole buffer, so a
 walk over stacked layers that hands a kernel ``stack[l]`` makes XLA copy the
@@ -83,11 +94,13 @@ def _block_n(n: int, want: int) -> int:
     return n
 
 
-def _cost(M, K, N, groups, itemsize):
-    """What the planner (graph/cost.py) and XLA's scheduler are told."""
+def _cost(M, K, N, groups, itemsize, more=0, transcendentals=0):
+    """What the planner (graph/cost.py) and XLA's scheduler are told;
+    ``more``: the ``[M, N]`` operands an epilogue reads."""
     return pl.CostEstimate(
-        flops=2 * M * K * N, transcendentals=0,
-        bytes_accessed=itemsize * (M * K + M * N + groups * K * N))
+        flops=2 * M * K * N, transcendentals=transcendentals,
+        bytes_accessed=itemsize * (M * K + (1 + more) * M * N
+                                   + groups * K * N))
 
 
 def _live(i, n_tiles):
@@ -96,13 +109,34 @@ def _live(i, n_tiles):
     return jnp.minimum(i, n_tiles[0] - 1)
 
 
-def _gmm_kernel(tile_group, n_tiles, *refs, dims):
-    x_ref, w_ref, o_ref = refs[-3:]      # after the layer's index, if any
+def swiglu(gate, up, row_weight):
+    """``silu(gate) * up * row_weight`` of float32 operands: a gated
+    expert's activation with the router's weight on the row."""
+    return jax.nn.silu(gate) * up * row_weight
+
+
+def relu_squared(up, row_weight):
+    """``relu(up)^2 * row_weight`` of float32 operands: the activation of
+    an expert of two matrices."""
+    u = jnp.maximum(up, 0.0)
+    return u * u * row_weight
+
+
+def _gmm_kernel(tile_group, n_tiles, *refs, dims, stacked, add):
+    # After the layer's index, if any; ``more``: the epilogue's operands.
+    x_ref, w_ref, *more, o_ref = refs[stacked:]
     i = pl.program_id(1)
 
     @pl.when(i < n_tiles[0])
     def _():
-        o_ref[...] = _dot(x_ref[...], w_ref[0], dims).astype(o_ref.dtype)
+        y = _dot(x_ref[...], w_ref[0], dims)
+        if add:
+            y = y + more[0][...].astype(jnp.float32)
+        elif more:      # the rows' weights come one to a lane, [1, tile_m]
+            *gate, weight = (m[...].astype(jnp.float32) for m in more)
+            y = swiglu(*gate, y, weight.T) if gate \
+                else relu_squared(y, weight.T)
+        o_ref[...] = y.astype(o_ref.dtype)
 
     @pl.when(i >= n_tiles[0])
     def _():
@@ -117,13 +151,19 @@ def _gmm_kernel(tile_group, n_tiles, *refs, dims):
 @functools.partial(
     jax.jit, inline=True, static_argnames=(
         "tile_m", "transpose_rhs", "block_n", "name", "interpret"))
-def gmm(x, w, tile_group, n_tiles, layer=None, *, tile_m: int,
-        transpose_rhs=False, block_n: int = 1024,
+def gmm(x, w, tile_group, n_tiles, layer=None, add=None, act=None, *,
+        tile_m: int, transpose_rhs=False, block_n: int = 1024,
         name: str = "tepdist_gmm_fwd", interpret=None):
     """x [M, K] in the tile-aligned layout times its tile's group's weight:
     w [E, K, N] -> [M, N], or with ``transpose_rhs`` w [E, N, K] -> [M, N]
     contracted over w's last dim. ``w`` [L, E, ...] with ``layer`` int32 [1]:
-    the same over ``w[layer[0]]``, read where it lies."""
+    the same over ``w[layer[0]]``, read where it lies.
+
+    One epilogue at most, on the float32 tile before it is rounded (zeros
+    past the live tiles either way): ``add`` [M, N] in x's dtype is added
+    and **its buffer is the result's**; ``act = (gate [M, N], row_weight
+    [M, 1])`` makes the result ``swiglu(gate, product, row_weight)`` and
+    ``act = (row_weight,)`` makes it ``relu_squared(product, row_weight)``."""
     M, K = x.shape
     stacked = layer is not None
     E = w.shape[-3]
@@ -133,6 +173,16 @@ def gmm(x, w, tile_group, n_tiles, layer=None, *, tile_m: int,
         raise ValueError(f"gmm: x {x.shape}, w {w.shape} with layer "
                          f"{'given' if stacked else 'None'}, "
                          f"transpose_rhs={transpose_rhs}, tile_m={tile_m}")
+    # The epilogue's operands: as wide as the result, then one a row.
+    *wide, weight = (add, None) if act is None else act
+    wide = [a for a in wide if a is not None]
+    if (add is not None and (act is not None or add.dtype != x.dtype)) \
+            or len(wide) > 1 or any(a.shape != (M, N) for a in wide) \
+            or (weight is not None and weight.shape != (M, 1)):
+        raise ValueError(
+            f"gmm: add {getattr(add, 'shape', None)} "
+            f"{getattr(add, 'dtype', None)}, act "
+            f"{act and [a.shape for a in act]} on {(M, N)} {x.dtype}")
     bn = _block_n(N, block_n)
 
     # The weight's block and where it lies: (group of row tile i, column
@@ -147,25 +197,40 @@ def gmm(x, w, tile_group, n_tiles, layer=None, *, tile_m: int,
 
     w_spec = pl.BlockSpec((None,) * stacked + block, at)
     scalars = (tile_group, n_tiles) + ((layer,) if stacked else ())
+    # The epilogue's operands by the rows' own tiles: nothing new is fetched
+    # for a skipped tile, whose result is zeros whatever they hold. One
+    # value a row goes in as [1, M], a tile's along the lanes: an [M, 1]
+    # operand is tiled (8, 128) in HBM, 128 times its size.
+    more = [(a, pl.BlockSpec(
+        (tile_m, bn), lambda j, i, tg, n, *_: (_live(i, n), j)))
+        for a in wide]
+    if weight is not None:
+        more.append((weight.reshape(1, M), pl.BlockSpec(
+            (1, tile_m), lambda j, i, tg, n, *_: (0, _live(i, n)))))
     # Row tiles innermost: consecutive tiles of one group keep their weight
     # block in VMEM, so each expert's weights cross HBM once a column block.
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, dims=_NT if transpose_rhs else _NN),
+        functools.partial(_gmm_kernel, dims=_NT if transpose_rhs else _NN,
+                          stacked=stacked, add=add is not None),
         name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars), grid=(N // bn, M // tile_m),
             in_specs=[pl.BlockSpec((tile_m, K),
                                    lambda j, i, tg, n, *_: (_live(i, n), 0)),
-                      w_spec],
+                      w_spec] + [spec for _, spec in more],
             out_specs=pl.BlockSpec((tile_m, bn),
                                    lambda j, i, tg, n, *_: (i, j))),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        cost_estimate=_cost(M, K, N, E, x.dtype.itemsize),
+        # The addend (the last operand, behind the scalars, x and w) is
+        # written over: a tile is read before its own result goes out.
+        input_output_aliases={len(scalars) + 2: 0} if add is not None else {},
+        cost_estimate=_cost(M, K, N, E, x.dtype.itemsize, len(wide),
+                            M * N * (len(more) == 2)),      # silu's
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(interpret),
-    )(*scalars, x, w)
+    )(*scalars, x, w, *(a for a, _ in more))
 
 
 def _tgmm_kernel(tile_group, n_tiles, *refs):
@@ -282,56 +347,57 @@ class ExpertStack(NamedTuple):
         return math.prod(self.shape)
 
 
+def _where(w):
+    """(the array, the layer's index or None) of a layer's ``[E, K, N]`` or
+    of an :class:`ExpertStack`: the form follows what ``w`` is."""
+    return (w.stack, w.layer) if isinstance(w, ExpertStack) else (w, None)
+
+
+def forward(x, w, tile_group, n_tiles, tile_m: int, act=None):
+    """``gmm`` of ``x`` and ``w`` (an ``[E, K, N]`` or an
+    :class:`ExpertStack`), ``act`` its epilogue."""
+    stack, layer = _where(w)
+    return gmm(x, stack, tile_group, n_tiles, layer, act=act, tile_m=tile_m)
+
+
+def input_grad(dy, w, tile_group, n_tiles, tile_m: int, add=None):
+    """``forward``'s cotangent of ``x``: ``dy @ w[group].T``, plus ``add``
+    (another product's input gradient of the same ``x``, written over)."""
+    stack, layer = _where(w)
+    return gmm(dy, stack, tile_group, n_tiles, layer, add, tile_m=tile_m,
+               transpose_rhs=True, name="tepdist_gmm_dx")
+
+
+def weight_grad(x, dy, w, tile_group, n_tiles, tile_m: int):
+    """``forward``'s cotangent of ``w``: the ``[E, K, N]`` gradient, or for
+    an :class:`ExpertStack` **its accumulator with the gradient added into
+    slice ``w.layer``** (the stack and the index get none)."""
+    if not isinstance(w, ExpertStack):
+        return tgmm(x, dy, tile_group, n_tiles, w.shape[0], tile_m=tile_m)
+    return ExpertStack(None, None, tgmm(
+        x, dy, tile_group, n_tiles, w.shape[0], w.into, w.layer,
+        tile_m=tile_m))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def grouped_matmul(x, w, tile_group, n_tiles, tile_m: int):
     """x [M, K] (tile-aligned layout) @ w[group of the row's tile] [E, K, N]
     -> [M, N]; differentiable in x and w. ``w`` an :class:`ExpertStack`: the
     same over ``w.stack[w.layer]`` read where it lies, differentiable in x
     and in ``w.into``, **whose cotangent is ``w.into`` with the weight's
     gradient added into slice ``w.layer``** (the stack's own is none)."""
-    if isinstance(w, ExpertStack):
-        return _grouped_in_place(x, *w, tile_group, n_tiles, tile_m)
-    return _grouped(x, w, tile_group, n_tiles, tile_m)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _grouped(x, w, tile_group, n_tiles, tile_m):
-    return gmm(x, w, tile_group, n_tiles, tile_m=tile_m)
+    return forward(x, w, tile_group, n_tiles, tile_m)
 
 
 def _grouped_fwd(x, w, tile_group, n_tiles, tile_m):
-    return gmm(x, w, tile_group, n_tiles, tile_m=tile_m), \
+    return forward(x, w, tile_group, n_tiles, tile_m), \
         (x, w, tile_group, n_tiles)
 
 
 def _grouped_bwd(tile_m, res, dy):
-    x, w, tile_group, n_tiles = res
-    dx = gmm(dy, w, tile_group, n_tiles, tile_m=tile_m, transpose_rhs=True,
-             name="tepdist_gmm_dx")
-    dw = tgmm(x, dy, tile_group, n_tiles, w.shape[0], tile_m=tile_m)
-    return dx, dw, None, None
+    x, w, *tiles = res
+    return (input_grad(dy, w, *tiles, tile_m),
+            weight_grad(x, dy, w, *tiles, tile_m), None, None)
 
 
-_grouped.defvjp(_grouped_fwd, _grouped_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _grouped_in_place(x, stack, layer, into, tile_group, n_tiles, tile_m):
-    del into
-    return gmm(x, stack, tile_group, n_tiles, layer, tile_m=tile_m)
-
-
-def _in_place_fwd(x, stack, layer, into, tile_group, n_tiles, tile_m):
-    return gmm(x, stack, tile_group, n_tiles, layer, tile_m=tile_m), \
-        (x, stack, layer, into, tile_group, n_tiles)
-
-
-def _in_place_bwd(tile_m, res, dy):
-    x, stack, layer, into, tile_group, n_tiles = res
-    dx = gmm(dy, stack, tile_group, n_tiles, layer, tile_m=tile_m,
-             transpose_rhs=True, name="tepdist_gmm_dx")
-    into = tgmm(x, dy, tile_group, n_tiles, stack.shape[1], into, layer,
-                tile_m=tile_m)
-    return dx, None, None, into, None, None
-
-
-_grouped_in_place.defvjp(_in_place_fwd, _in_place_bwd)
+grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)

@@ -123,6 +123,9 @@ def test_the_stacked_walk_is_the_layer_loop(micro, monkeypatch):
         # written-out backward.
         assert len(stacks) >= 3, handed
         assert metrics().gauge("moe_stack_in_place_calls").value == 4 * 12
+        # The activation in the walk's first forward, the addend in the
+        # second input gradient: two a gated expert layer.
+        assert metrics().gauge("moe_epilogue_calls").value == 4 * 2
     else:
         assert not stacks
 

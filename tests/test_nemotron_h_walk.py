@@ -114,6 +114,9 @@ def test_the_stacked_accumulating_walk_is_the_layer_loop(monkeypatch):
     # Two matmuls an expert, each forward, recomputed, its input's and its
     # weight's gradient, in four expert layers.
     assert metrics().gauge("moe_stack_in_place_calls").value == 4 * 8
+    # One epilogue a layer: the up projection's activation in the walk's
+    # first forward; an expert of two matrices has no second input gradient.
+    assert metrics().gauge("moe_epilogue_calls").value == 4
 
 
 def test_the_gauges_of_a_traced_step():

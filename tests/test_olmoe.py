@@ -423,11 +423,10 @@ def _one_by_one(h, weights, experts, w_gate, w_up, w_down, num_experts,
     rows = gm.dispatch(h, r.row_token, r.dest)
     row_weight = gm.dispatch_values(weights, r)
 
-    def mm(a, w):
-        return gmk.grouped_matmul(a, w, r.tile_group, r.n_tiles, tile_m)
-
-    act = gm.gated(mm(rows, w_gate), mm(rows, w_up), row_weight)
-    return gm.combine(mm(act, w_down), r.row_token, r.dest)
+    act = gm.activation(rows, w_gate, w_up, row_weight, r.tile_group,
+                        r.n_tiles, tile_m)
+    return gm.combine(gmk.grouped_matmul(
+        act, w_down, r.tile_group, r.n_tiles, tile_m), r.row_token, r.dest)
 
 
 @pytest.mark.parametrize("held", [None, "whole"])

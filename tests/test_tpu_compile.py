@@ -290,6 +290,56 @@ def test_grouped_matmul_stack_forms_compile_for_v5e(v5e_devices, L, E, K, N,
         and "output_to_operand_aliasing" in made[0], made
 
 
+# The epilogues at the cells' shapes that differ in tiling: OLMoE's whole
+# layer (81,920 rows, 64 experts of 2048 x 1024), Mellum2's smaller layout
+# (16 of 2304 x 896: a column block 896 wide) and Qwen3-Next's (64 held
+# experts of 2048 x 512, the smaller of its layout's sizes); tile 256.
+@pytest.mark.parametrize("L", [0, 4], ids=["sliced", "stacked"])
+@pytest.mark.parametrize("E,d,f,rows", [
+    (64, 2048, 1024, 81920), (16, 2304, 896, 53504), (64, 2048, 512, 32000)])
+def test_grouped_matmul_epilogues_compile_for_v5e(v5e_devices, E, d, f, rows,
+                                                  L):
+    """The up projection with each activation as its epilogue and the input
+    gradient that adds another, not interpreted, over ``[E, K, N]`` and
+    over ``[L, E, K, N]`` with the layer's index: three kernels under the
+    names they had, the addend's buffer the result's, and no operation of
+    XLA's on an ``[rows, .]`` array."""
+    from tepdist_tpu.ops.pallas import grouped_matmul as gmm
+    one_chip = SingleDeviceSharding(v5e_devices[0])
+    tile = 256
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def three(x, gate, d_up, dx, row_weight, w, tile_group, n_tiles, layer):
+        layer = (layer,) if L else ()
+        kw = dict(tile_m=tile, interpret=False)
+        return (gmm.gmm(x, w, tile_group, n_tiles, *layer,
+                        act=(gate, row_weight), **kw),
+                gmm.gmm(x, w, tile_group, n_tiles, *layer,
+                        act=(row_weight,), **kw),
+                gmm.gmm(d_up, w, tile_group, n_tiles, *layer, add=dx,
+                        transpose_rhs=True, name="tepdist_gmm_dx", **kw))
+
+    text = jax.jit(three, donate_argnums=3).lower(
+        sds((rows, d)), sds((rows, f)), sds((rows, f)), sds((rows, d)),
+        sds((rows, 1), jnp.float32), sds((L, E, d, f)[not L:]),
+        sds((rows // tile,), jnp.int32), sds((1,), jnp.int32),
+        sds((1,), jnp.int32)).compile().as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if " custom-call(" in line
+             and "tepdist_gmm_" in line.split(" = ", 1)[0]]
+    assert sorted(re.match(r"%(\w+)", c)[1] for c in calls) == [
+        "tepdist_gmm_dx", "tepdist_gmm_fwd", "tepdist_gmm_fwd"], calls
+    assert ["output_to_operand_aliasing" in c for c in calls
+            if "%tepdist_gmm_dx" in c] == [True]
+    # The rows' weights go in one to a lane (a bitcast), and nothing else
+    # makes an array as wide as the rows' or the result's.
+    assert not [line for line in text.splitlines() if re.search(
+        rf"= \w+\[{rows},\d\d+\]\S* (?!parameter\(|custom-call\()", line)]
+    assert not re.search(rf"= f32\[{rows},1\]\S* copy\(", text)
+
+
 def test_a_held_share_with_its_switch_compiles_for_v5e(v5e_devices,
                                                        monkeypatch):
     """``routed_experts`` at the Mellum2 cell's shapes (16,384 tokens, 16 of

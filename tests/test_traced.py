@@ -77,7 +77,7 @@ def test_every_declared_gauge_says_what_it_counts():
     for name in ("attn_kept_calls", "attn_kept_bytes", "ssm_scan_calls",
                  "ssm_boundary_bytes", "ssm_conv_calls",
                  "moe_rows_sum_calls", "moe_stack_in_place_calls",
-                 "lin_attn_calls", "topk_attn_calls",
+                 "moe_epilogue_calls", "lin_attn_calls", "topk_attn_calls",
                  "topk_attn_keys_per_query", "topk_attn_dense_calls",
                  "ce_fused_chunks", "ga_fused_bytes", "ga_unfused_bytes",
                  "flash_bwd_calls"):

@@ -28,11 +28,19 @@ against the weight gradient with XLA's slice, add and update of the
 accumulator (``weight_grad.sliced``: what a walk over slices runs a layer).
 With ``--check 1`` each is held to its counterpart bit for bit.
 
+``--epilogue 1`` times the calls that carry an epilogue beside what they
+replace: ``input_grad.add`` (the addend's buffer the result's) against
+``input_grad.xla_add`` (the kernel, then XLA's add of the two results), and
+``forward.act`` (a gate's forward, then the forward whose epilogue is
+``silu(gate) * product * row_weight``) against ``forward.xla_act`` (two
+forwards, then XLA's ``gated``). With ``--check 1`` all four are held to the
+host's float32 loop.
+
 No benchmark cell runs this; it is for work on the kernels. No CPU fallback.
 
 Run: chiprun -- python tools/gmm_bench.py [--rows 65536] [--k 2048]
      [--n 1024] [--groups 64] [--sizes balanced] [--tile-m 128,256,512]
-     [--block-n 1024] [--check 0] [--stack 0]
+     [--block-n 1024] [--check 0] [--stack 0] [--epilogue 0]
 """
 
 from __future__ import annotations
@@ -124,6 +132,8 @@ def main(argv=None) -> int:
     ap.add_argument("--check", type=int, default=1)
     ap.add_argument("--stack", type=int, default=0,
                     help="layers of a stack to time the other forms on")
+    ap.add_argument("--epilogue", type=int, default=0,
+                    help="1: time the calls with an addend or an activation")
     ap.add_argument("--iters", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=None)
@@ -147,6 +157,8 @@ def main(argv=None) -> int:
     bf16 = jnp.bfloat16
     kx, kw, ky = jax.random.split(jax.random.PRNGKey(args.seed), 3)
     w = (jax.random.normal(kw, (G, K, N), jnp.float32) * 0.02).astype(bf16)
+    # The epilogue's gate is another product's result.
+    w_gate = jnp.flip(w, 0) if args.epilogue else None
     records, sound = [], True
     if args.stack:      # the weight is the middle layer of the stack
         at = args.stack // 2
@@ -164,21 +176,26 @@ def main(argv=None) -> int:
         ids = rng.permutation(np.repeat(np.arange(G), sizes)).astype(np.int32)
         x = jax.random.normal(kx, (R, K), jnp.float32).astype(bf16)
         dy = jax.random.normal(ky, (R, N), jnp.float32).astype(bf16)
+        row_weight = jax.random.uniform(ky, (R, 1), jnp.float32, 0.1, 1.0)
         want = loop_reference(x, dy, w, ids) if args.check else None
         for tm in (int(t) for t in args.tile_m.split(",")):
             r = layout.route(jnp.asarray(ids)[:, None], G, tm)
             xp = layout.dispatch(x, r.row_token, r.dest)
             dyp = layout.dispatch(dy, r.row_token, r.dest)
+            rwp = layout.dispatch_values(row_weight, r)
             tg, nt = r.tile_group, r.n_tiles
             for bn in (int(b) for b in args.block_n.split(",")):
+                def fwd(x, w, *layer, **kw):
+                    return kernels.gmm(x, w, tg, nt, *layer, tile_m=tm,
+                                       block_n=bn, interpret=False, **kw)
+
+                def dx(dy, w, *layer, **kw):
+                    return fwd(dy, w, *layer, transpose_rhs=True,
+                               name="tepdist_gmm_dx", **kw)
+
                 forms = {
-                    "forward": jax.jit(lambda x, w: kernels.gmm(
-                        x, w, tg, nt, tile_m=tm, block_n=bn,
-                        interpret=False)),
-                    "input_grad": jax.jit(lambda dy, w: kernels.gmm(
-                        dy, w, tg, nt, tile_m=tm, block_n=bn,
-                        transpose_rhs=True, name="tepdist_gmm_dx",
-                        interpret=False)),
+                    "forward": jax.jit(fwd),
+                    "input_grad": jax.jit(dx),
                     "weight_grad": jax.jit(lambda x, dy: kernels.tgmm(
                         x, dy, tg, nt, G, tile_m=tm, block_n=bn,
                         interpret=False)),
@@ -194,13 +211,10 @@ def main(argv=None) -> int:
                             acc, one + dw(x, dy), layer[0], 0)
 
                     forms.update({
-                        "forward.stack": jax.jit(lambda x, s: kernels.gmm(
-                            x, s, tg, nt, layer, tile_m=tm, block_n=bn,
-                            interpret=False)),
-                        "input_grad.stack": jax.jit(lambda dy, s: kernels.gmm(
-                            dy, s, tg, nt, layer, tile_m=tm, block_n=bn,
-                            transpose_rhs=True, name="tepdist_gmm_dx",
-                            interpret=False)),
+                        "forward.stack": jax.jit(
+                            lambda x, s: fwd(x, s, layer)),
+                        "input_grad.stack": jax.jit(
+                            lambda dy, s: dx(dy, s, layer)),
                         "weight_grad.into": jax.jit(
                             lambda x, dy, acc: kernels.tgmm(
                                 x, dy, tg, nt, G, acc, layer, tile_m=tm,
@@ -214,6 +228,26 @@ def main(argv=None) -> int:
                         "weight_grad.into": (xp, dyp, jnp.copy(acc)),
                         "weight_grad.sliced": (xp, dyp, jnp.copy(acc))})
                     carried = {"weight_grad.into": 2, "weight_grad.sliced": 2}
+                if args.epilogue:
+                    forms.update({
+                        "input_grad.add": jax.jit(
+                            lambda dy, w, first: dx(dy, w, add=first),
+                            donate_argnums=2),
+                        "input_grad.xla_add": jax.jit(
+                            lambda dy, w, first: first + dx(dy, w),
+                            donate_argnums=2),
+                        "forward.act": jax.jit(lambda x, w, wg, rw: fwd(
+                            x, w, act=(fwd(x, wg), rw))),
+                        "forward.xla_act": jax.jit(
+                            lambda x, w, wg, rw: layout.gated(
+                                fwd(x, wg), fwd(x, w), rw))})
+                    operands.update({
+                        "input_grad.add": (dyp, w, jnp.copy(xp)),
+                        "input_grad.xla_add": (dyp, w, jnp.copy(xp)),
+                        "forward.act": (xp, w, w_gate, rwp),
+                        "forward.xla_act": (xp, w, w_gate, rwp)})
+                    carried.update({"input_grad.add": 2,
+                                    "input_grad.xla_add": 2})
                 rec = {"impl": "pallas", "sizes": label, "rows": R, "K": K,
                        "N": N, "groups": G, "rows_max": int(sizes.max()),
                        "rows_min": int(sizes.min()), "tile_m": tm,
@@ -268,6 +302,28 @@ def main(argv=None) -> int:
                                         xp, dyp, jnp.copy(acc))))}
                             c["sound"] = c["sound"] and all(
                                 c["stack_bit_for_bit"].values())
+                        if args.epilogue:
+                            # The addend: the rows themselves, K wide.
+                            first = np.asarray(x, np.float32)
+                            gate = loop_reference(x, dy, w_gate, ids)[0]
+                            act = gate / (1.0 + np.exp(-gate)) * want[0] \
+                                * np.asarray(row_weight)
+
+                            def fresh(f):   # a donated addend is spent
+                                if f not in carried:
+                                    return operands[f]
+                                return (*operands[f][:2], jnp.copy(xp))
+
+                            c["epilogue_rel_l2"] = {
+                                f: rel_l2(np.asarray(
+                                    forms[f](*fresh(f)))[dest], ref)
+                                for f, ref in (
+                                    ("input_grad.add", want[1] + first),
+                                    ("input_grad.xla_add", want[1] + first),
+                                    ("forward.act", act),
+                                    ("forward.xla_act", act))}
+                            c["sound"] = c["sound"] and max(
+                                c["epilogue_rel_l2"].values()) < CHECK_LIMIT
                         sound = sound and c["sound"]
                         del got
                     total = 0.0
@@ -277,8 +333,9 @@ def main(argv=None) -> int:
                                 trace_root, f"{label}_{tm}_{bn}_{f}"),
                             carried.get(f))
                         rec[f] = {"us_per_call": us, "top_ops": ops,
-                                  "roofline_share_pct":
-                                      100.0 * rec["roofline_us"] / us}
+                                  "roofline_share_pct":     # two products
+                                      100.0 * rec["roofline_us"] / us
+                                      * (1 + f.endswith("act"))}
                         total += us * ("." not in f)
                     rec["three_forms_us"] = total
                 except Exception as e:  # noqa: BLE001 — one refused variant

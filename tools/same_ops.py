@@ -5,8 +5,9 @@ under ``benchmark/`` and counts the optimized HLO's instructions by (opcode,
 result shape with layout, a custom call's kernel). In a copy of the parent
 ``JAX_PLATFORMS=cpu python tools/same_ops.py <cell> --save p.json``, in the
 change ``... <cell> --against p.json``: the last line is one JSON object with
-``same`` and what differs (exit 1 if anything). It says the operations are
-the parent's, not what they cost."""
+``same`` and what differs (exit 1 if anything), beside the gauges the step's
+trace set (``telemetry/traced.py``: a kernel's calls a micro batch). It says
+the operations are the parent's, not what they cost."""
 
 import argparse
 import collections
@@ -87,8 +88,11 @@ def main() -> int:
     a = ap.parse_args()
     with described_v5e() as devices:
         compiled, _ = compiled_step(a.cell, devices[0])
+    from tepdist_tpu.telemetry import traced
     counts = histogram(compiled.as_text())
-    out = {"cell": a.cell, "instructions": sum(counts.values()),
+    out = {"cell": a.cell, "gauges": {k: v for k, v in traced.values().items()
+                                      if v},
+           "instructions": sum(counts.values()),
            "kinds": len(counts), "digest": hashlib.sha256(
                json.dumps(counts).encode()).hexdigest()[:16],
            "peak_bytes": compiled.memory_analysis().peak_memory_in_bytes}
