@@ -76,6 +76,7 @@ from tepdist_tpu.models.layers import (
     rms_norm,
 )
 from tepdist_tpu.ops.grouped_matmul import routed_experts
+from tepdist_tpu.ops.pallas.router_choice import choose
 
 WINDOW, GLOBAL = "sliding_attention", "full_attention"
 
@@ -241,16 +242,9 @@ def router(blk, h, cfg):
     scores, normalised over the k chosen and scaled."""
     logits = jnp.dot(h, blk["router"], preferred_element_type=jnp.float32)
     scores = jax.nn.sigmoid(logits)
-    _, experts = jax.lax.top_k(
-        scores + jax.lax.stop_gradient(blk["router_bias"]),
-        cfg.num_experts_per_tok)
-    # Each choice's score by compare and sum: a look-up of single
-    # elements (take_along_axis) runs at 8 ns an element on a v5e, and its
-    # gradient is a scatter (PERF.md section 5).
-    chosen = jnp.sum(jnp.where(
-        experts[..., None] == jnp.arange(scores.shape[-1],
-                                         dtype=experts.dtype),
-        scores[:, None, :], 0.0), axis=-1)
+    chosen, experts = choose(
+        scores, cfg.num_experts_per_tok,
+        select=scores + jax.lax.stop_gradient(blk["router_bias"]))
     weights = chosen / (chosen.sum(-1, keepdims=True) + 1e-20) \
         * cfg.route_scale
     return scores, count_choices(weights, blk["router_bias"], experts), \

@@ -442,6 +442,9 @@ def _chunks_carrying(fn, weights, starts, xs):
     to a zeroed carry and the total to the accumulator, here each straight
     to the accumulator."""
     ws, tree = jax.tree_util.tree_flatten(weights, is_leaf=_is_stack)
+    # The backward rule is traced after the walk's body has returned: what
+    # it traces of ``fn`` stands for the layers this call stands for.
+    layers = traced.stood_for()
 
     def whole(plain, fixed, intos):
         """``weights`` again: its plain leaves, its stacks with their layer
@@ -477,9 +480,10 @@ def _chunks_carrying(fn, weights, starts, xs):
                 sums = [s + g for s, g in zip(sums, d_plain)]
             return (sums, intos), tuple(d_chunks)
 
-        (sums, intos), d_xs = jax.lax.scan(
-            step, ([jnp.zeros_like(w) for w in plain], intos),
-            (starts, xs, d_out), reverse=True)
+        with traced.stands_for(layers / traced.stood_for()):
+            (sums, intos), d_xs = jax.lax.scan(
+                step, ([jnp.zeros_like(w) for w in plain], intos),
+                (starts, xs, d_out), reverse=True)
         return sums, None, intos, d_xs
 
     chunked.defvjp(fwd, bwd)

@@ -64,6 +64,7 @@ from tepdist_tpu.models.layers import (
     yarn_table,
 )
 from tepdist_tpu.ops.grouped_matmul import routed_experts
+from tepdist_tpu.ops.pallas.router_choice import choose
 
 WINDOW, GLOBAL = "sliding_attention", "full_attention"
 
@@ -191,8 +192,8 @@ def router(blk, h, cfg: MellumConfig):
     """h [S, d] -> (weights [S, k], expert ids [S, k]): the top k of the
     float32 softmax over all experts, normalised over the k chosen."""
     logits = jnp.dot(h, blk["router"], preferred_element_type=jnp.float32)
-    chosen, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
-                                    cfg.num_experts_per_tok)
+    chosen, experts = choose(jax.nn.softmax(logits, axis=-1),
+                             cfg.num_experts_per_tok)
     return chosen / chosen.sum(-1, keepdims=True), experts
 
 

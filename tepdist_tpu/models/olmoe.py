@@ -57,6 +57,7 @@ from tepdist_tpu.ops.grouped_matmul import (  # noqa: F401 — gated: tests
     route,
     routed_experts,
 )
+from tepdist_tpu.ops.pallas.router_choice import choose
 
 
 @dataclasses.dataclass(frozen=True)
@@ -169,7 +170,7 @@ def router(blk, h, cfg: OlmoeConfig):
     weights [S, k] as they leave the softmax, expert ids [S, k])."""
     logits = jnp.dot(h, blk["router"], preferred_element_type=jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
-    weights, experts = jax.lax.top_k(probs, cfg.num_experts_per_tok)
+    weights, experts = choose(probs, cfg.num_experts_per_tok)
     return logits, probs, weights, experts
 
 

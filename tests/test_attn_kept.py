@@ -131,8 +131,15 @@ def one_forward_everywhere(monkeypatch):
                               nondiff_argnums=(6,))
     composed.defvjp(gm._activation_fwd, gm._activation_bwd)
     monkeypatch.setattr(gm, "activation", composed)
-    monkeypatch.setattr(gm, "_branch", jax.jit(     # no trace from before
-        gm.routed_experts_at, inline=True, static_argnames="tile_m"))
+    # No trace from before: ``jax.jit`` keeps its traces by the function it
+    # wraps, so a new ``jit`` of ``routed_experts_at`` itself would find
+    # what an earlier test file of this process traced at these shapes
+    # (``tests/test_stack_in_place.py``'s steps, with the epilogue).
+    def branch(*args, **static):
+        return gm.routed_experts_at(*args, **static)
+
+    monkeypatch.setattr(gm, "_branch", jax.jit(
+        branch, inline=True, static_argnames="tile_m"))
 
 
 @pytest.mark.parametrize("case", list(CASES))

@@ -50,12 +50,14 @@ T, CHUNKS = 32, 4           # a sequence, and sarvam's chunks where forced
 
 class Built(NamedTuple):
     """One traced step: what it returns, the gauge its trace set, the
-    moves of a layer's experts in its lowered text (``_expert_leaf_moves``)
-    and the rank of every grouped-matmul call's weight."""
+    moves of a layer's experts in its lowered text (``_expert_leaf_moves``),
+    the rank of every grouped-matmul call's weight and the routers'
+    differentiated choices its trace counted (``router_choice_calls``)."""
     out: Any
     gauge: float
     moves: Tuple[str, ...]
     ranks: Tuple[int, ...]
+    choices: float
 
 
 def _built(name, micro, sliced=False, chunks=1) -> Built:
@@ -94,6 +96,7 @@ def _trace(name, micro, sliced, chunks) -> Built:
                           2 * (T // chunks) * model._widest(cfg))
         traced = step.trace(*args)
     gauge = metrics().gauge("moe_stack_in_place_calls").value
+    choices = metrics().gauge("router_choice_calls").value
     lowered = traced.lower()
     return Built(
         lowered.compile()(*args), gauge,
@@ -101,7 +104,8 @@ def _trace(name, micro, sliced, chunks) -> Built:
         tuple(max(v.aval.ndim for v in (*e.invars, *e.outvars))
               for e in equations_of(traced.jaxpr.jaxpr)
               if e.primitive.name == "pallas_call"
-              and e.params["name"].startswith("tepdist_gmm_")))
+              and e.params["name"].startswith("tepdist_gmm_")),
+        choices)
 
 
 def _expert_leaf_moves(text, blocks):
@@ -160,10 +164,13 @@ def test_chunks_add_into_the_accumulator_one_rounding_a_chunk(micro):
     accumulator), so Adam's first moment, a tenth of the gradient, lies
     within a bf16 addition's rounding a chunk and one more of the sliced
     walk's; every other leaf, the loss and the router's bias bit for bit.
-    The gauge counts a layer's twelve calls once, whatever its chunks."""
+    The gauge counts a layer's twelve calls once, whatever its chunks, and
+    so does the routers' (the written-out chunk loop differentiates a chunk
+    after the walk's body has returned, and still for its layers)."""
     got = _built("sarvam", micro, chunks=CHUNKS)
     want = _built("sarvam", micro, sliced=True, chunks=CHUNKS)
     assert got.gauge == 12 * 2 and want.gauge == 0
+    assert got.choices == want.choices == 2
     experts = moved = 0
     for (path, a), (_, b) in zip(_leaves(got), _leaves(want)):
         if not any(f"['blocks']['{k}']" in path for k in EXPERT_LEAVES):

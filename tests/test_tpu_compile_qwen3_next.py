@@ -5,6 +5,8 @@ v5e (no chip): ``tests/test_tpu_compile.py``'s cases for
 other than that file's takes them (the suite is dealt out a file at a time).
 A compile that passes is NOT a chip run: nothing executes here."""
 
+import itertools
+import math
 import os
 import re
 
@@ -12,8 +14,11 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
 import jax
 import jax.numpy as jnp
+import pytest
 from jax.sharding import SingleDeviceSharding
 from test_tpu_compile import v5e_devices  # noqa: F401 — the fixture
+
+CELL = "qwen3-next-80b-a3b.train.s8192"
 
 
 def _custom_calls(text):
@@ -86,7 +91,18 @@ def test_flash_at_a_head_width_of_256_compiles_for_v5e(v5e_devices):
     assert sum("tepdist_flash_dkv" in n for n in names) == 1, names
 
 
-def test_the_qwen3_next_cells_step_compiles_for_v5e(v5e_devices):
+@pytest.fixture(scope="module")
+def cell_step(v5e_devices):
+    """(the cell's compiled step, its parameters' shapes, its gauges), one
+    compile for the tests below; a later trace in this process zeroes the
+    gauges, so they are read here."""
+    from tepdist_tpu.telemetry import traced
+    from tools.same_ops import compiled_step
+    compiled, params = compiled_step(CELL, v5e_devices[0])
+    return compiled, params, traced.values()
+
+
+def test_the_qwen3_next_cells_step_compiles_for_v5e(cell_step):
     """``qwen3-next-80b-a3b.train.s8192``'s step from the cell's own files
     (8 micro batches of one 8,192-token sequence; four layers in two walks
     of unequal shape; ``adamw_bf16``), kernels not interpreted: every walk's
@@ -95,13 +111,11 @@ def test_the_qwen3_next_cells_step_compiles_for_v5e(v5e_devices):
     3) and the flash forward once, the experts' stacks are read where they
     lie, and the compiler's peak is under 15.0e9 bytes."""
     from benchmark.lib import cells
-    from tepdist_tpu.telemetry import metrics
-    from tools.same_ops import compiled_step
-    T, cell = 8192, "qwen3-next-80b-a3b.train.s8192"
-    compiled, params = compiled_step(cell, v5e_devices[0])
+    T, cell = 8192, CELL
+    compiled, params, gauges = cell_step
     print("peak", compiled.memory_analysis().peak_memory_in_bytes)
 
-    gauge = lambda n: metrics().gauge(n).value              # noqa: E731
+    gauge = gauges.get
     n_params = sum(a.size for a in jax.tree_util.tree_leaves(params))
     assert n_params == 1_028_320_320
     stacks = sum(a.size * a.dtype.itemsize for r in range(2)
@@ -142,3 +156,36 @@ def test_the_qwen3_next_cells_step_compiles_for_v5e(v5e_devices):
     assert [c for c in calls if "tepdist_conv_fwd" in c] \
         and [c for c in calls if "tepdist_gmm_" in c], calls
     assert compiled.memory_analysis().peak_memory_in_bytes < 15.0e9
+
+
+def test_the_routers_choice_is_one_kernel_and_no_sort_no_scatter(cell_step):
+    """The step's four routers (10 of 512 experts, 8,192 tokens) choose in
+    ``tepdist_router_choice``, once in each walk's forward loop and once in
+    its backward loop's recomputation: nothing in the compiled step sorts or
+    scatters an array of tokens x experts elements (``lax.top_k``'s sort,
+    the gradient of its values), the scores reach the kernel experts-major
+    without a copy, and no ``[tokens, k, experts]`` comparison is named
+    anywhere."""
+    from benchmark.lib import cells
+    compiled, _, gauges = cell_step
+    model = cells.load_cell(CELL).config
+    S, E, k = 8192, model["router_num_experts"], model["num_experts_per_tok"]
+    assert (E, k) == (512, 10) and gauges["router_choice_calls"] == 4
+    text = compiled.as_text()
+    assert len([c for c in _custom_calls(text)
+                if "tepdist_router_choice" in c]) == 2 * 2
+
+    def sizes(line):
+        return [math.prod(map(int, shape.split(",")))
+                for shape in re.findall(r"\w+\[([\d,]+)\]", line)]
+
+    wide = [line.strip()[:160] for line in text.splitlines()
+            if re.search(r" (sort|scatter)\(", line)
+            and S * E in sizes(line)]
+    assert not wide, wide[:2]
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(rf"= f32\[(?:{S},{E}|{E},{S})\]\S* "
+                          r"(?:copy|transpose)\(", line)]
+    assert not moved, moved[:2]
+    assert not [shape for shape in itertools.permutations((S, k, E))
+                if "[%d,%d,%d]" % shape in text]

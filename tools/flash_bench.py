@@ -39,8 +39,11 @@ sys.path.insert(0, ROOT)
 
 
 def load_impl(label: str, path: str):
-    spec = importlib.util.spec_from_file_location("flash_impl_" + label, path)
-    module = importlib.util.module_from_spec(spec)
+    """The file at ``path`` as a module of its own, known to ``sys.modules``
+    (a ``dataclass`` in it looks its module up there)."""
+    name = "flash_impl_" + label
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = sys.modules[name] = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
 
