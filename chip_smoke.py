@@ -1,6 +1,6 @@
 """Quickest proof that the main path still starts on the chip.
 
-    python chip_smoke.py            # one chip: phases A, B, M, Z and K
+    python chip_smoke.py            # one chip: phases A, B, M, Z, K, Q, N, X
     python chip_smoke.py --chips 4  # one host, four chips: that phase only
 
 Drives GPT-2 117M at published widths (12 x 768 x 12 heads, vocab 50257,
@@ -54,6 +54,15 @@ through the entry points a user calls:
            scalar-decay kernels, the conv pair, the flash kernels and the
            grouped matmuls are in the compiled step and that the delta
            rule's forward ran once a Gated-DeltaNet layer.
+  phase X  the same for ``models/xing.py`` at its ``smoke`` preset (a
+           residual stream of four lanes of 256 mixed by hyper-connection
+           maps with their 20 Sinkhorn rounds, latent attention behind a
+           query latent at 4 heads of the published 128 + 64 / 128, 2 of 8
+           sigmoid-routed experts, one prediction module), 2 micro batches
+           of one 1024-token sequence, 5 steps; asserts the latent kernels
+           and the grouped matmuls are in the compiled step, that the three
+           walks (dense, expert layers, prediction module) kept one forward
+           a layer, and that the second loss weighted its positions.
   --chips 4  one child owning all four chips: ``plan_training(explore=True)``
            over ``jax.devices()`` at batch 16, 5 steps, then the same 5 steps
            on ``devices[:1]``; every device must hold a shard and the
@@ -500,6 +509,39 @@ def phase_nemotron(preset: str = "smoke", batch: int = 2, seq: int = 1024,
 
 
 # ---------------------------------------------------------------------------
+# Phase X: a residual stream of four lanes, a query latent, and a second loss
+# through the shared head; three walks.
+# ---------------------------------------------------------------------------
+
+def phase_xing(preset: str = "smoke", batch: int = 2, seq: int = 1024,
+               platform: str = "tpu") -> dict:
+    from tepdist_tpu.models import xing
+
+    cfg = xing.CONFIGS[preset]
+    devices, tplan, tokens, gauges = _plan_zoo_model(
+        xing, cfg, batch, seq, platform)
+    layers = cfg.num_hidden_layers + cfg.num_nextn_predict_layers
+    _check(gauges["attn_kept_calls"] == gauges["mla_fwd_calls"]
+           == gauges["mla_bwd_calls"] == layers,
+           f"phase X: the walks kept {gauges['attn_kept_calls']} forward "
+           f"passes and counted {gauges['mla_bwd_calls']} backward calls "
+           f"of {layers} layers, the prediction module's among them")
+    _check(gauges["residual_lanes"] == cfg.hc_mult
+           and gauges["mhc_sinkhorn_rounds"] == cfg.hc_sinkhorn_iters
+           and gauges["mhc_stream_bytes"]
+           == batch // 2 * seq * cfg.hc_mult * cfg.hidden_size * 2
+           and gauges["mtp_depth"] == 1
+           and gauges["ce_weighted_positions"] == batch // 2 * seq,
+           f"phase X: the gauges read {gauges['residual_lanes']} lanes of "
+           f"{gauges['mhc_stream_bytes']} bytes, {gauges['mtp_depth']} "
+           f"prediction module(s) and {gauges['ce_weighted_positions']} "
+           "weighted positions")
+    return _step_zoo_model(
+        "X", f"xing-{preset}", devices, tplan, tokens, gauges, platform,
+        ("tepdist_mla_fwd", "tepdist_mla_dkv", "tepdist_gmm_fwd"))
+
+
+# ---------------------------------------------------------------------------
 # Four chips: explored layout over the host's devices vs the same steps on
 # one of them, in one process that owns all four.
 # ---------------------------------------------------------------------------
@@ -562,7 +604,7 @@ def phase_four(cfg_name: str = "117M", batch: int = 16, seq: int = 1024,
 CHILD_PHASES = {"phase_b": phase_b, "phase_four": phase_four,
                 "phase_mla": phase_mla, "phase_zaya": phase_zaya,
                 "phase_kimi": phase_kimi, "phase_qwen": phase_qwen,
-                "phase_nemotron": phase_nemotron}
+                "phase_nemotron": phase_nemotron, "phase_xing": phase_xing}
 
 
 def _run_child(phase: str) -> dict:
@@ -613,6 +655,7 @@ def main() -> None:
         _emit(_run_child("phase_kimi"))
         _emit(_run_child("phase_qwen"))
         _emit(_run_child("phase_nemotron"))
+        _emit(_run_child("phase_xing"))
     _check(holder["platform"] == "tpu" and holder["n_devices"] == args.chips,
            f"ran on {holder['n_devices']} {holder['platform']} device(s), "
            f"wanted {args.chips} tpu")

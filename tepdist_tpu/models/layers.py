@@ -92,6 +92,9 @@ traced.declare(
 traced.declare(
     "ce_fused_chunks", "chunks of the loss whose gradients its forward chunk "
     "loop makes (0: the dense loss, or a call nobody differentiates)")
+traced.declare(
+    "ce_weighted_positions", "positions a micro batch hands to a cross "
+    "entropy with a weight a position (0: every loss is a plain mean)")
 
 
 class BlockGradSink:
@@ -508,6 +511,173 @@ def rms_norm0(x, w, eps: float = 1e-6):
     return rms_norm(x, 1.0 + w.astype(jnp.float32), eps)
 
 
+def _hyper_lanes(maps) -> int:
+    """``n`` of maps ``n + n + n^2`` wide."""
+    n = math.isqrt(maps.shape[-1] + 1) - 1
+    if n * n + 2 * n != maps.shape[-1]:
+        raise ValueError(f"{maps.shape[-1]} is no n^2 + 2n of hyper maps")
+    return n
+
+
+def _total(terms):
+    """The terms' sum, first to last (no zero to start from)."""
+    return functools.reduce(lambda a, t: a + t, terms)
+
+
+def _columns(M):
+    """[n, n, ...] -> its columns' sums [n, ...], adds of whole slices."""
+    return _total(M[i] for i in range(M.shape[0]))
+
+
+def _rows(M):
+    return _total(M[:, j] for j in range(M.shape[1]))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _sinkhorn(M, rounds: int, hc_eps: float):
+    """``rounds`` times ``M / (colsum(M) + hc_eps)`` then ``M / (rowsum(M)
+    + hc_eps)`` on ``M`` [n, n, ...] (entry ``[i, j]`` a slice): one
+    reciprocal a column or row and a multiply, **a loop of ``rounds``
+    trips** and not the rounds written out. Written out they are one chain
+    of elementwise operations, and the compiler does not keep a round's
+    matrix for the two things that want it: it makes it again from the
+    first one inside each (20 rounds: 4,000 fused operations and 108,000
+    instructions in the Xing cell's step, 3 minutes to compile, with
+    autodiff's backward or with this one). The loop costs nothing to see
+    (9 us of a 1,066 us read at the cell's shape; 4 or 20 rounds a trip
+    time no better: ``tools/mhc_bench.py``, PR 61). The backward pass
+    keeps the ``2 x rounds`` denominators [n, ...] and walks the rounds
+    back from the result, each round's input from its output: ``M_in =
+    M_out x denominator``."""
+    return _sinkhorn_fwd(M, rounds, hc_eps)[0]
+
+
+def _sinkhorn_fwd(M, rounds, hc_eps):
+    def one_round(M, _):
+        columns = _columns(M) + hc_eps
+        M = M * (1.0 / columns)
+        rows = _rows(M) + hc_eps
+        return M * (1.0 / rows)[:, None], (columns, rows)
+
+    M, kept = jax.lax.scan(one_round, M, None, length=rounds)
+    return M, (M, kept)
+
+
+def _sinkhorn_bwd(rounds, hc_eps, res, g):
+    # out = M / den, den = sum(M) + hc_eps: d M = (g - sum(g out)) / den
+    def back(carry, kept):
+        g, M = carry
+        columns, rows = kept
+        g = (g - _rows(g * M)[:, None]) * (1.0 / rows)[:, None]
+        M = M * rows[:, None]
+        g = (g - _columns(g * M)) * (1.0 / columns)
+        return (g, M * columns), None
+
+    (g, _), _ = jax.lax.scan(back, (g, res[0]), res[1], reverse=True)
+    return (g,)
+
+
+_sinkhorn.defvjp(_sinkhorn_fwd, _sinkhorn_bwd)
+
+
+def hyper_maps(x, phi, b, alpha, *, rounds: int, eps: float,
+               hc_eps: float = 1e-6, clamp: Tuple[float, float] = (-30, 30)):
+    """The three maps of one sub-layer under manifold-constrained
+    hyper-connections (mHC, arXiv:2512.24880): ``x`` [B, T, n d], the
+    residual stream's ``n`` lanes side by side, ``phi`` [n d, n^2 + 2n],
+    ``b`` [n^2 + 2n], ``alpha`` [3] -> float32 [B, T, n^2 + 2n], a token's
+    ``H_pre`` [n] | ``H_post`` [n] | ``H_res`` [n, n] row by row:
+
+        m      = RMSNorm(x_t) phi       over the n d joined channels, no gain
+        H_pre  = sigmoid(alpha_0 m_pre + b_pre)
+        H_post = 2 sigmoid(alpha_1 m_post + b_post)
+        M_0    = exp(clip(alpha_2 m_res + b_res, clamp))
+        M     <- M / (colsum(M) + hc_eps), M <- M / (rowsum(M) + hc_eps)
+                 ``rounds`` times; H_res = M: positive, rows and columns
+                 summing to one (Sinkhorn-Knopp)
+
+    The norm has no gain, so ``RMSNorm(x) phi`` is ``(x phi) / rms(x)``: the
+    product takes the stream as it is stored (bf16 operands where it is
+    bf16, accumulated in float32) and no normalised copy of it is made.
+    Everything after the product is float32 with the tokens along the
+    lanes, ``m`` as ``[n^2 + 2n, B, T]`` and the mixing matrix ``[n, n, B,
+    T]``: a Sinkhorn round is adds of its rows or columns as whole slices,
+    one reciprocal a column or row and a multiply, nothing is reduced
+    across lanes and no ``reduce`` is asked for (:func:`_sinkhorn`)."""
+    n = _hyper_lanes(phi)
+    with jax.named_scope("mhc_maps"):
+        x32 = x.astype(jnp.float32)
+        scale = jax.lax.rsqrt((x32 * x32).mean(-1) + eps)        # [B, T]
+        m = jnp.moveaxis(jnp.dot(x, phi, preferred_element_type=jnp.float32),
+                         -1, 0) * scale
+        alpha = alpha.astype(jnp.float32)
+        b = b.astype(jnp.float32)[:, None, None]
+        pre = jax.nn.sigmoid(alpha[0] * m[:n] + b[:n])
+        post = 2.0 * jax.nn.sigmoid(alpha[1] * m[n:2 * n] + b[n:2 * n])
+        M = jnp.exp(jnp.clip(alpha[2] * m[2 * n:] + b[2 * n:], *clamp)
+                    ).reshape(n, n, *m.shape[1:])
+        M = _sinkhorn(M, rounds, hc_eps)
+        return jnp.moveaxis(jnp.concatenate(
+            [pre, post, M.reshape(n * n, *m.shape[1:])]), 0, -1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def lanes_of(x, n: int):
+    """``x`` [..., n d] -> its ``n`` lanes [..., d], float32: slices of
+    whole lane tiles of the last axis, never a reshape to ``[.., n, d]``
+    (another tiling on the chip). Their cotangents go back joined, one
+    array written once, where a slice's own transpose is a zero-padded
+    array the stream's size a lane, and their sum."""
+    d = x.shape[-1] // n
+    return tuple(x[..., i * d:(i + 1) * d].astype(jnp.float32)
+                 for i in range(n))
+
+
+def _lanes_fwd(x, n):
+    # The residual is there for its dtype alone.
+    return lanes_of(x, n), jnp.zeros((), x.dtype)
+
+
+def _lanes_bwd(n, like, cts):
+    return (jnp.concatenate(cts, axis=-1).astype(like.dtype),)
+
+
+lanes_of.defvjp(_lanes_fwd, _lanes_bwd)
+
+
+def _entry(maps, c: int):
+    """Entry ``c`` of every token's maps, [B, T, 1]: a slice."""
+    return jax.lax.slice_in_dim(maps, c, c + 1, axis=-1)
+
+
+def hyper_read(x, maps):
+    """A sub-layer's input: ``y_t = sum_i H_pre[i] x_t[i]`` [B, T, d] from
+    the stream ``x`` [B, T, n d] and :func:`hyper_maps`' ``maps``; the sum
+    in float32, back in ``x``'s dtype. A lane is a slice of whole lane
+    tiles of the last axis (``d`` a multiple of 128 at any published
+    width): the stream is never reshaped to ``[.., n, d]``."""
+    n = _hyper_lanes(maps)
+    with jax.named_scope("mhc_read"):
+        return _total(_entry(maps, i) * lane for i, lane in enumerate(
+            lanes_of(x, n))).astype(x.dtype)
+
+
+def hyper_write(x, maps, f):
+    """The stream after a sub-layer: ``x'_t[i] = sum_j H_res[i, j] x_t[j] +
+    H_post[i] f_t`` [B, T, n d] from the stream ``x``, the maps the
+    sub-layer's input was read under and its output ``f`` [B, T, d]; float32
+    sums, back in ``x``'s dtype. With one lane and maps of one this is ``x +
+    f``."""
+    n = _hyper_lanes(maps)
+    with jax.named_scope("mhc_write"):
+        lanes = lanes_of(x, n)
+        f32 = f.astype(jnp.float32)
+        out = [_total(_entry(maps, 2 * n + i * n + j) * lanes[j]
+                      for j in range(n)) + _entry(maps, n + i) * f32
+               for i in range(n)]
+        return jnp.concatenate(out, axis=-1).astype(x.dtype)
+
+
 def heads_matrix(width: int, heads: int):
     """float32 [width, heads], 1 where a channel is its head's: a head's
     sum as a matmul and a head's number spread over its channels as the
@@ -808,11 +978,16 @@ def held_routing_stats(ids, num_experts: int, tile_m: int,
     return {**out, "experts": ids, "held_rows": sizes}
 
 
-def cross_entropy(x, head, targets, chunk: int = 0):
+def cross_entropy(x, head, targets, chunk: int = 0, weights=None):
     """Mean next-token cross entropy from final hidden states ``x``
     [B, T, D] through the output head ``head`` [V, D] (GPT-2 hands in its
     tied embedding, a model with an untied head that head), optionally
-    chunked.
+    chunked. ``weights`` [B, T]: a weight a position, the mean taken over
+    the weights' sum (a loss that leaves positions out carries 0 there, and
+    needs no slice of the sequence that no chunk size divides:
+    ``models/xing.py``'s second loss); None is every position at 1, and
+    the program it always was. A model with two losses calls this twice on
+    one ``head``, whose gradient is then the sum of the two calls'.
 
     Dense path (``chunk <= 0``): logits = x @ head.T in one [B, T, V] fp32
     tensor, differentiated by autodiff.
@@ -834,21 +1009,28 @@ def cross_entropy(x, head, targets, chunk: int = 0):
 
     The gauge ``ce_fused_chunks`` is set while the call is traced: the
     chunks whose gradients the forward loop makes, 0 for a dense or an
-    undifferentiated call. All of it is the step's ``head_loss`` part."""
+    undifferentiated call; ``ce_weighted_positions`` counts the positions
+    of the calls that carry ``weights``. All of it is the step's
+    ``head_loss`` part."""
     with part("head_loss"):
-        return _cross_entropy(x, head, targets, chunk)
+        return _cross_entropy(x, head, targets, chunk, weights)
 
 
-def _cross_entropy(x, head, targets, chunk):
+def _cross_entropy(x, head, targets, chunk, weights):
     B, T, D = x.shape
     n_tokens = B * T
     traced.note("ce_fused_chunks", 0)
+    if weights is not None:
+        traced.count("ce_weighted_positions", n_tokens)
+        weights = weights.astype(jnp.float32)
     if chunk <= 0:
         logits = (x @ head.T).astype(jnp.float32)
         logz = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(
             logits, targets[..., None], axis=-1)[..., 0]
-        return jnp.mean(logz - gold)
+        if weights is None:
+            return jnp.mean(logz - gold)
+        return jnp.sum((logz - gold) * weights) / jnp.sum(weights)
 
     # Non-dividing counts get a zero-padded, masked tail chunk — the LM
     # loss always shifts tokens (n_tokens = B*(T-1) at the call site), so
@@ -858,7 +1040,8 @@ def _cross_entropy(x, head, targets, chunk):
     pad = n_chunks * chunk - n_tokens
     xf = x.reshape(n_tokens, D)
     tf = targets.reshape(n_tokens)
-    valid = jnp.ones((n_tokens,), jnp.float32)
+    valid = jnp.ones((n_tokens,), jnp.float32) if weights is None \
+        else weights.reshape(n_tokens)
     if pad:
         xf = jnp.concatenate([xf, jnp.zeros((pad, D), x.dtype)])
         tf = jnp.concatenate([tf, jnp.zeros((pad,), targets.dtype)])
@@ -866,6 +1049,10 @@ def _cross_entropy(x, head, targets, chunk):
     xf = xf.reshape(n_chunks, chunk, D)
     tf = tf.reshape(n_chunks, chunk)
     valid = valid.reshape(n_chunks, chunk)
+
+    def weight_sum(valid):
+        """What the sum is divided by: the positions, or their weights'."""
+        return n_tokens if weights is None else jnp.sum(valid)
 
     def chunk_loss(xc, head, tc, mc):
         logits = (xc @ head.T).astype(jnp.float32)       # [chunk, V]
@@ -881,12 +1068,13 @@ def _cross_entropy(x, head, targets, chunk):
 
         total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32),
                                 (xf, tf, valid))
-        return total / n_tokens
+        return total / weight_sum(valid)
 
     def fwd(xf, head, tf, valid):
         traced.note("ce_fused_chunks", n_chunks)
         # The dtype autodiff hands the logits' cotangent back in.
         d_dtype = jnp.result_type(xf.dtype, head.dtype)
+        over = weight_sum(valid)
 
         def body(carry, inp):
             acc, dhead = carry
@@ -894,7 +1082,7 @@ def _cross_entropy(x, head, targets, chunk):
             logits, logz, term = chunk_loss(xc, head, tc, mc)
             onehot = jax.nn.one_hot(tc, logits.shape[-1], dtype=logits.dtype)
             d = ((jnp.exp(logits - logz[:, None]) - onehot)
-                 * (mc / n_tokens)[:, None]).astype(d_dtype)
+                 * (mc / over)[:, None]).astype(d_dtype)
             dxc = (d @ head).astype(xc.dtype)
             dhead = dhead + jax.lax.dot_general(
                 d, xc, (((0,), (0,)), ((), ()))).astype(dhead.dtype)
@@ -903,7 +1091,7 @@ def _cross_entropy(x, head, targets, chunk):
         (total, dhead), dx = jax.lax.scan(
             body, (jnp.zeros((), jnp.float32), jnp.zeros_like(head)),
             (xf, tf, valid))
-        return total / n_tokens, (dx, dhead)
+        return total / over, (dx, dhead)
 
     def bwd(residuals, g):
         dx, dhead = residuals

@@ -303,7 +303,9 @@ def attention_inputs(blk, a, cfg, table: Optional[RopeTable], start=0):
     q_rope, k_nope [B, T, Hh, .], k_rope [B, T, 1, Dr], v [B, T, Hh, Dv] of
     the held heads, the rotary parts rotated under ``table``; as they are
     where it is None (a model whose latent layers carry no position:
-    ``models/kimi_linear.py``, whose ``cfg`` this also takes)."""
+    ``models/kimi_linear.py``, whose ``cfg`` this also takes). The query
+    is one projection ``wq``, or where ``blk`` holds ``wqa`` the two through
+    a latent, ``q = rms(a Wqa; q_ln) Wqb`` (``models/xing.py``)."""
     B, T, _ = a.shape
     Hh, R = cfg.heads_held[1], cfg.kv_lora_rank
     Dn = cfg.qk_nope_head_dim
@@ -314,8 +316,14 @@ def attention_inputs(blk, a, cfg, table: Optional[RopeTable], start=0):
         return rope(t.transpose(0, 2, 1, 3), table,
                     start).transpose(0, 2, 1, 3)
 
-    with jax.named_scope("mla_q"):
-        q = (a @ blk["wq"]).reshape(B, T, Hh, -1)
+    if "wqa" in blk:        # a query latent with its norm, DeepSeek-V3's
+        with jax.named_scope("mla_q_down"):
+            cq = rms_norm(a @ blk["wqa"], blk["q_ln"], cfg.rms_norm_eps)
+        with jax.named_scope("mla_q_up"):
+            q = (cq @ blk["wqb"]).reshape(B, T, Hh, -1)
+    else:
+        with jax.named_scope("mla_q"):
+            q = (a @ blk["wq"]).reshape(B, T, Hh, -1)
     with jax.named_scope("mla_kv_down"):
         latent = a @ blk["wkva"]
         c = rms_norm(latent[..., :R], blk["kv_ln"], cfg.rms_norm_eps)
@@ -327,27 +335,39 @@ def attention_inputs(blk, a, cfg, table: Optional[RopeTable], start=0):
     return q[..., :Dn], q_rope, kv[..., :Dn], k_rope, kv[..., Dn:]
 
 
-def attend(blk, x, cfg):
+def attend(blk, x, cfg, read=None):
     """x [B, T, d] -> the held heads' outputs side by side [B, T, Hh * Dv],
     before ``wo``: the projections in chunks of the sequence, the kernels
     over the whole of it. ``cfg``: a :class:`SarvamMLAConfig` or whatever
     has its head widths, ``heads_held``, ``rope_table`` (which may be None),
-    ``softmax_scale`` and the widths :func:`_widest` reads."""
+    ``softmax_scale`` and the widths :func:`_widest` reads.
+
+    ``read``: for a walk whose carry is not the sub-layer's input (a
+    residual stream of several lanes: ``models/xing.py``), ``read(xc) ->
+    (the chunk's input [B, chunk, d], arrays [B, chunk, ...] to keep)``,
+    run inside the chunk loop before the norm; the call then returns the
+    outputs with what was kept, each [B, T, ...], for the chunk loop after
+    the kernels to take and not make again."""
     B, T, _ = x.shape
     traced.note("mla_heads_held", cfg.heads_held[1])
     traced.note("mla_latent_bytes",
                 B * T * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
                 * jnp.dtype(x.dtype).itemsize)
+
+    def inputs(start, xc):
+        xc, kept = (xc, ()) if read is None else read(xc)
+        return attention_inputs(
+            blk, rms_norm(xc, blk["input_ln"], cfg.rms_norm_eps), cfg,
+            cfg.rope_table, start) + tuple(kept)
+
     with jax.named_scope("mla_in"):
-        operands = over_sequence(
-            lambda start, xc: attention_inputs(
-                blk, rms_norm(xc, blk["input_ln"], cfg.rms_norm_eps), cfg,
-                cfg.rope_table, start), _widest(cfg), x)
-    o = mla_attention(*(t.transpose(0, 2, 1, 3) for t in operands),
+        operands = over_sequence(inputs, _widest(cfg), x)
+    o = mla_attention(*(t.transpose(0, 2, 1, 3) for t in operands[:5]),
                       causal=True, scale=cfg.softmax_scale,
                       block_q=cfg.flash_block_q or None,
                       block_k=cfg.flash_block_k or None)
-    return o.transpose(0, 2, 1, 3).reshape(B, T, -1)
+    o = o.transpose(0, 2, 1, 3).reshape(B, T, -1)
+    return o if read is None else (o, *operands[5:])
 
 
 def block(blk, x, cfg: SarvamMLAConfig):

@@ -56,13 +56,15 @@ def mesh8(devices):
 # a file and over; PR 56's runs: 5,539 s of cases on a quiet hour, 923 s a
 # worker at best, and 7,267 s on a loaded one).
 _LONGEST_FIRST = (
-    "test_sarvam_mla.py", "test_tpu_compile.py", "test_afmoe.py",
+    "test_sarvam_mla.py", "test_tpu_compile.py", "test_xing.py",
+    "test_afmoe.py",
     "test_minicpm_sala.py", "test_mellum.py", "test_jamba.py",
     "test_stack_in_place.py", "test_nemotron_h.py", "test_kimi_linear.py",
     "test_kimi_linear_walk.py", "test_kda_attention.py",
     "test_multiworker.py", "test_qwen3_next.py", "test_zaya.py",
     "test_olmoe.py", "test_ssd_attention.py", "test_nemotron_h_walk.py",
-    "test_tpu_compile_nemotron_h.py", "test_qwen3_next_walk.py",
+    "test_tpu_compile_nemotron_h.py", "test_tpu_compile_xing.py",
+    "test_qwen3_next_walk.py",
     "test_models.py",
     "test_attn_kept.py", "test_gdn_attention.py", "test_serving_chaos.py",
     "test_sequence_parallel.py", "test_zaya_walk.py", "test_ga_fused.py",

@@ -252,21 +252,30 @@ def test_the_sixteen_ranks_add_up_with_the_shared_expert_counted_once():
     params = uneven(init_params(WHOLE))
     blk = params["l2"]
     x = jax.random.normal(jax.random.PRNGKey(6), (2, 32, CFG.hidden_size))
-    shared = afmoe.swiglu(x, blk["shared_gate"], blk["shared_up"],
-                          blk["shared_down"])
-    total, ranks = shared, 0
-    for first in range(0, CFG.num_experts, 2):
-        share, cfg = kimi.rank_share(params, WHOLE, (first, 2))
+    ranks = range(0, CFG.num_experts, 2)
+    assert len(ranks) == 16
+    for first in ranks:
+        share, _ = kimi.rank_share(params, WHOLE, (first, 2))
         assert share["l2"]["w_gate"].shape[0] == 2 \
             and share["l2"]["wq"] is params["l2"]["wq"] \
             and share["l0"]["w_gate"] is params["l0"]["w_gate"]
-        total = total + afmoe.moe(share["l2"], x, cfg) - shared
-        ranks += 1
-    assert ranks == 16
+
+    @jax.jit        # one trace for the sixteen ranks, not sixteen dispatches
+    def every_rank(params, x):
+        blk = params["l2"]
+        shared = afmoe.swiglu(x, blk["shared_gate"], blk["shared_up"],
+                              blk["shared_down"])
+        total = shared
+        for first in ranks:
+            share, cfg = kimi.rank_share(params, WHOLE, (first, 2))
+            total = total + afmoe.moe(share["l2"], x, cfg) - shared
+        return total
+
     hp = hyper(WHOLE)
-    want = jnp.stack([ref._moe(blk, s, hp, ref.identity)[0] for s in x])
-    np.testing.assert_allclose(np.asarray(total), np.asarray(want), rtol=0,
-                               atol=2e-6)
+    want = jax.jit(lambda blk, x: jnp.stack(
+        [ref._moe(blk, s, hp, ref.identity)[0] for s in x]))(blk, x)
+    np.testing.assert_allclose(np.asarray(every_rank(params, x)),
+                               np.asarray(want), rtol=0, atol=2e-6)
 
 
 def test_a_rank_of_the_whole_model_is_the_reference_at_the_same_share():

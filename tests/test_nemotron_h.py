@@ -357,21 +357,30 @@ def test_the_eight_ranks_add_up_with_the_shared_expert_counted_once():
     params = uneven(init_params(WHOLE))
     blk = params["l1"]
     x = jax.random.normal(jax.random.PRNGKey(6), (2, 32, CFG.hidden_size))
-    shared = nemo.relu2_mlp(x, blk["shared_up"], blk["shared_down"])
-    total, ranks = shared, 0
-    for first in range(0, CFG.num_experts, 4):
-        share, cfg = nemo.rank_share(params, WHOLE, (first, 4))
+    ranks = range(0, CFG.num_experts, 4)
+    assert len(ranks) == 8
+    for first in ranks:
+        share, _ = nemo.rank_share(params, WHOLE, (first, 4))
         assert share["l1"]["w_up"].shape[0] == 4 \
             and share["l1"]["router"] is params["l1"]["router"] \
             and share["l0"]["w_xbc"] is params["l0"]["w_xbc"] \
             and share["l5"]["wq"] is params["l5"]["wq"]
-        total = total + nemo.moe(share["l1"], x, cfg) - shared
-        ranks += 1
-    assert ranks == 8
+
+    @jax.jit        # one trace for the eight ranks, not eight dispatches
+    def every_rank(params, x):
+        blk = params["l1"]
+        shared = nemo.relu2_mlp(x, blk["shared_up"], blk["shared_down"])
+        total = shared
+        for first in ranks:
+            share, cfg = nemo.rank_share(params, WHOLE, (first, 4))
+            total = total + nemo.moe(share["l1"], x, cfg) - shared
+        return total
+
     hp = hyper(WHOLE)
-    want = jnp.stack([ref._moe(blk, s, hp, ref.identity)[0] for s in x])
-    np.testing.assert_allclose(np.asarray(total), np.asarray(want), rtol=0,
-                               atol=2e-6)
+    want = jax.jit(lambda blk, x: jnp.stack(
+        [ref._moe(blk, s, hp, ref.identity)[0] for s in x]))(blk, x)
+    np.testing.assert_allclose(np.asarray(every_rank(params, x)),
+                               np.asarray(want), rtol=0, atol=2e-6)
 
 
 def test_a_rank_of_the_whole_model_is_the_reference_at_the_same_share():

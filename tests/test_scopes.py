@@ -70,6 +70,21 @@ def test_the_benchmark_repeats_the_programs_vocabulary():
     ("jvp()/while/body/closed_call/walk_fwd/part_mixer/"
      "tepdist_mla_fwd__c1__s0.1352337788608801__h16/pallas_call:",
      ("mixer", "walk_fwd", "tepdist_mla_fwd")),
+    # A residual stream of lanes: the maps under the part they serve, their
+    # Sinkhorn loop's words JAX's own; the query
+    # latent beside the key/value path; the prediction module's scope stands
+    # outside the parts and names no sub-scope.
+    ("walk_fwd/mla_out_mlp/checkpoint/part_moe/mhc_maps/while/body/mul:",
+     ("moe", "walk_fwd", "mhc_maps")),
+    ("walk_bwd/transpose(jvp(mla_out_mlp))/checkpoint/rematted_computation/"
+     "part_mixer/mla_out/mhc_write/slice:",
+     ("mixer", "rematted", "mhc_write")),
+    ("jit(s)/mtp/while/body/closed_call/walk_bwd/transpose(jvp(part_mixer))/"
+     "mla_in/checkpoint/mla_q_up/dot_general:",
+     ("mixer", "walk_bwd", "mla_q_up")),
+    ("jit(s)/mtp/part_embed/mtp_in/checkpoint/concatenate:",
+     ("embed", "-", "mtp_in")),
+    ("jit(s)/mtp/part_head_loss/while/body/exp:", ("head_loss", "-", "-")),
     # Whole words only: an einsum's string, a scope that begins alike.
     ("jvp(bhqk,bhkd->bhqd)/dot_general:", ("unscoped", "-", "-")),
     ("part_mixer_in/part_mlpx/mlp/moe/walk_fwds/dot:",
@@ -127,6 +142,7 @@ def _ga_step(module: str):
     ("minicpm_sala", ("mixer", "mlp")),
     ("sarvam_mla", ("mixer", "mlp", "moe")),
     ("zaya", ("mixer", "moe")),
+    ("xing", ("mixer", "mlp", "moe")),
 ])
 def test_a_models_step_holds_its_parts_and_the_walks_phases(module, parts):
     step, args = _ga_step(module)
@@ -147,6 +163,16 @@ def test_a_models_step_holds_its_parts_and_the_walks_phases(module, parts):
     # Autodiff wraps the scope; the word is found inside the wrapping.
     wrapped = [p for p in paths if "transpose(jvp(part_mixer" in p]
     assert wrapped and all(_scopes.place(p)[0] == "mixer" for p in wrapped)
+    if module == "xing":    # the maps' sub-scopes, in each part they serve
+        subs = {(_scopes.place(p)[0], _scopes.place(p)[2]) for p in paths}
+        assert subs >= {(part, sub) for part in parts for sub in (
+            "mhc_maps", "mhc_read", "mhc_write")} | {
+            ("mixer", "mla_q_down"), ("mixer", "mla_q_up"),
+            ("embed", "mtp_in")}
+        from benchmark.layer_metrics import _mtp
+        inside_mtp = {_scopes.place(p)[0] for p in paths
+                      if _mtp._MTP.search(p)}
+        assert inside_mtp >= {"embed", "mixer", "moe", "head_loss"}
 
 
 def test_the_lowered_text_carries_the_scopes():
