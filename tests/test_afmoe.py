@@ -9,24 +9,20 @@ import re
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
+from model_checks import KEY, Model, match_the_reference
 
 from benchmark.reference import afmoe as ref
 from tepdist_tpu.models import afmoe, decoder, olmoe
 from tepdist_tpu.ops import grouped_matmul as gm
 from tepdist_tpu.ops.pallas import flash_attention as fa
 from tepdist_tpu.optim import make_optimizer
-from tepdist_tpu.parallel.sync_free import build_ga_step
 from tepdist_tpu.telemetry import metrics
 
 CFG = afmoe.CONFIGS["test"]          # 16-wide router, experts 4..7 held;
 #                                      a dense window layer, then a global
 #                                      and a window expert layer
 LAYERS = range(CFG.num_dense_layers, CFG.num_hidden_layers)
-loss_and_grads = jax.jit(jax.value_and_grad(afmoe.loss_fn),
-                         static_argnums=2)
-KEY = jax.random.PRNGKey(0)
 OPT = {"name": "adamw_bf16_router_bias", "learning_rate": 1e-3,
        "bias_rate": 0.001}
 
@@ -46,61 +42,30 @@ def hyper(cfg):
         eps=cfg.rms_norm_eps)
 
 
-def to_reference(params, cfg):
-    """The reference's view of either layout of the program's parameters."""
-    if "l0" not in params:
-        return params
-    out = {k: params[k] for k in ("tok_emb", "norm_f", "lm_head")}
-    out["layers"] = [params[f"l{i}"] for i in range(cfg.num_hidden_layers)]
-    return out
-
-
-def tree_close(got, want, rtol=2e-5, skip=("router_bias",)):
-    flat_want = dict(jax.tree_util.tree_flatten_with_path(want)[0])
-    for path, g in jax.tree_util.tree_flatten_with_path(got)[0]:
-        if any(s in jax.tree_util.keystr(path) for s in skip):
-            continue
-        w = np.asarray(flat_want[path])
-        np.testing.assert_allclose(
-            np.asarray(g), w, rtol=0, atol=rtol * (np.abs(w).max() + 1e-12),
-            err_msg=jax.tree_util.keystr(path))
+# The row, and the file's compiled programs.
+MODEL = Model(
+    afmoe, ref, CFG, hyper, ("tok_emb", "norm_f", "lm_head"),
+    stack=lambda tree, cfg: decoder.stack_layers(
+        tree, afmoe._stacks(cfg), ("tok_emb", "norm_f", "lm_head")),
+    opt=OPT)
+loss_and_grads, to_reference = MODEL.loss_and_grads, MODEL.to_reference
 
 
 @pytest.mark.parametrize("stacked,remat", [(False, False), (True, True)],
                          ids=["unstacked-plain", "stacked-remat"])
 def test_loss_and_every_gradient_match_the_reference(stacked, remat):
-    cfg = dataclasses.replace(CFG, remat=remat, loss_chunk=16 if remat else 0)
-    init = afmoe.stacked_init_params if stacked else afmoe.init_params
-    params = init(cfg, KEY)
-    tokens = afmoe.fake_batch(cfg, 2, 32, seed=1)
-    loss, grads = loss_and_grads(params, tokens, cfg)
-    want_loss, want = jax.jit(jax.value_and_grad(
-        lambda p: ref.loss(to_reference(p, cfg), tokens, hyper(cfg))))(params)
-    assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
-    tree_close(grads, want)
+    grads = match_the_reference(MODEL, stacked, remat, logits=False)
     # Where a gradient would be, the bias holds its layer's counts.
-    counts = jax.jit(lambda p, t: ref.expert_counts(p, t, hyper(cfg)))(
-        to_reference(params, cfg), tokens)
+    counts = MODEL.reference("counts")
     got = grads["blocks"]["router_bias"] if stacked else jnp.stack(
         [grads[f"l{i}"]["router_bias"] for i in LAYERS])
     np.testing.assert_array_equal(np.asarray(got), np.asarray(counts))
     assert float(counts.sum()) == len(LAYERS) * 2 * 32 \
-        * cfg.num_experts_per_tok
+        * CFG.num_experts_per_tok
 
 
 def _two_steps(cfg, params, batches, micro):
-    tx = make_optimizer(dict(OPT))
-
-    def loss(p, t):
-        return afmoe.loss_fn(p, t, cfg)
-
-    def apply_fn(p, s, g):
-        updates, s = tx.update(g, s, p)
-        return optax.apply_updates(p, updates), s
-
-    step = jax.jit(build_ga_step(
-        lambda p, t: jax.value_and_grad(loss)(p, t), apply_fn, micro,
-        loss_fn=loss))
+    tx, step = MODEL.ga_step(cfg, micro)
     state, losses, after = tx.init(params), [], []
     for tokens in batches:
         loss_value, params, state = step(params, state, tokens)
@@ -117,8 +82,7 @@ def test_two_steps_do_not_depend_on_the_accumulation_split(stacked):
     whole batch's counts, whatever the split (the second step routes with
     the first step's bias)."""
     cfg = dataclasses.replace(CFG, remat=True)
-    init = afmoe.stacked_init_params if stacked else afmoe.init_params
-    params = init(cfg, KEY)
+    params = MODEL.init_params(cfg, stacked)
     batches = [afmoe.fake_batch(cfg, 4, 32, seed=s) for s in (2, 3)]
     one, p_one = _two_steps(cfg, params, batches, 1)
     four, p_four = _two_steps(cfg, params, batches, 4)
@@ -134,9 +98,9 @@ def test_two_steps_do_not_depend_on_the_accumulation_split(stacked):
     # Each step's update is the reference's, from that step's own counts:
     # the second routes with the first's bias and weights.
     bias = np.zeros_like(biases(params))
-    expert_counts = jax.jit(lambda p, t: ref.expert_counts(p, t, hyper(cfg)))
     for before, tokens, after in zip([params] + p_one, batches, p_one):
-        counts = expert_counts(to_reference(before, cfg), tokens)
+        counts = MODEL.ref_expert_counts(to_reference(before, cfg), tokens,
+                                         hyper(cfg))
         bias = np.asarray(ref.bias_update(bias, counts, OPT["bias_rate"]))
         np.testing.assert_allclose(biases(after), bias, atol=1e-9)
     assert np.abs(bias).max() > 0
@@ -226,11 +190,6 @@ def test_the_whole_share_is_olmoes_layer_bit_for_bit():
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-@jax.jit
-def _reference_loss(params, tokens):
-    return ref.loss(params, tokens, hyper(CFG))
-
-
 @pytest.mark.parametrize("send", ["all_held", "none_held", "mixed"])
 def test_no_assignment_to_a_held_expert_is_dropped(send):
     """A router forced to send every token to held experts, one that sends
@@ -268,7 +227,7 @@ def test_no_assignment_to_a_held_expert_is_dropped(send):
     assert np.isfinite(float(loss))
     assert all(np.isfinite(np.asarray(g)).all()
                for g in jax.tree_util.tree_leaves(grads))
-    want_loss = _reference_loss(to_reference(params, cfg), tokens)
+    want_loss = MODEL.ref_loss(to_reference(params, cfg), tokens, hyper(cfg))
     assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
 
 

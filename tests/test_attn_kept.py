@@ -25,6 +25,10 @@ from tepdist_tpu.ops.pallas import flash_attention as fa
 from tepdist_tpu.parallel.sync_free import build_ga_step
 from tepdist_tpu.telemetry import metrics
 
+# Level 1: at LLVM level 0 the walked block's flash forward and the plain
+# one round apart (``test_walked_blocks_keep_their_flash_forward[mellum]``).
+pytestmark = pytest.mark.usefixtures("optimized_programs")
+
 MICRO, BATCH, SEQ = 4, 8, 32
 BF16 = jnp.bfloat16
 

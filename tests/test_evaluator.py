@@ -14,6 +14,10 @@ from tepdist_tpu.parallel.auto_parallel import (
 )
 from tepdist_tpu.parallel.evaluator import Cost, Evaluator
 
+# Level 1: the ruler's readings (``_work_of``) stay the programs' they
+# were taken from.
+pytestmark = pytest.mark.usefixtures("optimized_programs")
+
 
 def _mlp(batch, d):
     def loss(params, x, y):

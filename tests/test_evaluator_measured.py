@@ -46,6 +46,10 @@ from tepdist_tpu.models import gpt2
 from tepdist_tpu.parallel.auto_parallel import auto_parallel, plan_axes
 from tepdist_tpu.parallel.evaluator import Evaluator
 
+# Level 1: the ruler's readings (``_work_of``) stay the programs' they
+# were taken from.
+pytestmark = pytest.mark.usefixtures("optimized_programs")
+
 CFG = gpt2.GPT2Config(vocab_size=4096, n_ctx=128, n_embd=256, n_layer=2,
                       n_head=8, dtype=jnp.float32)
 BATCH, SEQ = 16, 128

@@ -12,33 +12,25 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
 from benchmark.reference import jamba as ref
-from kernel_checks import leaves_close, rel_l2
+from kernel_checks import leaves_close
+from model_checks import (
+    KEY,
+    Model,
+    bf16_near_the_reference,
+    match_the_reference,
+    rel_l2_close,
+)
 from tepdist_tpu.models import decoder, jamba
 from tepdist_tpu.ops.pallas import selective_scan as ssm
 from tepdist_tpu.optim import make_optimizer
-from tepdist_tpu.parallel.sync_free import build_ga_step
 from tepdist_tpu.telemetry import metrics
 
 CFG = jamba.CONFIGS["test"]          # Mamba x 2, attention, Mamba x 2;
 #                                      128 channels of 8 states, chunks of 16
-KEY = jax.random.PRNGKey(0)
 OPT = {"name": "adamw_bf16", "learning_rate": 1e-3}
-# Traced and compiled once a (shapes, configuration), not run operation by
-# operation: the program and the reference (``hp`` a tuple of plain numbers).
-loss_and_grads = jax.jit(jax.value_and_grad(jamba.loss_fn), static_argnums=2)
-loss_of = jax.jit(jamba.loss_fn, static_argnums=2)
-forward = jax.jit(jamba.forward, static_argnums=2)
-ref_logits = jax.jit(lambda p, t, hp: ref.logits(p, t, hp), static_argnums=2)
-ref_loss = jax.jit(
-    lambda p, t, hp, weights=None: ref.loss(p, t, hp, ref.identity, weights),
-    static_argnums=2)
-ref_loss_and_grads = jax.jit(
-    jax.value_and_grad(lambda p, t, hp: ref.loss(p, t, hp)),
-    static_argnums=2)
 
 
 @pytest.fixture(autouse=True)
@@ -55,13 +47,18 @@ def hyper(cfg):
         dt_rank=cfg.mamba_dt_rank, eps=cfg.rms_norm_eps)
 
 
-def to_reference(params, cfg):
-    """The reference's view of either layout of the program's parameters."""
-    if "l0" not in params:
-        return params
-    out = {k: params[k] for k in ("tok_emb", "norm_f")}
-    out["layers"] = [params[f"l{i}"] for i in range(cfg.num_hidden_layers)]
-    return out
+# The row, and the file's compiled programs: the program and the reference
+# (``hp`` a tuple of plain numbers), each traced once a (shapes,
+# configuration).
+MODEL = Model(
+    jamba, ref, CFG, hyper, ("tok_emb", "norm_f"),
+    stack=lambda tree, cfg: decoder.stack_layers(
+        tree, decoder.run_stacks(cfg.layer_kinds), ("tok_emb", "norm_f"),
+        jamba.GROUPS, jamba._GROUP_OF),
+    batch=(2, 40),                  # 2.5 chunks of 16
+    logits_relative=True, close=rel_l2_close, opt=OPT)
+loss_and_grads, loss_of = MODEL.loss_and_grads, MODEL.loss_of
+ref_loss, ref_loss_and_grads = MODEL.ref_loss, MODEL.ref_loss_and_grads
 
 
 # -- the program against the reference ---------------------------------------
@@ -69,19 +66,7 @@ def to_reference(params, cfg):
 @pytest.mark.parametrize("stacked,remat", [(False, False), (True, False),
                                            (True, True)])
 def test_logits_loss_and_every_gradient_match_the_reference(stacked, remat):
-    cfg = dataclasses.replace(CFG, remat=remat, loss_chunk=16 * remat)
-    init = jamba.stacked_init_params if stacked else jamba.init_params
-    params = init(cfg, KEY)
-    tokens = jamba.fake_batch(cfg, 2, 40, seed=1)      # 2.5 chunks of 16
-    as_ref, hp = to_reference(params, cfg), hyper(cfg)
-    logits = ref_logits(as_ref, tokens[:, :-1], hp)
-    np.testing.assert_allclose(
-        np.asarray(forward(params, tokens[:, :-1], cfg)),
-        np.asarray(logits), rtol=0, atol=2e-5 * float(jnp.abs(logits).max()))
-    loss, grads = loss_and_grads(params, tokens, cfg)
-    want_loss, want = ref_loss_and_grads(as_ref, tokens, hp)
-    assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
-    leaves_close(to_reference(grads, cfg), want, 2e-5)
+    match_the_reference(MODEL, stacked, remat)
 
 
 def test_bf16_program_stays_near_the_float32_reference():
@@ -93,13 +78,7 @@ def test_bf16_program_stays_near_the_float32_reference():
     below hold it."""
     cfg = dataclasses.replace(CFG, dtype=jnp.bfloat16, remat=True,
                               loss_chunk=16)
-    params = jamba.stacked_init_params(cfg, KEY)
-    tokens = jamba.fake_batch(cfg, 2, 40, seed=1)
-    loss, grads = loss_and_grads(params, tokens, cfg)
-    want_loss, want = ref_loss_and_grads(params, tokens, hyper(cfg))
-    assert float(loss) == pytest.approx(float(want_loss), rel=2e-3)
-    for name in ("tok_emb", "norm_f"):
-        assert rel_l2(grads[name], want[name]) < 0.03, name
+    grads = bf16_near_the_reference(MODEL, cfg, MODEL.tokens(), limit=0.03)
     assert grads["decay0"]["A_log"].dtype == jnp.float32
     assert grads["run0"]["in_proj"].dtype == jnp.bfloat16
 
@@ -169,20 +148,6 @@ def test_the_reference_refuses_weights_out_of_the_rules_order():
 
 # -- gradient accumulation over walks of unequal shape -----------------------
 
-def ga_step(cfg, micro, **more):
-    tx = make_optimizer(OPT)
-
-    def loss(p, t):
-        return jamba.loss_fn(p, t, cfg)
-
-    def apply_fn(p, s, g):
-        updates, s = tx.update(g, s, p)
-        return optax.apply_updates(p, updates), s
-
-    return build_ga_step(lambda p, t: jax.value_and_grad(loss)(p, t),
-                         apply_fn, micro, **more, loss_fn=loss), tx
-
-
 def nbytes(tree):
     return sum(a.nbytes for a in jax.tree_util.tree_leaves(tree))
 
@@ -194,7 +159,7 @@ def test_every_walk_accumulates_in_the_layer_loop_and_attention_is_kept():
     twice a Mamba layer (the walk and its recomputation)."""
     cfg = dataclasses.replace(CFG, remat=True, loss_chunk=16)
     params = jamba.stacked_init_params(cfg, KEY)
-    step, tx = ga_step(cfg, 4)
+    tx, step = MODEL.step_fn(cfg, 4)
     tokens = jamba.fake_batch(cfg, 4, 32)
     jax.make_jaxpr(step)(params, tx.init(params), tokens)
     gauge = lambda n: metrics().gauge(n).value          # noqa: E731
@@ -213,15 +178,14 @@ def test_every_walk_accumulates_in_the_layer_loop_and_attention_is_kept():
 @pytest.mark.parametrize("stacked", [False, True])
 def test_two_steps_do_not_depend_on_the_accumulation_split(stacked):
     cfg = dataclasses.replace(CFG, remat=True, loss_chunk=16)
-    init = jamba.stacked_init_params if stacked else jamba.init_params
-    params = init(cfg, KEY)
+    params = MODEL.init_params(cfg, stacked)
     tokens = jamba.fake_batch(cfg, 4, 32, seed=2)
     results = []
     for micro in (1, 4):
-        step, tx = ga_step(cfg, micro)
+        tx, step = MODEL.ga_step(cfg, micro)
         state = (params, tx.init(params))
         for _ in range(2):
-            loss, *state = jax.jit(step)(*state, tokens)
+            loss, *state = step(*state, tokens)
         results.append((float(loss), state[0]))
     assert results[0][0] == pytest.approx(results[1][0], rel=1e-5)
     leaves_close(results[1][1], results[0][1], 1e-4)
@@ -234,19 +198,9 @@ def test_the_accumulating_walks_give_the_tree_wide_adds_step():
     cfg = dataclasses.replace(CFG, remat=True, loss_chunk=16)
     params = jamba.stacked_init_params(cfg, KEY)
     tokens = jamba.fake_batch(cfg, 4, 32, seed=5)
-    tx = make_optimizer(OPT)
-
-    def loss(p, t):
-        return jamba.loss_fn(p, t, cfg)
-
-    def apply_fn(p, s, g):
-        updates, s = tx.update(g, s, p)
-        return optax.apply_updates(p, updates), s
-
-    grad_fn = lambda p, t: jax.value_and_grad(loss)(p, t)   # noqa: E731
     ends = []
-    for more in ({"loss_fn": loss}, {}):
-        step = jax.jit(build_ga_step(grad_fn, apply_fn, 4, **more))
+    for fused in (True, False):     # the stacked case's step of 4, and its twin
+        tx, step = MODEL.ga_step(cfg, 4, fused=fused)
         state = (params, tx.init(params))
         for _ in range(2):
             _, *state = step(*state, tokens)
@@ -315,7 +269,7 @@ def test_a_narrow_jambas_step_compiles_for_a_described_v5e(v5e_chip,
         mamba_d_state=16, mamba_dt_rank=16, dtype=jnp.bfloat16, remat=True,
         loss_chunk=512, ssm_chunk=64, ssm_block_d=256, flash_block_q=512,
         flash_block_k=512)
-    step, tx = ga_step(cfg, 2)
+    tx, step = MODEL.step_fn(cfg, 2)
     params = jax.eval_shape(
         lambda: jamba.stacked_init_params(cfg, jax.random.PRNGKey(0)))
     args = jax.tree_util.tree_map(

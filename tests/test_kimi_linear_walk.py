@@ -10,49 +10,31 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 from kernel_checks import kernel_counts
+from model_checks import tree_close, two_planned_steps
 from test_kimi_linear import (
     CFG,
+    MODEL,
     OUTSIDE,
     biases,
     hyper,
     init_params,
     ref_expert_counts,
     to_reference,
-    tree_close,
 )
 
 from benchmark.reference import kimi_linear as ref
 from tepdist_tpu.models import afmoe, decoder, sarvam_mla
 from tepdist_tpu.models import kimi_linear as kimi
 from tepdist_tpu.ops.pallas.grouped_matmul import ExpertStack
-from tepdist_tpu.optim import make_optimizer
-from tepdist_tpu.parallel.sync_free import build_ga_step
 from tepdist_tpu.telemetry import metrics
-
-OPT = {"name": "adamw_bf16_router_bias", "learning_rate": 1e-3,
-       "bias_rate": 0.001}
 
 
 @pytest.fixture(autouse=True)
 def _highest():
     with jax.default_matmul_precision("highest"):
         yield
-
-
-def _ga_step(cfg, micro):
-    tx = make_optimizer(dict(OPT))
-    loss = lambda p, t: kimi.loss_fn(p, t, cfg)             # noqa: E731
-
-    def apply_fn(p, s, g):
-        updates, s = tx.update(g, s, p)
-        return optax.apply_updates(p, updates), s
-
-    return tx, build_ga_step(
-        lambda p, t: jax.value_and_grad(loss)(p, t), apply_fn, micro,
-        loss_fn=loss)
 
 
 def _unstacked(tree, cfg):
@@ -64,22 +46,12 @@ def _unstacked(tree, cfg):
     return out
 
 
-_STEPS = {}
-
-
-def _jitted_step(cfg, micro):
-    """``(optimizer, jitted step)`` of ``micro`` micro batches, one a
-    (configuration, micro): the planned steps' plain loop below is the
-    stacked walk's one-micro-batch step, compiled once."""
-    if (cfg, micro) not in _STEPS:
-        tx, step = _ga_step(cfg, micro)
-        _STEPS[cfg, micro] = tx, jax.jit(step)
-    return _STEPS[cfg, micro]
-
-
 def _one_step(cfg, micro, stacked, tokens):
+    """One step of the row's jitted step of ``micro`` micro batches (the
+    planned steps' plain loop below is the stacked walk's one-micro-batch
+    step, compiled once)."""
     params = jax.tree_util.tree_map(jnp.copy, init_params(cfg, stacked))
-    tx, step = _jitted_step(cfg, micro)
+    tx, step = MODEL.ga_step(cfg, micro)
     loss, new, _ = step(params, tx.init(params), tokens)
     return loss, new
 
@@ -139,7 +111,7 @@ def test_the_gauges_of_a_traced_step():
     cfg = dataclasses.replace(CFG, remat=True, loss_chunk=16)
     params = init_params(cfg, stacked=True)
     tokens = kimi.fake_batch(cfg, 4, 32, seed=8)
-    tx, step = _ga_step(cfg, 2)
+    tx, step = MODEL.step_fn(cfg, 2)
     found = kernel_counts(step, params, tx.init(params), tokens)
     gauge = lambda n: metrics().gauge(n).value              # noqa: E731
     assert gauge("kda_calls") == 4
@@ -179,7 +151,7 @@ def test_a_kda_block_rematerialised_whole_keeps_nothing(monkeypatch):
                         rematerialised_whole(kimi.kda_block))
     cfg = dataclasses.replace(CFG, remat=True, loss_chunk=16)
     params = init_params(cfg, stacked=True)
-    tx, step = _ga_step(cfg, 2)
+    tx, step = MODEL.step_fn(cfg, 2)
     found = kernel_counts(step, params, tx.init(params),
                           kimi.fake_batch(cfg, 4, 32, seed=8))
     gauge = lambda n: metrics().gauge(n).value              # noqa: E731
@@ -212,29 +184,16 @@ def test_two_planned_steps_are_a_plain_grad_and_optimizer_loop(stacked,
     against ``jax.grad`` of the whole batch and the optimizer by hand: the
     same losses, the same parameters, the selection bias moved by the
     reference's update of each step's counts."""
-    from tepdist_tpu.train import plan_training
-    cfg = dataclasses.replace(CFG, remat=True, loss_chunk=16)
-    params = init_params(cfg, stacked)
-    batches = [kimi.fake_batch(cfg, 4, 32, seed=s) for s in (2, 3)]
-    tx = make_optimizer(dict(OPT))
-    # The plan's first step donates the arrays it was given.
-    plan = plan_training(lambda p, t: kimi.loss_fn(p, t, cfg), tx,
-                         jax.tree_util.tree_map(jnp.copy, params),
-                         batches[0], devices=devices[:1], explore=False,
-                         num_micro_batches=2)
+    bias = [0.0]
 
-    # The plain loop: ``jax.value_and_grad`` of the whole batch and the
-    # optimizer, one micro batch (``build_ga_step(..., 1)`` is that).
-    _, plain = _jitted_step(cfg, 1)
-    state, p, bias = tx.init(params), params, None
-    for tokens in batches:
+    def the_references_update(p, tokens, cfg):
         counts = ref_expert_counts(to_reference(p, cfg), tokens, hyper(cfg))
-        want_loss, p, state = plain(p, state, tokens)
-        assert plan.step(tokens) == pytest.approx(float(want_loss), rel=2e-6)
-        bias = ref.bias_update(0.0 if bias is None else bias, counts,
-                               OPT["bias_rate"])
-    got, _ = jax.tree_util.tree_unflatten(plan._state_tree,
-                                          plan._device_state())
+        bias[0] = ref.bias_update(bias[0], counts, MODEL.opt["bias_rate"])
+
+    # The plain loop is the stacked walk's one-micro-batch step.
+    got, p = two_planned_steps(MODEL, stacked, devices,
+                               each=the_references_update)
+    bias = bias[0]
     # Adam's first steps are sign-like: where a gradient is next to nothing
     # the order of the accumulation's sums shows in the update.
     tree_close(got, p, 5e-4, skip=())

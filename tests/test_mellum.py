@@ -8,24 +8,18 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
+from model_checks import KEY, Model, match_the_reference, tree_close
 
 from benchmark.reference import mellum as ref
 from tepdist_tpu.models import decoder, layers, mellum
 from tepdist_tpu.ops import grouped_matmul as gm
-from tepdist_tpu.optim import make_optimizer
-from tepdist_tpu.parallel.sync_free import build_ga_step
 from tepdist_tpu.telemetry import metrics
 
 CFG = mellum.CONFIGS["test"]         # 16-wide router, experts 4..7 held;
 #                                      window, global, window; the YaRN
 #                                      table's original context is 8 of the
 #                                      tests' 32 positions
-loss_and_grads = jax.jit(jax.value_and_grad(mellum.loss_fn),
-                         static_argnums=2)
-KEY = jax.random.PRNGKey(0)
-OPT = {"name": "adamw_bf16", "learning_rate": 1e-3}
 
 
 @pytest.fixture(autouse=True)
@@ -46,22 +40,12 @@ def hyper(cfg):
         eps=cfg.rms_norm_eps)
 
 
-def to_reference(params, cfg):
-    """The reference's view of either layout of the program's parameters."""
-    if "l0" not in params:
-        return params
-    out = {k: params[k] for k in ("tok_emb", "norm_f", "lm_head")}
-    out["layers"] = [params[f"l{i}"] for i in range(cfg.num_hidden_layers)]
-    return out
-
-
-def tree_close(got, want, rtol=2e-5):
-    flat_want = dict(jax.tree_util.tree_flatten_with_path(want)[0])
-    for path, g in jax.tree_util.tree_flatten_with_path(got)[0]:
-        w = np.asarray(flat_want[path])
-        np.testing.assert_allclose(
-            np.asarray(g), w, rtol=0, atol=rtol * (np.abs(w).max() + 1e-12),
-            err_msg=jax.tree_util.keystr(path))
+MODEL = Model(
+    mellum, ref, CFG, hyper, ("tok_emb", "norm_f", "lm_head"),
+    stack=lambda tree, cfg: decoder.stack_layers(
+        tree, mellum._stacks(cfg), ("tok_emb", "norm_f", "lm_head")),
+    logits_atol=2e-6, opt={"name": "adamw_bf16", "learning_rate": 1e-3})
+loss_and_grads, to_reference = MODEL.loss_and_grads, MODEL.to_reference
 
 
 @pytest.mark.parametrize("stacked,remat", [
@@ -69,23 +53,7 @@ def tree_close(got, want, rtol=2e-5):
     ids=["unstacked-plain", "unstacked-remat", "stacked-plain",
          "stacked-remat"])
 def test_logits_loss_and_every_gradient_match_the_reference(stacked, remat):
-    cfg = dataclasses.replace(CFG, remat=remat, loss_chunk=16 if remat else 0)
-    init = mellum.stacked_init_params if stacked else mellum.init_params
-    params = init(cfg, KEY)
-    tokens = mellum.fake_batch(cfg, 2, 32, seed=1)
-    hp = hyper(cfg)
-    # Each traced and compiled once, not run operation by operation.
-    logits = jax.jit(mellum.forward, static_argnums=2)(
-        params, tokens[:, :-1], cfg)
-    want_logits = jax.jit(lambda p, t: ref.logits(p, t, hp))(
-        to_reference(params, cfg), tokens[:, :-1])
-    np.testing.assert_allclose(np.asarray(logits), np.asarray(want_logits),
-                               rtol=0, atol=2e-6)
-    loss, grads = loss_and_grads(params, tokens, cfg)
-    want_loss, want = jax.jit(jax.value_and_grad(
-        lambda p: ref.loss(to_reference(p, cfg), tokens, hp)))(params)
-    assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
-    tree_close(grads, want)
+    grads = match_the_reference(MODEL, stacked, remat)
     assert all(float(jnp.abs(g).max()) > 0
                for g in jax.tree_util.tree_leaves(grads))
 
@@ -145,25 +113,14 @@ def test_a_window_the_block_size_does_not_divide(window, block):
     params = mellum.stacked_init_params(cfg, KEY)
     tokens = mellum.fake_batch(cfg, 2, 32, seed=3)
     loss, grads = loss_and_grads(params, tokens, cfg)
-    want_loss, want = jax.jit(jax.value_and_grad(
-        lambda p: ref.loss(p, tokens, hyper(cfg))))(params)
+    # The reference over the stack as it is (it reads either layout).
+    want_loss, want = MODEL.ref_loss_and_grads(params, tokens, hyper(cfg))
     assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
     tree_close(grads, want)
 
 
 def _two_steps(cfg, params, batches, micro):
-    tx = make_optimizer(dict(OPT))
-
-    def loss(p, t):
-        return mellum.loss_fn(p, t, cfg)
-
-    def apply_fn(p, s, g):
-        updates, s = tx.update(g, s, p)
-        return optax.apply_updates(p, updates), s
-
-    step = jax.jit(build_ga_step(
-        lambda p, t: jax.value_and_grad(loss)(p, t), apply_fn, micro,
-        loss_fn=loss))
+    tx, step = MODEL.ga_step(cfg, micro)
     state, losses = tx.init(params), []
     for tokens in batches:
         loss_value, params, state = step(params, state, tokens)
@@ -174,9 +131,8 @@ def _two_steps(cfg, params, batches, micro):
 @pytest.mark.parametrize("stacked", [False, True],
                          ids=["unstacked", "stacked"])
 def test_two_steps_do_not_depend_on_the_accumulation_split(stacked):
-    cfg = dataclasses.replace(CFG, remat=True, loss_chunk=16)
-    init = mellum.stacked_init_params if stacked else mellum.init_params
-    params = init(cfg, KEY)
+    cfg = MODEL.variant(True)
+    params = MODEL.init_params(cfg, stacked)
     batches = [mellum.fake_batch(cfg, 4, 32, seed=s) for s in (2, 3)]
     one, p_one = _two_steps(cfg, params, batches, 1)
     four, p_four = _two_steps(cfg, params, batches, 4)
@@ -254,12 +210,12 @@ def test_no_assignment_to_a_held_expert_is_dropped(send):
         assert stats["moe_layout_rows_share"] == pytest.approx(88 / 168)
     assert metrics().gauge("moe_layout_rows_share").value \
         == stats["moe_layout_rows_share"]
-    # The gradient runs whatever the routing (no live tile, or all of them).
-    loss, grads = loss_and_grads(params, tokens, cfg)
+    # The gradient runs whatever the routing (no live tile, or all of them);
+    # under the preset's own share the program is the reference case's.
+    (loss, _), grads = MODEL.all_three(params, tokens, cfg)
     assert all(np.isfinite(np.asarray(g)).all()
                for g in jax.tree_util.tree_leaves(grads))
-    want_loss = jax.jit(lambda p, t: ref.loss(p, t, hyper(cfg)))(
-        to_reference(params, cfg), tokens)
+    want_loss = MODEL.ref_loss(to_reference(params, cfg), tokens, hyper(cfg))
     assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
 
 

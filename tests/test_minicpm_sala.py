@@ -14,51 +14,34 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
 from benchmark.reference import minicpm_sala as ref
-from kernel_checks import equations, kernel_counts, leaves_close, rel_l2
+from kernel_checks import equations, kernel_counts, leaves_close
 from kernel_checks import sala_hyper as hyper
+from model_checks import (
+    KEY,
+    Model,
+    bf16_near_the_reference,
+    match_the_reference,
+    rel_l2_close,
+)
 from tepdist_tpu.models import decoder, layers
 from tepdist_tpu.models import minicpm_sala as sala
 from tepdist_tpu.ops.pallas import block_topk_attention as bt
 from tepdist_tpu.ops.pallas import flash_attention as fa
 from tepdist_tpu.optim import make_optimizer
-from tepdist_tpu.parallel.sync_free import build_ga_step
 from tepdist_tpu.telemetry import metrics
 
 CFG = sala.CONFIGS["test"]
 GEO = CFG.sparse
-KEY = jax.random.PRNGKey(0)
 OPT = {"name": "adamw_bf16", "learning_rate": 1e-3}
-# Traced and compiled once a (shapes, configuration), not run operation by
-# operation: the program and the reference (``hp`` a tuple of plain values).
-loss_and_grads = jax.jit(jax.value_and_grad(sala.loss_fn), static_argnums=2)
-loss_of = jax.jit(sala.loss_fn, static_argnums=2)
-forward = jax.jit(sala.forward, static_argnums=2)
-ref_logits = jax.jit(lambda p, t, hp: ref.logits(p, t, hp), static_argnums=2)
-ref_loss = jax.jit(
-    lambda p, t, hp, weights=None: ref.loss(p, t, hp, ref.identity, weights),
-    static_argnums=2)
-ref_loss_and_grads = jax.jit(
-    jax.value_and_grad(lambda p, t, hp: ref.loss(p, t, hp)),
-    static_argnums=2)
 
 
 @pytest.fixture(autouse=True)
 def _highest():
     with jax.default_matmul_precision("highest"):
         yield
-
-
-def to_reference(params, cfg):
-    """The reference's view of either layout of the program's parameters."""
-    if "l0" not in params:
-        return params
-    out = {k: params[k] for k in ("tok_emb", "lm_head", "norm_f")}
-    out["layers"] = [params[f"l{i}"] for i in range(cfg.num_hidden_layers)]
-    return out
 
 
 def uneven_gains(params):
@@ -72,6 +55,22 @@ def uneven_gains(params):
     return jax.tree_util.tree_map_with_path(off, params)
 
 
+# The row, and the file's compiled programs: the program and the reference
+# (``hp`` a tuple of plain values), each traced once a (shapes,
+# configuration).
+MODEL = Model(
+    sala, ref, CFG, hyper, ("tok_emb", "lm_head", "norm_f"),
+    stack=lambda tree, cfg: decoder.stack_layers(
+        tree, decoder.run_stacks(cfg.mixer_types),
+        ("tok_emb", "lm_head", "norm_f"), sala.GROUPS, sala._GROUP_OF),
+    init=lambda cfg, key: sala.init_params(cfg, key, std=0.05),
+    uneven=uneven_gains, batch=(2, 128), chunk=48, logits_relative=True,
+    close=rel_l2_close, opt=OPT)
+loss_and_grads, loss_of = MODEL.loss_and_grads, MODEL.loss_of
+ref_loss, ref_loss_and_grads = MODEL.ref_loss, MODEL.ref_loss_and_grads
+to_reference = MODEL.to_reference
+
+
 # -- the program against the reference ---------------------------------------
 
 # 128 positions are past ``dense_len`` (the sparse layers choose blocks), 32
@@ -82,19 +81,7 @@ def uneven_gains(params):
     (True, True, 32)])
 def test_logits_loss_and_every_gradient_match_the_reference(stacked, remat,
                                                             T):
-    cfg = dataclasses.replace(CFG, remat=remat, loss_chunk=48 * remat)
-    init = sala.stacked_init_params if stacked else sala.init_params
-    params = uneven_gains(init(cfg, KEY, std=0.05))
-    tokens = sala.fake_batch(cfg, 2, T, seed=1)
-    as_ref, hp = to_reference(params, cfg), hyper(cfg)
-    logits = ref_logits(as_ref, tokens[:, :-1], hp)
-    np.testing.assert_allclose(
-        np.asarray(forward(params, tokens[:, :-1], cfg)),
-        np.asarray(logits), rtol=0, atol=2e-5 * float(jnp.abs(logits).max()))
-    loss, grads = loss_and_grads(params, tokens, cfg)
-    want_loss, want = ref_loss_and_grads(as_ref, tokens, hp)
-    assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
-    leaves_close(to_reference(grads, cfg), want, 2e-5)
+    match_the_reference(MODEL, stacked, remat, T=T)
 
 
 def test_the_dense_len_switch_counts_its_side():
@@ -119,13 +106,8 @@ def test_bf16_program_stays_near_the_float32_reference():
     and the sets it flips: a per cent, not the float32 test's 1e-5."""
     cfg = dataclasses.replace(CFG, dtype=jnp.bfloat16, remat=True,
                               loss_chunk=48)
-    params = sala.stacked_init_params(cfg, KEY)
-    tokens = sala.fake_batch(cfg, 2, 128, seed=1)
-    loss, grads = loss_and_grads(params, tokens, cfg)
-    want_loss, want = ref_loss_and_grads(params, tokens, hyper(cfg))
-    assert float(loss) == pytest.approx(float(want_loss), rel=2e-3)
-    for name in ("tok_emb", "lm_head", "norm_f"):
-        assert rel_l2(grads[name], want[name]) < 0.05, name
+    grads = bf16_near_the_reference(MODEL, cfg, MODEL.tokens(),
+                                    flat=sala.init_params(cfg, KEY))
     assert grads["vec1"]["o_norm"].dtype == jnp.float32
     assert grads["run1"]["wq"].dtype == jnp.bfloat16
 
@@ -227,20 +209,6 @@ def test_the_reference_refuses_weights_out_of_order():
         ref.hidden(params, jnp.zeros((16,), jnp.int32), hyper(CFG))
 
 
-def ga_step(cfg, micro, **more):
-    tx = make_optimizer(OPT)
-
-    def loss(p, t):
-        return sala.loss_fn(p, t, cfg)
-
-    def apply_fn(p, s, g):
-        updates, s = tx.update(g, s, p)
-        return optax.apply_updates(p, updates), s
-
-    return build_ga_step(lambda p, t: jax.value_and_grad(loss)(p, t),
-                         apply_fn, micro, **more, loss_fn=loss), tx
-
-
 def nbytes(tree):
     return sum(a.nbytes for a in jax.tree_util.tree_leaves(tree))
 
@@ -253,7 +221,7 @@ def test_every_walk_accumulates_in_the_layer_loop_and_counts_its_kernels():
     its sets and its forward kernel's ``(o, lse)``."""
     cfg = dataclasses.replace(CFG, remat=True, loss_chunk=48)
     params = sala.stacked_init_params(cfg, KEY)
-    step, tx = ga_step(cfg, 2)
+    tx, step = MODEL.step_fn(cfg, 2)
     tokens = sala.fake_batch(cfg, 2, 128)
     jax.make_jaxpr(step)(params, tx.init(params), tokens)
     gauge = lambda n: metrics().gauge(n).value          # noqa: E731
@@ -303,16 +271,17 @@ def test_a_walked_sparse_layer_keeps_its_forward_and_its_choice(dtype,
     cfg = dataclasses.replace(CFG, remat=True, loss_chunk=48, dtype=dtype)
     params = sala.stacked_init_params(cfg, KEY, std=0.05)
     tokens = sala.fake_batch(cfg, 2, 128, seed=3)
-    step, tx = ga_step(cfg, 2)
+    tx, step = MODEL.step_fn(cfg, 2)
+    jitted = MODEL.ga_step(cfg, 2)[1]       # the stacked split's, below
     state = tx.init(params)
     kept = kernel_counts(step, params, state, tokens)
     kept_choices = choices(step, params, state, tokens)
     got = (params, state)
     for _ in range(2):
-        loss_got, *got = jax.jit(step)(*got, tokens)
+        loss_got, *got = jitted(*got, tokens)
 
     whole_remat(monkeypatch)
-    step, _ = ga_step(cfg, 2)
+    _, step = MODEL.step_fn(cfg, 2)
     whole = kernel_counts(step, params, state, tokens)
     assert metrics().gauge("attn_kept_calls").value == 0
     assert metrics().gauge("topk_attn_calls").value == 2 * 2
@@ -346,7 +315,7 @@ def test_outside_a_walk_nothing_is_handed_over(stacked, remat, micro,
     init = sala.stacked_init_params if stacked else sala.init_params
     params = init(cfg, KEY)
     tokens = sala.fake_batch(cfg, 2, 128)
-    step, tx = ga_step(cfg, micro)
+    tx, step = MODEL.step_fn(cfg, micro)
     state = tx.init(params)
     here = [str(e.primitive) for e in equations(step, params, state, tokens)]
     assert metrics().gauge("attn_kept_calls").value == 0
@@ -358,7 +327,7 @@ def test_outside_a_walk_nothing_is_handed_over(stacked, remat, micro,
     for module in (bt, fa):
         monkeypatch.setattr(module, "hand_over",
                             lambda attend: attend(None))
-    step, _ = ga_step(cfg, micro)
+    _, step = MODEL.step_fn(cfg, micro)
     assert [str(e.primitive) for e in equations(
         step, params, state, tokens)] == here
     assert "optimization_barrier" not in here
@@ -372,10 +341,10 @@ def test_two_micro_batches_leave_the_state_one_batch_of_two_leaves(stacked):
     tokens = sala.fake_batch(cfg, 2, 128, seed=2)
     results = []
     for micro in (1, 2):
-        step, tx = ga_step(cfg, micro)
+        tx, step = MODEL.ga_step(cfg, micro)
         state = (params, tx.init(params))
         for _ in range(2):
-            loss, *state = jax.jit(step)(*state, tokens)
+            loss, *state = step(*state, tokens)
         results.append((float(loss), state))
     assert results[0][0] == pytest.approx(results[1][0], rel=1e-5)
     leaves_close(results[1][1][0], results[0][1][0], 1e-4)

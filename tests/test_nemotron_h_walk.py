@@ -11,44 +11,21 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-import optax
 import pytest
 from kernel_checks import kernel_counts
-from test_nemotron_h import CFG, OUTSIDE, init_params, stacked_like, tree_close
+from model_checks import tree_close, two_planned_steps
+from test_nemotron_h import CFG, MODEL, OUTSIDE
 
 from tepdist_tpu.models import decoder
 from tepdist_tpu.models import nemotron_h as nemo
 from tepdist_tpu.ops.pallas.grouped_matmul import ExpertStack
-from tepdist_tpu.optim import make_optimizer
-from tepdist_tpu.parallel.sync_free import build_ga_step
 from tepdist_tpu.telemetry import metrics
-
-OPT = {"name": "adamw_bf16_router_bias", "learning_rate": 1e-3,
-       "bias_rate": 0.001}
 
 
 @pytest.fixture(autouse=True)
 def _highest():
     with jax.default_matmul_precision("highest"):
         yield
-
-
-def _ga_step(cfg, micro):
-    tx = make_optimizer(dict(OPT))
-    loss = lambda p, t: nemo.loss_fn(p, t, cfg)             # noqa: E731
-
-    def apply_fn(p, s, g):
-        updates, s = tx.update(g, s, p)
-        return optax.apply_updates(p, updates), s
-
-    return tx, build_ga_step(
-        lambda p, t: jax.value_and_grad(loss)(p, t), apply_fn, micro,
-        loss_fn=loss)
-
-
-def _params(cfg, stacked):
-    flat = init_params(dataclasses.replace(cfg, remat=False, loss_chunk=0))
-    return stacked_like(flat, cfg) if stacked else flat
 
 
 def _in_units(tree, cfg):
@@ -60,21 +37,10 @@ def _in_units(tree, cfg):
     return out
 
 
-_STEPS = {}
-
-
-def _jitted_step(cfg, micro):
-    """``(optimizer, jitted step)`` of ``micro`` micro batches, one a
-    (configuration, micro)."""
-    if (cfg, micro) not in _STEPS:
-        tx, step = _ga_step(cfg, micro)
-        _STEPS[cfg, micro] = tx, jax.jit(step)
-    return _STEPS[cfg, micro]
-
-
 def _one_step(cfg, micro, stacked, tokens):
-    params = jax.tree_util.tree_map(jnp.copy, _params(cfg, stacked))
-    tx, step = _jitted_step(cfg, micro)
+    params = jax.tree_util.tree_map(jnp.copy,
+                                    MODEL.init_params(cfg, stacked))
+    tx, step = MODEL.ga_step(cfg, micro)    # one a (configuration, micro)
     loss, new, _ = step(params, tx.init(params), tokens)
     return loss, new
 
@@ -125,9 +91,9 @@ def test_the_gauges_of_a_traced_step():
     recomputation (the walk keeps no state-space forward), the flash forward
     once (the walk keeps ``(o, lse)``), the one conv with the rule."""
     cfg = dataclasses.replace(CFG, remat=True, loss_chunk=16)
-    params = _params(cfg, True)
+    params = MODEL.init_params(cfg, True)
     tokens = nemo.fake_batch(cfg, 4, 32, seed=8)
-    tx, step = _ga_step(cfg, 2)
+    tx, step = MODEL.step_fn(cfg, 2)
     found = kernel_counts(step, params, tx.init(params), tokens)
     gauge = lambda n: metrics().gauge(n).value              # noqa: E731
     assert gauge("ssd_calls") == 4 * 2
@@ -153,7 +119,7 @@ def test_the_gauges_of_a_traced_step():
 
 def test_the_layers_parts_carry_their_scopes():
     cfg = dataclasses.replace(CFG, remat=True)
-    params = _params(cfg, True)
+    params = MODEL.init_params(cfg, True)
     tokens = nemo.fake_batch(cfg, 1, 32)
     text = jax.jit(nemo.loss_fn, static_argnums=2).lower(
         params, tokens, cfg).as_text(debug_info=True)
@@ -170,27 +136,10 @@ def test_two_planned_steps_are_a_plain_grad_and_optimizer_loop(devices):
     """``plan_training`` with 2 micro batches accumulated in one program
     against ``jax.grad`` of the whole batch and the optimizer by hand: the
     same losses, the same parameters."""
-    from tepdist_tpu.train import plan_training
-    cfg = dataclasses.replace(CFG, remat=True, loss_chunk=16)
-    params = _params(cfg, True)
-    batches = [nemo.fake_batch(cfg, 4, 32, seed=s) for s in (2, 3)]
-    tx = make_optimizer(dict(OPT))
-    # The plan's first step donates the arrays it was given.
-    plan = plan_training(lambda p, t: nemo.loss_fn(p, t, cfg), tx,
-                         jax.tree_util.tree_map(jnp.copy, params),
-                         batches[0], devices=devices[:1], explore=False,
-                         num_micro_batches=2)
-    # The plain loop: ``jax.value_and_grad`` of the whole batch and the
-    # optimizer, one micro batch, over the ``l{i}`` dicts (compiled for the
+    cfg = MODEL.variant(True)
+    # The plain loop runs over the ``l{i}`` dicts (its step compiled for the
     # walk's test above already).
-    _, plain = _jitted_step(cfg, 1)
-    p = jax.tree_util.tree_map(jnp.copy, _params(cfg, False))
-    state = tx.init(p)
-    for tokens in batches:
-        want_loss, p, state = plain(p, state, tokens)
-        assert plan.step(tokens) == pytest.approx(float(want_loss), rel=2e-6)
-    got, _ = jax.tree_util.tree_unflatten(plan._state_tree,
-                                          plan._device_state())
+    got, p = two_planned_steps(MODEL, True, devices, plain_stacked=False)
     # Where Adam's sign-like step meets a gradient next to nothing, the
     # order of the accumulation's sums is the leaf's third digit.
     tree_close(_in_units(got, cfg), nemo.in_units(p, cfg), 2e-3)

@@ -7,13 +7,17 @@ counted once, and the held layer's routing statistics. The walks are
 ``test_qwen3_next_walk.py``'s."""
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from kernel_checks import rel_l2
+from model_checks import (
+    KEY,
+    Model,
+    bf16_near_the_reference,
+    match_the_reference,
+)
 
 from benchmark.reference import qwen3_next as ref
 from tepdist_tpu.models import afmoe, decoder, kimi_linear, layers, mellum
@@ -22,30 +26,8 @@ from tepdist_tpu.telemetry import metrics
 
 CFG = qwen.CONFIGS["test"]           # experts 8..23 of 32 held; gdn, gdn,
 #                                      gdn, attention
-KEY = jax.random.PRNGKey(0)
 WHOLE = dataclasses.replace(CFG, experts_held=(0, CFG.num_experts))
 OUTSIDE = ("tok_emb", "norm_f", "lm_head")
-# Traced once a (shapes, configuration) and a module, not once a test.
-loss_and_grads = jax.jit(jax.value_and_grad(qwen.loss_fn), static_argnums=2)
-forward = jax.jit(qwen.forward, static_argnums=2)
-ref_logits = jax.jit(lambda p, t, hp: ref.logits(p, t, hp), static_argnums=2)
-ref_loss_and_grads = jax.jit(
-    jax.value_and_grad(lambda p, t, hp: ref.loss(p, t, hp)),
-    static_argnums=2)
-ref_loss = jax.jit(lambda p, t, hp: ref.loss(p, t, hp), static_argnums=2)
-
-
-@functools.lru_cache(maxsize=None)
-def _init(cfg, stacked):
-    init = qwen.stacked_init_params if stacked else qwen.init_params
-    return init(cfg, KEY)
-
-
-def init_params(cfg, stacked=False):
-    """``cfg``'s parameters from ``KEY``, made once a preset and layout.
-    Shared: whoever donates them takes a copy."""
-    return _init(dataclasses.replace(cfg, remat=False, loss_chunk=0,
-                                     gdn_chunk=CFG.gdn_chunk), stacked)
 
 
 @pytest.fixture(autouse=True)
@@ -64,29 +46,6 @@ def hyper(cfg):
         eps=cfg.rms_norm_eps)
 
 
-def to_reference(params, cfg):
-    """The reference's view of either layout of the program's parameters."""
-    if "l0" not in params:
-        return params
-    out = {k: params[k] for k in OUTSIDE}
-    out["layers"] = [params[f"l{i}"] for i in range(cfg.num_hidden_layers)]
-    return out
-
-
-def from_reference(tree, cfg):
-    """The reference's ``layers`` list as the program's ``l{i}`` dicts."""
-    out = {k: tree[k] for k in OUTSIDE}
-    out.update({f"l{i}": tree["layers"][i]
-                for i in range(cfg.num_hidden_layers)})
-    return out
-
-
-def stacked_like(tree):
-    """An ``l{i}`` tree of ``CFG`` in the stacked layout, ``run{r}``."""
-    return decoder.stack_layers(tree, decoder.run_stacks(CFG.kinds), OUTSIDE,
-                                qwen.GROUPS)
-
-
 def uneven(params):
     """Norm leaves away from their initial values (a zero-centred one from
     0), so that a gain left out, or taken as ``w`` for ``1 + w``, shows."""
@@ -99,34 +58,18 @@ def uneven(params):
     return jax.tree_util.tree_map_with_path(leaf, params)
 
 
-# One batch for every case of the float32 preset, so that a program and the
-# reference are compiled once a layout and the reference is run once.
-TOKENS = qwen.fake_batch(CFG, 2, 32, seed=1)
-
-
-@functools.lru_cache(maxsize=None)
-def uneven_params(stacked):
-    """``CFG``'s uneven parameters, the same values in either layout."""
-    flat = uneven(init_params(CFG))
-    return stacked_like(flat) if stacked else flat
-
-
-@functools.lru_cache(maxsize=None)
-def reference():
-    """The reference's loss and gradients (as ``l{i}`` dicts) of
-    ``uneven_params`` on ``TOKENS``: once a module."""
-    params, hp = to_reference(uneven_params(False), CFG), hyper(CFG)
-    loss, grads = ref_loss_and_grads(params, TOKENS, hp)
-    return loss, from_reference(grads, CFG)
-
-
-def tree_close(got, want, rtol=2e-5):
-    flat_want = dict(jax.tree_util.tree_flatten_with_path(want)[0])
-    for path, g in jax.tree_util.tree_flatten_with_path(got)[0]:
-        w = np.asarray(flat_want[path])
-        np.testing.assert_allclose(
-            np.asarray(g), w, rtol=0, atol=rtol * (np.abs(w).max() + 1e-12),
-            err_msg=jax.tree_util.keystr(path))
+# The row, and the file's (and ``test_qwen3_next_walk.py``'s) compiled
+# programs. One batch for every case of the float32 preset, so that a program
+# and the reference are compiled once a layout and the reference is run once.
+MODEL = Model(
+    qwen, ref, CFG, hyper, OUTSIDE,
+    stack=lambda tree, cfg: decoder.stack_layers(
+        tree, decoder.run_stacks(cfg.kinds), OUTSIDE, qwen.GROUPS),
+    uneven=uneven, opt={"name": "adamw_bf16", "learning_rate": 1e-3})
+TOKENS = MODEL.tokens()
+init_params, uneven_params = MODEL.init_params, MODEL.uneven_params
+to_reference, loss_and_grads = MODEL.to_reference, MODEL.loss_and_grads
+ref_loss, ref_loss_and_grads = MODEL.ref_loss, MODEL.ref_loss_and_grads
 
 
 def test_the_presets_hold_the_published_structure():
@@ -163,32 +106,14 @@ def test_the_presets_hold_the_published_structure():
 @pytest.mark.parametrize("stacked,remat", [(False, False), (True, True)],
                          ids=["unstacked-plain", "stacked-remat"])
 def test_logits_loss_and_every_gradient_match_the_reference(stacked, remat):
-    cfg = dataclasses.replace(CFG, remat=remat, loss_chunk=16 if remat else 0)
-    params = uneven_params(stacked)
-    if not stacked:          # the logits once: the loss below holds both
-        np.testing.assert_allclose(
-            np.asarray(forward(params, TOKENS[:, :-1], cfg)),
-            np.asarray(ref_logits(to_reference(params, cfg), TOKENS[:, :-1],
-                                  hyper(cfg))), rtol=0, atol=2e-5)
-    loss, grads = loss_and_grads(params, TOKENS, cfg)
-    want_loss, want = reference()
-    assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
-    tree_close(grads, stacked_like(want) if stacked else want)
+    # The logits once: the loss holds both.
+    match_the_reference(MODEL, stacked, remat, logits=not stacked)
 
 
 def test_bf16_program_stays_near_the_float32_reference():
     cfg = dataclasses.replace(qwen.CONFIGS["test_bf16"], remat=True,
                               loss_chunk=16)
-    loss, grads = loss_and_grads(init_params(cfg, stacked=True), TOKENS, cfg)
-    # The reference on the same bf16 values, widened and as a list of
-    # layers: the program it is compiled for already.
-    wide = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32),
-                                  init_params(cfg))
-    want_loss, want = ref_loss_and_grads(to_reference(wide, cfg), TOKENS,
-                                         hyper(cfg))
-    assert float(loss) == pytest.approx(float(want_loss), rel=2e-3)
-    for k in OUTSIDE:
-        assert rel_l2(grads[k], want[k]) < 0.05, k
+    bf16_near_the_reference(MODEL, cfg, TOKENS)
 
 
 def test_the_conv_and_the_state_never_cross_between_rows_of_a_batch():
@@ -197,15 +122,15 @@ def test_the_conv_and_the_state_never_cross_between_rows_of_a_batch():
     position never sees a later one: the conv, the rule and the attention
     are causal. One shape, so one compiled program (``test_logits...``'s)."""
     params = uneven_params(False)
-    tokens = TOKENS[:, :-1]
     order = jnp.array([1, 0])
-    both = forward(params, tokens, CFG)
+    both = MODEL.logits(params, TOKENS, CFG)
     np.testing.assert_allclose(
         np.asarray(both[order]),
-        np.asarray(forward(params, tokens[order], CFG)), rtol=0, atol=1e-6)
+        np.asarray(MODEL.logits(params, TOKENS[order], CFG)), rtol=0,
+        atol=1e-6)
     assert float(jnp.abs(both[0] - both[1]).max()) > 1e-3
-    later = tokens.at[:, 16:].set((tokens[:, 16:] + 7) % CFG.vocab_size)
-    changed = forward(params, later, CFG)
+    later = TOKENS.at[:, 16:].set((TOKENS[:, 16:] + 7) % CFG.vocab_size)
+    changed = MODEL.logits(params, later, CFG)
     np.testing.assert_allclose(np.asarray(both[:, :16]),
                                np.asarray(changed[:, :16]), rtol=0,
                                atol=1e-6)
@@ -292,7 +217,7 @@ def test_a_rank_of_the_whole_model_is_the_reference_at_the_same_share():
     assert cfg == CFG
     # Both compiled already: the share has ``CFG``'s shapes.
     want, _ = ref_loss_and_grads(to_reference(share, cfg), TOKENS, hyper(cfg))
-    got, _ = loss_and_grads(share, TOKENS, cfg)
+    (got, _), _ = MODEL.all_three(share, TOKENS, cfg)
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     whole = ref_loss(to_reference(params, WHOLE), TOKENS, hyper(WHOLE))
     assert abs(float(whole) - float(want)) > 1e-5

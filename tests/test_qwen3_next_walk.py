@@ -10,38 +10,21 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-import optax
 import pytest
 from kernel_checks import kernel_counts
-from test_qwen3_next import CFG, OUTSIDE, init_params, tree_close
+from model_checks import tree_close, two_planned_steps
+from test_qwen3_next import CFG, MODEL, OUTSIDE, init_params
 
 from tepdist_tpu.models import decoder
 from tepdist_tpu.models import qwen3_next as qwen
 from tepdist_tpu.ops.pallas.grouped_matmul import ExpertStack
-from tepdist_tpu.optim import make_optimizer
-from tepdist_tpu.parallel.sync_free import build_ga_step
 from tepdist_tpu.telemetry import metrics
-
-OPT = {"name": "adamw_bf16", "learning_rate": 1e-3}
 
 
 @pytest.fixture(autouse=True)
 def _highest():
     with jax.default_matmul_precision("highest"):
         yield
-
-
-def _ga_step(cfg, micro):
-    tx = make_optimizer(dict(OPT))
-    loss = lambda p, t: qwen.loss_fn(p, t, cfg)             # noqa: E731
-
-    def apply_fn(p, s, g):
-        updates, s = tx.update(g, s, p)
-        return optax.apply_updates(p, updates), s
-
-    return tx, build_ga_step(
-        lambda p, t: jax.value_and_grad(loss)(p, t), apply_fn, micro,
-        loss_fn=loss)
 
 
 def _unstacked(tree, cfg):
@@ -53,21 +36,9 @@ def _unstacked(tree, cfg):
     return out
 
 
-_STEPS = {}
-
-
-def _jitted_step(cfg, micro):
-    """``(optimizer, jitted step)`` of ``micro`` micro batches, one a
-    (configuration, micro)."""
-    if (cfg, micro) not in _STEPS:
-        tx, step = _ga_step(cfg, micro)
-        _STEPS[cfg, micro] = tx, jax.jit(step)
-    return _STEPS[cfg, micro]
-
-
 def _one_step(cfg, micro, stacked, tokens):
     params = jax.tree_util.tree_map(jnp.copy, init_params(cfg, stacked))
-    tx, step = _jitted_step(cfg, micro)
+    tx, step = MODEL.ga_step(cfg, micro)    # one a (configuration, micro)
     loss, new, _ = step(params, tx.init(params), tokens)
     return loss, new
 
@@ -106,7 +77,7 @@ def test_the_gauges_of_a_traced_step():
     cfg = dataclasses.replace(CFG, remat=True, loss_chunk=16)
     params = init_params(cfg, stacked=True)
     tokens = qwen.fake_batch(cfg, 4, 32, seed=8)
-    tx, step = _ga_step(cfg, 2)
+    tx, step = MODEL.step_fn(cfg, 2)
     found = kernel_counts(step, params, tx.init(params), tokens)
     gauge = lambda n: metrics().gauge(n).value              # noqa: E731
     assert gauge("gdn_calls") == 3
@@ -150,27 +121,10 @@ def test_two_planned_steps_are_a_plain_grad_and_optimizer_loop(devices):
     """``plan_training`` with 2 micro batches accumulated in one program
     against ``jax.grad`` of the whole batch and the optimizer by hand: the
     same losses, the same parameters."""
-    from tepdist_tpu.train import plan_training
-    cfg = dataclasses.replace(CFG, remat=True, loss_chunk=16)
-    params = init_params(cfg, True)
-    batches = [qwen.fake_batch(cfg, 4, 32, seed=s) for s in (2, 3)]
-    tx = make_optimizer(dict(OPT))
-    # The plan's first step donates the arrays it was given.
-    plan = plan_training(lambda p, t: qwen.loss_fn(p, t, cfg), tx,
-                         jax.tree_util.tree_map(jnp.copy, params),
-                         batches[0], devices=devices[:1], explore=False,
-                         num_micro_batches=2)
-    # The plain loop: ``jax.value_and_grad`` of the whole batch and the
-    # optimizer, one micro batch, over the ``l{i}`` dicts (compiled for the
+    cfg = MODEL.variant(True)
+    # The plain loop runs over the ``l{i}`` dicts (its step compiled for the
     # walk's test above already).
-    _, plain = _jitted_step(cfg, 1)
-    p = jax.tree_util.tree_map(jnp.copy, init_params(cfg, False))
-    state = tx.init(p)
-    for tokens in batches:
-        want_loss, p, state = plain(p, state, tokens)
-        assert plan.step(tokens) == pytest.approx(float(want_loss), rel=2e-6)
-    got, _ = jax.tree_util.tree_unflatten(plan._state_tree,
-                                          plan._device_state())
+    got, p = two_planned_steps(MODEL, True, devices, plain_stacked=False)
     got = _unstacked(got, cfg)
     # A zero-centred norm leaf is all update after two steps (|w| = 2e-3):
     # where Adam's sign-like step meets a gradient next to nothing, the order

@@ -6,59 +6,35 @@ softmax scale against the formula written out, a planned step against a
 plain loop, and the gauges."""
 
 import dataclasses
-import functools
 import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 from kernel_checks import kernel_counts, rel_l2
+from model_checks import (
+    KEY,
+    Model,
+    bf16_near_the_reference,
+    match_the_reference,
+    tree_close,
+    two_planned_steps,
+)
 
 from benchmark.reference import sarvam_mla as ref
 from tepdist_tpu.models import afmoe, decoder, layers
 from tepdist_tpu.models import sarvam_mla as sarvam
 from tepdist_tpu.ops.pallas import flash_attention as fa
 from tepdist_tpu.ops.pallas import mla_attention as mla
-from tepdist_tpu.optim import make_optimizer
-from tepdist_tpu.parallel.sync_free import build_ga_step
 from tepdist_tpu.telemetry import metrics
 
 CFG = sarvam.CONFIGS["test"]         # heads 2..3 of 4 and experts 4..7 of 16
 #                                      held; a dense layer, two expert layers
-KEY = jax.random.PRNGKey(0)
 OPT = {"name": "adamw_bf16_router_bias", "learning_rate": 1e-3,
        "bias_rate": 0.001}
 WHOLE = dataclasses.replace(CFG, heads_held=(0, CFG.num_attention_heads),
                             experts_held=(0, CFG.num_experts))
-# Traced once a (shapes, configuration) and a module, not once a test: the
-# program, the reference (``hp`` a tuple of plain numbers) and the optimizer.
-loss_and_grads = jax.jit(jax.value_and_grad(sarvam.loss_fn),
-                         static_argnums=2)
-forward = jax.jit(sarvam.forward, static_argnums=2)
-ref_logits = jax.jit(lambda p, t, hp: ref.logits(p, t, hp), static_argnums=2)
-ref_loss_and_grads = jax.jit(
-    jax.value_and_grad(lambda p, t, hp: ref.loss(p, t, hp)),
-    static_argnums=2)
-loss_of = jax.jit(sarvam.loss_fn, static_argnums=2)
-ref_loss = jax.jit(lambda p, t, hp: ref.loss(p, t, hp), static_argnums=2)
-ref_expert_counts = jax.jit(lambda p, t, hp: ref.expert_counts(p, t, hp),
-                            static_argnums=2)
-
-
-@functools.lru_cache(maxsize=None)
-def _init(cfg, stacked):
-    init = sarvam.stacked_init_params if stacked else sarvam.init_params
-    return init(cfg, KEY)
-
-
-def init_params(cfg, stacked=False):
-    """``cfg``'s parameters from ``KEY``, made once a preset (its sizes and
-    dtype) and layout. Shared: whoever donates them takes a copy."""
-    return _init(dataclasses.replace(cfg, remat=False, loss_chunk=0), stacked)
-
-
 @pytest.fixture(autouse=True)
 def _highest():
     with jax.default_matmul_precision("highest"):
@@ -78,25 +54,6 @@ def hyper(cfg):
         eps=cfg.rms_norm_eps)
 
 
-def to_reference(params, cfg):
-    """The reference's view of either layout of the program's parameters."""
-    if "l0" not in params:
-        return params
-    out = {k: params[k] for k in ("tok_emb", "norm_f", "lm_head")}
-    out["layers"] = [params[f"l{i}"] for i in range(cfg.num_hidden_layers)]
-    return out
-
-
-def from_reference(tree, cfg, stacked):
-    """``to_reference``'s way back, for the reference's gradients."""
-    if stacked:
-        return tree
-    out = {k: tree[k] for k in ("tok_emb", "norm_f", "lm_head")}
-    out.update({f"l{i}": tree["layers"][i]
-                for i in range(cfg.num_hidden_layers)})
-    return out
-
-
 def uneven(params):
     """Norm gains and a selection bias away from their initial values, so
     that a gain or a bias left out shows."""
@@ -111,36 +68,24 @@ def uneven(params):
     return jax.tree_util.tree_map_with_path(leaf, params)
 
 
-def tree_close(got, want, rtol=2e-5, skip=("router_bias",)):
-    flat_want = dict(jax.tree_util.tree_flatten_with_path(want)[0])
-    for path, g in jax.tree_util.tree_flatten_with_path(got)[0]:
-        if any(s in jax.tree_util.keystr(path) for s in skip):
-            continue
-        w = np.asarray(flat_want[path])
-        np.testing.assert_allclose(
-            np.asarray(g), w, rtol=0, atol=rtol * (np.abs(w).max() + 1e-12),
-            err_msg=jax.tree_util.keystr(path))
+# The row, and the file's compiled programs.
+MODEL = Model(
+    sarvam, ref, CFG, hyper, ("tok_emb", "norm_f", "lm_head"),
+    stack=lambda tree, cfg: decoder.stack_layers(
+        tree, sarvam._stacks(cfg), ("tok_emb", "norm_f", "lm_head")),
+    uneven=uneven, opt=OPT)
+init_params, to_reference = MODEL.init_params, MODEL.to_reference
+loss_and_grads, loss_of = MODEL.loss_and_grads, MODEL.loss_of
+ref_loss, ref_loss_and_grads = MODEL.ref_loss, MODEL.ref_loss_and_grads
+ref_expert_counts = MODEL.ref_expert_counts
 
 
 @pytest.mark.parametrize("stacked,remat", [(False, False), (True, True)],
                          ids=["unstacked-plain", "stacked-remat"])
 def test_logits_loss_and_every_gradient_match_the_reference(stacked, remat):
-    cfg = dataclasses.replace(CFG, remat=remat, loss_chunk=16 if remat else 0)
-    params = uneven(init_params(cfg, stacked))
-    tokens = sarvam.fake_batch(cfg, 2, 32, seed=1)
-    hp = hyper(cfg)
-    np.testing.assert_allclose(
-        np.asarray(forward(params, tokens[:, :-1], cfg)),
-        np.asarray(ref_logits(to_reference(params, cfg), tokens[:, :-1],
-                              hp)), rtol=0, atol=2e-5)
-    loss, grads = loss_and_grads(params, tokens, cfg)
-    want_loss, want = ref_loss_and_grads(to_reference(params, cfg), tokens,
-                                         hp)
-    want = from_reference(want, cfg, stacked)
-    assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
-    tree_close(grads, want)
+    grads = match_the_reference(MODEL, stacked, remat)
     # The bias's "gradient" is the count of its router's choices.
-    counts = ref_expert_counts(to_reference(params, cfg), tokens, hp)
+    counts = MODEL.reference("counts")
     got = grads["blocks"]["router_bias"] if stacked else jnp.stack(
         [grads[f"l{i}"]["router_bias"] for i in (1, 2)])
     np.testing.assert_array_equal(np.asarray(got), np.asarray(counts))
@@ -149,13 +94,7 @@ def test_logits_loss_and_every_gradient_match_the_reference(stacked, remat):
 def test_bf16_program_stays_near_the_float32_reference():
     cfg = dataclasses.replace(CFG, dtype=jnp.bfloat16, remat=True,
                               loss_chunk=16)
-    params = init_params(cfg, stacked=True)
-    tokens = sarvam.fake_batch(cfg, 2, 32, seed=2)
-    loss, grads = loss_and_grads(params, tokens, cfg)
-    want_loss, want = ref_loss_and_grads(params, tokens, hyper(cfg))
-    assert float(loss) == pytest.approx(float(want_loss), rel=2e-3)
-    for k in ("tok_emb", "lm_head", "norm_f"):
-        assert rel_l2(grads[k], want[k]) < 0.05, k
+    bf16_near_the_reference(MODEL, cfg, sarvam.fake_batch(cfg, 2, 32, seed=2))
 
 
 def dense_attention(qn, qr, kn, kr, v, scale, causal=True):
@@ -440,19 +379,6 @@ def test_the_blocks_token_wise_parts_in_chunks_change_nothing(monkeypatch):
     tree_close(grads, whole[1], 1e-5, skip=())
 
 
-def _ga_step(cfg, micro):
-    tx = make_optimizer(dict(OPT))
-    loss = lambda p, t: sarvam.loss_fn(p, t, cfg)          # noqa: E731
-
-    def apply_fn(p, s, g):
-        updates, s = tx.update(g, s, p)
-        return optax.apply_updates(p, updates), s
-
-    return tx, jax.jit(build_ga_step(
-        lambda p, t: jax.value_and_grad(loss)(p, t), apply_fn, micro,
-        loss_fn=loss))
-
-
 def test_a_walk_keeps_one_forward_a_layer_and_the_gauges_say_so():
     """Two micro batches, three layers in two walks: the forward kernel
     runs once a layer and micro batch (its ``(o, lse)`` handed over, 3 calls
@@ -462,7 +388,8 @@ def test_a_walk_keeps_one_forward_a_layer_and_the_gauges_say_so():
     cfg = dataclasses.replace(CFG, remat=True, loss_chunk=16)
     params = init_params(cfg, stacked=True)
     tokens = sarvam.fake_batch(cfg, 4, 32, seed=8)
-    tx, step = _ga_step(cfg, 2)
+    tx, step = MODEL.step_fn(cfg, 2)
+
     def kernels(step):       # fwd, dkv: the places each stands in
         found = kernel_counts(step, params, tx.init(params), tokens)
         assert not [name for name in found if "tepdist_mla_dq" in name]
@@ -489,8 +416,7 @@ def test_a_walk_keeps_one_forward_a_layer_and_the_gauges_say_so():
     # One micro batch: the plain checkpointed scan keeps nothing, and the
     # forward kernel stands in the forward loop and in the backward loop's
     # recomputation.
-    _, plain = _ga_step(cfg, 1)
-    assert kernels(plain) == [4, 2]
+    assert kernels(MODEL.step_fn(cfg, 1)[1]) == [4, 2]
     assert gauge("attn_kept_calls") == 0 and gauge("mla_fwd_calls") >= 3
     assert gauge("mla_bwd_calls") == 3
 
@@ -515,32 +441,15 @@ def test_two_planned_steps_are_a_plain_grad_and_optimizer_loop(stacked,
     against ``jax.grad`` of the whole batch and the optimizer by hand: the
     same losses, the same parameters, the selection bias moved by the
     reference's update of each step's counts."""
-    from tepdist_tpu.train import plan_training
-    cfg = dataclasses.replace(CFG, remat=True, loss_chunk=16)
-    params = init_params(cfg, stacked)
-    batches = [sarvam.fake_batch(cfg, 4, 32, seed=s) for s in (2, 3)]
-    tx = make_optimizer(dict(OPT))
-    # The plan's first step donates the arrays it was given.
-    plan = plan_training(lambda p, t: sarvam.loss_fn(p, t, cfg), tx,
-                         jax.tree_util.tree_map(jnp.copy, params),
-                         batches[0], devices=devices[:1], explore=False,
-                         num_micro_batches=2)
+    bias = [0.0]
 
-    @jax.jit
-    def by_hand(p, state, grads):
-        updates, state = tx.update(grads, state, p)
-        return optax.apply_updates(p, updates), state
-
-    state, p, bias = tx.init(params), params, None
-    for tokens in batches:
-        want_loss, grads = loss_and_grads(p, tokens, cfg)
+    def the_references_update(p, tokens, cfg):
         counts = ref_expert_counts(to_reference(p, cfg), tokens, hyper(cfg))
-        p, state = by_hand(p, state, grads)
-        assert plan.step(tokens) == pytest.approx(float(want_loss), rel=2e-6)
-        bias = ref.bias_update(0.0 if bias is None else bias, counts,
-                               OPT["bias_rate"])
-    got, _ = jax.tree_util.tree_unflatten(plan._state_tree,
-                                          plan._device_state())
+        bias[0] = ref.bias_update(bias[0], counts, OPT["bias_rate"])
+
+    got, p = two_planned_steps(MODEL, stacked, devices,
+                               each=the_references_update)
+    bias = bias[0]
     # Adam's first steps are sign-like: where a gradient is next to nothing
     # the order of the accumulation's sums shows in the update.
     tree_close(got, p, 1e-4, skip=())

@@ -13,6 +13,10 @@ from benchmark.reference import jamba as ref
 from kernel_checks import rel_l2
 from tepdist_tpu.ops.pallas import selective_scan as ssm
 
+# Level 1: at LLVM level 0 the carried state's two programs round apart
+# (``test_state_is_carried_across_chunks_bit_for_bit``).
+pytestmark = pytest.mark.usefixtures("optimized_programs")
+
 
 @pytest.fixture(autouse=True)
 def _highest():

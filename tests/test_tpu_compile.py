@@ -25,8 +25,10 @@ from tepdist_tpu.ops.pallas.flash_attention import flash_attention
 
 
 @pytest.fixture(scope="module")
-def v5e_devices():
-    """The devices of a described v5e:2x2 (``tools/described_chip.py``)."""
+def v5e_devices(optimized_programs):
+    """The devices of a described v5e:2x2 (``tools/described_chip.py``).
+    Every case of the five described-chip files asks for them, so every one
+    compiles at the level its peaks and counts were pinned at."""
     from tools.described_chip import described_v5e
     with contextlib.ExitStack() as stack:
         try:

@@ -12,28 +12,26 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from kernel_checks import kernel_counts, rel_l2
+from kernel_checks import kernel_counts
+from model_checks import (
+    KEY,
+    Model,
+    bf16_near_the_reference,
+    match_the_reference,
+    tree_close,
+)
 
 from benchmark.reference import zaya as ref
-from tepdist_tpu.models import layers, zaya
+from tepdist_tpu.models import decoder, layers, zaya
 from tepdist_tpu.ops.grouped_matmul import layout_rows
 from tepdist_tpu.ops.pallas import cca_mix as cm
 
 CFG = zaya.CONFIGS["test"]           # 8 heads over 2 of 8, three layers
-KEY = jax.random.PRNGKey(0)
 # Heads the kernels take (128 wide), everything else small.
 WIDE = dataclasses.replace(CFG, hidden_size=64, head_dim=128,
                            moe_intermediate_size=32, remat=True,
                            loss_chunk=16)
-# Traced and compiled once a (shapes, configuration), not run operation by
-# operation: the program and the reference (``hp`` a tuple of plain numbers).
-loss_and_grads = jax.jit(jax.value_and_grad(zaya.loss_fn), static_argnums=2)
-loss_of = jax.jit(zaya.loss_fn, static_argnums=2)
-forward = jax.jit(zaya.forward, static_argnums=2)
 attention = jax.jit(zaya.attention, static_argnums=2)
-ref_logits = jax.jit(lambda p, t, hp: ref.logits(p, t, hp), static_argnums=2)
-ref_expert_counts = jax.jit(lambda p, t, hp: ref.expert_counts(p, t, hp),
-                            static_argnums=2)
 
 
 @pytest.fixture(autouse=True)
@@ -45,14 +43,6 @@ def _highest():
 def hyper(cfg):
     return ref.Hyper(head_dim=cfg.head_dim, rotary_dim=cfg.rotary_dim,
                      rope_theta=cfg.rope_theta, eps=cfg.rms_norm_eps)
-
-
-def to_reference(params, cfg):
-    """The reference's view of either layout of the program's parameters."""
-    if "l0" not in params:
-        return params
-    return {"tok_emb": params["tok_emb"], "norm_f": params["norm_f"],
-            "layers": [params[f"l{i}"] for i in range(cfg.num_hidden_layers)]}
 
 
 def uneven(params):
@@ -77,15 +67,15 @@ def uneven(params):
     return jax.tree_util.tree_map_with_path(leaf, params)
 
 
-def tree_close(got, want, rtol=2e-5, skip=("router_bias",)):
-    flat_want = dict(jax.tree_util.tree_flatten_with_path(want)[0])
-    for path, g in jax.tree_util.tree_flatten_with_path(got)[0]:
-        if any(s in jax.tree_util.keystr(path) for s in skip):
-            continue
-        w = np.asarray(flat_want[path])
-        np.testing.assert_allclose(
-            np.asarray(g), w, rtol=0, atol=rtol * (np.abs(w).max() + 1e-12),
-            err_msg=jax.tree_util.keystr(path))
+# The row, and the file's (and ``test_zaya_walk.py``'s) compiled programs.
+MODEL = Model(
+    zaya, ref, CFG, hyper, ("tok_emb", "norm_f"),
+    stack=lambda tree, cfg: decoder.stack_layers(
+        tree, zaya._stacks(cfg), ("tok_emb", "norm_f")),
+    uneven=uneven,
+    opt={"name": "adamw_bf16_router_bias", "learning_rate": 1e-3,
+         "bias_rate": 0.001})
+loss_and_grads, loss_of = MODEL.loss_and_grads, MODEL.loss_of
 
 
 def test_the_presets_have_the_published_ratios():
@@ -106,20 +96,7 @@ def test_the_presets_have_the_published_ratios():
 @pytest.mark.parametrize("stacked,remat", [(False, False), (True, True)],
                          ids=["unstacked-plain", "stacked-remat"])
 def test_logits_loss_and_every_gradient_match_the_reference(stacked, remat):
-    cfg = dataclasses.replace(CFG, remat=remat, loss_chunk=16 if remat else 0)
-    init = zaya.stacked_init_params if stacked else zaya.init_params
-    params = uneven(init(cfg, KEY))
-    tokens = zaya.fake_batch(cfg, 2, 32, seed=1)
-    hp = hyper(cfg)
-    np.testing.assert_allclose(
-        np.asarray(forward(params, tokens[:, :-1], cfg)),
-        np.asarray(ref_logits(to_reference(params, cfg), tokens[:, :-1],
-                              hp)), rtol=0, atol=2e-5)
-    loss, grads = loss_and_grads(params, tokens, cfg)
-    want_loss, want = jax.jit(jax.value_and_grad(
-        lambda p: ref.loss(to_reference(p, cfg), tokens, hp)))(params)
-    assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
-    tree_close(grads, want)
+    grads = match_the_reference(MODEL, stacked, remat)
     # Every leaf of a layer takes part, the router's state among them: the
     # first layer's gamma meets r_{-1} = 0, the later layers' a state.
     first = grads["blocks"] if stacked else grads["l0"]
@@ -131,7 +108,7 @@ def test_logits_loss_and_every_gradient_match_the_reference(stacked, remat):
     assert np.any(np.asarray(later))
     # The bias's "gradient" is the count of its router's choices; more than
     # one expert is chosen.
-    counts = ref_expert_counts(to_reference(params, cfg), tokens, hp)
+    counts = MODEL.reference("counts")
     got = grads["blocks"]["router_bias"] if stacked else jnp.stack(
         [grads[f"l{i}"]["router_bias"] for i in range(3)])
     np.testing.assert_array_equal(np.asarray(got), np.asarray(counts))
@@ -142,14 +119,7 @@ def test_logits_loss_and_every_gradient_match_the_reference(stacked, remat):
 def test_bf16_program_stays_near_the_float32_reference():
     cfg = dataclasses.replace(zaya.CONFIGS["test-bf16"], remat=True,
                               loss_chunk=16)
-    params = zaya.stacked_init_params(cfg, KEY)
-    tokens = zaya.fake_batch(cfg, 2, 32, seed=2)
-    loss, grads = loss_and_grads(params, tokens, cfg)
-    want_loss, want = jax.jit(jax.value_and_grad(
-        lambda p: ref.loss(p, tokens, hyper(cfg))))(params)
-    assert float(loss) == pytest.approx(float(want_loss), rel=2e-3)
-    for k in ("tok_emb", "norm_f"):
-        assert rel_l2(grads[k], want[k]) < 0.05, k
+    bf16_near_the_reference(MODEL, cfg, zaya.fake_batch(cfg, 2, 32, seed=2))
 
 
 @pytest.mark.parametrize("cfg", [CFG, WIDE], ids=["jax.numpy", "kernels"])

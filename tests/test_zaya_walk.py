@@ -3,35 +3,18 @@ checkpointed scan and by the written-out backward equal to the ``l{i}`` Python
 loop, what the walk keeps and the gauges say, and two steps through
 ``plan_training`` against a plain ``jax.grad`` and optimizer loop."""
 
-import dataclasses
-
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 from kernel_checks import kernel_counts
-from test_zaya import (
-    CFG,
-    KEY,
-    WIDE,
-    hyper,
-    loss_and_grads,
-    ref_expert_counts,
-    to_reference,
-    tree_close,
-    uneven,
-)
+from model_checks import KEY, tree_close, two_planned_steps
+from test_zaya import MODEL, WIDE
 
 from benchmark.reference import zaya as ref
 from tepdist_tpu.models import zaya
 from tepdist_tpu.ops.grouped_matmul import layout_rows
-from tepdist_tpu.optim import make_optimizer
-from tepdist_tpu.parallel.sync_free import build_ga_step
 from tepdist_tpu.telemetry import metrics
-
-OPT = {"name": "adamw_bf16_router_bias", "learning_rate": 1e-3,
-       "bias_rate": 0.001}
 
 
 @pytest.fixture(autouse=True)
@@ -40,40 +23,20 @@ def _highest():
         yield
 
 
-def _ga_step(cfg, micro):
-    tx = make_optimizer(dict(OPT))
-    loss = lambda p, t: zaya.loss_fn(p, t, cfg)            # noqa: E731
-
-    def apply_fn(p, s, g):
-        updates, s = tx.update(g, s, p)
-        return optax.apply_updates(p, updates), s
-
-    return tx, jax.jit(build_ga_step(
-        lambda p, t: jax.value_and_grad(loss)(p, t), apply_fn, micro,
-        loss_fn=loss))
-
-
 @pytest.mark.parametrize("micro", [1, 2], ids=["plain-scan", "accumulating"])
 def test_the_stacked_walk_is_the_python_loop(micro):
     """Forward and gradients of the pair ``(x, r)`` through
     ``scan_blocks``, by the checkpointed scan (one micro batch) and by the
     written-out backward that carries ``(dx, dr)`` (two): one optimizer step
     from the same weights lands where the ``l{i}`` loop's does."""
-    cfg = dataclasses.replace(CFG, remat=True, loss_chunk=16)
-    loop = uneven(zaya.init_params(cfg, KEY))
-    stacked = zaya.stack_layers(loop, zaya._stacks(cfg), ("tok_emb",
-                                                          "norm_f"))
+    cfg = MODEL.variant(True)
     tokens = zaya.fake_batch(cfg, 4, 32, seed=9)
-    out = {}
-    for name, params in (("loop", loop), ("stacked", stacked)):
-        tx, step = _ga_step(cfg, micro)
-        out[name] = step(params, tx.init(params), tokens)
-    (loss_loop, p_loop, _), (loss_stack, p_stack, _) = out["loop"], \
-        out["stacked"]
+    tx, step = MODEL.ga_step(cfg, micro)
+    (loss_loop, p_loop, _), (loss_stack, p_stack, _) = (
+        step(params, tx.init(params), tokens)
+        for params in map(MODEL.uneven_params, (False, True)))
     assert float(loss_stack) == pytest.approx(float(loss_loop), rel=2e-6)
-    again = zaya.stack_layers(p_loop, zaya._stacks(cfg), ("tok_emb",
-                                                          "norm_f"))
-    tree_close(p_stack, again, 1e-4, skip=())
+    tree_close(p_stack, MODEL.stack(p_loop, cfg), 1e-4, skip=())
 
 
 def test_a_walk_keeps_one_forward_a_layer_and_the_gauges_say_so():
@@ -85,7 +48,7 @@ def test_a_walk_keeps_one_forward_a_layer_and_the_gauges_say_so():
     cfg = WIDE
     params = zaya.stacked_init_params(cfg, KEY)
     tokens = zaya.fake_batch(cfg, 4, 32, seed=8)
-    tx, step = _ga_step(cfg, 2)
+    tx, step = MODEL.step_fn(cfg, 2)
     found = kernel_counts(step, params, tx.init(params), tokens)
     gauge = lambda n: metrics().gauge(n).value              # noqa: E731
     assert gauge("cca_mix_calls") == 6 and gauge("attn_kept_calls") == 3
@@ -116,36 +79,19 @@ def test_two_planned_steps_are_a_plain_grad_and_optimizer_loop(stacked,
     against ``jax.grad`` of the whole batch and the optimizer by hand: the
     same losses, the same parameters, the selection bias moved by the
     reference's update of each step's counts."""
-    from tepdist_tpu.train import plan_training
-    cfg = dataclasses.replace(CFG, remat=True, loss_chunk=16)
-    init = zaya.stacked_init_params if stacked else zaya.init_params
-    params = uneven(init(cfg, KEY))
-    batches = [zaya.fake_batch(cfg, 4, 32, seed=s) for s in (2, 3)]
-    tx = make_optimizer(dict(OPT))
-    state, p = tx.init(params), params
-    bias = np.asarray(        # a copy: the plan donates the leaves
-        params["blocks"]["router_bias"] if stacked else jnp.stack(
-            [params[f"l{i}"]["router_bias"] for i in range(3)]))
-    plan = plan_training(lambda p, t: zaya.loss_fn(p, t, cfg), tx, params,
-                         batches[0], devices=devices[:1], explore=False,
-                         num_micro_batches=2)
-    @jax.jit
-    def apply(p, state, grads):
-        updates, state = tx.update(grads, state, p)
-        return optax.apply_updates(p, updates), state
+    biases = lambda p: p["blocks"]["router_bias"] if stacked \
+        else jnp.stack([p[f"l{i}"]["router_bias"] for i in range(3)])  # noqa: E731,E501
+    bias = [np.asarray(biases(MODEL.uneven_params(stacked)))]
 
-    for tokens in batches:
-        want_loss, grads = loss_and_grads(p, tokens, cfg)
-        counts = ref_expert_counts(to_reference(p, cfg), tokens, hyper(cfg))
-        p, state = apply(p, state, grads)
-        assert plan.step(tokens) == pytest.approx(float(want_loss), rel=2e-6)
-        bias = ref.bias_update(bias, counts, OPT["bias_rate"])
-    got, _ = jax.tree_util.tree_unflatten(plan._state_tree,
-                                          plan._device_state())
+    def the_references_update(p, tokens, cfg):
+        counts = MODEL.ref_expert_counts(MODEL.to_reference(p), tokens,
+                                         MODEL.hyper(cfg))
+        bias[0] = ref.bias_update(bias[0], counts, MODEL.opt["bias_rate"])
+
+    got, p = two_planned_steps(MODEL, stacked, devices, uneven=True,
+                               each=the_references_update)
     # Adam's first steps are sign-like: where a gradient is next to nothing
     # the order of the accumulation's sums shows in the update.
     tree_close(got, p, 1e-4, skip=())
-    got_bias = got["blocks"]["router_bias"] if stacked else jnp.stack(
-        [got[f"l{i}"]["router_bias"] for i in range(3)])
-    np.testing.assert_allclose(np.asarray(got_bias), np.asarray(bias),
+    np.testing.assert_allclose(np.asarray(biases(got)), np.asarray(bias[0]),
                                rtol=0, atol=1e-7)
