@@ -871,7 +871,8 @@ def rope(x, table: Union[float, RopeTable], start=0,
 def gqa_heads(blk, a, *, n_head: int, n_kv_head: int, head_dim: int,
               eps: float, window: int, windowed, rope_window=None,
               rope_global=None, block_q: int = 0, block_k: int = 0,
-              rotary_dim: Optional[int] = None, norm=rms_norm):
+              rotary_dim: Optional[int] = None, norm=rms_norm,
+              scale: Optional[float] = None):
     """a [B, T, d] (the normed input) -> the attention heads' outputs side
     by side [B, T, n_head * head_dim], before any gate and before ``wo``:
     ``n_head`` query heads over ``n_kv_head`` key/value heads, RMSNorm over
@@ -885,6 +886,8 @@ def gqa_heads(blk, a, *, n_head: int, n_kv_head: int, head_dim: int,
     plain causal on a global one. ``windowed``: this layer's kind, a bool or
     a traced scalar (a stack of both kinds: the branch is a ``lax.cond``).
     ``norm``: the QK-norm, :func:`rms_norm` or :func:`rms_norm0`.
+    ``scale``: what multiplies the scores, None for ``head_dim ** -0.5`` (a
+    model whose multiplier is its own: ``models/granite_hybrid.py``).
     Inside a block that :func:`scan_blocks` walks the call hands its
     forward pass to the walk as ``flash_attention`` does."""
     B, T, _ = a.shape
@@ -900,7 +903,7 @@ def gqa_heads(blk, a, *, n_head: int, n_kv_head: int, head_dim: int,
                 k = rope(k, table, rotary_dim=rotary_dim)
         with jax.named_scope("attn_core"):
             return flash_attention_kept(
-                q, k, v, forward, causal=True,
+                q, k, v, forward, causal=True, scale=scale,
                 window=window if windowed else None,
                 block_q=block_q or None, block_k=block_k or None)
 

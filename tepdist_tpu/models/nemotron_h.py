@@ -31,6 +31,10 @@ Wdown`` with **no gate matrix** (``ops/grouped_matmul.py:routed_experts`` on
 two stacks), plus the shared expert of the same form, ungated. ``x0 =
 tok_emb[tokens]``, a final RMSNorm, an untied head, the cross entropy alone.
 
+The mixer is :func:`mamba2`, which takes the heads whose leaves it is handed:
+every head here (:func:`mamba`), a rank's share of them in a model whose
+mixers are divided over the ranks of a layer (``models/granite_hybrid.py``).
+
 bf16 weights, activations and residual stream; norms, ``Delta``, the state,
 the router's sigmoid and the loss in float32. Parameters: ``l{i}`` per-layer
 dicts (``init_params``), or **the layers in units** a walk takes as its
@@ -274,21 +278,42 @@ def rank_share(params, cfg: NemotronHConfig, experts_held: Tuple[int, int]):
     return out, dataclasses.replace(cfg, experts_held=tuple(experts_held))
 
 
-def gated_group_norm(y, z, gain, groups: int, eps: float):
+def gated_group_norm(y, z, gain, groups: int, eps: float, axis_name=None):
     """``rms_group(y * silu(z)) * gain``: the gate before the norm, the norm
     over each of the ``groups`` groups of channels, one gain a channel;
-    float32 inside, back in y's dtype."""
+    float32 inside, back in y's dtype.
+
+    ``axis_name``: the mapped axis (``jax.vmap(..., axis_name=)``,
+    ``shard_map``) over whose ranks the one group's channels are divided, a
+    rank holding ``y``'s (a mixer that holds a share of the heads of a model
+    with one group, ``models/granite_hybrid.py``): the sum of squares goes
+    through ``lax.psum`` over it and the mean is over every rank's channels,
+    the one number a token that a divided mixer exchanges before its output
+    projection. None: the channels that are here."""
     r = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    return (scaled_by_head(r, groups, eps, mean=True)
-            * gain.astype(jnp.float32)).astype(y.dtype)
+    if axis_name is None:
+        scaled = scaled_by_head(r, groups, eps, mean=True)
+    else:
+        if groups != 1:
+            raise ValueError("a gated norm divided over ranks has one group")
+        total = jax.lax.psum(jnp.sum(r * r, axis=-1, keepdims=True),
+                             axis_name)
+        scaled = r * jax.lax.rsqrt(
+            total / jax.lax.psum(r.shape[-1], axis_name) + eps)
+    return (scaled * gain.astype(jnp.float32)).astype(y.dtype)
 
 
-def mamba(blk, a, cfg: NemotronHConfig):
+def mamba2(blk, a, *, heads: int, head_dim: int, groups: int, states: int,
+           chunk: int, eps: float, axis_name=None):
     """a [B, T, d] (the normed input) -> the Mamba-2 mixer's output through
-    ``w_out``."""
+    ``w_out``, for the ``heads`` heads whose leaves ``blk`` holds: a whole
+    mixer (Nemotron-H's), or the share of the heads a rank of a divided
+    mixer holds (``models/granite_hybrid.py``: the held heads' columns of
+    ``w_z``, ``w_dt`` and of ``w_xbc``'s ``u``, every group's ``B`` and
+    ``C``, the held rows of ``w_out``; what comes out is that rank's partial
+    sum). ``axis_name``: :func:`gated_group_norm`'s."""
     B, T, _ = a.shape
-    H, P = cfg.mamba_num_heads, cfg.mamba_head_dim
-    G, N = cfg.n_groups, cfg.ssm_state_size
+    H, P, G, N = heads, head_dim, groups, states
     traced.note("ssd_state_bytes", B * H * P * N * 4)
     with jax.named_scope("ssd_in"):
         z, xbc = a @ blk["w_z"], a @ blk["w_xbc"]
@@ -302,10 +327,19 @@ def mamba(blk, a, cfg: NemotronHConfig):
             xbc[..., :H * P], xbc[..., H * P:H * P + G * N],
             xbc[..., H * P + G * N:], delta,
             -jnp.exp(blk["A_log"].astype(jnp.float32)), blk["D"], groups=G,
-            chunk=cfg.ssd_chunk)
+            chunk=chunk)
     with jax.named_scope("ssd_norm_out"):
-        return gated_group_norm(y, z, blk["ssm_norm"], G,
-                                cfg.layer_norm_epsilon) @ blk["w_out"]
+        return gated_group_norm(y, z, blk["ssm_norm"], G, eps,
+                                axis_name) @ blk["w_out"]
+
+
+def mamba(blk, a, cfg: NemotronHConfig):
+    """a [B, T, d] (the normed input) -> the Mamba-2 mixer's output through
+    ``w_out``: every head, whole on every rank."""
+    return mamba2(blk, a, heads=cfg.mamba_num_heads,
+                  head_dim=cfg.mamba_head_dim, groups=cfg.n_groups,
+                  states=cfg.ssm_state_size, chunk=cfg.ssd_chunk,
+                  eps=cfg.layer_norm_epsilon)
 
 
 def attention(blk, a, cfg: NemotronHConfig):

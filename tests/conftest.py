@@ -115,8 +115,8 @@ _LONGEST_FIRST = (
     "test_jamba.py", "test_minicpm_sala.py", "test_nemotron_h.py",
     "test_kimi_linear_walk.py", "test_xing.py",
     "test_tpu_compile_nemotron_h.py", "test_multiworker.py",
-    "test_qwen3_next.py", "test_models.py", "test_tpu_compile_kimi.py",
-    "test_attn_kept.py", "test_zaya.py", "test_gdn_attention.py",
+    "test_qwen3_next.py", "test_models.py", "test_granite_hybrid.py",
+    "test_tpu_compile_kimi.py", "test_attn_kept.py", "test_zaya.py", "test_gdn_attention.py",
     "test_qwen3_next_walk.py", "test_nemotron_h_walk.py",
     "test_kda_attention.py", "test_sequence_parallel.py", "test_olmoe.py",
     "test_zaya_walk.py", "test_ssd_attention.py",

@@ -168,7 +168,8 @@ def test_a_departure_from_the_equations_fails_the_tolerance(what,
         norm = nemo.gated_group_norm
         monkeypatch.setattr(
             nemo, "gated_group_norm",
-            lambda y, z, gain, groups, eps: norm(y, z, gain, 1, eps))
+            lambda y, z, gain, groups, eps, axis=None: norm(
+                y, z, gain, 1, eps))
     blk, hp = uneven_params(False)["l0"], hyper(CFG)
     a = jax.random.normal(jax.random.PRNGKey(4), (1, 48, CFG.hidden_size))
     ct = jax.random.normal(jax.random.PRNGKey(5), a.shape)

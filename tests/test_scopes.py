@@ -349,7 +349,10 @@ def test_the_entries_list_the_cells_that_have_the_part():
     for w in every:
         cell = cells.load_cell(w, ROOT)
         model = cell.config.get("model", cell.config)
-        # (The published key: ``n_routed_experts`` in a ``nemotron_h`` file.)
-        experts = model.get("num_experts", model.get("n_routed_experts", 0))
+        # (The published key: ``n_routed_experts`` in a ``nemotron_h`` file,
+        # ``num_local_experts`` in a ``granitemoehybrid`` one.)
+        experts = next((model[k] for k in (
+            "num_experts", "n_routed_experts", "num_local_experts")
+            if k in model), 0)
         assert (w in entries["scope_moe_share.train"]["workloads"]) == (
             experts > 1), w

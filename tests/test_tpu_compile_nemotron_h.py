@@ -13,6 +13,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
 import jax
 import jax.numpy as jnp
+import pytest
 from jax.sharding import SingleDeviceSharding
 from test_tpu_compile import v5e_devices  # noqa: F401 — the fixture
 
@@ -22,16 +23,20 @@ def _custom_calls(text):
             if " custom-call(" in line]
 
 
-def test_ssd_kernels_compile_for_v5e(v5e_devices):
+@pytest.mark.parametrize("H,G", [(64, 8), (16, 1)],
+                         ids=["whole-8-groups", "a-ranks-16-heads-1-group"])
+def test_ssd_kernels_compile_for_v5e(v5e_devices, H, G):
     """Forward and backward of the state-space-dual kernels at the cell's
-    ``[1, 8192, 64 x 64]`` over 8 groups of 128 states, chunks of 128 and of
+    ``[1, 8192, 64 x 64]`` over 8 groups of 128 states, and at a divided
+    mixer's ``[1, 8192, 16 x 64]`` over one group (the Granite 4.0-H cell's:
+    a grid step its 16 heads), chunks of 128 and of
     256, not interpreted: two kernels under their names, heads of 64 lanes
     two a lane block, nothing held but the states before every chunk (a
     ``[128, 128]`` block a pair of heads), and ``Delta`` and its gradient as
-    ``[T, 64]`` float32."""
+    ``[T, H]`` float32."""
     from tepdist_tpu.ops.pallas.ssd_attention import ssd_attention
     one_chip = SingleDeviceSharding(v5e_devices[0])
-    T, H, P, G, N = 8192, 64, 64, 8, 128
+    T, P, N = 8192, 64, 128
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -50,7 +55,7 @@ def test_ssd_kernels_compile_for_v5e(v5e_devices):
                                        wide).compile()
         text = compiled.as_text()
         names = _custom_calls(text)
-        for kernel in ("tepdist_ssd_fwd__g8", "tepdist_ssd_bwd__g8"):
+        for kernel in (f"tepdist_ssd_fwd__g{G}", f"tepdist_ssd_bwd__g{G}"):
             assert sum(kernel in n for n in names) == 1, names
         assert f"f32[1,{T // chunk},{H // 2},{2 * P},{N}]" in text
         states = T // chunk * H * P * N * 4
