@@ -600,25 +600,34 @@ def hyper_maps(x, phi, b, alpha, *, rounds: int, eps: float,
     product takes the stream as it is stored (bf16 operands where it is
     bf16, accumulated in float32) and no normalised copy of it is made.
     Everything after the product is float32 with the tokens along the
-    lanes, ``m`` as ``[n^2 + 2n, B, T]`` and the mixing matrix ``[n, n, B,
-    T]``: a Sinkhorn round is adds of its rows or columns as whole slices,
-    one reciprocal a column or row and a multiply, nothing is reduced
-    across lanes and no ``reduce`` is asked for (:func:`_sinkhorn`)."""
+    lanes and the sublanes, ``m`` as ``[n^2 + 2n, 8, B T / 8]`` and the
+    mixing matrix ``[n, n, 8, B T / 8]``, however the caller divides its
+    tokens into ``B`` and ``T`` (a plane ``[B, T]`` is tiled by its ``B``,
+    two sublanes a tile at two sequences and one of eight at one, and the
+    maps' seconds followed the chunk's shape: PERF.md, PR 65; ``[B, T]``
+    only where the tokens do not divide by eight): a Sinkhorn round is adds
+    of its rows or columns as whole slices, one reciprocal a column or row
+    and a multiply, nothing is reduced across lanes and no ``reduce`` is
+    asked for (:func:`_sinkhorn`)."""
     n = _hyper_lanes(phi)
+    tokens = x.shape[:-1]
+    count = math.prod(tokens)
+    plane = tokens if count % 8 else (8, count // 8)
     with jax.named_scope("mhc_maps"):
         x32 = x.astype(jnp.float32)
-        scale = jax.lax.rsqrt((x32 * x32).mean(-1) + eps)        # [B, T]
+        scale = jax.lax.rsqrt((x32 * x32).mean(-1) + eps).reshape(plane)
         m = jnp.moveaxis(jnp.dot(x, phi, preferred_element_type=jnp.float32),
-                         -1, 0) * scale
+                         -1, 0).reshape(-1, *plane) * scale
         alpha = alpha.astype(jnp.float32)
         b = b.astype(jnp.float32)[:, None, None]
         pre = jax.nn.sigmoid(alpha[0] * m[:n] + b[:n])
         post = 2.0 * jax.nn.sigmoid(alpha[1] * m[n:2 * n] + b[n:2 * n])
         M = jnp.exp(jnp.clip(alpha[2] * m[2 * n:] + b[2 * n:], *clamp)
-                    ).reshape(n, n, *m.shape[1:])
+                    ).reshape(n, n, *plane)
         M = _sinkhorn(M, rounds, hc_eps)
         return jnp.moveaxis(jnp.concatenate(
-            [pre, post, M.reshape(n * n, *m.shape[1:])]), 0, -1)
+            [pre, post, M.reshape(n * n, *plane)]).reshape(-1, *tokens),
+            0, -1)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))

@@ -99,6 +99,34 @@ def test_the_maps_are_the_references_loop(rounds):
     assert float(jnp.std(got[..., 0])) > 0.05     # a token's own maps
 
 
+@pytest.mark.parametrize("positions", [512, 13])
+def test_the_planes_layout_gives_the_same_maps_and_gradients(positions):
+    """The planes follow the count of tokens, not how they divide into
+    sequences (``[8, count / 8]``; 26 tokens do not divide and stay
+    ``[2, 13]``, a sequence of them ``[1, 13]``): two sequences at once are
+    each sequence alone, maps and the gradients of all four operands, to
+    float32's last places."""
+    x, phi, b, alpha, _ = operands()
+    x = jnp.tile(x, (1, 16, 1))[:, :positions]
+    cot = jax.random.normal(jax.random.PRNGKey(7), (B, positions, WIDE))
+
+    def loss(x, phi, b, alpha, cot):
+        maps = program_maps(x, phi, b, alpha, 5)
+        return jnp.sum(maps * cot), maps
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3), has_aux=True))
+    (dx, *both), maps = grad(x, phi, b, alpha, cot)
+    halves = [grad(x[i:i + 1], phi, b, alpha, cot[i:i + 1]) for i in (0, 1)]
+    for i, ((dxi, *_), mapsi) in enumerate(halves):
+        np.testing.assert_allclose(np.asarray(maps[i:i + 1]),
+                                   np.asarray(mapsi), rtol=1e-6, atol=1e-7)
+        np.testing.assert_allclose(np.asarray(dx[i:i + 1]), np.asarray(dxi),
+                                   rtol=1e-5, atol=1e-6)
+    for g, g0, g1 in zip(both, halves[0][0][1:], halves[1][0][1:]):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(g0 + g1),
+                                   rtol=1e-5, atol=1e-5)
+
+
 def test_twenty_rounds_leave_rows_and_columns_summing_to_one():
     """Rows sum to one by the round's last division; columns come there at
     the rate the matrix allows, so the 1e-4 is held on maps a third as far

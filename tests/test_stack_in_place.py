@@ -331,3 +331,48 @@ def test_plain_weights_keep_the_mapped_checkpointed_chunk():
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
         np.testing.assert_allclose(np.asarray(a), np.asarray(w), rtol=0,
                                    atol=2e-6 * float(jnp.abs(w).max()))
+
+
+# -- ``over_sequence`` over several sequences ---------------------------------
+
+D = 6                                   # channels of the chunk loop's toy
+WIDEST = 8                              # ... and what sizes its chunks
+
+
+def _turned(w, start, xc):
+    """Token-wise given ``start``: a token's channels through ``w``, turned
+    by cos of 0.3 its position (as rotary angles are); two results of
+    unequal width."""
+    positions = (start + jnp.arange(xc.shape[1])).astype(jnp.float32)
+    turn = jnp.cos(0.3 * positions)[None, :, None]
+    return jnp.tanh(xc @ w) * turn, (xc * turn).sum(-1)
+
+
+@pytest.mark.parametrize("B", [1, 2, 3])
+def test_chunks_of_several_sequences_give_the_unchunked_function(
+        B, monkeypatch):
+    """Float32, ``B`` sequences of 32 positions in four chunks of 8 of each:
+    both results and ``jax.grad``'s gradients are the unchunked function's,
+    the position-dependent turn at each chunk's own ``start``."""
+    monkeypatch.setattr(layers, "_CHUNK_ELEMENTS", B * 8 * WIDEST)
+    keys = jax.random.split(jax.random.PRNGKey(B), 4)
+    x = jax.random.normal(keys[0], (B, T, D))
+    w = 0.5 * jax.random.normal(keys[1], (D, D))
+    cots = (jax.random.normal(keys[2], (B, T, D)),
+            jax.random.normal(keys[3], (B, T)))
+
+    def loss(chunked, x, w):
+        out = (layers.over_sequence(_turned, WIDEST, x, weights=w)
+               if chunked else _turned(w, 0, x))
+        return sum(jnp.sum(o * c) for o, c in zip(out, cots))
+
+    text = str(jax.make_jaxpr(functools.partial(loss, True))(x, w))
+    assert f":f32[4,{B},8,{D}] " in text        # the chunk loop's results
+    for got, want in zip(
+            jax.tree_util.tree_leaves(jax.value_and_grad(
+                functools.partial(loss, True), argnums=(0, 1))(x, w)),
+            jax.tree_util.tree_leaves(jax.value_and_grad(
+                functools.partial(loss, False), argnums=(0, 1))(x, w))):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=0,
+            atol=2e-6 * float(jnp.abs(want).max()))

@@ -71,9 +71,9 @@ def test_the_xing_cells_step_compiles_for_v5e(v5e_devices):
             if re.search(r" = bf16\[8,(?:3584,1024|1024,3584)\]\S* "
                          r"(?!parameter)", line)]
     assert not made, made[:3]
-    # The mixing matrix [4, 4, 2 sequences, 1024 positions of a chunk] is
+    # The mixing matrix [4, 4, a chunk's 2 x 1,024 tokens as 8 x 256] is
     # multiplied in loops' bodies: a few dozen instructions, where the
     # rounds written out left 14,700.
-    mixes = len(re.findall(r" = f32\[4,4,2,1024\]\S* multiply\(", text))
+    mixes = len(re.findall(r" = f32\[4,4,8,256\]\S* multiply\(", text))
     assert 0 < mixes < 400, mixes
     assert peak < 15.75e9
