@@ -11,7 +11,10 @@ Usage::
 
 Spans are gated by ``TEPDIST_TRACE`` (or ``DEBUG``) and cost one branch
 when disabled; metrics are always on, the compile counter
-(``compile_stats()``, telemetry/compiles.py) among them.
+(``compile_stats()``, telemetry/compiles.py) among them, and so is the step
+log: ``step_log()`` holds one record for every finished
+``TrainingPlan.step()`` (wall, host phases, the wait before it, compiles and
+collector pauses inside it), spans on or off.
 ``start_device_trace(log_dir)`` / ``stop_device_trace()`` run
 ``jax.profiler`` with every span also on its clock as ``tepdist:<name>``.
 ``GetTelemetry`` (rpc/protocol.py)
@@ -31,6 +34,7 @@ from tepdist_tpu.telemetry.trace import (  # noqa: F401
     enabled,
     span,
     start_device_trace,
+    step_log,
     stop_device_trace,
     tracer,
 )
@@ -42,7 +46,7 @@ from tepdist_tpu.telemetry.export import (  # noqa: F401
     to_prometheus,
     write_trace,
 )
-from tepdist_tpu.telemetry import compiles
+from tepdist_tpu.telemetry import compiles, trace
 from tepdist_tpu.telemetry.compiles import compile_stats  # noqa: F401
 from tepdist_tpu.telemetry import calibrate  # noqa: F401
 from tepdist_tpu.telemetry import fidelity  # noqa: F401
@@ -58,3 +62,4 @@ from tepdist_tpu.telemetry.watchtower import (  # noqa: F401
 )
 
 compiles.install()
+trace.install_gc_hook()

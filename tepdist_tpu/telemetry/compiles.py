@@ -18,7 +18,9 @@ While the span recorder is on, each observation is also a ``lower:compile``
 span (``phase=trace|lower|backend``, ``program=<jit name>``), which is what
 answers "which step recompiled". The events report a duration when the
 work is over, so these spans are written then and carry no profiler
-annotation.
+annotation. Recorder on or off, a backend compile or cache read that has
+ended also bumps the step log's ``compiles`` (telemetry/trace.py), which a
+step reads as it starts and as it ends.
 """
 
 from __future__ import annotations
@@ -63,6 +65,8 @@ def _on_duration(event: str, seconds: float, **kw) -> None:
         if n:
             return
     metrics().histogram(f"compile_{phase}_s").observe(seconds)
+    if phase == "backend":
+        trace.STEP_LOG.compiles += 1
     t = trace.tracer()
     if t.enabled:
         t.record_finished("lower:compile", "lower", int(seconds * 1e9),

@@ -35,6 +35,11 @@ Design contract:
   so it lies on the device trace's clock and an idle gap of the device
   can be laid under the span that covers it. The annotation is made only
   while such a trace runs; off, the paths above are untouched.
+* STEP LOG (``StepLog`` / ``step_log()``): one record for every finished
+  ``TrainingPlan.step()``, written by the step itself whether the
+  recorder is on or off: the steps of a window that runs with spans off
+  still say which of them was long and in which phase. One more ring of
+  the same kind, read through the same anchor.
 * Gating: ``TEPDIST_TRACE`` in core/service_env.py. ``DEBUG`` mode
   implies tracing — the debug log lines in executor.py / worker_plan.py /
   rpc/server.py read their durations from spans, so spans are THE timing
@@ -44,15 +49,24 @@ Design contract:
 from __future__ import annotations
 
 import bisect
+import collections
+import gc
+import itertools
+import logging
+import statistics
 import threading
 import time
 import weakref
 from typing import Any, Dict, List, Optional, Tuple
 
+from tepdist_tpu.telemetry.metrics import metrics
+
 try:  # native write path (telemetry/_fastobs.c); pure Python otherwise
     from tepdist_tpu.telemetry import _fastobs
 except Exception:  # pragma: no cover — loader import never raises in-tree
     _fastobs = None  # type: ignore[assignment]
+
+log = logging.getLogger(__name__)
 
 _STRIDE = 5
 
@@ -181,10 +195,10 @@ class _Ring:
     __slots__ = ("data", "cap", "phys", "cursor", "base", "seg_starts",
                  "seg_tids")
 
-    def __init__(self, cap: int, tid: str):
+    def __init__(self, cap: int, tid: str, stride: int = _STRIDE):
         self.cap = cap
         self.phys = cap + 1
-        self.data: List[Any] = [None] * (_STRIDE * self.phys)
+        self.data: List[Any] = [None] * (stride * self.phys)
         self.cursor = 0
         self.base = 0
         self.seg_starts = [0]
@@ -475,3 +489,172 @@ def stop_device_trace() -> None:
     tracer().enabled = _ENABLED_BEFORE
     jax.profiler.stop_trace()
 
+
+# ---------------------------------------------------------------------------
+# The step log
+
+STEP_LOG_CAPACITY = 4096
+# A step is called stalled when its wall is more than STALL_RATIO times the
+# median of the same plan's previous steps, the newest STALL_HISTORY of them,
+# from the plan's fifth step on: the first compiles (or reads the cache) and
+# the second settles the signature.
+STALL_RATIO = 1.25
+STALL_HISTORY = 32
+_STALL_FROM = 4
+
+# plan, step, t0, wall, between, h2d, dispatch, wait, compiles, gc
+_STEP_STRIDE = 10
+_STEP_FIELDS = ("between", "h2d", "dispatch", "wait")
+
+
+def _ms(ns: Optional[int]) -> str:
+    return "-" if ns is None else f"{ns / 1e6:.3f}"
+
+
+class StepLog:
+    """The finished steps of every plan of this process, newest
+    ``STEP_LOG_CAPACITY`` kept: a ring as the span rings are, one for all
+    threads, because a plan's steps follow each other and a process steps
+    one plan at a time (two plans stepped from two threads at one moment
+    may cost a record). Always on; ``configure(enabled=False)`` silences
+    spans, not this.
+
+    Two plain integers are kept for the records and only ever grow:
+    ``compiles`` (backend compiles and cache reads that have ended, bumped
+    by telemetry/compiles.py's listener) and ``gc_ns`` (the collector's
+    pauses, by the ``gc.callbacks`` hook below). A step reads both as it
+    starts and as it ends."""
+
+    def __init__(self, capacity: int = STEP_LOG_CAPACITY):
+        self.ring = _Ring(capacity, "steps", _STEP_STRIDE)
+        self.compiles = 0
+        self.gc_ns = 0
+        self._gc_t0 = 0
+        self._plans = itertools.count()
+
+    def plan(self) -> "PlanSteps":
+        """A new plan's pen; the plan takes the next small integer."""
+        return PlanSteps(self, next(self._plans))
+
+    def _on_gc(self, phase: str, info: Dict[str, Any]) -> None:
+        if phase == "start":
+            self._gc_t0 = time.monotonic_ns()
+        else:
+            self.gc_ns += time.monotonic_ns() - self._gc_t0
+
+    @property
+    def dropped(self) -> int:
+        r = self.ring
+        return max((r.cursor - r.base) - r.cap, 0)
+
+    def snapshot(self, clear: bool = False) -> List[Dict[str, Any]]:
+        """The records oldest first as plain dicts: ``plan``, ``step``,
+        ``ts`` (the step's start, epoch microseconds through the tracer's
+        anchor, the clock of ``Tracer.snapshot()``), the durations ``wall``,
+        ``between``, ``h2d``, ``dispatch``, ``wait`` and ``gc`` in
+        microseconds (``between`` None on a plan's first step, the three
+        phases None where the runtime has none), ``compiles``."""
+        r = self.ring
+        cur = r.cursor
+        data = r.data[:]
+        lo = max(r.base, cur - r.cap, r.cursor - r.phys + 1)
+        anchor = tracer()._anchor_ns
+        out = []
+        for c in range(lo, cur):
+            i = (c % r.phys) * _STEP_STRIDE
+            rec = {"plan": data[i], "step": data[i + 1],
+                   "ts": (data[i + 2] + anchor) // 1000,
+                   "wall": data[i + 3] / 1e3}
+            for k, name in enumerate(_STEP_FIELDS, start=i + 4):
+                rec[name] = None if data[k] is None else data[k] / 1e3
+            rec["compiles"] = data[i + 8]
+            rec["gc"] = data[i + 9] / 1e3
+            out.append(rec)
+        if clear:
+            r.base = cur
+        return out
+
+
+class PlanSteps:
+    """One plan's pen in the step log: what a record needs of the plan's
+    earlier steps (the last return, the walls the stall check compares
+    with). ``t0 = begin()`` as ``step()`` is entered, ``end(step, t0, ...)``
+    as it returns; both read ``time.monotonic_ns``, the span recorder's
+    clock."""
+
+    __slots__ = ("log", "plan", "_returned", "_walls", "_between",
+                 "_compiles0", "_gc0")
+
+    def __init__(self, step_log: StepLog, plan: int):
+        self.log = step_log
+        self.plan = plan
+        self._returned: Optional[int] = None
+        self._walls: collections.deque = collections.deque(
+            maxlen=STALL_HISTORY)
+
+    def begin(self) -> int:
+        t0 = time.monotonic_ns()
+        self._between = None if self._returned is None \
+            else t0 - self._returned
+        self._compiles0 = self.log.compiles
+        self._gc0 = self.log.gc_ns
+        return t0
+
+    def end(self, step: int, t0: int, h2d: Optional[int] = None,
+            dispatch: Optional[int] = None,
+            wait_from: Optional[int] = None) -> int:
+        """Write the record of the step that began at ``t0`` and ends now;
+        ``h2d`` and ``dispatch`` in nanoseconds, the wait from ``wait_from``
+        to now. Returns the step's wall nanoseconds."""
+        now = time.monotonic_ns()
+        wall = now - t0
+        wait = None if wait_from is None else now - wait_from
+        compiles = self.log.compiles - self._compiles0
+        gc_ns = self.log.gc_ns - self._gc0
+        r = self.log.ring
+        c = r.cursor
+        i = (c % r.phys) * _STEP_STRIDE
+        d = r.data
+        d[i] = self.plan
+        d[i + 1] = step
+        d[i + 2] = t0
+        d[i + 3] = wall
+        d[i + 4] = self._between
+        d[i + 5] = h2d
+        d[i + 6] = dispatch
+        d[i + 7] = wait
+        d[i + 8] = compiles
+        d[i + 9] = gc_ns
+        r.cursor = c + 1
+        self._returned = now
+        m = metrics()
+        m.histogram("step_time_ms").observe(wall / 1e6)
+        walls = self._walls
+        if len(walls) >= _STALL_FROM:
+            median = statistics.median(walls)
+            if wall > STALL_RATIO * median:
+                m.counter("steps_stalled").inc()
+                log.warning(
+                    "step %d of plan %d stalled: wall %s ms, %.2f times the "
+                    "median of the %d steps before it; h2d %s, dispatch %s, "
+                    "wait %s, between %s ms; compiles %d, gc %s ms",
+                    step, self.plan, _ms(wall), wall / median, len(walls),
+                    _ms(h2d), _ms(dispatch), _ms(wait), _ms(self._between),
+                    compiles, _ms(gc_ns))
+        walls.append(wall)
+        return wall
+
+
+STEP_LOG = StepLog()
+
+
+def step_log(clear: bool = False) -> List[Dict[str, Any]]:
+    """The process's step log, oldest record first (``StepLog.snapshot``)."""
+    return STEP_LOG.snapshot(clear)
+
+
+def install_gc_hook() -> None:
+    """Time the collector's pauses into ``STEP_LOG.gc_ns``, once per
+    process (``telemetry`` does so when it is imported)."""
+    if STEP_LOG._on_gc not in gc.callbacks:
+        gc.callbacks.append(STEP_LOG._on_gc)

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import functools
 import logging
+import time
 from typing import Any, Callable, Dict, Optional, Sequence
 
 import jax
@@ -27,6 +28,7 @@ from tepdist_tpu.core.mesh import MeshTopology
 from tepdist_tpu.core.service_env import ServiceEnv
 from tepdist_tpu.models.layers import part
 from tepdist_tpu.telemetry import metrics, span, traced
+from tepdist_tpu.telemetry.trace import STEP_LOG
 
 log = logging.getLogger(__name__)
 
@@ -88,6 +90,7 @@ class _SpmdTrainingPlan(TrainingPlan):
     def __init__(self, plan, params, opt_state, n_batch_leaves, devices):
         self._plan = plan
         self._steps = 0     # the ``step=<n>`` every span of one step carries
+        self._log = STEP_LOG.plan()
         # The plan owns its state arrays and threads outputs back as the
         # next step's inputs, so the aliased state buffers are donated.
         with span("plan:lower", cat="planner"):
@@ -114,20 +117,28 @@ class _SpmdTrainingPlan(TrainingPlan):
 
     def step(self, *batch) -> float:
         n = self._steps
-        with span("step", cat="runtime", step=n) as sp:
+        # The step's record in the step log is written whether the span
+        # recorder is on or off, from the same clock and at the spans'
+        # boundaries (telemetry/trace.py: StepLog).
+        with span("step", cat="runtime", step=n):
+            t0 = self._log.begin()
             with span("step:h2d", cat="runtime", step=n):
                 flat_batch = jax.tree_util.tree_leaves(batch)
                 flat_batch = [jax.device_put(v, s) for v, s in
                               zip(flat_batch, self._batch_shardings)]
+            t1 = time.monotonic_ns()
             # Enqueue only (on the first call also the compile or its
             # cache read); the device works on while this returns.
             with span("step:dispatch", cat="runtime", step=n):
                 outs = self._step_fn(*self._state, *flat_batch)
+            t2 = time.monotonic_ns()
             self._state = list(outs[1:1 + self._n_state])
+            t3 = time.monotonic_ns()
             with span("step:wait", cat="runtime", step=n):
                 loss = float(jax.device_get(outs[0]))
+            wall_ns = self._log.end(n, t0, t1 - t0, t2 - t1, t3)
             if ServiceEnv.get().debug:
-                log.info("[ExecutePlan Duration] %.3f ms", sp.elapsed_ms)
+                log.info("[ExecutePlan Duration] %.3f ms", wall_ns / 1e6)
         self._steps = n + 1
         return loss
 
@@ -156,10 +167,17 @@ class _SpmdTrainingPlan(TrainingPlan):
 class _PipelineTrainingPlan(TrainingPlan):
     def __init__(self, exe, params):
         self._exe = exe
+        self._log = STEP_LOG.plan()
         exe.load_variables(params)
 
     def step(self, *batch) -> float:
-        return self._exe.step(*batch)
+        # The step log's record, without the three phases: the executable
+        # has tasks, not one h2d, one dispatch and one wait.
+        n = self._exe.global_step
+        t0 = self._log.begin()
+        loss = self._exe.step(*batch)
+        self._log.end(n, t0)
+        return loss
 
     def variables(self):
         """Same (params, opt_state) contract as the SPMD plan: per-stage
